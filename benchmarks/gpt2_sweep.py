@@ -63,9 +63,6 @@ def main():
         # overhead beats the saved softmax traffic at this seq len. The
         # fused optimizer (default here now, = bench.py) removes ~35ms
         # of optax/gnorm HBM passes per step.
-        # NOTE: benchmarks/tpu_ab_queue.py is the maintained priority
-        # queue for the open A/Bs (fused CE, flash_jax, batch sweep);
-        # run it first when a TPU window opens.
         dict(loss_chunk=4096, vocab_size=50304, ce_impl="checkpoint"),
         dict(loss_chunk=4096, vocab_size=50304, ce_impl="fused"),
         dict(loss_chunk=4096),                       # unpadded baseline

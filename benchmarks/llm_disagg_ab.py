@@ -347,10 +347,13 @@ def run_ab(n_requests: int = N_REQUESTS, clients: int = N_CLIENTS) -> dict:
 
 
 def main() -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # A CPU rehearsal at `tiny` by design (its rows are counts and
+    # ratios for SCALE.json, not device speeds): no chips in the
+    # cluster, so the replicas place as chipless actors.
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import ray_tpu
 
-    ray_tpu.init(num_cpus=max(4, os.cpu_count() or 4),
+    ray_tpu.init(num_cpus=max(4, os.cpu_count() or 4), num_tpus=0,
                  object_store_memory=256 * 1024 * 1024)
     try:
         results = run_ab()

@@ -256,9 +256,9 @@ class Config:
     # Head dispatch shards: >1 splits the head's hot path across that
     # many worker processes (each a full Head over a slice of the
     # cluster, fronted by a connection router + metadata directory in
-    # the parent — see _private/head_shards.py). 0 = auto
-    # (min(4, cpu count)); 1 = the single-process head, bit-identical
-    # to the pre-shard runtime (the kill switch).
+    # the parent — see _private/head_shards.py). 0/1 = the
+    # single-process head. Opt-in: a shard places only what fits its
+    # slice of the box, and a node with TPU chips refuses to shard.
     head_shards: int = 0
 
     # --- timeouts ---
@@ -480,8 +480,8 @@ ENV_KNOBS = {
     "RAY_TPU_HEAD_SHARDS": (
         "operator", "head dispatch shards: N>1 runs N parallel head "
         "shard processes behind a connection router + metadata "
-        "directory, 1 pins the single-process head (kill switch), "
-        "0/unset = auto (min(4, ncpu))"),
+        "directory; 0/1/unset = the single-process head. Refused on "
+        "a node with TPU chips"),
     # -- internal spawn plumbing -------------------------------------
     "RAY_TPU_SHARD_BOOT": (
         "internal", "pickled boot payload path handed to a head shard "

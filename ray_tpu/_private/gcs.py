@@ -186,9 +186,10 @@ class WorkerRecord:
         # at 1->0.
         self.blocked = 0
         self.released_alloc = None
-        # Spawned with device-plugin hooks intact (can take TPU leases).
-        # Chipless pool workers spawn with the hooks stripped so their
-        # jax can never touch — or hang on — the TPU path.
+        # Spawned without the JAX_PLATFORMS=cpu pin chipless workers
+        # get, so it can take ONE chip lease: libtpu holds the leased
+        # chips until the process exits, so the worker retires when the
+        # lease ends (_release_worker_allocation).
         self.tpu_capable = tpu_capable
         # Direct-call plane worker lease (reference: the raylet-granted
         # worker lease the owner-side cache pipelines onto,
@@ -1002,30 +1003,16 @@ class Head:
         retry on the next dispatch pass; zygote.on_ready sets
         dispatch_event so that pass happens immediately.
 
-        ``tpu_capable`` workers keep any TPU device-plugin startup hooks
-        so they can take chip leases; chipless pool workers spawn with
-        the hooks stripped (hermetic.strip_plugin_hooks) — a plugin that
-        loads at interpreter start ignores per-task JAX_PLATFORMS pins
-        and would capture or hang the worker's jax on the TPU path."""
+        Chipless pool workers are spawned pinned to the CPU and
+        ``tpu_capable`` ones are not (hermetic.worker_jax_env)."""
         if node_id != self.node_id:
             return self._spawn_remote_worker(node_id, tpu_capable)
         worker_id = self._new_worker_id()
-        env = dict(os.environ)
+        env = self._worker_base_env(tpu_capable)
         env["RAY_TPU_WORKER_ID"] = worker_id
-        env["RAY_TPU_HEAD"] = f"{self.address[0]}:{self.address[1]}"
         env["RAY_TPU_SHM"] = f"{self.shm_name}:{self.config.object_store_memory}"
         env["RAY_TPU_NODE_ID"] = node_id
         env["RAY_TPU_SESSION_DIR"] = self.session_dir
-        # Workers resolve functions pickled by reference (module+name), so
-        # they need the driver's import roots (reference analogue: workers
-        # inherit the driver's sys.path / working_dir runtime env).
-        extra = [p for p in sys.path if p and os.path.isdir(p)]
-        existing = env.get("PYTHONPATH", "")
-        env["PYTHONPATH"] = os.pathsep.join(extra + ([existing] if existing else []))
-        if not tpu_capable:
-            from ray_tpu._private.hermetic import strip_plugin_hooks
-
-            strip_plugin_hooks(env)
         logs = os.path.join(self.session_dir, "logs")
         os.makedirs(logs, exist_ok=True)
         proc = None
@@ -1066,20 +1053,30 @@ class Head:
             self.workers[worker_id] = rec
         return rec
 
+    def _worker_base_env(self, tpu_capable: bool) -> dict:
+        """Spawn environment every local worker (and the zygote they
+        fork from) starts with."""
+        from ray_tpu._private.hermetic import worker_jax_env
+
+        env = dict(os.environ)
+        env["RAY_TPU_HEAD"] = f"{self.address[0]}:{self.address[1]}"
+        # Workers resolve functions pickled by reference (module+name), so
+        # they need the driver's import roots (reference analogue: workers
+        # inherit the driver's sys.path / working_dir runtime env).
+        extra = [p for p in sys.path if p and os.path.isdir(p)]
+        existing = env.get("PYTHONPATH", "")
+        env["PYTHONPATH"] = os.pathsep.join(
+            extra + ([existing] if existing else []))
+        env.update(worker_jax_env(tpu_capable))
+        return env
+
     def _zygote(self):
         """Lazily-started fork-server for chipless local workers."""
         zy = getattr(self, "_zygote_client", None)
         if zy is None:
-            from ray_tpu._private.hermetic import strip_plugin_hooks
             from ray_tpu._private.zygote import ZygoteClient
 
-            env = dict(os.environ)
-            env["RAY_TPU_HEAD"] = f"{self.address[0]}:{self.address[1]}"
-            extra = [p for p in sys.path if p and os.path.isdir(p)]
-            existing = env.get("PYTHONPATH", "")
-            env["PYTHONPATH"] = os.pathsep.join(
-                extra + ([existing] if existing else []))
-            strip_plugin_hooks(env)
+            env = self._worker_base_env(tpu_capable=False)
             zy = self._zygote_client = ZygoteClient(
                 env, os.path.join(self.session_dir, "logs"))
             # Deferred spawns retry the moment warmup lands (or fails —
@@ -2197,6 +2194,10 @@ class Head:
             if old is not None and old is not conn:
                 old.peer_info.pop("node_agent_for", None)
             self.scheduler.add_node(entry)
+            # First join only: a re-joining node's leased chips are
+            # still out with their holders.
+            self.tpu_chip_pool.setdefault(
+                node_id, list(range(int(resources.get("TPU", 0)))))
             self.node_agents[node_id] = conn
             self._agent_last_seen[node_id] = time.time()
             # New capacity: retry pending placement groups (also the
@@ -3168,6 +3169,8 @@ class Head:
                 self._shed_expired(spec, "submit")
             elif spec.actor_id is not None:
                 self._enqueue_actor_task(spec)
+            elif (refusal := self._chips_never_fit(spec.resources)):
+                self._fail_task(spec, refusal, kind="unschedulable")
             else:
                 self._enqueue_task_spec(spec)
                 self._record_lineage(spec)
@@ -3642,6 +3645,10 @@ class Head:
 
     def _h_create_actor(self, body, conn):
         spec: ActorSpec = body["spec"]
+        with self.lock:
+            refusal = self._chips_never_fit(spec.resources)
+        if refusal:
+            raise rpc.RpcError(refusal)
         if spec.name and self.shard is not None:
             # Cluster-wide atomic claim in the directory (outside
             # self.lock: bus round-trip). The local table below stays
@@ -5864,7 +5871,23 @@ class Head:
         return True
 
     def _release_worker_allocation(self, rec: WorkerRecord) -> None:
-        """lock held. Return node or PG-bundle resources + chips."""
+        """lock held. Return node or PG-bundle resources + chips.
+
+        A live worker that was leased chips keeps its whole allocation
+        until its process has exited: libtpu holds the chips for the
+        life of the process, so handing them on any earlier makes the
+        next holder race its lock. Such a worker is retired here
+        instead (actor workers are killed by their callers) and the
+        death handler, which has waited for the exit, releases."""
+        if rec.tpu_chips and self.workers.get(rec.worker_id) is rec:
+            if rec.actor_id is None:
+                rec.retiring = True
+                if rec.expected_exit is None:
+                    rec.expected_exit = (
+                        "retired", "chip lease ended; the process exits "
+                        "to give its chips back")
+                self._maybe_release_retiree(rec.worker_id)
+            return
         if rec.acquired is not None:
             self.scheduler.release(rec.node_id, rec.acquired)
             rec.acquired = None
@@ -5877,27 +5900,58 @@ class Head:
         rec.cur_rkey = None
         self._return_tpu_chips(rec)
 
-    # TPU chip visibility assignment (reference semantics:
+    # TPU chip assignment (reference semantics:
     # _private/accelerators/tpu.py set_current_process_visible_accelerator_ids
-    # :193 — TPU_VISIBLE_CHIPS) handled at dispatch.
+    # :193 — TPU_VISIBLE_CHIPS). The worker turns rec.tpu_chips into its
+    # environment (accelerators/tpu.py chip_process_env).
     def _assign_tpu_chips(self, rec: WorkerRecord, resources: dict[str, float]) -> bool:
-        """Returns False if the chip pool cannot cover the request — callers
-        must treat that as unschedulable, never run with fewer chips than
-        the resource contract promised."""
+        """Returns False while the chip pool cannot cover the request
+        (chips of a finished lease come back when their holder has
+        exited) — never run with fewer chips than the resource contract
+        promised. Requests no node could ever cover were refused at
+        submission (_chips_never_fit)."""
         n = int(resources.get("TPU", 0))
         if n <= 0:
             return True
         pool = self.tpu_chip_pool.get(rec.node_id, [])
-        if len(pool) < n:
+        # Only a block aligned to its own size (0-1 / 2-3, never 1-2):
+        # chips are numbered along the host's torus, and an aligned
+        # block is a sub-box libtpu can form. A fragmented pool makes
+        # the request wait for a holder to exit.
+        free = set(pool)
+        for lo in range(0, max(free, default=-1) + 1, n):
+            if free.issuperset(range(lo, lo + n)):
+                break
+        else:
             return False
-        rec.tpu_chips = pool[:n]
-        self.tpu_chip_pool[rec.node_id] = pool[n:]
+        rec.tpu_chips = list(range(lo, lo + n))
+        self.tpu_chip_pool[rec.node_id] = [c for c in pool
+                                           if c not in rec.tpu_chips]
         return True
 
     def _return_tpu_chips(self, rec: WorkerRecord) -> None:
         if rec.tpu_chips:
             self.tpu_chip_pool.setdefault(rec.node_id, []).extend(rec.tpu_chips)
             rec.tpu_chips = []
+
+    def _chips_never_fit(self, resources: dict) -> "str | None":
+        """lock held. The refusal for a chip request that is not whole
+        or is larger than every alive node's chip TOTAL — such work
+        would otherwise queue forever. (A cluster that grows TPU nodes
+        on demand needs one node of the wanted size registered before
+        the work arrives.)"""
+        n = float((resources or {}).get("TPU", 0) or 0)
+        if n <= 0:
+            return None
+        if n != int(n):
+            return (f"TaskUnschedulableError: {n:g} TPU chips requested; "
+                    f"a chip belongs to one process, so ask for whole chips")
+        most = max((node.total.get("TPU")
+                    for node in self.scheduler.alive_nodes()), default=0.0)
+        if n <= most:
+            return None
+        return (f"TaskUnschedulableError: {n:g} TPU chips requested but "
+                f"no node has more than {most:g}")
 
     # ------------------------------------------------------------------
     # failure handling + crash forensics
@@ -6051,6 +6105,22 @@ class Head:
             blurb += f"\n  post-mortem stack excerpt:\n    {excerpt}"
         return blurb
 
+    @staticmethod
+    def _await_chip_holder_exit(rec: WorkerRecord, grace_s: float = 10.0
+                                ) -> None:
+        """A chip-holding LOCAL worker's connection dropped: its chips
+        go back to the pool only once the process is really gone (its
+        libtpu lock dies with it). Bounded: a process still alive after
+        the grace is killed. Remote workers have no handle here — their
+        connection drop is the exit."""
+        if rec.proc is None:
+            return
+        try:
+            rec.proc.wait(timeout=grace_s)
+        except subprocess.TimeoutExpired:
+            rec.proc.kill()
+            rec.proc.wait()
+
     def _handle_worker_death(self, rec: WorkerRecord) -> None:
         """Worker connection dropped or process died.
 
@@ -6065,6 +6135,8 @@ class Head:
         # worker dies at once there and nobody will read the reports —
         # N× (status wait + file reads) on the dying conns' reader
         # threads is pure teardown drag.
+        if rec.tpu_chips and not self._shutdown:
+            self._await_chip_holder_exit(rec)
         try:
             if self._shutdown:
                 crash = {"worker_id": rec.worker_id,

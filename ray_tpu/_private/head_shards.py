@@ -91,17 +91,18 @@ def mint_for_shard(prefix: str, shard: int, total: int) -> str:
 
 
 def resolved_head_shards(config: Config) -> int:
-    """The effective shard count: the knob, or min(4, ncpu) when 0
-    (auto). A 1-core box resolves to 1 — sharding there would only
-    add process hops around the same GIL'd core."""
+    """The effective shard count: the knob when set, else one head
+    process. Sharding is opt-in until it has been measured on real
+    cores (ROADMAP C6): each shard schedules only its SLICE of the box,
+    so under an automatic split a request bigger than a slice — two
+    CPUs of four, one unit of a custom resource, a chip — never
+    placed."""
     n = int(getattr(config, "head_shards", 0) or 0)
     if n < 1:
         # Config objects built without apply_overrides (scripts.py
         # cmd_start) still honor the operator knob.
         n = int(os.environ.get("RAY_TPU_HEAD_SHARDS") or 0)
-    if n >= 1:
-        return n
-    return max(1, min(4, os.cpu_count() or 1))
+    return max(1, n)
 
 
 def create_head(config: Config, num_cpus=None, num_tpus=None,
@@ -423,6 +424,14 @@ class ShardDirectory:
         from ray_tpu._private.scheduler import split_shard_resources
 
         base = self._detect(num_cpus, num_tpus, resources)
+        if base.get("TPU", 0) > 0:
+            # A chip belongs to one process and a mesh worker needs all
+            # of its host's chips from one pool; slices cannot offer
+            # either. Fail at boot instead of parking chip work forever.
+            raise ValueError(
+                f"head_shards={total} cannot be combined with TPU chips "
+                f"({base['TPU']:g} on this node): unset "
+                f"RAY_TPU_HEAD_SHARDS / head_shards")
         self._slices = [split_shard_resources(base, i, total)
                         for i in range(total)]
         # shard bus (loopback; shards dial it at boot)
@@ -470,10 +479,8 @@ class ShardDirectory:
             "parent_session": self.session_dir,
             "bus_addr": tuple(self.bus_server.address),
             "num_cpus": self._slices[sp.index].get("CPU", 1.0),
-            # Explicit 0.0 (not None) when the slice holds no chips:
-            # None would re-run detection and give EVERY shard the
-            # whole chip pool.
-            "num_tpus": self._slices[sp.index].get("TPU", 0.0),
+            # Explicit 0.0, not None: None would re-run detection.
+            "num_tpus": 0.0,
             "resources": {
                 k: v for k, v in self._slices[sp.index].items()
                 if k not in ("CPU", "TPU", "memory")} or None,

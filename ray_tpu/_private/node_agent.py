@@ -714,12 +714,10 @@ class NodeAgent:
 
     def _spawn(self, body: dict) -> None:
         worker_id = body["worker_id"]
-        env = dict(os.environ)
-        if not body.get("tpu_capable"):
-            # Chipless pool worker: TPU-invisible (see Head.spawn_worker).
-            from ray_tpu._private.hermetic import strip_plugin_hooks
+        from ray_tpu._private.hermetic import worker_jax_env
 
-            strip_plugin_hooks(env)
+        env = dict(os.environ)
+        env.update(worker_jax_env(bool(body.get("tpu_capable"))))
         env["RAY_TPU_WORKER_ID"] = worker_id
         # Use the address THIS agent dialed, not the head's bind address —
         # a head bound to 0.0.0.0 would otherwise tell remote workers to

@@ -38,6 +38,7 @@ from ray_tpu.exceptions import (
     RayTpuError,
     TaskError,
     TaskTimeoutError,
+    TaskUnschedulableError,
     WorkerCrashedError,
 )
 
@@ -48,6 +49,7 @@ _ERROR_KINDS = {
     "object_lost": ObjectLostError,
     "task_timeout": TaskTimeoutError,
     "pending_calls_limit": PendingCallsLimitError,
+    "unschedulable": TaskUnschedulableError,
 }
 
 
@@ -2320,7 +2322,15 @@ class CoreRuntime:
         self.conn.cast_buffered("submit_actor_task", body)
 
     def create_actor(self, spec: ActorSpec) -> None:
-        self.conn.call("create_actor", {"spec": spec})
+        try:
+            self.conn.call("create_actor", {"spec": spec})
+        except rpc.RpcError as e:
+            # The head's refusal (Head._chips_never_fit), typed; the
+            # reply carries the handler's traceback before it.
+            _, marker, reason = str(e).rpartition("TaskUnschedulableError:")
+            if marker:
+                raise TaskUnschedulableError(marker + reason.rstrip()) from None
+            raise
 
     # ------------------------------------------------------------------
 

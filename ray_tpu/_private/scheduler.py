@@ -44,8 +44,8 @@ def split_shard_resources(base: dict, index: int, total: int) -> dict:
     """One head shard's slice of the box (sharded head,
     head_shards.py): CPUs floor-divided with the remainder to the low
     shard indexes (never below 1 — a shard must be able to run a
-    worker), TPU chips partitioned contiguously so no chip is visible
-    from two shards, custom resources divided evenly. node:* keys are
+    worker), custom resources divided evenly. A node with TPU chips is
+    never sharded (ShardDirectory refuses it). node:* keys are
     dropped — each shard's Head mints its own node identity."""
     out: dict = {}
     for key, val in (base or {}).items():
@@ -55,12 +55,6 @@ def split_shard_resources(base: dict, index: int, total: int) -> dict:
             n = int(val)
             share = n // total + (1 if index < n % total else 0)
             out["CPU"] = float(max(1, share))
-        elif key == "TPU":
-            n = int(val)
-            lo = (n * index) // total
-            hi = (n * (index + 1)) // total
-            if hi > lo:
-                out["TPU"] = float(hi - lo)
         elif key == "memory":
             out["memory"] = float(val) / total
         else:

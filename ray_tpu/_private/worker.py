@@ -133,6 +133,8 @@ class Worker:
         # defensive getattr chain there is measurable at 100k pushes/s.
         self._recycle_pending = False
         self._retiring_sent = False
+        # Chips this process was pointed at by its (one) chip lease.
+        self._chips: "list | None" = None
         # Head-pushed normal tasks queued or running here. The head
         # grants a lease on the very push that makes this worker busy,
         # so the owner's lease can look idle while a head task runs —
@@ -182,6 +184,12 @@ class Worker:
             t = getattr(self, "_retire_timer", None)
             if t is not None:
                 t.cancel()
+            if self._chips:
+                # The head hands this process's chips on only once it is
+                # gone: a libtpu teardown that hangs must not park them.
+                backstop = threading.Timer(10.0, os._exit, (0,))
+                backstop.daemon = True
+                backstop.start()
             self._exit.set()
             return
         if kind == "push_task":
@@ -215,7 +223,8 @@ class Worker:
                 self.executor = ThreadPoolExecutor(
                     max_workers=maxc, thread_name_prefix="actor-exec"
                 )
-            self._set_tpu_env(body.get("tpu_chips"))
+            if body.get("tpu_chips"):
+                self._hold_chips(body["tpu_chips"])
             self.executor.submit(self._run_task_guarded, body["spec"], None)
         elif kind == "profile_start":
             # Sampling profiler (reference: reporter/profile_manager.py
@@ -438,22 +447,25 @@ class Worker:
         except Exception:
             pass
 
-    @staticmethod
-    def _set_tpu_env(chips) -> None:
-        """TPU chip visibility pinning for the actor lifetime (reference
-        semantics: _private/accelerators/tpu.py:193
-        set_current_process_visible_…). Actors without a TPU lease are
-        pinned to CPU jax — same policy as the reference making unleased
-        GPUs invisible (CUDA_VISIBLE_DEVICES=\"\"): parallel actors must
-        not contend for the chips the driver owns. Only effective before
-        this process's first jax import (the normal case — user code is
-        imported lazily). Normal tasks get the same pinning per-task with
-        save/restore in _run_task."""
-        if chips:
-            os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in chips)
-            os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = f"1,{len(chips)},1"
-        elif "jax" not in sys.modules:
-            os.environ["JAX_PLATFORMS"] = "cpu"
+    def _hold_chips(self, chips) -> None:
+        """Point this process at the chips the head leased it — the one
+        call both the actor path (become_actor) and the task path
+        (_run_task) make. It only works before the first jax backend
+        init, and libtpu keeps the chips until the process exits, so a
+        chip lease is for life: the head retires this worker when the
+        lease ends and a second, different lease is refused here."""
+        chips = list(chips)
+        if self._chips == chips:
+            return  # every push to a chip holder repeats its lease
+        if self._chips is not None:
+            raise RuntimeError(
+                f"worker {self.worker_id} already holds chips "
+                f"{self._chips}; it cannot be re-pointed at {chips}")
+        self._chips = chips
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+
+        TPUAcceleratorManager.set_current_process_visible_accelerator_ids(
+            chips)
 
     # ------------------------------------------------------------------
     # actor concurrency plumbing
@@ -1057,19 +1069,6 @@ class Worker:
         inherited_env = spec.runtime_env or getattr(
             self, "actor_runtime_env", None)
         env_vars = (spec.runtime_env or {}).get("env_vars", {})
-        if tpu_chips:
-            env_vars = dict(env_vars)
-            env_vars["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in tpu_chips)
-        elif (spec.actor_id is None and "jax" not in sys.modules
-              and "JAX_PLATFORMS" not in env_vars
-              and os.environ.get("JAX_PLATFORMS") != "cpu"):
-            # (the != "cpu" check: hook-stripped pool workers already
-            # carry the pin — skip the per-task set/restore entirely)
-            # Chipless task: keep this worker's (first) jax import off the
-            # TPU. Applied on the executor thread with save/restore, so a
-            # later TPU-leased task on this worker is unaffected.
-            env_vars = dict(env_vars)
-            env_vars["JAX_PLATFORMS"] = "cpu"
         for k, v in env_vars.items():
             saved_env[k] = os.environ.get(k)
             os.environ[k] = str(v)
@@ -1102,6 +1101,8 @@ class Worker:
                 cache = os.path.join(self.runtime.session_dir, "runtime_env_cache")
                 os.makedirs(cache, exist_ok=True)
                 applied_env.apply(spec.runtime_env, self.runtime, cache)
+            if tpu_chips:
+                self._hold_chips(tpu_chips)
             args, kwargs = self._load_args(spec)
 
             if spec.actor_creation:

@@ -4,14 +4,15 @@ Counterpart of the reference's pre-started worker-pool processes
 (reference: src/ray/raylet/worker_pool.h:224 — the raylet keeps warm
 workers so task/actor assignment costs one RPC, not an interpreter
 start). A fresh ``python -m ray_tpu._private.worker`` pays the full
-interpreter + package import (~300 ms hermetic, seconds with device-
-plugin site hooks). The zygote pays that ONCE: it imports the worker
-module single-threaded, then forks a child per spawn request (~5 ms),
-which applies its per-worker env and enters the normal worker main.
+interpreter + package import (~300 ms). The zygote pays that ONCE: it
+imports the worker module single-threaded, then forks a child per
+spawn request (~5 ms), which applies its per-worker env and enters the
+normal worker main.
 
-Only chipless workers fork from the zygote — TPU-capable workers must
-run the device-plugin interpreter hooks at startup, and a forked,
-already-initialized runtime cannot re-bind chips safely.
+Only chipless workers fork from the zygote: it runs with
+``JAX_PLATFORMS=cpu`` like they do, and forks inherit it. A TPU-capable
+worker starts as a fresh interpreter without that pin and is pointed at
+its leased chips before its first jax use.
 
 Protocol (line-JSON over stdin/stdout):
     parent -> zygote: {"env": {...}, "log": "/path/worker.log"}

@@ -45,10 +45,10 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
-        """Number of TPU chips attached to this host.
+        """Number of TPU chips this process may hand out.
 
         Order: explicit TPU_VISIBLE_CHIPS; TPU_CHIP_COUNT (set by TPU VM
-        images); /dev/accel* (v2-v4 PCI) or /dev/vfio/* (v5e+) device files.
+        images); the host's device files.
         """
         visible = os.environ.get("TPU_VISIBLE_CHIPS")
         if visible:
@@ -59,13 +59,7 @@ class TPUAcceleratorManager:
                 return int(count)
             except ValueError:
                 pass
-        accel = glob.glob("/dev/accel*")
-        if accel:
-            return len(accel)
-        vfio = [p for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()]
-        if vfio:
-            return len(vfio)
-        return 0
+        return host_chip_count()
 
     @staticmethod
     def get_current_node_tpu_pod_type() -> str | None:
@@ -114,13 +108,10 @@ class TPUAcceleratorManager:
 
     @staticmethod
     def set_current_process_visible_accelerator_ids(ids: list[str] | list[int]) -> None:
-        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i in ids)
-        n = len(ids)
-        # Topology bounds strings per reference tpu.py:39-44.
-        bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,2,2"}.get(n)
-        if bounds:
-            os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
-            os.environ["TPU_PROCESS_BOUNDS"] = "1,1,1"
+        """Confine this process's libtpu to ``ids``. Only effective
+        before the process's first jax backend init."""
+        for k, v in chip_process_env(ids, host_chip_count()).items():
+            os.environ[k] = v
 
     # --- gang resources (reference :375,419-434) ---
 
@@ -134,6 +125,43 @@ class TPUAcceleratorManager:
         if pod_type is not None and worker_id == 0:
             return {f"TPU-{pod_type}-head": 1.0}
         return {}
+
+
+def host_chip_count() -> int:
+    """Chips physically attached to this host, from its device files:
+    /dev/accel* (v2-v4 PCI) or the numbered /dev/vfio groups (v5e+; a
+    one-chip v5e VM shows /dev/vfio/1, a four-chip host /dev/vfio/0-3 —
+    the names are IOMMU groups, not chip ids). Ignores TPU_VISIBLE_CHIPS:
+    this is what a process would hold if nothing narrowed it."""
+    accel = glob.glob("/dev/accel*")
+    if accel:
+        return len(accel)
+    return len([p for p in glob.glob("/dev/vfio/*")
+                if os.path.basename(p).isdigit()])
+
+
+# libtpu's bounds ("x,y,z") for a process that owns a SUBSET of its
+# host's chips, by subset size. 1: run on a v5e 2x2 host, four such
+# processes at once, with nothing else set (no per-process port; the
+# inherited TPU_CHIPS_PER_HOST_BOUNDS=2,2,1 / TPU_HOST_BOUNDS stay).
+# Other sizes have no entry: chips are still narrowed by
+# TPU_VISIBLE_CHIPS and libtpu raises at init if it cannot form them.
+_SUBSET_BOUNDS = {1: "1,1,1"}
+
+
+def chip_process_env(chips, host_chips: int) -> dict[str, str]:
+    """The environment that makes a process hold exactly ``chips``
+    (chip indexes on this host) — the one place both the actor and the
+    task path of a chip-holding worker get it from. A process given
+    every chip of the host needs no bounds at all; so does one on a
+    host with no device files (``host_chips`` 0: a scheduling-only
+    ``num_tpus`` with no libtpu behind it)."""
+    env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips)}
+    bounds = _SUBSET_BOUNDS.get(len(chips))
+    if bounds and len(chips) < host_chips:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return env
 
 
 # --- public helpers (reference analogue: python/ray/util/accelerators/tpu.py) ---

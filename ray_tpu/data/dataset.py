@@ -226,19 +226,9 @@ class Dataset:
         drop_last defaults True: fixed shapes avoid XLA recompiles.
         `sharding` (e.g. a NamedSharding over the data axis) device_puts
         each batch for a pjit step."""
-        import jax
-        import jax.numpy as jnp
-
-        for batch in self.iter_batches(batch_size=batch_size, drop_last=drop_last):
-            out = {}
-            for k, v in batch.items():
-                arr = jnp.asarray(v) if v.dtype != object else v
-                if dtypes and k in dtypes:
-                    arr = arr.astype(dtypes[k])
-                if sharding is not None and isinstance(arr, jax.Array):
-                    arr = jax.device_put(arr, sharding)
-                out[k] = arr
-            yield out
+        return _jax_batches(
+            self.iter_batches(batch_size=batch_size, drop_last=drop_last),
+            sharding, dtypes)
 
     def iter_torch_batches(self, *, batch_size: int | None = 256,
                            drop_last: bool = False) -> Iterator[dict]:
@@ -642,6 +632,23 @@ class Datasink:
         pass
 
 
+def _jax_batches(batches, sharding, dtypes) -> Iterator[dict]:
+    """numpy batches -> dicts of jax arrays, placed by ``sharding``."""
+    import jax
+    import jax.numpy as jnp
+
+    for batch in batches:
+        out = {}
+        for k, v in batch.items():
+            arr = jnp.asarray(v) if v.dtype != object else v
+            if dtypes and k in dtypes:
+                arr = arr.astype(dtypes[k])
+            if sharding is not None and isinstance(arr, jax.Array):
+                arr = jax.device_put(arr, sharding)
+            out[k] = arr
+        yield out
+
+
 class DataIterator:
     """A worker's shard view (reference: data/iterator.py DataIterator)."""
 
@@ -663,6 +670,16 @@ class DataIterator:
             if drop_last and batch_size and acc.num_rows() < batch_size:
                 continue
             yield acc.to_batch(batch_format)
+
+    def iter_jax_batches(self, *, batch_size: int | None = 256,
+                         drop_last: bool = True, sharding=None,
+                         dtypes: dict | None = None) -> Iterator[dict]:
+        """This shard's batches as jax device arrays — what a train loop
+        calls on ``train.get_dataset_shard(...)`` (see
+        Dataset.iter_jax_batches)."""
+        return _jax_batches(
+            self.iter_batches(batch_size=batch_size, drop_last=drop_last),
+            sharding, dtypes)
 
     def iter_rows(self) -> Iterator[Any]:
         for block in self._blocks():

@@ -125,6 +125,11 @@ class PendingCallsLimitError(RayTpuError):
     """
 
 
+class TaskUnschedulableError(RayTpuError):
+    """The task or actor asks for resources no node of the cluster can
+    ever provide (more TPU chips than any node has, or part of one)."""
+
+
 class PlacementGroupUnschedulableError(RayTpuError):
     """The placement group cannot fit on the cluster."""
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import os
 import threading
 import time
 from typing import Any, Iterable
@@ -204,6 +205,7 @@ class LLMEngine:
             cache = {k: jax.device_put(v, kv_spec) for k, v in cache.items()}
         self.params = params
         self.cache = cache
+        self._device_info: "dict | None" = None
         # Host-side scheduling state (uploaded per decode call): keeping
         # positions on host avoids a device→host sync per slot per token.
         self.positions = np.zeros((B,), np.int32)
@@ -1020,6 +1022,25 @@ class LLMEngine:
             _, old = self._prefix_pool.popitem(last=False)
             self.kv_alloc.free(old)
 
+    def device_info(self) -> dict:
+        """What this engine runs on, read from its own arrays (not
+        from jax's defaults): a caller learns the platform from the
+        process that holds them. ``chips`` is the chip lease of that
+        process (every one-chip process numbers its device 0). Placement
+        is fixed at construction, so this is worked out once."""
+        if self._device_info is None:
+            devs = {d for leaf in jax.tree.leaves((self.params, self.cache))
+                    if isinstance(leaf, jax.Array)
+                    for d in leaf.sharding.device_set}
+            first = min(devs, key=lambda d: d.id)
+            self._device_info = {
+                "platform": first.platform,
+                "device_kind": first.device_kind,
+                "n_devices": len(devs),
+                "chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            }
+        return self._device_info
+
     def kv_stats(self) -> dict:
         """Paged-KV + prefix-cache accounting for telemetry/gauges."""
         out = {
@@ -1720,6 +1741,7 @@ class AsyncLLMEngine:
                 "owned": len(self._waiters) + len(self._streams),
                 "evicted_deadline": self._evicted_deadline,
                 "kv": self.engine.kv_stats(),
+                **self.engine.device_info(),
             }
 
     def _fail_all(self, exc: Exception) -> None:

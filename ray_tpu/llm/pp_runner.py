@@ -40,7 +40,6 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.llm import model_runner as mr
 from ray_tpu.models.transformer import TransformerConfig
-from ray_tpu.parallel.jax_compat import shard_map as _shard_map
 from ray_tpu.parallel.mesh import AXIS_PIPELINE
 from ray_tpu.parallel.pipeline import pipeline_last_to_all
 
@@ -140,7 +139,7 @@ class PPRunner:
             last = self._last_stage_logits(xl, params, dt)[0, 0]
             return last, kc, vc
 
-        last, k_new, v_new = _shard_map(
+        last, k_new, v_new = jax.shard_map(
             inner,
             mesh=self.mesh,
             in_specs=(self._param_specs(params), P(), P(), P(),
@@ -178,7 +177,7 @@ class PPRunner:
             toks = mr.sample_tokens(logits, temperature, rng)
             return toks, logits, kc, vc
 
-        toks, logits, k_new, v_new = _shard_map(
+        toks, logits, k_new, v_new = jax.shard_map(
             inner,
             mesh=self.mesh,
             in_specs=(self._param_specs(params), P(), P(),

@@ -491,13 +491,30 @@ class LLMServer:
         }
 
 
+def _replica_actor_options(config: LLMConfig) -> dict:
+    """Where an engine replica runs: on as many chips as its mesh has
+    devices (tensor × pipeline) when the cluster has TPU chips, as a
+    chipless actor when it has none (CPU tests). Decided from the
+    cluster, not from a config field, so the same app definition serves
+    from the chip wherever there is one."""
+    import ray_tpu
+
+    ray_tpu.api.auto_init()
+    if ray_tpu.cluster_resources().get("TPU", 0) <= 0:
+        return {}
+    chips = (int(config.tensor_parallel_size or 1)
+             * int(config.pipeline_parallel_size or 1))
+    return {"resources": {"TPU": float(chips)}}
+
+
 def build_openai_app(config: LLMConfig, *, num_replicas: int = 1,
                      name: str | None = None):
     """Serve Application exposing the OpenAI API under /v1 (reference:
     ray.serve.llm build_openai_app). Run with serve.run(app,
     route_prefix=\"/v1\")."""
     dep = deployment(LLMServer, name=name or f"llm:{config.model_id}",
-                     num_replicas=num_replicas)
+                     num_replicas=num_replicas,
+                     ray_actor_options=_replica_actor_options(config))
     return dep.bind(config)
 
 
@@ -825,8 +842,11 @@ def build_disaggregated_app(config: LLMConfig, *, num_prefill: int = 1,
     if config.kv_page_size <= 0:
         config = dataclasses.replace(config, kv_page_size=16)
     base = name or f"llm:{config.model_id}"
+    opts = _replica_actor_options(config)
     pre = deployment(PrefillServer, name=f"{base}-prefill",
-                     num_replicas=num_prefill).bind(config)
+                     num_replicas=num_prefill,
+                     ray_actor_options=opts).bind(config)
     dec = deployment(DecodeServer, name=f"{base}-decode",
-                     num_replicas=num_decode).bind(config)
+                     num_replicas=num_decode,
+                     ray_actor_options=opts).bind(config)
     return deployment(LLMRouter, name=base).bind(config, pre, dec)

@@ -438,6 +438,21 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k,
     return unflat(dq, tq), unflat(dk, tk), unflat(dv, tk)
 
 
+def _interpret() -> bool:
+    """Whether the Pallas kernels run in the interpreter: on the CPU
+    (how the tests run them) and nowhere else. On a TPU they compile
+    through Mosaic; any other platform is refused, because interpreting
+    there would look like a kernel that runs and never finishes."""
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise NotImplementedError(
+        f"the Pallas flash-attention kernels run on 'tpu' (compiled) or "
+        f"'cpu' (interpreted), not on platform {platform!r}")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
                     block_k: int = 256):
@@ -447,28 +462,25 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
     probabilities recomputed per tile from the saved logsumexp): O(T)
     memory end to end, no XLA recompute graph.
     """
-    interpret = jax.devices()[0].platform != "tpu"
     return _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
+        interpret=_interpret(),
     )
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k):
-    interpret = jax.devices()[0].platform != "tpu"
     out, lse = _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, return_lse=True,
+        interpret=_interpret(), return_lse=True,
     )
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, res, g):
     q, k, v, out, lse = res
-    interpret = jax.devices()[0].platform != "tpu"
     return _flash_backward(
         q, k, v, out, lse, g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret,
+        block_k=block_k, interpret=_interpret(),
     )
 
 
@@ -479,16 +491,23 @@ def flash_attention_jax(q, k, v, *, causal: bool = True,
                         block_q: int = 512, block_k: int = 512):
     """jax's bundled Pallas TPU flash kernel (fwd + dq/dkv backwards),
     called through its public API. Shapes here are [B,T,H,D]; the
-    kernel wants [B,H,T,D]. Falls back to blockwise off-TPU (the
-    bundled kernel has no interpret path wired through this API)."""
+    kernel wants [B,H,T,D]. TPU only (the bundled kernel has no
+    interpret path wired through this API), and only for sequence
+    lengths its tiles divide: a caller that asked for this kernel by
+    name gets it or an error, never another implementation."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
     bq = min(block_q, tq)
     bk = min(block_k, tk)
-    if (jax.devices()[0].platform != "tpu" or tq % bq or tk % bk):
-        # Off-TPU (no interpret path wired through this API) or shapes
-        # the kernel can't tile — same guard the 'auto' path applies.
-        return blockwise_attention(q, k, v, causal=causal)
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise NotImplementedError(
+            f"attention impl 'flash_jax' runs on 'tpu' only, not on "
+            f"platform {platform!r}; use impl='auto'")
+    if tq % bq or tk % bk:
+        raise ValueError(
+            f"attention impl 'flash_jax': seq lens ({tq},{tk}) must "
+            f"divide blocks ({bq},{bk}); use impl='auto'")
     from jax.experimental.pallas.ops.tpu import flash_attention as fa
     sizes = fa.BlockSizes(
         block_q=bq, block_k_major=bk, block_k=bk, block_b=1,

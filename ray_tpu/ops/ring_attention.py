@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import _finalize, online_softmax_block, _NEG_INF
-from ray_tpu.parallel.jax_compat import axis_size as _axis_size
-from ray_tpu.parallel.jax_compat import shard_map as _shard_map
 from ray_tpu.parallel.mesh import AXIS_SEQUENCE
 
 
@@ -37,7 +35,7 @@ def ring_attention(q, k, v, *, axis_name: str = AXIS_SEQUENCE,
     Returns [B, T_local, H, D].
     """
     rank = jax.lax.axis_index(axis_name)
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     b, t_local, h, d = q.shape
     ring = [(i, (i + 1) % n) for i in range(n)]
 
@@ -93,7 +91,7 @@ def ring_attention_sharded(q, k, v, mesh, *, axis_name: str = AXIS_SEQUENCE,
     spec = P(batch_spec, axis_name)
 
     fn = partial(ring_attention, axis_name=axis_name, causal=causal)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
     )(q, k, v)

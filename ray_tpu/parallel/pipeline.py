@@ -20,8 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.parallel.jax_compat import axis_size as _axis_size
-from ray_tpu.parallel.jax_compat import shard_map as _shard_map
 from ray_tpu.parallel.mesh import AXIS_PIPELINE
 
 
@@ -45,7 +43,7 @@ def spmd_pipeline(stage_fn, stage_params, microbatches, *, axis_name=AXIS_PIPELI
     the loss live on the last stage).
     """
     stage = jax.lax.axis_index(axis_name)
-    n_stages = _axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     n_micro = microbatches.shape[0]
     total_ticks = n_micro + n_stages - 1
     ring = [(i, (i + 1) % n_stages) for i in range(n_stages)]
@@ -66,10 +64,8 @@ def spmd_pipeline(stage_fn, stage_params, microbatches, *, axis_name=AXIS_PIPELI
         return (state, outputs), None
 
     # The carry varies per pipeline rank; mark it so (shard_map VMA rule).
-    from ray_tpu.parallel.jax_compat import pcast
-
-    state0 = pcast(jnp.zeros_like(microbatches[0]), (axis_name,), to="varying")
-    outputs0 = pcast(jnp.zeros_like(microbatches), (axis_name,), to="varying")
+    state0 = jax.lax.pcast(jnp.zeros_like(microbatches[0]), (axis_name,), to="varying")
+    outputs0 = jax.lax.pcast(jnp.zeros_like(microbatches), (axis_name,), to="varying")
     (_, outputs), _ = jax.lax.scan(
         tick, (state0, outputs0), jnp.arange(total_ticks)
     )
@@ -79,7 +75,7 @@ def spmd_pipeline(stage_fn, stage_params, microbatches, *, axis_name=AXIS_PIPELI
 def pipeline_last_to_all(outputs, *, axis_name=AXIS_PIPELINE):
     """Broadcast last-stage pipeline outputs to every rank (for losses or
     metrics computed off-pipeline). One ring hop per stage."""
-    n_stages = _axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     # all_gather then select the last stage's copy: simple and XLA lowers
     # it to an efficient ring on ICI.
     gathered = jax.lax.all_gather(outputs, axis_name)
@@ -110,7 +106,7 @@ def pipelined_apply(stage_fn, params_per_stage, mesh, batch, *, num_microbatches
     micro = batch.reshape((num_microbatches, -1) + batch.shape[1:])
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(AXIS_PIPELINE), P()),
         out_specs=P(),

@@ -76,18 +76,13 @@ class JaxBackend(Backend):
                 hosts = os.environ.get("TPU_WORKER_HOSTNAMES", "").split(",")
                 if hosts and hosts[0]:
                     coordinator = f"{hosts[0]}:8476"
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=int(os.environ.get("TPU_POD_PROCESS_COUNT", world_size)),
-                    process_id=rank,
-                )
-            except Exception as e:  # noqa: BLE001
-                if config.distributed == "on":
-                    raise
-                import sys
-
-                print(f"[train] jax.distributed auto-init skipped: {e}", file=sys.stderr)
+            # A failure raises in "auto" too: a pod host that trains on
+            # alone is a wrong run, not a degraded one.
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=int(os.environ.get("TPU_POD_PROCESS_COUNT", world_size)),
+                process_id=rank,
+            )
 
     @staticmethod
     def _is_multihost_pod() -> bool:

@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.parallel.jax_compat import shard_map as _shard_map
 import pytest
 
 from ray_tpu.parallel.mesh import MeshConfig
@@ -53,7 +52,7 @@ def test_psum_over_dcn_axis():
 
     @jax.jit
     def total(v):
-        return _shard_map(
+        return jax.shard_map(
             lambda s: jax.lax.psum(jnp.sum(s), (AXIS_DCN, "data")),
             mesh=mesh,
             in_specs=PartitionSpec((AXIS_DCN, "data")),

@@ -107,8 +107,9 @@ def test_tpu_accelerator_manager_env(monkeypatch):
     M.set_current_process_visible_accelerator_ids([0, 1, 2, 3])
     import os
 
+    # The bounds that go with a chip set are chip_process_env's
+    # (tests/test_chip_placement.py).
     assert os.environ["TPU_VISIBLE_CHIPS"] == "0,1,2,3"
-    assert os.environ["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,2,1"
 
 
 def test_process_runtime_env_refcounted():
