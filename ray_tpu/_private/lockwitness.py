@@ -89,18 +89,28 @@ def _note_released(site: str) -> None:
 
 
 def _record_edge(a: str, a_where: str, b: str, b_where: str) -> None:
-    key = (a, b)
-    with _state_lock:
-        if key in _edges:
-            return
-        _edges[key] = {
-            "holder_acquired_at": a_where,
-            "acquiring_at": b_where,
-            "stack": traceback.format_stack(sys._getframe(3), 24),
-        }
-        path = _path(b, a)
-    if path is not None:
-        _note_cycle([a] + path)
+    # What runs under _state_lock allocates, so the collector can run a
+    # __del__ that takes a witnessed lock (ObjectRef's does) and come back
+    # here on the same thread: that edge is left for its next occurrence
+    # rather than waited for on a lock this thread already holds.
+    if getattr(_tls, "recording", False):
+        return
+    _tls.recording = True
+    try:
+        key = (a, b)
+        with _state_lock:
+            if key in _edges:
+                return
+            _edges[key] = {
+                "holder_acquired_at": a_where,
+                "acquiring_at": b_where,
+                "stack": traceback.format_stack(sys._getframe(3), 24),
+            }
+            path = _path(b, a)
+        if path is not None:
+            _note_cycle([a] + path)
+    finally:
+        _tls.recording = False
 
 
 def _path(src: str, dst: str) -> "list[str] | None":

@@ -11,6 +11,7 @@ from __future__ import annotations
 import atexit
 import os
 import threading
+import time
 from typing import Any, Sequence
 
 from ray_tpu._private import profplane, worker_context
@@ -18,6 +19,7 @@ from ray_tpu._private.config import GLOBAL_CONFIG, Config
 from ray_tpu._private.ids import ObjectRef
 from ray_tpu._private.runtime import CoreRuntime
 from ray_tpu._private.worker_context import global_runtime
+from ray_tpu.util import tracing
 
 _init_lock = threading.Lock()
 _namespace = ""
@@ -50,6 +52,7 @@ def init(
             if ignore_reinit_error:
                 return context_info()
             raise RuntimeError("ray_tpu.init() called twice; pass ignore_reinit_error=True")
+        started = time.time()
         _namespace = namespace
         cfg = Config().apply_overrides(_system_config)
         if object_store_memory:
@@ -114,6 +117,11 @@ def init(
                 _teardown_locked()
                 raise
         atexit.register(shutdown)
+        # Recorded now that a runtime exists to take it (a span that
+        # closes before one does is dropped).
+        tracing.record_span("runtime.init", started, time.time(), {
+            "head": "connected" if worker_context.get_head() is None
+            else "started"})
         return context_info()
 
 

@@ -633,20 +633,42 @@ class Datasink:
 
 
 def _jax_batches(batches, sharding, dtypes) -> Iterator[dict]:
-    """numpy batches -> dicts of jax arrays, placed by ``sharding``."""
+    """numpy batches -> dicts of jax arrays, placed by ``sharding``.
+
+    Each batch is one ``data.next_batch`` span (``index`` counts from 0)
+    with two children: ``data.block_wait``, the time inside ``next()`` on
+    the numpy iterator (blocks from the object store, ``map_batches``
+    tasks, rebatching), and ``data.to_device``."""
     import jax
     import jax.numpy as jnp
 
-    for batch in batches:
-        out = {}
-        for k, v in batch.items():
-            arr = jnp.asarray(v) if v.dtype != object else v
-            if dtypes and k in dtypes:
-                arr = arr.astype(dtypes[k])
-            if sharding is not None and isinstance(arr, jax.Array):
-                arr = jax.device_put(arr, sharding)
-            out[k] = arr
+    from ray_tpu._private import compile_cache
+    from ray_tpu.util import tracing
+
+    compile_cache.install_listener()
+    batches = iter(batches)
+    index = 0
+    while True:
+        with tracing.span("data.next_batch", index=index) as attrs:
+            with tracing.span("data.block_wait"):
+                batch = next(batches, None)
+            if batch is None:
+                attrs["rows"] = 0
+                return
+            attrs["rows"] = len(next(iter(batch.values()), ()))
+            nbytes = sum(v.nbytes for v in batch.values()
+                         if v.dtype != object)
+            with tracing.span("data.to_device", bytes=nbytes):
+                out = {}
+                for k, v in batch.items():
+                    arr = jnp.asarray(v) if v.dtype != object else v
+                    if dtypes and k in dtypes:
+                        arr = arr.astype(dtypes[k])
+                    if sharding is not None and isinstance(arr, jax.Array):
+                        arr = jax.device_put(arr, sharding)
+                    out[k] = arr
         yield out
+        index += 1
 
 
 class DataIterator:

@@ -6,6 +6,7 @@ TPU-native framework owns this layer so Train/Serve/bench recipes are
 self-contained. See ray_tpu.models.transformer.
 """
 
+from ray_tpu._private import compile_cache
 from ray_tpu.models.transformer import (
     TransformerConfig,
     cross_entropy_loss,
@@ -30,6 +31,10 @@ from ray_tpu.models.transformer import (
     tiny,
     tiny_moe,
 )
+
+# Whoever imports the models compiles them: count it (llm/engine.py and
+# the trainers come through here).
+compile_cache.install_listener()
 
 __all__ = [
     "TransformerConfig",

@@ -23,16 +23,23 @@ BASELINE.json) need a model library here. This one is TPU-first:
 Two architectures behind one config:
   - ``arch="gpt2"``  — learned positions, LayerNorm, GELU MLP, tied head.
   - ``arch="llama"`` — RoPE, RMSNorm, SwiGLU, GQA, untied head.
+
+Every part runs under a ``jax.named_scope`` from ``SCOPES``, so each
+device instruction of a profiler trace says which part it belongs to
+(its ``op_name``; ``chipbench/scopes.py`` reads it). Scopes are metadata:
+the compiled program is the same with and without them.
 """
 
 from __future__ import annotations
 
+import hashlib
 import math
 from dataclasses import dataclass, replace
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import attention, dot_product_attention
@@ -52,6 +59,27 @@ from ray_tpu.parallel.mesh import (
     AXIS_TENSOR,
 )
 from ray_tpu.parallel.sharding import constrain
+
+# The parts of a train step, as the named scopes spell them: in forward()
+# ``embed``, then ``layers`` (the layer scan or loop; alone on an
+# instruction it is the scan's own traffic) around per block ``attn_norm``,
+# ``attn`` (projections, rope, scores, output projection), ``mlp_norm``,
+# ``mlp`` (``moe`` with experts), then ``final_norm`` and ``head_loss``
+# (head matmul + every cross entropy); in make_train_step ``grad_accum``
+# (the micro-batch scan's sums) and ``optimizer`` (update + apply).
+SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
+          "final_norm", "head_loss", "grad_accum", "optimizer")
+
+# Which tree's scopes an executable carries. jax's compile-cache key leaves
+# metadata out, so a step loaded from the cache would keep the scope names
+# of whatever tree compiled it. ``SCOPES_ID`` names this file's bytes and
+# rides on one instruction of the train step (the step counter's add) as a
+# frontend attribute, which the key does take: a tree whose model file
+# differs compiles its own step and never loads another's, so the names in
+# a trace are always those of the tree that ran
+# (``tests/test_model_scopes.py`` holds jax to it on a real cache).
+with open(__file__, "rb") as _source:
+    SCOPES_ID = "scopes." + hashlib.sha1(_source.read()).hexdigest()[:8]
 
 
 @dataclass(frozen=True)
@@ -409,19 +437,20 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     def con(x, *spec):
         return constrain(x, mesh, *spec) if mesh is not None else x
 
-    x = params["embed"]["tokens"][tokens].astype(dt)
-    if c.arch == "gpt2":
-        if positions is None:
-            pos_emb = params["embed"]["pos"][:T]
+    with jax.named_scope("embed"):
+        x = params["embed"]["tokens"][tokens].astype(dt)
+        if c.arch == "gpt2":
+            if positions is None:
+                pos_emb = params["embed"]["pos"][:T]
+            else:
+                pos_emb = params["embed"]["pos"][positions]
+            x = x + pos_emb.astype(dt)
+            rope = None
         else:
-            pos_emb = params["embed"]["pos"][positions]
-        x = x + pos_emb.astype(dt)
-        rope = None
-    else:
-        cos, sin = rope_frequencies(c.head_dim, c.max_seq_len,
-                                    theta=c.rope_theta)
-        rope = (cos, sin)
-    x = con(x, _BATCH, AXIS_SEQUENCE, None)
+            cos, sin = rope_frequencies(c.head_dim, c.max_seq_len,
+                                        theta=c.rope_theta)
+            rope = (cos, sin)
+        x = con(x, _BATCH, AXIS_SEQUENCE, None)
 
     def layer(x, lp):
         return _block(x, lp, c, rope=rope, con=con, positions=positions)
@@ -435,88 +464,109 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
         else:
             layer = jax.checkpoint(layer)
 
-    if c.scan_layers:
-        x, auxs = jax.lax.scan(lambda h, lp: layer(h, lp), x,
-                               params["layers"])
-        aux = auxs.mean()
-    else:
-        # Unrolled: larger compile, but lets XLA schedule across layer
-        # boundaries (and sidesteps scan-differentiation limits on some
-        # backends when remat is off).
-        aux = jnp.zeros((), jnp.float32)
-        for i in range(c.n_layers):
-            lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-            x, aux_i = layer(x, lp)
-            aux = aux + aux_i / c.n_layers
+    # ``layers`` holds what belongs to no one part of a block: the scan's
+    # reads of the stacked weights and writes of their stacked gradients.
+    with jax.named_scope("layers"):
+        if c.scan_layers:
+            x, auxs = jax.lax.scan(lambda h, lp: layer(h, lp), x,
+                                   params["layers"])
+            aux = auxs.mean()
+        else:
+            # Unrolled: larger compile, but lets XLA schedule across layer
+            # boundaries (and sidesteps scan-differentiation limits on some
+            # backends when remat is off).
+            aux = jnp.zeros((), jnp.float32)
+            for i in range(c.n_layers):
+                lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
+                x, aux_i = layer(x, lp)
+                aux = aux + aux_i / c.n_layers
 
-    if c.arch == "gpt2":
-        x = layer_norm(x, params["final_norm"]["w"], params["final_norm"]["b"])
-    else:
-        x = rms_norm(x, params["final_norm"]["w"])
+    with jax.named_scope("final_norm"):
+        if c.arch == "gpt2":
+            x = layer_norm(x, params["final_norm"]["w"],
+                           params["final_norm"]["b"])
+        else:
+            x = rms_norm(x, params["final_norm"]["w"])
     if return_hidden:
         return (x, aux) if return_aux else x
-    head = (params["embed"]["tokens"].T if c.tied else params["lm_head"])
-    logits = jnp.einsum("btd,dv->btv", x, head.astype(dt),
-                        preferred_element_type=jnp.float32)
-    logits = con(logits, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR)
+    with jax.named_scope("head_loss"):
+        head = (params["embed"]["tokens"].T if c.tied else params["lm_head"])
+        logits = jnp.einsum("btd,dv->btv", x, head.astype(dt),
+                            preferred_element_type=jnp.float32)
+        logits = con(logits, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR)
     return (logits, aux) if return_aux else logits
 
 
 def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
-    """One transformer block (pre-norm residual)."""
+    """One transformer block (pre-norm residual). Its parts carry the
+    scopes ``attn_norm``, ``attn``, ``mlp_norm`` and ``mlp`` / ``moe``
+    (see SCOPES); each part's residual add is inside its scope."""
     dt = c.compute_dtype
-    if c.arch == "gpt2":
-        h = layer_norm(x, lp["ln1"]["w"], lp["ln1"]["b"])
-    else:
-        h = rms_norm(x, lp["ln1"]["w"])
-    if c.kv_heads == c.n_heads:
-        # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
-        # three skinny d→d projections (the weight concat is a few MB,
-        # amortized by XLA across the fused step).
-        wqkv = jnp.concatenate(
-            [lp["attn"]["wq"].astype(dt), lp["attn"]["wk"].astype(dt),
-             lp["attn"]["wv"].astype(dt)],
-            axis=-1,
-        )  # [d, h, 3k]
-        qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-    else:
-        q = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wq"].astype(dt))
-        k = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wk"].astype(dt))
-        v = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wv"].astype(dt))
-    if rope is not None:
-        cos, sin = rope
-        q = apply_rope(q, cos, sin, positions=positions)
-        k = apply_rope(k, cos, sin, positions=positions)
-    k, v = _expand_gqa(k, v, c)
-    q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-    o = attention(q, k, v, causal=True, impl=c.attn_impl,
-                  block_q=c.flash_block_q, block_k=c.flash_block_k)
-    o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
-    x = x + o
+    with jax.named_scope("attn_norm"):
+        if c.arch == "gpt2":
+            h = layer_norm(x, lp["ln1"]["w"], lp["ln1"]["b"])
+        else:
+            h = rms_norm(x, lp["ln1"]["w"])
+    with jax.named_scope("attn"):
+        if c.kv_heads == c.n_heads:
+            # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
+            # three skinny d→d projections (the weight concat is a few MB,
+            # amortized by XLA across the fused step).
+            wqkv = jnp.concatenate(
+                [lp["attn"]["wq"].astype(dt), lp["attn"]["wk"].astype(dt),
+                 lp["attn"]["wv"].astype(dt)],
+                axis=-1,
+            )  # [d, h, 3k]
+            qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        else:
+            q = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wq"].astype(dt))
+            k = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wk"].astype(dt))
+            v = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wv"].astype(dt))
+        if rope is not None:
+            cos, sin = rope
+            q = apply_rope(q, cos, sin, positions=positions)
+            k = apply_rope(k, cos, sin, positions=positions)
+        k, v = _expand_gqa(k, v, c)
+        q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
+        o = attention(q, k, v, causal=True, impl=c.attn_impl,
+                      block_q=c.flash_block_q, block_k=c.flash_block_k)
+        o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
+        x = x + o
 
     aux = jnp.zeros((), jnp.float32)
+    with jax.named_scope("mlp_norm"):
+        if c.arch == "gpt2":
+            h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"])
+        else:
+            h = rms_norm(x, lp["ln2"]["w"])
     if c.arch == "gpt2":
-        h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"])
-        m = gelu_mlp(h, lp["mlp"]["w_in"].astype(dt), lp["mlp"]["b_in"].astype(dt),
-                     lp["mlp"]["w_out"].astype(dt), lp["mlp"]["b_out"].astype(dt))
+        with jax.named_scope("mlp"):
+            m = gelu_mlp(h, lp["mlp"]["w_in"].astype(dt),
+                         lp["mlp"]["b_in"].astype(dt),
+                         lp["mlp"]["w_out"].astype(dt),
+                         lp["mlp"]["b_out"].astype(dt))
+            x = x + m
     elif c.n_experts > 0:
         from ray_tpu.ops.moe import moe_swiglu
 
-        h = rms_norm(x, lp["ln2"]["w"])
-        m, aux = moe_swiglu(
-            h, lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-            lp["mlp"]["w_down"], top_k=c.expert_top_k,
-            capacity_factor=c.expert_capacity_factor,
-            # Group count n can be 1 (< data-axis size), so only the
-            # expert dim is constrained; GSPMD lays out the rest.
-            constrain_fn=lambda t: con(t, None, AXIS_EXPERT, None, None),
-        )
+        with jax.named_scope("moe"):
+            m, aux = moe_swiglu(
+                h, lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
+                lp["mlp"]["w_down"], top_k=c.expert_top_k,
+                capacity_factor=c.expert_capacity_factor,
+                # Group count n can be 1 (< data-axis size), so only the
+                # expert dim is constrained; GSPMD lays out the rest.
+                constrain_fn=lambda t: con(t, None, AXIS_EXPERT, None, None),
+            )
+            x = x + m
     else:
-        h = rms_norm(x, lp["ln2"]["w"])
-        m = swiglu(h, lp["mlp"]["w_gate"].astype(dt), lp["mlp"]["w_up"].astype(dt),
-                   lp["mlp"]["w_down"].astype(dt))
-    return x + m, aux
+        with jax.named_scope("mlp"):
+            m = swiglu(h, lp["mlp"]["w_gate"].astype(dt),
+                       lp["mlp"]["w_up"].astype(dt),
+                       lp["mlp"]["w_down"].astype(dt))
+            x = x + m
+    return x, aux
 
 
 def _expand_gqa(k, v, c: TransformerConfig):
@@ -723,33 +773,36 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
         if mask is not None:
             mask = mask[:, 1:]
     if config.loss_chunk > 0:
-        x, aux = forward(params, inp, config, mesh=mesh, return_aux=True,
-                         return_hidden=True)
-        head = (params["embed"]["tokens"].T if config.tied
-                else params["lm_head"]).astype(config.compute_dtype)
         if config.ce_impl not in ("fused", "checkpoint"):
             raise ValueError(
                 f"ce_impl must be 'fused' or 'checkpoint', got "
                 f"{config.ce_impl!r}")
-        if config.ce_impl == "fused":
-            B, T, D = x.shape
-            mf = (mask.reshape(-1).astype(jnp.float32) if mask is not None
-                  else jnp.ones((B * T,), jnp.float32))
-            loss, acc = fused_chunked_ce_loss(
-                x.reshape(B * T, D), head, tgt.reshape(-1), mf,
-                float(z_loss), int(config.loss_chunk),
-                bool(config.ce_accuracy))
-            metrics = {"loss": loss, "accuracy": acc,
-                       "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
-        else:
-            loss, metrics = chunked_ce_loss(x, head, tgt, mask=mask,
-                                            z_loss=z_loss,
-                                            chunk=config.loss_chunk,
-                                            accuracy=config.ce_accuracy)
+        x, aux = forward(params, inp, config, mesh=mesh, return_aux=True,
+                         return_hidden=True)
+        with jax.named_scope("head_loss"):
+            head = (params["embed"]["tokens"].T if config.tied
+                    else params["lm_head"]).astype(config.compute_dtype)
+            if config.ce_impl == "fused":
+                B, T, D = x.shape
+                mf = (mask.reshape(-1).astype(jnp.float32)
+                      if mask is not None
+                      else jnp.ones((B * T,), jnp.float32))
+                loss, acc = fused_chunked_ce_loss(
+                    x.reshape(B * T, D), head, tgt.reshape(-1), mf,
+                    float(z_loss), int(config.loss_chunk),
+                    bool(config.ce_accuracy))
+                metrics = {"loss": loss, "accuracy": acc,
+                           "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
+            else:
+                loss, metrics = chunked_ce_loss(x, head, tgt, mask=mask,
+                                                z_loss=z_loss,
+                                                chunk=config.loss_chunk,
+                                                accuracy=config.ce_accuracy)
     else:
         logits, aux = forward(params, inp, config, mesh=mesh, return_aux=True)
-        loss, metrics = cross_entropy_loss(logits, tgt, mask=mask,
-                                           z_loss=z_loss)
+        with jax.named_scope("head_loss"):
+            loss, metrics = cross_entropy_loss(logits, tgt, mask=mask,
+                                               z_loss=z_loss)
     if config.n_experts > 0:
         loss = loss + config.router_aux_weight * aux
         metrics = dict(metrics, router_aux=aux, loss=loss)
@@ -823,43 +876,49 @@ def make_train_step(config: TransformerConfig, optimizer, *, mesh=None,
             gsum, msum, wsum = carry
             (loss, metrics), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, mb)
-            w = micro_weight(mb)
-            gsum = jax.tree.map(
-                lambda a, g: a + w * g.astype(jnp.float32), gsum, grads)
-            msum = jax.tree.map(lambda a, m: a + w * m, msum, metrics)
-            return (gsum, msum, wsum + w), None
+            with jax.named_scope("grad_accum"):
+                w = micro_weight(mb)
+                gsum = jax.tree.map(
+                    lambda a, g: a + w * g.astype(jnp.float32), gsum, grads)
+                msum = jax.tree.map(lambda a, m: a + w * m, msum, metrics)
+                return (gsum, msum, wsum + w), None
 
         (gsum, msum, wsum), _ = jax.lax.scan(
             scan_body, (gzero, mzero, jnp.zeros((), jnp.float32)), micro)
-        inv = 1.0 / jnp.maximum(wsum, 1.0)
-        grads = jax.tree.map(lambda g: g * inv, gsum)
-        metrics = jax.tree.map(lambda m: m * inv, msum)
+        with jax.named_scope("grad_accum"):
+            inv = 1.0 / jnp.maximum(wsum, 1.0)
+            grads = jax.tree.map(lambda g: g * inv, gsum)
+            metrics = jax.tree.map(lambda m: m * inv, msum)
         return (metrics["loss"], metrics), grads
 
     def train_step(state, batch):
         (loss, metrics), grads = grads_of(state["params"], batch)
-        if fused:
-            # Single fused pass: clip + AdamW + param update in one
-            # kernel per leaf, grad norm shared with the metric (the
-            # optax path below reads the grads three times for the same
-            # result — ~35 ms/step on GPT-2 124M @ v5e).
-            params, opt_state, gnorm = optimizer.apply(
-                grads, state["opt_state"], state["params"]
-            )
-        else:
-            updates, opt_state = optimizer.update(
-                grads, state["opt_state"], state["params"]
-            )
-            params = jax.tree.map(
-                lambda p, u: (p + u.astype(p.dtype)), state["params"], updates
-            )
-            gnorm = jnp.sqrt(sum(
-                jnp.sum(jnp.square(g.astype(jnp.float32)))
-                for g in jax.tree.leaves(grads)
-            ))
+        with jax.named_scope("optimizer"):
+            if fused:
+                # Single fused pass: clip + AdamW + param update in one
+                # kernel per leaf, grad norm shared with the metric (the
+                # optax path below reads the grads three times for the
+                # same result — ~35 ms/step on GPT-2 124M @ v5e).
+                params, opt_state, gnorm = optimizer.apply(
+                    grads, state["opt_state"], state["params"]
+                )
+            else:
+                updates, opt_state = optimizer.update(
+                    grads, state["opt_state"], state["params"]
+                )
+                params = jax.tree.map(
+                    lambda p, u: (p + u.astype(p.dtype)), state["params"],
+                    updates
+                )
+                gnorm = jnp.sqrt(sum(
+                    jnp.sum(jnp.square(g.astype(jnp.float32)))
+                    for g in jax.tree.leaves(grads)
+                ))
         metrics = dict(metrics, grad_norm=gnorm)
+        with set_xla_metadata(scopes=SCOPES_ID):     # into the cache key
+            count = state["step"] + 1
         return {"params": params, "opt_state": opt_state,
-                "step": state["step"] + 1}, metrics
+                "step": count}, metrics
 
     return train_step
 

@@ -46,6 +46,7 @@ class TrainSession:
 
     def report(self, metrics: dict, checkpoint: Checkpoint | None = None) -> None:
         import ray_tpu
+        from ray_tpu.util import tracing
 
         ckpt_path = None
         if checkpoint is not None:
@@ -56,9 +57,10 @@ class TrainSession:
                 ckpt_path = checkpoint.path
             self.latest_checkpoint = checkpoint
         # Synchronous actor call: gives per-worker ordering + backpressure.
-        ray_tpu.get(
-            self.collector.report.remote(self.rank, self.iteration, metrics, ckpt_path)
-        )
+        with tracing.span("train.report", iteration=self.iteration):
+            ray_tpu.get(
+                self.collector.report.remote(self.rank, self.iteration, metrics, ckpt_path)
+            )
         self.iteration += 1
 
     def get_checkpoint(self) -> Checkpoint | None:

@@ -16,12 +16,16 @@ the idiomatic single-controller SPMD mode.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import logging
+import os
 import time
 import uuid
 from typing import Any, Callable
 
 import ray_tpu
+from ray_tpu._private.worker_context import global_runtime
 from ray_tpu.exceptions import RayTpuError
 from ray_tpu.train.checkpoint import Checkpoint
 from ray_tpu.train.config import (
@@ -32,6 +36,23 @@ from ray_tpu.train.config import (
     ScalingConfig,
 )
 from ray_tpu.train.worker_group import RunStateActor, WorkerGroup
+from ray_tpu.util import state, tracing
+
+logger = logging.getLogger(__name__)
+
+TIMELINE_FILE = "timeline.json"
+
+
+def _write_timeline(path: str) -> None:
+    """The cluster's timeline as it stands, this process's own spans
+    included, written where the run keeps its results. A run's outcome
+    never depends on it."""
+    try:
+        global_runtime().report_rpc_now()
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        state.timeline(path)
+    except Exception:
+        logger.warning("could not write %s", path, exc_info=True)
 
 
 class JaxTrainer:
@@ -97,12 +118,30 @@ class JaxTrainer:
         return fit
 
     def fit(self) -> Result:
+        """Run the job. Whatever its outcome, the run leaves its timeline
+        at ``<Result.path>/timeline.json``: the spans of every process on
+        the head's clock, as ``ray-tpu timeline`` draws them (open it in
+        Perfetto)."""
         ray_tpu.api.auto_init()
         scaling = self.scaling_config
         if scaling.topology == "mesh" and scaling.num_workers != 1:
             raise ValueError("topology='mesh' uses a single controller worker")
         name = self.run_config.name or f"JaxTrainer_{uuid.uuid4().hex[:6]}"
         storage = self.run_config.resolved_storage_path()
+        timeline_path = os.path.join(storage, TIMELINE_FILE)
+        # A file found after a run is that run's.
+        with contextlib.suppress(FileNotFoundError):
+            os.remove(timeline_path)
+        try:
+            with tracing.span("train.fit", run=name,
+                              workers=scaling.num_workers,
+                              chips_per_worker=scaling.worker_resources()
+                              .get("TPU", 0)):
+                return self._fit(scaling, name, storage)
+        finally:
+            _write_timeline(timeline_path)
+
+    def _fit(self, scaling: ScalingConfig, name: str, storage: str) -> Result:
         failure_config = self.run_config.failure_config or FailureConfig()
         ckpt_config = self.run_config.checkpoint_config or CheckpointConfig()
 

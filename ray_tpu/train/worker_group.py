@@ -9,12 +9,15 @@ its PG from ScalingConfig the same way).
 
 from __future__ import annotations
 
+import contextlib
 import inspect
 from typing import Any, Callable
 
 import ray_tpu
+from ray_tpu._private.worker_context import global_runtime
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import CheckpointConfig, ScalingConfig
+from ray_tpu.util import tracing
 from ray_tpu.util.placement_group import PlacementGroup, placement_group, remove_placement_group
 
 
@@ -114,10 +117,11 @@ class TrainWorker:
             # Dispatch on arity, not exception type: a TypeError raised
             # INSIDE setup must propagate, not trigger a silent re-run.
             params = inspect.signature(backend.on_worker_setup).parameters
-            if len(params) >= 4:
-                backend.on_worker_setup(rank, world_size, group_name, backend_config)
-            else:
-                backend.on_worker_setup(rank, world_size, group_name)
+            with tracing.span("train.worker.setup", rank=rank):
+                if len(params) >= 4:
+                    backend.on_worker_setup(rank, world_size, group_name, backend_config)
+                else:
+                    backend.on_worker_setup(rank, world_size, group_name)
 
     def run(
         self,
@@ -144,13 +148,19 @@ class TrainWorker:
         )
         session_mod.set_session(session)
         try:
-            sig = inspect.signature(fn)
-            if len(sig.parameters) == 0:
-                fn()
-            else:
-                fn(config or {})
+            with tracing.span("train.loop", rank=self.rank):
+                sig = inspect.signature(fn)
+                if len(sig.parameters) == 0:
+                    fn()
+                else:
+                    fn(config or {})
         finally:
             session_mod.set_session(None)
+            # fit() kills this process right after the loop returns: put
+            # its buffered spans on their way now (a cast, nothing awaited;
+            # a report that cannot go out is not the loop's failure).
+            with contextlib.suppress(Exception):
+                global_runtime().report_rpc_now()
         return self.rank
 
 

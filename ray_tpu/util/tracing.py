@@ -10,8 +10,13 @@ span buffer and flushed on the next amortized ``rpc_report`` cast — a
 At the head they land in both the task-event buffer (so
 ``ray_tpu.util.state.timeline()`` renders user spans alongside task
 execution spans) and, when a request-trace context is ambient, in the
-trace table as causal children of the enclosing request. OpenTelemetry
-export is attached on top when the package is importable.
+trace table as causal children of the enclosing request.
+
+In a process that has jax loaded, a span is also a
+``jax.profiler.TraceAnnotation``: while a profiler session runs it lies
+on the host's line of the same ``.xplane.pb`` as the device's
+instructions, on one clock. ``span()`` never imports jax itself, so a
+head, agent, proxy or plain worker does not start paying for it.
 """
 
 from __future__ import annotations
@@ -20,6 +25,7 @@ import contextlib
 import functools
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -39,12 +45,54 @@ def _emit(event: dict) -> None:
     traceplane.buffer_span(event)
 
 
+def record_span(name: str, start: float, end: float,
+                attributes: dict | None = None, *,
+                parent: str | None = None, error: str | None = None,
+                trace_link: tuple | None = None) -> None:
+    """Buffer one finished span (``start`` / ``end`` on ``time.time()``'s
+    clock) with this process's identity. ``span()`` ends here; code that
+    learns of an interval only after it is over (the compile listener)
+    calls it directly."""
+    from ray_tpu._private import worker_context
+
+    ctx = worker_context.get_task_context()
+    # Worker/actor identity from the runtime context (a worker
+    # runtime's client id IS its worker id) — without it user spans
+    # emitted from tasks carried "worker_id": None and refused to
+    # group with their task's lifecycle spans in the timeline.
+    rt = worker_context.try_runtime()
+    worker_id = (rt.client_id if rt is not None
+                 and rt.client_type == "worker" else None)
+    ev = {
+        "event": "span",
+        "name": name,
+        "parent": parent,
+        "task_id": getattr(ctx, "task_id", None),
+        "worker_id": worker_id,
+        "actor_id": getattr(ctx, "actor_id", None),
+        "node_id": (getattr(ctx, "node_id", None)
+                    or (rt.node_id if rt is not None else None)),
+        "pid": os.getpid(),
+        "start": start,
+        "end": end,
+        "failed": error is not None,
+        "attributes": {**(attributes or {}),
+                       **({"error": error} if error else {})},
+    }
+    if trace_link is not None:
+        ev["trace_id"], ev["span_id"], ev["parent_span_id"] = trace_link
+    _emit(ev)
+
+
 @contextlib.contextmanager
 def span(name: str, **attributes: Any):
     """Record a named span:
 
         with tracing.span("preprocess", rows=123):
             ...
+
+    The block gets the attributes as a dict and may add what it only
+    learns inside (``with span("load") as attrs: attrs["rows"] = n``).
 
     Nesting is tracked per-thread; child spans carry their parent's name
     in ``parent`` so trace viewers can reconstruct the hierarchy. When a
@@ -64,58 +112,25 @@ def span(name: str, **attributes: Any):
     span_id = traceplane.new_span_id() if tc else None
     tc_token = (worker_context.push_trace_context((tc[0], span_id, tc[2]))
                 if tc else None)
-    # Optional OpenTelemetry bridge.
-    otel_cm = None
+    # The profiler's clock: only where jax is loaded already (with no
+    # profiler session the annotation is jax's own no-op).
+    annotate = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                       None)
+    annotation = (annotate(name, **attributes) if annotate is not None
+                  else contextlib.nullcontext())
     try:
-        from opentelemetry import trace as otel_trace  # type: ignore
-
-        otel_cm = otel_trace.get_tracer("ray_tpu").start_as_current_span(name)
-        otel_cm.__enter__()
-    except Exception:
-        otel_cm = None
-    try:
-        yield
+        with annotation:
+            yield attributes
     except BaseException as e:
         error = repr(e)
         raise
     finally:
-        if otel_cm is not None:
-            try:
-                otel_cm.__exit__(None, None, None)
-            except Exception:
-                pass
         _local.span_name = parent
         if tc_token is not None:
             worker_context.pop_trace_context(tc_token)
-        end = time.time()
-        ctx = worker_context.get_task_context()
-        # Worker/actor identity from the runtime context (a worker
-        # runtime's client id IS its worker id) — without it user spans
-        # emitted from tasks carried "worker_id": None and refused to
-        # group with their task's lifecycle spans in the timeline.
-        rt = worker_context.try_runtime()
-        worker_id = (rt.client_id if rt is not None
-                     and rt.client_type == "worker" else None)
-        ev = {
-            "event": "span",
-            "name": name,
-            "parent": parent,
-            "task_id": getattr(ctx, "task_id", None),
-            "worker_id": worker_id,
-            "actor_id": getattr(ctx, "actor_id", None),
-            "node_id": (getattr(ctx, "node_id", None)
-                        or (rt.node_id if rt is not None else None)),
-            "pid": os.getpid(),
-            "start": start,
-            "end": end,
-            "failed": error is not None,
-            "attributes": {**attributes, **({"error": error} if error else {})},
-        }
-        if tc and int(tc[2] or 0):
-            ev["trace_id"] = tc[0]
-            ev["span_id"] = span_id
-            ev["parent_span_id"] = tc[1]
-        _emit(ev)
+        link = ((tc[0], span_id, tc[1]) if tc and int(tc[2] or 0) else None)
+        record_span(name, start, time.time(), attributes, parent=parent,
+                    error=error, trace_link=link)
 
 
 def trace(fn=None, *, name: str | None = None):

@@ -1,0 +1,150 @@
+"""Every device instruction of a train step says which part of the model
+it belongs to: the compiled ``tiny`` step carries the program's scopes in
+its instructions' ``op_name``, and the rule the benchmark's reader uses
+(``chipbench/scopes.classify``) tells the forward pass, the recompute and
+the backward pass apart. Settled here on CPU programs, not on the chip:
+``op_name`` is made by jax, before any backend."""
+
+from __future__ import annotations
+
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from chipbench import scopes
+from ray_tpu import models
+from ray_tpu.models import transformer
+
+
+def _parts_and_passes(cfg, accum_steps: int = 1) -> set[tuple[str, str]]:
+    opt = optax.adamw(1e-3)
+    state = jax.eval_shape(
+        lambda k: models.init_train_state(k, cfg, opt), jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 33), jnp.int32)}
+    step = jax.jit(models.make_train_step(cfg, opt, accum_steps=accum_steps))
+    text = step.lower(state, batch).compile().as_text()
+    assert text.startswith("HloModule jit_train_step")
+    return {scopes.classify(n)
+            for n in re.findall(r'op_name="([^"]*)"', text)}
+
+
+BLOCK = ("attn_norm", "attn", "mlp_norm", "mlp")
+
+
+@pytest.mark.parametrize("arch", ["gpt2", "llama"])
+@pytest.mark.parametrize("remat", [True, False])
+@pytest.mark.parametrize("scan_layers,ce_impl", [(True, "fused"),
+                                                 (False, "checkpoint")])
+def test_compiled_step_carries_every_scope_and_its_pass(
+        arch, remat, scan_layers, ce_impl):
+    cfg = models.tiny(arch=arch, remat=remat, scan_layers=scan_layers,
+                      loss_chunk=64, ce_impl=ce_impl)
+    found = _parts_and_passes(cfg)
+    for part in ("embed", "layers", *BLOCK, "final_norm", "head_loss"):
+        assert (part, "forward") in found, (part, sorted(found))
+        assert (part, "backward") in found, (part, sorted(found))
+    assert ("optimizer", "forward") in found
+    assert not any(part == "optimizer" and ps != "forward"
+                   for part, ps in found)
+    # Full remat re-runs every part of a block inside the backward pass;
+    # without it nothing of a block is recomputed.
+    for part in BLOCK:
+        assert ((part, "recompute") in found) == remat, (part, sorted(found))
+    # jax.checkpoint around the loss chunk recomputes its logits; the
+    # fused custom_vjp computes its gradients in its forward scan.
+    assert (("head_loss", "recompute") in found) == (ce_impl == "checkpoint")
+    assert {part for part, _ in found} <= set(scopes.PARTS) | {scopes.UNSCOPED}
+
+
+def test_unchunked_loss_accumulation_and_experts_have_their_scopes():
+    found = _parts_and_passes(models.tiny(remat=True), accum_steps=2)
+    assert ("grad_accum", "forward") in found
+    assert ("head_loss", "backward") in found       # plain cross entropy
+    assert ("attn", "recompute") in found           # inside the micro scan
+    moe = _parts_and_passes(models.tiny_moe(n_layers=1, remat=True))
+    assert {("moe", "forward"), ("moe", "recompute"),
+            ("moe", "backward")} <= moe
+    assert not any(part == "mlp" for part, _ in moe)
+
+
+def test_the_reader_knows_exactly_the_programs_scopes():
+    assert set(scopes.PARTS) == set(transformer.SCOPES)
+
+
+_CACHE_SCRIPT = r"""
+import json, jax, jax.numpy as jnp, optax
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from ray_tpu import models
+from ray_tpu.models import transformer
+from ray_tpu.util import tracing
+
+assert transformer.SCOPES_ID.startswith("scopes.")
+seen = []
+tracing._emit = lambda ev: seen.append(ev)
+cfg, opt = models.tiny(n_layers=1), optax.sgd(1e-3)
+state = jax.eval_shape(lambda k: models.init_train_state(k, cfg, opt),
+                       jax.random.PRNGKey(0))
+batch = {"tokens": jax.ShapeDtypeStruct((2, 17), jnp.int32)}
+
+def compile_step():
+    jax.clear_caches()
+    del seen[:]
+    jax.jit(models.make_train_step(cfg, opt)).lower(state, batch).compile()
+    return [e["attributes"]["cache"] for e in seen
+            if e["name"] == "jax.compile"
+            and "train_step" in e["attributes"]["fun"]]
+
+out = [compile_step(), compile_step()]
+transformer.SCOPES_ID = "scopes.00000000"     # the model file was edited
+out += [compile_step(), compile_step()]
+print(json.dumps(out))
+"""
+
+
+def test_a_tree_with_other_scopes_never_loads_this_trees_step(tmp_path):
+    """jax's compile-cache key leaves scope names out; SCOPES_ID is in it
+    (a frontend attribute of one instruction), so a step found in the
+    cache always carries the names of the tree that asks for it. Also the
+    compile listener on a real persistent cache: miss, then hit."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    done = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert json.loads(done.stdout.strip().splitlines()[-1]) == [
+        ["miss"], ["hit"], ["miss"], ["hit"]]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(train_step)/jvp(layers)/while/body/closed_call/attn/dot_general",
+     ("attn", "forward")),
+    ("jit(train_step)/transpose(jvp(layers))/while/body/closed_call/"
+     "checkpoint/rematted_computation/attn/dot_general:",
+     ("attn", "recompute")),
+    ("jit(train_step)/transpose(jvp(layers))/while/body/closed_call/"
+     "checkpoint/mlp/mul", ("mlp", "backward")),
+    ("jit(train_step)/transpose(jvp(layers))/while/body/"
+     "dynamic_update_slice", ("layers", "backward")),
+    ("jit(train_step)/transpose(jvp(head_loss))/mul", ("head_loss",
+                                                       "backward")),
+    ("jit(train_step)/optimizer/jit(_where)/select_n", ("optimizer",
+                                                        "forward")),
+    ("jit(train_step)/transpose(jvp())/reshape;jit(train_step)/"
+     "transpose(jvp(final_norm))/mul", ("final_norm", "backward")),
+    ("jit(convert_element_type)/convert_element_type", ("unscoped",
+                                                        "forward")),
+    ("", ("unscoped", "forward")),
+])
+def test_rule_on_names_as_jax_writes_them(op_name, want):
+    assert scopes.classify(op_name) == want
