@@ -9,12 +9,19 @@ scores matrix never materialized.
 
 Shapes follow [batch, seq, heads, head_dim] throughout.
 
-Three tiers:
+Four tiers:
   - ``dot_product_attention`` — O(T^2)-memory reference; ground truth in
-    tests and the fallback for odd shapes.
+    tests, what serving calls with cached keys, and the fallback for
+    odd shapes.
+  - ``causal_blocked_attention`` — the same exact softmax over
+    materialised scores, computed in query blocks against the key PREFIX
+    each block may see, so the masked upper triangle is mostly never
+    computed; what ``impl="auto"`` takes for causal self-attention at
+    T <= 1024 (the training path of both benchmark cells).
   - ``blockwise_attention`` — online-softmax lax.scan over key blocks:
-    O(T) memory, fully differentiable, XLA-fusable; the default training
-    path (pairs with jax.checkpoint for remat).
+    O(T) memory, fully differentiable, XLA-fusable; what ``auto`` takes
+    above 1024 keys where the kernel does not apply, and the step ring
+    attention is built from.
   - ``flash_attention`` — Pallas TPU forward kernel (interpret-mode on
     CPU); custom_vjp whose backward is the blockwise path, so training
     through it stays O(T) memory.
@@ -57,6 +64,42 @@ def dot_product_attention(q, k, v, *, causal: bool = True, q_offset: int = 0):
         s = jnp.where(_causal_mask(q_pos, k_pos)[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(p.dtype)).astype(q.dtype)
+
+
+def _causal_block_rows(t: int) -> int:
+    """Rows in a query block of ``causal_blocked_attention``, from T
+    alone: a quarter of T in whole 128-row tiles (so every key prefix
+    ends on a lane tile), at least one tile; 0 (no blocking) where T is
+    not a whole number of at least two such blocks. Four blocks (T = 512,
+    1024) compute 62.5% of the T^2 score entries; the sweep of 2 / 4 / 8
+    on the v5e that settled it, in both benchmark cells, is in PERF.md
+    section 6, PR 25."""
+    rows = max(128, t // 4 // 128 * 128)
+    return rows if t > rows and t % rows == 0 else 0
+
+
+def causal_blocked_attention(q, k, v, *, block_q: int):
+    """Exact causal self-attention (Tq == Tk, no offset) that skips the
+    masked blocks: query block i multiplies its ``block_q`` rows against
+    the key / value prefix ``[0 : (i+1)*block_q]`` only, a static slice,
+    and masks only inside that prefix. Every row's softmax runs over
+    exactly the keys ``dot_product_attention`` allows it (the entries
+    dropped had probability exactly 0), with the same dtype policy.
+    (n+1)/2n of the T^2 entries are computed with n blocks; jax
+    differentiates the loop as it stands, the gradients of k and v
+    arriving as a pad-and-add of the n prefix-shaped pieces."""
+    t = q.shape[1]
+    if t != k.shape[1] or t % block_q:
+        raise ValueError(
+            f"causal_blocked_attention: Tq == Tk must divide into blocks "
+            f"of {block_q} rows, got Tq={t}, Tk={k.shape[1]}")
+    outs = []
+    for start in range(0, t, block_q):
+        end = start + block_q
+        outs.append(dot_product_attention(
+            q[:, start:end], k[:, :end], v[:, :end], causal=True,
+            q_offset=start))
+    return jnp.concatenate(outs, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -529,11 +572,15 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
     """Dispatch: 'reference' | 'blockwise' | 'flash' | 'flash_jax' |
     'auto'.
 
-    'auto' uses the Pallas kernel on TPU when shapes tile cleanly, else
-    the blockwise path. ``block_q``/``block_k`` size the flash kernel's
-    VMEM tiles (bigger tiles amortize grid overhead and lengthen the
-    MXU contractions; bounded by VMEM — the f32 score tile alone is
-    block_q*block_k*4 bytes).
+    'auto' at Tk <= 1024 materialises the scores: causal self-attention
+    whose T is a whole number, at least two, of query blocks (a quarter
+    of T, in whole 128-row tiles) takes ``causal_blocked_attention``,
+    anything else the plain reference.
+    Above 1024 it uses the Pallas kernel on TPU when shapes tile
+    cleanly, else the blockwise path. ``block_q``/``block_k`` size the
+    flash kernel's VMEM tiles (bigger tiles amortize grid overhead and
+    lengthen the MXU contractions; bounded by VMEM — the f32 score tile
+    alone is block_q*block_k*4 bytes).
     """
     if impl == "reference":
         return dot_product_attention(q, k, v, causal=causal)
@@ -546,11 +593,16 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
                                    block_q=block_q, block_k=block_k)
     tq, tk = q.shape[1], k.shape[1]
     on_tpu = jax.devices()[0].platform == "tpu"
-    # Short sequences: the O(T^2) scores tensor is small enough that XLA's
-    # fused plain attention beats the kernel (measured on v5e: 52k vs 47k
-    # tok/s on GPT-2 124M @ T=1024); flash wins once the scores tensor
-    # stops fitting in VMEM-sized tiles.
+    # Up to 1024 keys the scores are materialised by XLA, and for causal
+    # self-attention only the blocks at or under the diagonal (PERF.md
+    # section 6, PR 25: the v5e runs of both benchmark cells that settled
+    # this branch). The Pallas kernel's 256-tiles make 3,200 grid steps
+    # here and its backward feeds the MXU float32; until that is repaired
+    # (ROADMAP A1) it starts above 1024.
     if tk <= 1024:
+        rows = _causal_block_rows(tq) if causal and tq == tk else 0
+        if rows:
+            return causal_blocked_attention(q, k, v, block_q=rows)
         return dot_product_attention(q, k, v, causal=causal)
     if on_tpu and tq % block_q == 0 and tk % block_k == 0:
         return flash_attention(q, k, v, causal, block_q, block_k)

@@ -8,6 +8,7 @@ the backward pass apart. Settled here on CPU programs, not on the chip:
 from __future__ import annotations
 
 import re
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -19,16 +20,21 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 
 
-def _parts_and_passes(cfg, accum_steps: int = 1) -> set[tuple[str, str]]:
+def _step_text(cfg, *, rows: int = 4, seq_len: int = 32,
+               accum_steps: int = 1) -> str:
     opt = optax.adamw(1e-3)
     state = jax.eval_shape(
         lambda k: models.init_train_state(k, cfg, opt), jax.random.PRNGKey(0))
-    batch = {"tokens": jax.ShapeDtypeStruct((4, 33), jnp.int32)}
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, seq_len + 1), jnp.int32)}
     step = jax.jit(models.make_train_step(cfg, opt, accum_steps=accum_steps))
     text = step.lower(state, batch).compile().as_text()
     assert text.startswith("HloModule jit_train_step")
-    return {scopes.classify(n)
-            for n in re.findall(r'op_name="([^"]*)"', text)}
+    return text
+
+
+def _parts_and_passes(cfg, accum_steps: int = 1) -> set[tuple[str, str]]:
+    return {scopes.classify(n) for n in re.findall(
+        r'op_name="([^"]*)"', _step_text(cfg, accum_steps=accum_steps))}
 
 
 BLOCK = ("attn_norm", "attn", "mlp_norm", "mlp")
@@ -68,6 +74,28 @@ def test_unchunked_loss_accumulation_and_experts_have_their_scopes():
     assert {("moe", "forward"), ("moe", "recompute"),
             ("moe", "backward")} <= moe
     assert not any(part == "mlp" for part, _ in moe)
+
+
+def test_causal_blocks_leave_no_whole_score_tensor_and_stay_in_attn():
+    """At T = 512 ``attention(impl="auto")`` computes four query blocks
+    of 128 rows against key prefixes of 128 to 512: no instruction of the
+    compiled step has the whole ``[B,H,512,512]`` score shape any more
+    (``impl="reference"`` shows the pattern finds one), and the
+    instructions that produce a block carry ``attn`` in the forward pass,
+    the recompute and the backward pass, so ``step_attn_ms`` still reads
+    them."""
+    whole = re.compile(r"\[\d+,\d+,512,512\]")
+    block = re.compile(
+        r" = \w+\[\d+,\d+,128,(?:128|256|384|512)\].*op_name=\"([^\"]*)\"")
+    cfg = models.tiny(max_seq_len=512, remat=True)
+    assert whole.search(_step_text(replace(cfg, attn_impl="reference"),
+                                   rows=2, seq_len=512))
+    text = _step_text(cfg, rows=2, seq_len=512)
+    assert not whole.search(text)
+    found = {scopes.classify(m.group(1))
+             for m in map(block.search, text.splitlines()) if m}
+    assert found == {("attn", "forward"), ("attn", "recompute"),
+                     ("attn", "backward")}, sorted(found)
 
 
 def test_the_reader_knows_exactly_the_programs_scopes():

@@ -1,12 +1,15 @@
 """Correctness of ops/ kernels and parallel/ strategies on the virtual
 8-device CPU mesh (test strategy per SURVEY.md §4 "lesson")."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from ray_tpu.ops import (
+    attention,
     blockwise_attention,
     dot_product_attention,
     flash_attention,
@@ -24,6 +27,9 @@ from ray_tpu.parallel import (
     shard_params,
 )
 from jax.sharding import PartitionSpec as P
+
+# ``ray_tpu.ops.attention`` the attribute is the dispatch function.
+attention_mod = importlib.import_module("ray_tpu.ops.attention")
 
 
 def _qkv(b=2, t=128, h=4, d=32, seed=0):
@@ -89,6 +95,96 @@ def test_flash_backward_kernels_multiblock(causal):
     g_fl = jax.grad(fla, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ref, g_fl):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+
+
+def _gqa_case(t, d, dtype, *, h=4, kv_heads=2, seed=0):
+    """q, narrow k / v and a cotangent, as the llama block has them
+    before ``_expand_gqa`` repeats k and v over the query heads."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = [(1, t, h, d), (1, t, kv_heads, d), (1, t, kv_heads, d),
+              (1, t, h, d)]
+    return [jax.random.normal(k, s, jnp.float32).astype(dtype)
+            for k, s in zip(ks, shapes)]
+
+
+def _out_and_grads(fn, q, k, v, w):
+    def loss(q, k, v):
+        rep = q.shape[2] // k.shape[2]
+        o = fn(q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2))
+        return (o.astype(jnp.float32) * w.astype(jnp.float32)).sum(), o
+
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (o, *grads)
+
+
+# Largest |difference| allowed, as a share of the reference's largest
+# entry: float32 differs by the order of a row's sums only; bfloat16 by
+# that and by dk / dv arriving as a bfloat16 sum of prefix-shaped pieces
+# (two units in the last place).
+_BLOCKED_TOL = {jnp.float32: 1e-5, jnp.bfloat16: 2.0 ** -6}
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("t", [256, 512, 1024])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_auto_blocked_causal_matches_reference(dtype, t, d):
+    q, k, v, w = _gqa_case(t, d, dtype)
+    want = _out_and_grads(dot_product_attention, q, k, v, w)
+    got = _out_and_grads(
+        lambda q, k, v: attention(q, k, v, causal=True, impl="auto"),
+        q, k, v, w)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= _BLOCKED_TOL[dtype] * np.abs(b).max(), \
+            name
+
+
+@pytest.mark.parametrize("block_q", [32, 64, 128])
+def test_blocked_causal_any_block_count(block_q):
+    q, k, v, w = _gqa_case(256, 32, jnp.float32, seed=1)
+    want = _out_and_grads(dot_product_attention, q, k, v, w)
+    got = _out_and_grads(
+        lambda q, k, v: attention_mod.causal_blocked_attention(
+            q, k, v, block_q=block_q), q, k, v, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=3e-5, rtol=1e-5)
+    with pytest.raises(ValueError, match="divide into blocks"):
+        attention_mod.causal_blocked_attention(q, k, v, block_q=96)
+
+
+@pytest.mark.parametrize("impl,causal,tq,tk,want", [
+    ("auto", True, 256, 256, ("blocked", 128)),
+    ("auto", True, 512, 512, ("blocked", 128)),
+    ("auto", True, 768, 768, ("blocked", 128)),
+    ("auto", True, 1024, 1024, ("blocked", 256)),
+    ("auto", False, 512, 512, ("plain", None)),      # nothing is masked
+    ("auto", True, 128, 512, ("plain", None)),       # Tq != Tk
+    ("auto", True, 128, 128, ("plain", None)),       # one block: the presets
+    ("auto", True, 300, 300, ("plain", None)),       # not whole 128-row tiles
+    ("auto", True, 2048, 2048, ("blockwise", None)),  # above 1024: as before
+    ("reference", True, 512, 512, ("plain", None)),
+])
+def test_which_path_attention_takes(monkeypatch, impl, causal, tq, tk, want):
+    """The ``auto`` rule reads shapes alone; a caller sets nothing."""
+    taken = []
+
+    def recorder(name):
+        def fn(q, k, v, *a, block_q=None, **kw):
+            taken.append((name, block_q))
+            return q
+        return fn
+
+    for name, attr in [("blocked", "causal_blocked_attention"),
+                       ("plain", "dot_product_attention"),
+                       ("blockwise", "blockwise_attention"),
+                       ("flash", "flash_attention")]:
+        monkeypatch.setattr(attention_mod, attr, recorder(name))
+    q = jnp.zeros((1, tq, 2, 8))
+    kv = jnp.zeros((1, tk, 2, 8))
+    attention(q, kv, kv, causal=causal, impl=impl)
+    assert taken == [want]
 
 
 @pytest.mark.parametrize("causal", [True, False])
