@@ -26,6 +26,7 @@ from ray_tpu.models.transformer import (
     mistral_7b,
     mixtral_8x7b,
     moe_small,
+    olmoe_1b_7b,
     partition_specs,
     qwen2_7b,
     tiny,
@@ -56,6 +57,7 @@ __all__ = [
     "make_train_step",
     "mistral_7b",
     "mixtral_8x7b",
+    "olmoe_1b_7b",
     "partition_specs",
     "qwen2_7b",
     "tiny",

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.ops import moe
 from ray_tpu.ops.attention import attention, dot_product_attention
 from ray_tpu.ops.layers import (
     apply_rope,
@@ -64,22 +65,35 @@ from ray_tpu.parallel.sharding import constrain
 # ``embed``, then ``layers`` (the layer scan or loop; alone on an
 # instruction it is the scan's own traffic) around per block ``attn_norm``,
 # ``attn`` (projections, rope, scores, output projection), ``mlp_norm``,
-# ``mlp`` (``moe`` with experts), then ``final_norm`` and ``head_loss``
-# (head matmul + every cross entropy); in make_train_step ``grad_accum``
-# (the micro-batch scan's sums) and ``optimizer`` (update + apply).
+# ``mlp`` (``moe`` with experts; the dropless dispatch opens its own
+# sub-scopes inside it, ``ops.moe.SCOPES``), then ``final_norm`` and
+# ``head_loss`` (head matmul + every cross entropy); in make_train_step
+# ``grad_accum`` (the micro-batch scan's sums) and ``optimizer`` (update +
+# apply).
 SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
           "final_norm", "head_loss", "grad_accum", "optimizer")
 
 # Which tree's scopes an executable carries. jax's compile-cache key leaves
 # metadata out, so a step loaded from the cache would keep the scope names
-# of whatever tree compiled it. ``SCOPES_ID`` names this file's bytes and
+# of whatever tree compiled it. ``SCOPES_ID`` names the bytes of every
+# file that opens a scope of the step (this one and ``ops/moe.py``) and
 # rides on one instruction of the train step (the step counter's add) as a
-# frontend attribute, which the key does take: a tree whose model file
+# frontend attribute, which the key does take: a tree in which one of them
 # differs compiles its own step and never loads another's, so the names in
 # a trace are always those of the tree that ran
 # (``tests/test_model_scopes.py`` holds jax to it on a real cache).
-with open(__file__, "rb") as _source:
-    SCOPES_ID = "scopes." + hashlib.sha1(_source.read()).hexdigest()[:8]
+SCOPE_FILES = (__file__, moe.__file__)
+
+
+def _scopes_id(files=SCOPE_FILES) -> str:
+    digest = hashlib.sha1()
+    for path in files:
+        with open(path, "rb") as source:
+            digest.update(source.read())
+    return "scopes." + digest.hexdigest()[:8]
+
+
+SCOPES_ID = _scopes_id()
 
 
 @dataclass(frozen=True)
@@ -136,8 +150,18 @@ class TransformerConfig:
     # the reference (SURVEY.md §2.4: EP absent upstream) — see ops/moe.py.
     n_experts: int = 0
     expert_top_k: int = 2
-    expert_capacity_factor: float = 1.25
-    router_aux_weight: float = 0.01
+    # Slots an expert and token group, over the even share; what overflows
+    # is dropped. None is DROPLESS: every assignment is computed (sorted
+    # dispatch + grouped matmul, one chip's experts only).
+    expert_capacity_factor: float | None = 1.25
+    # Renormalise the top-k gates to sum to 1 (Mixtral) or use the
+    # softmax's values as they are (OLMoE, ``norm_topk_prob: false``).
+    expert_norm_topk: bool = True
+    router_aux_weight: float = 0.01  # x the load-balance term
+    router_z_weight: float = 0.0     # x mean logsumexp(router logits)^2
+    # RMSNorm with a learned weight over the WHOLE q and k projections
+    # (all heads together), before the split into heads and RoPE (OLMoE).
+    qk_norm: bool = False
 
     @property
     def kv_heads(self) -> int:
@@ -246,6 +270,22 @@ def mixtral_8x7b(**kw) -> TransformerConfig:
     return mistral_7b(n_experts=8, expert_top_k=2, **kw)
 
 
+def olmoe_1b_7b(**kw) -> TransformerConfig:
+    """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct ``config.json``): 64
+    experts of width 1024, 8 a token, dropless, gates not renormalised,
+    QK-norm; balance and z weights from its recipe (arXiv:2409.02060)."""
+    return replace(
+        TransformerConfig(
+            vocab_size=50304, n_layers=16, d_model=2048, n_heads=16,
+            n_kv_heads=16, d_ff=1024, max_seq_len=4096, arch="llama",
+            n_experts=64, expert_top_k=8, expert_capacity_factor=None,
+            expert_norm_topk=False, router_aux_weight=0.01,
+            router_z_weight=0.001, qk_norm=True,
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -289,6 +329,8 @@ def init_params(rng, config: TransformerConfig):
     c = config
     if c.n_experts > 0 and c.arch != "llama":
         raise ValueError("MoE (n_experts > 0) requires arch='llama'")
+    if c.qk_norm and c.arch != "llama":
+        raise ValueError("qk_norm requires arch='llama'")
     pdt = jnp.dtype(c.param_dtype)
     L, D, H, KV, Dh, F = (
         c.n_layers, c.d_model, c.n_heads, c.kv_heads, c.head_dim, c.ffn_dim,
@@ -330,6 +372,9 @@ def init_params(rng, config: TransformerConfig):
     else:
         params["layers"]["ln1"] = {"w": jnp.ones((L, D), pdt)}
         params["layers"]["ln2"] = {"w": jnp.ones((L, D), pdt)}
+        if c.qk_norm:
+            params["layers"]["attn"]["q_norm"] = jnp.ones((L, H * Dh), pdt)
+            params["layers"]["attn"]["k_norm"] = jnp.ones((L, KV * Dh), pdt)
         if c.n_experts > 0:
             E = c.n_experts
             params["layers"]["router"] = {"w": norm(next(keys), L, D, E)}
@@ -425,14 +470,22 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
 
     ``mesh`` adds with_sharding_constraint annotations on activations
     (batch over data+fsdp, heads/ffn over tensor); pass None outside pjit.
-    ``return_aux`` additionally returns the mean per-layer router
-    load-balance loss (MoE models; 0 for dense). ``return_hidden`` skips
-    the LM head and returns the final normed hidden states [B, T, D]
-    (the chunked-loss path applies the head itself).
+    ``return_aux`` additionally returns the router's statistics over the
+    layers, ``{"balance", "z", "load_max"}`` (``ops/moe.py``; the two loss
+    terms as means, the fullest layer's ``load_max``; zeros for a dense
+    model). ``return_hidden`` skips the LM head and returns the final
+    normed hidden states [B, T, D] (the chunked-loss path applies the head
+    itself).
     """
     c = config
     dt = c.compute_dtype
     B, T = tokens.shape
+    if (c.n_experts > 0 and c.expert_capacity_factor is None
+            and mesh is not None and mesh.shape.get(AXIS_EXPERT, 1) > 1):
+        raise NotImplementedError(
+            "dropless MoE (expert_capacity_factor=None) keeps every expert "
+            "on the chip: it has no all-to-all over the mesh's 'expert' "
+            "axis yet (ROADMAP B3); give expert_capacity_factor a number")
 
     def con(x, *spec):
         return constrain(x, mesh, *spec) if mesh is not None else x
@@ -470,16 +523,18 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
         if c.scan_layers:
             x, auxs = jax.lax.scan(lambda h, lp: layer(h, lp), x,
                                    params["layers"])
-            aux = auxs.mean()
         else:
             # Unrolled: larger compile, but lets XLA schedule across layer
             # boundaries (and sidesteps scan-differentiation limits on some
             # backends when remat is off).
-            aux = jnp.zeros((), jnp.float32)
+            per_layer = []
             for i in range(c.n_layers):
                 lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
                 x, aux_i = layer(x, lp)
-                aux = aux + aux_i / c.n_layers
+                per_layer.append(aux_i)
+            auxs = jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+        aux = {"balance": auxs["balance"].mean(), "z": auxs["z"].mean(),
+               "load_max": auxs["load_max"].max()}
 
     with jax.named_scope("final_norm"):
         if c.arch == "gpt2":
@@ -523,6 +578,9 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
             q = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wq"].astype(dt))
             k = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wk"].astype(dt))
             v = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wv"].astype(dt))
+        if c.qk_norm:
+            q = _qk_norm(q, lp["attn"]["q_norm"])
+            k = _qk_norm(k, lp["attn"]["k_norm"])
         if rope is not None:
             cos, sin = rope
             q = apply_rope(q, cos, sin, positions=positions)
@@ -534,7 +592,8 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
         o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
         x = x + o
 
-    aux = jnp.zeros((), jnp.float32)
+    aux = {name: jnp.zeros((), jnp.float32)
+           for name in ("balance", "z", "load_max")}
     with jax.named_scope("mlp_norm"):
         if c.arch == "gpt2":
             h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"])
@@ -548,17 +607,24 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
                          lp["mlp"]["b_out"].astype(dt))
             x = x + m
     elif c.n_experts > 0:
-        from ray_tpu.ops.moe import moe_swiglu
-
+        weights = (lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
+                   lp["mlp"]["w_down"])
         with jax.named_scope("moe"):
-            m, aux = moe_swiglu(
-                h, lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-                lp["mlp"]["w_down"], top_k=c.expert_top_k,
-                capacity_factor=c.expert_capacity_factor,
-                # Group count n can be 1 (< data-axis size), so only the
-                # expert dim is constrained; GSPMD lays out the rest.
-                constrain_fn=lambda t: con(t, None, AXIS_EXPERT, None, None),
-            )
+            if c.expert_capacity_factor is None:
+                m, aux = moe.moe_swiglu_dropless(
+                    h, *weights, top_k=c.expert_top_k,
+                    norm_topk=c.expert_norm_topk)
+            else:
+                m, aux = moe.moe_swiglu(
+                    h, *weights, top_k=c.expert_top_k,
+                    capacity_factor=c.expert_capacity_factor,
+                    norm_topk=c.expert_norm_topk,
+                    # Group count n can be 1 (< data-axis size), so only
+                    # the expert dim is constrained; GSPMD lays out the
+                    # rest.
+                    constrain_fn=lambda t: con(t, None, AXIS_EXPERT, None,
+                                               None),
+                )
             x = x + m
     else:
         with jax.named_scope("mlp"):
@@ -567,6 +633,12 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
                        lp["mlp"]["w_down"].astype(dt))
             x = x + m
     return x, aux
+
+
+def _qk_norm(x, weight):
+    """RMSNorm of a q or k projection [B, T, H, Dh] over ALL its heads
+    together (H * Dh values a token), as OLMoE norms them."""
+    return rms_norm(x.reshape(*x.shape[:2], -1), weight).reshape(x.shape)
 
 
 def _expand_gqa(k, v, c: TransformerConfig):
@@ -804,8 +876,10 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
             loss, metrics = cross_entropy_loss(logits, tgt, mask=mask,
                                                z_loss=z_loss)
     if config.n_experts > 0:
-        loss = loss + config.router_aux_weight * aux
-        metrics = dict(metrics, router_aux=aux, loss=loss)
+        loss = (loss + config.router_aux_weight * aux["balance"]
+                + config.router_z_weight * aux["z"])
+        metrics = dict(metrics, router_aux=aux["balance"], router_z=aux["z"],
+                       moe_load_max=aux["load_max"], loss=loss)
     return loss, metrics
 
 
@@ -826,9 +900,10 @@ def make_train_step(config: TransformerConfig, optimizer, *, mesh=None,
     unaccumulated step's per-token weighting), and applies the optimizer
     ONCE — the activation-memory footprint of a 1/accum batch at the
     effective batch size of the whole one. Every metric lm_loss reports
-    (incl. router_aux for MoE) is the same weighted average; perplexity
-    is the weighted mean of per-microbatch perplexities (exp is convex,
-    so it can sit slightly above the unaccumulated exp-of-mean value).
+    (incl. router_aux, router_z and moe_load_max for MoE) is the same
+    weighted average; perplexity is the weighted mean of per-microbatch
+    perplexities (exp is convex, so it can sit slightly above the
+    unaccumulated exp-of-mean value).
     """
 
     def loss_fn(params, batch):

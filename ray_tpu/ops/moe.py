@@ -1,26 +1,62 @@
-"""Mixture-of-Experts ops: top-k routing with static-shape dispatch.
+"""Mixture-of-Experts ops: top-k routing with static shapes, two dispatches.
 
 The reference has no expert parallelism anywhere (SURVEY.md §2.4: EP —
 "Absent"; vLLM handles MoE internally for inference only), so this is
-greenfield, built the TPU way (GShard/Switch-style): routing is expressed
-as dense one-hot dispatch/combine einsums over a fixed per-expert
-capacity — every shape static, every op an MXU matmul or a cheap
-elementwise, zero dynamic gathers. Under a mesh, the expert dimension of
-the dispatched activations is sharded over the ``expert`` axis
-(parallel.mesh.AXIS_EXPERT) and GSPMD lowers the dispatch/combine
-einsums into ``all_to_all`` collectives over ICI.
+greenfield. One router (float32 softmax over the experts, top-k, the
+gates renormalised or not) feeds one of two dispatches, chosen by the
+model's ``expert_capacity_factor``:
 
-Aux (load-balance) loss follows Switch Transformer: E * Σ_e f_e · p_e,
-where f_e is the fraction of tokens routed to expert e and p_e the mean
-router probability — minimized when routing is uniform.
+* **dropless** (``None``; ``moe_swiglu_dropless``): the ``top_k x tokens``
+  (token, choice) assignments are SORTED by expert, the tokens' rows
+  gathered into that order, and each expert multiplies its own ragged
+  run of rows (a grouped matmul: the bundled megablox Pallas kernel on a
+  TPU, ``jax.lax.ragged_dot`` anywhere else). The results are gathered
+  back into token order and summed under their gates. Every assignment
+  is computed, whatever the imbalance; every shape is static (the rows
+  are ``top_k x tokens`` in all, only the group boundaries are data);
+  the arithmetic is the experts' own and nothing more. What OLMoE (64
+  experts, top-8) trains with. One chip's experts only: there is no
+  all-to-all here yet (ROADMAP B3).
+* **capacity** (a number; ``moe_swiglu``, GShard / Switch style): dense
+  one-hot dispatch / combine einsums over ``capacity`` slots an expert
+  and group; what overflows is DROPPED (it passes through the residual).
+  Under a mesh the expert dimension of the dispatched activations is
+  sharded over the ``expert`` axis (parallel.mesh.AXIS_EXPERT) and GSPMD
+  lowers the einsums into ``all_to_all`` collectives over ICI. Its
+  dispatch arithmetic grows with ``experts x capacity`` a token (at 64
+  experts nearly as much again as the experts' own matmuls, ROADMAP A8),
+  and its loop over choices is unrolled: it is for few experts and small
+  top-k (``tiny_moe``, ``moe_small``, ``mixtral_8x7b``).
+
+Router losses. Balance (Switch Transformer): ``E * sum_e f_e * P_e``,
+``P_e`` the mean router probability of expert e. The capacity path takes
+``f_e`` as the share of a GROUP's tokens that hold a slot at e (sums to
+top_k when nothing is dropped), mean over groups; the dropless path as
+the share of the whole batch's assignments that went to e (sums to 1; 1.0
+at uniform routing). z (ST-MoE): the mean over tokens of
+``logsumexp(router logits)^2``. ``load_max`` is a counter, not a loss:
+the fullest expert's assignments over the mean (1.0 is balance).
+
+The dropless path's parts run under the named scopes of ``SCOPES``
+(inside the model's ``moe``), so a profile says what routing costs
+beyond the experts' arithmetic.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+# Sub-scopes of the model's ``moe`` scope (models/transformer.py), opened
+# by ``moe_swiglu_dropless``: router matmul, softmax, top-k and the loss
+# terms; the sort and the gather into expert order; the three grouped
+# matmuls and the activation; the gather back, the gates and the sum.
+SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 
 
 def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
@@ -30,7 +66,37 @@ def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
     return max(8, ((c + 7) // 8) * 8)
 
 
-def topk_dispatch(router_logits, top_k: int, capacity: int):
+def route(router_logits, top_k: int, norm_topk: bool = True):
+    """Router logits [G, E] -> (probs [G, E] float32, gates [G, k], chosen
+    experts [G, k]). ``norm_topk`` renormalises the selected gates so a
+    token's combine weights sum to 1 (Mixtral); OLMoE uses them as they
+    are."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    topv, topi = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    return probs, topv, topi
+
+
+def router_z(router_logits):
+    """Mean over tokens of logsumexp(logits)^2, in float32."""
+    lse = jax.scipy.special.logsumexp(
+        router_logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(jnp.square(lse))
+
+
+def _assignment_counts(topi, num_experts: int):
+    """Assignments an expert, over every leading dimension: int32 [E]."""
+    return jax.nn.one_hot(topi.reshape(-1), num_experts,
+                          dtype=jnp.int32).sum(0)
+
+
+def _load_max(counts):
+    return counts.max() / jnp.maximum(counts.mean(dtype=jnp.float32), 1e-9)
+
+
+def topk_dispatch(router_logits, top_k: int, capacity: int,
+                  norm_topk: bool = True):
     """Build dispatch/combine tensors from router logits [G, E].
 
     Returns (dispatch [G, E, C] float, combine [G, E, C] float, aux_loss
@@ -41,15 +107,12 @@ def topk_dispatch(router_logits, top_k: int, capacity: int):
     through the residual unchanged, the standard Switch behavior).
     """
     G, E = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, top_k)  # [G, k]
-    # Renormalize the selected gates so combine weights sum to 1 per token.
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    probs, topv, topi = route(router_logits, top_k, norm_topk)
 
     counts = jnp.zeros((E,), jnp.int32)
     dispatch = jnp.zeros((G, E, capacity), jnp.float32)
     combine = jnp.zeros((G, E, capacity), jnp.float32)
-    for j in range(top_k):  # unrolled: top_k is tiny (1 or 2 typically)
+    for j in range(top_k):  # unrolled: this path is for small top_k
         oh = jax.nn.one_hot(topi[:, j], E, dtype=jnp.int32)  # [G, E]
         pos = jnp.cumsum(oh, axis=0) - 1 + counts[None, :]  # slot per token
         counts = counts + oh.sum(axis=0)
@@ -80,11 +143,12 @@ def _group_size(total: int, target: int) -> int:
 
 def moe_swiglu(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                capacity_factor: float = 1.25, group_size: int = 1024,
-               constrain_fn=None):
-    """MoE SwiGLU FFN for one layer.
+               norm_topk: bool = True, constrain_fn=None):
+    """MoE SwiGLU FFN for one layer, capacity dispatch (tokens over an
+    expert's capacity are dropped).
 
     x [B, S, D]; router_w [D, E]; w_gate/w_up [E, D, F]; w_down [E, F, D].
-    Returns (out [B, S, D], aux_loss scalar).
+    Returns (out [B, S, D], {"balance", "z", "load_max"} scalars).
 
     Tokens are processed in GROUPS of ~``group_size`` (GShard-style):
     dispatch/combine are [n, g, E, C_g] with C_g ∝ g, so memory and
@@ -105,7 +169,7 @@ def moe_swiglu(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                         router_w.astype(jnp.float32))
     C = expert_capacity(g, E, top_k, capacity_factor)
     dispatch, combine, aux = jax.vmap(
-        lambda lg: topk_dispatch(lg, top_k, C)
+        lambda lg: topk_dispatch(lg, top_k, C, norm_topk)
     )(logits)  # [n, g, E, C] ×2, aux [n]
     ein = xg.astype(jnp.float32)
     expert_in = jnp.einsum("ngec,ngd->necd", dispatch, ein).astype(dt)
@@ -119,4 +183,163 @@ def moe_swiglu(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         expert_out = constrain_fn(expert_out)
     out = jnp.einsum("ngec,necd->ngd", combine,
                      expert_out.astype(jnp.float32)).astype(dt)
-    return out.reshape(B, S, D), aux.mean()
+    # what was ASKED of each expert, before any drop
+    counts = _assignment_counts(jax.lax.top_k(logits, top_k)[1], E)
+    stats = {"balance": aux.mean(), "z": router_z(logits),
+             "load_max": _load_max(counts)}
+    return out.reshape(B, S, D), stats
+
+
+# -- dropless ----------------------------------------------------------------
+
+# megablox tiles (rows, contraction, columns) of the three kernels a
+# grouped matmul's forward and backward are made of, by the contraction's
+# width; settled by a sweep on the v5e at OLMoE's shapes, 65,536 rows x 64
+# groups, 2048 <-> 1024 (PERF.md section 6, PR 27). Larger tiles do not
+# fit the kernels' 16 MB of VMEM.
+_GMM_ROWS = 512                      # every kernel's row tile divides this
+
+
+def _gmm_tiles(k: int) -> tuple[int, int, int]:
+    """Tiles of ``gmm`` (forward, and the gradient of the rows) for a
+    contraction ``k`` wide."""
+    return (512 if k > 1024 else 256, 1024, 1024)
+
+
+_TGMM_TILES = (256, 1024, 1024)      # the gradient of the weights
+
+
+def _use_megablox(lhs, rhs) -> bool:
+    """The Pallas grouped-matmul kernel applies on a TPU, to bfloat16
+    rows that divide into its row tile and widths in whole lane tiles."""
+    (m, k), n = lhs.shape, rhs.shape[-1]
+    return (jax.devices()[0].platform == "tpu"
+            and lhs.dtype == rhs.dtype == jnp.bfloat16
+            and m % _GMM_ROWS == 0 and k % 128 == 0 and n % 128 == 0)
+
+
+def _fit(tiles, k: int, n: int):
+    return (tiles[0], min(tiles[1], k), min(tiles[2], n))
+
+
+def _megablox():
+    # the package's ``gmm`` attribute is its custom_vjp wrapper, which
+    # shadows the module of the kernels themselves
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+@jax.custom_vjp
+def _megablox_matmul(lhs, rhs, group_sizes):
+    k, n = rhs.shape[1], rhs.shape[2]
+    return _megablox().gmm(lhs, rhs, group_sizes, lhs.dtype,
+                           _fit(_gmm_tiles(k), k, n))
+
+
+def _megablox_fwd(lhs, rhs, group_sizes):
+    return _megablox_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+
+def _megablox_bwd(res, g):
+    backend = _megablox()
+    lhs, rhs, group_sizes = res
+    k, n = rhs.shape[1], rhs.shape[2]
+    dlhs = backend.gmm(g, rhs, group_sizes, lhs.dtype,
+                       _fit(_gmm_tiles(n), n, k), transpose_rhs=True)
+    drhs = backend.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+                        _fit(_TGMM_TILES, k, n),
+                        num_actual_groups=rhs.shape[0])
+    return dlhs, drhs, np.zeros(group_sizes.shape, jax.dtypes.float0)
+
+
+_megablox_matmul.defvjp(_megablox_fwd, _megablox_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` [M, K] rows sorted by group, ``rhs`` [G, K, N],
+    ``group_sizes`` int32 [G] summing to M -> [M, N]: each run of rows
+    times its own group's matrix. The selection is by what can be seen
+    (platform, dtype, shapes), like ``attention(impl="auto")``."""
+    if _use_megablox(lhs, rhs):
+        return _megablox_matmul(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_experts(x, order, inverse, top_k: int):
+    """x [N, D] -> [N * top_k, D]: row a is the token of assignment
+    ``order[a]`` (assignment n * top_k + j is token n's j-th choice). A
+    gather whose transpose is written as a gather too: TPU scatters are
+    slow, and this one is a permutation of ``top_k`` copies."""
+    return x[order // top_k]
+
+
+def _rows_to_experts_fwd(x, order, inverse, top_k):
+    return x[order // top_k], (inverse, x.shape[0])
+
+
+def _rows_to_experts_bwd(top_k, res, g):
+    inverse, n = res
+    dx = g[inverse].reshape(n, top_k, g.shape[-1]).astype(jnp.float32).sum(1)
+    return dx.astype(g.dtype), None, None
+
+
+_rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_tokens(y, order, inverse):
+    """y [A, D] in expert order -> assignment order: a permutation, so its
+    transpose is the gather by ``order``."""
+    return y[inverse]
+
+
+def _rows_to_tokens_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _rows_to_tokens_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
+def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+                        norm_topk: bool = True):
+    """MoE SwiGLU FFN for one layer; every (token, choice) assignment is
+    computed.
+
+    x [B, S, D]; router_w [D, E]; w_gate/w_up [E, D, F]; w_down [E, F, D].
+    Returns (out [B, S, D], {"balance", "z", "load_max"} scalars); the
+    losses are over all the tokens of ``x`` (see the module docstring).
+    """
+    B, S, D = x.shape
+    E = router_w.shape[-1]
+    N, A = B * S, B * S * top_k
+    dt = x.dtype
+    xf = x.reshape(N, D)
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32),
+                            router_w.astype(jnp.float32))
+        probs, gates, experts = route(logits, top_k, norm_topk)
+        counts = _assignment_counts(experts, E)
+        stats = {"balance": E * jnp.sum(counts / A * probs.mean(axis=0)),
+                 "z": router_z(logits), "load_max": _load_max(counts)}
+    with jax.named_scope("moe_dispatch"):
+        # Stable sort of the assignments by expert: ``order[a]`` is the
+        # assignment that lands in row a, ``inverse`` the other way.
+        iota = jnp.arange(A, dtype=jnp.int32)
+        _, order = jax.lax.sort((experts.reshape(A).astype(jnp.int32), iota),
+                                num_keys=1)
+        inverse = jnp.zeros((A,), jnp.int32).at[order].set(iota)
+        rows = _rows_to_experts(xf, order, inverse, top_k)
+    with jax.named_scope("moe_experts"):
+        gate = grouped_matmul(rows, w_gate.astype(dt), counts)
+        up = grouped_matmul(rows, w_up.astype(dt), counts)
+        rows = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(dt),
+                              counts)
+    with jax.named_scope("moe_combine"):
+        rows = _rows_to_tokens(rows, order, inverse).reshape(N, top_k, D)
+        out = (rows.astype(jnp.float32) * gates[:, :, None]).sum(axis=1)
+    return out.astype(dt).reshape(B, S, D), stats

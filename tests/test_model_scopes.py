@@ -76,6 +76,46 @@ def test_unchunked_loss_accumulation_and_experts_have_their_scopes():
     assert not any(part == "mlp" for part, _ in moe)
 
 
+def test_dropless_experts_carry_their_sub_scopes_in_every_pass():
+    """The four sub-scopes ``ops/moe.py`` opens inside ``moe`` reach the
+    compiled step's ``op_name``s in the forward pass, the recompute and the
+    backward pass, and the benchmark's rule still gives every one of those
+    instructions to ``moe`` (the innermost name IT knows), so
+    ``step_mlp_ms`` stays whole."""
+    from ray_tpu.ops import moe
+
+    cfg = models.olmoe_1b_7b(
+        n_layers=1, d_model=64, n_heads=4, n_kv_heads=4, d_ff=32,
+        n_experts=8, expert_top_k=3, vocab_size=256, max_seq_len=64)
+    names = re.findall(r'op_name="([^"]*)"', _step_text(cfg))
+    found = set()
+    for name in names:
+        for sub in moe.SCOPES:
+            if f"/{sub}/" in name:
+                assert scopes.classify(name)[0] == "moe", name
+                found.add((sub, scopes.classify(name)[1]))
+    assert found == {(sub, ps) for sub in moe.SCOPES
+                     for ps in scopes.PASSES}, sorted(found)
+    assert not set(moe.SCOPES) & set(scopes.PARTS)
+
+
+def test_scopes_id_covers_every_file_that_opens_a_scope(tmp_path):
+    """``SCOPES_ID`` is over the bytes of ``models/transformer.py`` AND
+    ``ops/moe.py``: an edit of either gives another id, so a step cached
+    by a tree with other sub-scope names is never loaded."""
+    from ray_tpu.ops import moe
+
+    assert transformer.SCOPE_FILES == (transformer.__file__, moe.__file__)
+    assert transformer.SCOPES_ID == transformer._scopes_id()
+    for i, path in enumerate(transformer.SCOPE_FILES):
+        edited = tmp_path / f"edited{i}.py"
+        with open(path, "rb") as f:
+            edited.write_bytes(f.read() + b"\n# an edit\n")
+        files = list(transformer.SCOPE_FILES)
+        files[i] = str(edited)
+        assert transformer._scopes_id(files) != transformer.SCOPES_ID
+
+
 def test_causal_blocks_leave_no_whole_score_tensor_and_stay_in_attn():
     """At T = 512 ``attention(impl="auto")`` computes four query blocks
     of 128 rows against key prefixes of 128 to 512: no instruction of the
