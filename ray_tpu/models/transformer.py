@@ -111,11 +111,6 @@ class TransformerConfig:
     param_dtype: str = "float32"
     tie_embeddings: bool | None = None  # default: True for gpt2, False for llama
     attn_impl: str = "auto"          # ray_tpu.ops.attention dispatch
-    # Flash-kernel VMEM tile sizes (attn_impl="flash"/"auto"): larger
-    # tiles amortize grid overhead; bounded by VMEM (f32 score tile is
-    # block_q*block_k*4 bytes).
-    flash_block_q: int = 256
-    flash_block_k: int = 256
     remat: bool = True               # checkpoint each layer (HBM↔FLOPs trade)
     # Checkpoint policy: "full" recomputes the whole layer (max memory
     # savings); "dots" saves matmul outputs and recomputes only cheap
@@ -587,8 +582,7 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
             k = apply_rope(k, cos, sin, positions=positions)
         k, v = _expand_gqa(k, v, c)
         q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-        o = attention(q, k, v, causal=True, impl=c.attn_impl,
-                      block_q=c.flash_block_q, block_k=c.flash_block_k)
+        o = attention(q, k, v, causal=True, impl=c.attn_impl)
         o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
         x = x + o
 

@@ -22,9 +22,12 @@ Four tiers:
     O(T) memory, fully differentiable, XLA-fusable; what ``auto`` takes
     above 1024 keys where the kernel does not apply, and the step ring
     attention is built from.
-  - ``flash_attention`` — Pallas TPU forward kernel (interpret-mode on
-    CPU); custom_vjp whose backward is the blockwise path, so training
-    through it stays O(T) memory.
+  - ``flash_attention`` — Pallas TPU kernels (interpret-mode on CPU):
+    a custom_vjp of a forward kernel and two backward kernels (dq;
+    dk / dv) that rebuild the probabilities tile by tile from the saved
+    logsumexp, so training through it stays O(T) memory. Each kernel
+    sizes its own tiles from the shapes (``_flash_tiles``); what
+    ``auto`` takes above 1024 keys on a TPU.
 """
 
 from __future__ import annotations
@@ -180,8 +183,95 @@ def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU flash-attention forward kernel.
+# Pallas TPU flash attention: the tiles of a grid step, the forward kernel.
 # ---------------------------------------------------------------------------
+
+# The most rows of q, and of k / v, one grid step takes, in all three
+# kernels: each kernel alone on the v5e, tiles of 128 to 2048 rows at T
+# 1280 to 8192, head widths 64 and 128, causal and not, ran fastest (or
+# within 3% of it) at the largest divisor of T up to 1024; only the
+# forward at T = 1280 / 1536 wants T whole, by 10% (PERF.md section 6,
+# PR 28). A 1024-tile computes 10/16 of T^2 at T = 4096 where a 256-tile
+# computes 136/256, and still wins: a grid step's fixed cost and the
+# refetch of k / v for every block of q outweigh the masked entries.
+_FLASH_ROWS = 1024
+# Score-shaped [block_q, block_k] float32 tiles a grid step keeps live
+# (scores, probabilities, the mask; the backward's ``dp`` and ``ds``).
+# With these the arithmetic below is 1.4 to 8 times the least limit
+# under which Mosaic compiles the kernel for the v5e (256 to 2048 rows,
+# widths 64 and 128, both dtypes): it counts every tile as live at once.
+_FLASH_SCORE_TILES = {"fwd": 3, "dq": 3, "dkv": 4}
+_FLASH_VMEM_MOST = 32 * 2 ** 20      # a quarter of a v5e core's 128 MiB
+_FLASH_VMEM_LEAST = 16 * 2 ** 20     # Mosaic's own default on the v5e
+
+
+def _vmem_tile(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes of a [rows, cols] tile in VMEM: the lanes pad to 128."""
+    return rows * -(-cols // 128) * 128 * itemsize
+
+
+def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
+                      dtype) -> int:
+    """VMEM one grid step of ``kernel`` ("fwd", "dq" or "dkv") keeps
+    live with these tiles: the operand and output blocks, each twice
+    (the pipeline fetches the next step's while this one computes), the
+    float32 accumulators, and the score-shaped tiles."""
+    io = jnp.dtype(dtype).itemsize
+    q_rows = _vmem_tile(block_q, d, io)          # q, o, g, dq
+    k_rows = _vmem_tile(block_k, d, io)          # k, v, dk, dv
+    column = _vmem_tile(block_q, 1, 4)           # lse, delta, m, l
+    if kernel == "fwd":      # q, k, v -> o, lse; scratch m, l, acc
+        blocks = 2 * q_rows + 2 * k_rows + column
+        scratch = 2 * column + _vmem_tile(block_q, d, 4)
+    elif kernel == "dq":     # q, k, v, g, lse, delta -> dq; scratch acc
+        blocks = 3 * q_rows + 2 * k_rows + 2 * column
+        scratch = _vmem_tile(block_q, d, 4)
+    else:                    # q, k, v, g, lse, delta -> dk, dv; two accs
+        blocks = 2 * q_rows + 4 * k_rows + 2 * column
+        scratch = 2 * _vmem_tile(block_k, d, 4)
+    scores = _FLASH_SCORE_TILES[kernel] * _vmem_tile(block_q, block_k, 4)
+    return 2 * blocks + scratch + scores
+
+
+def _flash_tiles(kernel: str, tq: int, tk: int, d: int, dtype):
+    """(block_q, block_k) of one grid step of ``kernel``, from the
+    lengths, the head width and the inputs' dtype: of the divisors of
+    each length in whole 128-row tiles up to ``_FLASH_ROWS``, the
+    largest pair whose grid step fits ``_FLASH_VMEM_MOST``. That is the
+    largest divisor of each, but for float32 heads four times as wide as
+    any preset's. None where ``tq`` or ``tk`` is not a whole number of
+    128-row tiles."""
+    def divisors(t):
+        return [r for r in range(128, min(_FLASH_ROWS, t) + 1, 128)
+                if t % r == 0]
+
+    fits = [(bq, bk) for bq in divisors(tq) for bk in divisors(tk)
+            if _flash_vmem_bytes(kernel, bq, bk, d, dtype) <= _FLASH_VMEM_MOST]
+    return max(fits, key=lambda tile: (tile[0] * tile[1], tile[1]),
+               default=None)
+
+
+def _flash_launch(kernel: str, q, k, block_q, block_k):
+    """What a ``pallas_call`` of ``kernel`` is launched with: the tiles
+    (the caller's, or the rule's where it gave none) and the compiler
+    parameters that grant the VMEM those tiles need."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
+    if block_q is None or block_k is None:
+        tiles = _flash_tiles(kernel, tq, tk, d, q.dtype)
+        if tiles is None:
+            raise ValueError(
+                f"flash_attention: seq lens ({tq},{tk}) are not whole "
+                f"128-row tiles; use impl='auto'")
+        block_q, block_k = tiles
+    block_q, block_k = min(block_q, tq), min(block_k, tk)
+    if tq % block_q or tk % block_k:
+        raise ValueError(f"seq lens ({tq},{tk}) must divide blocks "
+                         f"({block_q},{block_k})")
+    vmem = max(_flash_vmem_bytes(kernel, block_q, block_k, d, q.dtype),
+               _FLASH_VMEM_LEAST)
+    return block_q, block_k, pltpu.CompilerParams(vmem_limit_bytes=vmem)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
@@ -258,10 +348,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
     qf = q.transpose(0, 2, 1, 3).reshape(bh, tq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(bh, tk, d)
     vf = v.transpose(0, 2, 1, 3).reshape(bh, tk, d)
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    if tq % block_q or tk % block_k:
-        raise ValueError(f"seq lens ({tq},{tk}) must divide blocks ({block_q},{block_k})")
+    block_q, block_k, params = _flash_launch("fwd", q, k, block_q, block_k)
     n_q, n_k = tq // block_q, tk // block_k
 
     kernel = functools.partial(
@@ -289,6 +376,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=params,
         interpret=interpret,
     )(qf, kf, vf)
     out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
@@ -317,6 +405,11 @@ def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
 
     lse/delta arrive as [block_q, 1] column tiles (see the forward's
     _emit note on Mosaic block-shape legality) and broadcast over keys.
+    Both matmuls take their operands in the INPUTS' dtype and accumulate
+    in float32, as the forward kernel's do (``dot_product_attention``'s
+    dtype policy); the softmax statistics, ``delta`` and the ``ds``
+    arithmetic are float32. The callers cast ``p`` and ``ds`` once, to
+    the inputs' dtype, for the matmuls that consume them.
     """
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -352,8 +445,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
     def _compute():
         ds, _ = _bwd_block(
-            q_ref[0], k_ref[0], v_ref[0].astype(jnp.float32),
-            g_ref[0].astype(jnp.float32), lse_ref[0], delta_ref[0],
+            q_ref[0], k_ref[0], v_ref[0], g_ref[0], lse_ref[0], delta_ref[0],
             q_blk=q_blk, k_blk=k_blk, block_q=block_q, block_k=block_k,
             causal=causal, scale=scale)
         acc_ref[:] += jax.lax.dot_general(
@@ -386,17 +478,16 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     def _compute():
-        g = g_ref[0].astype(jnp.float32)
+        q, g = q_ref[0], g_ref[0]
         ds, p = _bwd_block(
-            q_ref[0], k_ref[0], v_ref[0].astype(jnp.float32), g,
-            lse_ref[0], delta_ref[0],
+            q, k_ref[0], v_ref[0], g, lse_ref[0], delta_ref[0],
             q_blk=q_blk, k_blk=k_blk, block_q=block_q, block_k=block_k,
             causal=causal, scale=scale)
         dv_acc[:] += jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())),
+            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[:] += jax.lax.dot_general(
-            ds, q_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if causal:
@@ -426,57 +517,54 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k,
     flat = lambda x, t: x.transpose(0, 2, 1, 3).reshape(bh, t, d)  # noqa: E731
     qf, gf, of = flat(q, tq), flat(g, tq), flat(out, tq)
     kf, vf = flat(k, tk), flat(v, tk)
-    block_q = min(block_q, tq)
-    block_k = min(block_k, tk)
-    n_q, n_k = tq // block_q, tk // block_k
     # delta = rowsum(dO * O): one fused elementwise pass in XLA. Kept as
     # a [bh, tq, 1] column (same block-legality story as lse).
     delta = (gf.astype(jnp.float32) * of.astype(jnp.float32)).sum(
         -1, keepdims=True)
+    operands = (qf, kf, vf, gf, lse, delta)
 
-    common = dict(block_q=block_q, block_k=block_k, causal=causal,
-                  scale=scale)
+    def in_specs(bq, bk, rows, keys):
+        return [pl.BlockSpec((1, bq, d), rows),      # q
+                pl.BlockSpec((1, bk, d), keys),      # k
+                pl.BlockSpec((1, bk, d), keys),      # v
+                pl.BlockSpec((1, bq, d), rows),      # g
+                pl.BlockSpec((1, bq, 1), rows),      # lse
+                pl.BlockSpec((1, bq, 1), rows)]      # delta
+
+    # The dq pass: grid (b, i, j), key blocks innermost.
+    bq, bk, params = _flash_launch("dq", q, k, block_q, block_k)
+    rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
+    keys = lambda b_, i, j: (b_, j, 0)  # noqa: E731
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, n_k=n_k, **common),
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
+        functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
+                          n_k=tk // bk, causal=causal, scale=scale),
+        grid=(bh, tq // bq, tk // bk),
+        in_specs=in_specs(bq, bk, rows, keys),
+        out_specs=pl.BlockSpec((1, bq, d), rows),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
-    )(qf, kf, vf, gf, lse, delta)
+    )(*operands)
+
+    # The dk / dv pass: grid (b, j, i), query blocks innermost.
+    bq, bk, params = _flash_launch("dkv", q, k, block_q, block_k)
+    rows = lambda b_, j, i: (b_, i, 0)  # noqa: E731
+    keys = lambda b_, j, i: (b_, j, 0)  # noqa: E731
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, n_q=n_q, **common),
-        grid=(bh, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b_, j, i: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, j, i: (b_, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, j, i: (b_, j, 0)),
-        ],
+        functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
+                          n_q=tq // bq, causal=causal, scale=scale),
+        grid=(bh, tk // bk, tq // bq),
+        in_specs=in_specs(bq, bk, rows, keys),
+        out_specs=[pl.BlockSpec((1, bk, d), keys)] * 2,
         out_shape=[
             jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        compiler_params=params,
         interpret=interpret,
-    )(qf, kf, vf, gf, lse, delta)
+    )(*operands)
     unflat = lambda x, t: x.reshape(b, h, t, d).transpose(0, 2, 1, 3)  # noqa: E731
     return unflat(dq, tq), unflat(dk, tk), unflat(dv, tk)
 
@@ -497,13 +585,17 @@ def _interpret() -> bool:
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 256,
-                    block_k: int = 256):
+def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
+                    block_k: int | None = None):
     """Pallas flash attention (TPU kernel; interpreter on CPU).
 
     Training runs the Pallas BACKWARD kernels (dq pass + dk/dv pass,
     probabilities recomputed per tile from the saved logsumexp): O(T)
     memory end to end, no XLA recompute graph.
+
+    Each of the three kernels sizes its own tiles from the shapes and
+    the dtype (``_flash_tiles``). ``block_q`` / ``block_k`` set the tiles
+    of all three instead: for tests, whose interpreter wants small ones.
     """
     return _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
@@ -567,8 +659,7 @@ def flash_attention_jax(q, k, v, *, causal: bool = True,
     return o.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
-              block_q: int = 256, block_k: int = 256):
+def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
     """Dispatch: 'reference' | 'blockwise' | 'flash' | 'flash_jax' |
     'auto'.
 
@@ -576,34 +667,33 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
     whose T is a whole number, at least two, of query blocks (a quarter
     of T, in whole 128-row tiles) takes ``causal_blocked_attention``,
     anything else the plain reference.
-    Above 1024 it uses the Pallas kernel on TPU when shapes tile
-    cleanly, else the blockwise path. ``block_q``/``block_k`` size the
-    flash kernel's VMEM tiles (bigger tiles amortize grid overhead and
-    lengthen the MXU contractions; bounded by VMEM — the f32 score tile
-    alone is block_q*block_k*4 bytes).
+    Above 1024 it uses the Pallas kernel on TPU where both lengths are
+    whole 128-row tiles (the kernel sizes its own tiles from the shapes:
+    ``_flash_tiles``), else the blockwise path.
     """
     if impl == "reference":
         return dot_product_attention(q, k, v, causal=causal)
     if impl == "blockwise":
         return blockwise_attention(q, k, v, causal=causal)
     if impl == "flash":
-        return flash_attention(q, k, v, causal, block_q, block_k)
+        return flash_attention(q, k, v, causal)
     if impl == "flash_jax":
-        return flash_attention_jax(q, k, v, causal=causal,
-                                   block_q=block_q, block_k=block_k)
+        return flash_attention_jax(q, k, v, causal=causal)
     tq, tk = q.shape[1], k.shape[1]
     on_tpu = jax.devices()[0].platform == "tpu"
     # Up to 1024 keys the scores are materialised by XLA, and for causal
     # self-attention only the blocks at or under the diagonal (PERF.md
     # section 6, PR 25: the v5e runs of both benchmark cells that settled
-    # this branch). The Pallas kernel's 256-tiles make 3,200 grid steps
-    # here and its backward feeds the MXU float32; until that is repaired
-    # (ROADMAP A1) it starts above 1024.
+    # this branch). The Pallas kernel starts above 1024 because there it
+    # still loses to these blocks, with the one 1024-row tile its rule
+    # gives it: forward + backward 3.97 against 2.33 ms at
+    # [8, 1024, 25, 64], 2.66 against 2.50 at [4, 1024, 32, 128] (v5e,
+    # PERF.md section 6, PR 28; ROADMAP A1).
     if tk <= 1024:
         rows = _causal_block_rows(tq) if causal and tq == tk else 0
         if rows:
             return causal_blocked_attention(q, k, v, block_q=rows)
         return dot_product_attention(q, k, v, causal=causal)
-    if on_tpu and tq % block_q == 0 and tk % block_k == 0:
-        return flash_attention(q, k, v, causal, block_q, block_k)
+    if on_tpu and _flash_tiles("fwd", tq, tk, q.shape[-1], q.dtype):
+        return flash_attention(q, k, v, causal)
     return blockwise_attention(q, k, v, causal=causal)

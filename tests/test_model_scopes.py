@@ -20,14 +20,18 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 
 
-def _step_text(cfg, *, rows: int = 4, seq_len: int = 32,
-               accum_steps: int = 1) -> str:
+def _traced_step(cfg, *, rows: int = 4, seq_len: int = 32,
+                 accum_steps: int = 1):
     opt = optax.adamw(1e-3)
     state = jax.eval_shape(
         lambda k: models.init_train_state(k, cfg, opt), jax.random.PRNGKey(0))
     batch = {"tokens": jax.ShapeDtypeStruct((rows, seq_len + 1), jnp.int32)}
     step = jax.jit(models.make_train_step(cfg, opt, accum_steps=accum_steps))
-    text = step.lower(state, batch).compile().as_text()
+    return step.trace(state, batch)
+
+
+def _step_text(cfg, **shape) -> str:
+    text = _traced_step(cfg, **shape).lower().compile().as_text()
     assert text.startswith("HloModule jit_train_step")
     return text
 
@@ -136,6 +140,26 @@ def test_causal_blocks_leave_no_whole_score_tensor_and_stay_in_attn():
              for m in map(block.search, text.splitlines()) if m}
     assert found == {("attn", "forward"), ("attn", "recompute"),
                      ("attn", "backward")}, sorted(found)
+
+
+def test_a_step_at_1024_has_no_kernel_and_one_at_2048_has(monkeypatch):
+    """The dense cells' bypass: on a TPU ``attention(impl="auto")`` at
+    T = 1024 takes the materialised blocks, so the train step lowered
+    for a TPU has no ``tpu_custom_call``; at T = 2048 it has four (the
+    kernel's forward, its recompute, dq, dk / dv)."""
+    import types
+
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")])
+
+    def kernels(t):
+        traced = _traced_step(models.tiny(max_seq_len=t, remat=True),
+                              rows=2, seq_len=t)
+        return traced.lower(lowering_platforms=("tpu",)).as_text().count(
+            "tpu_custom_call")
+
+    assert kernels(1024) == 0
+    assert kernels(2048) == 4
 
 
 def test_the_reader_knows_exactly_the_programs_scopes():

@@ -97,6 +97,88 @@ def test_flash_backward_kernels_multiblock(causal):
         np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
 
 
+# Largest |difference| allowed, as a share of the reference's largest
+# entry: float32 as the explicit-tile tests above; bfloat16 two units in
+# the last place (PR 25's rule for the blocked path), with operands of
+# every matmul of all three kernels in bfloat16.
+_FLASH_TOL = {jnp.float32: 2e-4, jnp.bfloat16: 2.0 ** -6}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_kernels_at_the_rules_own_tiles(d, dtype):
+    """Forward, dq, dk and dv at the tiles each kernel picks for itself
+    (no ``block_q`` / ``block_k``), T = 2048, causal, against
+    ``dot_product_attention`` in the same dtype. The cotangent is the
+    multiblock test's loss's, taken ONCE (at the float32 reference's
+    output) and handed to both sides: that loss's ``cos(out.sum(-1))``
+    turns one bfloat16 rounding of ``out`` into percents of cotangent,
+    which is the loss's doing and no kernel's."""
+    t = 2048
+    for kernel in ("fwd", "dq", "dkv"):
+        bq, bk = attention_mod._flash_tiles(kernel, t, t, d, dtype)
+        assert t // bq > 1 or t // bk > 1, (kernel, bq, bk)
+    q, k, v = _qkv(b=1, t=t, h=1, d=d, seed=3)
+    w = jnp.asarray(np.random.RandomState(7).randn(d), jnp.float32)
+    g = jax.grad(lambda o: (jnp.tanh(o @ w) * jnp.cos(o.sum(-1))).sum())(
+        dot_product_attention(q, k, v, causal=True))
+    q, k, v, g = (x.astype(dtype) for x in (q, k, v, g))
+
+    def run(att):
+        out, vjp = jax.vjp(att, q, k, v)
+        return (out, *vjp(g))
+
+    want = run(lambda q, k, v: dot_product_attention(q, k, v, causal=True))
+    got = run(lambda q, k, v: flash_attention(q, k, v, True))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= _FLASH_TOL[dtype] * np.abs(b).max(), \
+            name
+
+
+# (T, head width) of every preset above 1024 (`moe_small`, `llama2_7b`,
+# `olmoe_1b_7b`, `llama3_8b`, `mistral_7b` / `mixtral_8x7b` / `qwen2_7b`),
+# GPT-2's width at their lengths, and lengths 1024-tiles do not divide;
+# then Tq != Tk, and a float32 head no preset has, where the tiles step
+# down to fit.
+@pytest.mark.parametrize("tq,tk,d,dtype", [
+    (t, t, d, dtype)
+    for t in (1280, 1536, 2048, 3072, 4096, 8192, 32768)
+    for d in (64, 128) for dtype in (jnp.bfloat16, jnp.float32)
+] + [(128, 4096, 128, jnp.bfloat16), (4096, 128, 128, jnp.bfloat16),
+     (4096, 4096, 512, jnp.float32)])
+def test_flash_tile_rule_gives_legal_tiles(tq, tk, d, dtype):
+    """Whole 128-row tiles that divide the lengths, in a grid step whose
+    VMEM, by the kernel's own arithmetic, is inside what its
+    ``pallas_call`` is given, which is inside the rule's budget."""
+    q = jax.ShapeDtypeStruct((2, tq, 4, d), dtype)
+    k = jax.ShapeDtypeStruct((2, tk, 4, d), dtype)
+    for kernel in ("fwd", "dq", "dkv"):
+        bq, bk = attention_mod._flash_tiles(kernel, tq, tk, d, dtype)
+        assert bq % 128 == 0 and bk % 128 == 0 and bq > 0 and bk > 0
+        assert tq % bq == 0 and tk % bk == 0
+        assert max(bq, bk) <= attention_mod._FLASH_ROWS
+        launch_q, launch_k, params = attention_mod._flash_launch(
+            kernel, q, k, None, None)
+        assert (launch_q, launch_k) == (bq, bk)
+        need = attention_mod._flash_vmem_bytes(kernel, bq, bk, d, dtype)
+        assert need <= params.vmem_limit_bytes \
+            <= attention_mod._FLASH_VMEM_MOST, (kernel, bq, bk, need)
+
+
+@pytest.mark.parametrize("tq,tk", [(2000, 2000), (1100, 2048), (2048, 1100),
+                                   (64, 2048)])
+def test_flash_tile_rule_has_no_tile_for_ragged_lengths(tq, tk):
+    for kernel in ("fwd", "dq", "dkv"):
+        assert attention_mod._flash_tiles(
+            kernel, tq, tk, 128, jnp.bfloat16) is None
+    q = jnp.zeros((1, tq, 1, 128))
+    kv = jnp.zeros((1, tk, 1, 128))
+    with pytest.raises(ValueError, match="whole 128-row tiles"):
+        flash_attention(q, kv, kv, True)
+
+
 def _gqa_case(t, d, dtype, *, h=4, kv_heads=2, seed=0):
     """q, narrow k / v and a cotangent, as the llama block has them
     before ``_expand_gqa`` repeats k and v over the query heads."""
@@ -154,6 +236,25 @@ def test_blocked_causal_any_block_count(block_q):
         attention_mod.causal_blocked_attention(q, k, v, block_q=96)
 
 
+def _record_paths(monkeypatch) -> list:
+    """Replaces the four implementations ``attention`` picks from by
+    recorders of (which, the query tile it was handed, if any)."""
+    taken = []
+
+    def recorder(name):
+        def fn(q, k, v, *a, **kw):
+            taken.append((name, kw.get("block_q", a[1] if a[1:] else None)))
+            return q
+        return fn
+
+    for name, attr in [("blocked", "causal_blocked_attention"),
+                       ("plain", "dot_product_attention"),
+                       ("blockwise", "blockwise_attention"),
+                       ("flash", "flash_attention")]:
+        monkeypatch.setattr(attention_mod, attr, recorder(name))
+    return taken
+
+
 @pytest.mark.parametrize("impl,causal,tq,tk,want", [
     ("auto", True, 256, 256, ("blocked", 128)),
     ("auto", True, 512, 512, ("blocked", 128)),
@@ -168,22 +269,34 @@ def test_blocked_causal_any_block_count(block_q):
 ])
 def test_which_path_attention_takes(monkeypatch, impl, causal, tq, tk, want):
     """The ``auto`` rule reads shapes alone; a caller sets nothing."""
-    taken = []
-
-    def recorder(name):
-        def fn(q, k, v, *a, block_q=None, **kw):
-            taken.append((name, block_q))
-            return q
-        return fn
-
-    for name, attr in [("blocked", "causal_blocked_attention"),
-                       ("plain", "dot_product_attention"),
-                       ("blockwise", "blockwise_attention"),
-                       ("flash", "flash_attention")]:
-        monkeypatch.setattr(attention_mod, attr, recorder(name))
+    taken = _record_paths(monkeypatch)
     q = jnp.zeros((1, tq, 2, 8))
     kv = jnp.zeros((1, tk, 2, 8))
     attention(q, kv, kv, causal=causal, impl=impl)
+    assert taken == [want]
+
+
+@pytest.mark.parametrize("tq,tk,want", [
+    (2048, 2048, ("flash", None)),
+    (4096, 4096, ("flash", None)),
+    (1280, 1280, ("flash", None)),      # 640-row tiles
+    (128, 2048, ("flash", None)),       # Tq != Tk, both whole tiles
+    (2000, 2000, ("blockwise", None)),  # no whole 128-row tile divides it
+    (100, 2048, ("blockwise", None)),
+    (1024, 1024, ("blocked", 256)),     # the threshold has not moved
+])
+def test_which_path_auto_takes_on_a_tpu(monkeypatch, tq, tk, want):
+    """Above 1024 keys, on a TPU, the kernel where the rule has tiles
+    for both lengths and ``blockwise_attention`` where it has none; the
+    caller hands the kernel no tile."""
+    import types
+
+    taken = _record_paths(monkeypatch)
+    q = jnp.zeros((1, tq, 2, 64), jnp.bfloat16)
+    kv = jnp.zeros((1, tk, 2, 64), jnp.bfloat16)
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(platform="tpu")])
+    attention(q, kv, kv, causal=True, impl="auto")
     assert taken == [want]
 
 
