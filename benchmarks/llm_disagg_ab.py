@@ -28,8 +28,8 @@ cares about. Each side's saturation capacity (`capacity_tokens_per_s`,
 from the closed-loop rehearsal) and latency percentiles are reported
 alongside so nothing is hidden.
 
-Equal chips (2 vs 1+1); `goodput_ratio` and `p99_ratio` land in
-SCALE.json's llm block, plus the handoff's own latency/bytes and the
+Equal chips (2 vs 1+1); `goodput_ratio` and `p99_ratio` are
+printed, plus the handoff's own latency/bytes and the
 prefix/page telemetry behind it.
 
 Run (needs a live cluster when imported; standalone boots one):
@@ -348,7 +348,7 @@ def run_ab(n_requests: int = N_REQUESTS, clients: int = N_CLIENTS) -> dict:
 
 def main() -> None:
     # A CPU rehearsal at `tiny` by design (its rows are counts and
-    # ratios for SCALE.json, not device speeds): no chips in the
+    # ratios, not device speeds): no chips in the
     # cluster, so the replicas place as chipless actors.
     os.environ["JAX_PLATFORMS"] = "cpu"
     import ray_tpu

@@ -126,7 +126,6 @@ def main(as_json: bool = False) -> dict:
     bench_data_plane(results)
     bench_wire_binary(results)
     bench_native_loop(results)
-    bench_head_shards(results)
     bench_seal_coalescing(results)
     bench_event_overhead(results)
     bench_forensics_overhead(results)
@@ -269,55 +268,6 @@ def bench_native_loop(results: dict) -> None:
         ray_tpu.shutdown()
     os.environ.pop("RAY_TPU_NATIVE_LOOP", None)
     config_mod.GLOBAL_CONFIG.native_loop = True
-
-
-def bench_head_shards(results: dict) -> None:
-    """Sharded head on/off (RAY_TPU_HEAD_SHARDS): the depth-512
-    pipelined actor flood and the leased-task flood, once against a
-    single in-process head and once with the hot path split across 2
-    dispatch-shard processes. On a 1-core box the sharded numbers are
-    expected to be flat-to-worse (the shards time-share the core and
-    pay the process hop); the multi-core speedup claim lives in
-    benchmarks/scale_envelope.py, which records per-shard CPU
-    utilization alongside the A/B."""
-    import os
-
-    from ray_tpu._private import config as config_mod
-
-    ncpu = os.cpu_count() or 1
-    results["head_shards_ncpu"] = ncpu
-    for mode in ("off", "on"):
-        shards = 2 if mode == "on" else 1
-        os.environ["RAY_TPU_HEAD_SHARDS"] = str(shards)
-        config_mod.GLOBAL_CONFIG.head_shards = shards
-        ray_tpu.init(num_cpus=4, object_store_memory=256 * 1024 * 1024,
-                     log_to_driver=False)
-
-        @ray_tpu.remote
-        class SEcho:
-            def ping(self, x=None):
-                return x
-
-        actor = SEcho.remote()
-        ray_tpu.get([actor.ping.remote() for _ in range(64)])  # warm
-        timeit(f"actor pipeline depth 512 head_shards {mode}",
-               lambda: ray_tpu.get(
-                   [actor.ping.remote() for _ in range(512)]),
-               512, results=results)
-
-        @ray_tpu.remote
-        def stask(i):
-            return i
-
-        N = 100
-        ray_tpu.get([stask.remote(i) for i in range(64)])  # warm leases
-        timeit(f"tasks async head_shards {mode}",
-               lambda: ray_tpu.get([stask.remote(i) for i in range(N)]),
-               N, results=results)
-        ray_tpu.kill(actor)
-        ray_tpu.shutdown()
-    os.environ.pop("RAY_TPU_HEAD_SHARDS", None)
-    config_mod.GLOBAL_CONFIG.head_shards = 0
 
 
 def bench_seal_coalescing(results: dict) -> None:

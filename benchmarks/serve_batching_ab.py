@@ -15,7 +15,7 @@ batch_size, concurrency-tolerant — the TPU-forward-pass shape):
 
 At equal offered load the continuous scheduler should finish the run
 faster (higher throughput) at equal-or-better p99 — that delta is the
-acceptance row `speedup` in SCALE.json's serve block.
+row `speedup`.
 
 Run: python benchmarks/serve_batching_ab.py [--json]
 """

@@ -253,13 +253,6 @@ class Config:
     # --- networking ---
     head_host: str = "127.0.0.1"  # 0.0.0.0 for multi-host clusters
     head_port: int = 0  # 0 = ephemeral; CLI `start --head` defaults 6380
-    # Head dispatch shards: >1 splits the head's hot path across that
-    # many worker processes (each a full Head over a slice of the
-    # cluster, fronted by a connection router + metadata directory in
-    # the parent — see _private/head_shards.py). 0/1 = the
-    # single-process head. Opt-in: a shard places only what fits its
-    # slice of the box, and a node with TPU chips refuses to shard.
-    head_shards: int = 0
 
     # --- timeouts ---
     worker_register_timeout_s: float = 30.0
@@ -369,7 +362,7 @@ class Config:
     cluster_profile_max_windows: int = 512
     # Phase-regression pinning: a queue_wait/dispatch p95 above
     # factor * trailing median (given >= min_count observations) pins
-    # the head/shard flamegraphs for that window.
+    # the head's flamegraphs for that window.
     profiling_regression_factor: float = 2.0
     profiling_regression_min_count: int = 200
 
@@ -477,18 +470,7 @@ ENV_KNOBS = {
         "operator", "1 arms the runtime lock-order witness: every "
         "ray_tpu lock acquisition feeds a live ordering graph and "
         "cycles (potential deadlocks) are reported with both stacks"),
-    "RAY_TPU_HEAD_SHARDS": (
-        "operator", "head dispatch shards: N>1 runs N parallel head "
-        "shard processes behind a connection router + metadata "
-        "directory; 0/1/unset = the single-process head. Refused on "
-        "a node with TPU chips"),
     # -- internal spawn plumbing -------------------------------------
-    "RAY_TPU_SHARD_BOOT": (
-        "internal", "pickled boot payload path handed to a head shard "
-        "process (config, resource slice, shard index, bus address)"),
-    "RAY_TPU_SHARD_FD": (
-        "internal", "inherited socketpair fd a head shard receives "
-        "routed client connections on (SCM_RIGHTS fd-passing)"),
     "RAY_TPU_HEAD": (
         "internal", "head host:port handed to spawned workers"),
     "RAY_TPU_WORKER_ID": (

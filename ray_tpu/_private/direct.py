@@ -467,8 +467,7 @@ class DirectPlane:
                 self._expire_task(tid)
 
     def on_reconnect(self) -> None:
-        """The driver re-registered with a new/restarted head — possibly
-        a DIFFERENT dispatch shard of a sharded head (head_shards.py).
+        """The driver re-registered with a new/restarted head.
         Every grant the old head issued is void there: drop all routes
         back to head mode and all leases without lease_return (the old
         head is gone; the new one never issued them). In-flight calls

@@ -13,14 +13,6 @@ Kill switches, strictest wins:
   RAY_TPU_NATIVE_LOOP=0   — just this event loop (Config.native_loop)
   RAY_TPU_WIRE_BINARY=0   — binary wire off implies no native lane
     (the lane's cast coalescer only speaks the tagged binary format)
-
-Sharded head note (head_shards.py): sockets that reach a dispatch
-shard via SCM_RIGHTS fd-passing are adopted through
-``Server.adopt_socket`` and arm the lane exactly like accept()ed ones —
-the lane binds by fileno(), so a router-handed fd is indistinguishable
-from a locally accepted one. Each shard process loads its OWN copy of
-``_evloop.so``; the wire-version handshake above keeps a stale artifact
-in one shard from speaking a different dialect than its siblings.
 """
 
 from __future__ import annotations

@@ -292,14 +292,8 @@ class Head:
         num_tpus: float | None = None,
         resources: dict[str, float] | None = None,
         session_dir: str | None = None,
-        shard_ctx=None,
     ):
         self.config = config
-        # Sharded-head mode (head_shards.ShardCtx): None means the
-        # single-process head — every shard branch below is behind
-        # `self.shard is not None`, so shards=1 never runs sharding
-        # code (the bit-identical kill switch).
-        self.shard = shard_ctx
         self.session_id = uuid.uuid4().hex[:12]
         self.session_dir = session_dir or f"/tmp/ray_tpu/session_{self.session_id}"
         os.makedirs(self.session_dir, exist_ok=True)
@@ -458,7 +452,7 @@ class Head:
         # (queue_wait/dispatch), sampled once per health tick from the
         # cumulative phase histograms; a tick whose p95 exceeds the
         # trailing median by profiling_regression_factor pins the
-        # head/shard flamegraph windows covering that tick.
+        # head's flamegraph windows covering that tick.
         self._phase_p95_hist: dict[str, deque] = {}
         self._phase_prev_counts: dict[str, int] = {}
         self._pinned_windows: set[tuple] = set()
@@ -524,21 +518,6 @@ class Head:
         self._lru_tick = 0
         self._shutdown = False
         self._subscribers: dict[str, list[rpc.Connection]] = {}  # pubsub topic
-        # --- cross-shard tables (empty/idle at shards=1) ---
-        # Metas for objects owned by OTHER shards, learned through the
-        # bus (dir_obj_lookup replies + pushed xshard_sealed casts).
-        # Every cross-shard meta is PIN-FREE (inline copy / owner
-        # pointer / unpinned p2p) so no pin lifecycle spans shards;
-        # bounded FIFO — a consumer that comes back later just re-asks.
-        self._xshard_metas: dict[str, tuple] = {}
-        self._xshard_meta_fifo: deque[str] = deque()
-        # Owner side: oid -> set of shard indexes to push the meta to
-        # when it seals (registered by their pending lookups).
-        self._xshard_watch: dict[str, set] = {}
-        # actor_id -> owning shard index, learned via dir_find_actor /
-        # dir_name_get (a stale entry self-heals: the forward errors
-        # and the next locate re-asks).
-        self._xshard_actors: dict[str, int] = {}
 
         # --- local node (head node) ---
         node_resources = self._detect_resources(num_cpus, num_tpus, resources)
@@ -553,24 +532,19 @@ class Head:
             )
         )
         self.node_resources = node_resources
-        # Continuous profiling plane: the head (or this dispatch shard)
-        # samples its own dispatch/health/send threads from boot. Its
-        # windows are merged into cluster_profile by the health tick —
-        # no rpc needed for the in-process role. Shards run the same
-        # Head class; the role tag keeps their flamegraphs separable so
-        # PR 17's per-shard CPU rows become attributable.
+        # Continuous profiling plane: the head samples its own
+        # dispatch/health/send threads from boot. Its windows are
+        # merged into cluster_profile by the health tick — no rpc
+        # needed for the in-process role.
         from ray_tpu._private import profplane
 
-        profplane.arm("shard" if self.shard is not None else "head",
-                      self.node_id)
+        profplane.arm("head", self.node_id)
         # --- telemetry history + SLO alerting plane (tsdb.py /
         # alertplane.py) --- bounded embedded time-series store fed
         # from the EXISTING amortized casts (rpc_report, heartbeats,
         # report_metrics) plus this process's own tables sampled on the
         # health tick, and the declarative alert engine evaluated on
-        # the same tick. Sharded head: each shard keeps its own store
-        # and engine; queries/alert listings fan out like every other
-        # state read.
+        # the same tick.
         from ray_tpu._private import alertplane
         from ray_tpu._private import tsdb as tsdb_mod
 
@@ -762,33 +736,11 @@ class Head:
             res["memory"] = float(psutil.virtual_memory().total)
         except Exception:
             res["memory"] = 8e9
-        shard = getattr(self, "shard", None)
-        if shard is not None and shard.total > 1:
-            # Each shard of a sharded head detects the SAME host memory;
-            # divide so the cross-shard cluster_resources sum stays the
-            # real host total instead of total × shards.
-            res["memory"] /= shard.total
         res[f"node:{self.node_id if hasattr(self, 'node_id') else '127.0.0.1'}"] = 1.0
         return res
 
-    # ------------------------------------------------------------------
-    # cross-shard plumbing (every entry point no-ops at shards=1)
-
-    def _new_worker_id(self) -> str:
-        """Mint a worker id; in shard mode it is rejection-sampled so
-        shard_for(worker_id) == this shard — the router can then land a
-        re-dialing worker back on the shard that owns its record."""
-        if self.shard is None:
-            return "worker-" + uuid.uuid4().hex[:8]
-        from ray_tpu._private.head_shards import mint_for_shard
-
-        return mint_for_shard("worker-", self.shard.index,
-                              self.shard.total)
-
     def _client_cast(self, client_id: str, kind: str, body: dict) -> None:
-        """Push to a client by id, wherever its connection lives: the
-        local conn when we host it, else relayed through the shard bus
-        (the owner of a forwarded actor task is on another shard).
+        """Push to a client by id if it is still connected.
         Safe under self.lock (cast_buffered only serializes+queues)."""
         c = self.clients.get(client_id)
         if c is not None:
@@ -796,154 +748,6 @@ class Head:
                 c.cast_buffered(kind, body)
             except rpc.ConnectionLost:
                 pass
-        elif self.shard is not None:
-            self.shard.relay_client_cast(client_id, kind, body)
-
-    def _dir_name_del(self, key: tuple, actor_id: str) -> None:
-        """Release a name's directory claim (cast; guarded shard-side
-        and directory-side against a successor that re-took it)."""
-        if self.shard is not None:
-            self.shard.bus_cast("dir_name_del", {
-                "key": list(key), "actor_id": actor_id})
-
-    def _locate_actor_shard(self, actor_id: str) -> "int | None":
-        """Which shard hosts this actor? NEVER call under self.lock —
-        it blocks on a bus round-trip."""
-        cached = self._xshard_actors.get(actor_id)
-        if cached is not None:
-            return cached
-        try:
-            r = self.shard.bus_call("dir_find_actor",
-                                    {"actor_id": actor_id})
-        except rpc.RpcError:
-            return None
-        shard = r.get("shard") if r else None
-        if shard is not None:
-            self._xshard_actors[actor_id] = shard
-        return shard
-
-    def _xshard_track(self, ids) -> None:
-        """Resolve ids this shard doesn't own before the waiter parks:
-        ask the directory to fan a pin-free lookup out to the other
-        shards, record the metas, and register a sealed-watch for the
-        still-pending ones. Runs OUTSIDE self.lock (bus round-trip)."""
-        with self.lock:
-            unknown = [i for i in ids
-                       if i not in self.objects
-                       and i not in self._xshard_metas]
-        if not unknown:
-            return
-        try:
-            r = self.shard.bus_call("dir_obj_lookup", {
-                "ids": unknown, "shard": self.shard.index})
-        except rpc.RpcError:
-            return
-        metas = (r or {}).get("metas") or {}
-        if metas:
-            with self.lock:
-                for oid, meta in metas.items():
-                    self._xshard_meta_put(oid, meta)
-
-    def _xshard_meta_put(self, oid: str, meta) -> None:
-        """lock held. Record a bus-served meta (bounded FIFO)."""
-        if oid not in self._xshard_metas:
-            self._xshard_meta_fifo.append(oid)
-            while len(self._xshard_meta_fifo) > 8192:
-                self._xshard_metas.pop(self._xshard_meta_fifo.popleft(),
-                                       None)
-        self._xshard_metas[oid] = tuple(meta)
-
-    def _xshard_ref_relay(self, op: str, ids, conn) -> None:
-        """Forward ref/borrow ops on ids another shard owns (cast:
-        refcounts tolerate async application; the owner's own live ref
-        covers the gap)."""
-        if not ids or self.shard is None:
-            return
-        self.shard.bus_cast("dir_obj_ref", {
-            "op": op, "ids": list(ids),
-            "client_id": conn.peer_info.get("client_id"),
-            "shard": self.shard.index})
-
-    def _xshard_fanout(self, kind: str, body: dict) -> list:
-        """State-query merge: collect the other shards' replies for
-        this read-only handler through the directory. NEVER under
-        self.lock. `_shard_local` marks a fanned-out copy so the
-        receiving shard answers locally instead of re-fanning."""
-        if self.shard is None or body.get("_shard_local"):
-            return []
-        try:
-            r = self.shard.bus_call(
-                "dir_fanout",
-                {"kind": kind, "body": dict(body, _shard_local=True)})
-        except rpc.RpcError:
-            return []
-        return [x for x in (r or {}).get("replies", []) if x]
-
-    # -- bus-served handlers (arrive from other shards / the directory)
-
-    def _h_has_actor(self, body: dict, conn):
-        with self.lock:
-            return {"have": body["actor_id"] in self.actors}
-
-    def _h_xshard_obj_lookup(self, body: dict, conn):
-        """Pin-free meta service for another shard's consumers; pending
-        ids register a sealed-watch pushed from _on_sealed."""
-        watcher = body.get("watcher")
-        metas = {}
-        with self.lock:
-            for oid in body["ids"]:
-                e = self.objects.get(oid)
-                if e is None:
-                    continue
-                if e.state in (SEALED, SPILLED) or e.inline is not None \
-                        or e.owner_resident:
-                    meta = self._meta_for(e, remote=True, pin=False)
-                    if meta[0] != "lost":
-                        metas[oid] = meta
-                        continue
-                if watcher is not None:
-                    self._xshard_watch.setdefault(oid, set()).add(watcher)
-        return {"metas": metas}
-
-    def _h_xshard_sealed(self, body: dict, conn):
-        with self.lock:
-            self._xshard_meta_put(body["object_id"], body["meta"])
-            self._on_sealed(body["object_id"])
-        self.dispatch_event.set()
-        return None
-
-    def _h_xshard_obj_ref(self, body: dict, conn):
-        client_id = body.get("client_id")
-        op = body["op"]
-        with self.lock:
-            for oid in body["ids"]:
-                e = self.objects.get(oid)
-                if e is None:
-                    continue
-                if op == "add_ref":
-                    e.refcount += 1
-                elif op == "del_ref":
-                    e.refcount -= 1
-                    self._maybe_free(e)
-                elif op == "add_borrow" and client_id:
-                    e.borrowers.add(client_id)
-                elif op == "del_borrow" and client_id:
-                    e.borrowers.discard(client_id)
-                    self._maybe_free(e)
-        return None
-
-    def _h_xshard_client_gone(self, body: dict, conn):
-        """A client hosted on another shard disconnected: clear its
-        borrower marks and direct-watcher registrations here."""
-        client_id = body["client_id"]
-        with self.lock:
-            for e in self.objects.values():
-                if client_id in e.borrowers:
-                    e.borrowers.discard(client_id)
-                    self._maybe_free(e)
-            for a in self.actors.values():
-                a.direct_watchers.discard(client_id)
-        return None
 
     # --- head FT: write-behind snapshots --------------------------------
 
@@ -1007,7 +811,7 @@ class Head:
         ``tpu_capable`` ones are not (hermetic.worker_jax_env)."""
         if node_id != self.node_id:
             return self._spawn_remote_worker(node_id, tpu_capable)
-        worker_id = self._new_worker_id()
+        worker_id = "worker-" + uuid.uuid4().hex[:8]
         env = self._worker_base_env(tpu_capable)
         env["RAY_TPU_WORKER_ID"] = worker_id
         env["RAY_TPU_SHM"] = f"{self.shm_name}:{self.config.object_store_memory}"
@@ -1088,7 +892,7 @@ class Head:
                              tpu_capable: bool = False) -> WorkerRecord:
         """Ask the node's agent to fork a worker (reference: raylet spawns
         its own workers after the GCS-side lease decision)."""
-        worker_id = self._new_worker_id()
+        worker_id = "worker-" + uuid.uuid4().hex[:8]
         rec = WorkerRecord(worker_id, node_id, None, tpu_capable)
         with self.lock:
             self.workers[worker_id] = rec
@@ -1138,11 +942,6 @@ class Head:
         client_id = info.get("client_id")
         if client_id is None:
             return
-        if self.shard is not None:
-            # Other shards may hold this client's borrows / direct
-            # watches (cross-shard actor calls): broadcast the death.
-            self.shard.bus_cast("dir_client_gone", {
-                "client_id": client_id, "shard": self.shard.index})
         with self.lock:
             self.clients.pop(client_id, None)
             self.client_owner_addrs.pop(client_id, None)
@@ -1488,7 +1287,7 @@ class Head:
         """lock held. Phase-regression sentinel: once per health tick,
         read the cumulative queue_wait/dispatch histograms; if a
         phase's p95 drifted past profiling_regression_factor x the
-        trailing median, PIN the head/shard flamegraph windows covering
+        trailing median, PIN the head's flamegraph windows covering
         this tick so the evidence outlives FIFO eviction."""
         hists = self.task_events.hist_snapshot()
         win = int(now // max(0.5, self.config.profiling_window_s))
@@ -1511,7 +1310,7 @@ class Head:
                 if med > 0 and p95 > med * \
                         self.config.profiling_regression_factor:
                     for key in list(self.cluster_profile):
-                        if key[1] in ("head", "shard") and \
+                        if key[1] == "head" and \
                                 key[2] in (win, win - 1):
                             if key not in self._pinned_windows:
                                 self._pinned_windows.add(key)
@@ -1563,33 +1362,24 @@ class Head:
                     .get("frames_sent", 0)
                     for r in self.rpc_reports.values())
             ing = self.tsdb.ingest
-            # Sharded head: every shard samples its OWN tables, and two
-            # shards' cumulative counters must stay distinct series —
-            # merging them into one would interleave unrelated counter
-            # values. The shard label is bounded by head_shards; a
-            # single-process head keeps the unlabelled pre-shard shape.
-            base = {} if self.shard is None \
-                else {"shard": str(self.shard.index)}
             for name, v in counters.items():
-                ing(f"ray_tpu_{name}_total", base or None, v, now,
-                    "counter")
+                ing(f"ray_tpu_{name}_total", None, v, now, "counter")
             for name, v in gauges.items():
-                ing(f"ray_tpu_{name}", base or None, v, now, "gauge")
-            ing("ray_tpu_rpc_head_frames_total", base or None,
-                head_frames, now, "counter")
+                ing(f"ray_tpu_{name}", None, v, now, "gauge")
+            ing("ray_tpu_rpc_head_frames_total", None, head_frames, now,
+                "counter")
             for where, v in shed.items():
-                ing("ray_tpu_tasks_shed_total",
-                    {**base, "where": where}, v, now, "counter")
+                ing("ray_tpu_tasks_shed_total", {"where": where}, v, now,
+                    "counter")
             for reason, v in deaths.items():
-                ing("ray_tpu_worker_deaths_total",
-                    {**base, "reason": reason}, v, now, "counter")
+                ing("ray_tpu_worker_deaths_total", {"reason": reason}, v,
+                    now, "counter")
             for phase, h in hists.items():
                 for q, metric in ((0.95, "ray_tpu_phase_p95_seconds"),
                                   (0.99, "ray_tpu_phase_p99_seconds")):
                     val = _hist_quantile_dict(h, q)
                     if val is not None:
-                        ing(metric, {**base, "phase": phase}, val,
-                            now, "gauge")
+                        ing(metric, {"phase": phase}, val, now, "gauge")
             # The head's own host is a node too: self-sample load/mem
             # so `ray-tpu top` has node rows even in-process, where no
             # node agent exists to piggyback them on a heartbeat.
@@ -1664,7 +1454,7 @@ class Head:
                 >= self.config.object_leak_sweep_interval_s):
             self._last_leak_sweep = now
             self._leak_sweep(now)
-        # Profiling plane: the head/shard role is in-process — its
+        # Profiling plane: the head role is in-process — its
         # sampler window merges straight into cluster_profile on the
         # health tick (no rpc), and the same tick runs the
         # phase-regression sentinel that pins suspect windows.
@@ -1985,12 +1775,7 @@ class Head:
                                   "specenc": bool(body.get("specenc"))}
             self.dispatch_event.set()
         else:
-            # Sharded head: the router minted an id hashed to this
-            # shard (adopt_meta rides the fd handoff) so that
-            # shard_for(client_id) == its hosting shard everywhere.
-            meta = getattr(conn, "adopt_meta", None)
-            client_id = (meta or {}).get("client_id") \
-                or "driver-" + uuid.uuid4().hex[:8]
+            client_id = "driver-" + uuid.uuid4().hex[:8]
             with self.lock:
                 # Shm-fallback re-register on the same connection: drop the
                 # first registration's entry.
@@ -2019,10 +1804,6 @@ class Head:
             "node_id": rec.node_id if ctype == "worker" else self.node_id,
             "session_dir": self.session_dir,
         }
-        if self.shard is not None:
-            # Only in shard mode: the shards=1 reply stays bit-identical.
-            reply["shard"] = self.shard.index
-            reply["head_shards"] = self.shard.total
         return reply
 
     def _h_oom_pressure(self, body: dict, conn: rpc.Connection):
@@ -2156,10 +1937,7 @@ class Head:
         with the GCS node table, gcs_node_manager.h:49)."""
         from ray_tpu._private.scheduler import NodeEntry, ResourceSet
 
-        node_id = (body.get("node_id")
-                   or (getattr(conn, "adopt_meta", None)
-                       or {}).get("node_id")
-                   or ("node-" + uuid.uuid4().hex[:8]))
+        node_id = body.get("node_id") or ("node-" + uuid.uuid4().hex[:8])
         if body.get("transfer_port"):
             try:
                 peer_ip = conn._sock.getpeername()[0]
@@ -2207,11 +1985,7 @@ class Head:
                     self._try_place_pg(pg)
         conn.peer_info = {"node_agent_for": node_id}
         self.dispatch_event.set()
-        reply = {"node_id": node_id, "session_dir": self.session_dir}
-        if self.shard is not None:
-            reply["shard"] = self.shard.index
-            reply["head_shards"] = self.shard.total
-        return reply
+        return {"node_id": node_id, "session_dir": self.session_dir}
 
     def _h_worker_blocked(self, body: dict, conn):
         """A worker thread is entering a blocking nested get/wait:
@@ -2486,18 +2260,6 @@ class Head:
 
     def _on_sealed(self, object_id: str) -> None:
         """Resolve get/wait waiters; wake dependency-blocked tasks. lock held."""
-        watchers = self._xshard_watch.pop(object_id, None)
-        if watchers and self.shard is not None:
-            # Another shard's consumer asked for this object before it
-            # sealed: push the (pin-free) meta now. Cast — safe under
-            # the lock (cast_buffered serializes and queues).
-            e = self.objects.get(object_id)
-            if e is not None and e.state in (SEALED, SPILLED):
-                meta = self._meta_for(e, remote=True, pin=False)
-                for shard in watchers:
-                    self.shard.bus_cast("dir_fwd_cast", {
-                        "shard": shard, "kind": "xshard_sealed",
-                        "body": {"object_id": object_id, "meta": meta}})
         blocked = self.dep_blocked.pop(object_id, None)
         if blocked:
             self._sealed_woke_task = True
@@ -2529,21 +2291,12 @@ class Head:
 
     def _is_ready(self, object_id: str) -> bool:
         e = self.objects.get(object_id)
-        if e is None:
-            # Another shard's object whose meta the bus delivered.
-            return object_id in self._xshard_metas
-        return e.state in (SEALED, SPILLED)
+        return e is not None and e.state in (SEALED, SPILLED)
 
     def _meta_for(self, entry: ObjectEntry, remote: bool = False,
                   client_id: "str | None" = None,
                   client_node: "str | None" = None,
-                  client_host: "str | None" = None,
-                  pin: bool = True) -> tuple:
-        # pin=False (cross-shard bus lookups only): serve the meta
-        # without read pins or pull-slot accounting — no pin lifecycle
-        # may span shards (there is no cross-shard read_done), so bus
-        # metas ride the unpinned paths (inline copy / owner pointer /
-        # validated p2p read).
+                  client_host: "str | None" = None) -> tuple:
         # Leak-detector input: this entry was fetched (sealed-but-never-
         # read objects past the TTL are suspects; a read clears them).
         entry.reads += 1
@@ -2583,11 +2336,10 @@ class Head:
                 src = self._pick_source(entry, client_node)
                 if src is not None:
                     node_id, off, addr = src
-                    if pin:
-                        entry.read_pins += 1
-                        if client_id:
-                            entry.pin_holders[client_id] = (
-                                entry.pin_holders.get(client_id, 0) + 1)
+                    entry.read_pins += 1
+                    if client_id:
+                        entry.pin_holders[client_id] = (
+                            entry.pin_holders.get(client_id, 0) + 1)
                     # Data-plane "extra": the source arena's identity
                     # (host-colocated readers map it directly) and
                     # whether this source is a relay (a replica, not
@@ -2596,7 +2348,7 @@ class Head:
                     extra = dict(info) if info else {}
                     extra["relay"] = node_id != (entry.location
                                                  or self.node_id)
-                    if pin and client_id and self._pull_counted(
+                    if client_id and self._pull_counted(
                             entry, node_id, client_node, client_host,
                             extra):
                         # Remote bulk pull expected: account the slot
@@ -2798,10 +2550,6 @@ class Head:
         for oid in ids:
             entry = self.objects.get(oid)
             if entry is None:
-                xmeta = self._xshard_metas.get(oid)
-                if xmeta is not None:
-                    metas[oid] = xmeta
-                    continue
                 metas[oid] = ("lost", f"object {oid} unknown (freed?)", False)
             else:
                 metas[oid] = self._meta_for(
@@ -2822,8 +2570,6 @@ class Head:
 
     def _h_get_meta(self, body: dict, conn):
         waiter_id, ids = body["waiter_id"], body["ids"]
-        if self.shard is not None:
-            self._xshard_track(ids)
         with self.lock:
             self._waiter_ids[waiter_id] = list(ids)
             missing = set()
@@ -2868,8 +2614,6 @@ class Head:
 
     def _h_wait(self, body: dict, conn):
         waiter_id, ids, num_returns = body["waiter_id"], body["ids"], body["num_returns"]
-        if self.shard is not None:
-            self._xshard_track(ids)
         with self.lock:
             for i in ids:
                 if not self._is_ready(i):
@@ -2882,8 +2626,6 @@ class Head:
         return None
 
     def _h_wait_check(self, body: dict, conn):
-        if self.shard is not None:
-            self._xshard_track(body["ids"])
         with self.lock:
             for i in body["ids"]:
                 if not self._is_ready(i):
@@ -2900,28 +2642,20 @@ class Head:
         return None
 
     def _h_del_ref(self, body: dict, conn):
-        unknown = []
         with self.lock:
             for oid in body["ids"]:
                 e = self.objects.get(oid)
                 if e is not None:
                     e.refcount -= 1
                     self._maybe_free(e)
-                elif self.shard is not None:
-                    unknown.append(oid)
-        self._xshard_ref_relay("del_ref", unknown, conn)
         return None
 
     def _h_add_ref(self, body: dict, conn):
-        unknown = []
         with self.lock:
             for oid in body["ids"]:
                 e = self.objects.get(oid)
                 if e is not None:
                     e.refcount += 1
-                elif self.shard is not None:
-                    unknown.append(oid)
-        self._xshard_ref_relay("add_ref", unknown, conn)
         return None
 
     def _h_add_borrow(self, body: dict, conn):
@@ -2932,31 +2666,23 @@ class Head:
         client_id = conn.peer_info.get("client_id")
         if not client_id:
             return None
-        unknown = []
         with self.lock:
             for oid in body["ids"]:
                 e = self.objects.get(oid)
                 if e is not None:
                     e.borrowers.add(client_id)
-                elif self.shard is not None:
-                    unknown.append(oid)
-        self._xshard_ref_relay("add_borrow", unknown, conn)
         return None
 
     def _h_del_borrow(self, body: dict, conn):
         client_id = conn.peer_info.get("client_id")
         if not client_id:
             return None
-        unknown = []
         with self.lock:
             for oid in body["ids"]:
                 e = self.objects.get(oid)
                 if e is not None:
                     e.borrowers.discard(client_id)
                     self._maybe_free(e)
-                elif self.shard is not None:
-                    unknown.append(oid)
-        self._xshard_ref_relay("del_borrow", unknown, conn)
         return None
 
     def _release_container_pins(self, ids) -> None:
@@ -3472,10 +3198,7 @@ class Head:
             # id — push an ask-the-head marker so its get resolves now
             # instead of riding the 5 s stall probe.
             e = self.objects.get(rbody["object_id"])
-            if e is not None and (
-                    e.owner_id in self.client_owner_addrs
-                    or (self.shard is not None
-                        and e.owner_id not in self.clients)):
+            if e is not None and e.owner_id in self.client_owner_addrs:
                 self._client_cast(e.owner_id, "seal_objects", {
                     "objects": [{"object_id": rbody["object_id"],
                                  "remote": True}]})
@@ -3611,7 +3334,6 @@ class Head:
                         key = (actor.spec.namespace, actor.spec.name)
                         if self.named_actors.get(key) == rec.actor_id:
                             self.named_actors.pop(key, None)
-                            self._dir_name_del(key, rec.actor_id)
                     # Retire the dedicated worker and return its
                     # reservation — otherwise failed creations leak
                     # CPUs/chips and a zombie process each.
@@ -3649,17 +3371,6 @@ class Head:
             refusal = self._chips_never_fit(spec.resources)
         if refusal:
             raise rpc.RpcError(refusal)
-        if spec.name and self.shard is not None:
-            # Cluster-wide atomic claim in the directory (outside
-            # self.lock: bus round-trip). The local table below stays
-            # the authority for THIS shard's names; the directory
-            # arbitrates across shards.
-            r = self.shard.bus_call("dir_name_put", {
-                "key": [spec.namespace, spec.name],
-                "actor_id": spec.actor_id, "shard": self.shard.index})
-            if not (r or {}).get("ok"):
-                raise rpc.RpcError(
-                    f"actor name {spec.name!r} already taken")
         with self.lock:
             if spec.name:
                 key = (spec.namespace, spec.name)
@@ -3683,21 +3394,6 @@ class Head:
 
     def _h_submit_actor_task(self, body, conn):
         spec: TaskSpec = spec_from_body(body)
-        if self.shard is not None and not conn.peer_info.get("relay"):
-            with self.lock:
-                known = spec.actor_id in self.actors
-            if not known:
-                # Another shard's actor: forward the whole submit to
-                # its hosting shard (cast — this handler replies None
-                # either way; results flow back over the owner plane /
-                # relayed seal pushes). The owner rides along so the
-                # receiving shard can push to it through the bus.
-                shard = self._locate_actor_shard(spec.actor_id)
-                if shard is not None and shard != self.shard.index:
-                    self.shard.bus_cast("dir_fwd_cast", {
-                        "shard": shard, "kind": "submit_actor_task",
-                        "body": dict(body, _relay_owner=spec.owner_id)})
-                    return None
         self._adopt_evt(spec, body)
         with self.lock:
             if not self._admission_check(spec, conn):
@@ -3738,20 +3434,6 @@ class Head:
         only for ALIVE actors whose worker runs a peer server; the
         owner is registered as a watcher for death revokes."""
         owner_id = conn.peer_info.get("client_id")
-        if (self.shard is not None and owner_id
-                and not conn.peer_info.get("relay")):
-            with self.lock:
-                have = body["actor_id"] in self.actors
-            if not have:
-                # The actor lives on another shard: forward the watch
-                # registration there; the grant/revoke casts come back
-                # relayed through the bus to this owner.
-                shard = self._locate_actor_shard(body["actor_id"])
-                if shard is not None and shard != self.shard.index:
-                    self.shard.bus_cast("dir_fwd_cast", {
-                        "shard": shard, "kind": "actor_direct_info",
-                        "body": dict(body, _relay_owner=owner_id)})
-                    return None
         with self.lock:
             actor = self.actors.get(body["actor_id"])
             if actor is None or not owner_id:
@@ -3889,28 +3571,6 @@ class Head:
         recovery never double-submits (at-least-once only when the
         direct link itself silently ate the push or the ack)."""
         specs = list(body.get("specs") or ())
-        if self.shard is not None and not conn.peer_info.get("relay"):
-            # Items for actors hosted on other shards recover THERE
-            # (forwarded whole, owner riding along); the rest proceed
-            # locally. Locate runs outside self.lock (bus round-trip).
-            keep = []
-            for sbody in specs:
-                spec = spec_from_body(sbody)
-                if spec.actor_id is not None:
-                    with self.lock:
-                        known = spec.actor_id in self.actors
-                    if not known:
-                        shard = self._locate_actor_shard(spec.actor_id)
-                        if shard is not None \
-                                and shard != self.shard.index:
-                            self.shard.bus_cast("dir_fwd_cast", {
-                                "shard": shard,
-                                "kind": "direct_recover",
-                                "body": {"specs": [sbody],
-                                         "_relay_owner": spec.owner_id}})
-                            continue
-                keep.append(sbody)
-            specs = keep
         accepted = []
         with self.lock:
             for sbody in specs:
@@ -4131,15 +3791,6 @@ class Head:
     def _h_kill_actor(self, body, conn):
         with self.lock:
             actor = self.actors.get(body["actor_id"])
-        if actor is None and self.shard is not None \
-                and not body.get("_shard_local"):
-            shard = self._locate_actor_shard(body["actor_id"])
-            if shard is not None and shard != self.shard.index:
-                return self.shard.bus_call("dir_fwd", {
-                    "shard": shard, "kind": "kill_actor",
-                    "body": dict(body, _shard_local=True)})
-        with self.lock:
-            actor = self.actors.get(body["actor_id"])
             if actor is None:
                 return {}
             if body.get("no_restart", True):
@@ -4160,7 +3811,6 @@ class Head:
                     key = (actor.spec.namespace, actor.spec.name)
                     if self.named_actors.get(key) == body["actor_id"]:
                         self.named_actors.pop(key, None)
-                        self._dir_name_del(key, body["actor_id"])
             rec = self.workers.get(actor.worker_id) if actor.worker_id else None
             if rec is not None and rec.expected_exit is None:
                 rec.expected_exit = ("intended_kill",
@@ -4192,13 +3842,8 @@ class Head:
     def _h_list_named_actors(self, body, conn):
         """Names of live named actors (reference:
         util/__init__.py:29 list_named_actors)."""
-        if self.shard is not None:
-            # The directory's claim table is the cluster-wide view.
-            r = self.shard.bus_call("dir_name_list", {})
-            names = [tuple(k) for k in (r or {}).get("names", [])]
-        else:
-            with self.lock:
-                names = list(self.named_actors)
+        with self.lock:
+            names = list(self.named_actors)
         if body.get("all_namespaces"):
             return {"actors": [
                 {"namespace": ns, "name": name}
@@ -4218,15 +3863,6 @@ class Head:
                     "cls_func_id": actor.spec.cls_func_id,
                     "max_concurrency": actor.spec.max_concurrency,
                 }
-        if self.shard is not None and not body.get("_shard_local"):
-            # Another shard may hold the name: the directory knows.
-            r = self.shard.bus_call("dir_name_get", {"key": list(key)})
-            shard = (r or {}).get("shard")
-            if shard is not None and shard != self.shard.index:
-                self._xshard_actors[r["actor_id"]] = shard
-                return self.shard.bus_call("dir_fwd", {
-                    "shard": shard, "kind": "get_named_actor",
-                    "body": dict(body, _shard_local=True)})
         raise rpc.RpcError(f"no actor named {body['name']!r}")
 
     def _drain_actor_queue(self, actor: ActorRecord) -> None:
@@ -4313,11 +3949,6 @@ class Head:
                     total[k] = total.get(k, 0) + v
                 for k, v in n.available.to_dict().items():
                     avail[k] = avail.get(k, 0) + v
-        for r in self._xshard_fanout("cluster_resources", body):
-            for k, v in (r.get("total") or {}).items():
-                total[k] = total.get(k, 0) + v
-            for k, v in (r.get("available") or {}).items():
-                avail[k] = avail.get(k, 0) + v
         return {"total": total, "available": avail}
 
     def _h_profile_result(self, body, conn):
@@ -4448,9 +4079,7 @@ class Head:
         """Continuous-profiling state query (util.state.cluster_profile
         / `ray-tpu profile`): the bounded cluster profile table,
         filtered by role/node/window, plus GIL-starvation exemplars and
-        plane counters. Sharded head: each shard contributes its own
-        table through the directory fanout — window records keep their
-        (node, role) identity so the merged view stays attributable."""
+        plane counters."""
         role = body.get("role")
         node = body.get("node")
         window = body.get("window")
@@ -4475,11 +4104,6 @@ class Head:
                 "stats": dict(self.profile_stats),
                 "window_s": self.config.profiling_window_s,
             }
-        for rep in self._xshard_fanout("cluster_profile", body):
-            out["windows"].extend(rep.get("windows") or ())
-            out["gil_exemplars"].extend(rep.get("gil_exemplars") or ())
-            for k, v in (rep.get("stats") or {}).items():
-                out["stats"][k] = out["stats"].get(k, 0) + v
         return out
 
     def _h_get_nodes(self, body, conn):
@@ -4504,8 +4128,6 @@ class Head:
                     }
                     for n in self.scheduler.nodes.values()
                 ]
-        for r in self._xshard_fanout("get_nodes", body):
-            nodes.extend(r.get("nodes") or [])
         return {"nodes": nodes}
 
     def _h_list_tasks(self, body, conn):
@@ -4532,8 +4154,6 @@ class Head:
                              or t.get("worker_id") == worker_id)]
             else:
                 recs = list(self.tasks.values())
-        for r in self._xshard_fanout("list_tasks", body):
-            recs.extend(r.get("tasks") or [])
         limit = body.get("limit", 1000)
         return {"tasks": recs[-limit:]}
 
@@ -4562,9 +4182,6 @@ class Head:
             else:
                 rows = [self._actor_row(a)
                         for a in self.actors.values()]
-        if actor_id is None or not rows:
-            for r in self._xshard_fanout("list_actors", body):
-                rows.extend(r.get("actors") or [])
         return {"actors": rows}
 
     def _h_list_placement_groups(self, body, conn):
@@ -4580,8 +4197,6 @@ class Head:
                     }
                     for pg in self.pgs.values()
                 ]
-        for r in self._xshard_fanout("list_placement_groups", body):
-            pgs.extend(r.get("placement_groups") or [])
         return {"placement_groups": pgs}
 
     def _object_node(self, e: ObjectEntry) -> str:
@@ -4648,9 +4263,6 @@ class Head:
             else:
                 rows = [self._object_row(e, attribution)
                         for e in self.objects.values()]
-        if object_id is None or not rows:
-            for r in self._xshard_fanout("list_objects", body):
-                rows.extend(r.get("objects") or [])
         if object_id is not None:
             return {"objects": rows}
         limit = int(body.get("limit", 1_000_000))
@@ -4703,10 +4315,6 @@ class Head:
                 else None
             chain = self._lineage_chain(oid)
         if row is None and "task" not in chain:
-            # Not ours: the owning shard has the row + lineage.
-            for r in self._xshard_fanout("get_object", body):
-                if r.get("object"):
-                    return r
             return {"object": None}
         out = row or {"object_id": oid, "state": "FREED"}
         out["lineage"] = chain
@@ -4724,8 +4332,6 @@ class Head:
                     }
                     for w in self.workers.values()
                 ]
-        for r in self._xshard_fanout("list_workers", body):
-            workers.extend(r.get("workers") or [])
         return {"workers": workers}
 
     def _h_log_index(self, body, conn):
@@ -4788,11 +4394,6 @@ class Head:
 
         def _exit():
             time.sleep(0.5)
-            if self.shard is not None:
-                # Whole-cluster stop: the directory tears every shard
-                # down (including this one) with recorded intent.
-                self.shard.bus_cast("dir_stop", {})
-                time.sleep(10)  # the shard_stop cast exits us first
             self.shutdown()
             os._exit(0)
 
@@ -4886,10 +4487,6 @@ class Head:
     def _h_store_stats(self, body, conn):
         with self.lock:
             stats = self._store_stats_locked()
-        for r in self._xshard_fanout("store_stats", body):
-            for k, v in r.items():
-                if isinstance(v, (int, float)):
-                    stats[k] = stats.get(k, 0) + v
         return stats
 
     def _h_memory_summary(self, body, conn):
@@ -4946,25 +4543,6 @@ class Head:
                 "num_entries": len(self.objects),
                 "total_bytes": sum(v["bytes"] for v in by_state.values()),
             }
-        for r in self._xshard_fanout("memory_summary", body):
-            # Censuses/suspects concat; directory counters sum; nested
-            # node/state groups merge per bucket.
-            out["groups"].update(r.get("groups") or {})
-            out["census_clients"].update(r.get("census_clients") or {})
-            out["leak_suspects"].extend(r.get("leak_suspects") or [])
-            for node, states in (r.get("by_node") or {}).items():
-                b = out["by_node"].setdefault(node, {})
-                for st, s in states.items():
-                    m = b.setdefault(st, {"count": 0, "bytes": 0})
-                    m["count"] += s.get("count", 0)
-                    m["bytes"] += s.get("bytes", 0)
-            for st, s in (r.get("by_state") or {}).items():
-                m = out["by_state"].setdefault(st,
-                                               {"count": 0, "bytes": 0})
-                m["count"] += s.get("count", 0)
-                m["bytes"] += s.get("bytes", 0)
-            out["num_entries"] += r.get("num_entries", 0)
-            out["total_bytes"] += r.get("total_bytes", 0)
         return out
 
     def _h_task_events(self, body, conn):
@@ -4976,13 +4554,7 @@ class Head:
     def _h_get_trace(self, body, conn):
         """One causal trace tree, full span detail (util.state.get_trace,
         `ray-tpu trace <id>`, dashboard /api/traces/<id>)."""
-        trace = self.traces.get(body["trace_id"])
-        if trace is None:
-            # A trace assembles on the shard its owner registered with.
-            for r in self._xshard_fanout("get_trace", body):
-                if r.get("trace") is not None:
-                    return r
-        return {"trace": trace}
+        return {"trace": self.traces.get(body["trace_id"])}
 
     def _h_list_traces(self, body, conn):
         """Retained trace summaries, newest first; exemplars_only skips
@@ -4991,8 +4563,6 @@ class Head:
         traces = self.traces.list(
             limit=limit,
             exemplars_only=bool(body.get("exemplars_only")))
-        for r in self._xshard_fanout("list_traces", body):
-            traces.extend(r.get("traces") or [])
         return {"traces": traces[:limit]}
 
     def _h_report_metrics(self, body, conn):
@@ -5028,54 +4598,24 @@ class Head:
     def _h_get_metrics(self, body, conn):
         with self.lock:
             metrics = dict(self.metrics)
-        for r in self._xshard_fanout("get_metrics", body):
-            metrics.update(r.get("metrics") or {})
         return {"metrics": metrics}
 
     def _h_query_metrics(self, body, conn):
         """Telemetry-history range query (util.state.query_metrics /
-        `ray-tpu metrics query` / dashboard /api/metrics/query).
-        Sharded head: every shard holds its own store, so replies merge
-        by (name, labels) — same-keyed series from different shards
-        concatenate their buckets in time order."""
-        from ray_tpu._private import tsdb as tsdb_mod
-
+        `ray-tpu metrics query` / dashboard /api/metrics/query)."""
         series = [] if self.tsdb is None else self.tsdb.query(
             body.get("name") or "", body.get("labels"),
             body.get("start"), body.get("end"), body.get("step"))
-        for r in self._xshard_fanout("query_metrics", body):
-            series.extend(r.get("series") or [])
-        merged: dict[tuple, dict] = {}
-        for s in series:
-            key = (s["name"], tsdb_mod.label_key(s.get("labels")))
-            cur = merged.get(key)
-            if cur is None:
-                merged[key] = s
-            else:
-                cur["points"] = sorted(
-                    cur["points"] + s["points"], key=lambda b: b[0])
-        return {"series": list(merged.values()),
-                "enabled": self.tsdb is not None}
+        return {"series": series, "enabled": self.tsdb is not None}
 
     def _h_list_alerts(self, body, conn):
         """Alert-table read (util.state.list_alerts / `ray-tpu alerts`
         / dashboard /api/alerts): active (pending+firing) records,
-        optionally the resolved history, plus engine counters. Each
-        shard evaluates its own rules over its own store; rows carry
-        the rule name so merged views stay attributable."""
+        optionally the resolved history, plus engine counters."""
         include_history = bool(body.get("history"))
         alerts = [] if self.alerts is None \
             else self.alerts.list(include_history)
         stats = {} if self.alerts is None else self.alerts.stats()
-        for r in self._xshard_fanout("list_alerts", body):
-            alerts.extend(r.get("alerts") or [])
-            for k, v in (r.get("stats") or {}).items():
-                if isinstance(v, (int, float)):
-                    stats[k] = stats.get(k, 0) + v
-                elif isinstance(v, dict):
-                    mine = stats.setdefault(k, {})
-                    for sk, sv in v.items():
-                        mine[sk] = mine.get(sk, 0) + sv
         return {"alerts": alerts, "stats": stats,
                 "enabled": self.alerts is not None}
 
@@ -5118,11 +4658,6 @@ class Head:
                     {k: r.get(k) for k in summary_keys if r.get(k)
                      is not None}
                     for r in rows[-limit:]]
-        if wid is None or not reports:
-            # Other shards' tables + the directory's own shard-death
-            # reports (appended by its fanout handler).
-            for r in self._xshard_fanout("list_crash_reports", body):
-                reports.extend(r.get("reports") or [])
         return {"reports": reports}
 
     def _h_get_task_events(self, body, conn):
@@ -5140,9 +4675,6 @@ class Head:
             task_ids=body.get("task_ids"))
         with self.lock:
             offsets = dict(self.clock_offsets)
-        for r in self._xshard_fanout("get_task_events", body):
-            events.extend(r.get("events") or [])
-            offsets.update(r.get("clock_offsets") or {})
         return {"events": events, "clock_offsets": offsets,
                 "head_node_id": self.node_id}
 
@@ -5181,8 +4713,6 @@ class Head:
         with self.lock:
             buf, self._owned_freed_buf = self._owned_freed_buf, {}
         for owner_id, ids in buf.items():
-            if owner_id not in self.clients and self.shard is None:
-                continue
             self._client_cast(owner_id, "owned_freed", {"ids": ids})
 
     def _dispatch_once_locked(self) -> None:
@@ -6343,7 +5873,6 @@ class Head:
                 key = (actor.spec.namespace, actor.spec.name)
                 if self.named_actors.get(key) == rec.actor_id:
                     self.named_actors.pop(key, None)
-                    self._dir_name_del(key, rec.actor_id)
             self._wal_append(("actor_dead", rec.actor_id))
             self._mark_dirty()
 
@@ -6442,56 +5971,6 @@ class Head:
                   "dropped_total": 0}
         out["alerts"] = self.alerts.stats() if self.alerts is not None \
             else {}
-        out["head_shards"] = 1 if self.shard is None else self.shard.total
-        for r in self._xshard_fanout("runtime_stats", body):
-            # Numeric merge: counters/gauges/deaths/sheds sum; per-
-            # client rpc maps concat (client ids are disjoint between
-            # shards by construction of the owner hash).
-            for sect in ("counters", "gauges", "tasks_shed",
-                         "worker_deaths"):
-                for k, v in (r.get(sect) or {}).items():
-                    if isinstance(v, (int, float)):
-                        out[sect][k] = out[sect].get(k, 0) + v
-            rrpc = r.get("rpc") or {}
-            out["rpc"]["clients"].update(rrpc.get("clients") or {})
-            out["rpc"]["total_head_frames"] += rrpc.get(
-                "total_head_frames", 0)
-            out["rpc"]["clock_offsets"].update(
-                rrpc.get("clock_offsets") or {})
-            out["pressured_nodes"].update(r.get("pressured_nodes") or {})
-            for path, n in ((r.get("transfers") or {}).get("bytes")
-                            or {}).items():
-                out["transfers"]["bytes"][path] = \
-                    out["transfers"]["bytes"].get(path, 0) + n
-            for path, n in ((r.get("transfers") or {}).get("host_copies")
-                            or {}).items():
-                out["transfers"]["host_copies"][path] = \
-                    out["transfers"]["host_copies"].get(path, 0) + n
-            # Profiling plane: counters sum; per-(role,frame) self-time
-            # sums (shards report role="shard", so the merged top-N
-            # attributes shard CPU separately from the parent head's).
-            rprof = r.get("profiling") or {}
-            for k in ("windows", "windows_total", "dropped_windows",
-                      "gil_exemplars", "pinned", "samples_total"):
-                out["profiling"][k] = (out["profiling"].get(k, 0)
-                                       + rprof.get(k, 0))
-            for role, frames in (rprof.get("self_time") or {}).items():
-                mine = out["profiling"]["self_time"].setdefault(role, {})
-                for frame, n in frames.items():
-                    mine[frame] = mine.get(frame, 0) + n
-            # Telemetry + alert planes: per-shard stores/engines, so
-            # occupancy counters sum and the firing-by-severity map
-            # merges per key.
-            for k, v in (r.get("telemetry") or {}).items():
-                if isinstance(v, (int, float)):
-                    out["telemetry"][k] = out["telemetry"].get(k, 0) + v
-            for k, v in (r.get("alerts") or {}).items():
-                if isinstance(v, (int, float)):
-                    out["alerts"][k] = out["alerts"].get(k, 0) + v
-                elif isinstance(v, dict):
-                    mine = out["alerts"].setdefault(k, {})
-                    for sk, sv in v.items():
-                        mine[sk] = mine.get(sk, 0) + sv
         return out
 
     def _profiling_stats_locked(self) -> dict:
@@ -6609,9 +6088,7 @@ class Head:
         # The owner's get() waits LOCALLY for results it expects: push
         # the error seal to its owner plane so that wait resolves
         # without the stall-probe fallback.
-        if (entry.owner_id in self.client_owner_addrs
-                or (self.shard is not None
-                    and entry.owner_id not in self.clients)):
+        if entry.owner_id in self.client_owner_addrs:
             self._client_cast(entry.owner_id, "seal_objects", {
                 "objects": [{"object_id": object_id, "payload": payload,
                              "is_error": True}]})

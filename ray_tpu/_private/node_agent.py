@@ -180,10 +180,6 @@ class NodeAgent:
         )
         self.node_id = reply["node_id"]
         self.session_dir = reply["session_dir"]
-        # Sharded head: the router minted our node_id for the shard that
-        # owns us; remember which for diagnostics/census labelling.
-        self.head_shard = int(reply.get("shard", 0))
-        self.head_shards = int(reply.get("head_shards", 1))
         # Per-node worker log + crash-forensics dir: workers arm their
         # crash file/beacon here (RAY_TPU_CRASH_DIR at spawn) and the
         # reaper reads the evidence post-mortem.

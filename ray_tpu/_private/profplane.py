@@ -13,8 +13,8 @@ Architecture (reference analogue: the dashboard's py-spy-based
 profile_manager.py, made always-on the way the reference's
 TaskEventBuffer made task events always-on):
 
-  * One ``ContinuousSampler`` per process, role-tagged (head / shard /
-    agent / worker / driver). A single daemon thread samples every
+  * One ``ContinuousSampler`` per process, role-tagged (head / agent /
+    worker / driver). A single daemon thread samples every
     OTHER thread's stack via sys._current_frames() at
     ``RAY_TPU_PROFILE_HZ``, but only for ``RAY_TPU_PROFILE_DUTY_CYCLE``
     of each one-second cycle — steady-state cost is duty * hz stack

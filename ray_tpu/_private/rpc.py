@@ -804,46 +804,6 @@ class Server:
         if self._on_close:
             self._on_close(conn)
 
-    def adopt_socket(self, sock: socket.socket,
-                     first_frame: "bytes | None" = None,
-                     adopt_meta: "dict | None" = None) -> Connection:
-        """Adopt an already-accepted socket as if this server had
-        accepted it — the sharded head's router accepts on the
-        advertised address, reads ONE frame to pick a shard, then hands
-        the fd over SCM_RIGHTS; the shard re-enters it here. The frame
-        the router consumed is replayed through the normal dispatch
-        path so the peer sees exactly one handler pass, and
-        ``adopt_meta`` (pre-assigned client id, routed identity) rides
-        on the connection for the registration handler. Safe against
-        reordering because registration is a synchronous call: the peer
-        sends nothing else until the replayed frame's reply arrives."""
-        try:
-            name = str(sock.getpeername())
-        except OSError:
-            name = "adopted"
-        conn = Connection(sock, self._handler, self._remove, name=name)
-        if adopt_meta:
-            conn.adopt_meta = adopt_meta
-        with self._lock:
-            self.connections.append(conn)
-        if self._on_connect:
-            self._on_connect(conn)
-        if first_frame:
-            def _replay(frame=first_frame, conn=conn):
-                try:
-                    if frame and frame[0] == wirefmt.WIRE_MAGIC:
-                        kind, msg_id, payload = wirefmt.decode_frame(frame)
-                    else:
-                        kind, msg_id, payload = pickle.loads(frame)
-                except Exception:
-                    conn.close()
-                    return
-                conn._dispatch(kind, msg_id, payload)
-
-            threading.Thread(target=_replay, daemon=True,
-                             name="rpc-adopt").start()
-        return conn
-
     def stop(self) -> None:
         self._stopped.set()
         try:

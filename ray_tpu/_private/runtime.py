@@ -211,10 +211,6 @@ class CoreRuntime:
         self.client_id = reg["client_id"]
         self.node_id = reg["node_id"]
         self.session_dir = reg["session_dir"]
-        # Sharded head (head_shards.py): which dispatch shard this
-        # client landed on. 0/1 under a plain single-process head.
-        self.head_shard = int(reg.get("shard", 0))
-        self.head_shards = int(reg.get("head_shards", 1))
         if reg["shm_name"] is not None:
             try:
                 self.shm = ShmClient(reg["shm_name"], reg["shm_capacity"])
@@ -547,8 +543,6 @@ class CoreRuntime:
                 self.client_id = reg["client_id"]
                 self.node_id = reg["node_id"]
                 self.session_dir = reg["session_dir"]
-                self.head_shard = int(reg.get("shard", 0))
-                self.head_shards = int(reg.get("head_shards", 1))
                 self._head_specenc = bool(reg.get("specenc"))
                 conn.wire_binary = (
                     reg.get("wire") == self._wire_version() != 0)
@@ -559,10 +553,8 @@ class CoreRuntime:
                 self._fn_ids.clear()
                 self.conn = conn
                 if self._direct is not None:
-                    # Grants from the old head (or a dead shard) are
-                    # void: fall back to head routing until the new one
-                    # re-grants (sharded head: the router may have
-                    # landed us on a DIFFERENT shard).
+                    # Grants from the old head are void: fall back to
+                    # head routing until the new one re-grants.
                     self._direct.on_reconnect()
                 print("ray_tpu: driver re-registered with restarted head",
                       flush=True)

@@ -40,28 +40,6 @@ def _fnv1a(s: str) -> int:
     return h
 
 
-def split_shard_resources(base: dict, index: int, total: int) -> dict:
-    """One head shard's slice of the box (sharded head,
-    head_shards.py): CPUs floor-divided with the remainder to the low
-    shard indexes (never below 1 — a shard must be able to run a
-    worker), custom resources divided evenly. A node with TPU chips is
-    never sharded (ShardDirectory refuses it). node:* keys are
-    dropped — each shard's Head mints its own node identity."""
-    out: dict = {}
-    for key, val in (base or {}).items():
-        if key.startswith("node:"):
-            continue
-        if key == "CPU":
-            n = int(val)
-            share = n // total + (1 if index < n % total else 0)
-            out["CPU"] = float(max(1, share))
-        elif key == "memory":
-            out["memory"] = float(val) / total
-        else:
-            out[key] = float(val) / total
-    return out
-
-
 def _fp(v: float) -> int:
     return round(v * GRANULARITY)
 

@@ -26,9 +26,6 @@ Storage shape (a miniature Gorilla/Prometheus-TSDB, minus compression
     into one ``(other series)`` catch-all and a dropped counter
     increments — a label flood must not melt the head (the classic
     self-inflicted monitoring outage rtlint RT-M002 exists to prevent).
-  * under ``RAY_TPU_HEAD_SHARDS>1`` each shard keeps its own store;
-    range queries fan out over the PR 17 shard bus and merge, so no
-    shard ships points to another except at query time.
 
 Kill switch: ``RAY_TPU_TSDB_ENABLED=0`` — no store, no sampling, the
 query surface answers empty.

@@ -69,12 +69,10 @@ def init(
                 )
             address = env_addr
         if address is None:
-            # create_head: a plain Head at head_shards==1, the router +
-            # shard-process directory above (see _private/head_shards.py).
-            from ray_tpu._private.head_shards import create_head
+            from ray_tpu._private.gcs import Head
 
-            head = create_head(cfg, num_cpus=num_cpus,
-                               num_tpus=num_tpus, resources=resources)
+            head = Head(cfg, num_cpus=num_cpus, num_tpus=num_tpus,
+                        resources=resources)
             rt = CoreRuntime(head.address, client_type="driver")
             worker_context.set_runtime(rt, head)
             if log_to_driver:

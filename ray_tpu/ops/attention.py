@@ -622,46 +622,8 @@ def _flash_bwd_rule(causal, block_q, block_k, res, g):
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def flash_attention_jax(q, k, v, *, causal: bool = True,
-                        block_q: int = 512, block_k: int = 512):
-    """jax's bundled Pallas TPU flash kernel (fwd + dq/dkv backwards),
-    called through its public API. Shapes here are [B,T,H,D]; the
-    kernel wants [B,H,T,D]. TPU only (the bundled kernel has no
-    interpret path wired through this API), and only for sequence
-    lengths its tiles divide: a caller that asked for this kernel by
-    name gets it or an error, never another implementation."""
-    b, tq, h, d = q.shape
-    tk = k.shape[1]
-    bq = min(block_q, tq)
-    bk = min(block_k, tk)
-    platform = jax.devices()[0].platform
-    if platform != "tpu":
-        raise NotImplementedError(
-            f"attention impl 'flash_jax' runs on 'tpu' only, not on "
-            f"platform {platform!r}; use impl='auto'")
-    if tq % bq or tk % bk:
-        raise ValueError(
-            f"attention impl 'flash_jax': seq lens ({tq},{tk}) must "
-            f"divide blocks ({bq},{bk}); use impl='auto'")
-    from jax.experimental.pallas.ops.tpu import flash_attention as fa
-    sizes = fa.BlockSizes(
-        block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
-        block_q_major_dkv=bq, block_k_major_dkv=bk,
-        block_k_dkv=bk, block_q_dkv=bq,
-        block_k_major_dq=bk, block_k_dq=bk, block_q_dq=bq,
-    )
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    o = fa.flash_attention(qt, kt, vt, causal=causal,
-                           sm_scale=1.0 / math.sqrt(d),
-                           block_sizes=sizes)
-    return o.transpose(0, 2, 1, 3).astype(q.dtype)
-
-
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
-    """Dispatch: 'reference' | 'blockwise' | 'flash' | 'flash_jax' |
-    'auto'.
+    """Dispatch: 'reference' | 'blockwise' | 'flash' | 'auto'.
 
     'auto' at Tk <= 1024 materialises the scores: causal self-attention
     whose T is a whole number, at least two, of query blocks (a quarter
@@ -677,8 +639,6 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
         return blockwise_attention(q, k, v, causal=causal)
     if impl == "flash":
         return flash_attention(q, k, v, causal)
-    if impl == "flash_jax":
-        return flash_attention_jax(q, k, v, causal=causal)
     tq, tk = q.shape[1], k.shape[1]
     on_tpu = jax.devices()[0].platform == "tpu"
     # Up to 1024 keys the scores are materialised by XLA, and for causal

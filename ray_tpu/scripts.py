@@ -30,9 +30,10 @@ def _connect(address: str) -> None:
 def cmd_start(args) -> int:
     if args.head:
         from ray_tpu._private.config import Config
-        from ray_tpu._private.head_shards import create_head
+        from ray_tpu._private.gcs import Head
 
-        cfg = Config()
+        # RAY_TPU_<FIELD> applies here as it does in ray_tpu.init().
+        cfg = Config().apply_overrides()
         cfg.head_host = args.host
         cfg.head_port = args.port
         if args.object_store_memory:
@@ -45,7 +46,7 @@ def cmd_start(args) -> int:
             # Cross-node head HA: durable state in a shared store; a
             # fresh head anywhere restores it (redis_store_client.h:111).
             cfg.gcs_external_store = args.external_store
-        head = create_head(
+        head = Head(
             cfg, num_cpus=args.num_cpus, num_tpus=args.num_tpus,
             resources=json.loads(args.resources) if args.resources else None)
         host, port = head.address
@@ -771,7 +772,7 @@ def _counter_rates(series: "list[dict]") -> "list[float]":
 
 def cmd_top(args) -> int:
     """Live cluster view (`ray-tpu top`): one refreshing screen with
-    nodes, shards, tasks/s (with history sparkline from the embedded
+    nodes, tasks/s (with history sparkline from the embedded
     tsdb), phase p95s, firing alerts, and the hottest flamegraph leaf
     from the continuous profiler — the "is the cluster healthy right
     now" answer without a dashboard deployment."""
@@ -825,7 +826,6 @@ def _render_top(snap: dict, rate_q: dict, p95_q: dict, load_q: dict,
     print(f"ray-tpu top — {_time.strftime('%H:%M:%S')}")
     print(f"nodes {g.get('nodes_alive', '?')} "
           f"(pressured {g.get('mem_pressured_nodes', 0)})   "
-          f"head shards {snap.get('head_shards', 1)}   "
           f"workers {g.get('workers_alive', '?')}   "
           f"actors {g.get('actors_alive', '?')}   "
           f"pending {g.get('tasks_pending', 0)}")
@@ -1195,7 +1195,7 @@ def main(argv: list[str] | None = None) -> int:
              "export collapsed stacks / speedscope)")
     s.add_argument("--address", required=True)
     s.add_argument("--role", default=None,
-                   choices=["head", "shard", "agent", "worker", "driver"])
+                   choices=["head", "agent", "worker", "driver"])
     s.add_argument("--node", default=None, help="node id filter")
     s.add_argument("--window", type=int, default=None,
                    help="window index filter (floor(ts / window_s))")

@@ -330,8 +330,8 @@ def cluster_profile(*, role: "str | None" = None,
                     window: "int | None" = None) -> dict:
     """The continuous profiling plane's merged cluster table
     (`ray-tpu profile` backs onto this): every process samples its own
-    threads on a duty cycle from boot (head, dispatch shards, node
-    agents, workers, drivers — role-tagged), window summaries ride the
+    threads on a duty cycle from boot (head, node agents, workers,
+    drivers — role-tagged), window summaries ride the
     amortized rpc_report/heartbeat casts, and the head merges them into
     bounded windows keyed (node, role, window index).
 
@@ -363,9 +363,7 @@ def query_metrics(name: str, labels: "dict | None" = None,
 
     Returns ``{"series": [{"name", "labels", "kind", "resolution_s",
     "points"}], "enabled": bool}``; each point is a
-    ``[ts, min, max, sum, count, last]`` aggregate bucket. Under a
-    sharded head every shard's store is queried and same-keyed series
-    merge. Empty when ``RAY_TPU_TSDB_ENABLED=0``."""
+    ``[ts, min, max, sum, count, last]`` aggregate bucket. Empty when ``RAY_TPU_TSDB_ENABLED=0``."""
     body: dict = {"name": name}
     if labels:
         body["labels"] = dict(labels)

@@ -1,13 +1,11 @@
-"""Chip placement under the DEFAULT head (no RAY_TPU_HEAD_SHARDS).
+"""Chip placement.
 
 A fake ``num_tpus`` stands in for the chips: nothing here touches
 libtpu, so what is checked is the runtime's half — every request that
 fits is placed within seconds, concurrent holders see disjoint chip
 ids, a holder's chips come back only when its process is gone, chipless
 workers are pinned to the CPU, and a request that can never fit raises
-instead of waiting forever. (On a multi-core box the head used to split
-into shards that each rebuilt a chip pool from id 0; none of these
-placed.)
+instead of waiting forever.
 """
 
 from __future__ import annotations
@@ -45,8 +43,7 @@ def _chips(env: dict) -> list[int]:
 
 
 @pytest.fixture
-def cluster(request, monkeypatch):
-    monkeypatch.delenv("RAY_TPU_HEAD_SHARDS", raising=False)
+def cluster(request):
     if ray_tpu.is_initialized():
         ray_tpu.shutdown()
     ray_tpu.init(num_cpus=2, num_tpus=request.param,
@@ -128,13 +125,12 @@ def test_impossible_requests_raise(cluster):
     assert ray_tpu.get(env_task.options(num_tpus=2).remote(), timeout=30)
 
 
-def test_chips_on_a_joined_node_place(monkeypatch):
+def test_chips_on_a_joined_node_place():
     """A node agent's chips get a pool of their own (they had none: work
     placed there waited forever)."""
     from tests.test_multinode import _start_agent, _wait_nodes
     from ray_tpu._private.worker_context import get_head
 
-    monkeypatch.delenv("RAY_TPU_HEAD_SHARDS", raising=False)
     if ray_tpu.is_initialized():
         ray_tpu.shutdown()
     ray_tpu.init(num_cpus=2, num_tpus=0,
@@ -159,15 +155,6 @@ def test_chips_on_a_joined_node_place(monkeypatch):
         agent.kill()
         agent.wait(timeout=10)
         ray_tpu.shutdown()
-
-
-def test_head_shards_refuse_a_node_with_chips():
-    if ray_tpu.is_initialized():
-        ray_tpu.shutdown()
-    with pytest.raises(ValueError, match="cannot be combined with TPU"):
-        ray_tpu.init(num_cpus=2, num_tpus=1,
-                     _system_config={"head_shards": 2})
-    assert not ray_tpu.is_initialized()
 
 
 def test_chip_process_env():

@@ -30,7 +30,7 @@ from tools.rtlint import BASELINE_PATH, run_lint
 from tools.rtlint.core import Baseline, Finding, run_passes
 from tools.rtlint.passes import (ALL_PASSES, ClocksPass, FrameBudgetPass,
                                  KnobsPass, LocksPass, MetricsPass,
-                                 ShardBusPass, WirePass)
+                                 WirePass)
 
 
 def seed(tmp_path, files: "dict[str, str]") -> str:
@@ -505,76 +505,6 @@ def test_framebudget_dict_get_is_not_an_edge(tmp_path):
                 self.conn.call("fetch", {})
         '''})
     assert lint(root, FrameBudgetPass) == []
-
-
-# ---------------------------------------------------------------------------
-# RT-F1xx: sharded-head bus discipline
-
-
-_SHARD_DECL = '''
-    DIRECTORY_TABLES = frozenset({
-        "dir_named_actors", "dir_shards", "dir_crash_reports"})
-
-    class ShardDirectory:
-        def _h_dir_name_put(self, body, conn):
-            self.dir_named_actors[tuple(body["key"])] = body["actor_id"]
-'''
-
-
-def test_shardbus_table_reach_outside_directory_flagged(tmp_path):
-    """Shard-side code touching a declared directory-global table is a
-    finding; the same attribute inside ShardDirectory is the owner's
-    legitimate access and stays clean."""
-    root = seed(tmp_path, {
-        "ray_tpu/_private/head_shards.py": _SHARD_DECL,
-        "ray_tpu/_private/gcs.py": '''
-        class Head:
-            def _h_get_named_actor(self, body, conn):
-                # WRONG: only works in-process; must use the bus.
-                return self.shard.directory.dir_named_actors.get(
-                    tuple(body["key"]))
-        '''})
-    found = [f for f in lint(root, ShardBusPass) if f.id == "RT-F101"]
-    assert len(found) == 1
-    assert found[0].path == "ray_tpu/_private/gcs.py"
-    assert "dir_named_actors" in found[0].message
-    assert found[0].symbol == "Head._h_get_named_actor"
-
-
-def test_shardbus_orphan_bus_kind_flagged(tmp_path):
-    """A bus_call kind with no _h_<kind> handler anywhere fails only at
-    runtime on multi-shard topologies — the pass catches it statically;
-    a handled kind and a dynamic (non-literal) kind stay clean."""
-    root = seed(tmp_path, {
-        "ray_tpu/_private/head_shards.py": _SHARD_DECL,
-        "ray_tpu/_private/gcs.py": '''
-        class Head:
-            def _claim(self, key, kind):
-                self.shard.bus_call("dir_name_put", {"key": key})
-                self.shard.bus_call(kind, {})  # dynamic: out of scope
-                self.shard.bus_cast("dir_name_putt", {"key": key})
-        '''})
-    found = [f for f in lint(root, ShardBusPass) if f.id == "RT-F102"]
-    assert len(found) == 1
-    assert "dir_name_putt" in found[0].message
-
-
-def test_shardbus_handle_bus_dispatch_arm_counts_as_handler(tmp_path):
-    """Kinds dispatched by literal comparison inside _handle_bus (the
-    ShardHost fast-path arms) are receivers, not orphans."""
-    root = seed(tmp_path, {
-        "ray_tpu/_private/head_shards.py": _SHARD_DECL + '''
-    class ShardCtx:
-        def relay(self, client_id):
-            self.bus_cast("shard_client_cast", {"client_id": client_id})
-
-    class ShardHost:
-        def _handle_bus(self, kind, body, conn):
-            if kind == "shard_client_cast":
-                return None
-        '''})
-    assert [f for f in lint(root, ShardBusPass)
-            if f.id == "RT-F102"] == []
 
 
 # ---------------------------------------------------------------------------

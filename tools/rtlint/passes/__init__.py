@@ -1,4 +1,4 @@
-"""The seven rtlint passes, in catalog order (docs/INVARIANTS.md)."""
+"""The six rtlint passes, in catalog order (docs/INVARIANTS.md)."""
 
 from tools.rtlint.passes.wire import WirePass
 from tools.rtlint.passes.knobs import KnobsPass
@@ -6,11 +6,9 @@ from tools.rtlint.passes.locks import LocksPass
 from tools.rtlint.passes.clocks import ClocksPass
 from tools.rtlint.passes.metrics import MetricsPass
 from tools.rtlint.passes.framebudget import FrameBudgetPass
-from tools.rtlint.passes.shardbus import ShardBusPass
 
 ALL_PASSES = (WirePass, KnobsPass, LocksPass, ClocksPass, MetricsPass,
-              FrameBudgetPass, ShardBusPass)
+              FrameBudgetPass)
 
 __all__ = ["ALL_PASSES", "WirePass", "KnobsPass", "LocksPass",
-           "ClocksPass", "MetricsPass", "FrameBudgetPass",
-           "ShardBusPass"]
+           "ClocksPass", "MetricsPass", "FrameBudgetPass"]

@@ -79,20 +79,18 @@ _RULE_SERIES_KEYS = {"series", "bad", "total"}
 #                exemplar retention keeps a fixed-size working set)
 #   state      — object lifecycle states (fixed enum in object store)
 #   role       — profiling-plane process roles (fixed enum: head /
-#                shard / agent / worker / driver)
+#                agent / worker / driver)
 #   frame      — ray_tpu_profile_self_hits only: the head folds
 #                self-time to a fixed top-N per role before exposition,
 #                so cardinality is N*roles regardless of code shape
 #   severity   — alert-plane severity: fixed enum (page/warn/info,
 #                alertplane.SEVERITIES), every value pre-registered in
 #                the exposition so cardinality is exactly 3
-#   shard      — head shard index on a sharded head's tsdb self-
-#                samples: bounded by head_shards (single digits)
 ALLOWED_LABELS = {
     "node_id", "node", "reason", "phase", "where", "le", "deployment",
     "model", "pool", "callsite", "peer", "job", "kind", "quantile",
     "trace_id", "name", "direction", "path", "target", "state",
-    "role", "frame", "severity", "shard",
+    "role", "frame", "severity",
 }
 
 _METRIC_CTORS = {"Gauge", "Counter", "Histogram", "Summary"}
