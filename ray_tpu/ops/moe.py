@@ -40,6 +40,29 @@ the fullest expert's assignments over the mean (1.0 is balance).
 The dropless path's parts run under the named scopes of ``SCOPES``
 (inside the model's ``moe``), so a profile says what routing costs
 beyond the experts' arithmetic.
+
+Which rows the dropless path moves, and when (N tokens, A = ``top_k`` x N
+assignments, D the model's width; a test reads this off the gradient's
+jaxpr). A gather INTO expert order reads an [N, D] source at ``order //
+top_k``; a gather by ``inverse`` reads an [A, D] source, ``top_k`` times
+the bytes, which no fast memory holds (on the v5e at OLMoE's sizes: 2.2 ms
+from HBM against 0.4 where XLA keeps the 33-MB source on the chip;
+PERF.md section 5). The block does two of the second kind and no more:
+
+* forward: ``x[order // top_k]`` (dispatch), then ``y[inverse]``
+  (combine, from [A, D]);
+* recompute (under ``remat``): the dispatch's gather and the gate and up
+  matmuls, for ``h``. NOT the down matmul and NOT ``y[inverse]``: the
+  block's end has its own gradient (``_down_and_combine``) that reads
+  ``h`` and never ``y``. The gates' gradient is taken against ``h``:
+  ``d_gate[a] = <y[a], d_out[token(a)]> = <h[a], dh_u[a]>``, ``dh_u``
+  the down matmul's row gradient before the gate, which the backward
+  computes anyway;
+* backward: ``d_out[order // top_k]`` (combine, from [N, D]: the
+  cotangent of row a of ``y`` is ``gate[a]`` times it), then
+  ``g[inverse]`` (dispatch, from [A, D], summed over a token's choices).
+  The gates go to expert order and their gradient back as sorts of A
+  scalars.
 """
 
 from __future__ import annotations
@@ -240,16 +263,32 @@ def _megablox_fwd(lhs, rhs, group_sizes):
     return _megablox_matmul(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
 
 
-def _megablox_bwd(res, g):
-    backend = _megablox()
-    lhs, rhs, group_sizes = res
+def _grouped_matmul_grads(lhs, rhs, group_sizes, g):
+    """Both gradients of ``grouped_matmul(lhs, rhs, group_sizes)`` under
+    the cotangent ``g`` [M, N]: of the rows, ``g`` times each group's
+    matrix transposed [M, K], and of the weights, each group's own
+    ``lhs^T x g`` [G, K, N]. Neither reads the product itself."""
     k, n = rhs.shape[1], rhs.shape[2]
-    dlhs = backend.gmm(g, rhs, group_sizes, lhs.dtype,
-                       _fit(_gmm_tiles(n), n, k), transpose_rhs=True)
-    drhs = backend.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
-                        _fit(_TGMM_TILES, k, n),
-                        num_actual_groups=rhs.shape[0])
-    return dlhs, drhs, np.zeros(group_sizes.shape, jax.dtypes.float0)
+    if _use_megablox(lhs, rhs):
+        backend = _megablox()
+        dlhs = backend.gmm(g, rhs, group_sizes, lhs.dtype,
+                           _fit(_gmm_tiles(n), n, k), transpose_rhs=True)
+        drhs = backend.tgmm(lhs.swapaxes(0, 1), g, group_sizes, rhs.dtype,
+                            _fit(_TGMM_TILES, k, n),
+                            num_actual_groups=rhs.shape[0])
+        return dlhs, drhs
+    dlhs = jax.lax.ragged_dot(g, rhs.swapaxes(1, 2), group_sizes)
+    drhs = jax.lax.ragged_dot_general(
+        lhs, g, group_sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]))
+    return dlhs, drhs
+
+
+def _megablox_bwd(res, g):
+    lhs, rhs, group_sizes = res
+    return (*_grouped_matmul_grads(lhs, rhs, group_sizes, g),
+            np.zeros(group_sizes.shape, jax.dtypes.float0))
 
 
 _megablox_matmul.defvjp(_megablox_fwd, _megablox_bwd)
@@ -288,21 +327,60 @@ _rows_to_experts.defvjp(_rows_to_experts_fwd, _rows_to_experts_bwd)
 
 
 @jax.custom_vjp
-def _rows_to_tokens(y, order, inverse):
-    """y [A, D] in expert order -> assignment order: a permutation, so its
-    transpose is the gather by ``order``."""
-    return y[inverse]
+def _down_and_combine(h, w_down, gates, counts, order, inverse):
+    """The block's end as one function with its own gradient: ``h``
+    [A, F] (``silu(gate) * up``, expert order) times each expert's
+    ``w_down`` [E, F, D], the rows gathered back to token order and
+    summed under ``gates`` [N, top_k] in float32 -> [N, D].
+
+    The gradient is taken in EXPERT order and reads ``h``, never the
+    product ``y``: the cotangent of row a of ``y`` is ``gate[a] *
+    d_out[order[a] // top_k]``, and ``d_gate[a] = <y[a], d_out[token]> =
+    <h[a], dh_u[a]>`` with ``dh_u = d_out[token] x w_down^T``, the row
+    gradient of the down matmul before the gate. So the backward gathers
+    from ``d_out`` [N, D] (as the dispatch's forward does from ``x``),
+    gathers from no [A, D] array, and under remat neither the down
+    matmul nor the gather back is recomputed."""
+    n, top_k = gates.shape
+    with jax.named_scope("moe_experts"):
+        y = grouped_matmul(h, w_down, counts)
+    with jax.named_scope("moe_combine"):
+        rows = y[inverse].reshape(n, top_k, y.shape[-1])
+        out = (rows.astype(jnp.float32) * gates[:, :, None]).sum(axis=1)
+        return out.astype(y.dtype)
 
 
-def _rows_to_tokens_fwd(y, order, inverse):
-    return y[inverse], order
+def _down_and_combine_fwd(h, w_down, gates, counts, order, inverse):
+    return (_down_and_combine(h, w_down, gates, counts, order, inverse),
+            (h, w_down, gates, counts, order, inverse))
 
 
-def _rows_to_tokens_bwd(order, g):
-    return g[order], None, None
+def _permuted(values, to):
+    """``values`` [A] with element i moved to place ``to[i]`` (``to`` a
+    permutation). A sort by ``to``: the TPU sorts 65,536 pairs in a tenth
+    of the time it gathers as many scalars."""
+    return jax.lax.sort((to, values), num_keys=1)[1]
 
 
-_rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+def _down_and_combine_bwd(res, d_out):
+    h, w_down, gates, counts, order, inverse = res
+    top_k = gates.shape[1]
+    with jax.named_scope("moe_combine"):
+        gate = _permuted(gates.reshape(-1), inverse)[:, None]   # [A, 1]
+        g = d_out[order // top_k]                               # [A, D]
+    with jax.named_scope("moe_experts"):
+        hf = h.astype(jnp.float32)
+        dh_u, dw = _grouped_matmul_grads((gate * hf).astype(h.dtype), w_down,
+                                         counts, g)
+        dh_u = dh_u.astype(jnp.float32)
+        d_gate = (hf * dh_u).sum(axis=-1)
+        dh = (gate * dh_u).astype(h.dtype)
+    with jax.named_scope("moe_combine"):
+        d_gates = _permuted(d_gate, order).reshape(gates.shape)
+    return dh, dw, d_gates, None, None, None
+
+
+_down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 
 def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
@@ -337,9 +415,6 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     with jax.named_scope("moe_experts"):
         gate = grouped_matmul(rows, w_gate.astype(dt), counts)
         up = grouped_matmul(rows, w_up.astype(dt), counts)
-        rows = grouped_matmul(jax.nn.silu(gate) * up, w_down.astype(dt),
-                              counts)
-    with jax.named_scope("moe_combine"):
-        rows = _rows_to_tokens(rows, order, inverse).reshape(N, top_k, D)
-        out = (rows.astype(jnp.float32) * gates[:, :, None]).sum(axis=1)
-    return out.astype(dt).reshape(B, S, D), stats
+        h, w_down = jax.nn.silu(gate) * up, w_down.astype(dt)
+    out = _down_and_combine(h, w_down, gates, counts, order, inverse)
+    return out.reshape(B, S, D), stats

@@ -82,24 +82,34 @@ def test_unchunked_loss_accumulation_and_experts_have_their_scopes():
 
 def test_dropless_experts_carry_their_sub_scopes_in_every_pass():
     """The four sub-scopes ``ops/moe.py`` opens inside ``moe`` reach the
-    compiled step's ``op_name``s in the forward pass, the recompute and the
-    backward pass, and the benchmark's rule still gives every one of those
-    instructions to ``moe`` (the innermost name IT knows), so
-    ``step_mlp_ms`` stays whole."""
+    compiled step's ``op_name``s in every pass that has work of theirs,
+    and the benchmark's rule still gives every one of those instructions
+    to ``moe`` (the innermost name IT knows), so ``step_mlp_ms`` stays
+    whole. That is every (sub-scope, pass) pair but ONE: (``moe_combine``,
+    ``recompute``) went when the block took its own backward
+    (``_down_and_combine``), which reads ``h`` and never the experts'
+    output, so the gather back to token order is not run a second time.
+    For the same reason the recompute holds TWO grouped matmuls (gate and
+    up; a ``dot`` each on the CPU), not the forward's three; the backward
+    rule's own instructions (opened under ``moe_experts`` /
+    ``moe_combine`` inside the rule) read ``backward``."""
     from ray_tpu.ops import moe
 
     cfg = models.olmoe_1b_7b(
         n_layers=1, d_model=64, n_heads=4, n_kv_heads=4, d_ff=32,
         n_experts=8, expert_top_k=3, vocab_size=256, max_seq_len=64)
-    names = re.findall(r'op_name="([^"]*)"', _step_text(cfg))
-    found = set()
-    for name in names:
+    found, matmuls = set(), {ps: 0 for ps in scopes.PASSES}
+    for line in _step_text(cfg).splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
         for sub in moe.SCOPES:
-            if f"/{sub}/" in name:
-                assert scopes.classify(name)[0] == "moe", name
-                found.add((sub, scopes.classify(name)[1]))
-    assert found == {(sub, ps) for sub in moe.SCOPES
-                     for ps in scopes.PASSES}, sorted(found)
+            if name and f"/{sub}/" in name.group(1):
+                part, ps = scopes.classify(name.group(1))
+                assert part == "moe", name.group(1)
+                found.add((sub, ps))
+                matmuls[ps] += sub == "moe_experts" and " dot(" in line
+    assert found == {(sub, ps) for sub in moe.SCOPES for ps in scopes.PASSES
+                     } - {("moe_combine", "recompute")}, sorted(found)
+    assert matmuls == {"forward": 3, "recompute": 2, "backward": 6}
     assert not set(moe.SCOPES) & set(scopes.PARTS)
 
 

@@ -226,9 +226,10 @@ def test_dropless_on_an_expert_mesh_is_refused_by_name():
 
 
 def test_grouped_matmul_and_the_row_moves_by_hand():
-    """``grouped_matmul`` is each run of rows times its own matrix, and
-    the two row moves are each other's transposes (their custom gradients
-    equal the gradients jax derives for the plain gathers)."""
+    """``grouped_matmul`` is each run of rows times its own matrix, its
+    two gradients written out (``_grouped_matmul_grads``) are the ones jax
+    derives for ``ragged_dot``, and the dispatch's row move has the
+    gradient jax derives for the plain gather."""
     rng = np.random.default_rng(0)
     sizes = np.array([3, 0, 5, 4], np.int32)
     lhs = jnp.asarray(rng.normal(size=(12, 8)), jnp.float32)
@@ -248,7 +249,233 @@ def test_grouped_matmul_and_the_row_moves_by_hand():
                                * w).sum())(x)
     plain = jax.grad(lambda x: (x[order // k] * w).sum())(x)
     np.testing.assert_allclose(np.asarray(mine), np.asarray(plain), rtol=1e-6)
-    mine = jax.grad(lambda y: (moe._rows_to_tokens(y, order, inverse)
-                               * w).sum())(w * 2)
-    plain = jax.grad(lambda y: (y[inverse] * w).sum())(w * 2)
-    np.testing.assert_allclose(np.asarray(mine), np.asarray(plain), rtol=1e-6)
+    cot = jnp.asarray(rng.normal(size=(12, 6)), jnp.float32)
+    mine = moe._grouped_matmul_grads(lhs, rhs, jnp.asarray(sizes), cot)
+    plain = jax.vjp(lambda a, b: jax.lax.ragged_dot(a, b, jnp.asarray(sizes)),
+                    lhs, rhs)[1](cot)
+    for a, b in zip(mine, plain):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+# -- the block's own backward (``moe._down_and_combine``) ---------------------
+
+BLOCK_E, BLOCK_D, BLOCK_F, BLOCK_B, BLOCK_S = 16, 24, 8, 2, 64
+# router column 0's weight on the tokens' common direction (x[..., 0] = 1)
+ROUTINGS = {"balanced": 0.0, "one expert empty": -50.0,
+            "one expert chosen by over half of the tokens": 3.0}
+
+
+def block_inputs(routing: str, dtype=jnp.float32, seed: int = 0):
+    """(x [B, S, D], router_w, w_gate, w_up, w_down) of a small block.
+    Every token carries 1.0 in its first feature and expert 0's router
+    column weighs it by ``ROUTINGS[routing]``."""
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape) * scale, jnp.float32)
+
+    x = draw(BLOCK_B, BLOCK_S, BLOCK_D).at[:, :, 0].set(1.0)
+    router_w = draw(BLOCK_D, BLOCK_E).at[0].set(0.0)
+    router_w = router_w / jnp.linalg.norm(router_w, axis=0)   # even columns
+    router_w = router_w.at[0, 0].set(ROUTINGS[routing])
+    weights = (draw(BLOCK_E, BLOCK_D, BLOCK_F, scale=0.3),
+               draw(BLOCK_E, BLOCK_D, BLOCK_F, scale=0.3),
+               draw(BLOCK_E, BLOCK_F, BLOCK_D, scale=0.3))
+    return (x.astype(dtype), router_w, *(w.astype(dtype) for w in weights))
+
+
+def plain_block(x, router_w, w_gate, w_up, w_down, *, top_k, norm_topk):
+    """The block with no sort, no gather and no custom gradient: every
+    expert on every token, weighted by a dense [N, E] matrix of gates.
+    Returns what ``moe_swiglu_dropless`` does, less ``load_max``."""
+    xf = x.reshape(-1, x.shape[-1])
+    logits = xf @ router_w
+    probs, gates, experts = moe.route(logits, top_k, norm_topk)
+    n_experts = router_w.shape[-1]
+    weight = (jax.nn.one_hot(experts, n_experts) * gates[:, :, None]).sum(1)
+    h = jax.nn.silu(jnp.einsum("nd,edf->nef", xf, w_gate)) * jnp.einsum(
+        "nd,edf->nef", xf, w_up)
+    out = jnp.einsum("ne,nef,efd->nd", weight, h, w_down)
+    share = jax.nn.one_hot(experts, n_experts).sum((0, 1)) / experts.size
+    return out.reshape(x.shape), {
+        "balance": n_experts * (share * probs.mean(0)).sum(),
+        "z": moe.router_z(logits)}
+
+
+def _close(got, want, rel: float, name=""):
+    """|got - want| <= rel x (|want| + the largest |want|)."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert got.shape == want.shape, name
+    assert np.abs(want).max() > 1e-4, name               # not a dead branch
+    np.testing.assert_allclose(got, want, rtol=rel,
+                               atol=rel * np.abs(want).max(), err_msg=name)
+
+
+@pytest.mark.parametrize("routing", list(ROUTINGS))
+@pytest.mark.parametrize("norm_topk", [True, False])
+@pytest.mark.parametrize("top_k", [1, 3, 8])
+def test_the_blocks_own_backward_equals_jaxs(top_k, norm_topk, routing):
+    """``_down_and_combine``'s custom gradient, for every input it
+    differentiates (``h``, ``w_down``, the gates), equals ``jax.grad`` of
+    the plain composition (``ragged_dot``, ``y[inverse]``, the weighted
+    sum; no custom rule), and ``jax.grad`` of the whole
+    ``moe_swiglu_dropless`` equals that of ``plain_block`` for ``x``,
+    ``router_w`` and the three expert weights. Float32 on both sides, so
+    only the order of the sums differs: ``rtol`` 1e-5, and 1e-5 of the
+    largest entry for the entries near zero. (With ``top_k`` distinct
+    choices a token, one expert holds at most 1 / ``top_k`` of the
+    assignments: "over half" is of the tokens.)"""
+    x, router_w, w_gate, w_up, w_down = block_inputs(routing)
+    n = BLOCK_B * BLOCK_S
+    _, gates, experts = moe.route(x.reshape(n, -1) @ router_w, top_k,
+                                  norm_topk)
+    chosen = np.bincount(np.asarray(experts).reshape(-1), minlength=BLOCK_E)
+    mean = n * top_k / BLOCK_E
+    assert {"balanced": chosen.min() > 0 and chosen.max() < 3 * mean,
+            "one expert empty": chosen[0] == 0,
+            "one expert chosen by over half of the tokens":
+                chosen[0] > max(n // 2, 1.5 * mean)}[routing], chosen
+    order = np.argsort(np.asarray(experts).reshape(-1), kind="stable")
+    inverse = jnp.asarray(np.argsort(order), jnp.int32)
+    order = jnp.asarray(order, jnp.int32)
+    counts = jnp.asarray(chosen, jnp.int32)
+    rng = np.random.default_rng(1)
+    h = jnp.asarray(rng.normal(size=(n * top_k, BLOCK_F)), jnp.float32)
+    cot = jnp.asarray(rng.normal(size=(n, BLOCK_D)), jnp.float32)
+
+    def mine(h, w_down, gates):
+        return (moe._down_and_combine(h, w_down, gates, counts, order,
+                                      inverse) * cot).sum()
+
+    def plain(h, w_down, gates):
+        y = jax.lax.ragged_dot(h, w_down, counts)
+        rows = y[inverse].reshape(n, top_k, BLOCK_D)
+        return ((rows * gates[:, :, None]).sum(1) * cot).sum()
+
+    assert float(mine(h, w_down, gates)) == pytest.approx(
+        float(plain(h, w_down, gates)), rel=1e-5)
+    for name, a, b in zip(("h", "w_down", "gates"),
+                          jax.grad(mine, (0, 1, 2))(h, w_down, gates),
+                          jax.grad(plain, (0, 1, 2))(h, w_down, gates)):
+        _close(a, b, 1e-5, name)
+
+    cot = cot.reshape(x.shape)
+
+    def loss(block):
+        def f(*args):
+            out, stats = block(*args, top_k=top_k, norm_topk=norm_topk)
+            return (out * cot).sum() + stats["balance"] + stats["z"]
+        return f
+
+    args = (x, router_w, w_gate, w_up, w_down)
+    for name, a, b in zip(
+            ("x", "router_w", "w_gate", "w_up", "w_down"),
+            jax.grad(loss(moe.moe_swiglu_dropless), range(5))(*args),
+            jax.grad(loss(plain_block), range(5))(*args)):
+        _close(a, b, 1e-5, name)
+
+
+def test_the_bfloat16_block_stays_with_the_float32_one():
+    """The block and its gradients at bfloat16 rows and expert weights
+    (the configuration's precision) against the same block in float32 on
+    the same values (rounded to bfloat16 first: one flipped choice of the
+    router would be a step, not a rounding). The float32 tolerance of
+    ``test_program_equals_reference...`` cannot hold at an 8-bit mantissa
+    on either rule, so this is the kernels' bfloat16 tolerance
+    (``tests/test_ops_parallel.py``): 2^-6 of the largest entry. And the
+    rule written here rounds no worse than jax's own transpose of the
+    plain composition does at bfloat16 (it only moves WHERE the rounding
+    falls: from ``y`` to the down matmul's row gradient)."""
+    top_k = 3
+    full = tuple(a.astype(jnp.bfloat16).astype(jnp.float32)
+                 for a in block_inputs("balanced"))
+    cot = jnp.asarray(np.random.default_rng(1).normal(size=full[0].shape),
+                      jnp.float32)
+
+    def grads(args, dtype):
+        x, router_w, *weights = args
+        args = (x.astype(dtype), router_w, *(w.astype(dtype) for w in weights))
+        return jax.grad(lambda *a: (moe.moe_swiglu_dropless(
+            *a, top_k=top_k, norm_topk=False)[0].astype(jnp.float32)
+            * cot).sum(), range(5))(*args)
+
+    want = grads(full, jnp.float32)
+    got = grads(full, jnp.bfloat16)
+    for name, a, b in zip(("x", "router_w", "w_gate", "w_up", "w_down"),
+                          got, want):
+        assert a.dtype == (jnp.float32 if name == "router_w"
+                           else jnp.bfloat16), name
+        worst = float(np.abs(np.asarray(a, np.float32) - np.asarray(b)).max())
+        assert worst <= 2.0 ** -6 * float(np.abs(np.asarray(b)).max()), (
+            name, worst)
+
+    # the rule alone against jax's transpose of the plain composition,
+    # both at bfloat16, both measured against float32
+    n, a_rows = BLOCK_B * BLOCK_S, BLOCK_B * BLOCK_S * top_k
+    rng = np.random.default_rng(2)
+    experts = rng.integers(0, BLOCK_E, size=a_rows)
+    order = np.argsort(experts, kind="stable")
+    inverse = jnp.asarray(np.argsort(order), jnp.int32)
+    order = jnp.asarray(order, jnp.int32)
+    counts = jnp.asarray(np.bincount(experts, minlength=BLOCK_E), jnp.int32)
+    h = jnp.asarray(rng.normal(size=(a_rows, BLOCK_F)), jnp.float32)
+    gates = jnp.asarray(rng.uniform(0.02, 0.6, size=(n, top_k)), jnp.float32)
+    cot = cot.reshape(n, BLOCK_D)
+
+    def mine(h, w, gates):
+        return moe._down_and_combine(h, w, gates, counts, order, inverse)
+
+    def plain(h, w, gates):
+        rows = jax.lax.ragged_dot(h, w, counts)[inverse].reshape(n, top_k, -1)
+        return (rows.astype(jnp.float32) * gates[:, :, None]).sum(1).astype(
+            h.dtype)
+
+    def miss(f, dtype):
+        out = jax.vjp(f, h.astype(dtype), full[4].astype(dtype), gates)[1](
+            cot.astype(dtype))
+        return [np.asarray(o, np.float32) for o in out]
+
+    exact = miss(plain, jnp.float32)
+    for name, e, a, b in zip(("h", "w_down", "gates"), exact,
+                             miss(mine, jnp.bfloat16),
+                             miss(plain, jnp.bfloat16)):
+        assert np.abs(a - e).max() <= 1.5 * np.abs(b - e).max(), name
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of its sub-jaxprs."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_which_rows_the_blocks_gradient_moves_and_multiplies(remat):
+    """The contract of ``ops/moe.py``'s docstring, read from the jaxpr of
+    the block's gradient (after jax's own dead-code pass over a
+    ``jax.checkpoint``). Exactly TWO gathers take an [A, D] operand: the
+    forward's ``y[inverse]`` and the dispatch's backward ``g[inverse]``
+    (three before the block owned its backward); the combine's backward
+    gathers from [N, D], as the dispatch's forward does. Under
+    remat the recompute runs the dispatch's gather and TWO grouped
+    matmuls (gate, up), not three: nothing in the backward reads ``y``,
+    so neither the down matmul nor ``y[inverse]`` is recomputed."""
+    top_k = 3
+    args = block_inputs("balanced")
+    n, a_rows = BLOCK_B * BLOCK_S, BLOCK_B * BLOCK_S * top_k
+
+    def loss(*a):
+        return moe.moe_swiglu_dropless(*a, top_k=top_k)[0].sum()
+
+    if remat:
+        loss = jax.checkpoint(loss)
+    eqns = list(_eqns(jax.make_jaxpr(jax.grad(loss, range(5)))(*args).jaxpr))
+    gathered = [e.invars[0].aval.shape for e in eqns
+                if e.primitive.name == "gather"]
+    assert gathered.count((a_rows, BLOCK_D)) == 2, gathered
+    assert gathered.count((n, BLOCK_D)) == 2 + remat, gathered
+    # forward 3, backward 2 each, and the recompute's
+    matmuls = [e for e in eqns if e.primitive.name.startswith("ragged_dot")]
+    assert len(matmuls) == 3 + 6 + 2 * remat, [str(e) for e in matmuls]
