@@ -97,6 +97,10 @@ class LLMEngine:
                 "MoE decode is not wired into the slot engine yet; "
                 "train with MoE (models.transformer + Train) and serve dense."
             )
+        # layer_pattern / sliding_window / experts_held: the slot engine's
+        # decode runs one kind of layer and would run these wrongly in
+        # silence.
+        tfm.refuse_decode(c)
         # len(tokenizer) counts added special tokens on HF tokenizers;
         # vocab_size alone excludes them and would let special-token ids
         # silently clamp in the embedding gather.

@@ -29,6 +29,7 @@ from ray_tpu.models.transformer import (
     olmoe_1b_7b,
     partition_specs,
     qwen2_7b,
+    smallthinker_21b_a3b,
     tiny,
     tiny_moe,
 )
@@ -58,6 +59,7 @@ __all__ = [
     "mistral_7b",
     "mixtral_8x7b",
     "olmoe_1b_7b",
+    "smallthinker_21b_a3b",
     "partition_specs",
     "qwen2_7b",
     "tiny",

@@ -24,6 +24,21 @@ Two architectures behind one config:
   - ``arch="gpt2"``  — learned positions, LayerNorm, GELU MLP, tied head.
   - ``arch="llama"`` — RoPE, RMSNorm, SwiGLU, GQA, untied head.
 
+The ``llama`` arch also takes what describes a model whose layers are
+not all alike (SmallThinker): a **period** of layer kinds
+(``layer_pattern``: per position, a sliding window or none, RoPE or no
+positional encoding at all), heads whose width is not ``d_model /
+n_heads`` (``d_head``), a router that reads the block's FIRST norm
+(``router_input="attn_norm"``: its logits are made ahead of attention
+and its experts run after the second norm), ReGLU experts, and **held
+experts** (``experts_held = (rank, of)``: the parameters are one
+expert-parallel rank's share of every layer's experts, the router still
+chooses among all of them, and the block adds the held experts' part of
+the sum; ``ops/moe.py``). With a period of P > 1 the scan runs over
+WHOLE PERIODS and unrolls a period's P layers in its body, so each
+position's kind is static: a windowed layer compiles to the kernel that
+skips tiles, never to a ``cond`` over both kinds.
+
 Every part runs under a ``jax.named_scope`` from ``SCOPES``, so each
 device instruction of a profiler trace says which part it belongs to
 (its ``op_name``; ``chipbench/scopes.py`` reads it). Scopes are metadata:
@@ -32,6 +47,7 @@ the compiled program is the same with and without them.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import math
 from dataclasses import dataclass, replace
@@ -66,12 +82,17 @@ from ray_tpu.parallel.sharding import constrain
 # instruction it is the scan's own traffic) around per block ``attn_norm``,
 # ``attn`` (projections, rope, scores, output projection), ``mlp_norm``,
 # ``mlp`` (``moe`` with experts; the dropless dispatch opens its own
-# sub-scopes inside it, ``ops.moe.SCOPES``), then ``final_norm`` and
+# sub-scopes inside it, ``ops.moe.SCOPES``; a router that reads the first
+# norm runs under ``moe`` / ``moe_router`` ahead of ``attn``), then
+# ``final_norm`` and
 # ``head_loss`` (head matmul + every cross entropy); in make_train_step
 # ``grad_accum`` (the micro-batch scan's sums) and ``optimizer`` (update +
 # apply).
 SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
           "final_norm", "head_loss", "grad_accum", "optimizer")
+# Inside ``attn``, for a model with a ``layer_pattern`` only: which kind
+# of layer the instruction belongs to.
+ATTN_SCOPES = ("attn_full", "attn_window")
 
 # Which tree's scopes an executable carries. jax's compile-cache key leaves
 # metadata out, so a step loaded from the cache would keep the scope names
@@ -157,6 +178,31 @@ class TransformerConfig:
     # RMSNorm with a learned weight over the WHOLE q and k projections
     # (all heads together), before the split into heads and RoPE (OLMoE).
     qk_norm: bool = False
+    # -- what describes a model whose layers are not all alike (llama arch)
+    d_head: int | None = None        # a head's width; default d_model/n_heads
+    norm_eps: float = 1e-5           # RMSNorm / LayerNorm epsilon
+    # One period of layer kinds, repeated n_layers / len times: per
+    # position (windowed, rope). Empty: every layer is the arch's own
+    # (full causal attention; RoPE in the llama arch).
+    layer_pattern: tuple[tuple[bool, bool], ...] = ()
+    sliding_window: int | None = None  # keys a query of a windowed layer sees
+    expert_activation: str = "silu"  # "silu" (SwiGLU) | "relu" (ReGLU)
+    # What the router reads: "mlp_norm" (the experts' own input) or
+    # "attn_norm" (the block's first norm: the logits are made ahead of
+    # attention, the experts still run on the second norm's output).
+    router_input: str = "mlp_norm"
+    # (rank, of): the parameters hold rank ``rank``'s n_experts / of
+    # consecutive experts of every layer, one expert-parallel rank's
+    # share; the router and the top-k stay over all n_experts and the
+    # block adds the held experts' part of the sum (dropless only).
+    experts_held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        # A config file's JSON gives lists: keep the config hashable.
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        object.__setattr__(self, "layer_pattern", tuple(
+            (bool(w), bool(r)) for w, r in self.layer_pattern))
 
     @property
     def kv_heads(self) -> int:
@@ -164,7 +210,25 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def held_range(self) -> tuple[int, int] | None:
+        """[first, end) of the experts the parameters hold, or None."""
+        if self.experts_held is None:
+            return None
+        return moe.held_range(self.n_experts, *self.experts_held)
+
+    @property
+    def experts_here(self) -> int:
+        held = self.held_range
+        return self.n_experts if held is None else held[1] - held[0]
+
+    def layer_kind(self, i: int) -> tuple[bool, bool] | None:
+        """(windowed, rope) of layer ``i``; None with no pattern."""
+        if not self.layer_pattern:
+            return None
+        return self.layer_pattern[i % len(self.layer_pattern)]
 
     @property
     def ffn_dim(self) -> int:
@@ -281,6 +345,31 @@ def olmoe_1b_7b(**kw) -> TransformerConfig:
     )
 
 
+def smallthinker_21b_a3b(**kw) -> TransformerConfig:
+    """SmallThinker-21B-A3B-Instruct (PowerInfer ``config.json``;
+    arXiv:2507.20984): 52 layers in periods of four, the first global
+    (full causal attention, NO positional encoding), three windowed
+    (4,096 keys, RoPE theta 1.5e6); 28 query heads on 4 key / value
+    heads of 128 over a 2,560-wide model; 64 ReGLU experts 768 wide, 6 a
+    token, dropless, the gates a softmax over the six chosen logits; the
+    router reads the block's FIRST norm. Balance weight 0.01 (Switch's;
+    ``config.json`` gives none), no z term."""
+    return replace(
+        TransformerConfig(
+            vocab_size=151936, n_layers=52, d_model=2560, n_heads=28,
+            n_kv_heads=4, d_head=128, d_ff=768, max_seq_len=16384,
+            arch="llama", rope_theta=1.5e6, norm_eps=1e-6,
+            n_experts=64, expert_top_k=6, expert_capacity_factor=None,
+            expert_norm_topk=True, router_aux_weight=0.01,
+            router_z_weight=0.0, expert_activation="relu",
+            router_input="attn_norm", sliding_window=4096,
+            layer_pattern=((False, False), (True, True), (True, True),
+                           (True, True)),
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -314,6 +403,45 @@ def tiny(**kw) -> TransformerConfig:
 
 # -- init -------------------------------------------------------------------
 
+def _check_config(c: TransformerConfig) -> None:
+    """Refuse, by name, a combination the program does not run."""
+    if c.n_experts > 0 and c.arch != "llama":
+        raise ValueError("MoE (n_experts > 0) requires arch='llama'")
+    if c.qk_norm and c.arch != "llama":
+        raise ValueError("qk_norm requires arch='llama'")
+    patterned = (c.layer_pattern or c.sliding_window is not None
+                 or c.d_head is not None)
+    if patterned and c.arch != "llama":
+        raise ValueError("layer_pattern, sliding_window and d_head require "
+                         "arch='llama'")
+    if c.layer_pattern and c.n_layers % len(c.layer_pattern):
+        raise ValueError(
+            f"n_layers={c.n_layers} is not a whole number of periods of "
+            f"{len(c.layer_pattern)} layers (layer_pattern)")
+    windowed = any(w for w, _ in c.layer_pattern)
+    if windowed != (c.sliding_window is not None):
+        raise ValueError(
+            "sliding_window is the width of the layer_pattern's windowed "
+            f"positions: got sliding_window={c.sliding_window} with "
+            f"layer_pattern={c.layer_pattern}")
+    if c.expert_activation not in moe.ACTIVATIONS:
+        raise ValueError(f"expert_activation must be one of "
+                         f"{sorted(moe.ACTIVATIONS)}")
+    if c.router_input not in ("mlp_norm", "attn_norm"):
+        raise ValueError("router_input must be 'mlp_norm' or 'attn_norm'")
+    asks_dropless = (c.experts_held is not None
+                     or c.router_input != "mlp_norm"
+                     or c.expert_activation != "silu")
+    if asks_dropless and (c.n_experts == 0
+                          or c.expert_capacity_factor is not None):
+        raise ValueError(
+            "experts_held, router_input='attn_norm' and a ReGLU "
+            "expert_activation are the dropless path's "
+            "(n_experts > 0, expert_capacity_factor=None)")
+    if c.experts_held is not None:      # raises where they do not divide
+        moe.held_range(c.n_experts, *c.experts_held)
+
+
 def init_params(rng, config: TransformerConfig):
     """Initialize the parameter pytree.
 
@@ -322,10 +450,7 @@ def init_params(rng, config: TransformerConfig):
     1/sqrt(2*n_layers).
     """
     c = config
-    if c.n_experts > 0 and c.arch != "llama":
-        raise ValueError("MoE (n_experts > 0) requires arch='llama'")
-    if c.qk_norm and c.arch != "llama":
-        raise ValueError("qk_norm requires arch='llama'")
+    _check_config(c)
     pdt = jnp.dtype(c.param_dtype)
     L, D, H, KV, Dh, F = (
         c.n_layers, c.d_model, c.n_heads, c.kv_heads, c.head_dim, c.ffn_dim,
@@ -371,8 +496,9 @@ def init_params(rng, config: TransformerConfig):
             params["layers"]["attn"]["q_norm"] = jnp.ones((L, H * Dh), pdt)
             params["layers"]["attn"]["k_norm"] = jnp.ones((L, KV * Dh), pdt)
         if c.n_experts > 0:
-            E = c.n_experts
-            params["layers"]["router"] = {"w": norm(next(keys), L, D, E)}
+            E = c.experts_here
+            params["layers"]["router"] = {
+                "w": norm(next(keys), L, D, c.n_experts)}
             params["layers"]["mlp"] = {
                 "w_gate": norm(next(keys), L, E, D, F),
                 "w_up": norm(next(keys), L, E, D, F),
@@ -468,13 +594,15 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     ``return_aux`` additionally returns the router's statistics over the
     layers, ``{"balance", "z", "load_max"}`` (``ops/moe.py``; the two loss
     terms as means, the fullest layer's ``load_max``; zeros for a dense
-    model). ``return_hidden`` skips the LM head and returns the final
+    model; with ``experts_held`` also ``held_share``, the mean over the
+    layers). ``return_hidden`` skips the LM head and returns the final
     normed hidden states [B, T, D] (the chunked-loss path applies the head
     itself).
     """
     c = config
     dt = c.compute_dtype
     B, T = tokens.shape
+    _check_config(c)
     if (c.n_experts > 0 and c.expert_capacity_factor is None
             and mesh is not None and mesh.shape.get(AXIS_EXPERT, 1) > 1):
         raise NotImplementedError(
@@ -500,24 +628,43 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             rope = (cos, sin)
         x = con(x, _BATCH, AXIS_SEQUENCE, None)
 
-    def layer(x, lp):
-        return _block(x, lp, c, rope=rope, con=con, positions=positions)
+    def layer_of(kind):
+        """The block of one kind of layer (static), under remat."""
+        def layer(x, lp):
+            return _block(x, lp, c, rope=rope, con=con, positions=positions,
+                          kind=kind)
 
-    if c.remat:
+        if not c.remat:
+            return layer
         if c.remat_policy == "dots":
-            layer = jax.checkpoint(
+            return jax.checkpoint(
                 layer,
                 policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             )
-        else:
-            layer = jax.checkpoint(layer)
+        return jax.checkpoint(layer)
+
+    period = max(1, len(c.layer_pattern))
+    layers = [layer_of(c.layer_kind(i)) for i in range(period)]
 
     # ``layers`` holds what belongs to no one part of a block: the scan's
     # reads of the stacked weights and writes of their stacked gradients.
     with jax.named_scope("layers"):
-        if c.scan_layers:
-            x, auxs = jax.lax.scan(lambda h, lp: layer(h, lp), x,
+        if c.scan_layers and period == 1:
+            x, auxs = jax.lax.scan(lambda h, lp: layers[0](h, lp), x,
                                    params["layers"])
+        elif c.scan_layers:
+            # One scan step is one whole period, its layers unrolled: the
+            # stacked [L, ...] weights are read as [L / P, P, ...].
+            def one_period(h, pp):
+                per_position = []
+                for i, layer in enumerate(layers):
+                    h, aux_i = layer(h, jax.tree.map(lambda a, i=i: a[i], pp))
+                    per_position.append(aux_i)
+                return h, jax.tree.map(lambda *a: jnp.stack(a), *per_position)
+
+            x, auxs = jax.lax.scan(one_period, x, jax.tree.map(
+                lambda a: a.reshape(c.n_layers // period, period,
+                                    *a.shape[1:]), params["layers"]))
         else:
             # Unrolled: larger compile, but lets XLA schedule across layer
             # boundaries (and sidesteps scan-differentiation limits on some
@@ -525,18 +672,20 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             per_layer = []
             for i in range(c.n_layers):
                 lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-                x, aux_i = layer(x, lp)
+                x, aux_i = layers[i % period](x, lp)
                 per_layer.append(aux_i)
             auxs = jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
         aux = {"balance": auxs["balance"].mean(), "z": auxs["z"].mean(),
                "load_max": auxs["load_max"].max()}
+        if c.experts_held is not None:
+            aux["held_share"] = auxs["held_share"].mean()
 
     with jax.named_scope("final_norm"):
         if c.arch == "gpt2":
             x = layer_norm(x, params["final_norm"]["w"],
-                           params["final_norm"]["b"])
+                           params["final_norm"]["b"], eps=c.norm_eps)
         else:
-            x = rms_norm(x, params["final_norm"]["w"])
+            x = rms_norm(x, params["final_norm"]["w"], eps=c.norm_eps)
     if return_hidden:
         return (x, aux) if return_aux else x
     with jax.named_scope("head_loss"):
@@ -547,17 +696,32 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     return (logits, aux) if return_aux else logits
 
 
-def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
+def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
+           kind=None):
     """One transformer block (pre-norm residual). Its parts carry the
     scopes ``attn_norm``, ``attn``, ``mlp_norm`` and ``mlp`` / ``moe``
-    (see SCOPES); each part's residual add is inside its scope."""
+    (see SCOPES); each part's residual add is inside its scope. ``kind``
+    = (windowed, rope) is the layer's place in the ``layer_pattern``
+    (static; None with no pattern: full causal attention, the arch's own
+    positions), and names the sub-scope its attention runs under."""
     dt = c.compute_dtype
+    window = None
+    if kind is not None:
+        windowed, with_rope = kind
+        window = c.sliding_window if windowed else None
+        rope = rope if with_rope else None
     with jax.named_scope("attn_norm"):
         if c.arch == "gpt2":
-            h = layer_norm(x, lp["ln1"]["w"], lp["ln1"]["b"])
+            h = layer_norm(x, lp["ln1"]["w"], lp["ln1"]["b"], eps=c.norm_eps)
         else:
-            h = rms_norm(x, lp["ln1"]["w"])
-    with jax.named_scope("attn"):
+            h = rms_norm(x, lp["ln1"]["w"], eps=c.norm_eps)
+    router = None
+    if c.n_experts > 0 and c.router_input == "attn_norm":
+        with jax.named_scope("moe"):
+            router = moe.router_matmul(h, lp["router"]["w"])
+    with jax.named_scope("attn"), (
+            contextlib.nullcontext() if kind is None else jax.named_scope(
+                ATTN_SCOPES[window is not None])):
         if c.kv_heads == c.n_heads:
             # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
             # three skinny d→d projections (the weight concat is a few MB,
@@ -582,7 +746,7 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
             k = apply_rope(k, cos, sin, positions=positions)
         k, v = _expand_gqa(k, v, c)
         q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-        o = attention(q, k, v, causal=True, impl=c.attn_impl)
+        o = attention(q, k, v, causal=True, impl=c.attn_impl, window=window)
         o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
         x = x + o
 
@@ -590,9 +754,9 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
            for name in ("balance", "z", "load_max")}
     with jax.named_scope("mlp_norm"):
         if c.arch == "gpt2":
-            h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"])
+            h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"], eps=c.norm_eps)
         else:
-            h = rms_norm(x, lp["ln2"]["w"])
+            h = rms_norm(x, lp["ln2"]["w"], eps=c.norm_eps)
     if c.arch == "gpt2":
         with jax.named_scope("mlp"):
             m = gelu_mlp(h, lp["mlp"]["w_in"].astype(dt),
@@ -607,7 +771,8 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None):
             if c.expert_capacity_factor is None:
                 m, aux = moe.moe_swiglu_dropless(
                     h, *weights, top_k=c.expert_top_k,
-                    norm_topk=c.expert_norm_topk)
+                    norm_topk=c.expert_norm_topk, router_logits=router,
+                    held=c.held_range, activation=c.expert_activation)
             else:
                 m, aux = moe.moe_swiglu(
                     h, *weights, top_k=c.expert_top_k,
@@ -874,6 +1039,8 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
                 + config.router_z_weight * aux["z"])
         metrics = dict(metrics, router_aux=aux["balance"], router_z=aux["z"],
                        moe_load_max=aux["load_max"], loss=loss)
+        if "held_share" in aux:
+            metrics["moe_held_share"] = aux["held_share"]
     return loss, metrics
 
 
@@ -1003,13 +1170,27 @@ def init_train_state(rng, config: TransformerConfig, optimizer):
 
 # -- decode (KV cache) ------------------------------------------------------
 
-def init_kv_cache(config: TransformerConfig, batch_size: int, max_len: int):
-    """Preallocated decode cache: [L, B, max_len, KV, Dh] per k/v."""
-    c = config
+def refuse_decode(c: TransformerConfig) -> None:
+    """The KV-cache decode runs one kind of dense layer: refuse, by name,
+    a model it would run wrongly in silence."""
     if c.n_experts > 0:
         raise NotImplementedError(
             "KV-cache decode for MoE models is not implemented yet"
         )
+    for name, value in (("layer_pattern", c.layer_pattern),
+                        ("sliding_window", c.sliding_window),
+                        ("experts_held", c.experts_held)):
+        if value:
+            raise NotImplementedError(
+                f"KV-cache decode does not run a model with {name} "
+                f"({value!r}): every layer would be decoded as full causal "
+                f"attention with RoPE")
+
+
+def init_kv_cache(config: TransformerConfig, batch_size: int, max_len: int):
+    """Preallocated decode cache: [L, B, max_len, KV, Dh] per k/v."""
+    c = config
+    refuse_decode(c)
     shape = (c.n_layers, batch_size, max_len, c.kv_heads, c.head_dim)
     return {
         "k": jnp.zeros(shape, c.compute_dtype),
@@ -1026,6 +1207,7 @@ def decode_step(params, tokens, cache, config: TransformerConfig):
     program serves both prefill (S=prompt) and decode (S=1).
     """
     c = config
+    refuse_decode(c)
     dt = c.compute_dtype
     B, S = tokens.shape
     pos0 = cache["pos"]

@@ -41,15 +41,20 @@ import jax.numpy as jnp
 _NEG_INF = -1e30
 
 
-def _causal_mask(q_pos, k_pos):
-    return q_pos[:, None] >= k_pos[None, :]
+def _causal_mask(q_pos, k_pos, window: int | None = None):
+    """Key j is visible to query i iff ``j <= i`` and, under a sliding
+    ``window``, ``i - j < window`` (the query's own position counts)."""
+    d = q_pos[:, None] - k_pos[None, :]
+    return d >= 0 if window is None else (d >= 0) & (d < window)
 
 
-def dot_product_attention(q, k, v, *, causal: bool = True, q_offset: int = 0):
+def dot_product_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
+                          window: int | None = None):
     """Reference attention. q: [B,Tq,H,D], k/v: [B,Tk,H,D].
 
     ``q_offset`` is the global position of q's first row relative to k
     (used by decode steps and by ring attention's shifted blocks).
+    ``window`` (causal only) keeps the last ``window`` keys a query.
 
     Dtype policy (the v5e tuning that took GPT-2 124M training from 67k
     to 91k tok/s/chip): the [B,H,Tq,Tk] scores and saved softmax output
@@ -64,7 +69,8 @@ def dot_product_attention(q, k, v, *, causal: bool = True, q_offset: int = 0):
     if causal:
         q_pos = q_offset + jnp.arange(q.shape[1])
         k_pos = jnp.arange(k.shape[1])
-        s = jnp.where(_causal_mask(q_pos, k_pos)[None, None], s, _NEG_INF)
+        s = jnp.where(_causal_mask(q_pos, k_pos, window)[None, None], s,
+                      _NEG_INF)
     p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(p.dtype)).astype(q.dtype)
 
@@ -81,11 +87,13 @@ def _causal_block_rows(t: int) -> int:
     return rows if t > rows and t % rows == 0 else 0
 
 
-def causal_blocked_attention(q, k, v, *, block_q: int):
+def causal_blocked_attention(q, k, v, *, block_q: int,
+                             window: int | None = None):
     """Exact causal self-attention (Tq == Tk, no offset) that skips the
     masked blocks: query block i multiplies its ``block_q`` rows against
     the key / value prefix ``[0 : (i+1)*block_q]`` only, a static slice,
-    and masks only inside that prefix. Every row's softmax runs over
+    and masks only inside that prefix (under a ``window``, the prefix
+    starts at the first key the block's first row may see). Every row's softmax runs over
     exactly the keys ``dot_product_attention`` allows it (the entries
     dropped had probability exactly 0), with the same dtype policy.
     (n+1)/2n of the T^2 entries are computed with n blocks; jax
@@ -99,9 +107,10 @@ def causal_blocked_attention(q, k, v, *, block_q: int):
     outs = []
     for start in range(0, t, block_q):
         end = start + block_q
+        first = 0 if window is None else max(0, start - window + 1)
         outs.append(dot_product_attention(
-            q[:, start:end], k[:, :end], v[:, :end], causal=True,
-            q_offset=start))
+            q[:, start:end], k[:, first:end], v[:, first:end], causal=True,
+            q_offset=start - first, window=window))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -111,7 +120,7 @@ def causal_blocked_attention(q, k, v, *, block_q: int):
 
 
 def online_softmax_block(q, k, v, m, l, o, *, q_pos, k_pos, causal,
-                         k_valid=None):
+                         k_valid=None, window: int | None = None):
     """One flash step: fold key block (k, v) into accumulators (m, l, o).
 
     q [B,Tq,H,D]; k/v [B,Tk,H,D]; m,l [B,H,Tq]; o [B,Tq,H,D] float32.
@@ -125,7 +134,7 @@ def online_softmax_block(q, k, v, m, l, o, *, q_pos, k_pos, causal,
     s = s * scale
     mask = None
     if causal:
-        mask = _causal_mask(q_pos, k_pos)
+        mask = _causal_mask(q_pos, k_pos, window)
     if k_valid is not None:
         valid = jnp.broadcast_to(k_valid[None, :], (q.shape[1], k.shape[1]))
         mask = valid if mask is None else (mask & valid)
@@ -148,9 +157,10 @@ def _finalize(o, l):
 
 
 def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
-                        q_offset: int = 0):
+                        q_offset: int = 0, window: int | None = None):
     """Flash-style attention as a lax.scan over key blocks: O(T) memory,
-    differentiable, MXU-friendly block matmuls."""
+    differentiable, MXU-friendly block matmuls. A ``window`` is a mask
+    here: every key block is still visited."""
     b, tq, h, d = q.shape
     tk = k.shape[1]
     block_k = min(block_k, tk)
@@ -169,7 +179,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
         k_pos = idx * block_k + jnp.arange(block_k)
         m, l, o = online_softmax_block(
             q, kblk, vblk, m, l, o, q_pos=q_pos, k_pos=k_pos, causal=causal,
-            k_valid=(k_pos < tk) if pad else None,
+            k_valid=(k_pos < tk) if pad else None, window=window,
         )
         return (m, l, o), None
 
@@ -274,38 +284,130 @@ def _flash_launch(kernel: str, q, k, block_q, block_k):
     return block_q, block_k, pltpu.CompilerParams(vmem_limit_bytes=vmem)
 
 
+def _visible_blocks(i, rows: int, cols: int, n_cols: int, before: int,
+                    after: int):
+    """(first, last) of the ``n_cols`` blocks of ``cols`` positions that
+    hold a position some row of block ``i`` (``rows`` rows) can see, a row
+    r seeing ``r - before .. r + after`` (``_window_reach``). ``i`` is a Python int
+    (the grid's size) or a traced one (inside a kernel or an index map):
+    the ONE rule for which tiles a windowed kernel visits."""
+    lo, hi = i * rows - before, i * rows + rows - 1 + after
+    if isinstance(i, int):
+        return max(lo, 0) // cols, min(hi // cols, n_cols - 1)
+    return jnp.maximum(lo, 0) // cols, jnp.minimum(hi // cols, n_cols - 1)
+
+
+def _flash_inner(window, rows: int, cols: int, n_rows: int, n_cols: int,
+                 rows_are_queries: bool):
+    """The inner axis of a kernel's grid: (steps a row block takes, the
+    inner block that step j of row block i reads). With no window every
+    row block walks all ``n_cols`` blocks (a causal kernel skips the
+    arithmetic above the diagonal, not the step). Under a window the
+    axis is only as long as the most blocks any row block can see, step
+    j reads the j-th of them, and a step past the last visible block
+    stays on it (no new fetch) and computes nothing."""
+    if window is None:
+        return n_cols, lambda i, j: j
+    reach = _window_reach(window, rows_are_queries)
+    steps = max(last - first + 1 for first, last in (
+        _visible_blocks(i, rows, cols, n_cols, *reach)
+        for i in range(n_rows)))
+
+    def block(i, j):
+        first, last = _visible_blocks(i, rows, cols, n_cols, *reach)
+        return jnp.minimum(first + j, last)
+
+    return steps, block
+
+
+def _window_reach(window: int, rows_are_queries: bool) -> tuple[int, int]:
+    """(before, after) of ``_visible_blocks``: a query sees ``window - 1``
+    keys before it and none after; a key is seen by no query before it
+    and ``window - 1`` after."""
+    return (window - 1, 0) if rows_are_queries else (0, window - 1)
+
+
+def _inner_block(step, row_blk, rows: int, cols: int, n_cols: int, window,
+                 rows_are_queries: bool):
+    """Inside a kernel: (the inner block grid step ``step`` of row block
+    ``row_blk`` is at, whether that step is within the row block's
+    visible blocks). With no window the step IS the block and the second
+    is None: the diagonal decides (``_when_visible``)."""
+    if window is None:
+        return step, None
+    first, last = _visible_blocks(row_blk, rows, cols, n_cols,
+                                  *_window_reach(window, rows_are_queries))
+    return first + step, first + step <= last
+
+
+def _tile_is_full(q_blk, k_blk, block_q: int, block_k: int, window: int):
+    """Every (query, key) pair of the tile is visible: its last key is at
+    or before its first query, and its first key is inside the window of
+    its last query."""
+    return (((k_blk + 1) * block_k - 1 <= q_blk * block_q)
+            & ((q_blk + 1) * block_q - 1 - k_blk * block_k < window))
+
+
+def _tile_mask(q_blk, k_blk, block_q: int, block_k: int,
+               window: int | None):
+    q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    k_pos = k_blk * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    if window is None:
+        return q_pos >= k_pos
+    d = q_pos - k_pos
+    return (d >= 0) & (d < window)
+
+
+def _when_visible(compute, q_blk, k_blk, in_range, *, block_q, block_k,
+                  causal, window, diagonal):
+    """Run ``compute(masked)`` for the tile (q_blk, k_blk) if it holds a
+    visible pair. Plain causal: ``diagonal`` says so, and every computed
+    tile is masked. Under a window: ``in_range`` says so (the step is
+    within the row block's visible blocks), and only a tile the diagonal
+    or the window's edge crosses builds a mask."""
+    import jax.experimental.pallas as pl
+
+    if window is not None:
+        full = _tile_is_full(q_blk, k_blk, block_q, block_k, window)
+        pl.when(in_range & full)(lambda: compute(False))
+        pl.when(in_range & jnp.logical_not(full))(lambda: compute(True))
+    elif causal:
+        pl.when(diagonal)(lambda: compute(True))
+    else:
+        compute(False)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                      acc_ref, *, block_q, block_k, n_k, causal, scale):
+                      acc_ref, *, block_q, block_k, n_k, n_steps, causal,
+                      scale, window=None):
     import jax.experimental.pallas as pl
 
     q_blk = pl.program_id(1)
-    k_blk = pl.program_id(2)
+    step = pl.program_id(2)
+    k_blk, in_range = _inner_block(step, q_blk, block_q, block_k, n_k,
+                                   window, True)
 
-    @pl.when(k_blk == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _compute():
+    def _compute(masked):
         q = q_ref[0]  # [block_q, d]
         k = k_ref[0]  # [block_k, d]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [block_q, block_k]
-        if causal:
-            q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = k_blk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            mask = q_pos >= k_pos
+        if masked:
+            mask = _tile_mask(q_blk, k_blk, block_q, block_k, window)
             s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
-        if causal:
+        if masked:
             p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
         l_ref[:] = l_ref[:] * corr + p.sum(axis=1, keepdims=True)
@@ -316,15 +418,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         )
         acc_ref[:] = acc_ref[:] * corr + pv
 
-    if causal:
-        # Skip blocks strictly above the diagonal (whole block masked).
-        @pl.when(k_blk * block_k <= q_blk * block_q + block_q - 1)
-        def _():
-            _compute()
-    else:
-        _compute()
+    # Plain causal: skip blocks strictly above the diagonal (whole block
+    # masked). Windowed: the grid holds visible blocks only.
+    _when_visible(
+        _compute, q_blk, k_blk, in_range,
+        block_q=block_q, block_k=block_k, causal=causal, window=window,
+        diagonal=k_blk * block_k <= q_blk * block_q + block_q - 1)
 
-    @pl.when(k_blk == n_k - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
         # logsumexp row statistic: the backward kernels reconstruct the
@@ -337,7 +438,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 
 def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
-                   return_lse: bool = False):
+                   return_lse: bool = False, window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -350,18 +451,19 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
     vf = v.transpose(0, 2, 1, 3).reshape(bh, tk, d)
     block_q, block_k, params = _flash_launch("fwd", q, k, block_q, block_k)
     n_q, n_k = tq // block_q, tk // block_k
+    n_steps, k_of = _flash_inner(window, block_q, block_k, n_q, n_k, True)
 
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, n_k=n_k,
-        causal=causal, scale=scale,
+        n_steps=n_steps, causal=causal, scale=scale, window=window,
     )
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, n_q, n_k),
+        grid=(bh, n_q, n_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, k_of(i, j), 0)),
+            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, k_of(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
@@ -400,7 +502,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
 
 
 def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
-               causal, scale):
+               masked, scale, window=None):
     """Shared per-tile math: returns (ds [bq,bk] f32, p [bq,bk] f32).
 
     lse/delta arrive as [block_q, 1] column tiles (see the forward's
@@ -415,12 +517,8 @@ def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     mask = None
-    if causal:
-        q_pos = q_blk * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = k_blk * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = q_pos >= k_pos
+    if masked:
+        mask = _tile_mask(q_blk, k_blk, block_q, block_k, window)
         s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)
     if mask is not None:
@@ -432,57 +530,59 @@ def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, block_q, block_k, n_k, causal,
-                         scale):
+                         dq_ref, acc_ref, *, block_q, block_k, n_k, n_steps,
+                         causal, scale, window=None):
     import jax.experimental.pallas as pl
 
     q_blk = pl.program_id(1)
-    k_blk = pl.program_id(2)
+    step = pl.program_id(2)
+    k_blk, in_range = _inner_block(step, q_blk, block_q, block_k, n_k,
+                                   window, True)
 
-    @pl.when(k_blk == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _compute():
+    def _compute(masked):
         ds, _ = _bwd_block(
             q_ref[0], k_ref[0], v_ref[0], g_ref[0], lse_ref[0], delta_ref[0],
             q_blk=q_blk, k_blk=k_blk, block_q=block_q, block_k=block_k,
-            causal=causal, scale=scale)
+            masked=masked, scale=scale, window=window)
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(k_blk * block_k <= q_blk * block_q + block_q - 1)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _when_visible(
+        _compute, q_blk, k_blk, in_range,
+        block_q=block_q, block_k=block_k, causal=causal, window=window,
+        diagonal=k_blk * block_k <= q_blk * block_q + block_q - 1)
 
-    @pl.when(k_blk == n_k - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                          block_k, n_q, causal, scale):
+                          block_k, n_q, n_steps, causal, scale, window=None):
     import jax.experimental.pallas as pl
 
     k_blk = pl.program_id(1)
-    q_blk = pl.program_id(2)
+    step = pl.program_id(2)
+    q_blk, in_range = _inner_block(step, k_blk, block_k, block_q, n_q,
+                                   window, False)
 
-    @pl.when(q_blk == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _compute():
+    def _compute(masked):
         q, g = q_ref[0], g_ref[0]
         ds, p = _bwd_block(
             q, k_ref[0], v_ref[0], g, lse_ref[0], delta_ref[0],
             q_blk=q_blk, k_blk=k_blk, block_q=block_q, block_k=block_k,
-            causal=causal, scale=scale)
+            masked=masked, scale=scale, window=window)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -490,23 +590,21 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
-        # Skip query blocks entirely ABOVE the diagonal for this key
-        # block (no query there attends to these keys).
-        @pl.when(q_blk * block_q + block_q - 1 >= k_blk * block_k)
-        def _():
-            _compute()
-    else:
-        _compute()
+    # Plain causal: skip query blocks entirely ABOVE the diagonal for this
+    # key block (no query there attends to these keys).
+    _when_visible(
+        _compute, q_blk, k_blk, in_range,
+        block_q=block_q, block_k=block_k, causal=causal, window=window,
+        diagonal=q_blk * block_q + block_q - 1 >= k_blk * block_k)
 
-    @pl.when(q_blk == n_q - 1)
+    @pl.when(step == n_steps - 1)
     def _emit():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k,
-                    interpret):
+                    interpret, window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -533,12 +631,14 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k,
 
     # The dq pass: grid (b, i, j), key blocks innermost.
     bq, bk, params = _flash_launch("dq", q, k, block_q, block_k)
+    n_steps, k_of = _flash_inner(window, bq, bk, tq // bq, tk // bk, True)
     rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
-    keys = lambda b_, i, j: (b_, j, 0)  # noqa: E731
+    keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
-                          n_k=tk // bk, causal=causal, scale=scale),
-        grid=(bh, tq // bq, tk // bk),
+                          n_k=tk // bk, n_steps=n_steps, causal=causal,
+                          scale=scale, window=window),
+        grid=(bh, tq // bq, n_steps),
         in_specs=in_specs(bq, bk, rows, keys),
         out_specs=pl.BlockSpec((1, bq, d), rows),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
@@ -549,12 +649,14 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k,
 
     # The dk / dv pass: grid (b, j, i), query blocks innermost.
     bq, bk, params = _flash_launch("dkv", q, k, block_q, block_k)
-    rows = lambda b_, j, i: (b_, i, 0)  # noqa: E731
+    n_steps, q_of = _flash_inner(window, bk, bq, tk // bk, tq // bq, False)
+    rows = lambda b_, j, i: (b_, q_of(j, i), 0)  # noqa: E731
     keys = lambda b_, j, i: (b_, j, 0)  # noqa: E731
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
-                          n_q=tq // bq, causal=causal, scale=scale),
-        grid=(bh, tk // bk, tq // bq),
+                          n_q=tq // bq, n_steps=n_steps, causal=causal,
+                          scale=scale, window=window),
+        grid=(bh, tk // bk, n_steps),
         in_specs=in_specs(bq, bk, rows, keys),
         out_specs=[pl.BlockSpec((1, bk, d), keys)] * 2,
         out_shape=[
@@ -584,9 +686,9 @@ def _interpret() -> bool:
         f"'cpu' (interpreted), not on platform {platform!r}")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
-                    block_k: int | None = None):
+                    block_k: int | None = None, window: int | None = None):
     """Pallas flash attention (TPU kernel; interpreter on CPU).
 
     Training runs the Pallas BACKWARD kernels (dq pass + dk/dv pass,
@@ -596,34 +698,56 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
     Each of the three kernels sizes its own tiles from the shapes and
     the dtype (``_flash_tiles``). ``block_q`` / ``block_k`` set the tiles
     of all three instead: for tests, whose interpreter wants small ones.
+
+    ``window`` (causal self-attention only) is a sliding window of that
+    many keys a query, its own position among them. All three kernels
+    then walk a shorter grid that holds only the tiles with a visible
+    pair (``_flash_inner``) and build a mask only on the tiles the
+    diagonal or the window's edge crosses.
     """
+    _check_window(q, k, causal, window)
     return _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=_interpret(),
+        interpret=_interpret(), window=window,
     )
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k):
+def _check_window(q, k, causal, window):
+    if window is not None and (not causal or q.shape[1] != k.shape[1]
+                               or window < 1):
+        raise ValueError(
+            f"a sliding window is for causal self-attention (Tq == Tk) and "
+            f"holds at least the query's own key, got causal={causal}, "
+            f"Tq={q.shape[1]}, Tk={k.shape[1]}, window={window}")
+
+
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window):
+    _check_window(q, k, causal, window)
     out, lse = _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=_interpret(), return_lse=True,
+        interpret=_interpret(), return_lse=True, window=window,
     )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, res, g):
+def _flash_bwd_rule(causal, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(
         q, k, v, out, lse, g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=_interpret(),
+        block_k=block_k, interpret=_interpret(), window=window,
     )
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
+def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
+              window: int | None = None):
     """Dispatch: 'reference' | 'blockwise' | 'flash' | 'auto'.
+
+    ``window`` (causal self-attention only): a query sees the last
+    ``window`` keys, its own among them; every path takes it, and a
+    window that holds the whole row is the plain causal path (None).
 
     'auto' at Tk <= 1024 materialises the scores: causal self-attention
     whose T is a whole number, at least two, of query blocks (a quarter
@@ -633,12 +757,15 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
     whole 128-row tiles (the kernel sizes its own tiles from the shapes:
     ``_flash_tiles``), else the blockwise path.
     """
+    _check_window(q, k, causal, window)
+    if window is not None and window >= k.shape[1]:
+        window = None
     if impl == "reference":
-        return dot_product_attention(q, k, v, causal=causal)
+        return dot_product_attention(q, k, v, causal=causal, window=window)
     if impl == "blockwise":
-        return blockwise_attention(q, k, v, causal=causal)
+        return blockwise_attention(q, k, v, causal=causal, window=window)
     if impl == "flash":
-        return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal, None, None, window)
     tq, tk = q.shape[1], k.shape[1]
     on_tpu = jax.devices()[0].platform == "tpu"
     # Up to 1024 keys the scores are materialised by XLA, and for causal
@@ -652,8 +779,9 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto"):
     if tk <= 1024:
         rows = _causal_block_rows(tq) if causal and tq == tk else 0
         if rows:
-            return causal_blocked_attention(q, k, v, block_q=rows)
-        return dot_product_attention(q, k, v, causal=causal)
+            return causal_blocked_attention(q, k, v, block_q=rows,
+                                            window=window)
+        return dot_product_attention(q, k, v, causal=causal, window=window)
     if on_tpu and _flash_tiles("fwd", tq, tk, q.shape[-1], q.dtype):
-        return flash_attention(q, k, v, causal)
-    return blockwise_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal, None, None, window)
+    return blockwise_attention(q, k, v, causal=causal, window=window)

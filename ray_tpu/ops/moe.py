@@ -15,8 +15,23 @@ model's ``expert_capacity_factor``:
   is computed, whatever the imbalance; every shape is static (the rows
   are ``top_k x tokens`` in all, only the group boundaries are data);
   the arithmetic is the experts' own and nothing more. What OLMoE (64
-  experts, top-8) trains with. One chip's experts only: there is no
-  all-to-all here yet (ROADMAP B3).
+  experts, top-8) trains with. It can be told which experts it HOLDS
+  (``held``: one expert-parallel rank's consecutive share, what
+  SmallThinker's cell runs: 16 of 64): the router, the top-k and the
+  gates stay over all the experts, the assignments to held experts sort
+  to the front, the rest behind them as one run with no matrix, which
+  no grouped matmul visits (megablox's own sharded-groups case: more
+  group sizes than matrices, the rows past the last matrix zeroed), and
+  the result is the held experts' part of the sum. The row buffer stays
+  ``top_k x tokens`` rows whatever share is held: a smaller one could
+  overflow under a skewed router, and no assignment to a held expert is
+  ever dropped (the gathers and the elementwise work over the unused
+  rows are the price; PERF.md section 5 has what they cost on the v5e).
+  The caller may make the router's logits itself (``router_logits``;
+  ``router_matmul``), for a model whose router does not read the
+  experts' input, and the activation is SwiGLU's or ReGLU's. What is
+  NOT here is the exchange: there is no all-to-all yet (ROADMAP B2),
+  so a rank's share runs alone and nothing stands in for the others.
 * **capacity** (a number; ``moe_swiglu``, GShard / Switch style): dense
   one-hot dispatch / combine einsums over ``capacity`` slots an expert
   and group; what overflows is DROPPED (it passes through the residual).
@@ -242,7 +257,14 @@ def _use_megablox(lhs, rhs) -> bool:
 
 
 def _fit(tiles, k: int, n: int):
-    return (tiles[0], min(tiles[1], k), min(tiles[2], n))
+    """The tiles cut to the matrix: of each width its largest divisor in
+    whole lane tiles up to the tile (1024 for 2048 and 1024 themselves,
+    640 for SmallThinker's 2560, 768 for its experts' 768)."""
+    def most(width, tile):
+        return max(t for t in range(128, min(tile, width) + 1, 128)
+                   if width % t == 0)
+
+    return (tiles[0], most(k, tiles[1]), most(n, tiles[2]))
 
 
 def _megablox():
@@ -266,8 +288,9 @@ def _megablox_fwd(lhs, rhs, group_sizes):
 def _grouped_matmul_grads(lhs, rhs, group_sizes, g):
     """Both gradients of ``grouped_matmul(lhs, rhs, group_sizes)`` under
     the cotangent ``g`` [M, N]: of the rows, ``g`` times each group's
-    matrix transposed [M, K], and of the weights, each group's own
-    ``lhs^T x g`` [G, K, N]. Neither reads the product itself."""
+    matrix transposed [M, K] (zero in the rows of a group with no
+    matrix), and of the weights, each group's own ``lhs^T x g``
+    [G, K, N]. Neither reads the product itself."""
     k, n = rhs.shape[1], rhs.shape[2]
     if _use_megablox(lhs, rhs):
         backend = _megablox()
@@ -277,6 +300,7 @@ def _grouped_matmul_grads(lhs, rhs, group_sizes, g):
                             _fit(_TGMM_TILES, k, n),
                             num_actual_groups=rhs.shape[0])
         return dlhs, drhs
+    group_sizes = group_sizes[:rhs.shape[0]]
     dlhs = jax.lax.ragged_dot(g, rhs.swapaxes(1, 2), group_sizes)
     drhs = jax.lax.ragged_dot_general(
         lhs, g, group_sizes, jax.lax.RaggedDotDimensionNumbers(
@@ -297,11 +321,14 @@ _megablox_matmul.defvjp(_megablox_fwd, _megablox_bwd)
 def grouped_matmul(lhs, rhs, group_sizes):
     """``lhs`` [M, K] rows sorted by group, ``rhs`` [G, K, N],
     ``group_sizes`` int32 [G] summing to M -> [M, N]: each run of rows
-    times its own group's matrix. The selection is by what can be seen
-    (platform, dtype, shapes), like ``attention(impl="auto")``."""
+    times its own group's matrix. ``group_sizes`` may count MORE groups
+    than ``rhs`` holds matrices for (the runs of rows behind the held
+    experts'): no kernel visits those rows and the product is zero
+    there. The selection is by what can be seen (platform, dtype,
+    shapes), like ``attention(impl="auto")``."""
     if _use_megablox(lhs, rhs):
         return _megablox_matmul(lhs, rhs, group_sizes)
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes[:rhs.shape[0]])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -383,38 +410,92 @@ def _down_and_combine_bwd(res, d_out):
 _down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 
-def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
-                        norm_topk: bool = True):
-    """MoE SwiGLU FFN for one layer; every (token, choice) assignment is
-    computed.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
-    x [B, S, D]; router_w [D, E]; w_gate/w_up [E, D, F]; w_down [E, F, D].
-    Returns (out [B, S, D], {"balance", "z", "load_max"} scalars); the
-    losses are over all the tokens of ``x`` (see the module docstring).
+
+def router_matmul(x, router_w):
+    """The router's float32 logits [..., E] of ``x`` [..., D], under the
+    scope ``moe_router``: for a model whose router reads something other
+    than the experts' input (SmallThinker: the block's FIRST norm), so
+    the caller makes them where it has that input."""
+    with jax.named_scope("moe_router"):
+        return jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                          router_w.astype(jnp.float32))
+
+
+def held_range(num_experts: int, rank: int, of: int) -> tuple[int, int]:
+    """[first, end) of the experts that rank ``rank`` of ``of`` holds:
+    ``num_experts / of`` consecutive ones."""
+    if num_experts % of or not 0 <= rank < of:
+        raise ValueError(f"{num_experts} experts do not divide over {of} "
+                         f"ranks, or rank {rank} is not one of them")
+    share = num_experts // of
+    return rank * share, (rank + 1) * share
+
+
+def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
+                        norm_topk: bool = True, router_logits=None,
+                        held: tuple[int, int] | None = None,
+                        activation: str = "silu"):
+    """MoE gated FFN (``activation(gate) * up``: SwiGLU with "silu",
+    ReGLU with "relu") for one layer; every (token, choice) assignment
+    to an expert that is here is computed.
+
+    x [B, S, D]; router_w [D, E]; w_gate/w_up [Eh, D, F]; w_down
+    [Eh, F, D]. ``router_logits`` [B, S, E], where the caller made them
+    (``router_w`` is then not read). Returns (out [B, S, D], {"balance",
+    "z", "load_max"} scalars); the losses are over all the tokens of
+    ``x`` and all E experts (see the module docstring).
+
+    ``held`` = [first, end): the weights are those Eh = end - first of
+    the E experts, one expert-parallel rank's (``held_range``). The
+    router, the top-k and the gates stay over all E; the assignments to
+    the held experts sort to the front by expert, the others behind
+    them as one run that no grouped matmul visits (its rows are zero);
+    ``out`` is the held experts' part of the sum. The row buffer stays
+    ``top_k x tokens``: whatever the imbalance, no assignment to a held
+    expert is dropped. ``load_max`` is then over the held experts, and
+    ``held_share`` (the share of the assignments that went to one; 1 /
+    ranks at balance) joins the statistics.
     """
     B, S, D = x.shape
-    E = router_w.shape[-1]
     N, A = B * S, B * S * top_k
     dt = x.dtype
     xf = x.reshape(N, D)
+    if router_logits is None:
+        router_logits = router_matmul(xf, router_w)
     with jax.named_scope("moe_router"):
-        logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32),
-                            router_w.astype(jnp.float32))
+        logits = router_logits.reshape(N, -1).astype(jnp.float32)
+        E = logits.shape[-1]
         probs, gates, experts = route(logits, top_k, norm_topk)
         counts = _assignment_counts(experts, E)
         stats = {"balance": E * jnp.sum(counts / A * probs.mean(axis=0)),
-                 "z": router_z(logits), "load_max": _load_max(counts)}
+                 "z": router_z(logits)}
+        keys = experts.reshape(A).astype(jnp.int32)
+        if held is None:
+            stats["load_max"] = _load_max(counts)
+        else:
+            # Held experts first, by their place among the held; every
+            # other assignment behind them, in one run with no matrix.
+            first, end = held
+            here = (experts >= first) & (experts < end)
+            gates = jnp.where(here, gates, 0.0)
+            keys = jnp.where(here.reshape(A), keys - first, end - first)
+            held_counts = counts[first:end]
+            counts = jnp.concatenate(
+                [held_counts, (A - held_counts.sum())[None]])
+            stats["load_max"] = _load_max(held_counts)
+            stats["held_share"] = held_counts.sum() / jnp.float32(A)
     with jax.named_scope("moe_dispatch"):
         # Stable sort of the assignments by expert: ``order[a]`` is the
         # assignment that lands in row a, ``inverse`` the other way.
         iota = jnp.arange(A, dtype=jnp.int32)
-        _, order = jax.lax.sort((experts.reshape(A).astype(jnp.int32), iota),
-                                num_keys=1)
+        _, order = jax.lax.sort((keys, iota), num_keys=1)
         inverse = jnp.zeros((A,), jnp.int32).at[order].set(iota)
         rows = _rows_to_experts(xf, order, inverse, top_k)
     with jax.named_scope("moe_experts"):
         gate = grouped_matmul(rows, w_gate.astype(dt), counts)
         up = grouped_matmul(rows, w_up.astype(dt), counts)
-        h, w_down = jax.nn.silu(gate) * up, w_down.astype(dt)
+        h, w_down = ACTIVATIONS[activation](gate) * up, w_down.astype(dt)
     out = _down_and_combine(h, w_down, gates, counts, order, inverse)
     return out.reshape(B, S, D), stats
