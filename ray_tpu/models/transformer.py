@@ -59,7 +59,8 @@ from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import moe
-from ray_tpu.ops.attention import attention, dot_product_attention
+from ray_tpu.ops.attention import (FLASH_LSE_NAME, FLASH_OUT_NAME, attention,
+                                   dot_product_attention)
 from ray_tpu.ops.layers import (
     apply_rope,
     gelu_mlp,
@@ -136,7 +137,9 @@ class TransformerConfig:
     # Checkpoint policy: "full" recomputes the whole layer (max memory
     # savings); "dots" saves matmul outputs and recomputes only cheap
     # elementwise ops — ~MXU-free backward at a fraction of full remat's
-    # 1/3 FLOP overhead. Small models should prefer "dots".
+    # 1/3 FLOP overhead. Small models should prefer "dots". Under either,
+    # a layer whose attention ran the Pallas kernel also keeps that
+    # kernel's output and logsumexp (``layer_of``).
     remat_policy: str = "full"       # "full" | "dots"
     scan_layers: bool = True         # lax.scan over layers vs unrolled loop
     # Chunked LM-head loss: compute logits/CE in chunks of this many
@@ -636,12 +639,18 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
 
         if not c.remat:
             return layer
+        # Whatever else is recomputed, the Pallas attention kernel's output
+        # and logsumexp are kept: its backward needs exactly these two, and
+        # the recompute would launch the forward kernel again for them. A
+        # layer whose attention took another path has no such value, and
+        # the policy saves nothing more than it did.
+        policies = jax.checkpoint_policies
+        policy = policies.save_only_these_names(FLASH_OUT_NAME,
+                                                FLASH_LSE_NAME)
         if c.remat_policy == "dots":
-            return jax.checkpoint(
-                layer,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-            )
-        return jax.checkpoint(layer)
+            policy = policies.save_from_both_policies(
+                policies.dots_with_no_batch_dims_saveable, policy)
+        return jax.checkpoint(layer, policy=policy)
 
     period = max(1, len(c.layer_pattern))
     layers = [layer_of(c.layer_kind(i)) for i in range(period)]

@@ -7,6 +7,8 @@ shaped for XLA fusion.
 """
 
 from ray_tpu.ops.attention import (
+    FLASH_LSE_NAME,
+    FLASH_OUT_NAME,
     attention,
     blockwise_attention,
     dot_product_attention,
@@ -27,6 +29,8 @@ __all__ = [
     "blockwise_attention",
     "dot_product_attention",
     "flash_attention",
+    "FLASH_OUT_NAME",
+    "FLASH_LSE_NAME",
     "apply_rope",
     "gelu_mlp",
     "layer_norm",

@@ -37,6 +37,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 
@@ -693,7 +694,17 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
 
     Training runs the Pallas BACKWARD kernels (dq pass + dk/dv pass,
     probabilities recomputed per tile from the saved logsumexp): O(T)
-    memory end to end, no XLA recompute graph.
+    memory end to end, no XLA recompute graph. The residuals are q, k, v,
+    the output and the logsumexp ([B*H, Tq] float32). Under
+    differentiation the last two carry the checkpoint names
+    ``FLASH_OUT_NAME`` / ``FLASH_LSE_NAME``: a caller that wraps its
+    layer in ``jax.checkpoint`` with a policy saving those names (the
+    model's ``layer_of`` does) keeps them, one output's worth of memory a
+    layer, because they are all the backward kernels need that only the
+    forward kernel can make: the recompute pass then rebuilds q, k, v and
+    never launches the forward kernel a second time. With no such policy
+    the names do nothing; the primal (serving, any undifferentiated call)
+    has none.
 
     Each of the three kernels sizes its own tiles from the shapes and
     the dtype (``_flash_tiles``). ``block_q`` / ``block_k`` set the tiles
@@ -721,19 +732,34 @@ def _check_window(q, k, causal, window):
             f"Tq={q.shape[1]}, Tk={k.shape[1]}, window={window}")
 
 
+# Checkpoint names of the kernel's output and logsumexp under
+# differentiation (``flash_attention``'s docstring; saved by the policy of
+# ``models/transformer.py`` ``layer_of``).
+FLASH_OUT_NAME = "flash_attention_out"
+FLASH_LSE_NAME = "flash_attention_lse"
+
+
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window):
     _check_window(q, k, causal, window)
     out, lse = _flash_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=_interpret(), return_lse=True, window=window,
     )
+    # ONE named ``out`` is both the value returned (what the caller's
+    # output projection reads) and the backward kernels' residual: were
+    # either a tensor the policy does not save, the recompute would run
+    # the kernel for it. ``lse`` is named as [bh, tq]: saved as the kernel
+    # writes it, [bh, tq, 1] float32, its last dimension can be padded to
+    # the 128 lanes in HBM.
+    out = checkpoint_name(out, FLASH_OUT_NAME)
+    lse = checkpoint_name(lse[..., 0], FLASH_LSE_NAME)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(
-        q, k, v, out, lse, g, causal=causal, block_q=block_q,
+        q, k, v, out, lse[..., None], g, causal=causal, block_q=block_q,
         block_k=block_k, interpret=_interpret(), window=window,
     )
 
