@@ -5,8 +5,9 @@
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics with ``--trace 0``,
 its per-layer metrics with ``--trace 1``), ``device`` and, traced,
-``breakdown``. Earlier lines say what was counted and name the checks
-behind ``correct``.
+``breakdown``. Earlier lines say what was counted, give each number
+compared beside its limit (``compared ...``) and name the checks behind
+``correct``; those two kinds are the last lines of stderr too.
 
 There is no CPU mode: on a machine with no chip, or fewer chips than the
 cell asks for, the command prints no result and exits non-zero. This
@@ -126,9 +127,15 @@ def main(argv: list[str] | None = None) -> int:
         args.seconds = float(spec.load_benchmark()["run_seconds"])
     result = run_cell(args.workload, seed=args.seed, seconds=args.seconds,
                       trace=bool(args.trace))
-    for note in result.pop("notes"):
+    notes = result.pop("notes")
+    for note in notes:
         print(f"[chipbench] {note}", flush=True)
     print(json.dumps(result), flush=True)
+    # Each number compared beside its limit, and the checks, as the last
+    # lines of standard error too: what is kept of a run that is not correct.
+    for note in notes:
+        if note.startswith(("compared ", "check ")):
+            print(f"[chipbench] {note}", file=sys.stderr, flush=True)
     return 0
 
 

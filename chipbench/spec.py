@@ -34,6 +34,17 @@ spec tests run over every file in ``configs/``, and the rehearsal compile
 (``tests/chipbench/test_chipbench_rehearsal.py``) over every entry of
 ``workloads``. ``tests/chipbench/_tinycells.py`` does exactly this, for
 an architecture called ``tinymoe``, in a temporary copy.
+
+What a train cell's ``correct`` holds the program to is
+``reference/_common.py`` ``LIMITS``. A configuration whose sound program
+and controls do not fit ``logit_rel_d``'s (they move with width and
+depth) states its own under ``agreement_limits`` in its file: the limit
+WITH the two readings on the chip that place it (the sound program's
+worst, the nearest control's: ``python -m chipbench.reference.compare
+--control ...``) and the why. ``limits_for`` refuses a limit that those
+readings do not place by the one rule every limit obeys, so a file
+chooses its readings' cell, never its pass mark; the table they come
+from goes to PERF.md section 4.
 """
 
 from __future__ import annotations

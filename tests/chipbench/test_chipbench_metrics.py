@@ -63,6 +63,55 @@ def test_train_readers_on_a_hand_made_run():
     assert read["train_tok_s_chip"](_train_run([])) is None
 
 
+@pytest.mark.parametrize("name,metrics,want", [
+    # 16 of 64 experts held: balance is a quarter of the assignments
+    ("moe_held_off_balance", {"loss": 11.0, "moe_held_share": 0.2375}, 0.0125),
+    ("moe_held_off_balance", {"loss": 11.0, "moe_held_share": 0.44}, 0.19),
+    ("moe_load_max", {"loss": 11.0, "moe_load_max": 4.75}, 4.75),
+    # a dense step reports no such counter: nothing to read, never a 0
+    ("moe_held_off_balance", {"loss": 11.0, "moe_load_max": 4.75}, None),
+    ("moe_load_max", {"loss": 11.0}, None),
+    # a report from before the counters reached it, or no report at all
+    ("moe_held_off_balance", None, None),
+    ("moe_load_max", None, None),
+])
+def test_counter_readers_take_the_last_steps_scalar(name, metrics, want):
+    run = _train_run([2.0])
+    run["cell"]["config_data"] = spec.load_json(
+        "chipbench", "configs", "smallthinker-21b-a3b-ep4.json")
+    if metrics is not None:
+        run["train"]["step_metrics"] = metrics
+    read = spec.load_part("layer_metrics", name).read
+    assert read(run) == (want if want is None else pytest.approx(want))
+    assert read({"kind": "train", "train": None}) is None
+
+
+def test_a_held_share_where_no_expert_is_held_has_no_balance_to_be_off():
+    run = _train_run([2.0])
+    run["cell"]["config_data"] = spec.load_json(
+        "chipbench", "configs", "olmoe-1b-7b-1chip.json")
+    run["train"]["step_metrics"] = {"loss": 11.0, "moe_held_share": 1.0}
+    read = spec.load_part("layer_metrics", "moe_held_off_balance").read
+    assert read(run) is None
+
+
+def _counter_cells():
+    return [(m["name"], cell) for m in spec.load_benchmark()["per_layer"]
+            if m["source"] == "program_counter" for cell in m["workloads"]]
+
+
+@pytest.mark.parametrize("name,cell", _counter_cells())
+def test_a_counter_reader_lists_only_cells_whose_step_reports_it(name, cell):
+    """Whatever cells a later PR appends: each is a cell of the benchmark
+    that trains a model with experts (the step of a dense one reports no
+    routing counter), and the off-balance reader's cells hold a share."""
+    cfg = spec.model_config(spec.load_cell(cell)["config_data"])
+    assert cfg.n_experts > 0, (name, cell)
+    if name == "moe_held_off_balance":
+        assert cfg.experts_held is not None, cell
+    assert callable(spec.load_part("layer_metrics", name).read)
+
+
 def test_mfu_is_model_flops_over_the_peak_at_the_median_step():
     data = spec.load_json("chipbench", "configs", "gpt2-xl-1chip.json")
     run = _train_run([3.2, 3.2, 3.2], steps=8)
