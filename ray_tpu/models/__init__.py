@@ -19,6 +19,7 @@ from ray_tpu.models.transformer import (
     init_kv_cache,
     init_params,
     init_train_state,
+    kanana_2_30b_a3b,
     llama2_7b,
     llama3_8b,
     lm_loss,
@@ -52,6 +53,7 @@ __all__ = [
     "init_kv_cache",
     "init_params",
     "init_train_state",
+    "kanana_2_30b_a3b",
     "llama2_7b",
     "llama3_8b",
     "lm_loss",

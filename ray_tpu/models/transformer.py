@@ -34,7 +34,17 @@ and its experts run after the second norm), ReGLU experts, and **held
 experts** (``experts_held = (rank, of)``: the parameters are one
 expert-parallel rank's share of every layer's experts, the router still
 chooses among all of them, and the block adds the held experts' part of
-the sum; ``ops/moe.py``). With a period of P > 1 the scan runs over
+the sum; ``ops/moe.py``). And a DeepSeek-V3-shaped model (Kanana-2):
+**latent attention** (``kv_latent``: keys and values are made from one
+narrow compression of the token, the rotary part of the key is ONE
+vector all heads share, and values are narrower than queries and keys),
+a count of **leading dense layers** with an FFN width of their own
+(``n_dense_layers``: a SECOND stack of parameters, ``dense_layers``,
+run ahead of the scan over ``layers``), a **shared expert** beside the
+routed ones (``d_ff_shared``), and a **sigmoid router** whose learned-by-
+rule bias only the choice of experts sees (``router_score``,
+``router_bias``; the step moves the bias itself, ``make_train_step``).
+With a period of P > 1 the scan runs over
 WHOLE PERIODS and unrolls a period's P layers in its body, so each
 position's kind is static: a windowed layer compiles to the kernel that
 skips tiles, never to a ``cond`` over both kinds.
@@ -82,18 +92,24 @@ from ray_tpu.parallel.sharding import constrain
 # ``embed``, then ``layers`` (the layer scan or loop; alone on an
 # instruction it is the scan's own traffic) around per block ``attn_norm``,
 # ``attn`` (projections, rope, scores, output projection), ``mlp_norm``,
-# ``mlp`` (``moe`` with experts; the dropless dispatch opens its own
-# sub-scopes inside it, ``ops.moe.SCOPES``; a router that reads the first
-# norm runs under ``moe`` / ``moe_router`` ahead of ``attn``), then
+# ``mlp`` (``moe`` with experts; the dropless dispatch and the shared
+# expert open their own sub-scopes inside it, ``ops.moe.SCOPES``; a router
+# that reads the first norm runs under ``moe`` / ``moe_router`` ahead of
+# ``attn``; an expert model's leading dense layers run under ``mlp``), then
 # ``final_norm`` and
 # ``head_loss`` (head matmul + every cross entropy); in make_train_step
 # ``grad_accum`` (the micro-batch scan's sums) and ``optimizer`` (update +
 # apply).
 SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
           "final_norm", "head_loss", "grad_accum", "optimizer")
-# Inside ``attn``, for a model with a ``layer_pattern`` only: which kind
-# of layer the instruction belongs to.
+# Inside ``attn``, for a model with a ``layer_pattern`` or with latent
+# attention (whose layers are all full causal ones): which kind of layer
+# the instruction belongs to.
 ATTN_SCOPES = ("attn_full", "attn_window")
+# Inside ``attn`` (and its ``attn_full``), latent attention only: what the
+# latent form adds outside the kernels (down-projection, the latent's
+# norm, up-projection, RoPE on the rotary parts).
+MLA_SCOPE = "mla_latent"
 
 # Which tree's scopes an executable carries. jax's compile-cache key leaves
 # metadata out, so a step loaded from the cache would keep the scope names
@@ -199,6 +215,36 @@ class TransformerConfig:
     # share; the router and the top-k stay over all n_experts and the
     # block adds the held experts' part of the sum (dropless only).
     experts_held: tuple[int, int] | None = None
+    # -- a DeepSeek-V3-shaped model (llama arch) --------------------------
+    # Latent attention (MLA): the width of the compressed key / value
+    # latent (None: plain attention). A head's query and key are then
+    # ``d_head_nope`` wide without positions plus ``d_head_rope`` with
+    # RoPE, the rotary key ONE vector all heads share; its value is
+    # ``d_head_v`` wide. ``d_head`` is not read.
+    kv_latent: int | None = None
+    d_head_nope: int = 0
+    d_head_rope: int = 0
+    d_head_v: int = 0
+    # The first ``n_dense_layers`` of the ``n_layers`` have a dense FFN
+    # ``d_ff_dense`` wide in place of experts: a second stack of
+    # parameters, ``params["dense_layers"]``, ahead of ``params["layers"]``.
+    n_dense_layers: int = 0
+    d_ff_dense: int | None = None
+    # A gated FFN this wide that every token passes through beside its
+    # routed experts, ungated (0: none).
+    d_ff_shared: int = 0
+    # How the router scores an expert: "softmax" over all of them, or a
+    # "sigmoid" each (the gates are then the chosen scores, renormalised
+    # under ``expert_norm_topk``).
+    router_score: str = "softmax"
+    # A bias an expert, a leaf of the parameters (``layers/router/b``),
+    # added to the scores for the CHOICE alone: no gate and no gradient
+    # sees it. The train step sets it, after the optimizer (whose update
+    # and decay never touch it), to ``b + router_bias_rate x sign(mean
+    # load - the expert's load)`` from the step's own assignment counts.
+    router_bias: bool = False
+    router_bias_rate: float = 0.0
+    expert_gate_scale: float = 1.0   # x the gates, after renormalising
 
     def __post_init__(self):
         # A config file's JSON gives lists: keep the config hashable.
@@ -213,7 +259,16 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        """The width of a head's query and key."""
+        if self.kv_latent is not None:
+            return self.d_head_nope + self.d_head_rope
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def n_scan_layers(self) -> int:
+        """Layers of the main stack ``params["layers"]``: all but the
+        leading dense ones."""
+        return self.n_layers - self.n_dense_layers
 
     @property
     def held_range(self) -> tuple[int, int] | None:
@@ -373,6 +428,31 @@ def smallthinker_21b_a3b(**kw) -> TransformerConfig:
     )
 
 
+def kanana_2_30b_a3b(**kw) -> TransformerConfig:
+    """kanana-2-30b-a3b (kakaocorp ``config.json``, ``model_type``
+    ``deepseek_v3``): 48 layers, the first dense (SwiGLU 6,144); latent
+    attention, 32 heads of 128 + 64 (rotary, one key for all heads)
+    against values of 128 from a 512-wide latent, no query latent; 128
+    SwiGLU experts 768 wide, 6 a token by a sigmoid router whose bias
+    only the choice sees, gates renormalised and scaled by 2.448, two
+    shared experts as one SwiGLU of 1,536; no router loss term. The
+    bias's rate and rule are DeepSeek-V3's (arXiv:2412.19437)."""
+    return replace(
+        TransformerConfig(
+            vocab_size=128256, n_layers=48, d_model=2048, n_heads=32,
+            d_ff=768, max_seq_len=32768, arch="llama", rope_theta=1e6,
+            norm_eps=1e-6, kv_latent=512, d_head_nope=128, d_head_rope=64,
+            d_head_v=128, n_dense_layers=1, d_ff_dense=6144,
+            d_ff_shared=1536, n_experts=128, expert_top_k=6,
+            expert_capacity_factor=None, expert_norm_topk=True,
+            router_aux_weight=0.0, router_z_weight=0.0,
+            router_score="sigmoid", router_bias=True, router_bias_rate=1e-3,
+            expert_gate_scale=2.448,
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -432,15 +512,43 @@ def _check_config(c: TransformerConfig) -> None:
                          f"{sorted(moe.ACTIVATIONS)}")
     if c.router_input not in ("mlp_norm", "attn_norm"):
         raise ValueError("router_input must be 'mlp_norm' or 'attn_norm'")
+    if c.router_score not in moe.ROUTER_SCORES:
+        raise ValueError(f"router_score must be one of {moe.ROUTER_SCORES}")
     asks_dropless = (c.experts_held is not None
                      or c.router_input != "mlp_norm"
-                     or c.expert_activation != "silu")
+                     or c.expert_activation != "silu"
+                     or c.router_score != "softmax" or c.router_bias
+                     or c.expert_gate_scale != 1.0 or c.d_ff_shared)
     if asks_dropless and (c.n_experts == 0
                           or c.expert_capacity_factor is not None):
         raise ValueError(
-            "experts_held, router_input='attn_norm' and a ReGLU "
-            "expert_activation are the dropless path's "
+            "experts_held, router_input='attn_norm', a ReGLU "
+            "expert_activation, router_score='sigmoid', router_bias, "
+            "expert_gate_scale and d_ff_shared are the dropless path's "
             "(n_experts > 0, expert_capacity_factor=None)")
+    if c.router_bias_rate and not c.router_bias:
+        raise ValueError("router_bias_rate moves the router_bias: set it")
+    if c.kv_latent is not None:
+        for name, wrong in (
+                ("arch != 'llama'", c.arch != "llama"),
+                ("qk_norm", c.qk_norm),
+                ("a layer_pattern", bool(c.layer_pattern)),
+                ("GQA (n_kv_heads < n_heads)", c.kv_heads != c.n_heads)):
+            if wrong:
+                raise ValueError(
+                    f"latent attention (kv_latent) does not run with {name}")
+        if min(c.kv_latent, c.d_head_nope, c.d_head_rope, c.d_head_v) < 1 \
+                or c.d_head_rope % 2:
+            raise ValueError(
+                "latent attention needs kv_latent, d_head_nope, d_head_v >= "
+                "1 and an even d_head_rope >= 2")
+    if c.n_dense_layers:
+        if (c.n_experts == 0 or c.layer_pattern or c.d_ff_dense is None
+                or not 0 < c.n_dense_layers < c.n_layers):
+            raise ValueError(
+                "n_dense_layers are the first of an expert model's n_layers "
+                "(n_experts > 0, no layer_pattern, 0 < n_dense_layers < "
+                "n_layers) and need their FFN width d_ff_dense")
     if c.experts_held is not None:      # raises where they do not divide
         moe.held_range(c.n_experts, *c.experts_held)
 
@@ -450,31 +558,56 @@ def init_params(rng, config: TransformerConfig):
 
     Layer params carry a leading [n_layers] axis (consumed by lax.scan).
     GPT-2 init: N(0, 0.02), residual-out projections scaled by
-    1/sqrt(2*n_layers).
+    1/sqrt(2*n_layers). A model with leading dense layers has two
+    stacks: ``dense_layers`` [n_dense_layers, ...] and ``layers`` (the
+    expert layers, [n_layers - n_dense_layers, ...]).
     """
     c = config
     _check_config(c)
     pdt = jnp.dtype(c.param_dtype)
     L, D, H, KV, Dh, F = (
-        c.n_layers, c.d_model, c.n_heads, c.kv_heads, c.head_dim, c.ffn_dim,
+        c.n_scan_layers, c.d_model, c.n_heads, c.kv_heads, c.head_dim,
+        c.ffn_dim,
     )
     std = 0.02
-    res_std = std / math.sqrt(2 * L)
+    res_std = std / math.sqrt(2 * c.n_layers)
     keys = iter(jax.random.split(rng, 16))
+    # What a DeepSeek-V3-shaped model adds draws from keys of its own, so
+    # every other model's weights stay what the seed always gave.
+    more = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
 
     def norm(key, *shape, s=std):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
 
+    def attn_stack(keys, n):
+        if c.kv_latent is None:
+            return {
+                "wq": norm(next(keys), n, D, H, Dh),
+                "wk": norm(next(keys), n, D, KV, Dh),
+                "wv": norm(next(keys), n, D, KV, Dh),
+                "wo": norm(next(keys), n, H, Dh, D, s=res_std),
+            }
+        # [latent ; the one rotary key] down, the latent's norm, then
+        # [k_nope ; v] of every head up.
+        return {
+            "wq": norm(next(keys), n, D, H, Dh),
+            "wkv_a": norm(next(keys), n, D, c.kv_latent + c.d_head_rope),
+            "kv_norm": jnp.ones((n, c.kv_latent), pdt),
+            "wkv_b": norm(next(keys), n, c.kv_latent, H,
+                          c.d_head_nope + c.d_head_v),
+            "wo": norm(next(keys), n, H, c.d_head_v, D, s=res_std),
+        }
+
+    def ffn_stack(keys, n, width):
+        return {
+            "w_gate": norm(next(keys), n, D, width),
+            "w_up": norm(next(keys), n, D, width),
+            "w_down": norm(next(keys), n, width, D, s=res_std),
+        }
+
     params = {
         "embed": {"tokens": norm(next(keys), c.vocab_size, D)},
-        "layers": {
-            "attn": {
-                "wq": norm(next(keys), L, D, H, Dh),
-                "wk": norm(next(keys), L, D, KV, Dh),
-                "wv": norm(next(keys), L, D, KV, Dh),
-                "wo": norm(next(keys), L, H, Dh, D, s=res_std),
-            },
-        },
+        "layers": {"attn": attn_stack(keys, L)},
         "final_norm": {"w": jnp.ones((D,), pdt)},
     }
     if c.arch == "gpt2":
@@ -507,11 +640,25 @@ def init_params(rng, config: TransformerConfig):
                 "w_up": norm(next(keys), L, E, D, F),
                 "w_down": norm(next(keys), L, E, F, D, s=res_std),
             }
+            if c.router_bias:
+                # Seeded like a matrix, not zeros: with zeros a program
+                # that let the bias into the gates would compute what a
+                # sound one computes.
+                params["layers"]["router"]["b"] = norm(
+                    next(more), L, c.n_experts)
+            if c.d_ff_shared:
+                shared = ffn_stack(more, L, c.d_ff_shared)
+                params["layers"]["mlp"].update(
+                    {f"shared_{name}": w for name, w in shared.items()})
         else:
-            params["layers"]["mlp"] = {
-                "w_gate": norm(next(keys), L, D, F),
-                "w_up": norm(next(keys), L, D, F),
-                "w_down": norm(next(keys), L, F, D, s=res_std),
+            params["layers"]["mlp"] = ffn_stack(keys, L, F)
+        if c.n_dense_layers:
+            n = c.n_dense_layers
+            params["dense_layers"] = {
+                "attn": attn_stack(more, n),
+                "ln1": {"w": jnp.ones((n, D), pdt)},
+                "ln2": {"w": jnp.ones((n, D), pdt)},
+                "mlp": ffn_stack(more, n, c.d_ff_dense),
             }
     if not c.tied:
         params["lm_head"] = norm(next(keys), D, c.vocab_size)
@@ -529,18 +676,24 @@ def partition_specs(config: TransformerConfig):
     embedding/head. FSDP is layered on top by infer_param_specs.
     """
     c = config
+    attn = {
+        "wq": P(None, None, AXIS_TENSOR, None),
+        "wk": P(None, None, AXIS_TENSOR, None),
+        "wv": P(None, None, AXIS_TENSOR, None),
+        "wo": P(None, AXIS_TENSOR, None, None),
+        # latent attention: the down-projection and the latent's norm are
+        # every head's; the up-projection shards by head like wq
+        "wkv_b": P(None, None, AXIS_TENSOR, None),
+    }
+    ffn = {
+        "w_gate": P(None, None, AXIS_TENSOR),
+        "w_up": P(None, None, AXIS_TENSOR),
+        "w_down": P(None, AXIS_TENSOR, None),
+    }
     specs = {
         "embed": {"tokens": P(AXIS_TENSOR, None)},
-        "layers": {
-            "attn": {
-                "wq": P(None, None, AXIS_TENSOR, None),
-                "wk": P(None, None, AXIS_TENSOR, None),
-                "wv": P(None, None, AXIS_TENSOR, None),
-                "wo": P(None, AXIS_TENSOR, None, None),
-            },
-            "ln1": None,
-            "ln2": None,
-        },
+        "layers": {"attn": attn, "ln1": None, "ln2": None},
+        "dense_layers": {"attn": attn, "ln1": None, "ln2": None, "mlp": ffn},
         "final_norm": None,
     }
     if c.arch == "gpt2":
@@ -557,13 +710,10 @@ def partition_specs(config: TransformerConfig):
             "w_gate": P(None, AXIS_EXPERT, None, AXIS_TENSOR),
             "w_up": P(None, AXIS_EXPERT, None, AXIS_TENSOR),
             "w_down": P(None, AXIS_EXPERT, AXIS_TENSOR, None),
+            **{f"shared_{name}": spec for name, spec in ffn.items()},
         }
     else:
-        specs["layers"]["mlp"] = {
-            "w_gate": P(None, None, AXIS_TENSOR),
-            "w_up": P(None, None, AXIS_TENSOR),
-            "w_down": P(None, AXIS_TENSOR, None),
-        }
+        specs["layers"]["mlp"] = ffn
     if not c.tied:
         specs["lm_head"] = P(None, AXIS_TENSOR)
     # Expand None-marked subtrees to per-leaf None specs.
@@ -585,6 +735,31 @@ def _mirror(specs, shapes):
 # -- forward ----------------------------------------------------------------
 
 _BATCH = (AXIS_DATA, AXIS_FSDP)
+# A stack of a few LARGE layers runs unrolled, as ONE scan step
+# (``lax.scan``'s ``unroll``): around a ``while`` loop XLA keeps whole-stack
+# temporaries (the casts of the stacked weights hoisted out of the loop,
+# the stacked gradients beside the optimizer's) that layers laid out in
+# line do not need. kanana-2's cell, four expert layers of 446 MB each, is
+# 16.58 GB in the compiler's account as a loop and 12.25 GB in line (PR 35,
+# rehearsal compile for the v5e). Both bounds are what can be seen at trace
+# time: at most this many steps (longer stacks keep the loop: compile time
+# is O(1) in depth there), and at least this many bytes of parameters in
+# the stack (below it the temporaries are small change). A stack of one
+# step (a period of SmallThinker's, OLMoE's one layer) is what it was.
+_SCAN_UNROLL_MOST = 4
+_SCAN_UNROLL_BYTES = 2 ** 30
+
+
+def _scan_unroll(stack, steps: int) -> int:
+    """Steps of the scan over ``stack`` that run in line (``unroll``)."""
+    size = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(stack))
+    large = 1 < steps <= _SCAN_UNROLL_MOST and size >= _SCAN_UNROLL_BYTES
+    return steps if large else 1
+
+
+def _scan_layers(body, x, stack, steps: int):
+    """``lax.scan`` of ``body`` over the leading axis of ``stack``."""
+    return jax.lax.scan(body, x, stack, unroll=_scan_unroll(stack, steps))
 
 
 def forward(params, tokens, config: TransformerConfig, *, mesh=None,
@@ -598,7 +773,10 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     layers, ``{"balance", "z", "load_max"}`` (``ops/moe.py``; the two loss
     terms as means, the fullest layer's ``load_max``; zeros for a dense
     model; with ``experts_held`` also ``held_share``, the mean over the
-    layers). ``return_hidden`` skips the LM head and returns the final
+    layers; with a ``router_bias`` also ``expert_counts`` [layers,
+    experts], the batch's assignments to every expert, and
+    ``bias_swapped``, the mean over the layers). ``return_hidden`` skips
+    the LM head and returns the final
     normed hidden states [B, T, D] (the chunked-loss path applies the head
     itself).
     """
@@ -626,16 +804,17 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             x = x + pos_emb.astype(dt)
             rope = None
         else:
-            cos, sin = rope_frequencies(c.head_dim, c.max_seq_len,
-                                        theta=c.rope_theta)
+            cos, sin = rope_frequencies(
+                c.head_dim if c.kv_latent is None else c.d_head_rope,
+                c.max_seq_len, theta=c.rope_theta)
             rope = (cos, sin)
         x = con(x, _BATCH, AXIS_SEQUENCE, None)
 
-    def layer_of(kind):
+    def layer_of(kind, dense: bool = False):
         """The block of one kind of layer (static), under remat."""
         def layer(x, lp):
             return _block(x, lp, c, rope=rope, con=con, positions=positions,
-                          kind=kind)
+                          kind=kind, dense=dense)
 
         if not c.remat:
             return layer
@@ -658,9 +837,20 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     # ``layers`` holds what belongs to no one part of a block: the scan's
     # reads of the stacked weights and writes of their stacked gradients.
     with jax.named_scope("layers"):
+        if c.n_dense_layers:
+            # the leading dense layers: a stack of their own, a scan (or
+            # loop) of its own ahead of the expert layers'
+            dense = layer_of(None, dense=True)
+            if c.scan_layers:
+                x, _ = _scan_layers(dense, x, params["dense_layers"],
+                                    c.n_dense_layers)
+            else:
+                for i in range(c.n_dense_layers):
+                    x, _ = dense(x, jax.tree.map(lambda a, i=i: a[i],
+                                                 params["dense_layers"]))
         if c.scan_layers and period == 1:
-            x, auxs = jax.lax.scan(lambda h, lp: layers[0](h, lp), x,
-                                   params["layers"])
+            x, auxs = _scan_layers(lambda h, lp: layers[0](h, lp), x,
+                                   params["layers"], c.n_scan_layers)
         elif c.scan_layers:
             # One scan step is one whole period, its layers unrolled: the
             # stacked [L, ...] weights are read as [L / P, P, ...].
@@ -671,15 +861,16 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                     per_position.append(aux_i)
                 return h, jax.tree.map(lambda *a: jnp.stack(a), *per_position)
 
-            x, auxs = jax.lax.scan(one_period, x, jax.tree.map(
-                lambda a: a.reshape(c.n_layers // period, period,
-                                    *a.shape[1:]), params["layers"]))
+            x, auxs = _scan_layers(one_period, x, jax.tree.map(
+                lambda a: a.reshape(c.n_scan_layers // period, period,
+                                    *a.shape[1:]), params["layers"]),
+                c.n_scan_layers // period)
         else:
             # Unrolled: larger compile, but lets XLA schedule across layer
             # boundaries (and sidesteps scan-differentiation limits on some
             # backends when remat is off).
             per_layer = []
-            for i in range(c.n_layers):
+            for i in range(c.n_scan_layers):
                 lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
                 x, aux_i = layers[i % period](x, lp)
                 per_layer.append(aux_i)
@@ -688,6 +879,9 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                "load_max": auxs["load_max"].max()}
         if c.experts_held is not None:
             aux["held_share"] = auxs["held_share"].mean()
+        if c.router_bias:
+            aux["expert_counts"] = auxs["counts"]
+            aux["bias_swapped"] = auxs["bias_swapped"].mean()
 
     with jax.named_scope("final_norm"):
         if c.arch == "gpt2":
@@ -706,14 +900,18 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
 
 
 def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
-           kind=None):
+           kind=None, dense: bool = False):
     """One transformer block (pre-norm residual). Its parts carry the
     scopes ``attn_norm``, ``attn``, ``mlp_norm`` and ``mlp`` / ``moe``
     (see SCOPES); each part's residual add is inside its scope. ``kind``
     = (windowed, rope) is the layer's place in the ``layer_pattern``
     (static; None with no pattern: full causal attention, the arch's own
-    positions), and names the sub-scope its attention runs under."""
+    positions), and names the sub-scope its attention runs under.
+    ``dense``: one of an expert model's leading dense layers."""
     dt = c.compute_dtype
+    experts = c.n_experts > 0 and not dense
+    if c.kv_latent is not None:
+        kind = (False, True)        # full causal layers, all of them
     window = None
     if kind is not None:
         windowed, with_rope = kind
@@ -725,37 +923,20 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
         else:
             h = rms_norm(x, lp["ln1"]["w"], eps=c.norm_eps)
     router = None
-    if c.n_experts > 0 and c.router_input == "attn_norm":
+    if experts and c.router_input == "attn_norm":
         with jax.named_scope("moe"):
             router = moe.router_matmul(h, lp["router"]["w"])
     with jax.named_scope("attn"), (
             contextlib.nullcontext() if kind is None else jax.named_scope(
                 ATTN_SCOPES[window is not None])):
-        if c.kv_heads == c.n_heads:
-            # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
-            # three skinny d→d projections (the weight concat is a few MB,
-            # amortized by XLA across the fused step).
-            wqkv = jnp.concatenate(
-                [lp["attn"]["wq"].astype(dt), lp["attn"]["wk"].astype(dt),
-                 lp["attn"]["wv"].astype(dt)],
-                axis=-1,
-            )  # [d, h, 3k]
-            qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
+        if c.kv_latent is not None:
+            q, k, v, shared = _latent_qkv(h, lp["attn"], c, rope, positions)
         else:
-            q = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wq"].astype(dt))
-            k = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wk"].astype(dt))
-            v = jnp.einsum("btd,dhk->bthk", h, lp["attn"]["wv"].astype(dt))
-        if c.qk_norm:
-            q = _qk_norm(q, lp["attn"]["q_norm"])
-            k = _qk_norm(k, lp["attn"]["k_norm"])
-        if rope is not None:
-            cos, sin = rope
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
-        k, v = _expand_gqa(k, v, c)
+            q, k, v = _plain_qkv(h, lp["attn"], c, rope, positions)
+            shared = {}
         q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-        o = attention(q, k, v, causal=True, impl=c.attn_impl, window=window)
+        o = attention(q, k, v, causal=True, impl=c.attn_impl, window=window,
+                      **shared)
         o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
         x = x + o
 
@@ -773,7 +954,7 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                          lp["mlp"]["w_out"].astype(dt),
                          lp["mlp"]["b_out"].astype(dt))
             x = x + m
-    elif c.n_experts > 0:
+    elif experts:
         weights = (lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
                    lp["mlp"]["w_down"])
         with jax.named_scope("moe"):
@@ -781,7 +962,13 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                 m, aux = moe.moe_swiglu_dropless(
                     h, *weights, top_k=c.expert_top_k,
                     norm_topk=c.expert_norm_topk, router_logits=router,
-                    held=c.held_range, activation=c.expert_activation)
+                    held=c.held_range, activation=c.expert_activation,
+                    score=c.router_score, select_bias=lp["router"].get("b"),
+                    gate_scale=c.expert_gate_scale)
+                if c.d_ff_shared:
+                    m = m + moe.shared_expert(
+                        h, *(lp["mlp"][f"shared_{name}"].astype(dt)
+                             for name in ("w_gate", "w_up", "w_down")))
             else:
                 m, aux = moe.moe_swiglu(
                     h, *weights, top_k=c.expert_top_k,
@@ -801,6 +988,61 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                        lp["mlp"]["w_down"].astype(dt))
             x = x + m
     return x, aux
+
+
+def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
+    """q, k, v [B, T, H, Dh] of plain attention from the normed input
+    ``h`` [B, T, D]: projections, QK-norm, RoPE, k and v repeated to the
+    query heads."""
+    dt = c.compute_dtype
+    if c.kv_heads == c.n_heads:
+        # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
+        # three skinny d→d projections (the weight concat is a few MB,
+        # amortized by XLA across the fused step).
+        wqkv = jnp.concatenate(
+            [w["wq"].astype(dt), w["wk"].astype(dt), w["wv"].astype(dt)],
+            axis=-1,
+        )  # [d, h, 3k]
+        qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+    else:
+        q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
+        k = jnp.einsum("btd,dhk->bthk", h, w["wk"].astype(dt))
+        v = jnp.einsum("btd,dhk->bthk", h, w["wv"].astype(dt))
+    if c.qk_norm:
+        q = _qk_norm(q, w["q_norm"])
+        k = _qk_norm(k, w["k_norm"])
+    if rope is not None:
+        cos, sin = rope
+        q = apply_rope(q, cos, sin, positions=positions)
+        k = apply_rope(k, cos, sin, positions=positions)
+    return (q, *_expand_gqa(k, v, c))
+
+
+def _latent_qkv(h, w, c: TransformerConfig, rope, positions):
+    """Latent attention's operands from the normed input ``h`` [B, T, D]:
+    (q_nope [B, T, H, nope], k_nope [B, T, H, nope], v [B, T, H, v],
+    {"q_shared": q_rope [B, T, H, rope], "k_shared": k_rope [B, T,
+    rope]}). Keys and values come up from ONE ``kv_latent``-wide normed
+    compression of the token; the rotary part of the key is one vector a
+    token, which every head scores its own rotary query part against
+    (``ops.attention``: ``q_shared`` / ``k_shared``). RoPE pairs the
+    halves of the rotary part, as ``apply_rope`` does everywhere."""
+    dt = c.compute_dtype
+    nope, latent = c.d_head_nope, c.kv_latent
+    cos, sin = rope
+    q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
+    with jax.named_scope(MLA_SCOPE):
+        down = jnp.einsum("btd,dc->btc", h, w["wkv_a"].astype(dt))
+        kv = jnp.einsum("btc,chk->bthk",
+                        rms_norm(down[..., :latent], w["kv_norm"],
+                                 eps=c.norm_eps),
+                        w["wkv_b"].astype(dt))
+        q_rope = apply_rope(q[..., nope:], cos, sin, positions=positions)
+        k_rope = apply_rope(down[:, :, None, latent:], cos, sin,
+                            positions=positions)[:, :, 0]
+        return (q[..., :nope], kv[..., :nope], kv[..., nope:],
+                {"q_shared": q_rope, "k_shared": k_rope})
 
 
 def _qk_norm(x, weight):
@@ -1050,6 +1292,11 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
                        moe_load_max=aux["load_max"], loss=loss)
         if "held_share" in aux:
             metrics["moe_held_share"] = aux["held_share"]
+        if "expert_counts" in aux:
+            # [layers, experts], NOT a scalar: the train step's rule for
+            # the router's bias reads it and takes it out of the metrics
+            metrics["moe_expert_counts"] = aux["expert_counts"]
+            metrics["moe_bias_swapped"] = aux["bias_swapped"]
     return loss, metrics
 
 
@@ -1074,6 +1321,15 @@ def make_train_step(config: TransformerConfig, optimizer, *, mesh=None,
     weighted average; perplexity is the weighted mean of per-microbatch
     perplexities (exp is convex, so it can sit slightly above the
     unaccumulated exp-of-mean value).
+
+    A model with a ``router_bias`` gets one update outside the
+    optimizer's: after it, every layer's bias is set to what it was
+    BEFORE the optimizer plus ``router_bias_rate x sign(mean load - the
+    expert's load)``, from the step's own assignment counts over all the
+    experts (DeepSeek-V3's rule, arXiv:2412.19437), so neither the
+    optimizer's update nor its weight decay ever moves it (its gradient
+    is exactly zero). ``router_bias_absmax`` in the metrics says how far
+    the rule has taken it.
     """
 
     def loss_fn(params, batch):
@@ -1160,6 +1416,16 @@ def make_train_step(config: TransformerConfig, optimizer, *, mesh=None,
                     for g in jax.tree.leaves(grads)
                 ))
         metrics = dict(metrics, grad_norm=gnorm)
+        if config.router_bias:
+            counts = metrics.pop("moe_expert_counts")
+            with jax.named_scope("optimizer"):
+                b = state["params"]["layers"]["router"]["b"]
+                b = b + config.router_bias_rate * jnp.sign(
+                    counts.mean(-1, keepdims=True) - counts).astype(b.dtype)
+            router = dict(params["layers"]["router"], b=b)
+            params = dict(params, layers=dict(params["layers"],
+                                              router=router))
+            metrics["router_bias_absmax"] = jnp.abs(b).max()
         with set_xla_metadata(scopes=SCOPES_ID):     # into the cache key
             count = state["step"] + 1
         return {"params": params, "opt_state": opt_state,
@@ -1182,6 +1448,15 @@ def init_train_state(rng, config: TransformerConfig, optimizer):
 def refuse_decode(c: TransformerConfig) -> None:
     """The KV-cache decode runs one kind of dense layer: refuse, by name,
     a model it would run wrongly in silence."""
+    for name, value in (("kv_latent", c.kv_latent),
+                        ("n_dense_layers", c.n_dense_layers),
+                        ("d_ff_shared", c.d_ff_shared)):
+        if value:
+            raise NotImplementedError(
+                f"KV-cache decode does not run a model with {name} "
+                f"({value!r}): the cache holds kv_heads x head_dim x 2 a "
+                f"token and every layer would be decoded as a dense one "
+                f"of plain attention")
     if c.n_experts > 0:
         raise NotImplementedError(
             "KV-cache decode for MoE models is not implemented yet"

@@ -171,7 +171,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kb = k.reshape(b, n_blocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(b, n_blocks, block_k, h, d).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(b, n_blocks, block_k, h, -1).transpose(1, 0, 2, 3, 4)
     q_pos = q_offset + jnp.arange(tq)
 
     def step(carry, blk):
@@ -186,7 +186,7 @@ def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
 
     m0 = jnp.full((b, h, tq), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, tq), jnp.float32)
-    o0 = jnp.zeros((b, tq, h, d), jnp.float32)
+    o0 = jnp.zeros((b, tq, h, v.shape[-1]), jnp.float32)
     (m, l, o), _ = jax.lax.scan(
         step, (m0, l0, o0), (kb, vb, jnp.arange(n_blocks))
     )
@@ -222,31 +222,43 @@ def _vmem_tile(rows: int, cols: int, itemsize: int) -> int:
 
 
 def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
-                      dtype) -> int:
+                      dtype, dv: int | None = None, dr: int = 0) -> int:
     """VMEM one grid step of ``kernel`` ("fwd", "dq" or "dkv") keeps
     live with these tiles: the operand and output blocks, each twice
     (the pipeline fetches the next step's while this one computes), the
-    float32 accumulators, and the score-shaped tiles."""
+    float32 accumulators, and the score-shaped tiles. ``d`` is the width
+    of q and k, ``dv`` of v (None: ``d``), ``dr`` of the shared part of
+    the key and of the queries' part that meets it (0: none)."""
+    dv = d if dv is None else dv
     io = jnp.dtype(dtype).itemsize
-    q_rows = _vmem_tile(block_q, d, io)          # q, o, g, dq
-    k_rows = _vmem_tile(block_k, d, io)          # k, v, dk, dv
+    q_rows = _vmem_tile(block_q, d, io)          # q, dq
+    o_rows = _vmem_tile(block_q, dv, io)         # o, g
+    k_rows = _vmem_tile(block_k, d, io)          # k, dk
+    v_rows = _vmem_tile(block_k, dv, io)         # v, dv
+    qr_rows = _vmem_tile(block_q, dr, io) if dr else 0    # q_shared, its dq
+    kr_rows = _vmem_tile(block_k, dr, io) if dr else 0    # k_shared, its dk
     column = _vmem_tile(block_q, 1, 4)           # lse, delta, m, l
     if kernel == "fwd":      # q, k, v -> o, lse; scratch m, l, acc
-        blocks = 2 * q_rows + 2 * k_rows + column
-        scratch = 2 * column + _vmem_tile(block_q, d, 4)
+        blocks = q_rows + qr_rows + k_rows + kr_rows + v_rows + o_rows + column
+        scratch = 2 * column + _vmem_tile(block_q, dv, 4)
     elif kernel == "dq":     # q, k, v, g, lse, delta -> dq; scratch acc
-        blocks = 3 * q_rows + 2 * k_rows + 2 * column
-        scratch = _vmem_tile(block_q, d, 4)
-    else:                    # q, k, v, g, lse, delta -> dk, dv; two accs
-        blocks = 2 * q_rows + 4 * k_rows + 2 * column
-        scratch = 2 * _vmem_tile(block_k, d, 4)
+        blocks = (2 * (q_rows + qr_rows) + k_rows + kr_rows + v_rows + o_rows
+                  + 2 * column)
+        scratch = _vmem_tile(block_q, d, 4) + (
+            _vmem_tile(block_q, dr, 4) if dr else 0)
+    else:                    # q, k, v, g, lse, delta -> dk, dv; their accs
+        blocks = (q_rows + qr_rows + o_rows + 2 * (k_rows + kr_rows + v_rows)
+                  + 2 * column)
+        scratch = (_vmem_tile(block_k, d, 4) + _vmem_tile(block_k, dv, 4)
+                   + (_vmem_tile(block_k, dr, 4) if dr else 0))
     scores = _FLASH_SCORE_TILES[kernel] * _vmem_tile(block_q, block_k, 4)
     return 2 * blocks + scratch + scores
 
 
-def _flash_tiles(kernel: str, tq: int, tk: int, d: int, dtype):
+def _flash_tiles(kernel: str, tq: int, tk: int, d: int, dtype,
+                 dv: int | None = None, dr: int = 0):
     """(block_q, block_k) of one grid step of ``kernel``, from the
-    lengths, the head width and the inputs' dtype: of the divisors of
+    lengths, the head widths (``_flash_vmem_bytes``) and the inputs' dtype: of the divisors of
     each length in whole 128-row tiles up to ``_FLASH_ROWS``, the
     largest pair whose grid step fits ``_FLASH_VMEM_MOST``. That is the
     largest divisor of each, but for float32 heads four times as wide as
@@ -257,20 +269,25 @@ def _flash_tiles(kernel: str, tq: int, tk: int, d: int, dtype):
                 if t % r == 0]
 
     fits = [(bq, bk) for bq in divisors(tq) for bk in divisors(tk)
-            if _flash_vmem_bytes(kernel, bq, bk, d, dtype) <= _FLASH_VMEM_MOST]
+            if _flash_vmem_bytes(kernel, bq, bk, d, dtype, dv, dr)
+            <= _FLASH_VMEM_MOST]
     return max(fits, key=lambda tile: (tile[0] * tile[1], tile[1]),
                default=None)
 
 
-def _flash_launch(kernel: str, q, k, block_q, block_k):
+def _flash_launch(kernel: str, q, k, block_q, block_k, v=None,
+                  q_shared=None):
     """What a ``pallas_call`` of ``kernel`` is launched with: the tiles
     (the caller's, or the rule's where it gave none) and the compiler
-    parameters that grant the VMEM those tiles need."""
+    parameters that grant the VMEM those tiles need. ``v`` None: as wide
+    as q and k."""
     from jax.experimental.pallas import tpu as pltpu
 
     tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
+    dv = d if v is None else v.shape[-1]
+    dr = 0 if q_shared is None else q_shared.shape[-1]
     if block_q is None or block_k is None:
-        tiles = _flash_tiles(kernel, tq, tk, d, q.dtype)
+        tiles = _flash_tiles(kernel, tq, tk, d, q.dtype, dv, dr)
         if tiles is None:
             raise ValueError(
                 f"flash_attention: seq lens ({tq},{tk}) are not whole "
@@ -280,7 +297,7 @@ def _flash_launch(kernel: str, q, k, block_q, block_k):
     if tq % block_q or tk % block_k:
         raise ValueError(f"seq lens ({tq},{tk}) must divide blocks "
                          f"({block_q},{block_k})")
-    vmem = max(_flash_vmem_bytes(kernel, block_q, block_k, d, q.dtype),
+    vmem = max(_flash_vmem_bytes(kernel, block_q, block_k, d, q.dtype, dv, dr),
                _FLASH_VMEM_LEAST)
     return block_q, block_k, pltpu.CompilerParams(vmem_limit_bytes=vmem)
 
@@ -380,11 +397,27 @@ def _when_visible(compute, q_blk, k_blk, in_range, *, block_q, block_k,
         compute(False)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                      acc_ref, *, block_q, block_k, n_k, n_steps, causal,
-                      scale, window=None):
+def _scores(q, k, q_shared, k_shared, scale):
+    """[block_q, block_k] float32 scores of a tile: ``q k^T``, plus, where
+    the heads share a part of the key, ``q_shared k_shared^T``."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    if q_shared is not None:
+        s = s + jax.lax.dot_general(
+            q_shared, k_shared, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return s * scale
+
+
+def _flash_fwd_kernel(*refs, block_q, block_k, n_k, n_steps, causal, scale,
+                      window=None, shared=False):
     import jax.experimental.pallas as pl
 
+    if shared:
+        (q_ref, k_ref, v_ref, qs_ref, ks_ref, o_ref, lse_ref, m_ref, l_ref,
+         acc_ref) = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     q_blk = pl.program_id(1)
     step = pl.program_id(2)
     k_blk, in_range = _inner_block(step, q_blk, block_q, block_k, n_k,
@@ -397,11 +430,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def _compute(masked):
-        q = q_ref[0]  # [block_q, d]
-        k = k_ref[0]  # [block_k, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [block_q, block_k]
+        # q [block_q, d], k [block_k, d]
+        s = _scores(q_ref[0], k_ref[0], qs_ref[0] if shared else None,
+                    ks_ref[0] if shared else None, scale)
         if masked:
             mask = _tile_mask(q_blk, k_blk, block_q, block_k, window)
             s = jnp.where(mask, s, _NEG_INF)
@@ -438,51 +469,71 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
 
 
-def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
-                   return_lse: bool = False, window=None):
+def _heads_flat(x):
+    """[B, T, H, W] -> [B * H, T, W]: one grid row a (batch, head)."""
+    b, t, h, w = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, w)
+
+
+def _flash_scale(q, q_shared) -> float:
+    """1 / sqrt of the width the scores are summed over: q's, plus the
+    shared part's."""
+    width = q.shape[-1] + (0 if q_shared is None else q_shared.shape[-1])
+    return 1.0 / math.sqrt(width)
+
+
+def _flash_forward(q, k, v, q_shared=None, k_shared=None, *, causal, block_q,
+                   block_k, interpret, return_lse: bool = False, window=None):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, h, d = q.shape
-    tk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    tk, dv = k.shape[1], v.shape[-1]
     bh = b * h
-    qf = q.transpose(0, 2, 1, 3).reshape(bh, tq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(bh, tk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(bh, tk, d)
-    block_q, block_k, params = _flash_launch("fwd", q, k, block_q, block_k)
+    shared = q_shared is not None
+    block_q, block_k, params = _flash_launch("fwd", q, k, block_q, block_k,
+                                             v, q_shared)
     n_q, n_k = tq // block_q, tk // block_k
     n_steps, k_of = _flash_inner(window, block_q, block_k, n_q, n_k, True)
+    rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
+    keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
+    operands = [_heads_flat(q), _heads_flat(k), _heads_flat(v)]
+    in_specs = [pl.BlockSpec((1, block_q, d), rows),
+                pl.BlockSpec((1, block_k, d), keys),
+                pl.BlockSpec((1, block_k, dv), keys)]
+    if shared:      # one key part a batch row: every head reads the same
+        dr = q_shared.shape[-1]
+        operands += [_heads_flat(q_shared), k_shared]
+        in_specs += [pl.BlockSpec((1, block_q, dr), rows),
+                     pl.BlockSpec((1, block_k, dr),
+                                  lambda b_, i, j: (b_ // h, k_of(i, j), 0))]
 
     kernel = functools.partial(
         _flash_fwd_kernel, block_q=block_q, block_k=block_k, n_k=n_k,
-        n_steps=n_steps, causal=causal, scale=scale, window=window,
+        n_steps=n_steps, causal=causal, scale=_flash_scale(q, q_shared),
+        window=window, shared=shared,
     )
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, k_of(i, j), 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, i, j: (b_, k_of(i, j), 0)),
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b_, i, j: (b_, i, 0)),
+            pl.BlockSpec((1, block_q, dv), rows),
+            pl.BlockSpec((1, block_q, 1), rows),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=params,
         interpret=interpret,
-    )(qf, kf, vf)
-    out = out.reshape(b, h, tq, d).transpose(0, 2, 1, 3)
+    )(*operands)
+    out = out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)
     return (out, lse) if return_lse else out
 
 
@@ -503,7 +554,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
 
 
 def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
-               masked, scale, window=None):
+               masked, scale, window=None, q_shared=None, k_shared=None):
     """Shared per-tile math: returns (ds [bq,bk] f32, p [bq,bk] f32).
 
     lse/delta arrive as [block_q, 1] column tiles (see the forward's
@@ -514,9 +565,7 @@ def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
     arithmetic are float32. The callers cast ``p`` and ``ds`` once, to
     the inputs' dtype, for the matmuls that consume them.
     """
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+    s = _scores(q, k, q_shared, k_shared, scale)
     mask = None
     if masked:
         mask = _tile_mask(q_blk, k_blk, block_q, block_k, window)
@@ -530,11 +579,16 @@ def _bwd_block(q, k, v, g, lse, delta, *, q_blk, k_blk, block_q, block_k,
     return ds, p
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, block_q, block_k, n_k, n_steps,
-                         causal, scale, window=None):
+def _flash_bwd_dq_kernel(*refs, block_q, block_k, n_k, n_steps, causal,
+                         scale, window=None, shared=False):
     import jax.experimental.pallas as pl
 
+    if shared:
+        (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, qs_ref, ks_ref,
+         dq_ref, dqs_ref, acc_ref, accs_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+         acc_ref) = refs
     q_blk = pl.program_id(1)
     step = pl.program_id(2)
     k_blk, in_range = _inner_block(step, q_blk, block_q, block_k, n_k,
@@ -543,15 +597,24 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        if shared:
+            accs_ref[:] = jnp.zeros_like(accs_ref)
 
     def _compute(masked):
         ds, _ = _bwd_block(
             q_ref[0], k_ref[0], v_ref[0], g_ref[0], lse_ref[0], delta_ref[0],
             q_blk=q_blk, k_blk=k_blk, block_q=block_q, block_k=block_k,
-            masked=masked, scale=scale, window=window)
+            masked=masked, scale=scale, window=window,
+            q_shared=qs_ref[0] if shared else None,
+            k_shared=ks_ref[0] if shared else None)
+        ds = ds.astype(k_ref.dtype)
         acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            ds, k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared:
+            accs_ref[:] += jax.lax.dot_general(
+                ds, ks_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     _when_visible(
         _compute, q_blk, k_blk, in_range,
@@ -561,13 +624,20 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     @pl.when(step == n_steps - 1)
     def _emit():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        if shared:
+            dqs_ref[0] = accs_ref[:].astype(dqs_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q,
-                          block_k, n_q, n_steps, causal, scale, window=None):
+def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
+                          scale, window=None, shared=False):
     import jax.experimental.pallas as pl
 
+    if shared:
+        (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, qs_ref, ks_ref,
+         dk_ref, dv_ref, dks_ref, dk_acc, dv_acc, dks_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc, dv_acc) = refs
     k_blk = pl.program_id(1)
     step = pl.program_id(2)
     q_blk, in_range = _inner_block(step, k_blk, block_k, block_q, n_q,
@@ -577,19 +647,28 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+        if shared:
+            dks_acc[:] = jnp.zeros_like(dks_acc)
 
     def _compute(masked):
         q, g = q_ref[0], g_ref[0]
         ds, p = _bwd_block(
             q, k_ref[0], v_ref[0], g, lse_ref[0], delta_ref[0],
             q_blk=q_blk, k_blk=k_blk, block_q=block_q, block_k=block_k,
-            masked=masked, scale=scale, window=window)
+            masked=masked, scale=scale, window=window,
+            q_shared=qs_ref[0] if shared else None,
+            k_shared=ks_ref[0] if shared else None)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        ds = ds.astype(q.dtype)
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared:
+            dks_acc[:] += jax.lax.dot_general(
+                ds, qs_ref[0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     # Plain causal: skip query blocks entirely ABOVE the diagonal for this
     # key block (no query there attends to these keys).
@@ -602,74 +681,98 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     def _emit():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        if shared:
+            dks_ref[0] = dks_acc[:].astype(dks_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k,
-                    interpret, window=None):
+def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
+                    causal, block_q, block_k, interpret, window=None):
+    """(dq, dk, dv, dq_shared, dk_shared); the last two None with no
+    shared key part. Every head's gradient of the shared part leaves the
+    dk / dv kernel as its own [B * H, Tk, dr] rows and is summed over
+    the heads here: the part itself is never repeated."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, h, d = q.shape
-    tk = k.shape[1]
-    scale = 1.0 / math.sqrt(d)
+    tk, dv = k.shape[1], v.shape[-1]
+    scale = _flash_scale(q, q_shared)
     bh = b * h
-    flat = lambda x, t: x.transpose(0, 2, 1, 3).reshape(bh, t, d)  # noqa: E731
-    qf, gf, of = flat(q, tq), flat(g, tq), flat(out, tq)
-    kf, vf = flat(k, tk), flat(v, tk)
+    shared = q_shared is not None
+    dr = q_shared.shape[-1] if shared else 0
+    qf, gf, of = _heads_flat(q), _heads_flat(g), _heads_flat(out)
+    kf, vf = _heads_flat(k), _heads_flat(v)
     # delta = rowsum(dO * O): one fused elementwise pass in XLA. Kept as
     # a [bh, tq, 1] column (same block-legality story as lse).
     delta = (gf.astype(jnp.float32) * of.astype(jnp.float32)).sum(
         -1, keepdims=True)
-    operands = (qf, kf, vf, gf, lse, delta)
+    operands = [qf, kf, vf, gf, lse, delta]
+    if shared:
+        operands += [_heads_flat(q_shared), k_shared]
 
-    def in_specs(bq, bk, rows, keys):
-        return [pl.BlockSpec((1, bq, d), rows),      # q
-                pl.BlockSpec((1, bk, d), keys),      # k
-                pl.BlockSpec((1, bk, d), keys),      # v
-                pl.BlockSpec((1, bq, d), rows),      # g
-                pl.BlockSpec((1, bq, 1), rows),      # lse
-                pl.BlockSpec((1, bq, 1), rows)]      # delta
+    def in_specs(bq, bk, rows, keys, shared_keys):
+        specs = [pl.BlockSpec((1, bq, d), rows),      # q
+                 pl.BlockSpec((1, bk, d), keys),      # k
+                 pl.BlockSpec((1, bk, dv), keys),     # v
+                 pl.BlockSpec((1, bq, dv), rows),     # g
+                 pl.BlockSpec((1, bq, 1), rows),      # lse
+                 pl.BlockSpec((1, bq, 1), rows)]      # delta
+        if shared:
+            specs += [pl.BlockSpec((1, bq, dr), rows),
+                      pl.BlockSpec((1, bk, dr), shared_keys)]
+        return specs
 
     # The dq pass: grid (b, i, j), key blocks innermost.
-    bq, bk, params = _flash_launch("dq", q, k, block_q, block_k)
+    bq, bk, params = _flash_launch("dq", q, k, block_q, block_k, v, q_shared)
     n_steps, k_of = _flash_inner(window, bq, bk, tq // bq, tk // bk, True)
     rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
     keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
+    shared_keys = lambda b_, i, j: (b_ // h, k_of(i, j), 0)  # noqa: E731
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
                           n_k=tk // bk, n_steps=n_steps, causal=causal,
-                          scale=scale, window=window),
+                          scale=scale, window=window, shared=shared),
         grid=(bh, tq // bq, n_steps),
-        in_specs=in_specs(bq, bk, rows, keys),
-        out_specs=pl.BlockSpec((1, bq, d), rows),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        in_specs=in_specs(bq, bk, rows, keys, shared_keys),
+        out_specs=[pl.BlockSpec((1, bq, w), rows) for w in (d, dr) if w],
+        out_shape=[jax.ShapeDtypeStruct((bh, tq, w), q.dtype)
+                   for w in (d, dr) if w],
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)
+                        for w in (d, dr) if w],
         compiler_params=params,
         interpret=interpret,
     )(*operands)
 
     # The dk / dv pass: grid (b, j, i), query blocks innermost.
-    bq, bk, params = _flash_launch("dkv", q, k, block_q, block_k)
+    bq, bk, params = _flash_launch("dkv", q, k, block_q, block_k, v,
+                                   q_shared)
     n_steps, q_of = _flash_inner(window, bk, bq, tk // bk, tq // bq, False)
     rows = lambda b_, j, i: (b_, q_of(j, i), 0)  # noqa: E731
     keys = lambda b_, j, i: (b_, j, 0)  # noqa: E731
-    dk, dv = pl.pallas_call(
+    shared_keys = lambda b_, j, i: (b_ // h, j, 0)  # noqa: E731
+    dkv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
                           n_q=tq // bq, n_steps=n_steps, causal=causal,
-                          scale=scale, window=window),
+                          scale=scale, window=window, shared=shared),
         grid=(bh, tk // bk, n_steps),
-        in_specs=in_specs(bq, bk, rows, keys),
-        out_specs=[pl.BlockSpec((1, bk, d), keys)] * 2,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
+        in_specs=in_specs(bq, bk, rows, keys, shared_keys),
+        out_specs=[pl.BlockSpec((1, bk, w), keys) for w in (d, dv, dr) if w],
+        out_shape=[jax.ShapeDtypeStruct((bh, tk, w), k.dtype)
+                   for w in (d, dv, dr) if w],
+        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32)
+                        for w in (d, dv, dr) if w],
         compiler_params=params,
         interpret=interpret,
     )(*operands)
-    unflat = lambda x, t: x.reshape(b, h, t, d).transpose(0, 2, 1, 3)  # noqa: E731
-    return unflat(dq, tq), unflat(dk, tk), unflat(dv, tk)
+
+    def unflat(x, t):
+        return x.reshape(b, h, t, x.shape[-1]).transpose(0, 2, 1, 3)
+
+    grads = (unflat(dq[0], tq), unflat(dkv[0], tk), unflat(dkv[1], tk))
+    if not shared:
+        return (*grads, None, None)
+    dk_shared = dkv[2].reshape(b, h, tk, dr).astype(jnp.float32).sum(1)
+    return (*grads, unflat(dq[1], tq), dk_shared.astype(k_shared.dtype))
 
 
 def _interpret() -> bool:
@@ -689,7 +792,8 @@ def _interpret() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
-                    block_k: int | None = None, window: int | None = None):
+                    block_k: int | None = None, window: int | None = None,
+                    q_shared=None, k_shared=None):
     """Pallas flash attention (TPU kernel; interpreter on CPU).
 
     Training runs the Pallas BACKWARD kernels (dq pass + dk/dv pass,
@@ -715,11 +819,19 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
     then walk a shorter grid that holds only the tiles with a visible
     pair (``_flash_inner``) and build a mask only on the tiles the
     diagonal or the window's edge crosses.
+
+    ``v`` may be narrower or wider than q and k ([B, Tk, H, Dv]): the
+    output is as wide as ``v``. ``q_shared`` [B, Tq, H, Dr] with
+    ``k_shared`` [B, Tk, Dr] is a part of the key that ALL heads share
+    (latent attention's rotary key): the scores are ``q k^T + q_shared
+    k_shared^T`` over ``sqrt(D + Dr)``, every head reads the one
+    ``k_shared`` through its block's index map, and its gradient is the
+    sum of the heads'. It is never repeated to the heads in HBM.
     """
     _check_window(q, k, causal, window)
     return _flash_forward(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=_interpret(), window=window,
+        q, k, v, q_shared, k_shared, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=_interpret(), window=window,
     )
 
 
@@ -739,11 +851,13 @@ FLASH_OUT_NAME = "flash_attention_out"
 FLASH_LSE_NAME = "flash_attention_lse"
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window):
+def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window, q_shared=None,
+                    k_shared=None):
     _check_window(q, k, causal, window)
     out, lse = _flash_forward(
-        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=_interpret(), return_lse=True, window=window,
+        q, k, v, q_shared, k_shared, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=_interpret(), return_lse=True,
+        window=window,
     )
     # ONE named ``out`` is both the value returned (what the caller's
     # output projection reads) and the backward kernels' residual: were
@@ -753,14 +867,15 @@ def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window):
     # the 128 lanes in HBM.
     out = checkpoint_name(out, FLASH_OUT_NAME)
     lse = checkpoint_name(lse[..., 0], FLASH_LSE_NAME)
-    return out, (q, k, v, out, lse)
+    return out, (q, k, v, out, lse, q_shared, k_shared)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, window, res, g):
-    q, k, v, out, lse = res
+    q, k, v, out, lse, q_shared, k_shared = res
     return _flash_backward(
-        q, k, v, out, lse[..., None], g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=_interpret(), window=window,
+        q, k, v, out, lse[..., None], g, q_shared, k_shared, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=_interpret(),
+        window=window,
     )
 
 
@@ -768,7 +883,7 @@ flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
-              window: int | None = None):
+              window: int | None = None, q_shared=None, k_shared=None):
     """Dispatch: 'reference' | 'blockwise' | 'flash' | 'auto'.
 
     ``window`` (causal self-attention only): a query sees the last
@@ -782,18 +897,31 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
     Above 1024 it uses the Pallas kernel on TPU where both lengths are
     whole 128-row tiles (the kernel sizes its own tiles from the shapes:
     ``_flash_tiles``), else the blockwise path.
+
+    ``q_shared`` / ``k_shared``: a part of the key all heads share
+    (``flash_attention``). The kernel takes the parts as they are; every
+    other path gets them joined to q and to k repeated to the heads.
     """
     _check_window(q, k, causal, window)
     if window is not None and window >= k.shape[1]:
         window = None
+    tq, tk = q.shape[1], k.shape[1]
+    dr = 0 if q_shared is None else q_shared.shape[-1]
+    if impl == "flash" or (
+            impl == "auto" and tk > 1024
+            and jax.devices()[0].platform == "tpu"
+            and _flash_tiles("fwd", tq, tk, q.shape[-1], q.dtype,
+                             v.shape[-1], dr)):
+        return flash_attention(q, k, v, causal, None, None, window,
+                               q_shared, k_shared)
+    if q_shared is not None:
+        q = jnp.concatenate([q, q_shared], axis=-1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k_shared[:, :, None], (*k.shape[:3], dr))], axis=-1)
     if impl == "reference":
         return dot_product_attention(q, k, v, causal=causal, window=window)
-    if impl == "blockwise":
+    if impl == "blockwise" or tk > 1024:
         return blockwise_attention(q, k, v, causal=causal, window=window)
-    if impl == "flash":
-        return flash_attention(q, k, v, causal, None, None, window)
-    tq, tk = q.shape[1], k.shape[1]
-    on_tpu = jax.devices()[0].platform == "tpu"
     # Up to 1024 keys the scores are materialised by XLA, and for causal
     # self-attention only the blocks at or under the diagonal (PERF.md
     # section 6, PR 25: the v5e runs of both benchmark cells that settled
@@ -802,12 +930,7 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
     # gives it: forward + backward 3.97 against 2.33 ms at
     # [8, 1024, 25, 64], 2.66 against 2.50 at [4, 1024, 32, 128] (v5e,
     # PERF.md section 6, PR 28; ROADMAP A1).
-    if tk <= 1024:
-        rows = _causal_block_rows(tq) if causal and tq == tk else 0
-        if rows:
-            return causal_blocked_attention(q, k, v, block_q=rows,
-                                            window=window)
-        return dot_product_attention(q, k, v, causal=causal, window=window)
-    if on_tpu and _flash_tiles("fwd", tq, tk, q.shape[-1], q.dtype):
-        return flash_attention(q, k, v, causal, None, None, window)
-    return blockwise_attention(q, k, v, causal=causal, window=window)
+    rows = _causal_block_rows(tq) if causal and tq == tk else 0
+    if rows:
+        return causal_blocked_attention(q, k, v, block_q=rows, window=window)
+    return dot_product_attention(q, k, v, causal=causal, window=window)

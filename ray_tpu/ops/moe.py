@@ -2,9 +2,10 @@
 
 The reference has no expert parallelism anywhere (SURVEY.md §2.4: EP —
 "Absent"; vLLM handles MoE internally for inference only), so this is
-greenfield. One router (float32 softmax over the experts, top-k, the
-gates renormalised or not) feeds one of two dispatches, chosen by the
-model's ``expert_capacity_factor``:
+greenfield. One router (float32 scores over the experts, a softmax or a
+sigmoid each; top-k, under a bias that only the choice sees where the
+model has one; the gates renormalised or not, and scaled) feeds one of
+two dispatches, chosen by the model's ``expert_capacity_factor``:
 
 * **dropless** (``None``; ``moe_swiglu_dropless``): the ``top_k x tokens``
   (token, choice) assignments are SORTED by expert, the tokens' rows
@@ -29,7 +30,9 @@ model's ``expert_capacity_factor``:
   rows are the price; PERF.md section 5 has what they cost on the v5e).
   The caller may make the router's logits itself (``router_logits``;
   ``router_matmul``), for a model whose router does not read the
-  experts' input, and the activation is SwiGLU's or ReGLU's. What is
+  experts' input, and the activation is SwiGLU's or ReGLU's. A model
+  with a shared expert adds ``shared_expert``, which every token passes
+  through whole, to the block's output beside this. What is
   NOT here is the exchange: there is no all-to-all yet (ROADMAP B2),
   so a rank's share runs alone and nothing stands in for the others.
 * **capacity** (a number; ``moe_swiglu``, GShard / Switch style): dense
@@ -91,10 +94,14 @@ import jax.numpy as jnp
 import numpy as np
 
 # Sub-scopes of the model's ``moe`` scope (models/transformer.py), opened
-# by ``moe_swiglu_dropless``: router matmul, softmax, top-k and the loss
+# by ``moe_swiglu_dropless``: router matmul, scores, top-k and the loss
 # terms; the sort and the gather into expert order; the three grouped
-# matmuls and the activation; the gather back, the gates and the sum.
-SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+# matmuls and the activation; the gather back, the gates and the sum. And
+# by ``shared_expert``: the gated FFN every token passes through beside
+# its routed experts.
+SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+          "moe_shared")
+ROUTER_SCORES = ("softmax", "sigmoid")
 
 
 def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
@@ -104,16 +111,48 @@ def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
     return max(8, ((c + 7) // 8) * 8)
 
 
-def route(router_logits, top_k: int, norm_topk: bool = True):
+def route(router_logits, top_k: int, norm_topk: bool = True, *,
+          score: str = "softmax", select_bias=None, gate_scale: float = 1.0):
     """Router logits [G, E] -> (probs [G, E] float32, gates [G, k], chosen
     experts [G, k]). ``norm_topk`` renormalises the selected gates so a
     token's combine weights sum to 1 (Mixtral); OLMoE uses them as they
-    are."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, top_k)
+    are.
+
+    ``score="sigmoid"`` scores each expert by itself (DeepSeek-V3's
+    router); ``probs`` are then the scores over their sum, what the
+    balance statistic reads. ``select_bias`` [E] is added to the scores
+    FOR THE CHOICE ALONE: the top-k is of ``scores + bias``, the gates are
+    the chosen experts' scores without it, and no gradient reaches it
+    (its place is an integer index). ``gate_scale`` multiplies the gates
+    after the renormalisation."""
+    logits = router_logits.astype(jnp.float32)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-9)
+    else:
+        scores = probs = jax.nn.softmax(logits, axis=-1)
+    if select_bias is None:
+        topv, topi = jax.lax.top_k(scores, top_k)
+    else:
+        _, topi = jax.lax.top_k(jax.lax.stop_gradient(
+            scores + select_bias.astype(jnp.float32)), top_k)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
     if norm_topk:
         topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    if gate_scale != 1.0:
+        topv = topv * gate_scale
     return probs, topv, topi
+
+
+def bias_swapped(router_logits, chosen, top_k: int):
+    """The share of the assignments ``chosen`` [G, k] that the top-k of
+    the logits alone would not have made: what a selection bias changed.
+    A chosen expert is in that top-k iff fewer than k experts score
+    higher (scores are monotone in the logits, so the logits serve)."""
+    logits = router_logits.astype(jnp.float32)
+    own = jnp.take_along_axis(logits, chosen, axis=-1)            # [G, k]
+    higher = (logits[:, None, :] > own[:, :, None]).sum(-1)       # [G, k]
+    return jnp.mean((higher >= top_k).astype(jnp.float32))
 
 
 def router_z(router_logits):
@@ -423,6 +462,15 @@ def router_matmul(x, router_w):
                           router_w.astype(jnp.float32))
 
 
+def shared_expert(x, w_gate, w_up, w_down):
+    """The gated FFN every token passes through beside its routed experts
+    (DeepSeek's shared experts, fused into one SwiGLU), ungated, under
+    the scope ``moe_shared``. x [..., D]; w_gate / w_up [D, F]; w_down
+    [F, D]."""
+    with jax.named_scope("moe_shared"):
+        return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
 def held_range(num_experts: int, rank: int, of: int) -> tuple[int, int]:
     """[first, end) of the experts that rank ``rank`` of ``of`` holds:
     ``num_experts / of`` consecutive ones."""
@@ -436,7 +484,8 @@ def held_range(num_experts: int, rank: int, of: int) -> tuple[int, int]:
 def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                         norm_topk: bool = True, router_logits=None,
                         held: tuple[int, int] | None = None,
-                        activation: str = "silu"):
+                        activation: str = "silu", score: str = "softmax",
+                        select_bias=None, gate_scale: float = 1.0):
     """MoE gated FFN (``activation(gate) * up``: SwiGLU with "silu",
     ReGLU with "relu") for one layer; every (token, choice) assignment
     to an expert that is here is computed.
@@ -457,6 +506,11 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     expert is dropped. ``load_max`` is then over the held experts, and
     ``held_share`` (the share of the assignments that went to one; 1 /
     ranks at balance) joins the statistics.
+
+    ``score``, ``select_bias`` and ``gate_scale`` are ``route``'s. With a
+    ``select_bias`` the statistics also hold ``counts`` (float32 [E]: the
+    assignments of this batch to each of ALL E experts, what the bias's
+    update rule reads) and ``bias_swapped`` (``bias_swapped``).
     """
     B, S, D = x.shape
     N, A = B * S, B * S * top_k
@@ -467,10 +521,15 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     with jax.named_scope("moe_router"):
         logits = router_logits.reshape(N, -1).astype(jnp.float32)
         E = logits.shape[-1]
-        probs, gates, experts = route(logits, top_k, norm_topk)
+        probs, gates, experts = route(logits, top_k, norm_topk, score=score,
+                                      select_bias=select_bias,
+                                      gate_scale=gate_scale)
         counts = _assignment_counts(experts, E)
         stats = {"balance": E * jnp.sum(counts / A * probs.mean(axis=0)),
                  "z": router_z(logits)}
+        if select_bias is not None:
+            stats["counts"] = counts.astype(jnp.float32)
+            stats["bias_swapped"] = bias_swapped(logits, experts, top_k)
         keys = experts.reshape(A).astype(jnp.int32)
         if held is None:
             stats["load_max"] = _load_max(counts)
