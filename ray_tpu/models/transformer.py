@@ -772,8 +772,8 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     ``return_aux`` additionally returns the router's statistics over the
     layers, ``{"balance", "z", "load_max"}`` (``ops/moe.py``; the two loss
     terms as means, the fullest layer's ``load_max``; zeros for a dense
-    model; with ``experts_held`` also ``held_share``, the mean over the
-    layers; with a ``router_bias`` also ``expert_counts`` [layers,
+    model; with ``experts_held`` also ``held_share`` and ``full_buffer``,
+    the means over the layers; with a ``router_bias`` also ``expert_counts`` [layers,
     experts], the batch's assignments to every expert, and
     ``bias_swapped``, the mean over the layers). ``return_hidden`` skips
     the LM head and returns the final
@@ -879,6 +879,7 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                "load_max": auxs["load_max"].max()}
         if c.experts_held is not None:
             aux["held_share"] = auxs["held_share"].mean()
+            aux["full_buffer"] = auxs["full_buffer"].mean()
         if c.router_bias:
             aux["expert_counts"] = auxs["counts"]
             aux["bias_swapped"] = auxs["bias_swapped"].mean()
@@ -1292,6 +1293,7 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
                        moe_load_max=aux["load_max"], loss=loss)
         if "held_share" in aux:
             metrics["moe_held_share"] = aux["held_share"]
+            metrics["moe_full_buffer"] = aux["full_buffer"]
         if "expert_counts" in aux:
             # [layers, experts], NOT a scalar: the train step's rule for
             # the router's bias reads it and takes it out of the metrics

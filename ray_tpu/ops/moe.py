@@ -23,11 +23,17 @@ two dispatches, chosen by the model's ``expert_capacity_factor``:
   to the front, the rest behind them as one run with no matrix, which
   no grouped matmul visits (megablox's own sharded-groups case: more
   group sizes than matrices, the rows past the last matrix zeroed), and
-  the result is the held experts' part of the sum. The row buffer stays
-  ``top_k x tokens`` rows whatever share is held: a smaller one could
-  overflow under a skewed router, and no assignment to a held expert is
-  ever dropped (the gathers and the elementwise work over the unused
-  rows are the price; PERF.md section 5 has what they cost on the v5e).
+  the result is the held experts' part of the sum. The row buffer
+  FOLLOWS the share held: ``C`` = twice the rows a balanced router sends
+  this rank, in whole row tiles (``_buffer_rows``; half of the ``top_k x
+  tokens`` = A rows for a quarter of the experts, all A from a half up).
+  The step COUNTS the batch's assignments to the held experts before it
+  moves a row and runs the sorted rows through the experts ``C`` at a
+  time (``_held_block``: one loop in the forward, one in the block's
+  own backward rule, as many rounds as hold every held assignment: ONE
+  unless the router overflows ``C``), so no assignment to a held expert
+  is ever dropped and nothing is approximated; ``full_buffer`` in the
+  statistics says whether a layer needed more than the one round.
   The caller may make the router's logits itself (``router_logits``;
   ``router_matmul``), for a model whose router does not read the
   experts' input, and the activation is SwiGLU's or ReGLU's. A model
@@ -81,6 +87,20 @@ PERF.md section 5). The block does two of the second kind and no more:
   ``g[inverse]`` (dispatch, from [A, D], summed over a token's choices).
   The gates go to expert order and their gradient back as sorts of A
   scalars.
+
+With a row buffer of C < A rows (a rank that holds under half of the
+experts) a round moves the rows ``[i x C, (i + 1) x C)`` of the expert
+order: the three gathers at ``order // top_k`` write [C, D], and the
+grouped matmuls and everything elementwise between them run over C rows.
+The two moves by ``inverse`` read a [C, D] source a CHOICE at a time,
+``top_k`` gathers of [N, D] each summed in float32 as they come (no
+[A, D] rows and no float32 [N, top_k, D] copy of them between the
+rounds): an assignment outside the round reads a clamped index, under a
+gate that is 0 in the forward and a mask in the backward. There the
+recompute of the rows, gate and up is the backward RULE's own (what
+crosses from the forward is the block's inputs), with or without remat.
+A second round (the router overflowed ``C``) reads every token's
+choices again.
 """
 
 from __future__ import annotations
@@ -428,19 +448,26 @@ def _permuted(values, to):
     return jax.lax.sort((to, values), num_keys=1)[1]
 
 
-def _down_and_combine_bwd(res, d_out):
-    h, w_down, gates, counts, order, inverse = res
-    top_k = gates.shape[1]
-    with jax.named_scope("moe_combine"):
-        gate = _permuted(gates.reshape(-1), inverse)[:, None]   # [A, 1]
-        g = d_out[order // top_k]                               # [A, D]
+def _down_grads(h, w_down, counts, gate, g):
+    """What the down matmul's backward makes of ``h`` [R, F], the rows'
+    gates [R, 1] and their share ``g`` [R, D] of the block's cotangent:
+    the gradients of ``h``, of ``w_down`` and of the gates [R]."""
     with jax.named_scope("moe_experts"):
         hf = h.astype(jnp.float32)
         dh_u, dw = _grouped_matmul_grads((gate * hf).astype(h.dtype), w_down,
                                          counts, g)
         dh_u = dh_u.astype(jnp.float32)
         d_gate = (hf * dh_u).sum(axis=-1)
-        dh = (gate * dh_u).astype(h.dtype)
+        return (gate * dh_u).astype(h.dtype), dw, d_gate
+
+
+def _down_and_combine_bwd(res, d_out):
+    h, w_down, gates, counts, order, inverse = res
+    top_k = gates.shape[1]
+    with jax.named_scope("moe_combine"):
+        gate = _permuted(gates.reshape(-1), inverse)[:, None]   # [A, 1]
+        g = d_out[order // top_k]                               # [A, D]
+    dh, dw, d_gate = _down_grads(h, w_down, counts, gate, g)
     with jax.named_scope("moe_combine"):
         d_gates = _permuted(d_gate, order).reshape(gates.shape)
     return dh, dw, d_gates, None, None, None
@@ -481,6 +508,154 @@ def held_range(num_experts: int, rank: int, of: int) -> tuple[int, int]:
     return rank * share, (rank + 1) * share
 
 
+# A rank that holds Eh of E experts keeps a row buffer of this many times
+# the rows a balanced router sends it. The seeded routers on record read
+# 0.10-0.44 of the assignments held where 0.25 is balance and 0-0.13 where
+# 0.125 is (PERF.md section 6, PR 31 / 34 / 35): twice covers them in all
+# but a layer now and then, which takes a second round and says so.
+_HELD_ROOM = 2
+
+
+def _buffer_rows(assignments: int, held: int, of: int) -> int:
+    """Rows of the expert-order buffer of a rank that holds ``held`` of
+    ``of`` experts: ``_HELD_ROOM`` times its balanced share of the
+    ``assignments``, in whole row tiles; all of them from a share of 1 /
+    ``_HELD_ROOM`` up."""
+    rows = -(-_HELD_ROOM * assignments * held // of)
+    return min(assignments, -(-rows // _GMM_ROWS) * _GMM_ROWS)
+
+
+def _gated(rows, w_gate, w_up, counts, activation: str):
+    """``activation(gate) * up`` of the rows, each by its own expert."""
+    with jax.named_scope("moe_experts"):
+        return ACTIVATIONS[activation](grouped_matmul(
+            rows, w_gate, counts)) * grouped_matmul(rows, w_up, counts)
+
+
+def _round(i, buffer: int, held_counts, order, inverse, top_k: int):
+    """Round ``i`` of a rank's held rows: the rows ``[i x buffer, (i + 1)
+    x buffer)`` of the expert order. Returns the tokens of those rows
+    [buffer] (``order`` comes padded to whole rounds), their group sizes
+    (what of each held expert's run lies in the round and, behind, one
+    run with no matrix), and for every assignment [N, top_k] its row in
+    the round, clamped, and whether it is in the round at all."""
+    start = i * buffer
+    ends = jnp.cumsum(held_counts)
+    sizes = (jnp.clip(ends - start, 0, buffer)
+             - jnp.clip(ends - held_counts - start, 0, buffer))
+    counts = jnp.concatenate([sizes, (buffer - sizes.sum())[None]])
+    tokens = jax.lax.dynamic_slice(order, (start,), (buffer,)) // top_k
+    row = (inverse - start).reshape(-1, top_k)
+    return (tokens, counts, jnp.clip(row, 0, buffer - 1),
+            (row >= 0) & (row < buffer))
+
+
+def _rounds(held_counts, buffer: int):
+    """Rounds of ``buffer`` rows that hold every assignment to a held
+    expert; one where there is none (the step costs what it costs)."""
+    return jnp.maximum(1, -(-held_counts.sum() // buffer))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _held_block(x, w_gate, w_up, w_down, gates, held_counts, order, inverse,
+                buffer: int, activation: str):
+    """The sorted assignments' way through the experts a rank holds,
+    ``buffer`` < A rows of the expert order at a time: x [N, D] -> the
+    sum under ``gates`` [N, top_k] (0 for an absent expert) of each
+    token's rows [N, D], in float32 over the rounds. The batch's held
+    assignments are counted before a row moves; they fit ONE round unless
+    the router overflows the buffer, and then there are as many rounds as
+    hold them all: the same rows meet the same matrices in the same
+    tiles, and none is dropped. One loop with one body, so the step holds
+    the block's code once, over ``buffer`` rows (a ``cond`` between this
+    and the A-row block held it twice and loaded 15% slower, PERF.md
+    section 6, PR 36); with its own gradient, since a loop of a counted
+    length has none, and so that what crosses from the forward to the
+    backward is the block's inputs."""
+    top_k = gates.shape[1]
+    order = jnp.pad(order, (0, -order.shape[0] % buffer))
+
+    def one_round(i, out):
+        tokens, counts, row, here = _round(i, buffer, held_counts, order,
+                                           inverse, top_k)
+        with jax.named_scope("moe_dispatch"):
+            rows = x[tokens]
+        h = _gated(rows, w_gate, w_up, counts, activation)
+        with jax.named_scope("moe_experts"):
+            y = grouped_matmul(h, w_down, counts)
+        with jax.named_scope("moe_combine"):
+            # a choice at a time: no [A, D] rows between the loop's rounds
+            gate = jnp.where(here, gates, 0.0)
+            for j in range(top_k):
+                out = out + (y[row[:, j]].astype(jnp.float32)
+                             * gate[:, j, None])
+            return out
+
+    with jax.named_scope("moe_combine"):
+        out = jnp.zeros(x.shape, jnp.float32)
+    out = jax.lax.fori_loop(0, _rounds(held_counts, buffer), one_round, out)
+    with jax.named_scope("moe_combine"):
+        return out.astype(x.dtype)
+
+
+def _held_block_fwd(x, w_gate, w_up, w_down, gates, held_counts, order,
+                    inverse, buffer, activation):
+    inputs = (x, w_gate, w_up, w_down, gates, held_counts, order, inverse)
+    return _held_block(*inputs, buffer, activation), inputs
+
+
+def _held_block_bwd(buffer, activation, inputs, d_out):
+    """As ``_down_and_combine``'s and ``_rows_to_experts``' gradients, a
+    round at a time: the rows and ``h`` are made again here (what a
+    layer's remat does anyway), never ``y``."""
+    x, w_gate, w_up, w_down, gates, held_counts, order, inverse = inputs
+    top_k = gates.shape[1]
+    a_rows, pad = order.shape[0], -order.shape[0] % buffer
+    with jax.named_scope("moe_combine"):
+        sorted_gates = jnp.pad(_permuted(gates.reshape(-1), inverse), (0, pad))
+    padded = jnp.pad(order, (0, pad))
+
+    def one_round(i, grads):
+        dx, dw_gate, dw_up, dw_down, d_gate = grads
+        tokens, counts, row, here = _round(i, buffer, held_counts, padded,
+                                           inverse, top_k)
+        with jax.named_scope("moe_dispatch"):
+            rows = x[tokens]
+        h, to_rows = jax.vjp(
+            lambda rows, w_gate, w_up: _gated(rows, w_gate, w_up, counts,
+                                              activation), rows, w_gate, w_up)
+        with jax.named_scope("moe_combine"):
+            gate = jax.lax.dynamic_slice(sorted_gates, (i * buffer,),
+                                         (buffer,))[:, None]
+            g = d_out[tokens]
+        dh, dw, d_gate_here = _down_grads(h, w_down, counts, gate, g)
+        d_rows, dw_g, dw_u = to_rows(dh)
+        with jax.named_scope("moe_dispatch"):
+            for j in range(top_k):
+                dx = dx + jnp.where(here[:, j, None], d_rows[row[:, j]],
+                                    0).astype(jnp.float32)
+        with jax.named_scope("moe_combine"):
+            d_gate = jax.lax.dynamic_update_slice(d_gate, d_gate_here,
+                                                  (i * buffer,))
+        with jax.named_scope("moe_experts"):
+            return (dx, dw_gate + dw_g, dw_up + dw_u, dw_down + dw, d_gate)
+
+    with jax.named_scope("moe_experts"):
+        grads = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w_gate),
+                 jnp.zeros_like(w_up), jnp.zeros_like(w_down),
+                 jnp.zeros((a_rows + pad,), jnp.float32))
+    dx, dw_gate, dw_up, dw_down, d_gate = jax.lax.fori_loop(
+        0, _rounds(held_counts, buffer), one_round, grads)
+    with jax.named_scope("moe_combine"):
+        d_gates = _permuted(d_gate[:a_rows], order).reshape(gates.shape)
+    with jax.named_scope("moe_dispatch"):
+        dx = dx.astype(x.dtype)
+    return dx, dw_gate, dw_up, dw_down, d_gates, None, None, None
+
+
+_held_block.defvjp(_held_block_fwd, _held_block_bwd)
+
+
 def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
                         norm_topk: bool = True, router_logits=None,
                         held: tuple[int, int] | None = None,
@@ -501,11 +676,15 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     router, the top-k and the gates stay over all E; the assignments to
     the held experts sort to the front by expert, the others behind
     them as one run that no grouped matmul visits (its rows are zero);
-    ``out`` is the held experts' part of the sum. The row buffer stays
-    ``top_k x tokens``: whatever the imbalance, no assignment to a held
-    expert is dropped. ``load_max`` is then over the held experts, and
-    ``held_share`` (the share of the assignments that went to one; 1 /
-    ranks at balance) joins the statistics.
+    ``out`` is the held experts' part of the sum. The row buffer is
+    twice the rank's balanced share of the ``top_k x tokens`` rows
+    (``_buffer_rows``), and a layer whose held assignments overflow it
+    takes as many rounds of it as hold them: whatever the imbalance, no
+    assignment to a held expert is dropped. ``load_max`` is then over
+    the held experts, and ``held_share`` (the share of the assignments
+    that went to one; 1 / ranks at balance) and ``full_buffer`` (1.0
+    where the held assignments overflowed the buffer and the layer took
+    more than one round, else 0.0) join the statistics.
 
     ``score``, ``select_bias`` and ``gate_scale`` are ``route``'s. With a
     ``select_bias`` the statistics also hold ``counts`` (float32 [E]: the
@@ -543,14 +722,24 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
             held_counts = counts[first:end]
             counts = jnp.concatenate(
                 [held_counts, (A - held_counts.sum())[None]])
+            buffer = _buffer_rows(A, end - first, E)
             stats["load_max"] = _load_max(held_counts)
             stats["held_share"] = held_counts.sum() / jnp.float32(A)
+            stats["full_buffer"] = (held_counts.sum() > buffer).astype(
+                jnp.float32)
     with jax.named_scope("moe_dispatch"):
         # Stable sort of the assignments by expert: ``order[a]`` is the
         # assignment that lands in row a, ``inverse`` the other way.
         iota = jnp.arange(A, dtype=jnp.int32)
         _, order = jax.lax.sort((keys, iota), num_keys=1)
         inverse = jnp.zeros((A,), jnp.int32).at[order].set(iota)
+    if held is not None and buffer < A:
+        with jax.named_scope("moe_experts"):
+            weights = w_gate.astype(dt), w_up.astype(dt), w_down.astype(dt)
+        out = _held_block(xf, *weights, gates, held_counts, order, inverse,
+                          buffer, activation)
+        return out.reshape(B, S, D), stats
+    with jax.named_scope("moe_dispatch"):
         rows = _rows_to_experts(xf, order, inverse, top_k)
     with jax.named_scope("moe_experts"):
         gate = grouped_matmul(rows, w_gate.astype(dt), counts)

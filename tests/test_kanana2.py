@@ -454,7 +454,7 @@ def test_the_train_steps_counters_count_the_whole_batch():
     cfg, params, rows = make()
     metrics = models.lm_loss(params, {"tokens": rows}, cfg)[1]
     n = 2 * T
-    share, load, swapped = [], [], []
+    share, load, swapped, over = [], [], [], []
     x = params["embed"]["tokens"][rows[:, :-1]]
     rope = transformer.rope_frequencies(cfg.d_head_rope, cfg.max_seq_len,
                                         theta=cfg.rope_theta)
@@ -474,6 +474,8 @@ def test_the_train_steps_counters_count_the_whole_batch():
         first, end = moe.held_range(E, *cfg.experts_held)
         share.append(counts[first:end].sum() / (n * K))
         load.append(counts[first:end].max() / counts[first:end].mean())
+        over.append(counts[first:end].sum() > moe._buffer_rows(
+            n * K, end - first, E))
         swapped.append(np.mean([[e not in plain[t] for e in chosen[t]]
                                 for t in range(n)]))
         np.testing.assert_array_equal(
@@ -486,6 +488,8 @@ def test_the_train_steps_counters_count_the_whole_batch():
                                                            rel=1e-5)
     assert float(metrics["moe_bias_swapped"]) == pytest.approx(
         np.mean(swapped), abs=1e-6)
+    # half of the experts are held: the row buffer is every assignment
+    assert float(metrics["moe_full_buffer"]) == np.mean(over) == 0.0
 
 
 # -- what is refused, by name -----------------------------------------------------

@@ -479,3 +479,63 @@ def test_which_rows_the_blocks_gradient_moves_and_multiplies(remat):
     # forward 3, backward 2 each, and the recompute's
     matmuls = [e for e in eqns if e.primitive.name.startswith("ragged_dot")]
     assert len(matmuls) == 3 + 6 + 2 * remat, [str(e) for e in matmuls]
+
+
+def _gathers(eqns):
+    """(operand rows, rows written) of every gather of whole rows."""
+    return [(e.invars[0].aval.shape[0], e.outvars[0].aval.shape[0])
+            for e in eqns if e.primitive.name == "gather"
+            and len(e.outvars[0].aval.shape) == 2]
+
+
+def _block_gradient(held, experts_here, top_k=2, tokens=1024, d=16):
+    shapes = [(1, tokens, d), (d, 16), (experts_here, d, 8),
+              (experts_here, d, 8), (experts_here, 8, d)]
+
+    def loss(*a):
+        return moe.moe_swiglu_dropless(*a, top_k=top_k, held=held)[0].sum()
+
+    return jax.make_jaxpr(jax.grad(loss, range(5)))(
+        *[jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]).jaxpr
+
+
+@pytest.mark.parametrize("held", [None, (0, 8), (8, 16)])
+def test_a_caller_with_no_shorter_buffer_traces_no_loop(held):
+    """A model that holds every expert, and a rank that holds half of them
+    or more (twice its balanced share is every assignment), get the block
+    they got: no ``while`` and no ``cond`` in the gradient's jaxpr, and
+    the same four gathers, two of them from an [A, D] operand."""
+    tokens, a_rows = 1024, 2048
+    eqns = list(_eqns(_block_gradient(held, 16 if held is None else 8)))
+    assert not [e for e in eqns if e.primitive.name in ("while", "cond")]
+    assert sorted(_gathers(eqns)) == [
+        (tokens, a_rows), (tokens, a_rows), (a_rows, a_rows), (a_rows, a_rows)]
+
+
+def test_which_rows_a_rank_with_a_quarter_of_the_experts_moves():
+    """Rank 1 of 4 (4 of 16 experts held): the buffer is C = half of the A
+    assignments. The gradient's jaxpr has two ``while`` (the forward's and
+    the backward's rounds) and every gather and grouped matmul of the
+    block is in their bodies, once: the three gathers by ``order //
+    top_k`` (dispatch, its recompute in the backward rule, the combine's
+    backward) write [C, D]; the two moves by ``inverse`` read a [C, D]
+    operand a choice at a time, ``top_k`` gathers of [N, D] each, and
+    write no [A, D] rows; every grouped matmul is over C rows."""
+    top_k, tokens, a_rows = 2, 1024, 2048
+    c_rows = moe._buffer_rows(a_rows, 4, 16)
+    assert c_rows == a_rows // 2
+    jaxpr = _block_gradient((4, 8), 4)
+    loops = [e for e in _eqns(jaxpr) if e.primitive.name == "while"]
+    assert len(loops) == 2
+    assert not [e for e in _eqns(jaxpr) if e.primitive.name == "cond"]
+    inside = [e for loop in loops
+              for e in _eqns(loop.params["body_jaxpr"].jaxpr)]
+    assert sorted(_gathers(inside)) == (
+        [(tokens, c_rows)] * 3 + [(c_rows, tokens)] * 2 * top_k)
+    assert sorted(_gathers(_eqns(jaxpr))) == sorted(_gathers(inside))
+    matmuls = [e for e in inside if e.primitive.name.startswith("ragged_dot")]
+    # forward 3; in the backward rule gate and up again, then 2 each
+    assert len(matmuls) == 3 + 2 + 6
+    assert len([e for e in _eqns(jaxpr)
+                if e.primitive.name.startswith("ragged_dot")]) == 11
+    assert {e.invars[0].aval.shape[0] for e in matmuls} == {c_rows}

@@ -153,6 +153,7 @@ def test_program_equals_reference_logits_loss_and_gradients(seed, held):
     ce = -_common.token_logprobs(want, rows[:, 1:]).mean()
     assert float(loss) - rest == pytest.approx(float(ce), abs=TOL)
     assert ("moe_held_share" in metrics) == (held is not None)
+    assert ("moe_full_buffer" in metrics) == (held is not None)
     g = jax.jit(jax.grad(program_loss), static_argnums=2)(
         params, rows, cfg)["layers"]
     r = jax.jit(jax.grad(reference.loss), static_argnums=2)(
@@ -274,68 +275,151 @@ def _plain_held(x, logits, w_gate, w_up, w_down, held, top_k):
     return out
 
 
+def _held_by(both: int, one: int = 0):
+    """Logits under which the first ``both`` tokens choose held experts 2
+    AND 3, the next ``one`` tokens expert 2 and an absent one, and every
+    other token no held expert: 2 x ``both`` + ``one`` held assignments
+    where ``top_k`` is 2."""
+    def skew(noise):
+        token = jnp.arange(noise.shape[1])[None, :, None]
+        noise = noise.at[..., 2:4].add(jnp.where(token < both, 20.0, -20.0))
+        return noise.at[..., 2:3].add(jnp.where(
+            (token >= both) & (token < both + one), 40.0, 0.0))
+    return skew
+
+
+# name: (tokens, top_k, the logits from the noise, ``full_buffer``). Two of
+# eight experts are held, so the row buffer is twice a quarter of the
+# assignments in whole 512-row tiles: all 288 of 96 x 3, 1024 of 1024 x 2.
 SKEWS = {
     # every token's three choices are held experts 2, 3 and one more
-    "all on held experts": lambda noise: noise.at[..., 2:4].add(20.0),
-    "all on ONE held expert first": lambda noise: noise.at[..., 3].add(20.0),
+    "all on held experts": (
+        96, 3, lambda noise: noise.at[..., 2:4].add(20.0), 0.0),
+    "all on ONE held expert first": (
+        96, 3, lambda noise: noise.at[..., 3].add(20.0), 0.0),
     # no token chooses a held expert at all
-    "all on absent experts": lambda noise: noise.at[..., 2:4].add(-20.0),
-    "as the noise falls": lambda noise: noise,
+    "all on absent experts": (
+        96, 3, lambda noise: noise.at[..., 2:4].add(-20.0), 0.0),
+    "as the noise falls": (96, 3, lambda noise: noise, 0.0),
+    "held total under the buffer": (1024, 2, lambda noise: noise, 0.0),
+    "held total fills the buffer": (1024, 2, _held_by(512), 0.0),
+    "held total one over the buffer": (1024, 2, _held_by(512, 1), 1.0),
+    "every assignment held": (1024, 2, _held_by(1024), 1.0),
+    "no assignment held": (1024, 2, _held_by(0), 0.0),
 }
+HELD_TOTALS = {"held total fills the buffer": 1024,
+               "held total one over the buffer": 1025,
+               "every assignment held": 2048, "no assignment held": 0}
 
 
 @pytest.mark.parametrize("skew", list(SKEWS))
-def test_dropless_with_held_experts_under_skew(skew):
+def test_dropless_with_held_experts_under_skew(skew, monkeypatch):
     """Rank 1 of 4 holds experts 2 and 3. Whatever share of the
     ``top_k x tokens`` assignments lands on them (all of them, none), each
     is computed, nothing is dropped, the absent ones add nothing, and the
-    counters say what happened."""
-    x, w_gate, w_up, w_down, noise = _block_inputs()
-    logits = SKEWS[skew](noise)
-    held, top_k = (2, 4), 3
+    counters say what happened. The row buffer is twice the rank's
+    balanced share and the layer takes it a round at a time: where the
+    held assignments fit one round (to the row), the output, the
+    gradients of x and of the weights and the counters EQUAL, bit for
+    bit, those of the block traced over all ``top_k x tokens`` rows at
+    once; where they take a second round (``full_buffer``), they agree to
+    float32 rounding."""
+    tokens, top_k, skewed, full_buffer = SKEWS[skew]
+    x, w_gate, w_up, w_down, noise = _block_inputs(n=tokens)
+    logits, held = skewed(noise), (2, 4)
     weights = [w[held[0]:held[1]] for w in (w_gate, w_up, w_down)]
+    assert moe._buffer_rows(tokens * top_k, 2, E) == {96: 288, 1024: 1024}[
+        tokens]
 
-    def run(x, *weights):
+    def run(x, logits, *weights):
         return moe.moe_swiglu_dropless(
             x, None, *weights, top_k=top_k, router_logits=logits, held=held,
             activation="relu")
 
-    out, stats = run(x, *weights)
+    def grads_of(block):        # compiled, as a step is: op by op rounds apart
+        return jax.jit(jax.grad(lambda *a: (block(*a) ** 2).sum(), range(5)))(
+            x, logits, *weights)
+
+    out, stats = jax.jit(run)(x, logits, *weights)
     want = _plain_held(x, logits, w_gate, w_up, w_down, held, top_k)
     assert float(jnp.abs(out - want).max()) < TOL
     chosen = np.asarray(jax.lax.top_k(logits, top_k)[1]).reshape(-1)
     counts = np.bincount(chosen, minlength=E)
     here = counts[held[0]:held[1]]
+    assert here.sum() == HELD_TOTALS.get(skew, here.sum())
     assert float(stats["held_share"]) == pytest.approx(
         here.sum() / chosen.size)
+    assert float(stats["full_buffer"]) == full_buffer
     if here.sum():
         assert float(stats["load_max"]) == pytest.approx(
             here.max() / here.mean())
     if skew == "all on held experts":
         assert here.sum() >= 2 * x.shape[1]         # 2 of 3 choices, or more
-    if skew == "all on absent experts":
+    if skew in ("all on absent experts", "no assignment held"):
         assert here.sum() == 0 and float(jnp.abs(out).max()) == 0.0
     # gradients: jax's own through the plain block
-    grads = jax.jit(jax.grad(lambda *a: (run(*a)[0] ** 2).sum(), range(4)))(
-        x, *weights)
-    plain = jax.jit(jax.grad(lambda x, *w: (_plain_held(
+    grads = grads_of(lambda *a: run(*a)[0])
+    plain = grads_of(lambda x, logits, *w: _plain_held(
         x, logits, *[jnp.zeros_like(full).at[held[0]:held[1]].set(part)
                      for full, part in zip((w_gate, w_up, w_down), w)],
-        held, top_k) ** 2).sum(), range(4)))(x, *weights)
+        held, top_k))
     for got, want_g in zip(grads, plain):
         assert bool(jnp.isfinite(got).all())
         np.testing.assert_allclose(np.asarray(got), np.asarray(want_g),
                                    rtol=1e-3, atol=1e-5)
+    # the same block over all ``top_k x tokens`` rows at once, whatever is
+    # held: EQUAL where the held rows take one round of the buffer; where
+    # they take two, a token's choices are summed round by round
+    monkeypatch.setattr(moe, "_buffer_rows", lambda rows, held, of: rows)
+    full_out, full_stats = jax.jit(lambda *a: run(*a))(x, logits, *weights)
+    same = (np.testing.assert_array_equal if not full_buffer else
+            lambda a, b: np.testing.assert_allclose(
+                a, b, rtol=1e-5, atol=1e-5 * float(np.abs(b).max())))
+    same(np.asarray(out), np.asarray(full_out))
+    assert float(full_stats.pop("full_buffer")) == 0.0
+    for name, value in full_stats.items():
+        np.testing.assert_array_equal(np.asarray(stats[name]),
+                                      np.asarray(value), err_msg=name)
+    x_g, logits_g, *weight_gs = zip(grads, grads_of(lambda *a: run(*a)[0]))
+    for got, full in (x_g, *weight_gs):
+        same(np.asarray(got), np.asarray(full))
+    # the gates' gradient <h, dh_u> is a sum of ``d_ff`` products that
+    # XLA's CPU compiler contracts one way in a loop and another outside
+    # one: the last place of float32, not the rows
+    np.testing.assert_allclose(*map(np.asarray, logits_g), rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(logits_g[1]).max()))
+
+
+def _held_counts(cfg, params, rows):
+    """[layers, held experts] assignments, by walking the reference's
+    layers over ``rows``."""
+    first, end = cfg.held_range
+    counts = []
+    x = params["embed"]["tokens"][rows[:, :-1]]
+    for i in range(cfg.n_layers):
+        lp = _common.layer_slice(params["layers"], i)
+        r = reference._rms(x, lp["ln1"]["w"]).reshape(
+            -1, cfg.d_model) @ lp["router"]["w"]
+        chosen = np.asarray(jax.lax.top_k(r, cfg.expert_top_k)[1]).reshape(-1)
+        counts.append(np.bincount(chosen, minlength=cfg.n_experts)[first:end])
+        windowed, with_rope = cfg.layer_pattern[i % len(cfg.layer_pattern)]
+        x, _ = reference._layer(
+            x, lp, cfg.n_heads, float(cfg.rope_theta), cfg.expert_top_k,
+            cfg.sliding_window if windowed else None, bool(with_rope), first)
+    return np.stack(counts)
 
 
 @pytest.mark.parametrize("seed,held", [(0, (1, 4)), (4, (3, 4))])
 def test_the_train_steps_counters_count_the_whole_batch(seed, held):
-    """``moe_held_share`` and ``moe_load_max`` in the TRAIN STEP's metrics
-    dict (what a driver that fetched more than the loss would read; the
-    benchmark's fetches the loss alone) against counts made by walking
-    the reference's layers over both rows: the mean over the layers of
-    the share that went to a held expert, and the fullest held expert
-    over the held mean in the fullest layer."""
+    """``moe_held_share``, ``moe_load_max`` and ``moe_full_buffer`` in the
+    TRAIN STEP's metrics dict (what a driver that fetched more than the
+    loss would read; the benchmark's fetches the loss alone) against
+    counts made by walking the reference's layers over both rows: the
+    mean over the layers of the share that went to a held expert, the
+    fullest held expert over the held mean in the fullest layer, and the
+    share of the layers whose held assignments overflow the row buffer
+    (none: at 384 assignments the buffer is all of them). A model that
+    holds every expert reports neither the share nor the buffer."""
     import optax
 
     cfg, params, rows = make(seed, experts_held=held)
@@ -344,26 +428,56 @@ def test_the_train_steps_counters_count_the_whole_batch(seed, held):
              "step": jnp.zeros((), jnp.int32)}
     _, metrics = jax.jit(models.make_train_step(cfg, opt))(
         state, {"tokens": rows})
-    first, end = moe.held_range(cfg.n_experts, *held)
-    shares, fullest = [], []
-    x = params["embed"]["tokens"][rows[:, :-1]]
-    for i in range(cfg.n_layers):
-        lp = _common.layer_slice(params["layers"], i)
-        r = reference._rms(x, lp["ln1"]["w"]).reshape(
-            -1, cfg.d_model) @ lp["router"]["w"]
-        chosen = np.asarray(jax.lax.top_k(r, cfg.expert_top_k)[1]).reshape(-1)
-        here = np.bincount(chosen, minlength=cfg.n_experts)[first:end]
-        shares.append(here.sum() / chosen.size)
-        fullest.append(here.max() / here.mean())
-        windowed, with_rope = cfg.layer_pattern[i % len(cfg.layer_pattern)]
-        x, _ = reference._layer(
-            x, lp, cfg.n_heads, float(cfg.rope_theta), cfg.expert_top_k,
-            cfg.sliding_window if windowed else None, bool(with_rope), first)
+    here = _held_counts(cfg, params, rows)
+    assignments = rows[:, :-1].size * cfg.expert_top_k
+    shares, fullest = here.sum(1) / assignments, here.max(1) / here.mean(1)
     assert float(metrics["moe_held_share"]) == pytest.approx(
         np.mean(shares), abs=1e-6)
     assert float(metrics["moe_load_max"]) == pytest.approx(
         max(fullest), rel=1e-5)
     assert len(set(np.round(shares, 4))) > 1 and max(fullest) > 1.1   # uneven
+    assert moe._buffer_rows(assignments, here.shape[1],
+                            cfg.n_experts) == assignments
+    assert float(metrics["moe_full_buffer"]) == 0.0
+    whole = replace(cfg, experts_held=None)
+    _, metrics = jax.jit(models.make_train_step(whole, opt))(
+        {**state, "params": models.init_params(jax.random.PRNGKey(0), whole),
+         "opt_state": opt.init(models.init_params(jax.random.PRNGKey(0),
+                                                  whole))}, {"tokens": rows})
+    assert not {"moe_held_share", "moe_full_buffer"} & set(metrics)
+    assert "moe_load_max" in metrics
+
+
+@pytest.mark.parametrize("seed,room", [(0, 2), (4, 2), (4, 1)])
+def test_the_short_row_buffer_is_the_same_model(seed, room, monkeypatch):
+    """At 2 x 256 tokens the row buffer (two tiles of 512 rows for the 2
+    held of 8 experts) is shorter than the 1536 assignments: the loss and
+    every gradient are those of the model traced with the full buffer
+    alone, and ``moe_full_buffer`` is the share of the layers whose held
+    assignments do not fit. With room for the balanced share only (one
+    tile) seed 4's skewed routers overflow it in some layers."""
+    cfg, params, _ = make(seed, max_seq_len=256)
+    rows = jax.random.randint(jax.random.PRNGKey(seed + 2000), (2, 257), 0,
+                              cfg.vocab_size)
+    monkeypatch.setattr(moe, "_HELD_ROOM", room)
+    buffer = moe._buffer_rows(512 * cfg.expert_top_k, 2, cfg.n_experts)
+    assert buffer == 512 * room
+
+    def step():
+        return jax.jit(jax.value_and_grad(lambda p: models.lm_loss(
+            p, {"tokens": rows}, cfg), has_aux=True))(params)
+
+    (loss, metrics), grads = step()
+    over = _held_counts(cfg, params, rows).sum(1) > buffer
+    assert float(metrics["moe_full_buffer"]) == over.mean()
+    assert over.any() == (room == 1) and not over.all()
+    monkeypatch.setattr(moe, "_buffer_rows", lambda rows, held, of: rows)
+    (full_loss, full_metrics), full_grads = step()
+    assert float(full_metrics["moe_full_buffer"]) == 0.0
+    assert float(loss) == pytest.approx(float(full_loss), abs=1e-6)
+    for got, full in zip(jax.tree.leaves(grads), jax.tree.leaves(full_grads)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(full),
+                                   rtol=1e-4, atol=1e-6)
 
 
 def test_held_range_and_what_the_config_refuses():
