@@ -60,6 +60,7 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import math
+import sys
 from dataclasses import dataclass, replace
 from functools import partial
 
@@ -110,17 +111,30 @@ ATTN_SCOPES = ("attn_full", "attn_window")
 # latent form adds outside the kernels (down-projection, the latent's
 # norm, up-projection, RoPE on the rotary parts).
 MLA_SCOPE = "mla_latent"
+# Inside ``attn``, whatever its kind: what attention does, part by part.
+# ``attn_qkv`` the projections into it (latent attention: the query's
+# alone, the latent's are ``mla_latent``), ``attn_pos`` QK-norm and RoPE
+# (latent attention: nested inside ``mla_latent``), ``attn_gqa`` k and v
+# repeated to the query heads, ``attn_core`` the one ``attention(...)``
+# call (the kernels and what ``ops.attention.SCOPES`` names around them,
+# or the materialised scores, softmax and ``p v``), ``attn_out`` the
+# output projection and the residual add.
+ATTN_PART_SCOPES = ("attn_qkv", "attn_pos", "attn_gqa", "attn_core",
+                    "attn_out")
 
 # Which tree's scopes an executable carries. jax's compile-cache key leaves
 # metadata out, so a step loaded from the cache would keep the scope names
 # of whatever tree compiled it. ``SCOPES_ID`` names the bytes of every
-# file that opens a scope of the step (this one and ``ops/moe.py``) and
+# file that opens a scope of the step (this one, ``ops/moe.py`` and
+# ``ops/attention.py``) and
 # rides on one instruction of the train step (the step counter's add) as a
 # frontend attribute, which the key does take: a tree in which one of them
 # differs compiles its own step and never loads another's, so the names in
 # a trace are always those of the tree that ran
 # (``tests/test_model_scopes.py`` holds jax to it on a real cache).
-SCOPE_FILES = (__file__, moe.__file__)
+# (``ray_tpu.ops.attention`` the attribute is the function, not the module.)
+SCOPE_FILES = (__file__, moe.__file__,
+               sys.modules[attention.__module__].__file__)
 
 
 def _scopes_id(files=SCOPE_FILES) -> str:
@@ -936,10 +950,12 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
             q, k, v = _plain_qkv(h, lp["attn"], c, rope, positions)
             shared = {}
         q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-        o = attention(q, k, v, causal=True, impl=c.attn_impl, window=window,
-                      **shared)
-        o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
-        x = x + o
+        with jax.named_scope("attn_core"):
+            o = attention(q, k, v, causal=True, impl=c.attn_impl,
+                          window=window, **shared)
+        with jax.named_scope("attn_out"):
+            o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
+            x = x + o
 
     aux = {name: jnp.zeros((), jnp.float32)
            for name in ("balance", "z", "load_max")}
@@ -996,27 +1012,29 @@ def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
     ``h`` [B, T, D]: projections, QK-norm, RoPE, k and v repeated to the
     query heads."""
     dt = c.compute_dtype
-    if c.kv_heads == c.n_heads:
-        # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
-        # three skinny d→d projections (the weight concat is a few MB,
-        # amortized by XLA across the fused step).
-        wqkv = jnp.concatenate(
-            [w["wq"].astype(dt), w["wk"].astype(dt), w["wv"].astype(dt)],
-            axis=-1,
-        )  # [d, h, 3k]
-        qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-    else:
-        q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
-        k = jnp.einsum("btd,dhk->bthk", h, w["wk"].astype(dt))
-        v = jnp.einsum("btd,dhk->bthk", h, w["wv"].astype(dt))
-    if c.qk_norm:
-        q = _qk_norm(q, w["q_norm"])
-        k = _qk_norm(k, w["k_norm"])
-    if rope is not None:
-        cos, sin = rope
-        q = apply_rope(q, cos, sin, positions=positions)
-        k = apply_rope(k, cos, sin, positions=positions)
+    with jax.named_scope("attn_qkv"):
+        if c.kv_heads == c.n_heads:
+            # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
+            # three skinny d→d projections (the weight concat is a few MB,
+            # amortized by XLA across the fused step).
+            wqkv = jnp.concatenate(
+                [w["wq"].astype(dt), w["wk"].astype(dt), w["wv"].astype(dt)],
+                axis=-1,
+            )  # [d, h, 3k]
+            qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+        else:
+            q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
+            k = jnp.einsum("btd,dhk->bthk", h, w["wk"].astype(dt))
+            v = jnp.einsum("btd,dhk->bthk", h, w["wv"].astype(dt))
+    with jax.named_scope("attn_pos"):
+        if c.qk_norm:
+            q = _qk_norm(q, w["q_norm"])
+            k = _qk_norm(k, w["k_norm"])
+        if rope is not None:
+            cos, sin = rope
+            q = apply_rope(q, cos, sin, positions=positions)
+            k = apply_rope(k, cos, sin, positions=positions)
     return (q, *_expand_gqa(k, v, c))
 
 
@@ -1032,16 +1050,18 @@ def _latent_qkv(h, w, c: TransformerConfig, rope, positions):
     dt = c.compute_dtype
     nope, latent = c.d_head_nope, c.kv_latent
     cos, sin = rope
-    q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
+    with jax.named_scope("attn_qkv"):
+        q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
     with jax.named_scope(MLA_SCOPE):
         down = jnp.einsum("btd,dc->btc", h, w["wkv_a"].astype(dt))
         kv = jnp.einsum("btc,chk->bthk",
                         rms_norm(down[..., :latent], w["kv_norm"],
                                  eps=c.norm_eps),
                         w["wkv_b"].astype(dt))
-        q_rope = apply_rope(q[..., nope:], cos, sin, positions=positions)
-        k_rope = apply_rope(down[:, :, None, latent:], cos, sin,
-                            positions=positions)[:, :, 0]
+        with jax.named_scope("attn_pos"):
+            q_rope = apply_rope(q[..., nope:], cos, sin, positions=positions)
+            k_rope = apply_rope(down[:, :, None, latent:], cos, sin,
+                                positions=positions)[:, :, 0]
         return (q[..., :nope], kv[..., :nope], kv[..., nope:],
                 {"q_shared": q_rope, "k_shared": k_rope})
 
@@ -1056,7 +1076,8 @@ def _expand_gqa(k, v, c: TransformerConfig):
     if c.kv_heads == c.n_heads:
         return k, v
     rep = c.n_heads // c.kv_heads
-    return (jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2))
+    with jax.named_scope("attn_gqa"):
+        return (jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2))
 
 
 # -- loss / train step ------------------------------------------------------

@@ -41,6 +41,15 @@ from jax.ad_checkpoint import checkpoint_name
 
 _NEG_INF = -1e30
 
+# What the Pallas kernels' operand layout costs around them, as named
+# scopes inside the model's ``attn`` / ``attn_core`` (forward, and INSIDE
+# the custom gradient's backward rule): ``attn_layout`` every move between
+# [B, T, H, W] and the kernels' [B * H, T, W] and the logsumexp's between
+# a column and dense; ``attn_delta`` the backward's rowsum(dO * O). The
+# kernels themselves need none: their ``op_name`` ends in ``pallas_call``.
+# This file is one of ``models.transformer.SCOPE_FILES``.
+SCOPES = ("attn_layout", "attn_delta")
+
 
 def _causal_mask(q_pos, k_pos, window: int | None = None):
     """Key j is visible to query i iff ``j <= i`` and, under a sliding
@@ -472,7 +481,15 @@ def _flash_fwd_kernel(*refs, block_q, block_k, n_k, n_steps, causal, scale,
 def _heads_flat(x):
     """[B, T, H, W] -> [B * H, T, W]: one grid row a (batch, head)."""
     b, t, h, w = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, t, w)
+    with jax.named_scope("attn_layout"):
+        return x.transpose(0, 2, 1, 3).reshape(b * h, t, w)
+
+
+def _heads_back(x, b: int):
+    """[B * H, T, W] -> [B, T, H, W]: what ``_heads_flat`` undoes."""
+    bh, t, w = x.shape
+    with jax.named_scope("attn_layout"):
+        return x.reshape(b, bh // b, t, w).transpose(0, 2, 1, 3)
 
 
 def _flash_scale(q, q_shared) -> float:
@@ -533,7 +550,7 @@ def _flash_forward(q, k, v, q_shared=None, k_shared=None, *, causal, block_q,
         compiler_params=params,
         interpret=interpret,
     )(*operands)
-    out = out.reshape(b, h, tq, dv).transpose(0, 2, 1, 3)
+    out = _heads_back(out, b)
     return (out, lse) if return_lse else out
 
 
@@ -704,8 +721,9 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
     kf, vf = _heads_flat(k), _heads_flat(v)
     # delta = rowsum(dO * O): one fused elementwise pass in XLA. Kept as
     # a [bh, tq, 1] column (same block-legality story as lse).
-    delta = (gf.astype(jnp.float32) * of.astype(jnp.float32)).sum(
-        -1, keepdims=True)
+    with jax.named_scope("attn_delta"):
+        delta = (gf.astype(jnp.float32) * of.astype(jnp.float32)).sum(
+            -1, keepdims=True)
     operands = [qf, kf, vf, gf, lse, delta]
     if shared:
         operands += [_heads_flat(q_shared), k_shared]
@@ -765,14 +783,12 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
         interpret=interpret,
     )(*operands)
 
-    def unflat(x, t):
-        return x.reshape(b, h, t, x.shape[-1]).transpose(0, 2, 1, 3)
-
-    grads = (unflat(dq[0], tq), unflat(dkv[0], tk), unflat(dkv[1], tk))
+    grads = (_heads_back(dq[0], b), _heads_back(dkv[0], b),
+             _heads_back(dkv[1], b))
     if not shared:
         return (*grads, None, None)
     dk_shared = dkv[2].reshape(b, h, tk, dr).astype(jnp.float32).sum(1)
-    return (*grads, unflat(dq[1], tq), dk_shared.astype(k_shared.dtype))
+    return (*grads, _heads_back(dq[1], b), dk_shared.astype(k_shared.dtype))
 
 
 def _interpret() -> bool:
@@ -866,14 +882,18 @@ def _flash_fwd_rule(q, k, v, causal, block_q, block_k, window, q_shared=None,
     # writes it, [bh, tq, 1] float32, its last dimension can be padded to
     # the 128 lanes in HBM.
     out = checkpoint_name(out, FLASH_OUT_NAME)
-    lse = checkpoint_name(lse[..., 0], FLASH_LSE_NAME)
+    with jax.named_scope("attn_layout"):
+        lse = lse[..., 0]
+    lse = checkpoint_name(lse, FLASH_LSE_NAME)
     return out, (q, k, v, out, lse, q_shared, k_shared)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, window, res, g):
     q, k, v, out, lse, q_shared, k_shared = res
+    with jax.named_scope("attn_layout"):
+        lse = lse[..., None]        # back to the kernels' column
     return _flash_backward(
-        q, k, v, out, lse[..., None], g, q_shared, k_shared, causal=causal,
+        q, k, v, out, lse, g, q_shared, k_shared, causal=causal,
         block_q=block_q, block_k=block_k, interpret=_interpret(),
         window=window,
     )

@@ -603,4 +603,4 @@ def test_the_new_scopes_are_on_the_instructions():
         assert path in text, path
     assert "attn_window" not in text
     assert "moe_shared" in moe.SCOPES and transformer.MLA_SCOPE == "mla_latent"
-    assert transformer.SCOPE_FILES == (transformer.__file__, moe.__file__)
+    assert transformer.SCOPE_FILES[:2] == (transformer.__file__, moe.__file__)

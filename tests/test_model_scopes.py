@@ -7,6 +7,8 @@ the backward pass apart. Settled here on CPU programs, not on the chip:
 
 from __future__ import annotations
 
+import contextlib
+import importlib
 import re
 from dataclasses import replace
 
@@ -18,6 +20,9 @@ import pytest
 from chipbench import scopes
 from ray_tpu import models
 from ray_tpu.models import transformer
+
+# ``ray_tpu.ops.attention`` the attribute is the dispatch function.
+attention_ops = importlib.import_module("ray_tpu.ops.attention")
 
 
 def _traced_step(cfg, *, rows: int = 4, seq_len: int = 32,
@@ -117,12 +122,14 @@ def test_dropless_experts_carry_their_sub_scopes_in_every_pass():
 
 
 def test_scopes_id_covers_every_file_that_opens_a_scope(tmp_path):
-    """``SCOPES_ID`` is over the bytes of ``models/transformer.py`` AND
-    ``ops/moe.py``: an edit of either gives another id, so a step cached
-    by a tree with other sub-scope names is never loaded."""
+    """``SCOPES_ID`` is over the bytes of ``models/transformer.py``,
+    ``ops/moe.py`` AND ``ops/attention.py``: an edit of any gives another
+    id, so a step cached by a tree with other sub-scope names is never
+    loaded."""
     from ray_tpu.ops import moe
 
-    assert transformer.SCOPE_FILES == (transformer.__file__, moe.__file__)
+    assert transformer.SCOPE_FILES == (
+        transformer.__file__, moe.__file__, attention_ops.__file__)
     assert transformer.SCOPES_ID == transformer._scopes_id()
     for i, path in enumerate(transformer.SCOPE_FILES):
         edited = tmp_path / f"edited{i}.py"
@@ -131,6 +138,101 @@ def test_scopes_id_covers_every_file_that_opens_a_scope(tmp_path):
         files = list(transformer.SCOPE_FILES)
         files[i] = str(edited)
         assert transformer._scopes_id(files) != transformer.SCOPES_ID
+
+
+ATTN_NAMES = transformer.ATTN_PART_SCOPES + attention_ops.SCOPES
+_F, _R, _B = scopes.PASSES
+_EVERY = {_F, _R, _B}
+# One tiny model per attention path: (the config, row length, the passes
+# each name must be on in the COMPILED step). The kernel's output and
+# logsumexp outlive the layer's remat, so nothing of ``attn_core`` is
+# recomputed on the kernel path; its forward layout moves are layouts,
+# not instructions, on the CPU (the lowered text is asked for them).
+ATTENTION_PATHS = {
+    # GQA 4 / 2, RoPE, QK-norm, the Pallas kernels (interpreted): all seven
+    "kernel": (lambda: models.TransformerConfig(
+        arch="llama", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
+        d_ff=128, vocab_size=256, max_seq_len=256, qk_norm=True,
+        attn_impl="flash", remat=True), 256,
+        {"attn_qkv": _EVERY, "attn_pos": _EVERY, "attn_gqa": _EVERY,
+         "attn_core": {_F, _B}, "attn_out": _EVERY, "attn_layout": {_B},
+         "attn_delta": {_B}}),
+    # latent attention: no repeat, positions inside ``mla_latent``
+    "latent": (lambda: models.kanana_2_30b_a3b(
+        n_layers=3, d_model=64, n_heads=4, d_ff=32, kv_latent=32,
+        d_head_nope=16, d_head_rope=8, d_head_v=16, d_ff_dense=96,
+        d_ff_shared=48, n_experts=8, expert_top_k=3, vocab_size=256,
+        max_seq_len=256, experts_held=(1, 4), attn_impl="flash"), 256,
+        {"attn_qkv": _EVERY, "attn_pos": _EVERY, "attn_core": {_F, _B},
+         "attn_out": _EVERY, "attn_layout": {_B}, "attn_delta": {_B}}),
+    # gpt2: learned positions, every head its own key, materialised scores
+    "materialised": (lambda: models.tiny(remat=True), 32,
+                     {"attn_qkv": _EVERY, "attn_core": _EVERY,
+                      "attn_out": _EVERY}),
+}
+
+
+@pytest.mark.parametrize("path", ATTENTION_PATHS)
+def test_attention_names_its_parts_in_every_pass(path):
+    """The seven names of ``transformer.ATTN_PART_SCOPES`` and
+    ``ops.attention.SCOPES`` reach the compiled step's ``op_name``s in
+    every pass that has work of theirs, a model has only the names whose
+    work it has, and the benchmark's rule still gives every one of those
+    instructions to ``attn``, so ``step_attn_ms`` stays whole. The two
+    names ``ops/attention.py`` opens inside the custom gradient's
+    backward rule read ``backward`` (their path holds ``transpose(``)."""
+    make, seq_len, want = ATTENTION_PATHS[path]
+    lowered = _traced_step(make(), rows=2, seq_len=seq_len).lower()
+    found: dict[str, set] = {}
+    for name in re.findall(r'op_name="([^"]*)"',
+                           lowered.compile().as_text()):
+        pieces = scopes._CUT.split(name)
+        for part in set(pieces).intersection(ATTN_NAMES):
+            model_part, ps = scopes.classify(name)
+            assert model_part == "attn", name
+            found.setdefault(part, set()).add(ps)
+            if part in attention_ops.SCOPES and name.startswith("jit("):
+                assert "transpose(" in name, name
+            if part == "attn_pos":
+                assert (transformer.MLA_SCOPE in pieces) == (path == "latent")
+                assert "attn_qkv" not in pieces, name
+    assert set(found) == set(want), sorted(found)
+    for part, passes in want.items():
+        assert found[part] >= passes, (part, sorted(found[part]))
+    # the forward rule's own moves, before any compiler has had them
+    moves = re.findall(r'loc\("([^"]*attn_core/attn_layout/transpose)"',
+                       lowered.as_text(debug_info=True))
+    assert any("transpose(" not in name and not name.startswith("checkpoint/")
+               for name in moves) == ("attn_layout" in want), moves
+    assert not set(ATTN_NAMES) & set(scopes.PARTS)
+
+
+@pytest.mark.parametrize("path", ATTENTION_PATHS)
+def test_the_attention_scopes_cost_nothing(path, monkeypatch):
+    """A named scope is metadata: the optimised step compiled with the
+    seven names patched away is, ``metadata={...}``, the tables of
+    Python frames it points at and the ``scopes=`` frontend attribute
+    stripped, the same text, instruction for instruction."""
+    make, seq_len, _ = ATTENTION_PATHS[path]
+
+    def stripped() -> tuple[str, bool]:
+        jax.clear_caches()
+        text = _step_text(make(), rows=2, seq_len=seq_len)
+        named = any(f"/{name}/" in text for name in ATTN_NAMES)
+        # the module's tables of the Python frames its metadata points at
+        text = re.sub(r"\n(?:FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(?:\d+ .*\n)+", "\n", text)
+        text = re.sub(r',? ?metadata=\{[^}]*\}', "", text)
+        return re.sub(r'scopes="[^"]*"', "", text), named
+
+    with_names, named = stripped()
+    assert named
+    opened = jax.named_scope
+    monkeypatch.setattr(jax, "named_scope", lambda name: (
+        contextlib.nullcontext() if name in ATTN_NAMES else opened(name)))
+    without, named = stripped()
+    assert not named
+    assert with_names == without
 
 
 def test_causal_blocks_leave_no_whole_score_tensor_and_stay_in_attn():
