@@ -23,11 +23,12 @@ Four tiers:
     above 1024 keys where the kernel does not apply, and the step ring
     attention is built from.
   - ``flash_attention`` — Pallas TPU kernels (interpret-mode on CPU):
-    a custom_vjp of a forward kernel and two backward kernels (dq;
-    dk / dv) that rebuild the probabilities tile by tile from the saved
-    logsumexp, so training through it stays O(T) memory. Each kernel
-    sizes its own tiles from the shapes (``_flash_tiles``); what
-    ``auto`` takes above 1024 keys on a TPU.
+    a custom_vjp of a forward kernel and ONE backward kernel that
+    rebuilds a tile's probabilities from the saved logsumexp once and
+    feeds dq, dk and dv from them (two kernels, dq and dk / dv, where a
+    head's dq does not fit in VMEM), so training through it stays O(T)
+    memory. Each kernel sizes its own tiles from the shapes
+    (``_flash_tiles``); what ``auto`` takes above 1024 keys on a TPU.
 """
 
 from __future__ import annotations
@@ -206,12 +207,12 @@ def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
 # Pallas TPU flash attention: the tiles of a grid step, the forward kernel.
 # ---------------------------------------------------------------------------
 
-# The most rows of q, and of k / v, one grid step takes, in all three
-# kernels: each kernel alone on the v5e, tiles of 128 to 2048 rows at T
-# 1280 to 8192, head widths 64 and 128, causal and not, ran fastest (or
-# within 3% of it) at the largest divisor of T up to 1024; only the
-# forward at T = 1280 / 1536 wants T whole, by 10% (PERF.md section 6,
-# PR 28). A 1024-tile computes 10/16 of T^2 at T = 4096 where a 256-tile
+# The most rows of q, and of k / v, one grid step takes, in every
+# kernel: each of forward, dq and dk / dv alone on the v5e, tiles of 128
+# to 2048 rows at T 1280 to 8192, head widths 64 and 128, causal and not,
+# ran fastest (or within 3% of it) at the largest divisor of T up to
+# 1024; only the forward at T = 1280 / 1536 wants T whole, by 10%
+# (PERF.md section 6, PR 28). A 1024-tile computes 10/16 of T^2 at T = 4096 where a 256-tile
 # computes 136/256, and still wins: a grid step's fixed cost and the
 # refetch of k / v for every block of q outweigh the masked entries.
 _FLASH_ROWS = 1024
@@ -220,8 +221,16 @@ _FLASH_ROWS = 1024
 # With these the arithmetic below is 1.4 to 8 times the least limit
 # under which Mosaic compiles the kernel for the v5e (256 to 2048 rows,
 # widths 64 and 128, both dtypes): it counts every tile as live at once.
-_FLASH_SCORE_TILES = {"fwd": 3, "dq": 3, "dkv": 4}
-_FLASH_VMEM_MOST = 32 * 2 ** 20      # a quarter of a v5e core's 128 MiB
+# "bwd" is "dkv" with dq accumulated as well: the same tiles, once.
+_FLASH_SCORE_TILES = {"fwd": 3, "dq": 3, "dkv": 4, "bwd": 4}
+# A quarter of a v5e core's 128 MiB, for "bwd" too: with both parts of
+# kanana-2's dq for the whole head beside them ([8192, 128 + 64] float32,
+# 8 MiB) 1024 x 1024 tiles come to 33.0 MiB by this arithmetic and the
+# rule steps to (512, 1024), which ran the layer's backward in 38.98 ms
+# where (1024, 1024) under a 40-MiB ceiling ran it in 38.99 (v5e,
+# [2, 8192, 32, 128 + 64]; the two kernels 52.41; PERF.md section 6,
+# PR 38): nothing to move the ceiling for.
+_FLASH_VMEM_MOST = 32 * 2 ** 20
 _FLASH_VMEM_LEAST = 16 * 2 ** 20     # Mosaic's own default on the v5e
 
 
@@ -231,13 +240,15 @@ def _vmem_tile(rows: int, cols: int, itemsize: int) -> int:
 
 
 def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
-                      dtype, dv: int | None = None, dr: int = 0) -> int:
-    """VMEM one grid step of ``kernel`` ("fwd", "dq" or "dkv") keeps
-    live with these tiles: the operand and output blocks, each twice
-    (the pipeline fetches the next step's while this one computes), the
-    float32 accumulators, and the score-shaped tiles. ``d`` is the width
-    of q and k, ``dv`` of v (None: ``d``), ``dr`` of the shared part of
-    the key and of the queries' part that meets it (0: none)."""
+                      dtype, dv: int | None = None, dr: int = 0,
+                      tq: int = 0) -> int:
+    """VMEM one grid step of ``kernel`` ("fwd", "dq", "dkv" or "bwd")
+    keeps live with these tiles: the operand and output blocks, each
+    twice (the pipeline fetches the next step's while this one computes),
+    the float32 accumulators, and the score-shaped tiles. ``d`` is the
+    width of q and k, ``dv`` of v (None: ``d``), ``dr`` of the shared part
+    of the key and of the queries' part that meets it (0: none). ``tq``
+    ("bwd" only): the rows of a head, all of whose dq the kernel keeps."""
     dv = d if dv is None else dv
     io = jnp.dtype(dtype).itemsize
     q_rows = _vmem_tile(block_q, d, io)          # q, dq
@@ -247,19 +258,23 @@ def _flash_vmem_bytes(kernel: str, block_q: int, block_k: int, d: int,
     qr_rows = _vmem_tile(block_q, dr, io) if dr else 0    # q_shared, its dq
     kr_rows = _vmem_tile(block_k, dr, io) if dr else 0    # k_shared, its dk
     column = _vmem_tile(block_q, 1, 4)           # lse, delta, m, l
+    dq_acc = lambda rows: _vmem_tile(rows, d, 4) + (  # noqa: E731
+        _vmem_tile(rows, dr, 4) if dr else 0)
     if kernel == "fwd":      # q, k, v -> o, lse; scratch m, l, acc
         blocks = q_rows + qr_rows + k_rows + kr_rows + v_rows + o_rows + column
         scratch = 2 * column + _vmem_tile(block_q, dv, 4)
     elif kernel == "dq":     # q, k, v, g, lse, delta -> dq; scratch acc
         blocks = (2 * (q_rows + qr_rows) + k_rows + kr_rows + v_rows + o_rows
                   + 2 * column)
-        scratch = _vmem_tile(block_q, d, 4) + (
-            _vmem_tile(block_q, dr, 4) if dr else 0)
+        scratch = dq_acc(block_q)
     else:                    # q, k, v, g, lse, delta -> dk, dv; their accs
         blocks = (q_rows + qr_rows + o_rows + 2 * (k_rows + kr_rows + v_rows)
                   + 2 * column)
         scratch = (_vmem_tile(block_k, d, 4) + _vmem_tile(block_k, dv, 4)
                    + (_vmem_tile(block_k, dr, 4) if dr else 0))
+        if kernel == "bwd":  # ... -> dq as well; its acc holds the head
+            blocks += q_rows + qr_rows
+            scratch += dq_acc(tq)
     scores = _FLASH_SCORE_TILES[kernel] * _vmem_tile(block_q, block_k, 4)
     return 2 * blocks + scratch + scores
 
@@ -272,13 +287,14 @@ def _flash_tiles(kernel: str, tq: int, tk: int, d: int, dtype,
     largest pair whose grid step fits ``_FLASH_VMEM_MOST``. That is the
     largest divisor of each, but for float32 heads four times as wide as
     any preset's. None where ``tq`` or ``tk`` is not a whole number of
-    128-row tiles."""
+    128-row tiles, and for "bwd" where a head's dq does not fit beside
+    the smallest tiles: the backward then takes "dq" and "dkv"."""
     def divisors(t):
         return [r for r in range(128, min(_FLASH_ROWS, t) + 1, 128)
                 if t % r == 0]
 
     fits = [(bq, bk) for bq in divisors(tq) for bk in divisors(tk)
-            if _flash_vmem_bytes(kernel, bq, bk, d, dtype, dv, dr)
+            if _flash_vmem_bytes(kernel, bq, bk, d, dtype, dv, dr, tq)
             <= _FLASH_VMEM_MOST]
     return max(fits, key=lambda tile: (tile[0] * tile[1], tile[1]),
                default=None)
@@ -306,8 +322,8 @@ def _flash_launch(kernel: str, q, k, block_q, block_k, v=None,
     if tq % block_q or tk % block_k:
         raise ValueError(f"seq lens ({tq},{tk}) must divide blocks "
                          f"({block_q},{block_k})")
-    vmem = max(_flash_vmem_bytes(kernel, block_q, block_k, d, q.dtype, dv, dr),
-               _FLASH_VMEM_LEAST)
+    vmem = max(_flash_vmem_bytes(kernel, block_q, block_k, d, q.dtype, dv, dr,
+                                 tq), _FLASH_VMEM_LEAST)
     return block_q, block_k, pltpu.CompilerParams(vmem_limit_bytes=vmem)
 
 
@@ -564,9 +580,19 @@ def _flash_forward(q, k, v, q_shared=None, k_shared=None, *, causal, block_q,
 #   dp = dO V^T
 #   ds = p * (dp - delta) * scale
 #   dq += ds K ;  dk += ds^T Q
-# Two kernels: dq accumulates over key blocks (grid b,i,j — the forward's
-# layout), dk/dv accumulate over query blocks (grid b,j,i). O(T) memory;
-# the O(T^2) probabilities exist only as VMEM tiles.
+# ONE kernel ("bwd") wherever a head's dq fits in VMEM: the grid is
+# (b, j, i), query blocks innermost, dk / dv accumulate over them in
+# [block_k, d] float32 scratch, and the SAME ``ds`` adds to the rows of
+# its query block in a float32 scratch that holds the whole head's dq,
+# [Tq, d]. A tile's scores, mask, exponential, ``dp`` and ``ds`` are made
+# once and its five matmuls run once; each element of dq leaves the
+# kernel once, in the inputs' dtype, when its last key block is done.
+# Where that scratch does not fit (``_flash_tiles("bwd", ...)`` is None,
+# e.g. T = 65,536) two kernels, each of which rebuilds every tile: dq
+# accumulates over key blocks (grid b,i,j — the forward's layout), dk/dv
+# over query blocks (grid b,j,i). Both forms sum in the same order and
+# give the same bits. O(T) memory; the O(T^2) probabilities exist only
+# as VMEM tiles.
 # ---------------------------------------------------------------------------
 
 
@@ -645,16 +671,52 @@ def _flash_bwd_dq_kernel(*refs, block_q, block_k, n_k, n_steps, causal,
             dqs_ref[0] = accs_ref[:].astype(dqs_ref.dtype)
 
 
+def _dq_last_key_block(q_blk, block_q: int, block_k: int, n_k: int, causal):
+    """The last key block that adds to the dq of query block ``q_blk``:
+    the one its last row's own key is in (causal), else the last."""
+    if not causal:
+        return n_k - 1
+    return jnp.minimum((q_blk * block_q + block_q - 1) // block_k, n_k - 1)
+
+
+def _dq_out_block(q_blk, k_blk, block_q: int, block_k: int, n_q: int,
+                  n_k: int, causal):
+    """The query block whose dq the "bwd" kernel's output block holds
+    while key block ``k_blk`` walks query block ``q_blk``. Pallas writes
+    an output block back when its index moves on, so the index only ever
+    moves onto a block that is finished (``_dq_last_key_block``) during
+    its stay, and never returns to one: the block being walked, held
+    between the first block that key block ``k_blk`` or a later one
+    finishes and the last that it or an earlier one did. Causal with
+    ``block_q == block_k``: the key block's own, all along; not causal:
+    block 0 until the last key block, which finishes them all."""
+    def finished_before(j):
+        done = jnp.minimum(j * block_k // block_q, n_q) if causal else 0
+        return jnp.where(j >= n_k, n_q, done)
+    blk = jnp.minimum(jnp.maximum(q_blk, finished_before(k_blk)),
+                      finished_before(k_blk + 1) - 1)
+    return jnp.maximum(blk, 0)
+
+
 def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
-                          scale, window=None, shared=False):
+                          scale, window=None, shared=False, n_k=None):
+    """dk and dv of key block ``program_id(1)``, summed over the query
+    blocks it walks. With ``n_k`` (the "bwd" kernel) dq as well: every
+    tile's ``ds`` also adds ``ds k`` to its query block's rows of a
+    float32 accumulator that holds the whole head (zeroed at the head's
+    first step; key blocks are the OUTER axis, so a row's dq is summed in
+    ascending key blocks, the dq kernel's order), and the tile of a query
+    block's last key block writes those rows out, once."""
     import jax.experimental.pallas as pl
 
-    if shared:
-        (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, qs_ref, ks_ref,
-         dk_ref, dv_ref, dks_ref, dk_acc, dv_acc, dks_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-         dk_acc, dv_acc) = refs
+    n_in = 8 if shared else 6
+    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref = refs[:6]
+    qs_ref, ks_ref = refs[6:n_in] if shared else (None, None)
+    rest = refs[n_in:]       # the outputs, then a float32 accumulator each
+    outs, accs = rest[:len(rest) // 2], rest[len(rest) // 2:]
+    n_keys = 3 if shared else 2       # dk, dv, dk_shared; then dq, dq_shared
+    dk_acc, dv_acc = accs[:2]
+    dks_acc = accs[2] if shared else None
     k_blk = pl.program_id(1)
     step = pl.program_id(2)
     q_blk, in_range = _inner_block(step, k_blk, block_k, block_q, n_q,
@@ -662,10 +724,14 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
 
     @pl.when(step == 0)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-        if shared:
-            dks_acc[:] = jnp.zeros_like(dks_acc)
+        for acc in accs[:n_keys]:
+            acc[:] = jnp.zeros_like(acc)
+
+    if n_k is not None:
+        @pl.when((step == 0) & (k_blk == 0))
+        def _init_dq():
+            for acc in accs[n_keys:]:
+                acc[:] = jnp.zeros_like(acc)
 
     def _compute(masked):
         q, g = q_ref[0], g_ref[0]
@@ -686,6 +752,18 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
             dks_acc[:] += jax.lax.dot_general(
                 ds, qs_ref[0], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+        if n_k is None:
+            return
+        for acc, keys in zip(accs[n_keys:], (k_ref, ks_ref)):
+            acc[q_blk] += jax.lax.dot_general(
+                ds, keys[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(k_blk == _dq_last_key_block(q_blk, block_q, block_k, n_k,
+                                             causal))
+        def _emit_dq():
+            for out, acc in zip(outs[n_keys:], accs[n_keys:]):
+                out[0] = acc[q_blk].astype(out.dtype)
 
     # Plain causal: skip query blocks entirely ABOVE the diagonal for this
     # key block (no query there attends to these keys).
@@ -696,10 +774,8 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
 
     @pl.when(step == n_steps - 1)
     def _emit():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
-        if shared:
-            dks_ref[0] = dks_acc[:].astype(dks_ref.dtype)
+        for out, acc in zip(outs[:n_keys], accs[:n_keys]):
+            out[0] = acc[:].astype(out.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
@@ -707,7 +783,12 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
     """(dq, dk, dv, dq_shared, dk_shared); the last two None with no
     shared key part. Every head's gradient of the shared part leaves the
     dk / dv kernel as its own [B * H, Tk, dr] rows and is summed over
-    the heads here: the part itself is never repeated."""
+    the heads here: the part itself is never repeated.
+
+    ONE kernel ("bwd": the dk / dv kernel, accumulating dq as well)
+    wherever a head's float32 dq fits in VMEM beside its tiles
+    (``_flash_tiles("bwd", ...)``), decided from the shapes; else the two
+    kernels "dq" and "dkv"."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -717,6 +798,7 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
     bh = b * h
     shared = q_shared is not None
     dr = q_shared.shape[-1] if shared else 0
+    one_kernel = _flash_tiles("bwd", tq, tk, d, q.dtype, dv, dr) is not None
     qf, gf, of = _heads_flat(q), _heads_flat(g), _heads_flat(out)
     kf, vf = _heads_flat(k), _heads_flat(v)
     # delta = rowsum(dO * O): one fused elementwise pass in XLA. Kept as
@@ -740,48 +822,64 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
                       pl.BlockSpec((1, bk, dr), shared_keys)]
         return specs
 
-    # The dq pass: grid (b, i, j), key blocks innermost.
-    bq, bk, params = _flash_launch("dq", q, k, block_q, block_k, v, q_shared)
-    n_steps, k_of = _flash_inner(window, bq, bk, tq // bq, tk // bk, True)
-    rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
-    keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
-    shared_keys = lambda b_, i, j: (b_ // h, k_of(i, j), 0)  # noqa: E731
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
-                          n_k=tk // bk, n_steps=n_steps, causal=causal,
-                          scale=scale, window=window, shared=shared),
-        grid=(bh, tq // bq, n_steps),
-        in_specs=in_specs(bq, bk, rows, keys, shared_keys),
-        out_specs=[pl.BlockSpec((1, bq, w), rows) for w in (d, dr) if w],
-        out_shape=[jax.ShapeDtypeStruct((bh, tq, w), q.dtype)
-                   for w in (d, dr) if w],
-        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)
-                        for w in (d, dr) if w],
-        compiler_params=params,
-        interpret=interpret,
-    )(*operands)
+    dq_widths = [w for w in (d, dr) if w]
+    dq_shapes = [jax.ShapeDtypeStruct((bh, tq, w), q.dtype)
+                 for w in dq_widths]
+    if not one_kernel:      # the dq pass: grid (b, i, j), key blocks innermost
+        bq, bk, params = _flash_launch("dq", q, k, block_q, block_k, v,
+                                       q_shared)
+        n_steps, k_of = _flash_inner(window, bq, bk, tq // bq, tk // bk, True)
+        rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
+        keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
+        shared_keys = lambda b_, i, j: (b_ // h, k_of(i, j), 0)  # noqa: E731
+        dq = pl.pallas_call(
+            functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
+                              n_k=tk // bk, n_steps=n_steps, causal=causal,
+                              scale=scale, window=window, shared=shared),
+            grid=(bh, tq // bq, n_steps),
+            in_specs=in_specs(bq, bk, rows, keys, shared_keys),
+            out_specs=[pl.BlockSpec((1, bq, w), rows) for w in dq_widths],
+            out_shape=dq_shapes,
+            scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)
+                            for w in dq_widths],
+            compiler_params=params,
+            interpret=interpret,
+        )(*operands)
 
     # The dk / dv pass: grid (b, j, i), query blocks innermost.
-    bq, bk, params = _flash_launch("dkv", q, k, block_q, block_k, v,
-                                   q_shared)
-    n_steps, q_of = _flash_inner(window, bk, bq, tk // bk, tq // bq, False)
+    bq, bk, params = _flash_launch("bwd" if one_kernel else "dkv", q, k,
+                                   block_q, block_k, v, q_shared)
+    n_q, n_k = tq // bq, tk // bk
+    n_steps, q_of = _flash_inner(window, bk, bq, n_k, n_q, False)
     rows = lambda b_, j, i: (b_, q_of(j, i), 0)  # noqa: E731
     keys = lambda b_, j, i: (b_, j, 0)  # noqa: E731
     shared_keys = lambda b_, j, i: (b_ // h, j, 0)  # noqa: E731
+    key_widths = [w for w in (d, dv, dr) if w]
+    out_specs = [pl.BlockSpec((1, bk, w), keys) for w in key_widths]
+    out_shape = [jax.ShapeDtypeStruct((bh, tk, w), k.dtype)
+                 for w in key_widths]
+    scratch = [pltpu.VMEM((bk, w), jnp.float32) for w in key_widths]
+    if one_kernel:          # dq as well: its outputs and accumulators follow
+        dq_rows = lambda b_, j, i: (b_, _dq_out_block(  # noqa: E731
+            q_of(j, i), j, bq, bk, n_q, n_k, causal), 0)
+        out_specs += [pl.BlockSpec((1, bq, w), dq_rows) for w in dq_widths]
+        out_shape += dq_shapes
+        scratch += [pltpu.VMEM((n_q, bq, w), jnp.float32) for w in dq_widths]
     dkv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
-                          n_q=tq // bq, n_steps=n_steps, causal=causal,
-                          scale=scale, window=window, shared=shared),
-        grid=(bh, tk // bk, n_steps),
+                          n_q=n_q, n_steps=n_steps, causal=causal,
+                          scale=scale, window=window, shared=shared,
+                          n_k=n_k if one_kernel else None),
+        grid=(bh, n_k, n_steps),
         in_specs=in_specs(bq, bk, rows, keys, shared_keys),
-        out_specs=[pl.BlockSpec((1, bk, w), keys) for w in (d, dv, dr) if w],
-        out_shape=[jax.ShapeDtypeStruct((bh, tk, w), k.dtype)
-                   for w in (d, dv, dr) if w],
-        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32)
-                        for w in (d, dv, dr) if w],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=params,
         interpret=interpret,
     )(*operands)
+    if one_kernel:
+        dkv, dq = dkv[:len(key_widths)], dkv[len(key_widths):]
 
     grads = (_heads_back(dq[0], b), _heads_back(dkv[0], b),
              _heads_back(dkv[1], b))
@@ -812,7 +910,8 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
                     q_shared=None, k_shared=None):
     """Pallas flash attention (TPU kernel; interpreter on CPU).
 
-    Training runs the Pallas BACKWARD kernels (dq pass + dk/dv pass,
+    Training runs the Pallas BACKWARD kernel (dq, dk and dv in one pass
+    where a head's dq fits in VMEM, else a dq pass + a dk/dv pass;
     probabilities recomputed per tile from the saved logsumexp): O(T)
     memory end to end, no XLA recompute graph. The residuals are q, k, v,
     the output and the logsumexp ([B*H, Tq] float32). Under
@@ -826,14 +925,14 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
     the names do nothing; the primal (serving, any undifferentiated call)
     has none.
 
-    Each of the three kernels sizes its own tiles from the shapes and
-    the dtype (``_flash_tiles``). ``block_q`` / ``block_k`` set the tiles
-    of all three instead: for tests, whose interpreter wants small ones.
+    Each kernel sizes its own tiles from the shapes and the dtype
+    (``_flash_tiles``). ``block_q`` / ``block_k`` set the tiles of all of
+    them instead: for tests, whose interpreter wants small ones.
 
     ``window`` (causal self-attention only) is a sliding window of that
-    many keys a query, its own position among them. All three kernels
-    then walk a shorter grid that holds only the tiles with a visible
-    pair (``_flash_inner``) and build a mask only on the tiles the
+    many keys a query, its own position among them. Every kernel
+    then walks a shorter grid that holds only the tiles with a visible
+    pair (``_flash_inner``) and builds a mask only on the tiles the
     diagonal or the window's edge crosses.
 
     ``v`` may be narrower or wider than q and k ([B, Tk, H, Dv]): the
@@ -945,11 +1044,15 @@ def attention(q, k, v, *, causal: bool = True, impl: str = "auto",
     # Up to 1024 keys the scores are materialised by XLA, and for causal
     # self-attention only the blocks at or under the diagonal (PERF.md
     # section 6, PR 25: the v5e runs of both benchmark cells that settled
-    # this branch). The Pallas kernel starts above 1024 because there it
-    # still loses to these blocks, with the one 1024-row tile its rule
-    # gives it: forward + backward 3.97 against 2.33 ms at
-    # [8, 1024, 25, 64], 2.66 against 2.50 at [4, 1024, 32, 128] (v5e,
-    # PERF.md section 6, PR 28; ROADMAP A1).
+    # this branch). The Pallas kernel starts above 1024 because at 1024,
+    # with the one 1024-row tile its rule gives it, it loses to these
+    # blocks at GPT-2 XL's shape and only draws level at Mistral's:
+    # forward + the one-kernel backward 4.26-4.32 against 3.24 ms at
+    # [8, 1024, 25, 64] and 3.20-3.26 against 3.43 at [4, 1024, 32, 128]
+    # (v5e, a jitted gradient with the layout moves in it, PERF.md
+    # section 7, PR 38; with two backward kernels 4.93 and 3.55 there,
+    # PR 28's 3.97 / 2.33 and 2.66 / 2.50 in its own harness). The
+    # threshold is ROADMAP A1's to move.
     rows = _causal_block_rows(tq) if causal and tq == tk else 0
     if rows:
         return causal_blocked_attention(q, k, v, block_q=rows, window=window)

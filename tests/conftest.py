@@ -230,3 +230,18 @@ def ray_start_shared():
     ray_tpu.init(num_cpus=4, object_store_memory=64 * 1024 * 1024, ignore_reinit_error=True)
     yield
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def two_backward_kernels(monkeypatch):
+    """Inside: no head's dq fits in VMEM by ``ops/attention.py``'s tile
+    rule, so the Pallas attention backward takes its two-kernel form ("dq"
+    and "dkv") whatever the shapes: what the one-kernel form (PR 38) is
+    held against."""
+    import importlib
+
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    tiles = attention._flash_tiles
+    monkeypatch.setattr(
+        attention, "_flash_tiles", lambda kernel, *a, **kw: (
+            None if kernel == "bwd" else tiles(kernel, *a, **kw)))

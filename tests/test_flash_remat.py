@@ -1,13 +1,14 @@
 """The layer's checkpoint keeps the Pallas attention kernel's output and
 logsumexp (PR 32: ``ops.attention.FLASH_OUT_NAME`` / ``FLASH_LSE_NAME``,
 saved by ``models/transformer.py`` ``layer_of``'s policy), so the
-backward's recompute launches no forward kernel: three ``pallas_call``s a
-layer (forward, dq, dk / dv) where ``jax.checkpoint(layer)`` with no
-policy has four; the same gradients, bit for bit; and a layer whose
-attention never took the kernel saves what it saved before. A file of its
-own beside ``tests/test_smallthinker_kernels.py`` so that the two run on
-two workers. CPU only: the kernels in the interpreter, float32, at the one
-256-row tile their rule gives a row of 256.
+backward's recompute launches no forward kernel: two ``pallas_call``s a
+layer (forward, and since PR 38 the ONE backward kernel) where
+``jax.checkpoint(layer)`` with no policy has three; the same gradients,
+bit for bit; and a layer whose attention never took the kernel saves what
+it saved before. A file of its own beside
+``tests/test_smallthinker_kernels.py`` so that the two run on two workers.
+CPU only: the kernels in the interpreter, float32, at the one 256-row tile
+their rule gives a row of 256.
 
 "Unpoliced" is the program's own ``forward`` with ``jax.checkpoint``'s
 ``policy`` argument dropped: ``jax.checkpoint(layer)``, what ``"full"``
@@ -98,11 +99,13 @@ def _kernels_in_grad(cfg) -> int:
 
 @pytest.mark.parametrize("remat_policy", ["full", "dots"])
 @pytest.mark.parametrize("kind", sorted(KERNEL_CONFIGS))
-def test_backward_runs_three_kernels_a_layer(kind, remat_policy, request):
+def test_backward_runs_two_kernels_a_layer(kind, remat_policy, request):
+    """Three until PR 38 (forward, dq, dk / dv), two since: the forward
+    and the one backward kernel; unpoliced, the forward once more."""
     cfg = KERNEL_CONFIGS[kind](remat_policy=remat_policy)
-    assert _kernels_in_grad(cfg) == 3 * LAYERS_IN_BODY[kind]
+    assert _kernels_in_grad(cfg) == 2 * LAYERS_IN_BODY[kind]
     request.getfixturevalue("unpoliced")
-    assert _kernels_in_grad(cfg) == 4 * LAYERS_IN_BODY[kind]
+    assert _kernels_in_grad(cfg) == 3 * LAYERS_IN_BODY[kind]
 
 
 @pytest.mark.parametrize("remat_policy", ["full", "dots"])
@@ -130,7 +133,7 @@ def test_a_renamed_residual_brings_the_recompute_back(kind, monkeypatch):
     in the recompute, and nothing else says so."""
     cfg = KERNEL_CONFIGS[kind]()
     monkeypatch.setattr(transformer, "FLASH_LSE_NAME", "another_name")
-    assert _kernels_in_grad(cfg) == 4 * LAYERS_IN_BODY[kind]
+    assert _kernels_in_grad(cfg) == 3 * LAYERS_IN_BODY[kind]
 
 
 def test_the_names_are_exported_and_the_primal_has_none():

@@ -136,16 +136,16 @@ def test_the_rotary_key_is_not_repeated_to_the_heads():
     calls = _kernel_calls(jax.grad(lambda *a: (attention.flash_attention(
         *a[:3], True, 128, 128, None, *a[3:]) * g).sum(), (0, 1, 2, 3, 4)),
         *ops)
-    assert len(calls) == 3
+    assert len(calls) == 2          # forward; the one backward kernel
     for call in calls:
         assert (B, 512, ROPE) in [v.aval.shape for v in call.invars]
         assert all(v.aval.shape[0] in (B, B * H) for v in call.invars)
-    dq, dkv = calls[1], calls[2]
-    assert [v.aval.shape[-1] for v in dq.outvars] == [NOPE, ROPE]
     # every head writes its own gradient of the rotary key; the sum over
-    # the heads is outside the kernel
-    assert [v.aval.shape for v in dkv.outvars] == [
-        (B * H, 512, NOPE), (B * H, 512, WIDE), (B * H, 512, ROPE)]
+    # the heads is outside the kernel. dk, dv, dk_shared, then dq and
+    # dq_shared, which the same kernel accumulates since PR 38
+    assert [v.aval.shape for v in calls[1].outvars] == [
+        (B * H, 512, NOPE), (B * H, 512, WIDE), (B * H, 512, ROPE),
+        (B * H, 512, NOPE), (B * H, 512, ROPE)]
 
 
 # -- an equal-width caller gets the kernels it got ----------------------------------
@@ -163,14 +163,16 @@ def _parent_vmem_bytes(kernel, block_q, block_k, d, dtype):
     elif kernel == "dq":
         blocks = 3 * q_rows + 2 * k_rows + 2 * column
         scratch = tile(block_q, d, 4)
-    else:
-        blocks = 2 * q_rows + 4 * k_rows + 2 * column
+    else:       # "bwd" (PR 38): "dkv" and dq's output block; the head's
+        # float32 dq is counted by its rows, ``tq``, which these calls leave 0
+        blocks = (3 if kernel == "bwd" else 2) * q_rows + 4 * k_rows \
+            + 2 * column
         scratch = 2 * tile(block_k, d, 4)
     scores = attention._FLASH_SCORE_TILES[kernel] * tile(block_q, block_k, 4)
     return 2 * blocks + scratch + scores
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "bwd"])
 @pytest.mark.parametrize("d", [64, 128, 512])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_equal_widths_size_their_tiles_as_the_parent_did(kernel, d, dtype):
@@ -198,17 +200,21 @@ def test_equal_widths_launch_the_three_operand_kernels():
     v = k + 1.0
     calls = _kernel_calls(jax.grad(lambda *a: attention.flash_attention(
         *a, True, 128, 128, None).sum(), (0, 1, 2)), q, k, v)
-    assert [len(c.invars) for c in calls] == [3, 6, 6]
-    assert [len(c.outvars) for c in calls] == [2, 1, 2]
+    assert [len(c.invars) for c in calls] == [3, 6]
+    assert [len(c.outvars) for c in calls] == [2, 3]
     want = attention.dot_product_attention(q, k, v, causal=True)
     got = attention.flash_attention(q, k, v, True, 128, 128, None)
     assert float(jnp.abs(got - want).max()) < 5e-6
 
 
 def test_the_cells_tiles_at_8192():
-    """The benchmark cell's shape, bfloat16: 1024-row tiles in all three
-    kernels, for the shared-key form (128 + 64 against 128) and for the
-    192-wide one; each within the rule's VMEM budget."""
+    """The benchmark cell's shape, bfloat16: 1024-row tiles in the
+    forward and in the two-kernel backward, for the shared-key form (128
+    + 64 against 128) and for the 192-wide one; each within the rule's
+    VMEM budget. The one backward kernel keeps the head's dq beside its
+    tiles (both parts: 8 MiB): 1024 x 1024 is 33.0 MiB by the rule's
+    arithmetic, so it steps to (512, 1024), which ran as fast on the v5e
+    (``_FLASH_VMEM_MOST``'s comment)."""
     for d, dv, dr in ((128, 128, 64), (192, 128, 0)):
         for kernel in ("fwd", "dq", "dkv"):
             assert attention._flash_tiles(kernel, 8192, 8192, d, jnp.bfloat16,
@@ -216,6 +222,12 @@ def test_the_cells_tiles_at_8192():
             assert attention._flash_vmem_bytes(
                 kernel, 1024, 1024, d, jnp.bfloat16, dv, dr) \
                 <= attention._FLASH_VMEM_MOST
+        assert attention._flash_tiles("bwd", 8192, 8192, d, jnp.bfloat16, dv,
+                                      dr) == (512, 1024)
+        assert attention._flash_vmem_bytes(
+            "bwd", 512, 1024, d, jnp.bfloat16, dv, dr, 8192) \
+            <= attention._FLASH_VMEM_MOST < attention._flash_vmem_bytes(
+            "bwd", 1024, 1024, d, jnp.bfloat16, dv, dr, 8192)
 
 
 # -- the kept output and logsumexp under the layer's checkpoint ----------------------
@@ -262,20 +274,21 @@ def unpoliced(monkeypatch):
 
 
 @pytest.mark.parametrize("remat_policy", ["full", "dots"])
-def test_latent_layers_backward_runs_three_kernels_a_layer(remat_policy,
-                                                           request):
+def test_latent_layers_backward_runs_two_kernels_a_layer(remat_policy,
+                                                         request):
     """Two scan bodies in the jaxpr (the dense stack's and the expert
     layers'; ``unroll`` is the scan's parameter, its body is there once),
-    one layer each: three ``pallas_call``s a body where a checkpoint with
-    no name policy has four; the same gradients, bit for bit."""
+    one layer each: two ``pallas_call``s a body (three until PR 38 made
+    the backward one kernel) where a checkpoint with no name policy has
+    three; the same gradients, bit for bit."""
     cfg = _mla(remat_policy=remat_policy)
     params, rows = _inputs(cfg)
     grad = jax.value_and_grad(_loss(cfg))
-    assert len(_kernel_calls(grad, params, rows)) == 3 * 2
+    assert len(_kernel_calls(grad, params, rows)) == 2 * 2
     loss, grads = grad(params, rows)
     request.getfixturevalue("unpoliced")
     grad = jax.value_and_grad(_loss(cfg))       # traced anew, unpoliced
-    assert len(_kernel_calls(grad, params, rows)) == 4 * 2
+    assert len(_kernel_calls(grad, params, rows)) == 3 * 2
     want_loss, want = grad(params, rows)
     assert float(loss) == float(want_loss)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),

@@ -260,10 +260,10 @@ def test_causal_blocks_leave_no_whole_score_tensor_and_stay_in_attn():
 def test_a_step_at_1024_has_no_kernel_and_one_at_2048_has(monkeypatch):
     """The dense cells' bypass: on a TPU ``attention(impl="auto")`` at
     T = 1024 takes the materialised blocks, so the train step lowered
-    for a TPU has no ``tpu_custom_call``; at T = 2048 it has three (the
-    kernel's forward, dq, dk / dv: the layer's checkpoint keeps the
-    kernel's output and logsumexp, so its recompute launches no fourth;
-    ``tests/test_flash_remat.py``)."""
+    for a TPU has no ``tpu_custom_call``; at T = 2048 it has two (the
+    kernel's forward and, since PR 38, its one backward kernel: the
+    layer's checkpoint keeps the kernel's output and logsumexp, so its
+    recompute launches no third; ``tests/test_flash_remat.py``)."""
     import types
 
     monkeypatch.setattr(
@@ -276,7 +276,7 @@ def test_a_step_at_1024_has_no_kernel_and_one_at_2048_has(monkeypatch):
             "tpu_custom_call")
 
     assert kernels(1024) == 0
-    assert kernels(2048) == 3
+    assert kernels(2048) == 2
 
 
 def test_the_reader_knows_exactly_the_programs_scopes():

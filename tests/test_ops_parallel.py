@@ -104,20 +104,25 @@ def test_flash_backward_kernels_multiblock(causal):
 _FLASH_TOL = {jnp.float32: 2e-4, jnp.bfloat16: 2.0 ** -6}
 
 
+@pytest.mark.parametrize("backward", ["bwd", "dq+dkv"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("d", [64, 128])
-def test_flash_kernels_at_the_rules_own_tiles(d, dtype):
+def test_flash_kernels_at_the_rules_own_tiles(d, dtype, backward, request):
     """Forward, dq, dk and dv at the tiles each kernel picks for itself
     (no ``block_q`` / ``block_k``), T = 2048, causal, against
-    ``dot_product_attention`` in the same dtype. The cotangent is the
+    ``dot_product_attention`` in the same dtype; the backward as the one
+    kernel the shapes give it (PR 38) and as the two it takes where a
+    head's dq does not fit. The cotangent is the
     multiblock test's loss's, taken ONCE (at the float32 reference's
     output) and handed to both sides: that loss's ``cos(out.sum(-1))``
     turns one bfloat16 rounding of ``out`` into percents of cotangent,
     which is the loss's doing and no kernel's."""
     t = 2048
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "dq", "dkv", "bwd"):
         bq, bk = attention_mod._flash_tiles(kernel, t, t, d, dtype)
         assert t // bq > 1 or t // bk > 1, (kernel, bq, bk)
+    if backward == "dq+dkv":
+        request.getfixturevalue("two_backward_kernels")
     q, k, v = _qkv(b=1, t=t, h=1, d=d, seed=3)
     w = jnp.asarray(np.random.RandomState(7).randn(d), jnp.float32)
     g = jax.grad(lambda o: (jnp.tanh(o @ w) * jnp.cos(o.sum(-1))).sum())(
@@ -165,12 +170,23 @@ def test_flash_tile_rule_gives_legal_tiles(tq, tk, d, dtype):
         need = attention_mod._flash_vmem_bytes(kernel, bq, bk, d, dtype)
         assert need <= params.vmem_limit_bytes \
             <= attention_mod._FLASH_VMEM_MOST, (kernel, bq, bk, need)
+    # the one backward kernel: tiles that hold the head's dq as well, or
+    # none where that leaves no room for the smallest ones
+    least = attention_mod._flash_vmem_bytes("bwd", 128, 128, d, dtype, tq=tq)
+    tiles = attention_mod._flash_tiles("bwd", tq, tk, d, dtype)
+    assert (tiles is None) == (least > attention_mod._FLASH_VMEM_MOST)
+    if tiles:
+        bq, bk = tiles
+        assert bq % 128 == 0 and bk % 128 == 0 and tq % bq == 0 == tk % bk
+        assert attention_mod._flash_launch("bwd", q, k, None, None)[:2] == tiles
+        assert least <= attention_mod._flash_vmem_bytes(
+            "bwd", bq, bk, d, dtype, tq=tq) <= attention_mod._FLASH_VMEM_MOST
 
 
 @pytest.mark.parametrize("tq,tk", [(2000, 2000), (1100, 2048), (2048, 1100),
                                    (64, 2048)])
 def test_flash_tile_rule_has_no_tile_for_ragged_lengths(tq, tk):
-    for kernel in ("fwd", "dq", "dkv"):
+    for kernel in ("fwd", "dq", "dkv", "bwd"):
         assert attention_mod._flash_tiles(
             kernel, tq, tk, 128, jnp.bfloat16) is None
     q = jnp.zeros((1, tq, 1, 128))

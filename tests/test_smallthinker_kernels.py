@@ -109,12 +109,14 @@ def _brute_tiles(t, bq, bk, window):
 @pytest.mark.parametrize("t,bq,bk,window", [
     (1024, 128, 128, 300), (1024, 256, 128, 129), (1024, 128, 256, 256),
     (2048, 256, 256, 512), (2048, 512, 256, 2047), (1024, 128, 128, None)])
-def test_the_tiles_each_kernel_visits(t, bq, bk, window):
+def test_the_tiles_each_kernel_visits(t, bq, bk, window, request):
     """``_tile_visits`` (the kernels' own pieces) against a count made
     from the mask itself: the tiles computed are exactly those with a
     visible pair, those that build a mask exactly the ones not wholly
-    visible, and the grids the three ``pallas_call``s are launched with
-    are as long as the count says."""
+    visible, and the grids the ``pallas_call``s are launched with are as
+    long as the count says: the forward's and the one backward kernel's,
+    which walks dk / dv's grid (PR 38), and the three of the two-kernel
+    form."""
     computed, crossed, some = _brute_tiles(t, bq, bk, window)
     visits = _tile_visits(t, bq, bk, window)
     assert visits["computed"] == computed
@@ -125,13 +127,18 @@ def test_the_tiles_each_kernel_visits(t, bq, bk, window):
                                    n_k * int(some.sum(0).max()))
         assert visits["steps"][0] < n_q * n_k or window > t - bq
     q, k, v, g = _qkvg(t, h=1, d=8)
-    jaxpr = jax.make_jaxpr(jax.grad(lambda *a: (attention.flash_attention(
-        *a, True, bq, bk, window) * g).sum(), (0, 1, 2)))(q, k, v)
-    grids = [e.params["grid_mapping"].grid for e in _eqns(jaxpr.jaxpr)
-             if e.primitive.name == "pallas_call"]
-    fwd, dq, dkv = grids
-    assert fwd == dq and fwd[1] * fwd[2] == visits["steps"][0]
-    assert dkv[1] * dkv[2] == visits["steps"][1]
+
+    def grids():
+        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: (attention.flash_attention(
+            *a, True, bq, bk, window) * g).sum(), (0, 1, 2)))(q, k, v)
+        return [e.params["grid_mapping"].grid for e in _eqns(jaxpr.jaxpr)
+                if e.primitive.name == "pallas_call"]
+
+    fwd, bwd = grids()
+    assert fwd[1] * fwd[2] == visits["steps"][0]
+    assert bwd[1] * bwd[2] == visits["steps"][1]
+    request.getfixturevalue("two_backward_kernels")
+    assert grids() == [fwd, fwd, bwd]
 
 
 def test_the_cells_tiles_at_16384():
@@ -139,8 +146,9 @@ def test_the_cells_tiles_at_16384():
     T = 16,384, width 128, bfloat16), window 4,096: 70 of the 136 causal
     tiles are computed (66 skipped), 28 of them masked; the grid is 80
     steps a head where the plain causal kernel's is 256."""
-    assert attention._flash_tiles("fwd", 16384, 16384, 128, jnp.bfloat16) == (
-        1024, 1024)
+    for kernel in ("fwd", "dq", "dkv", "bwd"):
+        assert attention._flash_tiles(kernel, 16384, 16384, 128,
+                                      jnp.bfloat16) == (1024, 1024)
     assert _tile_visits(16384, 1024, 1024, 4096) == {
         "steps": (80, 80), "computed": 70, "masked": 28}
     assert _tile_visits(16384, 1024, 1024, None) == {
