@@ -12,6 +12,10 @@ at the full width of GPT-2 124M (``models.gpt2_small()``: 12 layers x
           gradient at [4, 2048, 12, 64] bf16 against
           dot_product_attention, the lowered text carrying
           tpu_custom_call; then one make_train_step at T=2048.
+  delta_rule  in a chip-holding task: the chunked gated delta rule
+          (ops/linear_attention.py) at [1, 16384, 32, 128] bf16, forward
+          and gradient timed, then both against the token-by-token
+          recurrence on a 2,048-token prefix.
   serve   serve.run(build_openai_app(...)), one one-chip replica per
           chip, concurrent POST /v1/completions through the proxy port,
           one /v1/chat/completions; each replica reports what it ran on.
@@ -50,6 +54,8 @@ FULL = dict(
     seq=1024, rows_per_chip=8, warmup=2, steps=5,
     kernel_shape=(4, 2048, 12, 64), kernel_batch=4,
     kernel_timeout_s=420.0,
+    # the gated delta rule at train-kimilinear-ep32share's shape
+    delta_shape=(1, 16384, 32, 128), delta_prefix=2048,
     serve_model="gpt2_small", serve_slots=8, serve_seq=1024,
     n_requests=8, prompt_tokens=100, max_tokens=32,
     request_timeout_s=300.0,
@@ -60,6 +66,7 @@ TINY = dict(
     seq=32, rows_per_chip=2, warmup=1, steps=2,
     kernel_shape=(2, 64, 2, 16), kernel_batch=2,
     kernel_timeout_s=180.0,
+    delta_shape=(1, 192, 2, 16), delta_prefix=128,
     serve_model="tiny", serve_slots=4, serve_seq=64,
     n_requests=4, prompt_tokens=20, max_tokens=4,
     request_timeout_s=120.0,
@@ -385,6 +392,107 @@ def kernel_phase(size: dict, platform: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# the gated delta rule (ray_tpu/ops/linear_attention.py)
+
+
+def delta_rule_body(size: dict) -> dict:
+    """Runs in a one-chip worker: the chunked gated delta rule at a KDA
+    layer's shape, forward and gradient timed; then, on a prefix of the
+    row, forward and all five gradients against the token-by-token
+    recurrence (``chipbench/reference/kimi_linear.py`` ``delta_rule``,
+    float32, ``highest``). Decays as the model's init gives them: up to
+    1.6 a token and channel, 100 over a chunk."""
+    import jax
+    import jax.numpy as jnp
+
+    from chipbench.reference.kimi_linear import delta_rule
+    from ray_tpu.ops import linear_attention as la
+
+    dev = jax.devices()[0]
+    b, t, h, d = size["delta_shape"]
+    out = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "shape": [b, t, h, d], "prefix": size["delta_prefix"],
+           "chunk": la.CHUNK}
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 6)
+    normal = lambda key: jax.random.normal(key, (b, t, h, d), jnp.float32)
+    q, k = (la.l2_norm(normal(key)).astype(jnp.bfloat16) for key in ks[:2])
+    v, w = (normal(key).astype(jnp.bfloat16) for key in ks[2:4])
+    # log-uniform steps in [0.001, 0.1] x a head's rate in [1, 16]
+    g = -jnp.exp(jax.random.uniform(ks[4], (b, t, h, d), jnp.float32,
+                                    math.log(1e-3), math.log(1.6)))
+    beta = jax.random.uniform(ks[5], (b, t, h), jnp.float32)
+    operands = (q, k, v, g, beta)
+
+    def loss(fn, weight):
+        return lambda *a: (fn(*a).astype(jnp.float32)
+                           * weight.astype(jnp.float32)).sum()
+
+    def timed(fn, *args):
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        compile_s = round(time.perf_counter() - t0, 2)
+        jax.block_until_ready(compiled(*args))
+        t0 = time.perf_counter()
+        result = jax.block_until_ready(compiled(*args))
+        return result, compile_s, round(time.perf_counter() - t0, 5)
+
+    o, out["fwd_compile_s"], out["fwd_run_s"] = timed(
+        la.gated_delta_rule, *operands)
+    out["finite"] = bool(jnp.isfinite(o.astype(jnp.float32)).all())
+    _, out["grad_compile_s"], out["grad_run_s"] = timed(
+        jax.grad(loss(la.gated_delta_rule, w), argnums=(0, 1, 2, 3, 4)),
+        *operands)
+    out["log_decay_min"] = float(la.log_decay_min(g))
+
+    # A prefix is a whole problem: the rule is causal and starts at zero.
+    n = size["delta_prefix"]
+    short = tuple(a[:, :n] for a in operands)
+    exact = tuple(a.astype(jnp.float32) for a in short)
+    grad = lambda fn: jax.jit(jax.value_and_grad(
+        lambda *a: (loss(fn, w[:, :n])(*a), fn(*a)), argnums=(0, 1, 2, 3, 4),
+        has_aux=True))
+    (_, o_p), grads_p = grad(la.gated_delta_rule)(*short)
+    with jax.default_matmul_precision("highest"):
+        (_, o_r), grads_r = grad(delta_rule)(*exact)
+
+    def err(a, r):
+        a, r = a.astype(jnp.float32), r.astype(jnp.float32)
+        return {"max_abs_err": float(jnp.abs(a - r).max()),
+                "mean_abs_err": float(jnp.abs(a - r).mean()),
+                "ref_abs_max": float(jnp.abs(r).max()),
+                "ref_abs_mean": float(jnp.abs(r).mean())}
+
+    out["errors"] = {name: err(a, r) for name, a, r in zip(
+        ("o", "dq", "dk", "dv", "dg", "dbeta"), (o_p, *grads_p),
+        (o_r, *grads_r))}
+    return out
+
+
+# bfloat16 operands through the chunk's products (the intra-chunk
+# matrices, the inverse's products, the state's) against float32: the
+# mean error is held to this share of the reference's mean magnitude.
+DELTA_RULE_TOLERANCE = 0.03
+
+
+def delta_rule_phase(size: dict, platform: str) -> dict:
+    import ray_tpu
+
+    r = ray_tpu.get(ray_tpu.remote(num_tpus=1)(delta_rule_body).remote(size),
+                    timeout=size["kernel_timeout_s"])
+    show("delta_rule", r)
+    require(r["platform"] == platform,
+            f"delta-rule worker ran on {r['platform']!r}, expected "
+            f"{platform!r}")
+    require(r["finite"], "the delta rule's output has a non-finite value")
+    for name, e in r["errors"].items():
+        require(e["mean_abs_err"] <= DELTA_RULE_TOLERANCE * e["ref_abs_mean"],
+                f"delta rule {name}: mean |err| {e['mean_abs_err']} over "
+                f"{DELTA_RULE_TOLERANCE} x the recurrence's mean "
+                f"{e['ref_abs_mean']}")
+    return r
+
+
+# ---------------------------------------------------------------------------
 # serve
 
 
@@ -604,6 +712,9 @@ def smoke(size: dict, platform: str = "tpu", *, watchdog: bool = False,
         with phase("kernel", walls):
             summary["chips_free_wait_s"] = [wait_chips_free(chips)]
             summary["kernel"] = kernel_phase(size, platform)
+        with phase("delta_rule", walls):
+            summary["chips_free_wait_s"].append(wait_chips_free(chips))
+            summary["delta_rule"] = delta_rule_phase(size, platform)
         with phase("serve", walls):
             summary["chips_free_wait_s"].append(wait_chips_free(chips))
             summary["serve"] = serve_phase(size, chips, platform)

@@ -92,15 +92,16 @@ class LLMEngine:
         self.model_config = config.resolve_model()
         self.tokenizer = load_tokenizer(config.tokenizer)
         c = self.model_config
+        # layer_mixers (KDA's recurrent state) / kv_latent / layer_pattern /
+        # sliding_window / experts_held: the slot engine's decode runs one
+        # kind of layer and would run these wrongly in silence. First, so
+        # that a model is refused by what it is and not only for its experts.
+        tfm.refuse_decode(c)
         if c.n_experts > 0:
             raise NotImplementedError(
                 "MoE decode is not wired into the slot engine yet; "
                 "train with MoE (models.transformer + Train) and serve dense."
             )
-        # layer_pattern / sliding_window / experts_held: the slot engine's
-        # decode runs one kind of layer and would run these wrongly in
-        # silence.
-        tfm.refuse_decode(c)
         # len(tokenizer) counts added special tokens on HF tokenizers;
         # vocab_size alone excludes them and would let special-token ids
         # silently clamp in the embedding gather.

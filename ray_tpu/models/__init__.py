@@ -20,6 +20,7 @@ from ray_tpu.models.transformer import (
     init_params,
     init_train_state,
     kanana_2_30b_a3b,
+    kimi_linear_48b_a3b,
     llama2_7b,
     llama3_8b,
     lm_loss,
@@ -54,6 +55,7 @@ __all__ = [
     "init_params",
     "init_train_state",
     "kanana_2_30b_a3b",
+    "kimi_linear_48b_a3b",
     "llama2_7b",
     "llama3_8b",
     "lm_loss",

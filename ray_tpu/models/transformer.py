@@ -44,10 +44,21 @@ run ahead of the scan over ``layers``), a **shared expert** beside the
 routed ones (``d_ff_shared``), and a **sigmoid router** whose learned-by-
 rule bias only the choice of experts sees (``router_score``,
 ``router_bias``; the step moves the bias itself, ``make_train_step``).
+And a model whose layers do not all mix tokens the same way (Kimi
+Linear): **a mixer a layer** (``layer_mixers``: ``"kda"``, gated
+delta-rule linear attention with a state carried along the sequence,
+``ops/linear_attention.py``, or ``"attn"``, the model's latent
+attention, here without any rotation: ``latent_rope``). Such a model
+keeps, beside the leaves every layer has (the norms, ``attn/wo``, the
+router, the FFN: stacked over a stack's layers as ever), ONE STACK A KIND
+OF MIXER for the leaves only that kind has (``kda/*``, ``mla/*``:
+stacked over the layers of that kind).
 With a period of P > 1 the scan runs over
 WHOLE PERIODS and unrolls a period's P layers in its body, so each
 position's kind is static: a windowed layer compiles to the kernel that
-skips tiles, never to a ``cond`` over both kinds.
+skips tiles, never to a ``cond`` over both kinds. Layers left over after
+the last whole period (Kimi Linear's 26 expert layers are six periods of
+K K A K and then K A) run unrolled behind the scan.
 
 Every part runs under a ``jax.named_scope`` from ``SCOPES``, so each
 device instruction of a profiler trace says which part it belongs to
@@ -69,7 +80,7 @@ import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import moe
+from ray_tpu.ops import linear_attention, moe
 from ray_tpu.ops.attention import (FLASH_LSE_NAME, FLASH_OUT_NAME, attention,
                                    dot_product_attention)
 from ray_tpu.ops.layers import (
@@ -103,10 +114,19 @@ from ray_tpu.parallel.sharding import constrain
 # apply).
 SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
           "final_norm", "head_loss", "grad_accum", "optimizer")
-# Inside ``attn``, for a model with a ``layer_pattern`` or with latent
-# attention (whose layers are all full causal ones): which kind of layer
-# the instruction belongs to.
-ATTN_SCOPES = ("attn_full", "attn_window")
+# Inside ``attn``, for a model with a ``layer_pattern``, with latent
+# attention (whose layers are all full causal ones) or with
+# ``layer_mixers``: which kind of layer the instruction belongs to.
+# ``attn_linear`` is everything of a KDA layer's mixer: inside it
+# ``attn_qkv`` (the q / k / v projections), ``ops.linear_attention.SCOPES``
+# (``kda_conv``, ``kda_gate``), ``attn_core`` (the chunked delta rule and
+# nothing else) and ``attn_out``.
+ATTN_SCOPES = ("attn_full", "attn_window", "attn_linear")
+# The token mixers ``layer_mixers`` may name.
+MIXERS = ("attn", "kda")
+# A stack's subtrees that hold ONE kind of mixer's own leaves, stacked
+# over the layers of that kind (``layer_mixers`` models only).
+MIXER_STACKS = {"kda": "kda", "attn": "mla"}
 # Inside ``attn`` (and its ``attn_full``), latent attention only: what the
 # latent form adds outside the kernels (down-projection, the latent's
 # norm, up-projection, RoPE on the rotary parts).
@@ -125,8 +145,8 @@ ATTN_PART_SCOPES = ("attn_qkv", "attn_pos", "attn_gqa", "attn_core",
 # Which tree's scopes an executable carries. jax's compile-cache key leaves
 # metadata out, so a step loaded from the cache would keep the scope names
 # of whatever tree compiled it. ``SCOPES_ID`` names the bytes of every
-# file that opens a scope of the step (this one, ``ops/moe.py`` and
-# ``ops/attention.py``) and
+# file that opens a scope of the step (this one, ``ops/moe.py``,
+# ``ops/attention.py`` and ``ops/linear_attention.py``) and
 # rides on one instruction of the train step (the step counter's add) as a
 # frontend attribute, which the key does take: a tree in which one of them
 # differs compiles its own step and never loads another's, so the names in
@@ -134,7 +154,8 @@ ATTN_PART_SCOPES = ("attn_qkv", "attn_pos", "attn_gqa", "attn_core",
 # (``tests/test_model_scopes.py`` holds jax to it on a real cache).
 # (``ray_tpu.ops.attention`` the attribute is the function, not the module.)
 SCOPE_FILES = (__file__, moe.__file__,
-               sys.modules[attention.__module__].__file__)
+               sys.modules[attention.__module__].__file__,
+               linear_attention.__file__)
 
 
 def _scopes_id(files=SCOPE_FILES) -> str:
@@ -259,6 +280,19 @@ class TransformerConfig:
     router_bias: bool = False
     router_bias_rate: float = 0.0
     expert_gate_scale: float = 1.0   # x the gates, after renormalising
+    # -- a model whose layers mix tokens in more than one way (llama arch) --
+    # The token mixer of every one of the ``n_layers``, by name: "kda"
+    # (gated delta-rule linear attention, ``ops/linear_attention.py``) or
+    # "attn" (the model's latent attention). Empty: attention everywhere.
+    # The scan's period is read off the list (``_period``).
+    layer_mixers: tuple[str, ...] = ()
+    kda_heads: int = 0               # heads of a KDA layer
+    kda_head_dim: int = 0            # a KDA head's key AND value width,
+    #                                  and the rank of its two low-rank maps
+    kda_conv: int = 4                # positions the short convolution reads
+    # Latent attention rotates its ``d_head_rope``-wide parts (RoPE); False:
+    # no positional encoding at all, the part is a plain shared key.
+    latent_rope: bool = True
 
     def __post_init__(self):
         # A config file's JSON gives lists: keep the config hashable.
@@ -266,6 +300,7 @@ class TransformerConfig:
             object.__setattr__(self, "experts_held", tuple(self.experts_held))
         object.__setattr__(self, "layer_pattern", tuple(
             (bool(w), bool(r)) for w, r in self.layer_pattern))
+        object.__setattr__(self, "layer_mixers", tuple(self.layer_mixers))
 
     @property
     def kv_heads(self) -> int:
@@ -296,8 +331,14 @@ class TransformerConfig:
         held = self.held_range
         return self.n_experts if held is None else held[1] - held[0]
 
-    def layer_kind(self, i: int) -> tuple[bool, bool] | None:
-        """(windowed, rope) of layer ``i``; None with no pattern."""
+    def layer_kind(self, i: int) -> tuple[bool, bool] | str | None:
+        """The kind of layer ``i`` of the ``n_layers``: "kda" for a KDA
+        layer, else (windowed, rope) of its attention (latent attention:
+        full, rotated or not); None with no pattern: the arch's own."""
+        if self.layer_mixers and self.layer_mixers[i] == "kda":
+            return "kda"
+        if self.kv_latent is not None:
+            return (False, self.latent_rope)
         if not self.layer_pattern:
             return None
         return self.layer_pattern[i % len(self.layer_pattern)]
@@ -467,6 +508,40 @@ def kanana_2_30b_a3b(**kw) -> TransformerConfig:
     )
 
 
+def kimi_linear_48b_a3b(**kw) -> TransformerConfig:
+    """Kimi-Linear-48B-A3B (moonshotai ``config.json``, ``model_type``
+    ``kimi_linear``; arXiv:2510.26692): 27 layers, the first dense (SwiGLU
+    9,216); layers 4, 8, ..., 24 and 27 latent attention WITHOUT any
+    rotation (32 heads of 128 + 64, the 64 ONE key part all heads share,
+    against values of 128 from a 512-wide latent), the other twenty KDA
+    (32 heads of 128, a short convolution over 4 positions); 256 SwiGLU
+    experts 1,024 wide, 8 a token by a sigmoid router whose bias only
+    the choice sees, gates renormalised and scaled by 2.446, one shared
+    expert; no router loss term. ``n_layers=n`` takes the published
+    layers 1 to n. The bias's rate and rule are DeepSeek-V3's
+    (arXiv:2412.19437); the low-rank maps' rank (the head width), the
+    output gate and the gates' init are the paper's and its public
+    implementation's (``fla`` ``KimiDeltaAttention``)."""
+    full = (4, 8, 12, 16, 20, 24, 27)
+    mixers = tuple("attn" if i in full else "kda"
+                   for i in range(1, kw.get("n_layers", 27) + 1))
+    return replace(
+        TransformerConfig(
+            vocab_size=163840, n_layers=27, d_model=2304, n_heads=32,
+            d_ff=1024, max_seq_len=1048576, arch="llama", norm_eps=1e-5,
+            kv_latent=512, d_head_nope=128, d_head_rope=64, d_head_v=128,
+            latent_rope=False, layer_mixers=mixers, kda_heads=32,
+            kda_head_dim=128, kda_conv=4, n_dense_layers=1, d_ff_dense=9216,
+            d_ff_shared=1024, n_experts=256, expert_top_k=8,
+            expert_capacity_factor=None, expert_norm_topk=True,
+            router_aux_weight=0.0, router_z_weight=0.0,
+            router_score="sigmoid", router_bias=True, router_bias_rate=1e-3,
+            expert_gate_scale=2.446,
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -556,6 +631,27 @@ def _check_config(c: TransformerConfig) -> None:
             raise ValueError(
                 "latent attention needs kv_latent, d_head_nope, d_head_v >= "
                 "1 and an even d_head_rope >= 2")
+    if not c.latent_rope and c.kv_latent is None:
+        raise ValueError("latent_rope=False describes latent attention "
+                         "(kv_latent)")
+    if c.layer_mixers:
+        wo = (c.n_heads, c.d_head_v)
+        for name, wrong in (
+                (f"names other than {MIXERS}",
+                 not set(c.layer_mixers) <= set(MIXERS)),
+                (f"{len(c.layer_mixers)} names for n_layers={c.n_layers}",
+                 len(c.layer_mixers) != c.n_layers),
+                ("a layer_pattern", bool(c.layer_pattern)),
+                ("attention that is not latent (kv_latent)",
+                 c.kv_latent is None),
+                ("kda_heads, kda_head_dim or kda_conv < 1",
+                 min(c.kda_heads, c.kda_head_dim, c.kda_conv) < 1),
+                # attn/wo is ONE stack over every layer, whatever its mixer
+                (f"KDA heads {(c.kda_heads, c.kda_head_dim)} that are not "
+                 f"attention's (n_heads, d_head_v) {wo}",
+                 (c.kda_heads, c.kda_head_dim) != wo)):
+            if wrong:
+                raise ValueError(f"layer_mixers does not run with {name}")
     if c.n_dense_layers:
         if (c.n_experts == 0 or c.layer_pattern or c.d_ff_dense is None
                 or not 0 < c.n_dense_layers < c.n_layers):
@@ -574,7 +670,13 @@ def init_params(rng, config: TransformerConfig):
     GPT-2 init: N(0, 0.02), residual-out projections scaled by
     1/sqrt(2*n_layers). A model with leading dense layers has two
     stacks: ``dense_layers`` [n_dense_layers, ...] and ``layers`` (the
-    expert layers, [n_layers - n_dense_layers, ...]).
+    expert layers, [n_layers - n_dense_layers, ...]). With
+    ``layer_mixers`` a stack's ``attn`` holds ``wo`` alone, every
+    layer's; ``kda`` and ``mla`` hold the KDA layers' and the latent-
+    attention layers' own leaves, stacked over the layers of that kind.
+    KDA's init is its public implementation's: the convolutions U(-1 /
+    sqrt(taps), 1 / sqrt(taps)), ``A_log`` = log U(1, 16), ``dt_bias`` the
+    inverse softplus of a log-uniform step in [0.001, 0.1].
     """
     c = config
     _check_config(c)
@@ -589,9 +691,14 @@ def init_params(rng, config: TransformerConfig):
     # What a DeepSeek-V3-shaped model adds draws from keys of its own, so
     # every other model's weights stay what the seed always gave.
     more = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
+    # ... and so does what a model with ``layer_mixers`` adds.
+    third = iter(jax.random.split(jax.random.fold_in(rng, 2), 32))
 
     def norm(key, *shape, s=std):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
+
+    def uniform(key, *shape, low, high):
+        return jax.random.uniform(key, shape, jnp.float32, low, high)
 
     def attn_stack(keys, n):
         if c.kv_latent is None:
@@ -612,6 +719,41 @@ def init_params(rng, config: TransformerConfig):
             "wo": norm(next(keys), n, H, c.d_head_v, D, s=res_std),
         }
 
+    def kda_stack(keys, n):
+        Hk, dk, taps = c.kda_heads, c.kda_head_dim, c.kda_conv
+        edge = 1.0 / math.sqrt(taps)
+        step = jnp.exp(uniform(next(keys), n, Hk, dk, low=math.log(1e-3),
+                               high=math.log(1e-1)))
+        return {
+            **{f"w{x}": norm(next(keys), n, D, Hk, dk) for x in "qkv"},
+            **{f"conv_{x}": uniform(next(keys), n, taps, Hk, dk, low=-edge,
+                                    high=edge).astype(pdt) for x in "qkv"},
+            "f_a": norm(next(keys), n, D, dk),
+            "f_b": norm(next(keys), n, dk, Hk, dk),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+            "A_log": jnp.log(uniform(next(keys), n, Hk, low=1.0,
+                                     high=16.0)).astype(pdt),
+            "w_beta": norm(next(keys), n, D, Hk),
+            "g_a": norm(next(keys), n, D, dk),
+            "g_b": norm(next(keys), n, dk, Hk, dk),
+            "o_norm": jnp.ones((n, dk), pdt),
+        }
+
+    def mixer_stacks(keys, first, n):
+        """The token mixers' leaves of the ``n`` layers from ``first``."""
+        if not c.layer_mixers:
+            return {"attn": attn_stack(keys, n)}
+        n_kda = c.layer_mixers[first:first + n].count("kda")
+        stacks = {}
+        if n - n_kda:
+            stacks["mla"] = attn_stack(keys, n - n_kda)
+            del stacks["mla"]["wo"]
+        if n_kda:
+            stacks["kda"] = kda_stack(third, n_kda)
+        stacks["attn"] = {"wo": norm(next(third), n, H, c.d_head_v, D,
+                                     s=res_std)}
+        return stacks
+
     def ffn_stack(keys, n, width):
         return {
             "w_gate": norm(next(keys), n, D, width),
@@ -621,7 +763,7 @@ def init_params(rng, config: TransformerConfig):
 
     params = {
         "embed": {"tokens": norm(next(keys), c.vocab_size, D)},
-        "layers": {"attn": attn_stack(keys, L)},
+        "layers": mixer_stacks(keys, c.n_dense_layers, L),
         "final_norm": {"w": jnp.ones((D,), pdt)},
     }
     if c.arch == "gpt2":
@@ -669,7 +811,7 @@ def init_params(rng, config: TransformerConfig):
         if c.n_dense_layers:
             n = c.n_dense_layers
             params["dense_layers"] = {
-                "attn": attn_stack(more, n),
+                **mixer_stacks(more, 0, n),
                 "ln1": {"w": jnp.ones((n, D), pdt)},
                 "ln2": {"w": jnp.ones((n, D), pdt)},
                 "mlp": ffn_stack(more, n, c.d_ff_dense),
@@ -704,10 +846,21 @@ def partition_specs(config: TransformerConfig):
         "w_up": P(None, None, AXIS_TENSOR),
         "w_down": P(None, AXIS_TENSOR, None),
     }
+    # KDA: whatever has a head axis shards by head; the low-rank maps'
+    # first halves and the head norm's one weight are every head's
+    by_head = P(None, None, AXIS_TENSOR, None)
+    kda = {
+        **{name: by_head for name in ("wq", "wk", "wv", "conv_q", "conv_k",
+                                      "conv_v", "f_b", "g_b")},
+        "dt_bias": P(None, AXIS_TENSOR, None),
+        "A_log": P(None, AXIS_TENSOR),
+        "w_beta": P(None, None, AXIS_TENSOR),
+    }
+    mixers = {"attn": attn, "mla": attn, "kda": kda}
     specs = {
         "embed": {"tokens": P(AXIS_TENSOR, None)},
-        "layers": {"attn": attn, "ln1": None, "ln2": None},
-        "dense_layers": {"attn": attn, "ln1": None, "ln2": None, "mlp": ffn},
+        "layers": {**mixers, "ln1": None, "ln2": None},
+        "dense_layers": {**mixers, "ln1": None, "ln2": None, "mlp": ffn},
         "final_norm": None,
     }
     if c.arch == "gpt2":
@@ -776,6 +929,53 @@ def _scan_layers(body, x, stack, steps: int):
     return jax.lax.scan(body, x, stack, unroll=_scan_unroll(stack, steps))
 
 
+def _period(kinds: tuple) -> int:
+    """The layers a scan step holds: the shortest period the kinds repeat
+    with at least twice (the layers left after the last whole period are
+    run behind the scan); kinds that repeat with none are one period."""
+    n = len(kinds)
+    for p in range(1, n // 2 + 1):
+        if all(kinds[i] == kinds[i % p] for i in range(n // p * p)):
+            return p
+    return n
+
+
+def _split_stack(c: TransformerConfig, stack, kinds: tuple, period: int):
+    """A stack's leaves as (whole periods [periods, layers of the leaf's
+    kind a period, ...], the layers left after them): a mixer's own
+    subtree (``MIXER_STACKS``) is stacked over the layers of that kind,
+    every other subtree over all."""
+    periods = len(kinds) // period
+    own = {}
+    if c.layer_mixers:
+        n_kda = sum(k == "kda" for k in kinds[:period])
+        own = {"kda": n_kda, "mla": period - n_kda}
+
+    def split(name, sub):
+        n = own.get(name, period)
+        return (jax.tree.map(lambda a: a[:periods * n].reshape(
+                    periods, n, *a.shape[1:]), sub),
+                jax.tree.map(lambda a: a[periods * n:], sub))
+
+    both = {name: split(name, sub) for name, sub in stack.items()}
+    return ({name: b[0] for name, b in both.items()},
+            {name: b[1] for name, b in both.items()})
+
+
+def _take_layer(c: TransformerConfig, stack, kinds: tuple, i: int):
+    """Layer ``i``'s parameters out of a stack (or a slice of one) whose
+    layers have the kinds ``kinds``: what every layer has at ``i``, its
+    mixer's own leaves at its place among the layers of its kind."""
+    if not c.layer_mixers:
+        return jax.tree.map(lambda a: a[i], stack)
+    own = MIXER_STACKS["kda" if kinds[i] == "kda" else "attn"]
+    place = sum((k == "kda") == (kinds[i] == "kda") for k in kinds[:i])
+    return {name: jax.tree.map(
+                lambda a, at=(place if name == own else i): a[at], sub)
+            for name, sub in stack.items()
+            if name == own or name not in MIXER_STACKS.values()}
+
+
 def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             positions=None, return_aux: bool = False,
             return_hidden: bool = False):
@@ -789,7 +989,9 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     model; with ``experts_held`` also ``held_share`` and ``full_buffer``,
     the means over the layers; with a ``router_bias`` also ``expert_counts`` [layers,
     experts], the batch's assignments to every expert, and
-    ``bias_swapped``, the mean over the layers). ``return_hidden`` skips
+    ``bias_swapped``, the mean over the layers; with KDA layers also
+    ``kda_log_decay_min``, the most negative cumulative log-decay inside
+    any chunk of any of them). ``return_hidden`` skips
     the LM head and returns the final
     normed hidden states [B, T, D] (the chunked-loss path applies the head
     itself).
@@ -817,6 +1019,8 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                 pos_emb = params["embed"]["pos"][positions]
             x = x + pos_emb.astype(dt)
             rope = None
+        elif c.kv_latent is not None and not c.latent_rope:
+            rope = None                 # no layer rotates anything
         else:
             cos, sin = rope_frequencies(
                 c.head_dim if c.kv_latent is None else c.d_head_rope,
@@ -845,8 +1049,41 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                 policies.dots_with_no_batch_dims_saveable, policy)
         return jax.checkpoint(layer, policy=policy)
 
-    period = max(1, len(c.layer_pattern))
-    layers = [layer_of(c.layer_kind(i)) for i in range(period)]
+    def run_stack(x, stack, first: int, n: int, dense: bool = False):
+        """Layers ``first`` to ``first + n`` of the model, whose
+        parameters are ``stack`` -> (x, every layer's aux, stacked)."""
+        kinds = tuple(c.layer_kind(first + i) for i in range(n))
+        period = _period(kinds)
+        layers = [layer_of(kind, dense) for kind in kinds]
+
+        def in_line(h, part, kinds, layers):
+            per_layer = []
+            for i, layer in enumerate(layers):
+                h, aux_i = layer(h, _take_layer(c, part, kinds, i))
+                per_layer.append(aux_i)
+            return h, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+
+        if not c.scan_layers:
+            # Unrolled: larger compile, but lets XLA schedule across layer
+            # boundaries (and sidesteps scan-differentiation limits on some
+            # backends when remat is off).
+            return in_line(x, stack, kinds, layers)
+        if period == 1:
+            return _scan_layers(lambda h, lp: layers[0](h, lp), x, stack, n)
+        # One scan step is one whole period, its layers unrolled: the
+        # stacked [L, ...] weights are read as [L / P, P, ...] (a mixer's
+        # own leaves as [L / P, layers of that kind a period, ...]); the
+        # layers behind the last whole period run in line.
+        whole, left = _split_stack(c, stack, kinds, period)
+        x, auxs = _scan_layers(
+            lambda h, pp: in_line(h, pp, kinds[:period], layers[:period]),
+            x, whole, n // period)
+        if n % period:
+            at = n // period * period
+            x, more = in_line(x, left, kinds[at:], layers[at:])
+            auxs = jax.tree.map(lambda a, b: jnp.concatenate(
+                [a.reshape(-1, *a.shape[2:]), b]), auxs, more)
+        return x, auxs
 
     # ``layers`` holds what belongs to no one part of a block: the scan's
     # reads of the stacked weights and writes of their stacked gradients.
@@ -854,49 +1091,25 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
         if c.n_dense_layers:
             # the leading dense layers: a stack of their own, a scan (or
             # loop) of its own ahead of the expert layers'
-            dense = layer_of(None, dense=True)
-            if c.scan_layers:
-                x, _ = _scan_layers(dense, x, params["dense_layers"],
-                                    c.n_dense_layers)
-            else:
-                for i in range(c.n_dense_layers):
-                    x, _ = dense(x, jax.tree.map(lambda a, i=i: a[i],
-                                                 params["dense_layers"]))
-        if c.scan_layers and period == 1:
-            x, auxs = _scan_layers(lambda h, lp: layers[0](h, lp), x,
-                                   params["layers"], c.n_scan_layers)
-        elif c.scan_layers:
-            # One scan step is one whole period, its layers unrolled: the
-            # stacked [L, ...] weights are read as [L / P, P, ...].
-            def one_period(h, pp):
-                per_position = []
-                for i, layer in enumerate(layers):
-                    h, aux_i = layer(h, jax.tree.map(lambda a, i=i: a[i], pp))
-                    per_position.append(aux_i)
-                return h, jax.tree.map(lambda *a: jnp.stack(a), *per_position)
-
-            x, auxs = _scan_layers(one_period, x, jax.tree.map(
-                lambda a: a.reshape(c.n_scan_layers // period, period,
-                                    *a.shape[1:]), params["layers"]),
-                c.n_scan_layers // period)
-        else:
-            # Unrolled: larger compile, but lets XLA schedule across layer
-            # boundaries (and sidesteps scan-differentiation limits on some
-            # backends when remat is off).
-            per_layer = []
-            for i in range(c.n_scan_layers):
-                lp = jax.tree.map(lambda a, i=i: a[i], params["layers"])
-                x, aux_i = layers[i % period](x, lp)
-                per_layer.append(aux_i)
-            auxs = jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+            x, dense_auxs = run_stack(x, params["dense_layers"], 0,
+                                      c.n_dense_layers, dense=True)
+        x, auxs = run_stack(x, params["layers"], c.n_dense_layers,
+                            c.n_scan_layers)
         aux = {"balance": auxs["balance"].mean(), "z": auxs["z"].mean(),
                "load_max": auxs["load_max"].max()}
         if c.experts_held is not None:
             aux["held_share"] = auxs["held_share"].mean()
             aux["full_buffer"] = auxs["full_buffer"].mean()
         if c.router_bias:
-            aux["expert_counts"] = auxs["counts"]
+            # [layers, experts], whether the scan stacked layers or periods
+            aux["expert_counts"] = auxs["counts"].reshape(-1, c.n_experts)
             aux["bias_swapped"] = auxs["bias_swapped"].mean()
+        if "kda" in c.layer_mixers:
+            aux["kda_log_decay_min"] = auxs["log_decay_min"].min()
+            if c.n_dense_layers:
+                aux["kda_log_decay_min"] = jnp.minimum(
+                    aux["kda_log_decay_min"],
+                    dense_auxs["log_decay_min"].min())
 
     with jax.named_scope("final_norm"):
         if c.arch == "gpt2":
@@ -922,13 +1135,12 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     = (windowed, rope) is the layer's place in the ``layer_pattern``
     (static; None with no pattern: full causal attention, the arch's own
     positions), and names the sub-scope its attention runs under.
+    ``kind`` = "kda": the token mixer is KDA, under ``attn_linear``.
     ``dense``: one of an expert model's leading dense layers."""
     dt = c.compute_dtype
     experts = c.n_experts > 0 and not dense
-    if c.kv_latent is not None:
-        kind = (False, True)        # full causal layers, all of them
     window = None
-    if kind is not None:
+    if kind is not None and kind != "kda":
         windowed, with_rope = kind
         window = c.sliding_window if windowed else None
         rope = rope if with_rope else None
@@ -941,18 +1153,24 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     if experts and c.router_input == "attn_norm":
         with jax.named_scope("moe"):
             router = moe.router_matmul(h, lp["router"]["w"])
+    decay_min = None
     with jax.named_scope("attn"), (
             contextlib.nullcontext() if kind is None else jax.named_scope(
-                ATTN_SCOPES[window is not None])):
-        if c.kv_latent is not None:
-            q, k, v, shared = _latent_qkv(h, lp["attn"], c, rope, positions)
+                ATTN_SCOPES[2 if kind == "kda" else window is not None])):
+        if kind == "kda":
+            o, decay_min = _kda_mixer(h, lp["kda"], c)
         else:
-            q, k, v = _plain_qkv(h, lp["attn"], c, rope, positions)
-            shared = {}
-        q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-        with jax.named_scope("attn_core"):
-            o = attention(q, k, v, causal=True, impl=c.attn_impl,
-                          window=window, **shared)
+            if c.kv_latent is not None:
+                q, k, v, shared = _latent_qkv(
+                    h, lp["mla" if c.layer_mixers else "attn"], c, rope,
+                    positions)
+            else:
+                q, k, v = _plain_qkv(h, lp["attn"], c, rope, positions)
+                shared = {}
+            q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
+            with jax.named_scope("attn_core"):
+                o = attention(q, k, v, causal=True, impl=c.attn_impl,
+                              window=window, **shared)
         with jax.named_scope("attn_out"):
             o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
             x = x + o
@@ -1004,7 +1222,30 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                        lp["mlp"]["w_up"].astype(dt),
                        lp["mlp"]["w_down"].astype(dt))
             x = x + m
+    if "kda" in c.layer_mixers:         # every layer of a stack alike
+        aux = dict(aux, log_decay_min=jnp.zeros((), jnp.float32)
+                   if decay_min is None else decay_min)
     return x, aux
+
+
+def _kda_mixer(h, w, c: TransformerConfig):
+    """A KDA layer's mixer up to (not with) the output projection, from
+    the normed input ``h`` [B, T, D] and the layer's own leaves ``w`` ->
+    (o [B, T, H, dv], the most negative cumulative log-decay inside any
+    chunk). ``attn_qkv`` the three projections, ``attn_core`` the chunked
+    delta rule and nothing else; convolutions, gates and the gated head
+    norm open their scopes in ``ops/linear_attention.py``."""
+    dt = c.compute_dtype
+    with jax.named_scope("attn_qkv"):
+        q, k, v = (jnp.einsum("btd,dhk->bthk", h, w[name].astype(dt))
+                   for name in ("wq", "wk", "wv"))
+    q, k, v = linear_attention.conv_silu(q, k, v, w["conv_q"], w["conv_k"],
+                                         w["conv_v"])
+    g, beta = linear_attention.gates(h, w)
+    with jax.named_scope("attn_core"):
+        o = linear_attention.gated_delta_rule(q, k, v, g, beta)
+    return (linear_attention.gated_head_norm(o, h, w, eps=c.norm_eps),
+            linear_attention.log_decay_min(g))
 
 
 def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
@@ -1046,10 +1287,11 @@ def _latent_qkv(h, w, c: TransformerConfig, rope, positions):
     compression of the token; the rotary part of the key is one vector a
     token, which every head scores its own rotary query part against
     (``ops.attention``: ``q_shared`` / ``k_shared``). RoPE pairs the
-    halves of the rotary part, as ``apply_rope`` does everywhere."""
+    halves of the rotary part, as ``apply_rope`` does everywhere. With
+    ``rope`` None (``latent_rope`` False) nothing is rotated: the shared
+    part is a plain key part, and ``attn_pos`` stays empty."""
     dt = c.compute_dtype
     nope, latent = c.d_head_nope, c.kv_latent
-    cos, sin = rope
     with jax.named_scope("attn_qkv"):
         q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
     with jax.named_scope(MLA_SCOPE):
@@ -1058,10 +1300,15 @@ def _latent_qkv(h, w, c: TransformerConfig, rope, positions):
                         rms_norm(down[..., :latent], w["kv_norm"],
                                  eps=c.norm_eps),
                         w["wkv_b"].astype(dt))
-        with jax.named_scope("attn_pos"):
-            q_rope = apply_rope(q[..., nope:], cos, sin, positions=positions)
-            k_rope = apply_rope(down[:, :, None, latent:], cos, sin,
-                                positions=positions)[:, :, 0]
+        if rope is None:
+            q_rope, k_rope = q[..., nope:], down[..., latent:]
+        else:
+            cos, sin = rope
+            with jax.named_scope("attn_pos"):
+                q_rope = apply_rope(q[..., nope:], cos, sin,
+                                    positions=positions)
+                k_rope = apply_rope(down[:, :, None, latent:], cos, sin,
+                                    positions=positions)[:, :, 0]
         return (q[..., :nope], kv[..., :nope], kv[..., nope:],
                 {"q_shared": q_rope, "k_shared": k_rope})
 
@@ -1320,6 +1567,8 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
             # the router's bias reads it and takes it out of the metrics
             metrics["moe_expert_counts"] = aux["expert_counts"]
             metrics["moe_bias_swapped"] = aux["bias_swapped"]
+    if "kda_log_decay_min" in aux:
+        metrics = dict(metrics, kda_log_decay_min=aux["kda_log_decay_min"])
     return loss, metrics
 
 
@@ -1471,7 +1720,15 @@ def init_train_state(rng, config: TransformerConfig, optimizer):
 def refuse_decode(c: TransformerConfig) -> None:
     """The KV-cache decode runs one kind of dense layer: refuse, by name,
     a model it would run wrongly in silence."""
+    if "kda" in c.layer_mixers:
+        raise NotImplementedError(
+            f"KV-cache decode does not run a model with layer_mixers "
+            f"({c.layer_mixers!r}; kda_heads {c.kda_heads}, kda_head_dim "
+            f"{c.kda_head_dim}, kda_conv {c.kda_conv}): a KDA layer keeps a "
+            f"recurrent state and its convolution's last positions, not "
+            f"keys and values, and would be decoded as plain attention")
     for name, value in (("kv_latent", c.kv_latent),
+                        ("latent_rope", not c.latent_rope),
                         ("n_dense_layers", c.n_dense_layers),
                         ("d_ff_shared", c.d_ff_shared)):
         if value:

@@ -56,8 +56,8 @@ def test_rehearsal_at_tiny_size_on_fake_chips():
 
     assert s["parent_backend_initialized"] is False
     assert s["size"] == "tiny" and s["chips"] == 2
-    assert set(s["walls_s"]) == {"detect", "train", "kernel", "serve",
-                                 "shutdown", "total"}
+    assert set(s["walls_s"]) == {"detect", "train", "kernel", "delta_rule",
+                                 "serve", "shutdown", "total"}
     assert set(s["native_lanes"].values()) <= {"native", "python fallback"}
     cache = s["compile_cache"]
     assert cache["dir"] and cache["entries_after"] >= cache["entries_before"]
@@ -72,6 +72,11 @@ def test_rehearsal_at_tiny_size_on_fake_chips():
     k = s["kernel"]
     assert k["chips_env"] in ("0", "1")
     assert set(k["errors"]) == {"out", "dq", "dk", "dv"}
+
+    d = s["delta_rule"]
+    assert d["shape"] == [1, 192, 2, 16] and d["prefix"] == 128
+    assert set(d["errors"]) == {"o", "dq", "dk", "dv", "dg", "dbeta"}
+    assert d["finite"] and d["log_decay_min"] < 0
 
     reps = s["serve"]["replicas"]
     assert len(reps) == 2
