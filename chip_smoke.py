@@ -397,8 +397,11 @@ def kernel_phase(size: dict, platform: str) -> dict:
 
 def delta_rule_body(size: dict) -> dict:
     """Runs in a one-chip worker: the chunked gated delta rule at a KDA
-    layer's shape, forward and gradient timed; then, on a prefix of the
-    row, forward and all five gradients against the token-by-token
+    layer's shape, forward and gradient timed, as ``gated_delta_rule``
+    chooses its implementation there (``implementation``: the Pallas
+    kernels on one TPU chip at 128-wide heads) and as the XLA scan
+    (``scan_*``); then, on a prefix of the
+    row, forward and all five gradients of both against the token-by-token
     recurrence (``chipbench/reference/kimi_linear.py`` ``delta_rule``,
     float32, ``highest``). Decays as the model's init gives them: up to
     1.6 a token and channel, 100 over a chunk."""
@@ -436,12 +439,17 @@ def delta_rule_body(size: dict) -> dict:
         result = jax.block_until_ready(compiled(*args))
         return result, compile_s, round(time.perf_counter() - t0, 5)
 
+    # the op as ``gated_delta_rule`` chooses it here, and beside it the
+    # XLA scan at the same shape (the same thing where the choice is the scan)
+    out["implementation"] = ("kernels" if la._takes_kernels(q, v) else "scan")
+    gradient = lambda fn: jax.grad(loss(fn, w), argnums=(0, 1, 2, 3, 4))
     o, out["fwd_compile_s"], out["fwd_run_s"] = timed(
         la.gated_delta_rule, *operands)
     out["finite"] = bool(jnp.isfinite(o.astype(jnp.float32)).all())
     _, out["grad_compile_s"], out["grad_run_s"] = timed(
-        jax.grad(loss(la.gated_delta_rule, w), argnums=(0, 1, 2, 3, 4)),
-        *operands)
+        gradient(la.gated_delta_rule), *operands)
+    _, _, out["scan_fwd_run_s"] = timed(la._by_scan, *operands)
+    _, _, out["scan_grad_run_s"] = timed(gradient(la._by_scan), *operands)
     out["log_decay_min"] = float(la.log_decay_min(g))
 
     # A prefix is a whole problem: the rule is causal and starts at zero.
@@ -451,7 +459,6 @@ def delta_rule_body(size: dict) -> dict:
     grad = lambda fn: jax.jit(jax.value_and_grad(
         lambda *a: (loss(fn, w[:, :n])(*a), fn(*a)), argnums=(0, 1, 2, 3, 4),
         has_aux=True))
-    (_, o_p), grads_p = grad(la.gated_delta_rule)(*short)
     with jax.default_matmul_precision("highest"):
         (_, o_r), grads_r = grad(delta_rule)(*exact)
 
@@ -462,9 +469,12 @@ def delta_rule_body(size: dict) -> dict:
                 "ref_abs_max": float(jnp.abs(r).max()),
                 "ref_abs_mean": float(jnp.abs(r).mean())}
 
-    out["errors"] = {name: err(a, r) for name, a, r in zip(
-        ("o", "dq", "dk", "dv", "dg", "dbeta"), (o_p, *grads_p),
-        (o_r, *grads_r))}
+    names = ("o", "dq", "dk", "dv", "dg", "dbeta")
+    for key, fn in (("errors", la.gated_delta_rule),
+                    ("scan_errors", la._by_scan)):
+        (_, o_p), grads_p = grad(fn)(*short)
+        out[key] = {name: err(a, r) for name, a, r in zip(
+            names, (o_p, *grads_p), (o_r, *grads_r))}
     return out
 
 
@@ -484,10 +494,15 @@ def delta_rule_phase(size: dict, platform: str) -> dict:
             f"delta-rule worker ran on {r['platform']!r}, expected "
             f"{platform!r}")
     require(r["finite"], "the delta rule's output has a non-finite value")
-    for name, e in r["errors"].items():
-        require(e["mean_abs_err"] <= DELTA_RULE_TOLERANCE * e["ref_abs_mean"],
-                f"delta rule {name}: mean |err| {e['mean_abs_err']} over "
-                f"{DELTA_RULE_TOLERANCE} x the recurrence's mean "
+    if platform == "tpu":       # [1, 16384, 32, 128] on one chip: the kernels'
+        require(r["implementation"] == "kernels",
+                f"the delta rule ran as {r['implementation']!r} on the chip")
+    for key in ("errors", "scan_errors"):
+        for name, e in r[key].items():
+            require(
+                e["mean_abs_err"] <= DELTA_RULE_TOLERANCE * e["ref_abs_mean"],
+                f"delta rule {key} {name}: mean |err| {e['mean_abs_err']} "
+                f"over {DELTA_RULE_TOLERANCE} x the recurrence's mean "
                 f"{e['ref_abs_mean']}")
     return r
 

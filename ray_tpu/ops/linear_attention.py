@@ -40,7 +40,18 @@ cancels catastrophically where keys repeat).
 
 The state is float32; the products take their operands in the inputs'
 dtype (bfloat16 in a train step) and accumulate in float32, as the
-attention kernels do. Plain XLA: no Pallas kernel here.
+attention kernels do.
+
+**Two implementations of that one algorithm**, chosen by what the call
+shows (``_takes_kernels``: the platform, the heads' width, the operands'
+sharding or the mesh in force; no option): on a TPU at 128-wide heads
+with one device under the operands, two Pallas kernels (forward and
+backward, further down: a grid over chunks whose steps keep a chunk's
+matrices, its inverse and the state in VMEM; a third of the scan's time
+forward and a quarter backward at [1, 16384, 32, 128] on a v5e, PERF.md
+section 6, PR 40); anywhere else (the CPU tests' 16-wide heads, a mesh)
+the scan in plain XLA that the next paragraph describes, which is also
+what the kernels are tested against.
 
 **The backward is the op's own** (``jax.custom_vjp``): the forward keeps
 the operands and the state at every chunk's START (T / CHUNK states of
@@ -65,7 +76,10 @@ softplus / exp, the head norm and the gate's product).
 
 from __future__ import annotations
 
+import functools
+import logging
 import math
+
 import jax
 import jax.numpy as jnp
 
@@ -74,6 +88,7 @@ SUB = 16        # positions a sub-block of a chunk's A and B
 SCOPES = ("kda_conv", "kda_gate")
 L2_EPS = 1e-6
 _HIGHEST = jax.lax.Precision.HIGHEST
+logger = logging.getLogger(__name__)
 
 
 def short_conv(x, w):
@@ -332,6 +347,524 @@ def _delta_rule_bwd(kept, d_o):
 _delta_rule.defvjp(_delta_rule_fwd, _delta_rule_bwd)
 
 
+# -- the same chunk, as Pallas kernels ------------------------------------------
+#
+# One grid step is one chunk of ``_KERNEL_HEADS`` heads. What ``_intra`` and
+# ``_chunk_forward`` (backward: ``_chunk_backward`` and ``_intra``'s own
+# gradient, by hand) make of it lives in VMEM and nowhere else; the state,
+# float32 and TRANSPOSED ([dv, dk]: ``gamma`` then scales its lanes and
+# every product with it is one the MXU takes as it stands), is a scratch
+# that the sequential grid dimension over chunks carries. The operands
+# arrive as [B, T, H * d]: a head is a 128-lane slice of a chunk's block,
+# and nothing is transposed on the way in or out. ``beta`` and its
+# gradient are rows, [B, H / heads, T / CHUNK, heads, CHUNK] (``_column``
+# and ``_row`` turn them inside the kernel: [.., T, heads] would be 64
+# times its size in HBM's tiles).
+#
+# A head's chunk is ONE chain of some thirty small products, each waiting
+# for the last, and the MXU answers in a hundred cycles: so every stage
+# runs for all the heads of the step before the next stage starts (the
+# loops over ``xs``), which puts independent products side by side in the
+# program for the scheduler to overlap.
+
+_KERNEL_HEADS = 4
+_F32 = jnp.float32
+_NT, _TN = ((1,), (1,)), ((0,), (0,))
+
+
+def _mm(a, b, dims=((1,), (0,)), precision=None):
+    """a @ b (``_NT``: a @ b^T, ``_TN``: a^T @ b), summed in float32."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=precision,
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _split(a):
+    """float32 -> (its bfloat16 rounding, the rest's bfloat16 rounding)."""
+    high = a.astype(jnp.bfloat16)
+    return high, (a - high.astype(_F32)).astype(jnp.bfloat16)
+
+
+def _running_sum(x, reverse: bool = False):
+    """Inclusive running sums of ``x`` [C, d] float32 down its rows (up
+    them with ``reverse``), exact to float32: a triangle of ones against
+    ``x`` split into three bfloat16 parts that sum to it, so every product
+    is exact whatever precision the MXU gives float32 operands."""
+    c = x.shape[0]
+    r, s = _iota((c, c), 0), _iota((c, c), 1)
+    ones = jnp.where((r <= s) if reverse else (r >= s), 1.0, 0.0).astype(
+        jnp.bfloat16)
+    high = x.astype(jnp.bfloat16)
+    middle, low = _split(x - high.astype(_F32))
+    return _mm(ones, high) + _mm(ones, middle) + _mm(ones, low)
+
+
+def _under(whole, top: int, rows):
+    """``whole`` with its rows from ``top`` down replaced by ``rows``."""
+    return jnp.concatenate([whole[:top], rows], 0) if top else rows
+
+
+def _column(row):
+    """[1, C] -> [C, 1]."""
+    c = row.shape[1]
+    return jnp.sum(jnp.where(_iota((c, c), 0) == _iota((c, c), 1), row, 0.0),
+                   1, keepdims=True)
+
+
+def _row(column):
+    """[C, 1] -> [1, C]."""
+    c = column.shape[0]
+    return jnp.sum(jnp.where(_iota((c, c), 0) == _iota((c, c), 1), column,
+                             0.0), 0, keepdims=True)
+
+
+class _Chunk:
+    """What one head's chunk is made into, by name (``_kernel_intra``)."""
+
+
+def _kernel_intra(operands):
+    """``_intra`` inside a kernel. ``operands``: a head each, (``q``, ``k``
+    [C, dk], ``v`` [C, dv] in the operands' dtype, ``g`` [C, dk] and
+    ``beta`` [C, 1] float32) -> a ``_Chunk`` each with ``_intra``'s six
+    results (``w``, ``u0``, ``qt``, ``bm``, ``kbar``; ``gamma`` [1, dk])
+    and what the backward reads besides. Sub-block by sub-block as
+    ``_intra``: below the diagonal one product against a reference decay,
+    on it pair by pair, a column ``s`` of the block a step; the SUB x SUB
+    diagonal blocks of the inverse by elimination in float32, all of them
+    side by side in the lanes of one [SUB, C] array; then
+    ``_block_lower_inverse``'s merges, float32 products at ``highest``
+    whatever the chunk's dtype, as there (``inv`` is the float32 inverse
+    the backward's gradient goes through, ``inv_d`` its rounding)."""
+    xs = []
+    for q, k, v, g, beta in operands:
+        x = _Chunk()
+        x.dt, x.g, x.beta = q.dtype, g, beta
+        x.qf, x.kf, x.vf = [a.astype(_F32) for a in (q, k, v)]
+        x.scale = 1.0 / math.sqrt(q.shape[1])
+        xs.append(x)
+    c, dk = xs[0].qf.shape
+    n_sub = c // SUB
+    for x in xs:
+        x.big = _running_sum(x.g)
+    lane, sub = _iota((SUB, c), 1), _iota((SUB, c), 0)
+    # the lanes of the rows from ``top`` down (a slice of ``lane`` is
+    # something Mosaic's compiler fails on)
+    lanes_under = {top: _iota((SUB - top, c), 1) for top in range(0, SUB, 8)}
+    for x in xs:
+        x.a_rows, x.b_rows, x.rows, x.cols = [], [], [], []
+        x.columns = [jnp.zeros((SUB, c), _F32) for _ in range(SUB - 1)]
+        x.beta_lanes = jnp.zeros((SUB, c), _F32)
+    for i in range(n_sub):
+        r0 = i * SUB
+        for x in xs:
+            big_i, k_i, q_i = (a[r0:r0 + SUB] for a in (x.big, x.kf, x.qf))
+            acc_a = acc_b = jnp.zeros((SUB, c), _F32)
+            for s in range(SUB):
+                # column s is read from its own row down: the 8-row tiles
+                # above that row are left out
+                top = s // 8 * 8
+                lane_s = lanes_under[top]
+                at = lane_s == r0 + s
+                keyed = jnp.exp(jnp.minimum(big_i[top:] - big_i[s:s + 1],
+                                            0.0)) * k_i[s:s + 1]
+                col_a = jnp.sum(keyed * k_i[top:], -1, keepdims=True)
+                col_b = jnp.sum(keyed * q_i[top:], -1, keepdims=True)
+                acc_a = _under(acc_a, top, jnp.where(at, col_a, acc_a[top:]))
+                acc_b = _under(acc_b, top, jnp.where(at, col_b, acc_b[top:]))
+                if s < SUB - 1:
+                    x.columns[s] = _under(x.columns[s], top, jnp.where(
+                        lane_s // SUB == i, col_a, x.columns[s][top:]))
+            x.beta_lanes = jnp.where(lane // SUB == i, x.beta[r0:r0 + SUB],
+                                     x.beta_lanes)
+            x.a_rows.append(jnp.where(sub + r0 > lane, acc_a, 0.0))
+            x.b_rows.append(jnp.where(sub + r0 >= lane, acc_b, 0.0))
+            ref = big_i[:1] - x.g[r0:r0 + 1]
+            x.rows.append(jnp.exp(big_i - ref))             # exponent <= 0
+            if i:
+                x.cols.append(jnp.exp(ref - x.big[:r0]))    # exponent <= 0
+        for x in xs:
+            if not i:
+                continue
+            k_col = jnp.concatenate(
+                [(x.kf[:r0] * x.cols[-1]).astype(x.dt),
+                 jnp.zeros((c - r0, dk), x.dt)], 0)
+            rows = jnp.concatenate(
+                [x.kf[r0:r0 + SUB] * x.rows[i], x.qf[r0:r0 + SUB] * x.rows[i]],
+                0).astype(x.dt)
+            below = _mm(rows, k_col, _NT)                   # [2 SUB, C]
+            x.a_rows[i] = x.a_rows[i] + below[:SUB]
+            x.b_rows[i] = x.b_rows[i] + below[SUB:]
+    for x in xs:
+        x.a = jnp.concatenate(x.a_rows, 0)
+        x.m = x.beta * x.a
+        x.b = jnp.concatenate(x.b_rows, 0)
+        # column j of every diagonal block of beta * A, across its lanes
+        x.columns = [jnp.where(sub > j, col * x.beta_lanes, 0.0)
+                     for j, col in enumerate(x.columns)]
+        x.packed = jnp.where(lane % SUB == sub, 1.0, 0.0)
+    # the diagonal blocks' inverses: X <- X - n_j (x) X[j], j ascending,
+    # n_j column j of the block (rows > j)
+    for j in range(SUB - 1):
+        for x in xs:
+            x.packed = x.packed - x.columns[j] * x.packed[j:j + 1]
+    for x in xs:
+        x.inv = jnp.concatenate([jnp.where(lane // SUB == i, x.packed, 0.0)
+                                 for i in range(n_sub)], 0)     # [C, C]
+    r, s = _iota((c, c), 0), _iota((c, c), 1)
+    size = SUB
+    while size < c:
+        under = ((r // size) % 2 == 1) & (s // size == r // size - 1)
+        for x in xs:
+            x.part = _mm(x.inv, jnp.where(under, x.m, 0.0),
+                         precision=_HIGHEST)
+        for x in xs:
+            x.inv = x.inv - _mm(x.part, x.inv, precision=_HIGHEST)
+        size *= 2
+    for x in xs:
+        x.inv_d = x.inv.astype(x.dt)
+        x.gam = jnp.exp(x.big)
+        x.kg_vb = jnp.concatenate([(x.beta * x.kf * x.gam).astype(x.dt),
+                                   (x.beta * x.vf).astype(x.dt)], 1)
+    for x in xs:
+        w_u0 = _mm(x.inv_d, x.kg_vb).astype(x.dt)           # [C, dk + dv]
+        x.w, x.u0 = w_u0[:, :dk], w_u0[:, dk:]
+        last = x.big[c - 1:]
+        x.tail = jnp.exp(last - x.big)
+        x.qt = (x.qf * x.gam * x.scale).astype(x.dt)
+        x.bm = (x.b * x.scale).astype(x.dt)
+        x.kbar = (x.kf * x.tail).astype(x.dt)
+        x.gamma = jnp.exp(last)
+    return xs
+
+
+def _head_slices(h: int, dk: int, dv: int):
+    return slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
+
+
+def _load_heads(heads, dk, dv, q_ref, k_ref, v_ref, g_ref, beta_ref):
+    out = []
+    for h in range(heads):
+        keys, values = _head_slices(h, dk, dv)
+        out.append((q_ref[0, :, keys], k_ref[0, :, keys], v_ref[0, :, values],
+                    g_ref[0, :, keys], _column(beta_ref[0, 0, 0, h:h + 1])))
+    return out
+
+
+def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
+                      heads: int, dk: int, dv: int):
+    import jax.experimental.pallas as pl
+
+    *starts_ref, state = rest       # the chunk-start states only if kept
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        state[...] = jnp.zeros_like(state)
+
+    xs = _kernel_intra(_load_heads(heads, dk, dv, q_ref, k_ref, v_ref, g_ref,
+                                   beta_ref))
+    for h, x in enumerate(xs):
+        x.s = state[h]
+        if starts_ref:
+            starts_ref[0][0, 0, h] = x.s
+        # W and Qt against the state in one product
+        x.by_state = _mm(jnp.concatenate([x.w, x.qt], 0), x.s.astype(x.dt),
+                         _NT)
+    c = xs[0].qf.shape[0]
+    for x in xs:
+        x.u = (x.u0.astype(_F32) - x.by_state[:c]).astype(x.dt)
+    for h, x in enumerate(xs):
+        o_ref[0, :, _head_slices(h, dk, dv)[1]] = (
+            x.by_state[c:] + _mm(x.bm, x.u)).astype(o_ref.dtype)
+        state[h] = x.gamma * x.s + _mm(x.u, x.kbar, _TN)
+
+
+def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref,
+                      do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                      d_state, *, heads: int, dk: int, dv: int):
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    xs = _kernel_intra(_load_heads(heads, dk, dv, q_ref, k_ref, v_ref, g_ref,
+                                   beta_ref))
+    c = xs[0].qf.shape[0]
+    n_sub = c // SUB
+    r, col = _iota((c, c), 0), _iota((c, c), 1)
+    lane, column = _iota((SUB, c), 1), _iota((SUB, 1), 0)
+    # ``_chunk_backward``, the state and its gradient transposed
+    for h, x in enumerate(xs):
+        x.s, x.d_after = starts_ref[0, 0, h], d_state[h]
+        x.d_o = do_ref[0, :, _head_slices(h, dk, dv)[1]]
+        x.sd, x.dsd = x.s.astype(x.dt), x.d_after.astype(x.dt)
+    for x in xs:
+        x.u = (x.u0.astype(_F32) - _mm(x.w, x.sd, _NT)).astype(x.dt)
+        x.du = (_mm(x.bm, x.d_o, _TN) + _mm(x.kbar, x.dsd, _NT)).astype(x.dt)
+    for h, x in enumerate(xs):
+        d_state[h] = (_mm(x.d_o, x.qt, _TN) + x.gamma * x.d_after
+                      - _mm(x.du, x.w, _TN))
+        by_state = _mm(jnp.concatenate([x.du, x.d_o], 0), x.sd)
+        x.d_w = (-by_state[:c]).astype(x.dt)
+        x.d_qt = by_state[c:]
+        x.d_bm = _mm(x.d_o, x.u, _NT)
+        x.d_kbar = _mm(x.u, x.dsd)
+        x.d_gamma = jnp.sum(x.s * x.d_after, 0, keepdims=True)
+    # ``_intra``'s gradient. The inverse T of I + tril(beta * A, -1): its
+    # gradient dT = [dW | dU] [Kg | Vb]^T arrives rounded to the chunk's
+    # dtype (T entered both products so), and beta * A's is -T^T dT T^T
+    # under the diagonal, through the float32 inverse at ``highest`` as
+    # ``_block_lower_inverse``'s own gradient takes it
+    for x in xs:
+        d_w_u = jnp.concatenate([x.d_w, x.du], 1)
+        x.d_kg_vb = _mm(x.inv_d, d_w_u, _TN)
+        x.d_inv = _mm(d_w_u, x.kg_vb, _NT).astype(x.dt).astype(_F32)
+    for x in xs:
+        x.d_inv = _mm(x.inv, x.d_inv, _TN, precision=_HIGHEST)
+    for x in xs:
+        d_m = jnp.where(r > col, -_mm(x.d_inv, x.inv, _NT,
+                                      precision=_HIGHEST), 0.0)
+        d_kg, d_vb = x.d_kg_vb[:, :dk], x.d_kg_vb[:, dk:]
+        x.d_a = x.beta * d_m
+        x.d_b = jnp.where(r >= col, x.d_bm * x.scale, 0.0)
+        x.d_beta = (jnp.sum(d_m * x.a, -1, keepdims=True)
+                    + jnp.sum(d_kg * x.kf * x.gam, -1, keepdims=True)
+                    + jnp.sum(d_vb * x.vf, -1, keepdims=True))
+        x.d_v = d_vb * x.beta
+        kept = x.d_kbar * x.tail                # d_kbar as k receives it
+        written = d_kg * x.beta * x.gam         # d_kg likewise
+        x.d_k = written + kept
+        x.d_q = x.d_qt * x.gam * x.scale
+        x.d_big = x.kf * (written - kept) + x.qf * x.d_q
+        x.d_last = (jnp.sum(kept * x.kf, 0, keepdims=True)
+                    + x.d_gamma * x.gamma)
+        x.k_rows, x.q_rows, x.k_diag = [], [], []
+        x.k_cols = jnp.zeros_like(x.kf)
+    # A and B: the gradient of k as a row of either (``k_rows``), of q as a
+    # row of B, of k as a column of both (``k_cols``; ``k_diag`` its part
+    # from the diagonal blocks); the decays' gradient is those times k and q
+    for i in range(n_sub):
+        r0 = i * SUB
+        for x in xs:
+            big_i, k_i, q_i = (a[r0:r0 + SUB] for a in (x.big, x.kf, x.qf))
+            da_i, db_i = x.d_a[r0:r0 + SUB], x.d_b[r0:r0 + SUB]   # [SUB, C]
+            by_row_k = by_row_q = by_col = jnp.zeros_like(k_i)
+            for s in range(SUB):
+                top = s // 8 * 8                             # as the forward
+                decay = jnp.exp(jnp.minimum(big_i[top:] - big_i[s:s + 1],
+                                            0.0))
+                keyed = decay * k_i[s:s + 1]
+                a_col = da_i[top:, r0 + s:r0 + s + 1]        # rows > s
+                b_col = db_i[top:, r0 + s:r0 + s + 1]        # rows >= s
+                by_row_k = _under(by_row_k, top, by_row_k[top:] + a_col * keyed)
+                by_row_q = _under(by_row_q, top, by_row_q[top:] + b_col * keyed)
+                by_col = jnp.where(
+                    column == s,
+                    jnp.sum((a_col * k_i[top:] + b_col * q_i[top:]) * decay,
+                            0, keepdims=True), by_col)
+            x.k_rows.append(by_row_k)
+            x.q_rows.append(by_row_q)
+            x.k_diag.append(by_col)
+        for x in xs:
+            if not i:
+                continue
+            row, col_i = x.rows[i], x.cols[i - 1]
+            zeros = jnp.zeros((c - r0, dk), _F32)
+            k_col = jnp.concatenate([x.kf[:r0] * col_i, zeros], 0)
+            both = jnp.concatenate(
+                [jnp.where(lane < r0, x.d_a[r0:r0 + SUB], 0.0),
+                 jnp.where(lane < r0, x.d_b[r0:r0 + SUB], 0.0)], 0).astype(
+                     x.dt)
+            rows = _mm(both, k_col.astype(x.dt)) * jnp.concatenate(
+                [row, row], 0)                               # [2 SUB, dk]
+            cols = _mm(both, jnp.concatenate(
+                [x.kf[r0:r0 + SUB] * row, x.qf[r0:r0 + SUB] * row],
+                0).astype(x.dt), _TN) * jnp.concatenate([col_i, zeros], 0)
+            x.k_rows[i] = x.k_rows[i] + rows[:SUB]
+            x.q_rows[i] = x.q_rows[i] + rows[SUB:]
+            x.k_cols = x.k_cols + cols
+            # the reference decay's own gradient: nothing in exact
+            # arithmetic, but the rounded operands' products depend on it,
+            # and without it their rounding would reach ``g`` at every
+            # position ahead of the pair
+            d_ref = (jnp.sum(cols * x.kf, 0, keepdims=True)
+                     - jnp.sum(rows[:SUB] * x.kf[r0:r0 + SUB]
+                               + rows[SUB:] * x.qf[r0:r0 + SUB], 0,
+                               keepdims=True))
+            x.d_big = x.d_big + jnp.where(_iota((c, 1), 0) == r0 - 1, d_ref,
+                                          0.0)
+    for h, x in enumerate(xs):
+        keys, values = _head_slices(h, dk, dv)
+        k_rows, q_rows = (jnp.concatenate(a, 0) for a in (x.k_rows, x.q_rows))
+        k_cols = x.k_cols + jnp.concatenate(x.k_diag, 0)
+        d_big = (x.d_big + x.kf * (k_rows - k_cols) + x.qf * q_rows
+                 + jnp.where(_iota((c, 1), 0) == c - 1, x.d_last, 0.0))
+        dq_ref[0, :, keys] = (x.d_q + q_rows).astype(dq_ref.dtype)
+        dk_ref[0, :, keys] = (x.d_k + k_rows + k_cols).astype(dk_ref.dtype)
+        dv_ref[0, :, values] = x.d_v.astype(dv_ref.dtype)
+        dg_ref[0, :, keys] = _running_sum(d_big, reverse=True)
+        dbeta_ref[0, 0, 0, h:h + 1] = _row(x.d_beta)
+
+
+def _kernel_call(kernel, operands, out_like, *, starts_out: bool = False,
+                 reverse: bool = False, interpret: bool | None = None):
+    """One ``pallas_call`` over (batch, head blocks, chunks), the chunks
+    sequential and walked from the last with ``reverse``. ``operands``: q,
+    k, v, g as [B, T, H * d], beta's rows, then (backward) the chunk-start
+    states and d_o; ``out_like``: arrays whose shapes and dtypes the
+    outputs take, blocked as the operand of that shape is; ``starts_out``
+    adds the chunk-start states, [B, T / CHUNK, H, dv, dk] float32.
+    ``interpret`` None: as the attention kernels decide it (compiled on a
+    TPU, interpreted on the CPU, refused anywhere else)."""
+    from ray_tpu.ops.attention import _interpret
+
+    if interpret is None:
+        interpret = _interpret()
+    return _launch(kernel, tuple(operands),
+                   tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                         for a in out_like), starts_out, reverse, interpret)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4, 5))
+def _launch(kernel, operands, out_shape, starts_out, reverse, interpret):
+    """``_kernel_call`` behind a ``jax.jit`` of its own, for the time a
+    step takes to TRACE: a kernel's body is some ten thousand equations,
+    seconds to trace and to lower, and a train step calls each kernel in
+    every KDA layer and again wherever ``jax.checkpoint`` and the custom
+    gradient run the rule once more. Under one jitted function, the same
+    kernel at the same shapes is traced once a process and lowered once a
+    module; XLA inlines the calls, and an instruction's ``op_name`` still
+    carries the scopes of the call it came from."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ray_tpu.ops.attention import _FLASH_VMEM_MOST
+
+    q, v, beta = operands[0], operands[2], operands[4]
+    b, t = q.shape[:2]
+    heads, n = beta.shape[-2], t // CHUNK
+    h = beta.shape[1] * heads
+    dk, dv = q.shape[-1] // h, v.shape[-1] // h
+    at = (lambda j: n - 1 - j) if reverse else (lambda j: j)
+    starts_shape = (b, n, h, dv, dk)
+
+    def spec(a):
+        if a.shape == starts_shape:
+            return pl.BlockSpec((1, 1, heads, dv, dk),
+                                lambda i, hb, j: (i, at(j), hb, 0, 0))
+        if a.ndim == 5:                                 # beta's rows
+            return pl.BlockSpec((1, 1, 1, heads, CHUNK),
+                                lambda i, hb, j: (i, hb, at(j), 0, 0))
+        return pl.BlockSpec((1, CHUNK, a.shape[-1] // h * heads),
+                            lambda i, hb, j: (i, at(j), hb))
+
+    out_shape = list(out_shape)
+    if starts_out:
+        out_shape.append(jax.ShapeDtypeStruct(starts_shape, jnp.float32))
+    return pl.pallas_call(
+        functools.partial(kernel, heads=heads, dk=dk, dv=dv),
+        grid=(b, h // heads, n),
+        in_specs=[spec(a) for a in operands],
+        out_specs=[spec(a) for a in out_shape], out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((heads, dv, dk), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_MOST),
+        interpret=interpret,
+    )(*operands)
+
+
+@jax.custom_vjp
+def _kernel_rule(q, k, v, g, beta):
+    """``q``, ``k`` [B, T, H * dk], ``v`` [B, T, H * dv], ``g`` float32,
+    ``beta`` [B, H / heads, T / CHUNK, heads, CHUNK], T whole chunks -> o
+    [B, T, H * dv]."""
+    return _kernel_call(_delta_fwd_kernel, (q, k, v, g, beta), [v])[0]
+
+
+def _kernel_rule_fwd(q, k, v, g, beta):
+    o, starts = _kernel_call(_delta_fwd_kernel, (q, k, v, g, beta), [v],
+                             starts_out=True)
+    return o, (q, k, v, g, beta, starts)
+
+
+def _kernel_rule_bwd(kept, d_o):
+    return tuple(_kernel_call(_delta_bwd_kernel, (*kept, d_o), kept[:5],
+                              reverse=True))
+
+
+_kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
+
+
+_KERNEL_WIDTH = 128     # dk = dv: ONE 128-lane tile a head
+
+
+def _takes_kernels(q, v) -> bool:
+    """Whether the Pallas kernels run this call, from what can be seen of
+    it: a TPU; heads of ``_KERNEL_WIDTH``, the one width the kernels were
+    measured at and their four-head step's live set fits the 32-MiB
+    ceiling at (a wider head would fail in Mosaic where the scan runs);
+    no mesh over the operands (``_mesh_over``)."""
+    return (q.shape[-1] == v.shape[-1] == _KERNEL_WIDTH
+            and jax.devices()[0].platform == "tpu" and not _mesh_over(q))
+
+
+def _mesh_over(a) -> bool:
+    """Whether more than one device may lie under ``a``: Mosaic refuses a
+    call that XLA would have to partition, and the state runs along T,
+    which no kernel of a shard could carry. From what says it, in order: a
+    concrete array's own sharding; the mesh in force where the call is
+    traced (``jax.set_mesh``; a ``shard_map`` all of whose axes are manual
+    hands the kernel its shard, as does a mesh of one device). Traced with
+    neither, the devices the jitted function will run on cannot be seen
+    from here (``forward(mesh=...)`` puts its mesh in shardings, not in
+    force): the process's own count stands for them, and where that alone
+    keeps the kernels out the choice is logged, once."""
+    if not isinstance(a, jax.core.Tracer):
+        sharding = getattr(a, "sharding", None)     # None: the host's array
+        return sharding is not None and len(sharding.device_set) > 1
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        return mesh.size > 1 and set(mesh.manual_axes) != set(mesh.axis_names)
+    devices = len(jax.devices())
+    if devices > 1:
+        _log_once(
+            f"gated_delta_rule: traced under no mesh in a process that holds "
+            f"{devices} devices, so the XLA scan runs and not the Pallas "
+            f"kernels (a mesh may lie over the operands); for one device's "
+            f"work trace it under jax.set_mesh of that device's mesh or "
+            f"inside a shard_map")
+    return devices > 1
+
+
+@functools.cache
+def _log_once(message: str) -> None:
+    logger.warning(message)
+
+
+def _by_kernels(q, k, v, g, beta):
+    """``gated_delta_rule`` through the kernels: operands [B, T, H, d] as
+    they come, T padded to whole chunks, the heads in blocks of
+    ``_KERNEL_HEADS`` where they come in fours."""
+    b, t, h, _ = q.shape
+    pad = -t % CHUNK
+    heads = next(n for n in (_KERNEL_HEADS, 2, 1) if h % n == 0)
+
+    def flat(a):
+        if pad:
+            a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return a.reshape(b, t + pad, -1)
+
+    rows = jnp.transpose(
+        flat(beta.astype(jnp.float32)).reshape(
+            b, (t + pad) // CHUNK, CHUNK, h // heads, heads), (0, 3, 1, 4, 2))
+    o = _kernel_rule(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)),
+                     rows)
+    return o.reshape(b, t + pad, h, -1)[:, :t]
+
+
 def gated_delta_rule(q, k, v, g, beta):
     """The gated delta rule in chunks (module docstring): ``q``, ``k`` [B,
     T, H, dk], ``v`` [B, T, H, dv], ``g`` [B, T, H, dk] float32 (the
@@ -339,7 +872,14 @@ def gated_delta_rule(q, k, v, g, beta):
     in ``q``'s dtype, ``S_0 = 0``. A ``T`` that is no whole number of
     chunks is padded behind the row with tokens that write nothing
     (``beta`` = 0) and forget nothing (``g`` = 0): no real token sees
-    them. Differentiable in all five operands."""
+    them. Differentiable in all five operands. The Pallas kernels where
+    ``_takes_kernels`` finds their case, else the scan in plain XLA."""
+    if _takes_kernels(q, v):
+        return _by_kernels(q, k, v, g, beta)
+    return _by_scan(q, k, v, g, beta)
+
+
+def _by_scan(q, k, v, g, beta):
     b, t, h, _ = q.shape
     pad = -t % CHUNK
 
