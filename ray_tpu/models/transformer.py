@@ -1234,7 +1234,13 @@ def _kda_mixer(h, w, c: TransformerConfig):
     (o [B, T, H, dv], the most negative cumulative log-decay inside any
     chunk). ``attn_qkv`` the three projections, ``attn_core`` the chunked
     delta rule and nothing else; convolutions, gates and the gated head
-    norm open their scopes in ``ops/linear_attention.py``."""
+    norm open their scopes in ``ops/linear_attention.py``. Between the
+    gates and the rule ``g`` is FLAT, [B, T, H * dk] float32, a head a
+    128-lane slice with 8 tokens in a tile's sublanes, as the rule's
+    kernels read it, and the head norm works on the rule's ``o`` in the
+    same tiling: a 268-MB float32 array that changes its tiling costs a
+    pass over HBM each way, 44 of them a step before PR 43
+    (``ops/linear_attention.py``'s docstring)."""
     dt = c.compute_dtype
     with jax.named_scope("attn_qkv"):
         q, k, v = (jnp.einsum("btd,dhk->bthk", h, w[name].astype(dt))

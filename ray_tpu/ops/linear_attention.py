@@ -66,6 +66,25 @@ batched form is bound by its intermediates' trips through HBM; one
 chunk's (32 heads of [64, 128]) stay in fast memory, and forward +
 backward take half the time.
 
+**Between the gate code and the rule the arrays are FLAT**: ``g`` [B, T,
+H * dk] float32 into the rule, ``o`` out of it into the head norm, a head
+a 128-lane slice and 8 TOKENS in a (8, 128) tile's sublanes, which is how
+the kernels' blocks ``[1, CHUNK, heads * d]`` read and write them. By
+heads, [B, T, H, d], XLA tiles 8 HEADS in the sublanes, and every
+crossing of a 268-MB float32 array between the two tilings is a pass over
+HBM of 0.82 ms at [1, 16384, 32, 128]: after PR 40 a train step of four
+KDA layers made 44 of them (16 for ``g`` and its gradient, 28 for ``o``
+into ``gated_head_norm``, its statistic's broadcast and its output gate),
+none of them arithmetic. So ``gates`` and ``gated_head_norm`` make their
+low-rank maps by ONE plain matmul against ``f_b`` / ``g_b`` viewed [r, H *
+d] (the contraction ``btr,rhk->bthk`` is, whose result XLA writes by
+heads), take per-head vectors as [H * d] views, and the head norm's mean
+over a head's lanes is a product with ``_head_lanes``; ``gated_delta_rule``
+takes ``g`` flat or by heads and tells them apart by rank. The parameter
+leaves keep their shapes: the views are of a megabyte of weights
+(PERF.md section 6, PR 43; ``tests/test_kda_layout.py`` holds the step
+compiled for a described v5e to it).
+
 ``SCOPES`` are the named scopes this file opens around the parts of a KDA
 layer's mixer that are neither projections nor the delta rule
 (``ray_tpu/models/transformer.py`` opens ``attn_linear`` and the rest):
@@ -129,22 +148,43 @@ def conv_silu(q, k, v, w_q, w_k, w_v):
 
 
 def gates(h, w):
-    """(``g`` [B, T, H, dk] float32, the log-decay per channel ``-exp(A_log)
-    x softplus(W_f2 (W_f1 h) + dt_bias)``; ``beta`` [B, T, H] float32,
-    ``sigmoid(W_b h)``) of the normed input ``h`` [B, T, D] (scope
-    ``kda_gate``). ``w``: ``f_a`` [D, r], ``f_b`` [r, H, dk], ``dt_bias``
-    [H, dk], ``A_log`` [H], ``w_beta`` [D, H]."""
+    """(``g`` [B, T, H * dk] float32, the log-decay per channel ``-exp(A_log)
+    x softplus(W_f2 (W_f1 h) + dt_bias)``, FLAT as the delta rule's kernels
+    read it (module docstring); ``beta`` [B, T, H] float32, ``sigmoid(W_b
+    h)``) of the normed input ``h`` [B, T, D] (scope ``kda_gate``). ``w``:
+    ``f_a`` [D, r], ``f_b`` [r, H, dk], ``dt_bias`` [H, dk], ``A_log`` [H],
+    ``w_beta`` [D, H]: the leaves keep their shapes, the flat views are
+    taken here. ``f`` is ONE plain matmul ``[B T, r] x [r, H * dk]``,
+    bfloat16 operands summed in float32, whose result XLA writes with 8
+    tokens in a tile's sublanes; ``g`` goes to ``gated_delta_rule`` with no
+    reshape, transpose or copy, and its gradient comes back from the
+    kernels flat and reaches softplus', the bias', ``A_log``'s and both
+    matmuls' gradients flat."""
     dt = h.dtype
+    rank, _, dk = w["f_b"].shape
     with jax.named_scope("kda_gate"):
         low = jnp.einsum("btd,dr->btr", h, w["f_a"].astype(dt))
-        f = jnp.einsum("btr,rhk->bthk", low, w["f_b"].astype(dt),
+        f = jnp.einsum("btr,rc->btc", low,
+                       w["f_b"].astype(dt).reshape(rank, -1),
                        preferred_element_type=jnp.float32)
-        g = -jnp.exp(w["A_log"].astype(jnp.float32))[:, None] * \
-            jax.nn.softplus(f + w["dt_bias"].astype(jnp.float32))
+        rate = jnp.repeat(-jnp.exp(w["A_log"].astype(jnp.float32)), dk)
+        g = rate * jax.nn.softplus(
+            f + w["dt_bias"].astype(jnp.float32).reshape(-1))
         beta = jax.nn.sigmoid(jnp.einsum(
             "btd,dh->bth", h, w["w_beta"].astype(dt),
             preferred_element_type=jnp.float32))
         return g, beta
+
+
+def _head_lanes(heads: int, d: int):
+    """[H, H * d] float32: row ``h`` is 1 over head ``h``'s ``d`` lanes of
+    a flat ``[.., H * d]`` array and 0 elsewhere. A flat array times its
+    transpose is the sum over each head's lanes, ``[.., H]`` times it puts
+    a head's number on each of its lanes: both as plain products of flat
+    arrays, at ``highest`` (six bfloat16 passes; 0 and 1 are exact, so the
+    sums are float32's), where a reduction or a broadcast over a [.., H,
+    d] VIEW makes XLA change the flat array's tiling (module docstring)."""
+    return jnp.repeat(jnp.eye(heads, dtype=jnp.float32), d, axis=1)
 
 
 def gated_head_norm(o, h, w, *, eps: float):
@@ -152,27 +192,39 @@ def gated_head_norm(o, h, w, *, eps: float):
     the delta rule's output, normed over each head's dv values with ONE
     weight ``o_norm`` [dv] all heads share, times the output gate of the
     normed input ``h`` (scope ``kda_gate``). ``w``: ``g_a`` [D, r],
-    ``g_b`` [r, H, dv], ``o_norm`` [dv]."""
+    ``g_b`` [r, H, dv], ``o_norm`` [dv]: the leaves keep their shapes.
+    Worked FLAT, [B, T, H * dv], as the delta rule's kernels write ``o``
+    (module docstring): the gate is ONE plain matmul ``[B T, r] x [r, H *
+    dv]`` (``gates``' form), the mean over a head's lanes and its spread
+    back are products with ``_head_lanes``, the rest is elementwise; all
+    float32, rounded once at the end."""
     dt = h.dtype
+    b, t, heads, dv = o.shape
     with jax.named_scope("kda_gate"):
         low = jnp.einsum("btd,dr->btr", h, w["g_a"].astype(dt))
-        gate = jnp.einsum("btr,rhk->bthk", low, w["g_b"].astype(dt),
+        gate = jnp.einsum("btr,rc->btc", low,
+                          w["g_b"].astype(dt).reshape(low.shape[-1], -1),
                           preferred_element_type=jnp.float32)
-        of = o.astype(jnp.float32)
-        normed = of * jax.lax.rsqrt(
-            jnp.mean(of * of, -1, keepdims=True) + eps)
-        return (normed * w["o_norm"].astype(jnp.float32)
-                * jax.nn.sigmoid(gate)).astype(dt)
+        of = o.astype(jnp.float32).reshape(b, t, heads * dv)
+        lanes = _head_lanes(heads, dv)
+        mean = jnp.einsum("btc,hc->bth", of * of, lanes,
+                          precision=_HIGHEST) / dv
+        scale = jnp.einsum("bth,hc->btc", jax.lax.rsqrt(mean + eps), lanes,
+                           precision=_HIGHEST)
+        weight = jnp.tile(w["o_norm"].astype(jnp.float32), heads)
+        return (of * scale * weight * jax.nn.sigmoid(gate)).astype(
+            dt).reshape(o.shape)
 
 
 def log_decay_min(g):
     """The most negative cumulative log-decay inside any chunk: how near
-    the chunked form runs to float32's range (no gradient)."""
+    the chunked form runs to float32's range (no gradient). ``g`` [B, T, H
+    * dk] or [B, T, H, dk]: the chunks' sums are over positions alone."""
     with jax.named_scope("kda_gate"):
         g = jax.lax.stop_gradient(g)
         pad = -g.shape[1] % CHUNK
         if pad:
-            g = jnp.pad(g, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            g = jnp.pad(g, ((0, 0), (0, pad)) + ((0, 0),) * (g.ndim - 2))
         return g.reshape(g.shape[0], -1, CHUNK, *g.shape[2:]).sum(2).min()
 
 
@@ -846,7 +898,8 @@ def _log_once(message: str) -> None:
 
 def _by_kernels(q, k, v, g, beta):
     """``gated_delta_rule`` through the kernels: operands [B, T, H, d] as
-    they come, T padded to whole chunks, the heads in blocks of
+    they come (a flat ``g`` [B, T, H * dk] is handed on as it is: ``flat``
+    does nothing to it), T padded to whole chunks, the heads in blocks of
     ``_KERNEL_HEADS`` where they come in fours."""
     b, t, h, _ = q.shape
     pad = -t % CHUNK
@@ -867,13 +920,16 @@ def _by_kernels(q, k, v, g, beta):
 
 def gated_delta_rule(q, k, v, g, beta):
     """The gated delta rule in chunks (module docstring): ``q``, ``k`` [B,
-    T, H, dk], ``v`` [B, T, H, dv], ``g`` [B, T, H, dk] float32 (the
-    log-decay per channel, <= 0), ``beta`` [B, T, H] -> ``o`` [B, T, H, dv]
-    in ``q``'s dtype, ``S_0 = 0``. A ``T`` that is no whole number of
-    chunks is padded behind the row with tokens that write nothing
-    (``beta`` = 0) and forget nothing (``g`` = 0): no real token sees
-    them. Differentiable in all five operands. The Pallas kernels where
-    ``_takes_kernels`` finds their case, else the scan in plain XLA."""
+    T, H, dk], ``v`` [B, T, H, dv], ``g`` float32 (the log-decay per
+    channel, <= 0), ``beta`` [B, T, H] -> ``o`` [B, T, H, dv] in ``q``'s
+    dtype, ``S_0 = 0``. ``g`` comes as [B, T, H * dk], what ``gates`` makes
+    and the kernels read (they take it with no reshape, transpose or copy,
+    and its gradient goes back flat), or as [B, T, H, dk]; its RANK says
+    which. A ``T`` that is no whole number of chunks is padded behind the
+    row with tokens that write nothing (``beta`` = 0) and forget nothing
+    (``g`` = 0): no real token sees them. Differentiable in all five
+    operands. The Pallas kernels where ``_takes_kernels`` finds their
+    case, else the scan in plain XLA."""
     if _takes_kernels(q, v):
         return _by_kernels(q, k, v, g, beta)
     return _by_scan(q, k, v, g, beta)
@@ -882,6 +938,7 @@ def gated_delta_rule(q, k, v, g, beta):
 def _by_scan(q, k, v, g, beta):
     b, t, h, _ = q.shape
     pad = -t % CHUNK
+    g = g.reshape(q.shape)      # a flat g by heads: the scan is chunk-major
 
     def lay_out(a):
         """[B, T, H, *] -> [chunks, B, H, C, *]"""

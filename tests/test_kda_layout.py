@@ -1,0 +1,258 @@
+"""The layout in which a KDA layer hands arrays between its gate code and the
+delta rule (``ray_tpu/ops/linear_attention.py`` ``gates``,
+``gated_delta_rule``, ``gated_head_norm``, ``log_decay_min``;
+``ray_tpu/models/transformer.py`` ``_kda_mixer``): FLAT, [B, T, H * d], a
+head a 128-lane slice, which is how the rule's Pallas kernels read ``g`` and
+write ``o``. On the CPU at tiny widths: the flat ``g``, the head norm and
+their gradients are the parent's ``btr,rhk->bthk`` forms' (kept here,
+``_gates_by_heads``, ``_head_norm_by_heads``), and the rule and the counter
+give the same for a rank-3 and a rank-4 ``g``. Compiled for a described
+``v5e:2x2`` device at the cell's widths: the mixer's forward, recompute and
+backward hold NO relayout of a float32 array of ``g``'s size outside the
+convolutions' chains (``kda_conv``: ROADMAP A12 (1)'s), and the same
+assertion fails on either of the parent's forms, so it sees the fault (268
+MB crossing between two tilings, 44 passes a step: PERF.md section 6, PR 40
+and PR 43).
+
+Nothing here is a speed. The topology is described inside a module-scoped
+fixture, never at import (the on-chip-measurement guide).
+"""
+
+from __future__ import annotations
+
+import math
+import os
+import re
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from ray_tpu.models import transformer
+from ray_tpu.ops import linear_attention as la
+
+F32 = jnp.float32
+
+
+def _gates_by_heads(h, w):
+    """``gates`` as the parent made it: ``g`` [B, T, H, dk] from the
+    einsum ``btr,rhk->bthk``, 8 HEADS in a tile's sublanes on the chip."""
+    dt = h.dtype
+    with jax.named_scope("kda_gate"):
+        low = jnp.einsum("btd,dr->btr", h, w["f_a"].astype(dt))
+        f = jnp.einsum("btr,rhk->bthk", low, w["f_b"].astype(dt),
+                       preferred_element_type=F32)
+        g = -jnp.exp(w["A_log"].astype(F32))[:, None] * \
+            jax.nn.softplus(f + w["dt_bias"].astype(F32))
+        beta = jax.nn.sigmoid(jnp.einsum(
+            "btd,dh->bth", h, w["w_beta"].astype(dt),
+            preferred_element_type=F32))
+        return g, beta
+
+
+def _head_norm_by_heads(o, h, w, *, eps: float):
+    """``gated_head_norm`` as the parent made it: the gate by the einsum
+    ``btr,rhk->bthk``, the mean over the last dimension of ``o`` [B, T, H,
+    dv], whose flat form the kernels write."""
+    dt = h.dtype
+    with jax.named_scope("kda_gate"):
+        low = jnp.einsum("btd,dr->btr", h, w["g_a"].astype(dt))
+        gate = jnp.einsum("btr,rhk->bthk", low, w["g_b"].astype(dt),
+                          preferred_element_type=F32)
+        of = o.astype(F32)
+        normed = of * jax.lax.rsqrt(
+            jnp.mean(of * of, -1, keepdims=True) + eps)
+        return (normed * w["o_norm"].astype(F32)
+                * jax.nn.sigmoid(gate)).astype(dt)
+
+
+def _near(a, b, tol: float = 1e-6) -> bool:
+    return float(jnp.abs(a - b).max()) <= tol * (1.0 + float(jnp.abs(b).max()))
+
+
+# -- (a) on the CPU, tiny widths ----------------------------------------------
+
+GATE_LEAVES = ("f_a", "f_b", "dt_bias", "A_log")
+
+
+def _gate_case(dtype):
+    b, t, d, r, heads, dk = 2, 40, 24, 8, 3, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 8)
+    w = {"f_a": jax.random.normal(ks[0], (d, r)) * 0.3,
+         "f_b": jax.random.normal(ks[1], (r, heads, dk)) * 0.3,
+         "dt_bias": jax.random.normal(ks[2], (heads, dk)),
+         "A_log": jnp.log(jax.random.uniform(ks[3], (heads,), minval=1.0,
+                                             maxval=16.0)),
+         "w_beta": jax.random.normal(ks[4], (d, heads))}
+    h = jax.random.normal(ks[5], (b, t, d)).astype(dtype)
+    weight = jax.random.normal(ks[6], (b, t, heads, dk))
+    return h, w, weight
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_flat_g_is_the_parents_viewed_flat(dtype):
+    h, w, weight = _gate_case(dtype)
+    g, beta = la.gates(h, w)
+    g_heads, beta_heads = _gates_by_heads(h, w)
+    assert g.shape == (*h.shape[:2], weight.shape[2] * weight.shape[3])
+    assert g.dtype == F32 and float(g.max()) <= 0.0
+    assert _near(g, g_heads.reshape(g.shape)) and _near(beta, beta_heads)
+    # the leaves keep their shapes: so do their gradients
+    loss = lambda make: lambda h, w: (  # noqa: E731
+        make(h, w)[0].reshape(weight.shape) * weight).sum()
+    flat = jax.grad(loss(la.gates), argnums=(0, 1))(h, w)
+    heads = jax.grad(loss(_gates_by_heads), argnums=(0, 1))(h, w)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2    # h's gradient is bfloat16
+    assert _near(flat[0].astype(F32), heads[0].astype(F32), tol)
+    for name in GATE_LEAVES:
+        assert flat[1][name].shape == w[name].shape
+        assert _near(flat[1][name], heads[1][name], tol), name
+
+
+# T = 37: no whole number of 8-token tiles
+@pytest.mark.parametrize("dtype,t", [(jnp.float32, 40), (jnp.float32, 37),
+                                     (jnp.bfloat16, 40)])
+def test_the_flat_head_norm_is_the_parents(dtype, t):
+    b, d, r, heads, dv = 2, 24, 8, 3, 16
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    w = {"g_a": jax.random.normal(ks[0], (d, r)) * 0.3,
+         "g_b": jax.random.normal(ks[1], (r, heads, dv)) * 0.3,
+         "o_norm": 1.0 + 0.1 * jax.random.normal(ks[2], (dv,))}
+    h = jax.random.normal(ks[3], (b, t, d)).astype(dtype)
+    o = jax.random.normal(ks[4], (b, t, heads, dv)).astype(dtype)
+    weight = jax.random.normal(ks[5], o.shape)
+    loss = lambda norm: lambda o, h, w: (  # noqa: E731
+        norm(o, h, w, eps=1e-5).astype(F32) * weight).sum()
+    flat = jax.value_and_grad(loss(la.gated_head_norm), (0, 1, 2))(o, h, w)
+    parents = jax.value_and_grad(loss(_head_norm_by_heads), (0, 1, 2))(
+        o, h, w)
+    assert la.gated_head_norm(o, h, w, eps=1e-5).shape == o.shape
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    for a, b_ in zip(jax.tree.leaves(flat), jax.tree.leaves(parents)):
+        assert a.shape == b_.shape and a.dtype == b_.dtype
+        assert _near(a.astype(F32), b_.astype(F32), tol)
+
+
+def _rule_case(t: int, heads: int, d: int):
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    ops = (la.l2_norm(jax.random.normal(ks[0], (1, t, heads, d))),
+           la.l2_norm(jax.random.normal(ks[1], (1, t, heads, d))),
+           jax.random.normal(ks[2], (1, t, heads, d)),
+           -0.3 * jax.random.uniform(ks[3], (1, t, heads, d)),
+           jax.nn.sigmoid(jax.random.normal(ks[4], (1, t, heads))))
+    return ops, jax.random.normal(ks[5], (1, t, heads, d))
+
+
+# T is no whole number of chunks: the flat g is padded as the others are
+@pytest.mark.parametrize("rule,t,heads,d", [
+    ("gated_delta_rule", 150, 3, 16),   # on the CPU: the scan
+    ("_by_scan", 150, 3, 16),
+    ("_by_kernels", 136, 2, 128),       # the kernels, interpreted
+])
+def test_the_rule_takes_g_flat_or_by_heads_and_gives_the_same(rule, t, heads,
+                                                              d):
+    ops, weight = _rule_case(t, heads, d)
+    flat = (*ops[:3], ops[3].reshape(1, t, heads * d), ops[4])
+    with jax.default_matmul_precision("highest"):
+        both = jax.jit(jax.value_and_grad(
+            lambda *a: (getattr(la, rule)(*a) * weight).sum(),
+            argnums=range(5)))
+        (o4, grads4), (o3, grads3) = both(*ops), both(*flat)
+    assert float(o3) == float(o4)
+    assert grads3[3].shape == flat[3].shape         # dg comes back flat
+    for a, b in zip(grads3, grads4):
+        assert float(jnp.abs(a.reshape(b.shape) - b).max()) == 0.0
+
+
+@pytest.mark.parametrize("t", [128, 150])
+def test_the_counter_reads_the_same_for_both(t):
+    g = _rule_case(t, 3, 16)[0][3]
+    flat = g.reshape(1, t, -1)
+    assert float(la.log_decay_min(flat)) == float(la.log_decay_min(g)) < 0
+    assert float(jax.grad(lambda g: la.log_decay_min(g))(flat).sum()) == 0.0
+
+
+# -- (b) compiled for a described v5e, the cell's widths ------------------------
+
+B, T, HEADS, DK, RANK, D_MODEL = 1, 1024, 32, 128, 128, 2304
+
+
+@pytest.fixture(scope="module")
+def chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile cannot be read back from the persistent
+    # cache without a chip: keep these out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _mixer_hlo(device) -> str:
+    """The optimised HLO of ``value_and_grad`` of ``_kda_mixer`` under
+    ``jax.checkpoint`` (forward, recompute, backward: a layer of the train
+    step) at the cell's widths, compiled for ``device``."""
+    c = transformer.kimi_linear_48b_a3b(n_layers=5)
+    assert (c.kda_heads, c.kda_head_dim, c.d_model) == (HEADS, DK, D_MODEL)
+    one = jax.sharding.SingleDeviceSharding(device)
+    w = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype, sharding=one),
+        c.shapes()["layers"]["kda"])
+    assert w["f_b"].shape == (RANK, HEADS, DK)
+    h = jax.ShapeDtypeStruct((B, T, D_MODEL), c.compute_dtype, sharding=one)
+
+    @jax.checkpoint
+    def layer(h, w):
+        o, decay_min = transformer._kda_mixer(h, w, c)
+        return jnp.square(o.astype(F32)).sum() + decay_min
+
+    # ``gated_delta_rule`` asks ``jax.devices()``, which here is the CPU's:
+    # steer it, in the test, to the described chip, as the rehearsal does
+    with mock.patch.object(jax, "devices", lambda *a, **k: [device]):
+        lowered = jax.jit(jax.value_and_grad(layer, argnums=(0, 1))).lower(
+            h, w)
+    assert "tpu_custom_call" in lowered.as_text()       # the kernels
+    return lowered.compile().as_text()
+
+
+_INSTRUCTION = re.compile(
+    r"= f32\[([\d,]+)\]\S* (copy|transpose|reshape)\(")
+
+
+def _relayouts_of_g(hlo: str) -> list[str]:
+    """Every ``copy``, ``transpose`` and ``reshape`` (one that is no
+    bitcast stays a ``reshape`` in optimised HLO) whose result is float32
+    with ``B * T * H * dk`` elements, inside fusions too; but for those of
+    the convolutions' float32 chains, whose backward XLA lays out with the
+    positions in the lanes (scope ``kda_conv``: not this contract's)."""
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTRUCTION.search(line)
+        if m and "/kda_conv/" not in line and math.prod(
+                map(int, m.group(1).split(","))) == B * T * HEADS * DK:
+            found.append(line.strip()[:400])
+    return found
+
+
+def test_no_float32_array_of_gs_size_changes_its_tiling(chip):
+    hlo = _mixer_hlo(chip)
+    assert "/kda_conv/" in hlo and "/kda_gate/" in hlo     # the scopes' names
+    assert _relayouts_of_g(hlo) == []
+
+
+@pytest.mark.parametrize("name,by_heads", [
+    ("gates", _gates_by_heads), ("gated_head_norm", _head_norm_by_heads)])
+def test_the_parents_forms_do_and_the_assertion_sees_it(chip, name, by_heads):
+    with mock.patch.object(la, name, by_heads):
+        found = _relayouts_of_g(_mixer_hlo(chip))
+    assert found, f"{name} by heads should cross between two tilings"
