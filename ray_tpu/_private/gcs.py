@@ -27,7 +27,7 @@ import uuid
 from collections import deque
 from typing import Any
 
-from ray_tpu._private import rpc
+from ray_tpu._private import rpc, worker_exit
 from ray_tpu._private.config import Config
 from ray_tpu._private.scheduler import (
     ClusterScheduler,
@@ -131,9 +131,9 @@ class WorkerRecord:
     __slots__ = (
         "worker_id", "node_id", "conn", "proc", "pid", "busy", "actor_id",
         "inflight", "started_at", "tpu_chips", "acquired", "ready", "pg_alloc",
-        "tpu_capable", "cur_rkey", "zygote", "env_key", "blocked",
+        "tpu_capable", "cur_rkey", "env_key", "blocked",
         "released_alloc", "retiring", "leased_to", "lease_deadline",
-        "lease_key", "expected_exit",
+        "lease_key", "expected_exit", "ended", "exit",
     )
 
     def __init__(self, worker_id: str, node_id: str, proc,
@@ -169,9 +169,6 @@ class WorkerRecord:
         # owner-side lease cache pipelining tasks onto leased workers,
         # normal_task_submitter.cc:29).
         self.cur_rkey: tuple | None = None
-        # Forked from the local zygote: no Popen handle, but the pid is
-        # THIS machine's — hard kills go through os.kill.
-        self.zygote = False
         # Package-env affinity (reference: runtime-env-keyed worker pool
         # caching, worker_pool.h:224): once a worker runs a task with a
         # pip/conda env, its sys.modules may cache that env's package
@@ -206,6 +203,10 @@ class WorkerRecord:
         # kills/releases this worker so its own kills never classify as
         # anonymous SIGKILLs (reference: WorkerExitType INTENDED_*).
         self.expected_exit: tuple | None = None
+        # The end of a LOCAL worker's process (Head._end_workers): set by
+        # whoever ends it first, waited on by the rest; the WorkerExit seen.
+        self.ended: threading.Event | None = None
+        self.exit = None
 
 
 class ActorRecord:
@@ -411,6 +412,10 @@ class Head:
         self._crash_fifo: deque[str] = deque()
         self.death_counts: dict[str, int] = {}
         self._oom_watch = None
+        # LOCAL workers that may have left ``workers`` with their process
+        # not yet seen gone (being ended, or dead to the head and still
+        # in teardown): shutdown() waits for these too.
+        self._undead: set[WorkerRecord] = set()
         # Per-node clock offsets (node_clock - head_clock), estimated
         # NTP-style over the agent heartbeat loop; timeline() aligns
         # cross-node spans with them.
@@ -820,7 +825,6 @@ class Head:
         logs = os.path.join(self.session_dir, "logs")
         os.makedirs(logs, exist_ok=True)
         proc = None
-        pid = None
         if not tpu_capable:
             # Fork from the pre-imported zygote (~5 ms) instead of a
             # fresh interpreter (~300 ms+): reference analogue is the
@@ -833,7 +837,9 @@ class Head:
                 os.path.join(logs, f"{worker_id}.log"))
             if pid is None and zy.deferral_active():
                 return None  # warmup imminent; retry next dispatch pass
-        if pid is None:
+            if pid is not None:
+                proc = worker_exit.PidHandle(pid)
+        if proc is None:
             with open(os.path.join(logs, f"{worker_id}.log"), "ab") as out:
                 proc = subprocess.Popen(
                     [sys.executable, "-m", "ray_tpu._private.worker"],
@@ -843,16 +849,13 @@ class Head:
                     cwd=os.getcwd(),
                 )  # child keeps its inherited fd; don't leak one per spawn
         rec = WorkerRecord(worker_id, node_id, proc, tpu_capable)
-        if pid is not None:
-            rec.pid = pid
-            rec.zygote = True
         # Best-effort cgroup v2 isolation: workers land in the node's
         # application slice (reference: cgroup_setup.h; no-op without a
         # writable cgroupfs).
         from ray_tpu._private.cgroup import CgroupSetup
 
         CgroupSetup.get_or_create(self, self.node_id).add_worker_process(
-            proc.pid if proc is not None else pid)
+            proc.pid)
         with self.lock:
             self.workers[worker_id] = rec
         return rec
@@ -1109,11 +1112,7 @@ class Head:
             # alive and still connected: tell them to exit so ghosts
             # don't keep computing against a node the scheduler already
             # buried (their in-flight tasks requeue below either way).
-            if rec.conn is not None:
-                try:
-                    rec.conn.cast("kill", {})
-                except rpc.ConnectionLost:
-                    pass
+            self._end_workers([rec])
             self._handle_worker_death(rec)
         self.dispatch_event.set()
 
@@ -3338,11 +3337,7 @@ class Head:
                     # reservation — otherwise failed creations leak
                     # CPUs/chips and a zombie process each.
                     self._release_worker_allocation(rec)
-                    if rec.conn is not None:
-                        try:
-                            rec.conn.cast("kill", {})
-                        except rpc.ConnectionLost:
-                            pass
+                    self._end_workers_soon([rec])
             # flush queued calls for this actor
             if actor is not None:
                 self._flush_actor(actor)
@@ -3815,20 +3810,11 @@ class Head:
             if rec is not None and rec.expected_exit is None:
                 rec.expected_exit = ("intended_kill",
                                      "ray_tpu.kill(actor) requested")
-        if rec is not None and rec.proc is not None:
-            rec.proc.kill()
-        elif rec is not None and rec.zygote and rec.pid:
-            try:
-                os.kill(rec.pid, 9)
-            except OSError:
-                pass
-        elif rec is not None and rec.conn is not None:
-            # Remote worker: tell it to exit; its connection drop runs the
-            # normal death handling.
-            try:
-                rec.conn.cast("kill", {})
-            except rpc.ConnectionLost:
-                pass
+        if rec is not None and (rec.proc is not None
+                                or rec.conn is not None):
+            # Its connection drop runs the normal death handling; a
+            # shutdown() right behind finds this ending and waits for it.
+            self._end_workers_soon([rec])
         else:
             with self.lock:
                 actor.state = "DEAD"
@@ -4016,10 +4002,7 @@ class Head:
             rec = self.workers.get(worker_id)
             if rec is None:
                 return {"worker_id": worker_id, "error": "unknown worker"}
-            # Zygote-forked workers have no Popen handle but ARE local
-            # (their pid is this machine's — signal via os.kill).
-            pid, node_id, local = (rec.pid, rec.node_id,
-                                   rec.proc is not None or rec.zygote)
+            pid, node_id, local = rec.pid, rec.node_id, rec.proc is not None
             agent = self.node_agents.get(node_id)
         path = os.path.join(self.session_dir, "logs", f"{worker_id}.log")
         before = 0
@@ -5043,10 +5026,7 @@ class Head:
                 and rec.env_key != env_key
                 and rec.env_key is not None
             ):
-                try:
-                    rec.conn.cast("kill", {})
-                except rpc.ConnectionLost:
-                    pass
+                self._end_workers_soon([rec])
                 return
 
     def _lease_matched_worker(self, node_id: "str | None", key: tuple,
@@ -5282,16 +5262,11 @@ class Head:
             if reused:
                 rec.actor_id = None  # back to the pool, untouched
                 return
-            if rec.proc is not None:
-                rec.proc.kill()
-            elif rec.zygote and rec.pid:
-                try:
-                    os.kill(rec.pid, 9)
-                except OSError:
-                    pass
             # Remote spawn: the worker registers, finds its record gone,
             # and exits (registration is rejected for unknown workers).
             self.workers.pop(rec.worker_id, None)
+            if rec.proc is not None:
+                self._end_workers_soon([rec])
             return
         actor.state = "STARTING"
         actor.worker_id = rec.worker_id
@@ -5511,25 +5486,65 @@ class Head:
 
     def _reap_exit_status(self, rec: WorkerRecord, wait_s: float = 0.5
                           ) -> "tuple[int | None, int | None]":
-        """(exit_code, term_signal) of a LOCAL worker. Bounded wait: the
+        """(exit_code, term_signal) of a LOCAL worker: what its ending
+        saw if it was ended (_end_workers), else a bounded wait: the
         conn close usually races the process teardown by mere
-        milliseconds, and this runs on the dead conn's reader thread."""
-        if rec.proc is not None:
-            deadline = time.time() + wait_s
-            while True:
-                rc = rec.proc.poll()
-                if rc is not None:
-                    return (rc, None) if rc >= 0 else (None, -rc)
-                if time.time() >= deadline:
-                    return None, None
-                time.sleep(0.02)
-        if rec.zygote and rec.pid:
+        milliseconds, and this runs on the dead conn's reader thread.
+        A zygote child's status is the zygote's to know."""
+        if isinstance(rec.proc, worker_exit.PidHandle):
             zy = getattr(self, "_zygote_client", None)
-            if zy is not None:
-                from ray_tpu._private.forensics import split_status
+            if zy is None:
+                return None, None
+            from ray_tpu._private.forensics import split_status
 
-                return split_status(zy.exit_status(rec.pid, wait_s=wait_s))
-        return None, None
+            return split_status(zy.exit_status(rec.pid, wait_s=wait_s))
+        if rec.ended is not None:
+            rec.ended.wait(wait_s)
+            seen = rec.exit
+            return (seen.exit_code, seen.term_signal) if seen else (None, None)
+        deadline = time.monotonic() + wait_s
+        while (rc := rec.proc.poll()) is None and time.monotonic() < deadline:
+            time.sleep(0.02)
+        # None: still there, and _undead keeps it for shutdown().
+        return worker_exit.split_returncode(rc)
+
+    def _end_workers(self, recs) -> None:
+        """The one place the head ends worker processes; returns when
+        every LOCAL one of ``recs`` is gone (worker_exit.end_workers; a
+        remote one has no handle here and gets the cast alone). The
+        first caller for a record ends it and the rest wait for that,
+        so no two threads poll one process. Blocks: with the lock held,
+        call _end_workers_soon."""
+        mine, theirs = [], []
+        with self.lock:
+            for rec in recs:
+                if rec.proc is not None and rec.ended is not None:
+                    theirs.append(rec)
+                    continue
+                mine.append(rec)
+                if rec.proc is not None:
+                    rec.ended = threading.Event()
+                    self._undead.add(rec)
+        try:
+            exits = worker_exit.end_workers(
+                (r.proc, r.conn, r.tpu_capable) for r in mine)
+            for rec, seen in zip(mine, exits):
+                rec.exit = seen
+        finally:
+            with self.lock:
+                self._undead.difference_update(mine)
+            for rec in mine:
+                if rec.ended is not None:
+                    rec.ended.set()
+        for rec in theirs:
+            rec.ended.wait(worker_exit.CHIP_RELEASE_BOUND_S + 5.0)
+
+    def _end_workers_soon(self, recs) -> None:
+        """_end_workers for callers that hold the lock or must not wait:
+        the ending runs on a thread of its own, and shutdown() and the
+        death handler wait for it."""
+        threading.Thread(target=self._end_workers, args=(recs,),
+                         daemon=True, name="worker-exit").start()
 
     def _build_crash_report(self, rec: WorkerRecord) -> dict:
         """Classify one worker death with everything the HEAD can see
@@ -5540,7 +5555,7 @@ class Head:
         one asynchronously (worker_death) and _record_crash upgrades."""
         from ray_tpu._private import forensics
 
-        local = rec.proc is not None or rec.zygote
+        local = rec.proc is not None
         exit_code = term_signal = None
         if local and (rec.expected_exit is None
                       or rec.expected_exit[0] != "node_death"):
@@ -5635,22 +5650,6 @@ class Head:
             blurb += f"\n  post-mortem stack excerpt:\n    {excerpt}"
         return blurb
 
-    @staticmethod
-    def _await_chip_holder_exit(rec: WorkerRecord, grace_s: float = 10.0
-                                ) -> None:
-        """A chip-holding LOCAL worker's connection dropped: its chips
-        go back to the pool only once the process is really gone (its
-        libtpu lock dies with it). Bounded: a process still alive after
-        the grace is killed. Remote workers have no handle here — their
-        connection drop is the exit."""
-        if rec.proc is None:
-            return
-        try:
-            rec.proc.wait(timeout=grace_s)
-        except subprocess.TimeoutExpired:
-            rec.proc.kill()
-            rec.proc.wait()
-
     def _handle_worker_death(self, rec: WorkerRecord) -> None:
         """Worker connection dropped or process died.
 
@@ -5665,8 +5664,12 @@ class Head:
         # worker dies at once there and nobody will read the reports —
         # N× (status wait + file reads) on the dying conns' reader
         # threads is pure teardown drag.
-        if rec.tpu_chips and not self._shutdown:
-            self._await_chip_holder_exit(rec)
+        # A LOCAL worker that could open the chips: they go back to the
+        # pool only once the process is really gone (at shutdown this
+        # waits for shutdown()'s own ending). A remote worker has no
+        # handle here: its connection drop is the exit.
+        if rec.tpu_capable and rec.proc is not None:
+            self._end_workers([rec])
         try:
             if self._shutdown:
                 crash = {"worker_id": rec.worker_id,
@@ -5686,6 +5689,13 @@ class Head:
             crash = self._record_crash(crash)
             blurb = self._death_blurb(crash)
             self.workers.pop(rec.worker_id, None)
+            # Dead to the head, the process maybe not yet (poll reaps):
+            # keep what is still there for shutdown() to wait for.
+            self._undead = {r for r in self._undead
+                            if r.ended is not None or r.proc.poll() is None}
+            if (rec.proc is not None and rec.ended is None
+                    and rec.proc.poll() is None):
+                self._undead.add(rec)
             getattr(self, "_pending_creation_push", {}).pop(
                 rec.worker_id, None)
             if rec.leased_to is not None:
@@ -6096,6 +6106,11 @@ class Head:
     # ------------------------------------------------------------------
 
     def shutdown(self) -> None:
+        """Stop the head. When this returns, every process the session
+        spawned on this machine is gone, reaped and not left to pid 1
+        (workers, then the zygote that reaps its forks), so the chips
+        they held are free for whoever runs next: the seconds the kernel
+        takes over a chip holder are spent here, not by that process."""
         self._shutdown = True
         vp = getattr(self, "_view_publisher", None)
         if vp is not None:
@@ -6104,9 +6119,6 @@ class Head:
             self.bulk_server.stop()
         except Exception:
             pass
-        zy = getattr(self, "_zygote_client", None)
-        if zy is not None:
-            zy.stop()
         if self._snapshot_path and self._snapshot_dirty:
             self._snapshot_now()
         if self._wal is not None:
@@ -6114,35 +6126,15 @@ class Head:
         if self.memory_monitor is not None:
             self.memory_monitor.stop()
         with self.lock:
-            workers = list(self.workers.values())
+            workers = set(self.workers.values()) | self._undead
             for rec in workers:
                 if rec.expected_exit is None:
                     rec.expected_exit = ("shutdown", "cluster shutdown")
-        for rec in workers:
-            try:
-                if rec.conn:
-                    rec.conn.cast("kill", {})
-            except rpc.ConnectionLost:
-                pass
-        deadline = time.time() + 2.0
-        for rec in workers:
-            if rec.proc is None:
-                if rec.zygote and rec.pid:
-                    # Zygote children are reaped by the zygote (SIGCHLD
-                    # ignored there); a hung one still needs the kill.
-                    try:
-                        os.kill(rec.pid, 9)
-                    except OSError:
-                        pass
-                continue
-            try:
-                rec.proc.wait(timeout=max(0.05, deadline - time.time()))
-            except subprocess.TimeoutExpired:
-                rec.proc.kill()
-                try:
-                    rec.proc.wait(timeout=1.0)
-                except subprocess.TimeoutExpired:
-                    pass
+        self._end_workers(workers)
+        # Zygote children are reaped by the zygote, so it goes last.
+        zy = getattr(self, "_zygote_client", None)
+        if zy is not None:
+            zy.stop()
         # Cgroup teardown only after the workers are gone: rmdir on a
         # populated cgroup is EBUSY.
         cg = getattr(self, "_cgroup", None)

@@ -192,7 +192,10 @@ class MemoryMonitor:
             "total_bytes": total,
             "ts": now,
         })
-        self._kill(victim)
+        # The connection close triggers _handle_worker_death →
+        # retry/restart (the OOM path reuses the crash path end to end,
+        # like the reference raylet's policy kills).
+        self._head._end_workers([victim])
         return True
 
     def _pick_victim(self, node_id: str):
@@ -232,14 +235,3 @@ class MemoryMonitor:
                     return result(r)
         return None, []
 
-    def _kill(self, victim) -> None:
-        # Kill the process; the connection close triggers
-        # _handle_worker_death → retry/restart (the OOM path reuses the
-        # crash path end to end, like the reference raylet's policy kills).
-        try:
-            if victim.proc is not None:
-                victim.proc.kill()
-            elif victim.conn is not None:
-                victim.conn.cast("kill", {})
-        except Exception:
-            pass

@@ -21,6 +21,7 @@ from collections import deque
 
 from ray_tpu._private import rpc
 from ray_tpu._private.config import GLOBAL_CONFIG
+from ray_tpu._private.worker_exit import PidHandle, end_workers
 
 
 def _host_id() -> str:
@@ -54,47 +55,6 @@ def _sys_sample() -> dict:
     return out
 
 
-class _ZygotePid:
-    """Popen-shaped handle for a worker forked by the node's zygote
-    (the zygote is the OS parent and auto-reaps; this handle can only
-    signal and poll liveness)."""
-
-    def __init__(self, pid: int):
-        self.pid = pid
-
-    def poll(self):
-        try:
-            os.kill(self.pid, 0)
-            return None
-        except OSError:
-            return 0
-
-    def send_signal(self, signum: int) -> None:
-        os.kill(self.pid, signum)
-
-    def terminate(self) -> None:
-        try:
-            os.kill(self.pid, 15)
-        except OSError:
-            pass
-
-    def kill(self) -> None:
-        try:
-            os.kill(self.pid, 9)
-        except OSError:
-            pass
-
-    def wait(self, timeout: "float | None" = None):
-        import time as _time
-
-        deadline = None if timeout is None else _time.time() + timeout
-        while self.poll() is None:
-            if deadline is not None and _time.time() > deadline:
-                raise subprocess.TimeoutExpired("zygote-child", timeout)
-            _time.sleep(0.02)
-        return 0
-
-
 class NodeAgent:
     def __init__(
         self,
@@ -110,6 +70,8 @@ class NodeAgent:
         self.head_address = head_address
         self.force_remote_objects = force_remote_objects
         self.procs: dict[str, subprocess.Popen] = {}
+        # Ids of the workers spawned able to open this host's chips.
+        self._tpu_capable: set[str] = set()
         self._exit = threading.Event()
         self._labels = labels or {}
         self._resources = self._detect_resources(num_cpus, num_tpus, resources)
@@ -238,6 +200,7 @@ class NodeAgent:
             for wid, proc in dead:
                 if self.procs.get(wid) is proc:
                     self.procs.pop(wid, None)
+                    self._tpu_capable.discard(wid)
                 try:
                     self._report_worker_death(wid, proc, oom)
                 except Exception:
@@ -251,7 +214,7 @@ class NodeAgent:
         from ray_tpu._private import forensics
 
         exit_code = term_signal = None
-        if isinstance(proc, _ZygotePid):
+        if isinstance(proc, PidHandle):
             # Forked from the node zygote: the zygote is the OS parent
             # and recorded the waitpid status in its exit file.
             zy = getattr(self, "_zygote", None)
@@ -351,22 +314,12 @@ class NodeAgent:
 
         deadline = time.time() + GLOBAL_CONFIG.agent_reconnect_grace_s
         # Old-epoch workers die with their head connections, but not
-        # instantly (one may be mid-task): give them a moment, then
-        # TERMINATE stragglers — the new epoch schedules against this
-        # node's full resources, so ghosts must not keep holding them.
-        for proc in list(self.procs.values()):
-            try:
-                proc.wait(timeout=0.5)
-            except Exception:
-                try:
-                    proc.terminate()
-                    proc.wait(timeout=2.0)
-                except Exception:
-                    try:
-                        proc.kill()
-                    except Exception:
-                        pass
+        # instantly (one may be mid-task): the new epoch schedules
+        # against this node's full resources and chips, so ghosts must
+        # be gone before it does.
+        self._end_all_workers()
         self.procs.clear()
+        self._tpu_capable.clear()
         from ray_tpu._private.retry import backoff_delays
 
         delays = backoff_delays(self._retry_policy)
@@ -751,7 +704,7 @@ class NodeAgent:
                  if k.startswith("RAY_TPU_")},
                 os.path.join(log_dir, f"{worker_id}.log"))
             if pid is not None:
-                proc = _ZygotePid(pid)
+                proc = PidHandle(pid)
         if proc is None:
             with open(os.path.join(log_dir, f"{worker_id}.log"), "ab") as out:
                 proc = subprocess.Popen(
@@ -762,6 +715,8 @@ class NodeAgent:
                     cwd=os.getcwd(),
                 )  # child keeps inherited fd; parent must not leak one per spawn
         self.procs[worker_id] = proc
+        if body.get("tpu_capable"):
+            self._tpu_capable.add(worker_id)
         # Best-effort cgroup v2 isolation (reference: cgroup_setup.h).
         from ray_tpu._private.cgroup import CgroupSetup
 
@@ -771,18 +726,18 @@ class NodeAgent:
         self._exit.wait()
         self.shutdown()
 
+    def _end_all_workers(self) -> None:
+        """This agent's workers connect to the head, not to it: they
+        are signalled by pid (worker_exit.end_workers)."""
+        end_workers((proc, None, wid in self._tpu_capable)
+                    for wid, proc in list(self.procs.items()))
+
     def shutdown(self) -> None:
+        self._end_all_workers()
+        # Zygote children are reaped by the zygote, so it goes last.
         zy = getattr(self, "_zygote", None)
         if zy is not None:
             zy.stop()
-        for proc in self.procs.values():
-            if proc.poll() is None:
-                proc.kill()
-        for proc in self.procs.values():
-            try:
-                proc.wait(timeout=2.0)
-            except Exception:
-                pass
         # Only after the workers actually exited (rmdir on a populated
         # cgroup is EBUSY).
         cg = getattr(self, "_cgroup", None)

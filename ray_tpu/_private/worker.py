@@ -453,7 +453,18 @@ class Worker:
         (_run_task) make. It only works before the first jax backend
         init, and libtpu keeps the chips until the process exits, so a
         chip lease is for life: the head retires this worker when the
-        lease ends and a second, different lease is refused here."""
+        lease ends and a second, different lease is refused here.
+
+        A lease also begins where the last holder has let go: the
+        kernel takes seconds to close a dead holder's device nodes
+        (now and then it is still at it when the process has been
+        reaped), and the holder may have belonged to a session that is
+        already over, so before this returns the nodes libtpu is about
+        to open can be opened (worker_exit.await_chips_free). A holder
+        of chip c opens the c-th of the host's nodes and no other
+        (measured on a v5e 2x2 host, PERF.md section 6, PR 46), so a
+        sibling that holds the rest of the host for good is not waited
+        for. A host with no device files probes nothing."""
         chips = list(chips)
         if self._chips == chips:
             return  # every push to a chip holder repeats its lease
@@ -461,9 +472,18 @@ class Worker:
             raise RuntimeError(
                 f"worker {self.worker_id} already holds chips "
                 f"{self._chips}; it cannot be re-pointed at {chips}")
-        self._chips = chips
-        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
+        from ray_tpu._private.worker_exit import await_chips_free
+        from ray_tpu.accelerators.tpu import (TPUAcceleratorManager,
+                                              host_chip_nodes)
 
+        host = host_chip_nodes()
+        nodes = [host[int(c)] for c in chips if int(c) < len(host)]
+        waited = await_chips_free(nodes)
+        if waited:
+            print(f"[ray_tpu] worker {self.worker_id} waited {waited:.1f} s "
+                  f"for the last holder of {', '.join(nodes)} to let go",
+                  file=sys.stderr, flush=True)
+        self._chips = chips
         TPUAcceleratorManager.set_current_process_visible_accelerator_ids(
             chips)
 

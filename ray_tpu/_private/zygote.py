@@ -97,6 +97,17 @@ def main() -> None:
         sys.stdout.flush()
 
 
+def _kill_and_reap(proc: subprocess.Popen) -> None:
+    """SIGKILL a zygote and wait for it: killed and not waited for, it
+    stays a zombie child of its client's process."""
+    proc.kill()
+    try:
+        proc.wait(timeout=10.0)
+    except subprocess.TimeoutExpired:
+        print(f"[ray_tpu] zygote pid {proc.pid} is still there 10 s "
+              f"after SIGKILL", file=sys.stderr, flush=True)
+
+
 def _read_line_bounded(fd: int, timeout_s: float) -> str:
     """Read one newline-terminated line from a raw fd within a
     deadline; raises TimeoutError on ANY stall, including mid-line."""
@@ -149,6 +160,9 @@ class ZygoteClient:
         self._stopped = False
         self._ready = threading.Event()
         self._warming = False
+        # The warmup under way, for stop(): its thread and its process.
+        self._warm_thread: "threading.Thread | None" = None
+        self._warm_proc: "subprocess.Popen | None" = None
         self._warm_started_at: "float | None" = None
         self._direct_spawns_this_warmup = 0
         self.on_ready: "Callable[[], None] | None" = None
@@ -171,8 +185,9 @@ class ZygoteClient:
             # window or burst callers all fall back to Popen storms.
             self._warm_started_at = time.monotonic()
             self._direct_spawns_this_warmup = 0
-        threading.Thread(target=self._warmup, daemon=True,
-                         name="zygote-warmup").start()
+        self._warm_thread = threading.Thread(
+            target=self._warmup, daemon=True, name="zygote-warmup")
+        self._warm_thread.start()
 
     def _warmup(self) -> None:
         """Slow path, lock-free: fork the zygote and wait for READY."""
@@ -189,16 +204,14 @@ class ZygoteClient:
                 cwd=os.getcwd(),
                 text=True,
             )
+            self._warm_proc = proc
             err.close()
             ready = proc.stdout.readline()
             if ready.strip() != "READY":
                 raise RuntimeError(f"zygote failed to start: {ready!r}")
         except Exception:
-            try:
-                if proc is not None:
-                    proc.kill()
-            except Exception:
-                pass
+            if proc is not None:
+                _kill_and_reap(proc)
             with self._lock:
                 self._failed = True
                 self._warming = False
@@ -211,10 +224,7 @@ class ZygoteClient:
             if self._stopped:
                 # stop() raced the warmup: don't publish a process
                 # nobody will ever reap.
-                try:
-                    proc.kill()
-                except Exception:
-                    pass
+                _kill_and_reap(proc)
                 return
             self._proc = proc
             self._ready.set()
@@ -288,10 +298,7 @@ class ZygoteClient:
                     pid = int(json.loads(reply)["pid"])
                 except Exception:
                     # Zygote died mid-request: restart attempt next call.
-                    try:
-                        self._proc.kill()
-                    except Exception:
-                        pass
+                    _kill_and_reap(self._proc)
                     self._proc = None
                     self._ready.clear()
         if rewarm:
@@ -325,14 +332,20 @@ class ZygoteClient:
             time.sleep(0.05)
 
     def stop(self) -> None:
+        """Kill the zygote and wait for it. Its children are ended
+        first (it is what reaps them): the callers' shutdown()s do."""
         with self._lock:
             self._stopped = True
             if self._proc is not None:
-                try:
-                    self._proc.kill()
-                except Exception:
-                    pass
+                _kill_and_reap(self._proc)
                 self._proc = None
+        # A warmup still under way has a process too: without its READY
+        # the warmup fails, reaps it and ends; wait for that.
+        thread, warming = self._warm_thread, self._warm_proc
+        if thread is not None and thread.is_alive():
+            if warming is not None:
+                warming.kill()
+            thread.join(timeout=15.0)
 
 
 if __name__ == "__main__":

@@ -127,17 +127,25 @@ class TPUAcceleratorManager:
         return {}
 
 
-def host_chip_count() -> int:
-    """Chips physically attached to this host, from its device files:
+def host_chip_nodes() -> list[str]:
+    """The device files of the chips physically attached to this host:
     /dev/accel* (v2-v4 PCI) or the numbered /dev/vfio groups (v5e+; a
     one-chip v5e VM shows /dev/vfio/1, a four-chip host /dev/vfio/0-3 —
-    the names are IOMMU groups, not chip ids). Ignores TPU_VISIBLE_CHIPS:
-    this is what a process would hold if nothing narrowed it."""
+    the names are IOMMU groups, not chip ids), in number order. libtpu
+    opens them exclusively and keeps them until its process is gone."""
     accel = glob.glob("/dev/accel*")
     if accel:
-        return len(accel)
-    return len([p for p in glob.glob("/dev/vfio/*")
-                if os.path.basename(p).isdigit()])
+        return sorted(accel)
+    return sorted((p for p in glob.glob("/dev/vfio/*")
+                   if os.path.basename(p).isdigit()),
+                  key=lambda p: int(os.path.basename(p)))
+
+
+def host_chip_count() -> int:
+    """Chips physically attached to this host, from its device files.
+    Ignores TPU_VISIBLE_CHIPS: this is what a process would hold if
+    nothing narrowed it."""
+    return len(host_chip_nodes())
 
 
 # libtpu's bounds ("x,y,z") for a process that owns a SUBSET of its
