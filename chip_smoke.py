@@ -16,6 +16,12 @@ at the full width of GPT-2 124M (``models.gpt2_small()``: 12 layers x
           (ops/linear_attention.py) at [1, 16384, 32, 128] bf16, forward
           and gradient timed, then both against the token-by-token
           recurrence on a 2,048-token prefix.
+  afmoe_step  in a chip-holding task: Trinity-Mini's block
+          (models.trinity_mini_26b_a3b: gated attention, a head's q and k
+          normed, window 2,048 and global NoPE layers behind a dense one,
+          four norms a block, 8 of 128 experts held) at published widths,
+          published layers 1 to 3, one row of 4,096: its logits against
+          chipbench/reference/afmoe.py, then one make_train_step.
   serve   serve.run(build_openai_app(...)), one one-chip replica per
           chip, concurrent POST /v1/completions through the proxy port,
           one /v1/chat/completions; each replica reports what it ran on.
@@ -56,6 +62,10 @@ FULL = dict(
     kernel_timeout_s=420.0,
     # the gated delta rule at train-kimilinear-ep32share's shape
     delta_shape=(1, 16384, 32, 128), delta_prefix=2048,
+    # train-trinitymini-ep16share's block, three of its five layers (a
+    # dense sliding one, a sliding and a full expert layer), two windows
+    afmoe=dict(n_layers=3, first_layer=1, n_dense_layers=1, vocab_size=25024,
+               experts_held=(0, 16)), afmoe_seq=4096,
     serve_model="gpt2_small", serve_slots=8, serve_seq=1024,
     n_requests=8, prompt_tokens=100, max_tokens=32,
     request_timeout_s=300.0,
@@ -67,6 +77,11 @@ TINY = dict(
     kernel_shape=(2, 64, 2, 16), kernel_batch=2,
     kernel_timeout_s=180.0,
     delta_shape=(1, 192, 2, 16), delta_prefix=128,
+    afmoe=dict(n_layers=3, first_layer=1, n_dense_layers=1, d_model=64,
+               n_heads=4, n_kv_heads=2, d_head=16, d_ff=32, d_ff_dense=32,
+               d_ff_shared=32, n_experts=8, expert_top_k=3, vocab_size=256,
+               sliding_window=16, embed_scale=8.0, experts_held=(1, 2),
+               dtype="float32"), afmoe_seq=64,
     serve_model="tiny", serve_slots=4, serve_seq=64,
     n_requests=4, prompt_tokens=20, max_tokens=4,
     request_timeout_s=120.0,
@@ -508,6 +523,82 @@ def delta_rule_phase(size: dict, platform: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# the afmoe arch's step (Trinity-Mini's block)
+
+
+def afmoe_step_body(size: dict) -> dict:
+    """Runs in a one-chip worker: the program's logits on one seeded row
+    against the plain reference's (``chipbench/reference/afmoe.py``,
+    float32, ``highest``) as the benchmark compares them (mean |d| over
+    the reference's std), then one ``make_train_step`` on that row."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from chipbench.reference import afmoe as reference
+    from ray_tpu import models
+
+    dev = jax.devices()[0]
+    t = size["afmoe_seq"]
+    cfg = models.trinity_mini_26b_a3b(**dict(size["afmoe"], max_seq_len=t))
+    out = {"platform": dev.platform, "device_kind": dev.device_kind,
+           "seq": t, "n_params": cfg.num_params(),
+           "kinds": [list(cfg.layer_kind(i)) for i in range(cfg.n_layers)]}
+    opt = optax.adamw(1e-5, weight_decay=0.1)
+    state = models.init_train_state(jax.random.PRNGKey(SEED), cfg, opt)
+    tokens = jax.random.randint(jax.random.PRNGKey(SEED + 1), (1, t + 1), 0,
+                                cfg.vocab_size)
+    z_p = jax.jit(lambda p, x: models.forward(p, x, cfg))(
+        state["params"], tokens[:, :-1]).astype(jnp.float32)
+    z_r = reference.forward(state["params"], tokens[:, :-1], cfg)
+    out["logit_rel_d"] = float(jnp.abs(z_p - z_r).mean() / jnp.std(z_r))
+    del z_p, z_r
+    lowered = jax.jit(models.make_train_step(cfg, opt)).lower(
+        state, {"tokens": tokens})
+    out["step_custom_calls"] = lowered.as_text().count("tpu_custom_call")
+    t0 = time.perf_counter()
+    step = lowered.compile()
+    out["step_compile_s"] = round(time.perf_counter() - t0, 2)
+    t0 = time.perf_counter()
+    _, metrics = step(state, {"tokens": tokens})
+    out["step_loss"] = float(metrics["loss"])
+    out["step_run_s"] = round(time.perf_counter() - t0, 4)
+    out["attn_gate_mean"] = float(metrics["attn_gate_mean"])
+    out["moe_held_share"] = float(metrics["moe_held_share"])
+    out["first_loss_expected"] = (math.log(cfg.vocab_size)
+                                  + 0.5 * cfg.d_model * 0.02 ** 2)
+    return out
+
+
+# the benchmark's limit on the same statistic (reference/_common.py)
+AFMOE_LOGIT_REL_D = 0.03
+
+
+def afmoe_step_phase(size: dict, platform: str) -> dict:
+    import ray_tpu
+
+    r = ray_tpu.get(ray_tpu.remote(num_tpus=1)(afmoe_step_body).remote(size),
+                    timeout=size["kernel_timeout_s"])
+    show("afmoe_step", r)
+    require(r["platform"] == platform,
+            f"afmoe worker ran on {r['platform']!r}, expected {platform!r}")
+    require(r["kinds"] == [[True, True], [True, True], [False, False]],
+            f"published layers 1 to 3 are S S F, the model ran {r['kinds']}")
+    require(r["logit_rel_d"] <= AFMOE_LOGIT_REL_D,
+            f"afmoe logits: mean |d| {r['logit_rel_d']} x the reference's "
+            f"std, over {AFMOE_LOGIT_REL_D}")
+    require(abs(r["step_loss"] - r["first_loss_expected"]) < 0.1,
+            f"afmoe step loss {r['step_loss']}, the init predicts "
+            f"{r['first_loss_expected']}")
+    require(abs(r["attn_gate_mean"] - 0.5) < 0.02,
+            f"attn_gate_mean {r['attn_gate_mean']} at a seeded init")
+    if platform == "tpu":
+        require(r["step_custom_calls"] >= 1,
+                f"lowered text lacks tpu_custom_call: {r}")
+    return r
+
+
+# ---------------------------------------------------------------------------
 # serve
 
 
@@ -730,6 +821,9 @@ def smoke(size: dict, platform: str = "tpu", *, watchdog: bool = False,
         with phase("delta_rule", walls):
             summary["chips_free_wait_s"].append(wait_chips_free(chips))
             summary["delta_rule"] = delta_rule_phase(size, platform)
+        with phase("afmoe_step", walls):
+            summary["chips_free_wait_s"].append(wait_chips_free(chips))
+            summary["afmoe_step"] = afmoe_step_phase(size, platform)
         with phase("serve", walls):
             summary["chips_free_wait_s"].append(wait_chips_free(chips))
             summary["serve"] = serve_phase(size, chips, platform)

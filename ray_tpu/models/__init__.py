@@ -34,6 +34,7 @@ from ray_tpu.models.transformer import (
     smallthinker_21b_a3b,
     tiny,
     tiny_moe,
+    trinity_mini_26b_a3b,
 )
 
 # Whoever imports the models compiles them: count it (llm/engine.py and
@@ -56,6 +57,7 @@ __all__ = [
     "init_train_state",
     "kanana_2_30b_a3b",
     "kimi_linear_48b_a3b",
+    "trinity_mini_26b_a3b",
     "llama2_7b",
     "llama3_8b",
     "lm_loss",

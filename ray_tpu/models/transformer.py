@@ -53,6 +53,16 @@ keeps, beside the leaves every layer has (the norms, ``attn/wo``, the
 router, the FFN: stacked over a stack's layers as ever), ONE STACK A KIND
 OF MIXER for the leaves only that kind has (``kda/*``, ``mla/*``:
 stacked over the layers of that kind).
+And an ``afmoe``-shaped model (Trinity): a **gate on attention's
+output** (``attn_gate``: ``sigmoid(W_g h)``, a value a query head and
+column, multiplies what the kernels return ahead of ``wo``), **QK-norm a
+head** (``qk_norm="head"``: ONE ``head_dim``-wide weight for all the
+query heads of a layer and one for its key heads, ahead of RoPE),
+**norms on the sublayers' outputs** (``post_norm``: four norms a block),
+a **scaled embedding** (``embed_scale``), and a ``layer_pattern``
+**anchored to the published layer numbers** (``first_layer``), which may
+then stand behind leading dense layers (the dense stack's layers take
+their kinds from the same pattern) and start or stop mid-period.
 With a period of P > 1 the scan runs over
 WHOLE PERIODS and unrolls a period's P layers in its body, so each
 position's kind is static: a windowed layer compiles to the kernel that
@@ -131,9 +141,15 @@ MIXER_STACKS = {"kda": "kda", "attn": "mla"}
 # latent form adds outside the kernels (down-projection, the latent's
 # norm, up-projection, RoPE on the rotary parts).
 MLA_SCOPE = "mla_latent"
+# Inside ``attn``: the output gate (its projection, the sigmoid and the
+# product, every pass). Inside ``attn`` and inside ``mlp`` / ``moe``: the
+# norm of the sublayer's output and the residual add behind it.
+GATE_SCOPE = "attn_gate"
+POST_NORM_SCOPE = "post_norm"
 # Inside ``attn``, whatever its kind: what attention does, part by part.
 # ``attn_qkv`` the projections into it (latent attention: the query's
-# alone, the latent's are ``mla_latent``), ``attn_pos`` QK-norm and RoPE
+# alone, the latent's are ``mla_latent``), ``attn_pos`` QK-norm (of all
+# heads together or a head) and RoPE
 # (latent attention: nested inside ``mla_latent``), ``attn_gqa`` k and v
 # repeated to the query heads, ``attn_core`` the one ``attention(...)``
 # call (the kernels and what ``ops.attention.SCOPES`` names around them,
@@ -229,9 +245,11 @@ class TransformerConfig:
     expert_norm_topk: bool = True
     router_aux_weight: float = 0.01  # x the load-balance term
     router_z_weight: float = 0.0     # x mean logsumexp(router logits)^2
-    # RMSNorm with a learned weight over the WHOLE q and k projections
-    # (all heads together), before the split into heads and RoPE (OLMoE).
-    qk_norm: bool = False
+    # RMSNorm with a learned weight on q and k ahead of RoPE. True: over
+    # the WHOLE projection, all heads together (OLMoE). "head": over each
+    # head's ``head_dim`` values, ONE weight for all the query heads of a
+    # layer and one for its key heads (Trinity).
+    qk_norm: bool | str = False
     # -- what describes a model whose layers are not all alike (llama arch)
     d_head: int | None = None        # a head's width; default d_model/n_heads
     norm_eps: float = 1e-5           # RMSNorm / LayerNorm epsilon
@@ -239,6 +257,15 @@ class TransformerConfig:
     # position (windowed, rope). Empty: every layer is the arch's own
     # (full causal attention; RoPE in the llama arch).
     layer_pattern: tuple[tuple[bool, bool], ...] = ()
+    # The PUBLISHED number of the model's first layer, which anchors the
+    # pattern to the published numbering: layer i is position
+    # ``(first_layer + i) % len``. A number: any n_layers, and leading
+    # dense layers take their kinds from the pattern too. None runs as 0
+    # and is its own state because it says less: the caller has not said
+    # where in the published numbering the stack stands, so a part-period
+    # and a dense stack ahead of the pattern are refused (a SmallThinker
+    # of 6 layers is a slip, not a cut).
+    first_layer: int | None = None
     sliding_window: int | None = None  # keys a query of a windowed layer sees
     expert_activation: str = "silu"  # "silu" (SwiGLU) | "relu" (ReGLU)
     # What the router reads: "mlp_norm" (the experts' own input) or
@@ -293,6 +320,16 @@ class TransformerConfig:
     # Latent attention rotates its ``d_head_rope``-wide parts (RoPE); False:
     # no positional encoding at all, the part is a plain shared key.
     latent_rope: bool = True
+    # -- an afmoe-shaped model (llama arch, plain attention) ---------------
+    # Attention's output, a query head and column, times ``sigmoid(W_g
+    # h)`` of the block's normed input, ahead of ``wo`` (``attn/wg``).
+    attn_gate: bool = False
+    # RMSNorm with a learned weight on each sublayer's OUTPUT, ahead of
+    # the residual add (``ln1_post``, ``ln2_post``): four norms a block.
+    post_norm: bool = False
+    # x the embedding's rows as they enter the stream (muP: sqrt(d_model));
+    # the head is not scaled.
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         # A config file's JSON gives lists: keep the config hashable.
@@ -341,7 +378,8 @@ class TransformerConfig:
             return (False, self.latent_rope)
         if not self.layer_pattern:
             return None
-        return self.layer_pattern[i % len(self.layer_pattern)]
+        return self.layer_pattern[((self.first_layer or 0) + i)
+                                  % len(self.layer_pattern)]
 
     @property
     def ffn_dim(self) -> int:
@@ -542,6 +580,40 @@ def kimi_linear_48b_a3b(**kw) -> TransformerConfig:
     )
 
 
+def trinity_mini_26b_a3b(**kw) -> TransformerConfig:
+    """Trinity-Mini (arcee-ai ``config.json``, ``model_type`` ``afmoe``;
+    the public implementation is ``transformers``' ``modeling_afmoe.py``):
+    32 layers, the first two dense (SwiGLU 6,144); 32 query heads on 4 key
+    / value heads of 128 over a 2,048-wide model, each head's q and k
+    RMS-normed (one weight for a layer's query heads, one for its key
+    heads), the kernels' output gated by ``sigmoid(W_g h)``; three layers
+    of four windowed (2,048 keys, RoPE theta 1e4), the fourth global with
+    NO positional encoding; a norm on each sublayer's input AND output;
+    the embedding times sqrt(2,048); 128 SwiGLU experts 1,024 wide, 8 a
+    token by a sigmoid router whose bias only the choice sees, gates
+    renormalised and scaled by 2.826, one shared expert; no router loss
+    term. ``first_layer=f, n_layers=n, n_dense_layers=k`` takes the
+    published layers f to f + n - 1, the first k of them dense. The bias's
+    rule is DeepSeek-V3's (arXiv:2412.19437) at ``load_balance_coeff``."""
+    window, full = (True, True), (False, False)
+    return replace(
+        TransformerConfig(
+            vocab_size=200192, n_layers=32, d_model=2048, n_heads=32,
+            n_kv_heads=4, d_head=128, d_ff=1024, max_seq_len=131072,
+            arch="llama", rope_theta=1e4, norm_eps=1e-5,
+            sliding_window=2048, layer_pattern=(window, window, window, full),
+            first_layer=0, qk_norm="head", attn_gate=True, post_norm=True,
+            embed_scale=math.sqrt(2048), n_dense_layers=2, d_ff_dense=6144,
+            d_ff_shared=1024, n_experts=128, expert_top_k=8,
+            expert_capacity_factor=None, expert_norm_topk=True,
+            router_aux_weight=0.0, router_z_weight=0.0,
+            router_score="sigmoid", router_bias=True, router_bias_rate=1e-3,
+            expert_gate_scale=2.826,
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -579,6 +651,9 @@ def _check_config(c: TransformerConfig) -> None:
     """Refuse, by name, a combination the program does not run."""
     if c.n_experts > 0 and c.arch != "llama":
         raise ValueError("MoE (n_experts > 0) requires arch='llama'")
+    if c.qk_norm not in (False, True, "head"):
+        raise ValueError("qk_norm must be False, True (all heads together) "
+                         "or 'head'")
     if c.qk_norm and c.arch != "llama":
         raise ValueError("qk_norm requires arch='llama'")
     patterned = (c.layer_pattern or c.sliding_window is not None
@@ -586,10 +661,25 @@ def _check_config(c: TransformerConfig) -> None:
     if patterned and c.arch != "llama":
         raise ValueError("layer_pattern, sliding_window and d_head require "
                          "arch='llama'")
-    if c.layer_pattern and c.n_layers % len(c.layer_pattern):
+    if c.first_layer is not None and (not c.layer_pattern
+                                      or c.first_layer < 0):
+        raise ValueError(
+            "first_layer is the published number (>= 0) of the first layer "
+            f"of a layer_pattern: got first_layer={c.first_layer} with "
+            f"layer_pattern={c.layer_pattern}")
+    if (c.layer_pattern and c.first_layer is None
+            and c.n_layers % len(c.layer_pattern)):
         raise ValueError(
             f"n_layers={c.n_layers} is not a whole number of periods of "
-            f"{len(c.layer_pattern)} layers (layer_pattern)")
+            f"{len(c.layer_pattern)} layers (layer_pattern; first_layer "
+            f"anchors a pattern that starts or stops mid-period)")
+    for name, wrong in (("attn_gate", c.attn_gate),
+                        ("post_norm", c.post_norm)):
+        if wrong and c.arch != "llama":
+            raise ValueError(f"{name} requires arch='llama'")
+    if c.attn_gate and (c.kv_latent is not None or c.layer_mixers):
+        raise ValueError("attn_gate gates plain attention's output: it does "
+                         "not run with kv_latent or layer_mixers")
     windowed = any(w for w, _ in c.layer_pattern)
     if windowed != (c.sliding_window is not None):
         raise ValueError(
@@ -653,12 +743,14 @@ def _check_config(c: TransformerConfig) -> None:
             if wrong:
                 raise ValueError(f"layer_mixers does not run with {name}")
     if c.n_dense_layers:
-        if (c.n_experts == 0 or c.layer_pattern or c.d_ff_dense is None
+        unanchored = bool(c.layer_pattern) and c.first_layer is None
+        if (c.n_experts == 0 or unanchored or c.d_ff_dense is None
                 or not 0 < c.n_dense_layers < c.n_layers):
             raise ValueError(
                 "n_dense_layers are the first of an expert model's n_layers "
-                "(n_experts > 0, no layer_pattern, 0 < n_dense_layers < "
-                "n_layers) and need their FFN width d_ff_dense")
+                "(n_experts > 0, no layer_pattern that first_layer does not "
+                "anchor, 0 < n_dense_layers < n_layers) and need their FFN "
+                "width d_ff_dense")
     if c.experts_held is not None:      # raises where they do not divide
         moe.held_range(c.n_experts, *c.experts_held)
 
@@ -693,6 +785,8 @@ def init_params(rng, config: TransformerConfig):
     more = iter(jax.random.split(jax.random.fold_in(rng, 1), 16))
     # ... and so does what a model with ``layer_mixers`` adds.
     third = iter(jax.random.split(jax.random.fold_in(rng, 2), 32))
+    # ... and the gate on attention's output.
+    fourth = iter(jax.random.split(jax.random.fold_in(rng, 3), 2))
 
     def norm(key, *shape, s=std):
         return (jax.random.normal(key, shape, jnp.float32) * s).astype(pdt)
@@ -702,12 +796,21 @@ def init_params(rng, config: TransformerConfig):
 
     def attn_stack(keys, n):
         if c.kv_latent is None:
-            return {
+            stack = {
                 "wq": norm(next(keys), n, D, H, Dh),
                 "wk": norm(next(keys), n, D, KV, Dh),
                 "wv": norm(next(keys), n, D, KV, Dh),
                 "wo": norm(next(keys), n, H, Dh, D, s=res_std),
             }
+            if c.qk_norm:
+                per_head = c.qk_norm == "head"
+                stack["q_norm"] = jnp.ones((n, Dh if per_head else H * Dh),
+                                           pdt)
+                stack["k_norm"] = jnp.ones((n, Dh if per_head else KV * Dh),
+                                           pdt)
+            if c.attn_gate:
+                stack["wg"] = norm(next(fourth), n, D, H, Dh)
+            return stack
         # [latent ; the one rotary key] down, the latent's norm, then
         # [k_nope ; v] of every head up.
         return {
@@ -754,6 +857,11 @@ def init_params(rng, config: TransformerConfig):
                                      s=res_std)}
         return stacks
 
+    def block_norms(n):
+        names = ("ln1", "ln2") + (("ln1_post", "ln2_post") if c.post_norm
+                                  else ())
+        return {name: {"w": jnp.ones((n, D), pdt)} for name in names}
+
     def ffn_stack(keys, n, width):
         return {
             "w_gate": norm(next(keys), n, D, width),
@@ -782,11 +890,7 @@ def init_params(rng, config: TransformerConfig):
         }
         params["final_norm"]["b"] = jnp.zeros((D,), pdt)
     else:
-        params["layers"]["ln1"] = {"w": jnp.ones((L, D), pdt)}
-        params["layers"]["ln2"] = {"w": jnp.ones((L, D), pdt)}
-        if c.qk_norm:
-            params["layers"]["attn"]["q_norm"] = jnp.ones((L, H * Dh), pdt)
-            params["layers"]["attn"]["k_norm"] = jnp.ones((L, KV * Dh), pdt)
+        params["layers"].update(block_norms(L))
         if c.n_experts > 0:
             E = c.experts_here
             params["layers"]["router"] = {
@@ -811,9 +915,7 @@ def init_params(rng, config: TransformerConfig):
         if c.n_dense_layers:
             n = c.n_dense_layers
             params["dense_layers"] = {
-                **mixer_stacks(more, 0, n),
-                "ln1": {"w": jnp.ones((n, D), pdt)},
-                "ln2": {"w": jnp.ones((n, D), pdt)},
+                **mixer_stacks(more, 0, n), **block_norms(n),
                 "mlp": ffn_stack(more, n, c.d_ff_dense),
             }
     if not c.tied:
@@ -837,6 +939,9 @@ def partition_specs(config: TransformerConfig):
         "wk": P(None, None, AXIS_TENSOR, None),
         "wv": P(None, None, AXIS_TENSOR, None),
         "wo": P(None, AXIS_TENSOR, None, None),
+        # the output gate shards by head like wq; a head's q / k norm
+        # weights are every head's (no entry: replicated)
+        "wg": P(None, None, AXIS_TENSOR, None),
         # latent attention: the down-projection and the latent's norm are
         # every head's; the up-projection shards by head like wq
         "wkv_b": P(None, None, AXIS_TENSOR, None),
@@ -991,7 +1096,9 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     experts], the batch's assignments to every expert, and
     ``bias_swapped``, the mean over the layers; with KDA layers also
     ``kda_log_decay_min``, the most negative cumulative log-decay inside
-    any chunk of any of them). ``return_hidden`` skips
+    any chunk of any of them; with an ``attn_gate`` also
+    ``attn_gate_mean``, the gate's mean over the layers).
+    ``return_hidden`` skips
     the LM head and returns the final
     normed hidden states [B, T, D] (the chunked-loss path applies the head
     itself).
@@ -1011,7 +1118,10 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
         return constrain(x, mesh, *spec) if mesh is not None else x
 
     with jax.named_scope("embed"):
-        x = params["embed"]["tokens"][tokens].astype(dt)
+        x = params["embed"]["tokens"][tokens]
+        if c.embed_scale != 1.0:        # in the parameters' precision
+            x = x * jnp.asarray(c.embed_scale, x.dtype)
+        x = x.astype(dt)
         if c.arch == "gpt2":
             if positions is None:
                 pos_emb = params["embed"]["pos"][:T]
@@ -1104,6 +1214,11 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             # [layers, experts], whether the scan stacked layers or periods
             aux["expert_counts"] = auxs["counts"].reshape(-1, c.n_experts)
             aux["bias_swapped"] = auxs["bias_swapped"].mean()
+        if c.attn_gate:
+            total = auxs["gate_mean"].sum()
+            if c.n_dense_layers:
+                total = total + dense_auxs["gate_mean"].sum()
+            aux["attn_gate_mean"] = total / c.n_layers
         if "kda" in c.layer_mixers:
             aux["kda_log_decay_min"] = auxs["log_decay_min"].min()
             if c.n_dense_layers:
@@ -1136,9 +1251,19 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     (static; None with no pattern: full causal attention, the arch's own
     positions), and names the sub-scope its attention runs under.
     ``kind`` = "kda": the token mixer is KDA, under ``attn_linear``.
-    ``dense``: one of an expert model's leading dense layers."""
+    ``dense``: one of an expert model's leading dense layers. With
+    ``post_norm`` a sublayer's output is normed under ``post_norm``,
+    inside the sublayer's own scope, and joins the stream there."""
     dt = c.compute_dtype
     experts = c.n_experts > 0 and not dense
+
+    def join(x, out, norm: str):
+        """The residual stream with a sublayer's output added to it."""
+        if not c.post_norm:
+            return x + out
+        with jax.named_scope(POST_NORM_SCOPE):
+            return x + rms_norm(out, lp[norm]["w"], eps=c.norm_eps)
+
     window = None
     if kind is not None and kind != "kda":
         windowed, with_rope = kind
@@ -1153,7 +1278,7 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     if experts and c.router_input == "attn_norm":
         with jax.named_scope("moe"):
             router = moe.router_matmul(h, lp["router"]["w"])
-    decay_min = None
+    decay_min = gate_mean = None
     with jax.named_scope("attn"), (
             contextlib.nullcontext() if kind is None else jax.named_scope(
                 ATTN_SCOPES[2 if kind == "kda" else window is not None])):
@@ -1171,9 +1296,14 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
             with jax.named_scope("attn_core"):
                 o = attention(q, k, v, causal=True, impl=c.attn_impl,
                               window=window, **shared)
+            if c.attn_gate:
+                o, gate_mean = _gate_output(o, h, lp["attn"]["wg"])
         with jax.named_scope("attn_out"):
             o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
-            x = x + o
+            if not c.post_norm:
+                x = x + o
+        if c.post_norm:
+            x = join(x, o, "ln1_post")
 
     aux = {name: jnp.zeros((), jnp.float32)
            for name in ("balance", "z", "load_max")}
@@ -1190,42 +1320,49 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                          lp["mlp"]["b_out"].astype(dt))
             x = x + m
     elif experts:
-        weights = (lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-                   lp["mlp"]["w_down"])
         with jax.named_scope("moe"):
-            if c.expert_capacity_factor is None:
-                m, aux = moe.moe_swiglu_dropless(
-                    h, *weights, top_k=c.expert_top_k,
-                    norm_topk=c.expert_norm_topk, router_logits=router,
-                    held=c.held_range, activation=c.expert_activation,
-                    score=c.router_score, select_bias=lp["router"].get("b"),
-                    gate_scale=c.expert_gate_scale)
-                if c.d_ff_shared:
-                    m = m + moe.shared_expert(
-                        h, *(lp["mlp"][f"shared_{name}"].astype(dt)
-                             for name in ("w_gate", "w_up", "w_down")))
-            else:
-                m, aux = moe.moe_swiglu(
-                    h, *weights, top_k=c.expert_top_k,
-                    capacity_factor=c.expert_capacity_factor,
-                    norm_topk=c.expert_norm_topk,
-                    # Group count n can be 1 (< data-axis size), so only
-                    # the expert dim is constrained; GSPMD lays out the
-                    # rest.
-                    constrain_fn=lambda t: con(t, None, AXIS_EXPERT, None,
-                                               None),
-                )
-            x = x + m
+            m, aux = _expert_ffn(h, lp, c, router, con)
+            x = join(x, m, "ln2_post")
     else:
         with jax.named_scope("mlp"):
             m = swiglu(h, lp["mlp"]["w_gate"].astype(dt),
                        lp["mlp"]["w_up"].astype(dt),
                        lp["mlp"]["w_down"].astype(dt))
-            x = x + m
+            x = join(x, m, "ln2_post")
+    if c.attn_gate:                     # every layer of a stack alike
+        aux = dict(aux, gate_mean=gate_mean)
     if "kda" in c.layer_mixers:         # every layer of a stack alike
         aux = dict(aux, log_decay_min=jnp.zeros((), jnp.float32)
                    if decay_min is None else decay_min)
     return x, aux
+
+
+def _expert_ffn(h, lp, c: TransformerConfig, router, con):
+    """An expert layer's FFN on its normed input ``h`` [B, T, D] -> (the
+    held experts' part of the routed sum plus the shared expert, the
+    router's statistics), AHEAD of any output norm and of the residual
+    add. ``router``: the logits, where they were made ahead of attention."""
+    dt = c.compute_dtype
+    weights = (lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
+               lp["mlp"]["w_down"])
+    if c.expert_capacity_factor is not None:
+        return moe.moe_swiglu(
+            h, *weights, top_k=c.expert_top_k,
+            capacity_factor=c.expert_capacity_factor,
+            norm_topk=c.expert_norm_topk,
+            # Group count n can be 1 (< data-axis size), so only the
+            # expert dim is constrained; GSPMD lays out the rest.
+            constrain_fn=lambda t: con(t, None, AXIS_EXPERT, None, None))
+    m, aux = moe.moe_swiglu_dropless(
+        h, *weights, top_k=c.expert_top_k, norm_topk=c.expert_norm_topk,
+        router_logits=router, held=c.held_range,
+        activation=c.expert_activation, score=c.router_score,
+        select_bias=lp["router"].get("b"), gate_scale=c.expert_gate_scale)
+    if c.d_ff_shared:
+        m = m + moe.shared_expert(
+            h, *(lp["mlp"][f"shared_{name}"].astype(dt)
+                 for name in ("w_gate", "w_up", "w_down")))
+    return m, aux
 
 
 def _kda_mixer(h, w, c: TransformerConfig):
@@ -1254,6 +1391,18 @@ def _kda_mixer(h, w, c: TransformerConfig):
             linear_attention.log_decay_min(g))
 
 
+def _gate_output(o, h, wg):
+    """Attention's output ``o`` [B, T, H, Dh] times ``sigmoid(W_g h)`` of
+    the block's normed input ``h`` [B, T, D] -> (gated o, the gate's mean:
+    0.5 at a seeded init, 0 where the gate has shut and attention is paid
+    for by nobody). The sigmoid and the product are float32."""
+    with jax.named_scope(GATE_SCOPE):
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btd,dhk->bthk", h, wg.astype(h.dtype),
+            preferred_element_type=jnp.float32))
+        return (o * gate).astype(o.dtype), jax.lax.stop_gradient(gate).mean()
+
+
 def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
     """q, k, v [B, T, H, Dh] of plain attention from the normed input
     ``h`` [B, T, D]: projections, QK-norm, RoPE, k and v repeated to the
@@ -1275,7 +1424,10 @@ def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
             k = jnp.einsum("btd,dhk->bthk", h, w["wk"].astype(dt))
             v = jnp.einsum("btd,dhk->bthk", h, w["wv"].astype(dt))
     with jax.named_scope("attn_pos"):
-        if c.qk_norm:
+        if c.qk_norm == "head":         # a head at a time, one weight
+            q = rms_norm(q, w["q_norm"], eps=c.norm_eps)
+            k = rms_norm(k, w["k_norm"], eps=c.norm_eps)
+        elif c.qk_norm:
             q = _qk_norm(q, w["q_norm"])
             k = _qk_norm(k, w["k_norm"])
         if rope is not None:
@@ -1573,8 +1725,9 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
             # the router's bias reads it and takes it out of the metrics
             metrics["moe_expert_counts"] = aux["expert_counts"]
             metrics["moe_bias_swapped"] = aux["bias_swapped"]
-    if "kda_log_decay_min" in aux:
-        metrics = dict(metrics, kda_log_decay_min=aux["kda_log_decay_min"])
+    for name in ("kda_log_decay_min", "attn_gate_mean"):
+        if name in aux:
+            metrics = dict(metrics, **{name: aux[name]})
     return loss, metrics
 
 
@@ -1736,13 +1889,19 @@ def refuse_decode(c: TransformerConfig) -> None:
     for name, value in (("kv_latent", c.kv_latent),
                         ("latent_rope", not c.latent_rope),
                         ("n_dense_layers", c.n_dense_layers),
-                        ("d_ff_shared", c.d_ff_shared)):
+                        ("d_ff_shared", c.d_ff_shared),
+                        ("qk_norm", c.qk_norm),
+                        ("attn_gate", c.attn_gate),
+                        ("post_norm", c.post_norm),
+                        ("embed_scale", c.embed_scale != 1.0
+                         and c.embed_scale)):
         if value:
             raise NotImplementedError(
                 f"KV-cache decode does not run a model with {name} "
                 f"({value!r}): the cache holds kv_heads x head_dim x 2 a "
                 f"token and every layer would be decoded as a dense one "
-                f"of plain attention")
+                f"of plain attention, its q and k not normed, its output "
+                f"neither gated nor normed, its embedding not scaled")
     if c.n_experts > 0:
         raise NotImplementedError(
             "KV-cache decode for MoE models is not implemented yet"

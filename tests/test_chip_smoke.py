@@ -57,7 +57,7 @@ def test_rehearsal_at_tiny_size_on_fake_chips():
     assert s["parent_backend_initialized"] is False
     assert s["size"] == "tiny" and s["chips"] == 2
     assert set(s["walls_s"]) == {"detect", "train", "kernel", "delta_rule",
-                                 "serve", "shutdown", "total"}
+                                 "afmoe_step", "serve", "shutdown", "total"}
     assert set(s["native_lanes"].values()) <= {"native", "python fallback"}
     cache = s["compile_cache"]
     assert cache["dir"] and cache["entries_after"] >= cache["entries_before"]
@@ -77,6 +77,12 @@ def test_rehearsal_at_tiny_size_on_fake_chips():
     assert d["shape"] == [1, 192, 2, 16] and d["prefix"] == 128
     assert set(d["errors"]) == {"o", "dq", "dk", "dv", "dg", "dbeta"}
     assert d["finite"] and d["log_decay_min"] < 0
+
+    a = s["afmoe_step"]
+    assert a["kinds"] == [[True, True], [True, True], [False, False]]
+    assert a["logit_rel_d"] < 1e-4 and a["seq"] == 64        # float32 here
+    assert abs(a["attn_gate_mean"] - 0.5) < 0.02
+    assert 0 <= a["moe_held_share"] <= 1
 
     reps = s["serve"]["replicas"]
     assert len(reps) == 2
