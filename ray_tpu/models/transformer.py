@@ -1371,16 +1371,24 @@ def _kda_mixer(h, w, c: TransformerConfig):
     (o [B, T, H, dv], the most negative cumulative log-decay inside any
     chunk). ``attn_qkv`` the three projections, ``attn_core`` the chunked
     delta rule and nothing else; convolutions, gates and the gated head
-    norm open their scopes in ``ops/linear_attention.py``. Between the
-    gates and the rule ``g`` is FLAT, [B, T, H * dk] float32, a head a
-    128-lane slice with 8 tokens in a tile's sublanes, as the rule's
-    kernels read it, and the head norm works on the rule's ``o`` in the
-    same tiling: a 268-MB float32 array that changes its tiling costs a
-    pass over HBM each way, 44 of them a step before PR 43
-    (``ops/linear_attention.py``'s docstring)."""
+    norm open their scopes in ``ops/linear_attention.py``. **From the
+    projections to the head norm every array is FLAT**, [B, T, H * d], a
+    head a 128-lane slice with 8 tokens in a tile's sublanes, as the
+    rule's kernels read q, k, v, ``g`` and write ``o``: the projections
+    are plain matmuls against ``wq`` / ``wk`` / ``wv`` viewed [D, H * d]
+    (the leaves and their sharding by head keep their shapes; viewed FIRST
+    and cast after, which is the order in which XLA takes the weights'
+    gradient from the flat ``dq`` with no transposed copy of it), the
+    convolution chains take and return flat arrays and round once, at
+    their end, and ``g`` [B, T, H * dk] float32 goes from the gates to the
+    rule as it is. An array of that size that changes its tiling costs a
+    pass over HBM each way, 44 of them a step before PR 43 and 48 more
+    before PR 49 (``ops/linear_attention.py``'s docstring;
+    ``tests/test_kda_layout.py`` holds the compiled mixer to none)."""
     dt = c.compute_dtype
     with jax.named_scope("attn_qkv"):
-        q, k, v = (jnp.einsum("btd,dhk->bthk", h, w[name].astype(dt))
+        q, k, v = (jnp.einsum("btd,dc->btc", h,
+                              w[name].reshape(c.d_model, -1).astype(dt))
                    for name in ("wq", "wk", "wv"))
     q, k, v = linear_attention.conv_silu(q, k, v, w["conv_q"], w["conv_k"],
                                          w["conv_v"])

@@ -43,7 +43,7 @@ dtype (bfloat16 in a train step) and accumulate in float32, as the
 attention kernels do.
 
 **Two implementations of that one algorithm**, chosen by what the call
-shows (``_takes_kernels``: the platform, the heads' width, the operands'
+shows (``_on_one_tpu``: the platform, the heads' width, the operands'
 sharding or the mesh in force; no option): on a TPU at 128-wide heads
 with one device under the operands, two Pallas kernels (forward and
 backward, further down: a grid over chunks whose steps keep a chunk's
@@ -85,10 +85,30 @@ leaves keep their shapes: the views are of a megabyte of weights
 (PERF.md section 6, PR 43; ``tests/test_kda_layout.py`` holds the step
 compiled for a described v5e to it).
 
+**q, k and v are flat the whole way too** (PR 49): the mixer projects them
+as plain matmuls against ``wq`` / ``wk`` / ``wv`` viewed [D, H * d], so XLA
+writes them with 8 tokens in a tile's sublanes; ``conv_silu`` takes and
+returns [B, T, H * d]; the rule reads them as they are. Each of the three
+chains (short convolution, SiLU, for q and k the l2 norm over a head's 128
+lanes) is ONE pass over HBM forward and one backward, two Pallas kernels
+(``_conv_fwd_kernel``, ``_conv_bwd_kernel``) over (batch, lane blocks, token
+tiles) that run where the rule's kernels do (``_on_one_tpu``: one rule for
+the whole mixer); elsewhere ``_chain``, the same float32 arithmetic in
+plain XLA on the flat arrays viewed by heads. Either way a chain is float32
+from the projection's bfloat16 to its own end and rounds ONCE, there: the
+kernel at its store of ``y`` (and of ``dx``; ``dw`` stays float32), the
+plain chain at its last ``astype``. By heads the chains were some twenty XLA
+fusions a layer with the backward laid out positions-in-lanes, and q, k, v
+crossed between the two tilings on the way in (unnamed transposes) and on
+the way out (copies into the kernels' tiling): 87 + 11 + 10 ms of a 720-ms
+step (PERF.md section 6, PR 49).
+
 ``SCOPES`` are the named scopes this file opens around the parts of a KDA
 layer's mixer that are neither projections nor the delta rule
 (``ray_tpu/models/transformer.py`` opens ``attn_linear`` and the rest):
-``kda_conv`` (the three convolutions, SiLU, the l2 norms) and
+``kda_conv`` (the three convolutions, SiLU, the l2 norms: on a TPU the
+chains' Pallas calls, which ``step_kda_kernel_ms`` and
+``step_attn_kernel_ms`` therefore count beside the rule's) and
 ``kda_gate`` (the decay's and the output gate's low-rank maps, ``beta``,
 softplus / exp, the head norm and the gate's product).
 """
@@ -135,16 +155,35 @@ def l2_norm(x):
 
 
 def conv_silu(q, k, v, w_q, w_k, w_v):
-    """A KDA layer's operands from its three projections [B, T, H, d]:
-    ``q, k = l2_norm(silu(short_conv(.)))``, ``v = silu(short_conv(.))``
-    (scope ``kda_conv``). Each chain runs in float32 and rounds ONCE, at
-    its end: a chain of bfloat16 steps is rounded wherever XLA happens to
-    cut its fusions, which the forward of a train step and a forward alone
-    do differently."""
+    """A KDA layer's operands from its three projections, FLAT on both
+    sides: ``q``, ``k`` [B, T, H * dk], ``v`` [B, T, H * dv] (a head a
+    slice of its ``d`` lanes), the taps ``w_*`` [K, H, d] as the leaves are
+    -> ``q, k = l2_norm(silu(short_conv(.)))``, ``v = silu(short_conv(.))``
+    in the same shapes (scope ``kda_conv``). Each chain runs in float32
+    and rounds ONCE, at its end: a chain of bfloat16 steps is rounded
+    wherever XLA happens to cut its fusions, which the forward of a train
+    step and a forward alone do differently. Where ``_on_one_tpu`` finds
+    the delta rule's kernels' case (one rule for the whole mixer), a chain
+    is ONE Pallas pass forward and one backward over the flat arrays
+    (``_chain_kernels``, further down); anywhere else ``_chain``, the same
+    arithmetic in plain XLA on the flat arrays viewed by heads, which is
+    also what the kernels are tested against."""
     with jax.named_scope("kda_conv"):
-        return (l2_norm(jax.nn.silu(_conv(q, w_q))).astype(q.dtype),
-                l2_norm(jax.nn.silu(_conv(k, w_k))).astype(k.dtype),
-                jax.nn.silu(_conv(v, w_v)).astype(v.dtype))
+        fused = (_on_one_tpu(q, w_q.shape[-1], w_v.shape[-1])
+                 and w_q.shape[0] - 1 <= _CONV_HALO)
+        chain = _chain_kernels if fused else _chain
+        return (chain(q, w_q, True), chain(k, w_k, True),
+                chain(v, w_v, False))
+
+
+def _chain(x, w, norm: bool):
+    """One chain in plain XLA: ``x`` [B, T, H * d], ``w`` [K, H, d] ->
+    ``silu(short_conv(x))``, l2-normed over each head's ``d`` lanes with
+    ``norm``; float32 throughout, rounded once to ``x``'s dtype."""
+    y = jax.nn.silu(_conv(x, w.reshape(w.shape[0], -1)))
+    if norm:
+        y = l2_norm(y.reshape(*x.shape[:2], *w.shape[1:])).reshape(x.shape)
+    return y.astype(x.dtype)
 
 
 def gates(h, w):
@@ -854,13 +893,20 @@ _KERNEL_WIDTH = 128     # dk = dv: ONE 128-lane tile a head
 
 
 def _takes_kernels(q, v) -> bool:
-    """Whether the Pallas kernels run this call, from what can be seen of
-    it: a TPU; heads of ``_KERNEL_WIDTH``, the one width the kernels were
-    measured at and their four-head step's live set fits the 32-MiB
-    ceiling at (a wider head would fail in Mosaic where the scan runs);
-    no mesh over the operands (``_mesh_over``)."""
-    return (q.shape[-1] == v.shape[-1] == _KERNEL_WIDTH
-            and jax.devices()[0].platform == "tpu" and not _mesh_over(q))
+    """Whether the Pallas kernels run a call whose operands come by heads,
+    ``q`` [.., dk] and ``v`` [.., dv] (``_on_one_tpu``)."""
+    return _on_one_tpu(q, q.shape[-1], v.shape[-1])
+
+
+def _on_one_tpu(a, dk: int, dv: int) -> bool:
+    """Whether the Pallas kernels (the delta rule's and the convolution
+    chains': one rule for the whole mixer) run a call, from what can be
+    seen of it: a TPU; heads of ``_KERNEL_WIDTH``, the one width the
+    kernels were measured at and their four-head step's live set fits the
+    32-MiB ceiling at (a wider head would fail in Mosaic where the scan
+    runs); no mesh over the operand ``a`` (``_mesh_over``)."""
+    return (dk == dv == _KERNEL_WIDTH
+            and jax.devices()[0].platform == "tpu" and not _mesh_over(a))
 
 
 def _mesh_over(a) -> bool:
@@ -896,12 +942,223 @@ def _log_once(message: str) -> None:
     logger.warning(message)
 
 
+# -- a convolution chain, as Pallas kernels ---------------------------------------
+#
+# One grid step is ``_CONV_TOKENS`` tokens of ``_CONV_LANES`` lanes (whole
+# heads) of a flat [B, T, H * d] array: tokens in the sublanes, as the delta
+# rule's kernels read their operands. A tile's K - 1 rows of HALO, the
+# tokens just ahead of it, come as a second block of the same array: its
+# last ``_CONV_HALO`` rows before the tile (zeros ahead of the row's first
+# tile), so no tile waits for another and nothing is padded in HBM. Inside
+# a step the chain runs a head's 128 lanes and ``_CONV_ROWS`` tokens at a
+# time, start to end, in Python loops, which is unrolled code: a whole
+# tile's chain at once goes through VMEM once an operation, and a
+# ``fori_loop`` waits out every block's lane sums and rsqrt (both measured:
+# PERF.md section 6, PR 49). The backward walks the tiles, and a tile's
+# blocks, from the LAST: ``dx`` of a token reads the pre-activation's
+# gradient of the K - 1 tokens behind it, which the next block is handed
+# and a scratch carries from tile to tile (``dz`` is made once a token); ``dw`` [B, K, C] float32 sums in
+# its output block, which stays in VMEM along the token axis. All
+# arithmetic float32, ONE rounding, at the store.
+
+_CONV_TOKENS = 1024
+_CONV_LANES = 512
+_CONV_ROWS = 128
+_CONV_HALO = 8          # rows of halo a tile is given: K - 1 at most
+_CONV_HALO_BLOCK = 16   # rows of the block that brings them: a whole tile
+                        # of bfloat16's (16, 128) tiling
+
+
+def _halo(ref, at: int, cols):
+    """The ``_CONV_HALO`` rows that end the ``_CONV_HALO_BLOCK`` rows of
+    ``ref`` from row ``at``, float32."""
+    block = ref[0, at:at + _CONV_HALO_BLOCK, cols].astype(_F32)
+    return block[_CONV_HALO_BLOCK - _CONV_HALO:]
+
+
+def _taps(ext, w, rows: int):
+    """(the convolution ``z`` [rows, d] of ``ext`` [_CONV_HALO + rows, d],
+    the rows under the halo ahead of them: ``_conv``'s sums in ``_conv``'s
+    order; the rows each tap read)."""
+    taps = w.shape[0]
+    read = [ext[_CONV_HALO - taps + 1 + j:][:rows] for j in range(taps)]
+    return sum(x * w[j:j + 1] for j, x in enumerate(read)), read
+
+
+def _unit_scale(s):
+    return jax.lax.rsqrt(jnp.sum(s * s, -1, keepdims=True) + L2_EPS)
+
+
+def _conv_blocks(x_ref):
+    """(tokens a block of the loop inside a tile: ``_CONV_ROWS``, or a
+    ``_CONV_HALO_BLOCK`` where a short row's tile is no whole number of
+    those; every block's first row) of a tile."""
+    tokens = x_ref.shape[1]
+    rows = _CONV_ROWS if tokens % _CONV_ROWS == 0 else _CONV_HALO_BLOCK
+    return rows, range(0, tokens, rows)
+
+
+def _conv_fwd_kernel(x_ref, ahead_ref, w_ref, y_ref, *, norm: bool, d: int):
+    import jax.experimental.pallas as pl
+
+    rows, starts = _conv_blocks(x_ref)
+    for at in range(0, x_ref.shape[2], d):
+        cols = slice(at, at + d)
+        w = w_ref[:, cols]
+        ahead = jnp.where(pl.program_id(2) == 0, 0.0,
+                          _halo(ahead_ref, 0, cols))
+        for r0 in starts:
+            here = x_ref[0, r0:r0 + rows, cols].astype(_F32)
+            z, _ = _taps(jnp.concatenate([ahead, here], 0), w, rows)
+            y = jax.nn.silu(z)
+            if norm:
+                y = y * _unit_scale(y)
+            y_ref[0, r0:r0 + rows, cols] = y.astype(y_ref.dtype)
+            ahead = here[rows - _CONV_HALO:]
+
+
+def _conv_bwd_kernel(x_ref, ahead_ref, w_ref, dy_ref, dx_ref, dw_ref, behind,
+                     *, norm: bool, d: int):
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        behind[...] = jnp.zeros_like(behind)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    rows, starts = _conv_blocks(x_ref)
+    taps = w_ref.shape[0]
+    first = pl.program_id(2) == pl.num_programs(2) - 1      # of its row
+    for at in range(0, x_ref.shape[2], d):
+        cols = slice(at, at + d)
+        w = w_ref[:, cols]
+        after = behind[:, cols]
+        sums = [jnp.zeros((8, d), _F32)] * taps
+        for r0 in reversed(starts):
+            here = x_ref[0, r0:r0 + rows, cols].astype(_F32)
+            ahead = (_halo(x_ref, r0 - _CONV_HALO_BLOCK, cols) if r0 else
+                     jnp.where(first, 0.0, _halo(ahead_ref, 0, cols)))
+            z, read = _taps(jnp.concatenate([ahead, here], 0), w, rows)
+            gate = jax.nn.sigmoid(z)
+            ds = dy_ref[0, r0:r0 + rows, cols].astype(_F32)
+            if norm:
+                # y = s / |s|: ds = (dy - y (dy . y)) / |s|
+                s = z * gate
+                scale = _unit_scale(s)
+                y = s * scale
+                ds = scale * (ds - y * jnp.sum(ds * y, -1, keepdims=True))
+            dz = ds * gate * (1.0 + z * (1.0 - gate))
+            under = jnp.concatenate([dz, after], 0)
+            dx_ref[0, r0:r0 + rows, cols] = sum(
+                under[taps - 1 - j:][:rows] * w[j:j + 1]
+                for j in range(taps)).astype(dx_ref.dtype)
+            # eight rows' sums a tap: whole registers added, no shuffles
+            sums = [total + sum((dz * x)[r:r + 8] for r in range(0, rows, 8))
+                    for total, x in zip(sums, read)]
+            after = dz[:_CONV_HALO]
+        behind[:, cols] = after
+        dw_ref[0, :, cols] += jnp.concatenate(
+            [jnp.sum(total, 0, keepdims=True) for total in sums], 0)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _conv_launch(norm, d, tile, interpret, x, w, dy=None):
+    """One chain's forward (``dy`` None: -> y) or backward (-> dx, dw [B,
+    K, C] float32) over (batch, lane blocks, token tiles), behind a
+    ``jax.jit`` of its own as ``_launch`` is: a step traces each variant
+    once. ``x`` [B, T, C] and ``tile`` (tokens, lanes) as ``_conv_call``
+    gives them; ``w`` [K, C] float32."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ray_tpu.ops.attention import _FLASH_VMEM_MOST
+
+    b, t, c = x.shape
+    rows, lanes = tile
+    n, per = t // rows, rows // _CONV_HALO_BLOCK
+    at = (lambda j: n - 1 - j) if dy is not None else (lambda j: j)
+    block = pl.BlockSpec((1, rows, lanes), lambda i, l, j: (i, at(j), l))
+    ahead = pl.BlockSpec(
+        (1, _CONV_HALO_BLOCK, lanes),
+        lambda i, l, j: (i, jnp.maximum(at(j) * per - 1, 0), l))
+    taps = pl.BlockSpec((w.shape[0], lanes), lambda i, l, j: (0, l))
+    like = jax.ShapeDtypeStruct(x.shape, x.dtype)
+    params = dict(
+        grid=(b, c // lanes, n), interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_MOST))
+    if dy is None:
+        return pl.pallas_call(
+            functools.partial(_conv_fwd_kernel, norm=norm, d=d),
+            in_specs=[block, ahead, taps], out_specs=block, out_shape=like,
+            **params)(x, x, w)
+    return pl.pallas_call(
+        functools.partial(_conv_bwd_kernel, norm=norm, d=d),
+        in_specs=[block, ahead, taps, block],
+        out_specs=[block, pl.BlockSpec((1, w.shape[0], lanes),
+                                       lambda i, l, j: (i, 0, l))],
+        out_shape=[like, jax.ShapeDtypeStruct((b, *w.shape), _F32)],
+        scratch_shapes=[pltpu.VMEM((_CONV_HALO, lanes), _F32)],
+        **params)(x, x, w, dy)
+
+
+def _conv_tokens(t: int) -> int:
+    """Tokens a grid step: ``_CONV_TOKENS``, or a shorter row's whole
+    length in ``_CONV_HALO_BLOCK``s."""
+    return min(_CONV_TOKENS, -(-t // _CONV_HALO_BLOCK) * _CONV_HALO_BLOCK)
+
+
+def _conv_call(norm: bool, d: int, x, w, dy=None):
+    """``_conv_launch`` with the tile the operands take (``x`` [B, T, C],
+    T whole ``_conv_tokens``, C whole heads of ``d`` = 128 lanes),
+    interpreted where the attention kernels are."""
+    from ray_tpu.ops.attention import _interpret
+
+    lanes = next(n for n in (_CONV_LANES, 256, 128) if x.shape[2] % n == 0)
+    return _conv_launch(norm, d, (_conv_tokens(x.shape[1]), lanes),
+                        _interpret(), x, w, dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _kernel_chain(x, w, norm, d):
+    """``x`` [B, T, C], T whole tiles; ``w`` [K, C] float32 -> the chain's
+    ``y`` [B, T, C] in ``x``'s dtype. The backward keeps ``x`` and ``w``
+    and makes the pre-activation again inside its one pass."""
+    return _conv_call(norm, d, x, w)
+
+
+def _kernel_chain_fwd(x, w, norm, d):
+    return _conv_call(norm, d, x, w), (x, w)
+
+
+def _kernel_chain_bwd(norm, d, kept, dy):
+    dx, dw = _conv_call(norm, d, *kept, dy)
+    return dx, dw.sum(0)
+
+
+_kernel_chain.defvjp(_kernel_chain_fwd, _kernel_chain_bwd)
+
+
+def _chain_kernels(x, w, norm: bool):
+    """``_chain`` through the kernels: T padded behind the row to whole
+    tiles (zeros: a causal chain's real tokens never read them, and their
+    ``dy`` is zero), the taps viewed [K, H * d] in float32."""
+    t = x.shape[1]
+    pad = -t % _conv_tokens(t)
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    y = _kernel_chain(x, w.astype(_F32).reshape(w.shape[0], -1), norm,
+                      w.shape[-1])
+    return y[:, :t]
+
+
 def _by_kernels(q, k, v, g, beta):
-    """``gated_delta_rule`` through the kernels: operands [B, T, H, d] as
-    they come (a flat ``g`` [B, T, H * dk] is handed on as it is: ``flat``
-    does nothing to it), T padded to whole chunks, the heads in blocks of
-    ``_KERNEL_HEADS`` where they come in fours."""
-    b, t, h, _ = q.shape
+    """``gated_delta_rule`` through the kernels: operands flat, [B, T, H *
+    d], or by heads as they come (``flat`` does nothing to a flat one, so
+    what ``conv_silu`` and ``gates`` make is handed on as it is), T padded
+    to whole chunks, the heads in blocks of ``_KERNEL_HEADS`` where they
+    come in fours."""
+    b, t, h = beta.shape
     pad = -t % CHUNK
     heads = next(n for n in (_KERNEL_HEADS, 2, 1) if h % n == 0)
 
@@ -922,23 +1179,27 @@ def gated_delta_rule(q, k, v, g, beta):
     """The gated delta rule in chunks (module docstring): ``q``, ``k`` [B,
     T, H, dk], ``v`` [B, T, H, dv], ``g`` float32 (the log-decay per
     channel, <= 0), ``beta`` [B, T, H] -> ``o`` [B, T, H, dv] in ``q``'s
-    dtype, ``S_0 = 0``. ``g`` comes as [B, T, H * dk], what ``gates`` makes
-    and the kernels read (they take it with no reshape, transpose or copy,
-    and its gradient goes back flat), or as [B, T, H, dk]; its RANK says
-    which. A ``T`` that is no whole number of chunks is padded behind the
+    dtype, ``S_0 = 0``. Every one of ``q``, ``k``, ``v``, ``g`` comes by
+    heads or FLAT, [B, T, H * d], what ``conv_silu`` and ``gates`` make and
+    the kernels read (they take it with no reshape, transpose or copy, and
+    its gradient goes back flat); its RANK says which, ``beta`` how many
+    heads. A ``T`` that is no whole number of chunks is padded behind the
     row with tokens that write nothing (``beta`` = 0) and forget nothing
     (``g`` = 0): no real token sees them. Differentiable in all five
-    operands. The Pallas kernels where ``_takes_kernels`` finds their
-    case, else the scan in plain XLA."""
-    if _takes_kernels(q, v):
+    operands. The Pallas kernels where ``_on_one_tpu`` finds their case,
+    else the scan in plain XLA."""
+    dk, dv = (a.shape[-1] // (beta.shape[-1] if a.ndim == 3 else 1)
+              for a in (q, v))
+    if _on_one_tpu(q, dk, dv):
         return _by_kernels(q, k, v, g, beta)
     return _by_scan(q, k, v, g, beta)
 
 
 def _by_scan(q, k, v, g, beta):
-    b, t, h, _ = q.shape
+    b, t, h = beta.shape
     pad = -t % CHUNK
-    g = g.reshape(q.shape)      # a flat g by heads: the scan is chunk-major
+    # a flat operand by heads: the scan is chunk-major
+    q, k, v, g = (a.reshape(b, t, h, -1) for a in (q, k, v, g))
 
     def lay_out(a):
         """[B, T, H, *] -> [chunks, B, H, C, *]"""

@@ -1,18 +1,20 @@
-"""The layout in which a KDA layer hands arrays between its gate code and the
-delta rule (``ray_tpu/ops/linear_attention.py`` ``gates``,
-``gated_delta_rule``, ``gated_head_norm``, ``log_decay_min``;
-``ray_tpu/models/transformer.py`` ``_kda_mixer``): FLAT, [B, T, H * d], a
-head a 128-lane slice, which is how the rule's Pallas kernels read ``g`` and
-write ``o``. On the CPU at tiny widths: the flat ``g``, the head norm and
-their gradients are the parent's ``btr,rhk->bthk`` forms' (kept here,
-``_gates_by_heads``, ``_head_norm_by_heads``), and the rule and the counter
-give the same for a rank-3 and a rank-4 ``g``. Compiled for a described
-``v5e:2x2`` device at the cell's widths: the mixer's forward, recompute and
-backward hold NO relayout of a float32 array of ``g``'s size outside the
-convolutions' chains (``kda_conv``: ROADMAP A12 (1)'s), and the same
-assertion fails on either of the parent's forms, so it sees the fault (268
-MB crossing between two tilings, 44 passes a step: PERF.md section 6, PR 40
-and PR 43).
+"""The layout in which a KDA layer hands arrays from its projections to the
+delta rule and on to its head norm (``ray_tpu/ops/linear_attention.py``
+``conv_silu``, ``gates``, ``gated_delta_rule``, ``gated_head_norm``,
+``log_decay_min``; ``ray_tpu/models/transformer.py`` ``_kda_mixer``): FLAT,
+[B, T, H * d], a head a 128-lane slice, which is how the rule's Pallas
+kernels read q, k, v, ``g`` and write ``o``. On the CPU at tiny widths: the
+flat ``g``, the head norm and their gradients are the parent's
+``btr,rhk->bthk`` forms' (kept here, ``_gates_by_heads``,
+``_head_norm_by_heads``), and the rule and the counter give the same for
+rank-3 and rank-4 operands. Compiled for a described ``v5e:2x2`` device at
+the cell's widths: the mixer's forward, recompute and backward hold NO
+relayout of an array of that size, float32 or bfloat16, and the convolution
+chains are Pallas calls under ``kda_conv``; the same assertion fails on each
+of the parent's forms (PR 43's ``gates`` and ``gated_head_norm`` by heads,
+PR 49's projections and ``conv_silu`` by heads: ``_mixer_by_heads``), so it
+sees the fault (268 MB float32 or 134 MB bfloat16 crossing between two
+tilings: PERF.md section 6, PR 40, PR 43 and PR 49).
 
 Nothing here is a speed. The topology is described inside a module-scoped
 fixture, never at import (the on-chip-measurement guide).
@@ -65,6 +67,31 @@ def _head_norm_by_heads(o, h, w, *, eps: float):
             jnp.mean(of * of, -1, keepdims=True) + eps)
         return (normed * w["o_norm"].astype(F32)
                 * jax.nn.sigmoid(gate)).astype(dt)
+
+
+def _conv_silu_by_heads(q, k, v, w_q, w_k, w_v):
+    """``conv_silu`` as the parent made it: operands [B, T, H, d], three
+    float32 chains in plain XLA."""
+    with jax.named_scope("kda_conv"):
+        return (la.l2_norm(jax.nn.silu(la._conv(q, w_q))).astype(q.dtype),
+                la.l2_norm(jax.nn.silu(la._conv(k, w_k))).astype(k.dtype),
+                jax.nn.silu(la._conv(v, w_v)).astype(v.dtype))
+
+
+def _mixer_by_heads(h, w, c):
+    """``_kda_mixer`` as the parent made it: q, k, v projected by heads
+    (``btd,dhk->bthk``: 8 HEADS in a tile's sublanes) and handed so through
+    the convolutions to the rule, whose kernels read them flat."""
+    dt = c.compute_dtype
+    with jax.named_scope("attn_qkv"):
+        q, k, v = (jnp.einsum("btd,dhk->bthk", h, w[name].astype(dt))
+                   for name in ("wq", "wk", "wv"))
+    q, k, v = _conv_silu_by_heads(q, k, v, w["conv_q"], w["conv_k"],
+                                  w["conv_v"])
+    g, beta = la.gates(h, w)
+    with jax.named_scope("attn_core"):
+        o = la.gated_delta_rule(q, k, v, g, beta)
+    return la.gated_head_norm(o, h, w, eps=c.norm_eps), la.log_decay_min(g)
 
 
 def _near(a, b, tol: float = 1e-6) -> bool:
@@ -144,24 +171,28 @@ def _rule_case(t: int, heads: int, d: int):
     return ops, jax.random.normal(ks[5], (1, t, heads, d))
 
 
-# T is no whole number of chunks: the flat g is padded as the others are
+# T is no whole number of chunks: a flat operand is padded as the others are
+@pytest.mark.parametrize("which", [(3,), (0, 1, 2, 3)], ids=["g", "qkvg"])
 @pytest.mark.parametrize("rule,t,heads,d", [
     ("gated_delta_rule", 150, 3, 16),   # on the CPU: the scan
     ("_by_scan", 150, 3, 16),
     ("_by_kernels", 136, 2, 128),       # the kernels, interpreted
 ])
 def test_the_rule_takes_g_flat_or_by_heads_and_gives_the_same(rule, t, heads,
-                                                              d):
+                                                              d, which):
+    """``g`` alone flat (PR 43) or q, k, v with it (PR 49): the same ``o``
+    and the same gradients, each in its operand's own shape."""
     ops, weight = _rule_case(t, heads, d)
-    flat = (*ops[:3], ops[3].reshape(1, t, heads * d), ops[4])
+    flat = tuple(a.reshape(1, t, heads * d) if i in which else a
+                 for i, a in enumerate(ops))
     with jax.default_matmul_precision("highest"):
         both = jax.jit(jax.value_and_grad(
             lambda *a: (getattr(la, rule)(*a) * weight).sum(),
             argnums=range(5)))
         (o4, grads4), (o3, grads3) = both(*ops), both(*flat)
     assert float(o3) == float(o4)
-    assert grads3[3].shape == flat[3].shape         # dg comes back flat
-    for a, b in zip(grads3, grads4):
+    for a, b, given in zip(grads3, grads4, flat):
+        assert a.shape == given.shape               # comes back as it went
         assert float(jnp.abs(a.reshape(b.shape) - b).max()) == 0.0
 
 
@@ -226,33 +257,56 @@ def _mixer_hlo(device) -> str:
 
 
 _INSTRUCTION = re.compile(
-    r"= f32\[([\d,]+)\]\S* (copy|transpose|reshape)\(")
+    r"= (?:f32|bf16)\[([\d,]+)\]\S* (copy|transpose|reshape)\(")
 
 
-def _relayouts_of_g(hlo: str) -> list[str]:
+def _relayouts(hlo: str) -> list[str]:
     """Every ``copy``, ``transpose`` and ``reshape`` (one that is no
     bitcast stays a ``reshape`` in optimised HLO) whose result is float32
-    with ``B * T * H * dk`` elements, inside fusions too; but for those of
-    the convolutions' float32 chains, whose backward XLA lays out with the
-    positions in the lanes (scope ``kda_conv``: not this contract's)."""
+    or bfloat16 with ``B * T * H * dk`` elements, inside fusions too,
+    under ANY scope or none: the projections' (``attn_qkv``), the
+    convolutions' (``kda_conv``), the rule's (``attn_core``), the gates'
+    and the head norm's (``kda_gate``: PR 43's contract, which the
+    parent's mixer keeps in bfloat16 too, so no scope is exempted)."""
     found = []
     for line in hlo.splitlines():
         m = _INSTRUCTION.search(line)
-        if m and "/kda_conv/" not in line and math.prod(
-                map(int, m.group(1).split(","))) == B * T * HEADS * DK:
+        if m and math.prod(map(int, m.group(1).split(","))) == (
+                B * T * HEADS * DK):
             found.append(line.strip()[:400])
     return found
 
 
-def test_no_float32_array_of_gs_size_changes_its_tiling(chip):
+def _kernels_under(hlo: str, scope: str) -> int:
+    """Pallas calls whose ``op_name`` holds ``scope``."""
+    return sum("tpu_custom_call" in line and f"/{scope}/" in line.replace(
+        f"({scope})", f"/{scope}/") for line in hlo.splitlines()
+        if " custom-call(" in line)
+
+
+def test_no_array_of_the_operands_size_changes_its_tiling(chip):
     hlo = _mixer_hlo(chip)
-    assert "/kda_conv/" in hlo and "/kda_gate/" in hlo     # the scopes' names
-    assert _relayouts_of_g(hlo) == []
+    assert "kda_conv" in hlo and "/kda_gate/" in hlo       # the scopes' names
+    assert _relayouts(hlo) == []
+    # a chain a Pallas call: three forward, three recomputed, three backward
+    assert _kernels_under(hlo, "kda_conv") == 9
+    assert _kernels_under(hlo, "attn_core") == 3
 
 
-@pytest.mark.parametrize("name,by_heads", [
-    ("gates", _gates_by_heads), ("gated_head_norm", _head_norm_by_heads)])
-def test_the_parents_forms_do_and_the_assertion_sees_it(chip, name, by_heads):
-    with mock.patch.object(la, name, by_heads):
-        found = _relayouts_of_g(_mixer_hlo(chip))
+@pytest.mark.parametrize("where,name,parents", [
+    (la, "gates", _gates_by_heads),
+    (la, "gated_head_norm", _head_norm_by_heads),
+    (transformer, "_kda_mixer", _mixer_by_heads)],
+    ids=["gates", "gated_head_norm", "_kda_mixer"])
+def test_the_parents_forms_do_and_the_assertion_sees_it(chip, where, name,
+                                                        parents):
+    with mock.patch.object(where, name, parents):
+        found = _relayouts(_mixer_hlo(chip))
     assert found, f"{name} by heads should cross between two tilings"
+    if where is transformer:
+        # q, k, v into the kernels' tiling (bfloat16, forward and recompute)
+        # and the chains' backward with the positions in the lanes (float32)
+        assert sum("bf16[" in line and "attn_core" in line
+                   for line in found) >= 6
+        assert sum("f32[" in line and "kda_conv" in line
+                   for line in found) >= 3
