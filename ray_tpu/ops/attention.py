@@ -333,23 +333,23 @@ def _visible_blocks(i, rows: int, cols: int, n_cols: int, before: int,
     hold a position some row of block ``i`` (``rows`` rows) can see, a row
     r seeing ``r - before .. r + after`` (``_window_reach``). ``i`` is a Python int
     (the grid's size) or a traced one (inside a kernel or an index map):
-    the ONE rule for which tiles a windowed kernel visits."""
+    the ONE rule for which tiles a causal kernel visits, windowed or not."""
     lo, hi = i * rows - before, i * rows + rows - 1 + after
     if isinstance(i, int):
         return max(lo, 0) // cols, min(hi // cols, n_cols - 1)
     return jnp.maximum(lo, 0) // cols, jnp.minimum(hi // cols, n_cols - 1)
 
 
-def _flash_inner(window, rows: int, cols: int, n_rows: int, n_cols: int,
-                 rows_are_queries: bool):
+def _flash_inner(causal, window, rows: int, cols: int, n_rows: int,
+                 n_cols: int, rows_are_queries: bool):
     """The inner axis of a kernel's grid: (steps a row block takes, the
-    inner block that step j of row block i reads). With no window every
-    row block walks all ``n_cols`` blocks (a causal kernel skips the
-    arithmetic above the diagonal, not the step). Under a window the
-    axis is only as long as the most blocks any row block can see, step
-    j reads the j-th of them, and a step past the last visible block
-    stays on it (no new fetch) and computes nothing."""
-    if window is None:
+    inner block that step j of row block i reads). Not causal: every row
+    block walks all ``n_cols`` blocks. Causal, with a window or none: the
+    axis is only as long as the most blocks any row block can see
+    (``n_cols`` at Tq == Tk with no window), step j reads the j-th of
+    them, and a step past the last visible block stays on it (no new
+    fetch) and computes nothing."""
+    if not causal:
         return n_cols, lambda i, j: j
     reach = _window_reach(window, rows_are_queries)
     steps = max(last - first + 1 for first, last in (
@@ -363,32 +363,35 @@ def _flash_inner(window, rows: int, cols: int, n_rows: int, n_cols: int,
     return steps, block
 
 
-def _window_reach(window: int, rows_are_queries: bool) -> tuple[int, int]:
+def _window_reach(window, rows_are_queries: bool) -> tuple[int, int]:
     """(before, after) of ``_visible_blocks``: a query sees ``window - 1``
     keys before it and none after; a key is seen by no query before it
-    and ``window - 1`` after."""
-    return (window - 1, 0) if rows_are_queries else (0, window - 1)
+    and ``window - 1`` after. No window is the widest one: a reach past
+    any row's end (positions stay inside int32), whatever Tq and Tk."""
+    reach = 2 ** 30 if window is None else window - 1
+    return (reach, 0) if rows_are_queries else (0, reach)
 
 
-def _inner_block(step, row_blk, rows: int, cols: int, n_cols: int, window,
-                 rows_are_queries: bool):
+def _inner_block(step, row_blk, rows: int, cols: int, n_cols: int, causal,
+                 window, rows_are_queries: bool):
     """Inside a kernel: (the inner block grid step ``step`` of row block
     ``row_blk`` is at, whether that step is within the row block's
-    visible blocks). With no window the step IS the block and the second
-    is None: the diagonal decides (``_when_visible``)."""
-    if window is None:
-        return step, None
+    visible blocks: all of them where not causal)."""
+    if not causal:
+        return step, True
     first, last = _visible_blocks(row_blk, rows, cols, n_cols,
                                   *_window_reach(window, rows_are_queries))
     return first + step, first + step <= last
 
 
-def _tile_is_full(q_blk, k_blk, block_q: int, block_k: int, window: int):
+def _tile_is_full(q_blk, k_blk, block_q: int, block_k: int, window):
     """Every (query, key) pair of the tile is visible: its last key is at
-    or before its first query, and its first key is inside the window of
-    its last query."""
-    return (((k_blk + 1) * block_k - 1 <= q_blk * block_q)
-            & ((q_blk + 1) * block_q - 1 - k_blk * block_k < window))
+    or before its first query, and its first key is inside the window
+    (if any) of its last query."""
+    full = (k_blk + 1) * block_k - 1 <= q_blk * block_q
+    if window is None:
+        return full
+    return full & ((q_blk + 1) * block_q - 1 - k_blk * block_k < window)
 
 
 def _tile_mask(q_blk, k_blk, block_q: int, block_k: int,
@@ -404,22 +407,18 @@ def _tile_mask(q_blk, k_blk, block_q: int, block_k: int,
 
 
 def _when_visible(compute, q_blk, k_blk, in_range, *, block_q, block_k,
-                  causal, window, diagonal):
+                  causal, window):
     """Run ``compute(masked)`` for the tile (q_blk, k_blk) if it holds a
-    visible pair. Plain causal: ``diagonal`` says so, and every computed
-    tile is masked. Under a window: ``in_range`` says so (the step is
-    within the row block's visible blocks), and only a tile the diagonal
-    or the window's edge crosses builds a mask."""
+    visible pair. Causal: ``in_range`` says so (the step is within the
+    row block's visible blocks), and only a tile the diagonal or the
+    window's edge crosses builds a mask. Not causal: every tile, no mask."""
     import jax.experimental.pallas as pl
 
-    if window is not None:
-        full = _tile_is_full(q_blk, k_blk, block_q, block_k, window)
-        pl.when(in_range & full)(lambda: compute(False))
-        pl.when(in_range & jnp.logical_not(full))(lambda: compute(True))
-    elif causal:
-        pl.when(diagonal)(lambda: compute(True))
-    else:
-        compute(False)
+    if not causal:
+        return compute(False)
+    full = _tile_is_full(q_blk, k_blk, block_q, block_k, window)
+    pl.when(in_range & full)(lambda: compute(False))
+    pl.when(in_range & jnp.logical_not(full))(lambda: compute(True))
 
 
 def _scores(q, k, q_shared, k_shared, scale):
@@ -446,7 +445,7 @@ def _flash_fwd_kernel(*refs, block_q, block_k, n_k, n_steps, causal, scale,
     q_blk = pl.program_id(1)
     step = pl.program_id(2)
     k_blk, in_range = _inner_block(step, q_blk, block_q, block_k, n_k,
-                                   window, True)
+                                   causal, window, True)
 
     @pl.when(step == 0)
     def _init():
@@ -475,12 +474,10 @@ def _flash_fwd_kernel(*refs, block_q, block_k, n_k, n_steps, causal, scale,
         )
         acc_ref[:] = acc_ref[:] * corr + pv
 
-    # Plain causal: skip blocks strictly above the diagonal (whole block
-    # masked). Windowed: the grid holds visible blocks only.
-    _when_visible(
-        _compute, q_blk, k_blk, in_range,
-        block_q=block_q, block_k=block_k, causal=causal, window=window,
-        diagonal=k_blk * block_k <= q_blk * block_q + block_q - 1)
+    # Causal: a row block's visible key blocks come first; the steps past
+    # them (above the diagonal) stay on the last and compute nothing.
+    _when_visible(_compute, q_blk, k_blk, in_range, block_q=block_q,
+                  block_k=block_k, causal=causal, window=window)
 
     @pl.when(step == n_steps - 1)
     def _emit():
@@ -527,7 +524,8 @@ def _flash_forward(q, k, v, q_shared=None, k_shared=None, *, causal, block_q,
     block_q, block_k, params = _flash_launch("fwd", q, k, block_q, block_k,
                                              v, q_shared)
     n_q, n_k = tq // block_q, tk // block_k
-    n_steps, k_of = _flash_inner(window, block_q, block_k, n_q, n_k, True)
+    n_steps, k_of = _flash_inner(causal, window, block_q, block_k, n_q, n_k,
+                                 True)
     rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
     keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
     operands = [_heads_flat(q), _heads_flat(k), _heads_flat(v)]
@@ -635,7 +633,7 @@ def _flash_bwd_dq_kernel(*refs, block_q, block_k, n_k, n_steps, causal,
     q_blk = pl.program_id(1)
     step = pl.program_id(2)
     k_blk, in_range = _inner_block(step, q_blk, block_q, block_k, n_k,
-                                   window, True)
+                                   causal, window, True)
 
     @pl.when(step == 0)
     def _init():
@@ -659,10 +657,8 @@ def _flash_bwd_dq_kernel(*refs, block_q, block_k, n_k, n_steps, causal,
                 ds, ks_ref[0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    _when_visible(
-        _compute, q_blk, k_blk, in_range,
-        block_q=block_q, block_k=block_k, causal=causal, window=window,
-        diagonal=k_blk * block_k <= q_blk * block_q + block_q - 1)
+    _when_visible(_compute, q_blk, k_blk, in_range, block_q=block_q,
+                  block_k=block_k, causal=causal, window=window)
 
     @pl.when(step == n_steps - 1)
     def _emit():
@@ -720,7 +716,7 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
     k_blk = pl.program_id(1)
     step = pl.program_id(2)
     q_blk, in_range = _inner_block(step, k_blk, block_k, block_q, n_q,
-                                   window, False)
+                                   causal, window, False)
 
     @pl.when(step == 0)
     def _init():
@@ -765,12 +761,10 @@ def _flash_bwd_dkv_kernel(*refs, block_q, block_k, n_q, n_steps, causal,
             for out, acc in zip(outs[n_keys:], accs[n_keys:]):
                 out[0] = acc[q_blk].astype(out.dtype)
 
-    # Plain causal: skip query blocks entirely ABOVE the diagonal for this
-    # key block (no query there attends to these keys).
-    _when_visible(
-        _compute, q_blk, k_blk, in_range,
-        block_q=block_q, block_k=block_k, causal=causal, window=window,
-        diagonal=q_blk * block_q + block_q - 1 >= k_blk * block_k)
+    # Causal: the walk starts at the first query block that sees this key
+    # block; the steps past the last stay on it and compute nothing.
+    _when_visible(_compute, q_blk, k_blk, in_range, block_q=block_q,
+                  block_k=block_k, causal=causal, window=window)
 
     @pl.when(step == n_steps - 1)
     def _emit():
@@ -828,7 +822,8 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
     if not one_kernel:      # the dq pass: grid (b, i, j), key blocks innermost
         bq, bk, params = _flash_launch("dq", q, k, block_q, block_k, v,
                                        q_shared)
-        n_steps, k_of = _flash_inner(window, bq, bk, tq // bq, tk // bk, True)
+        n_steps, k_of = _flash_inner(causal, window, bq, bk, tq // bq,
+                                     tk // bk, True)
         rows = lambda b_, i, j: (b_, i, 0)  # noqa: E731
         keys = lambda b_, i, j: (b_, k_of(i, j), 0)  # noqa: E731
         shared_keys = lambda b_, i, j: (b_ // h, k_of(i, j), 0)  # noqa: E731
@@ -850,7 +845,7 @@ def _flash_backward(q, k, v, out, lse, g, q_shared=None, k_shared=None, *,
     bq, bk, params = _flash_launch("bwd" if one_kernel else "dkv", q, k,
                                    block_q, block_k, v, q_shared)
     n_q, n_k = tq // bq, tk // bk
-    n_steps, q_of = _flash_inner(window, bk, bq, n_k, n_q, False)
+    n_steps, q_of = _flash_inner(causal, window, bk, bq, n_k, n_q, False)
     rows = lambda b_, j, i: (b_, q_of(j, i), 0)  # noqa: E731
     keys = lambda b_, j, i: (b_, j, 0)  # noqa: E731
     shared_keys = lambda b_, j, i: (b_ // h, j, 0)  # noqa: E731
@@ -930,10 +925,11 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int | None = None,
     them instead: for tests, whose interpreter wants small ones.
 
     ``window`` (causal self-attention only) is a sliding window of that
-    many keys a query, its own position among them. Every kernel
-    then walks a shorter grid that holds only the tiles with a visible
-    pair (``_flash_inner``) and builds a mask only on the tiles the
-    diagonal or the window's edge crosses.
+    many keys a query, its own position among them. Every causal kernel
+    walks a row block's tiles with a visible pair first and fetches
+    nothing past them (``_flash_inner``; under a window the grid is
+    shorter too) and builds a mask only on the tiles the diagonal or the
+    window's edge crosses.
 
     ``v`` may be narrower or wider than q and k ([B, Tk, H, Dv]): the
     output is as wide as ``v``. ``q_shared`` [B, Tq, H, Dr] with

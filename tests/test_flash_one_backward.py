@@ -24,20 +24,34 @@ attention = importlib.import_module("ray_tpu.ops.attention")
 B, H = 2, 2
 
 
-def _operands(tq, tk, d, dv, dr, seed=0):
+def _operands(tq, tk, d, dv, dr, seed=0, b=B):
     """[q, k, v, (q_shared, k_shared)], then the output's cotangent."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
-    shapes = [(B, tq, H, d), (B, tk, H, d), (B, tk, H, dv)]
+    shapes = [(b, tq, H, d), (b, tk, H, d), (b, tk, H, dv)]
     if dr:
-        shapes += [(B, tq, H, dr), (B, tk, dr)]
+        shapes += [(b, tq, H, dr), (b, tk, dr)]
     ops = [jax.random.normal(k, s, jnp.float32) for k, s in zip(keys, shapes)]
-    return ops, jax.random.normal(keys[5], (B, tq, H, dv), jnp.float32)
+    return ops, jax.random.normal(keys[5], (b, tq, H, dv), jnp.float32)
 
 
 def _grad_fn(ops, g, causal, block_q, block_k, window):
     return jax.grad(lambda *a: (attention.flash_attention(
         *a[:3], causal, block_q, block_k, window, *a[3:]) * g).sum(),
         tuple(range(len(ops))))
+
+
+def _reference(causal, window):
+    """``dot_product_attention`` on ``_operands``' list: a shared key part
+    joined to q and, repeated to the heads, to k."""
+    def fn(q, k, v, q_shared=None, k_shared=None):
+        if q_shared is not None:
+            q = jnp.concatenate([q, q_shared], -1)
+            k = jnp.concatenate([k, jnp.broadcast_to(
+                k_shared[:, :, None],
+                (*k.shape[:3], k_shared.shape[-1]))], -1)
+        return attention.dot_product_attention(q, k, v, causal=causal,
+                                               window=window)
+    return fn
 
 
 def _launches(fn, *args) -> int:
@@ -80,19 +94,51 @@ def test_one_kernel_equals_two_bit_for_bit(form, request):
         assert bool(jnp.array_equal(a, b)), name
         assert bool(jnp.any(a != 0)), name
 
-    def reference(q, k, v, q_shared=None, k_shared=None):
-        if dr:
-            q = jnp.concatenate([q, q_shared], -1)
-            k = jnp.concatenate([k, jnp.broadcast_to(
-                k_shared[:, :, None], (*k.shape[:3], dr))], -1)
-        return (attention.dot_product_attention(
-            q, k, v, causal=causal, window=window) * g).sum()
-
+    reference = _reference(causal, window)
     for name, a, b in zip(("dq", "dk", "dv", "dq_shared", "dk_shared"), got,
-                          jax.grad(reference, tuple(range(len(ops))))(*ops)):
+                          jax.grad(lambda *a: (reference(*a) * g).sum(),
+                                   tuple(range(len(ops))))(*ops)):
         # ``test_flash_kernels_at_the_rules_own_tiles``' float32 tolerance
         assert float(jnp.abs(a - b).max()) <= 2e-4 * float(
             jnp.abs(b).max()), name
+
+
+# (name, T, d, dv, dr, block_q, block_k): square tiles with skipped,
+# crossed and full ones; kanana-2's backward's shape of tile and its
+# transpose, with a shared key part as there
+PLAIN = [
+    ("square tiles", 1024, 16, 16, 0, 256, 256),
+    ("shared key, tiles (512, 1024)", 2048, 8, 16, 8, 512, 1024),
+    ("shared key, tiles (1024, 512)", 2048, 8, 16, 8, 1024, 512),
+]
+
+
+@pytest.mark.parametrize("backward", ["bwd", "dq+dkv"])
+@pytest.mark.parametrize("form", PLAIN, ids=[f[0] for f in PLAIN])
+def test_plain_causal_is_the_widest_window_bit_for_bit(form, backward,
+                                                       request):
+    """Plain causal attention walks its grid by the windowed kernels' rule
+    (PR 50): the output and dq, dk, dv (and dq_shared, dk_shared) with no
+    window EQUAL the same call with ``window = T``, which takes the
+    windowed path whatever the window holds, every bit, in the one-kernel
+    backward and in the two-kernel form; and are the masked reference's."""
+    _, t, d, dv, dr, block_q, block_k = form
+    if backward == "dq+dkv":
+        request.getfixturevalue("two_backward_kernels")
+    ops, g = _operands(t, t, d, dv, dr, seed=4, b=1)
+
+    def run(window):
+        out, vjp = jax.vjp(lambda *a: attention.flash_attention(
+            *a[:3], True, block_q, block_k, window, *a[3:]), *ops)
+        return (out, *vjp(g))
+
+    got = run(None)
+    out, vjp = jax.vjp(_reference(True, None), *ops)
+    names = ("out", "dq", "dk", "dv", "dq_shared", "dk_shared")
+    for name, a, b, c in zip(names, got, run(t), (out, *vjp(g))):
+        assert bool(jnp.array_equal(a, b)), name
+        assert float(jnp.abs(a - c).max()) <= 2e-4 * float(
+            jnp.abs(c).max()), name
 
 
 # -- the tiles, and where a head's dq stops fitting ----------------------------------
@@ -160,18 +206,15 @@ def _walk(tq, tk, bq, bk, causal, window):
     """The "bwd" grid of one head in order: (key block, query block, the
     tile is computed, dq's output block index)."""
     n_q, n_k = tq // bq, tk // bk
-    n_steps, q_of = attention._flash_inner(window, bk, bq, n_k, n_q, False)
+    n_steps, q_of = attention._flash_inner(causal, window, bk, bq, n_k, n_q,
+                                           False)
     for j in range(n_k):
         for step in range(n_steps):
-            i, in_range = attention._inner_block(step, j, bk, bq, n_q, window,
-                                                 False)
-            if window is not None:
-                computed = bool(in_range)
-            else:
-                computed = not causal or i * bq + bq - 1 >= j * bk
+            i, in_range = attention._inner_block(step, j, bk, bq, n_q, causal,
+                                                 window, False)
             out = int(attention._dq_out_block(int(q_of(j, step)), j, bq, bk,
                                               n_q, n_k, causal))
-            yield j, int(i), computed, out
+            yield j, int(i), bool(in_range), out
 
 
 @pytest.mark.parametrize("tq,tk,bq,bk,causal,window", [

@@ -214,7 +214,8 @@ def test_the_cells_tiles_at_8192():
     VMEM budget. The one backward kernel keeps the head's dq beside its
     tiles (both parts: 8 MiB): 1024 x 1024 is 33.0 MiB by the rule's
     arithmetic, so it steps to (512, 1024), which ran as fast on the v5e
-    (``_FLASH_VMEM_MOST``'s comment)."""
+    (``_FLASH_VMEM_MOST``'s comment). And how a plain causal kernel walks
+    those tiles."""
     for d, dv, dr in ((128, 128, 64), (192, 128, 0)):
         for kernel in ("fwd", "dq", "dkv"):
             assert attention._flash_tiles(kernel, 8192, 8192, d, jnp.bfloat16,
@@ -228,6 +229,16 @@ def test_the_cells_tiles_at_8192():
             "bwd", 512, 1024, d, jnp.bfloat16, dv, dr, 8192) \
             <= attention._FLASH_VMEM_MOST < attention._flash_vmem_bytes(
             "bwd", 1024, 1024, d, jnp.bfloat16, dv, dr, 8192)
+    # The walk at those tiles (PR 50; counted tile by tile in
+    # ``tests/test_smallthinker_kernels.py``): the forward 8 steps a query
+    # block, the backward 16 a key block, which start at the first block
+    # that sees the row block and stay on the last one once past it.
+    steps, k_of = attention._flash_inner(True, None, 1024, 1024, 8, 8, True)
+    assert steps == 8
+    assert [int(k_of(2, s)) for s in range(8)] == [0, 1, 2, 2, 2, 2, 2, 2]
+    steps, q_of = attention._flash_inner(True, None, 1024, 512, 8, 16, False)
+    assert steps == 16
+    assert [int(q_of(5, s)) for s in range(16)] == [*range(10, 16)] + 10 * [15]
 
 
 # -- the kept output and logsumexp under the layer's checkpoint ----------------------
