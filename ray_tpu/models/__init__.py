@@ -31,6 +31,7 @@ from ray_tpu.models.transformer import (
     olmoe_1b_7b,
     partition_specs,
     qwen2_7b,
+    qwen3_next_80b_a3b,
     smallthinker_21b_a3b,
     tiny,
     tiny_moe,
@@ -58,6 +59,7 @@ __all__ = [
     "kanana_2_30b_a3b",
     "kimi_linear_48b_a3b",
     "trinity_mini_26b_a3b",
+    "qwen3_next_80b_a3b",
     "llama2_7b",
     "llama3_8b",
     "lm_loss",

@@ -63,6 +63,15 @@ a **scaled embedding** (``embed_scale``), and a ``layer_pattern``
 **anchored to the published layer numbers** (``first_layer``), which may
 then stand behind leading dense layers (the dense stack's layers take
 their kinds from the same pattern) and start or stop mid-period.
+And a ``qwen3_next``-shaped model (Qwen3-Next): **Gated DeltaNet** mixers
+(``layer_mixers`` ``"gdn"``: the delta rule with ONE decay a value head,
+fewer key heads than value heads, ``linear_key_heads``, and an output
+gate ``silu(z)`` of a full-rank projection) three to one layer of PLAIN
+gated attention (``"attn"`` beside ``"gdn"`` with no ``kv_latent``: own
+stack ``mha``), whose heads rotate their first quarter only
+(``rope_fraction``); **zero-centred norms** (``norm_zero_centred``: ``x /
+rms x (1 + w)``, ``w`` made 0); a **gate on the shared expert**
+(``shared_expert_gate``: ``sigmoid(w_s . h)``, one number a token).
 With a period of P > 1 the scan runs over
 WHOLE PERIODS and unrolls a period's P layers in its body, so each
 position's kind is static: a windowed layer compiles to the kernel that
@@ -127,16 +136,31 @@ SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
 # Inside ``attn``, for a model with a ``layer_pattern``, with latent
 # attention (whose layers are all full causal ones) or with
 # ``layer_mixers``: which kind of layer the instruction belongs to.
-# ``attn_linear`` is everything of a KDA layer's mixer: inside it
-# ``attn_qkv`` (the q / k / v projections), ``ops.linear_attention.SCOPES``
-# (``kda_conv``, ``kda_gate``), ``attn_core`` (the chunked delta rule and
-# nothing else) and ``attn_out``.
+# ``attn_linear`` is everything of a linear (KDA or Gated DeltaNet)
+# layer's mixer: inside it ``attn_qkv`` (the q / k / v projections; Gated
+# DeltaNet's z with them), ``ops.linear_attention.SCOPES`` (``kda_conv``,
+# ``kda_gate``), ``attn_core`` (the chunked delta rule and nothing else)
+# and ``attn_out``.
 ATTN_SCOPES = ("attn_full", "attn_window", "attn_linear")
-# The token mixers ``layer_mixers`` may name.
-MIXERS = ("attn", "kda")
+# The token mixers ``layer_mixers`` may name: "kda" and "gdn" are the
+# LINEAR ones (a state carried along the sequence); "attn" is the model's
+# attention, latent with ``kv_latent`` and plain without.
+MIXERS = ("attn", "kda", "gdn")
+LINEAR_MIXERS = ("kda", "gdn")
 # A stack's subtrees that hold ONE kind of mixer's own leaves, stacked
-# over the layers of that kind (``layer_mixers`` models only).
-MIXER_STACKS = {"kda": "kda", "attn": "mla"}
+# over the layers of that kind (``layer_mixers`` models only): a linear
+# mixer's under its own name, attention's under ``mla`` (latent) or
+# ``mha`` (plain: q, k, v, the head norms, the gate). ``attn/wo`` is every
+# layer's, whatever its mixer.
+MIXER_STACKS = {"kda": "kda", "gdn": "gdn", "attn": ("mla", "mha")}
+
+
+def _own_stacks(c) -> tuple[str | None, str]:
+    """(the name of the subtree that holds the model's linear mixer's own
+    leaves, or None with none; the name of attention's)."""
+    linear = c.linear_mixer
+    return (linear and MIXER_STACKS[linear],
+            MIXER_STACKS["attn"][c.kv_latent is None])
 # Inside ``attn`` (and its ``attn_full``), latent attention only: what the
 # latent form adds outside the kernels (down-projection, the latent's
 # norm, up-projection, RoPE on the rotary parts).
@@ -313,10 +337,14 @@ class TransformerConfig:
     # "attn" (the model's latent attention). Empty: attention everywhere.
     # The scan's period is read off the list (``_period``).
     layer_mixers: tuple[str, ...] = ()
-    kda_heads: int = 0               # heads of a KDA layer
-    kda_head_dim: int = 0            # a KDA head's key AND value width,
-    #                                  and the rank of its two low-rank maps
+    kda_heads: int = 0               # (value) heads of a linear layer
+    kda_head_dim: int = 0            # a linear head's key AND value width,
+    #                                  and the rank of KDA's two low-rank maps
     kda_conv: int = 4                # positions the short convolution reads
+    # Query / key heads of a Gated DeltaNet layer where they are fewer than
+    # its ``kda_heads`` value heads: value head j reads key head ``j //
+    # (kda_heads / linear_key_heads)``. None: as many.
+    linear_key_heads: int | None = None
     # Latent attention rotates its ``d_head_rope``-wide parts (RoPE); False:
     # no positional encoding at all, the part is a plain shared key.
     latent_rope: bool = True
@@ -330,6 +358,19 @@ class TransformerConfig:
     # x the embedding's rows as they enter the stream (muP: sqrt(d_model));
     # the head is not scaled.
     embed_scale: float = 1.0
+    # -- a qwen3_next-shaped model (llama arch) ----------------------------
+    # The share of a head's width that RoPE rotates: its FIRST ``head_dim x
+    # rope_fraction`` values (the two halves of those paired), the rest as
+    # they are (plain attention only).
+    rope_fraction: float = 1.0
+    # Every RMSNorm with a weight the width of the stream or of an
+    # attention head (the block norms, the final norm, ``qk_norm="head"``)
+    # scales by ``1 + w``, ``w`` made 0 (a linear mixer's output norm stays
+    # the plain form, its weight made 1).
+    norm_zero_centred: bool = False
+    # The shared expert's output times ``sigmoid(w_s . h)``, ONE number a
+    # token (``mlp/shared_gate`` [D]).
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         # A config file's JSON gives lists: keep the config hashable.
@@ -368,14 +409,24 @@ class TransformerConfig:
         held = self.held_range
         return self.n_experts if held is None else held[1] - held[0]
 
+    @property
+    def linear_mixer(self) -> str | None:
+        """The model's LINEAR mixer ("kda" or "gdn": one model has at most
+        one kind), None for a model of attention layers only."""
+        return next((m for m in LINEAR_MIXERS if m in self.layer_mixers),
+                    None)
+
     def layer_kind(self, i: int) -> tuple[bool, bool] | str | None:
-        """The kind of layer ``i`` of the ``n_layers``: "kda" for a KDA
-        layer, else (windowed, rope) of its attention (latent attention:
-        full, rotated or not); None with no pattern: the arch's own."""
-        if self.layer_mixers and self.layer_mixers[i] == "kda":
-            return "kda"
+        """The kind of layer ``i`` of the ``n_layers``: "kda" or "gdn" for
+        a linear layer, else (windowed, rope) of its attention (latent
+        attention: full, rotated or not; plain attention beside linear
+        layers: full, rotated); None with no pattern: the arch's own."""
+        if self.layer_mixers and self.layer_mixers[i] in LINEAR_MIXERS:
+            return self.layer_mixers[i]
         if self.kv_latent is not None:
             return (False, self.latent_rope)
+        if self.layer_mixers:
+            return (False, True)
         if not self.layer_pattern:
             return None
         return self.layer_pattern[((self.first_layer or 0) + i)
@@ -614,6 +665,41 @@ def trinity_mini_26b_a3b(**kw) -> TransformerConfig:
     )
 
 
+def qwen3_next_80b_a3b(**kw) -> TransformerConfig:
+    """Qwen3-Next-80B-A3B (Qwen ``config.json``, ``model_type``
+    ``qwen3_next``; the public implementation is ``transformers``'
+    ``modeling_qwen3_next.py``): 48 layers over a 2,048-wide stream, layer
+    ``i`` (from 0) gated softmax attention where ``(i + 1) % 4 == 0`` (16
+    query heads on 2 key / value heads of 256, q and k RMS-normed a head,
+    RoPE theta 1e7 on the first 64 of a head's 256 values, the output
+    times ``sigmoid`` of a gate projected beside q), every other layer
+    Gated DeltaNet (16 query / key heads under 32 value heads of 128, a
+    short convolution over 4 positions, ONE log-decay a value head, the
+    head-normed output times ``silu(z)``); every norm but DeltaNet's
+    output norm zero-centred; every layer 512 SwiGLU experts 512 wide, 10
+    a token by a softmax router, gates renormalised, beside one shared
+    expert 512 wide times ``sigmoid(w_s . h)``; load-balance weight 0.001
+    (``Qwen3NextConfig``'s ``router_aux_loss_coef``), no z term.
+    ``n_layers=n`` takes the published layers 0 to n - 1. The published
+    multi-token-prediction module is not here (``lm_loss`` has one head)."""
+    mixers = tuple("attn" if (i + 1) % 4 == 0 else "gdn"
+                   for i in range(kw.get("n_layers", 48)))
+    return replace(
+        TransformerConfig(
+            vocab_size=151936, n_layers=48, d_model=2048, n_heads=16,
+            n_kv_heads=2, d_head=256, d_ff=512, max_seq_len=262144,
+            arch="llama", rope_theta=1e7, rope_fraction=0.25, norm_eps=1e-6,
+            norm_zero_centred=True, qk_norm="head", attn_gate=True,
+            layer_mixers=mixers, kda_heads=32, kda_head_dim=128, kda_conv=4,
+            linear_key_heads=16, d_ff_shared=512, shared_expert_gate=True,
+            n_experts=512, expert_top_k=10, expert_capacity_factor=None,
+            expert_norm_topk=True, router_aux_weight=0.001,
+            router_z_weight=0.0,
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -677,9 +763,25 @@ def _check_config(c: TransformerConfig) -> None:
                         ("post_norm", c.post_norm)):
         if wrong and c.arch != "llama":
             raise ValueError(f"{name} requires arch='llama'")
-    if c.attn_gate and (c.kv_latent is not None or c.layer_mixers):
+    if c.attn_gate and c.kv_latent is not None:
         raise ValueError("attn_gate gates plain attention's output: it does "
-                         "not run with kv_latent or layer_mixers")
+                         "not run with kv_latent")
+    for name, wrong in (("norm_zero_centred", c.norm_zero_centred),
+                        ("shared_expert_gate", c.shared_expert_gate),
+                        ("rope_fraction", c.rope_fraction != 1.0)):
+        if wrong and c.arch != "llama":
+            raise ValueError(f"{name} requires arch='llama'")
+    rotated = c.head_dim * c.rope_fraction
+    if c.rope_fraction != 1.0 and (
+            not 0.0 < c.rope_fraction < 1.0 or rotated != int(rotated)
+            or int(rotated) % 2 or c.kv_latent is not None):
+        raise ValueError(
+            f"rope_fraction={c.rope_fraction} has to leave plain attention "
+            f"an even whole number of a head's {c.head_dim} values to "
+            f"rotate (latent attention rotates its d_head_rope)")
+    if c.shared_expert_gate and not c.d_ff_shared:
+        raise ValueError("shared_expert_gate gates the shared expert: set "
+                         "d_ff_shared")
     windowed = any(w for w, _ in c.layer_pattern)
     if windowed != (c.sliding_window is not None):
         raise ValueError(
@@ -724,22 +826,38 @@ def _check_config(c: TransformerConfig) -> None:
     if not c.latent_rope and c.kv_latent is None:
         raise ValueError("latent_rope=False describes latent attention "
                          "(kv_latent)")
+    if c.linear_key_heads is not None and (
+            "gdn" not in c.layer_mixers or c.linear_key_heads < 1
+            or c.kda_heads % c.linear_key_heads):
+        raise ValueError(
+            f"linear_key_heads={c.linear_key_heads} are the query / key "
+            f"heads of a model with 'gdn' layer_mixers and divide its "
+            f"kda_heads={c.kda_heads}")
     if c.layer_mixers:
-        wo = (c.n_heads, c.d_head_v)
+        latent = c.kv_latent is not None
+        wo = (c.n_heads, c.d_head_v if latent else c.head_dim)
         for name, wrong in (
                 (f"names other than {MIXERS}",
                  not set(c.layer_mixers) <= set(MIXERS)),
                 (f"{len(c.layer_mixers)} names for n_layers={c.n_layers}",
                  len(c.layer_mixers) != c.n_layers),
                 ("a layer_pattern", bool(c.layer_pattern)),
-                ("attention that is not latent (kv_latent)",
-                 c.kv_latent is None),
+                ("'kda' beside attention that is not latent (kv_latent)",
+                 "kda" in c.layer_mixers and not latent),
+                ("'gdn' beside latent attention (kv_latent)",
+                 "gdn" in c.layer_mixers and latent),
+                ("'kda' and 'gdn' in one model",
+                 set(LINEAR_MIXERS) <= set(c.layer_mixers)),
                 ("kda_heads, kda_head_dim or kda_conv < 1",
                  min(c.kda_heads, c.kda_head_dim, c.kda_conv) < 1),
-                # attn/wo is ONE stack over every layer, whatever its mixer
-                (f"KDA heads {(c.kda_heads, c.kda_head_dim)} that are not "
-                 f"attention's (n_heads, d_head_v) {wo}",
-                 (c.kda_heads, c.kda_head_dim) != wo)):
+                # attn/wo is ONE stack over every layer, whatever its mixer:
+                # as many rows from a linear layer's heads as from
+                # attention's (KDA: the same heads)
+                (f"{'KDA' if latent else 'Gated DeltaNet'} heads "
+                 f"{(c.kda_heads, c.kda_head_dim)} that are not attention's "
+                 f"(n_heads, a value's width) {wo}",
+                 (c.kda_heads, c.kda_head_dim) != wo if latent
+                 else c.kda_heads * c.kda_head_dim != wo[0] * wo[1])):
             if wrong:
                 raise ValueError(f"layer_mixers does not run with {name}")
     if c.n_dense_layers:
@@ -764,11 +882,17 @@ def init_params(rng, config: TransformerConfig):
     stacks: ``dense_layers`` [n_dense_layers, ...] and ``layers`` (the
     expert layers, [n_layers - n_dense_layers, ...]). With
     ``layer_mixers`` a stack's ``attn`` holds ``wo`` alone, every
-    layer's; ``kda`` and ``mla`` hold the KDA layers' and the latent-
-    attention layers' own leaves, stacked over the layers of that kind.
-    KDA's init is its public implementation's: the convolutions U(-1 /
-    sqrt(taps), 1 / sqrt(taps)), ``A_log`` = log U(1, 16), ``dt_bias`` the
-    inverse softplus of a log-uniform step in [0.001, 0.1].
+    layer's; ``kda`` / ``gdn`` and ``mla`` / ``mha`` hold the linear
+    layers' and the attention layers' own leaves, stacked over the layers
+    of that kind. A linear mixer's init is its public implementation's:
+    the convolutions U(-1 / sqrt(taps), 1 / sqrt(taps)), ``A_log`` = log
+    U(1, 16), ``dt_bias`` the inverse softplus of a log-uniform step in
+    [0.001, 0.1]. Gated DeltaNet's one published input projection is the
+    leaves ``wq``, ``wk`` [D, key heads, dk], ``wv``, ``wz`` [D, heads,
+    dv] (its columns regrouped by what they make: each shards by head),
+    ``w_a`` and ``w_beta`` its second; plain attention's fused q-and-gate
+    projection the leaves ``wq`` and ``wg``. A zero-centred norm's weight
+    is made 0.
     """
     c = config
     _check_config(c)
@@ -794,6 +918,10 @@ def init_params(rng, config: TransformerConfig):
     def uniform(key, *shape, low, high):
         return jax.random.uniform(key, shape, jnp.float32, low, high)
 
+    def unit(*shape):
+        """A norm's weight at its start: it scales by 1."""
+        return (jnp.zeros if c.norm_zero_centred else jnp.ones)(shape, pdt)
+
     def attn_stack(keys, n):
         if c.kv_latent is None:
             stack = {
@@ -804,10 +932,8 @@ def init_params(rng, config: TransformerConfig):
             }
             if c.qk_norm:
                 per_head = c.qk_norm == "head"
-                stack["q_norm"] = jnp.ones((n, Dh if per_head else H * Dh),
-                                           pdt)
-                stack["k_norm"] = jnp.ones((n, Dh if per_head else KV * Dh),
-                                           pdt)
+                stack["q_norm"] = unit(n, Dh if per_head else H * Dh)
+                stack["k_norm"] = unit(n, Dh if per_head else KV * Dh)
             if c.attn_gate:
                 stack["wg"] = norm(next(fourth), n, D, H, Dh)
             return stack
@@ -842,25 +968,48 @@ def init_params(rng, config: TransformerConfig):
             "o_norm": jnp.ones((n, dk), pdt),
         }
 
+    def gdn_stack(keys, n):
+        Hv, Hk = c.kda_heads, c.linear_key_heads or c.kda_heads
+        d, taps = c.kda_head_dim, c.kda_conv
+        edge = 1.0 / math.sqrt(taps)
+        heads = {"q": Hk, "k": Hk, "v": Hv, "z": Hv}
+        step = jnp.exp(uniform(next(keys), n, Hv, low=math.log(1e-3),
+                               high=math.log(1e-1)))
+        return {
+            **{f"w{x}": norm(next(keys), n, D, heads[x], d) for x in "qkvz"},
+            **{f"conv_{x}": uniform(next(keys), n, taps, heads[x], d,
+                                    low=-edge, high=edge).astype(pdt)
+               for x in "qkv"},
+            "w_a": norm(next(keys), n, D, Hv),
+            "w_beta": norm(next(keys), n, D, Hv),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+            "A_log": jnp.log(uniform(next(keys), n, Hv, low=1.0,
+                                     high=16.0)).astype(pdt),
+            "o_norm": jnp.ones((n, d), pdt),
+        }
+
     def mixer_stacks(keys, first, n):
         """The token mixers' leaves of the ``n`` layers from ``first``."""
         if not c.layer_mixers:
             return {"attn": attn_stack(keys, n)}
-        n_kda = c.layer_mixers[first:first + n].count("kda")
+        linear, own = _own_stacks(c)
+        n_linear = sum(m in LINEAR_MIXERS
+                       for m in c.layer_mixers[first:first + n])
         stacks = {}
-        if n - n_kda:
-            stacks["mla"] = attn_stack(keys, n - n_kda)
-            del stacks["mla"]["wo"]
-        if n_kda:
-            stacks["kda"] = kda_stack(third, n_kda)
-        stacks["attn"] = {"wo": norm(next(third), n, H, c.d_head_v, D,
-                                     s=res_std)}
+        if n - n_linear:
+            stacks[own] = attn_stack(keys, n - n_linear)
+            del stacks[own]["wo"]
+        if n_linear:
+            stacks[linear] = (kda_stack if linear == "kda" else gdn_stack)(
+                third, n_linear)
+        value = c.d_head_v if c.kv_latent is not None else Dh
+        stacks["attn"] = {"wo": norm(next(third), n, H, value, D, s=res_std)}
         return stacks
 
     def block_norms(n):
         names = ("ln1", "ln2") + (("ln1_post", "ln2_post") if c.post_norm
                                   else ())
-        return {name: {"w": jnp.ones((n, D), pdt)} for name in names}
+        return {name: {"w": unit(n, D)} for name in names}
 
     def ffn_stack(keys, n, width):
         return {
@@ -872,7 +1021,7 @@ def init_params(rng, config: TransformerConfig):
     params = {
         "embed": {"tokens": norm(next(keys), c.vocab_size, D)},
         "layers": mixer_stacks(keys, c.n_dense_layers, L),
-        "final_norm": {"w": jnp.ones((D,), pdt)},
+        "final_norm": {"w": unit(D)},
     }
     if c.arch == "gpt2":
         params["embed"]["pos"] = norm(next(keys), c.max_seq_len, D)
@@ -910,6 +1059,9 @@ def init_params(rng, config: TransformerConfig):
                 shared = ffn_stack(more, L, c.d_ff_shared)
                 params["layers"]["mlp"].update(
                     {f"shared_{name}": w for name, w in shared.items()})
+                if c.shared_expert_gate:
+                    params["layers"]["mlp"]["shared_gate"] = norm(
+                        next(more), L, D)
         else:
             params["layers"]["mlp"] = ffn_stack(keys, L, F)
         if c.n_dense_layers:
@@ -961,7 +1113,15 @@ def partition_specs(config: TransformerConfig):
         "A_log": P(None, AXIS_TENSOR),
         "w_beta": P(None, None, AXIS_TENSOR),
     }
-    mixers = {"attn": attn, "mla": attn, "kda": kda}
+    # Gated DeltaNet: the same rule (q and k by KEY head)
+    gdn = {
+        **{name: by_head for name in ("wq", "wk", "wv", "wz", "conv_q",
+                                      "conv_k", "conv_v")},
+        "dt_bias": P(None, AXIS_TENSOR), "A_log": P(None, AXIS_TENSOR),
+        "w_a": P(None, None, AXIS_TENSOR),
+        "w_beta": P(None, None, AXIS_TENSOR),
+    }
+    mixers = {"attn": attn, "mla": attn, "mha": attn, "kda": kda, "gdn": gdn}
     specs = {
         "embed": {"tokens": P(AXIS_TENSOR, None)},
         "layers": {**mixers, "ln1": None, "ln2": None},
@@ -1053,8 +1213,9 @@ def _split_stack(c: TransformerConfig, stack, kinds: tuple, period: int):
     periods = len(kinds) // period
     own = {}
     if c.layer_mixers:
-        n_kda = sum(k == "kda" for k in kinds[:period])
-        own = {"kda": n_kda, "mla": period - n_kda}
+        linear, attn = _own_stacks(c)
+        n_linear = sum(isinstance(k, str) for k in kinds[:period])
+        own = {linear: n_linear, attn: period - n_linear}
 
     def split(name, sub):
         n = own.get(name, period)
@@ -1073,12 +1234,14 @@ def _take_layer(c: TransformerConfig, stack, kinds: tuple, i: int):
     mixer's own leaves at its place among the layers of its kind."""
     if not c.layer_mixers:
         return jax.tree.map(lambda a: a[i], stack)
-    own = MIXER_STACKS["kda" if kinds[i] == "kda" else "attn"]
-    place = sum((k == "kda") == (kinds[i] == "kda") for k in kinds[:i])
+    stacks = _own_stacks(c)
+    linear = isinstance(kinds[i], str)      # a linear layer's kind is a name
+    own = stacks[not linear]
+    place = sum(isinstance(k, str) == linear for k in kinds[:i])
     return {name: jax.tree.map(
                 lambda a, at=(place if name == own else i): a[at], sub)
             for name, sub in stack.items()
-            if name == own or name not in MIXER_STACKS.values()}
+            if name == own or name not in stacks}
 
 
 def forward(params, tokens, config: TransformerConfig, *, mesh=None,
@@ -1094,10 +1257,12 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     model; with ``experts_held`` also ``held_share`` and ``full_buffer``,
     the means over the layers; with a ``router_bias`` also ``expert_counts`` [layers,
     experts], the batch's assignments to every expert, and
-    ``bias_swapped``, the mean over the layers; with KDA layers also
+    ``bias_swapped``, the mean over the layers; with linear layers also
     ``kda_log_decay_min``, the most negative cumulative log-decay inside
     any chunk of any of them; with an ``attn_gate`` also
-    ``attn_gate_mean``, the gate's mean over the layers).
+    ``attn_gate_mean``, the gate's mean over the attention layers; with a
+    ``shared_expert_gate`` also ``moe_shared_gate_mean``, that gate's mean
+    over the layers).
     ``return_hidden`` skips
     the LM head and returns the final
     normed hidden states [B, T, D] (the chunked-loss path applies the head
@@ -1133,8 +1298,8 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             rope = None                 # no layer rotates anything
         else:
             cos, sin = rope_frequencies(
-                c.head_dim if c.kv_latent is None else c.d_head_rope,
-                c.max_seq_len, theta=c.rope_theta)
+                int(c.head_dim * c.rope_fraction) if c.kv_latent is None
+                else c.d_head_rope, c.max_seq_len, theta=c.rope_theta)
             rope = (cos, sin)
         x = con(x, _BATCH, AXIS_SEQUENCE, None)
 
@@ -1218,8 +1383,12 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             total = auxs["gate_mean"].sum()
             if c.n_dense_layers:
                 total = total + dense_auxs["gate_mean"].sum()
-            aux["attn_gate_mean"] = total / c.n_layers
-        if "kda" in c.layer_mixers:
+            aux["attn_gate_mean"] = total / sum(
+                m not in LINEAR_MIXERS
+                for m in c.layer_mixers or ("attn",) * c.n_layers)
+        if c.shared_expert_gate:
+            aux["moe_shared_gate_mean"] = auxs["shared_gate_mean"].mean()
+        if c.linear_mixer:
             aux["kda_log_decay_min"] = auxs["log_decay_min"].min()
             if c.n_dense_layers:
                 aux["kda_log_decay_min"] = jnp.minimum(
@@ -1231,7 +1400,8 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             x = layer_norm(x, params["final_norm"]["w"],
                            params["final_norm"]["b"], eps=c.norm_eps)
         else:
-            x = rms_norm(x, params["final_norm"]["w"], eps=c.norm_eps)
+            x = rms_norm(x, _norm_weight(c, params["final_norm"]["w"]),
+                         eps=c.norm_eps)
     if return_hidden:
         return (x, aux) if return_aux else x
     with jax.named_scope("head_loss"):
@@ -1250,8 +1420,8 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     = (windowed, rope) is the layer's place in the ``layer_pattern``
     (static; None with no pattern: full causal attention, the arch's own
     positions), and names the sub-scope its attention runs under.
-    ``kind`` = "kda": the token mixer is KDA, under ``attn_linear``.
-    ``dense``: one of an expert model's leading dense layers. With
+    ``kind`` = "kda" or "gdn": the token mixer is that linear one, under
+    ``attn_linear``. ``dense``: one of an expert model's leading dense layers. With
     ``post_norm`` a sublayer's output is normed under ``post_norm``,
     inside the sublayer's own scope, and joins the stream there."""
     dt = c.compute_dtype
@@ -1262,10 +1432,12 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
         if not c.post_norm:
             return x + out
         with jax.named_scope(POST_NORM_SCOPE):
-            return x + rms_norm(out, lp[norm]["w"], eps=c.norm_eps)
+            return x + rms_norm(out, _norm_weight(c, lp[norm]["w"]),
+                                eps=c.norm_eps)
 
     window = None
-    if kind is not None and kind != "kda":
+    linear = kind in LINEAR_MIXERS
+    if kind is not None and not linear:
         windowed, with_rope = kind
         window = c.sliding_window if windowed else None
         rope = rope if with_rope else None
@@ -1273,7 +1445,7 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
         if c.arch == "gpt2":
             h = layer_norm(x, lp["ln1"]["w"], lp["ln1"]["b"], eps=c.norm_eps)
         else:
-            h = rms_norm(x, lp["ln1"]["w"], eps=c.norm_eps)
+            h = rms_norm(x, _norm_weight(c, lp["ln1"]["w"]), eps=c.norm_eps)
     router = None
     if experts and c.router_input == "attn_norm":
         with jax.named_scope("moe"):
@@ -1281,25 +1453,32 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     decay_min = gate_mean = None
     with jax.named_scope("attn"), (
             contextlib.nullcontext() if kind is None else jax.named_scope(
-                ATTN_SCOPES[2 if kind == "kda" else window is not None])):
-        if kind == "kda":
-            o, decay_min = _kda_mixer(h, lp["kda"], c)
+                ATTN_SCOPES[2 if linear else window is not None])):
+        if linear:
+            mixer = _kda_mixer if kind == "kda" else _gdn_mixer
+            o, decay_min = mixer(h, lp[kind], c)
         else:
+            # attention's own leaves: a stack of their own beside linear
+            # layers' (``MIXER_STACKS``)
+            own = lp[_own_stacks(c)[1] if c.layer_mixers else "attn"]
             if c.kv_latent is not None:
-                q, k, v, shared = _latent_qkv(
-                    h, lp["mla" if c.layer_mixers else "attn"], c, rope,
-                    positions)
+                q, k, v, shared = _latent_qkv(h, own, c, rope, positions)
             else:
-                q, k, v = _plain_qkv(h, lp["attn"], c, rope, positions)
+                q, k, v = _plain_qkv(h, own, c, rope, positions)
                 shared = {}
             q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
             with jax.named_scope("attn_core"):
                 o = attention(q, k, v, causal=True, impl=c.attn_impl,
                               window=window, **shared)
             if c.attn_gate:
-                o, gate_mean = _gate_output(o, h, lp["attn"]["wg"])
+                o, gate_mean = _gate_output(o, h, own["wg"])
         with jax.named_scope("attn_out"):
-            o = jnp.einsum("bthk,hkd->btd", o, lp["attn"]["wo"].astype(dt))
+            wo = lp["attn"]["wo"].astype(dt)
+            # one stack for every layer: a linear layer's heads are its
+            # rows regrouped (32 x 128 of Gated DeltaNet's for 16 x 256)
+            if o.shape[2:] != wo.shape[:2]:
+                o = o.reshape(*o.shape[:2], *wo.shape[:2])
+            o = jnp.einsum("bthk,hkd->btd", o, wo)
             if not c.post_norm:
                 x = x + o
         if c.post_norm:
@@ -1311,7 +1490,7 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
         if c.arch == "gpt2":
             h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"], eps=c.norm_eps)
         else:
-            h = rms_norm(x, lp["ln2"]["w"], eps=c.norm_eps)
+            h = rms_norm(x, _norm_weight(c, lp["ln2"]["w"]), eps=c.norm_eps)
     if c.arch == "gpt2":
         with jax.named_scope("mlp"):
             m = gelu_mlp(h, lp["mlp"]["w_in"].astype(dt),
@@ -1329,12 +1508,19 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                        lp["mlp"]["w_up"].astype(dt),
                        lp["mlp"]["w_down"].astype(dt))
             x = join(x, m, "ln2_post")
+    zero = jnp.zeros((), jnp.float32)
     if c.attn_gate:                     # every layer of a stack alike
-        aux = dict(aux, gate_mean=gate_mean)
-    if "kda" in c.layer_mixers:         # every layer of a stack alike
-        aux = dict(aux, log_decay_min=jnp.zeros((), jnp.float32)
-                   if decay_min is None else decay_min)
+        aux = dict(aux, gate_mean=zero if gate_mean is None else gate_mean)
+    if c.linear_mixer:                  # likewise
+        aux = dict(aux, log_decay_min=zero if decay_min is None
+                   else decay_min)
     return x, aux
+
+
+def _norm_weight(c: TransformerConfig, w):
+    """What a norm scales by, from its leaf: ``1 + w`` where the model's
+    norms are zero-centred (``norm_zero_centred``), in float32."""
+    return 1.0 + w.astype(jnp.float32) if c.norm_zero_centred else w
 
 
 def _expert_ffn(h, lp, c: TransformerConfig, router, con):
@@ -1359,9 +1545,14 @@ def _expert_ffn(h, lp, c: TransformerConfig, router, con):
         activation=c.expert_activation, score=c.router_score,
         select_bias=lp["router"].get("b"), gate_scale=c.expert_gate_scale)
     if c.d_ff_shared:
-        m = m + moe.shared_expert(
-            h, *(lp["mlp"][f"shared_{name}"].astype(dt)
-                 for name in ("w_gate", "w_up", "w_down")))
+        shared = [lp["mlp"][f"shared_{name}"].astype(dt)
+                  for name in ("w_gate", "w_up", "w_down")]
+        if c.shared_expert_gate:
+            out, gate_mean = moe.gated_shared_expert(
+                h, *shared, lp["mlp"]["shared_gate"])
+            m, aux = m + out, dict(aux, shared_gate_mean=gate_mean)
+        else:
+            m = m + moe.shared_expert(h, *shared)
     return m, aux
 
 
@@ -1399,6 +1590,35 @@ def _kda_mixer(h, w, c: TransformerConfig):
             linear_attention.log_decay_min(g))
 
 
+def _gdn_mixer(h, w, c: TransformerConfig):
+    """A Gated DeltaNet layer's mixer up to (not with) the output
+    projection, from the normed input ``h`` [B, T, D] and the layer's own
+    leaves ``w`` -> (o [B, T, H, dv], the most negative cumulative
+    log-decay inside any chunk). As ``_kda_mixer``, FLAT from the
+    projections to the head norm; what differs is the mechanism: the
+    published ONE input projection (q, k by KEY head, v and the gate's z
+    by value head: four plain matmuls under ``attn_qkv``), ONE log-decay a
+    value head and ``beta`` from a second, 2 x heads wide (``head_gates``),
+    a key head read by ``kda_heads / linear_key_heads`` value heads (q and
+    k go to the rule as VIEWS by heads, which is how it learns their
+    count: nothing is computed on the view), and the output gate
+    ``silu(z)`` (``silu_gated_head_norm``)."""
+    dt = c.compute_dtype
+    with jax.named_scope("attn_qkv"):
+        q, k, v, z = (jnp.einsum("btd,dc->btc", h,
+                                 w[name].reshape(c.d_model, -1).astype(dt))
+                      for name in ("wq", "wk", "wv", "wz"))
+    q, k, v = linear_attention.conv_silu(q, k, v, w["conv_q"], w["conv_k"],
+                                         w["conv_v"])
+    g, beta = linear_attention.head_gates(h, w)
+    with jax.named_scope("attn_core"):
+        q, k = (a.reshape(*a.shape[:2], -1, c.kda_head_dim) for a in (q, k))
+        o = linear_attention.gated_delta_rule(q, k, v, g, beta)
+    return (linear_attention.silu_gated_head_norm(o, z, w["o_norm"],
+                                                  eps=c.norm_eps),
+            linear_attention.log_decay_min(g))
+
+
 def _gate_output(o, h, wg):
     """Attention's output ``o`` [B, T, H, Dh] times ``sigmoid(W_g h)`` of
     the block's normed input ``h`` [B, T, D] -> (gated o, the gate's mean:
@@ -1433,8 +1653,8 @@ def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
             v = jnp.einsum("btd,dhk->bthk", h, w["wv"].astype(dt))
     with jax.named_scope("attn_pos"):
         if c.qk_norm == "head":         # a head at a time, one weight
-            q = rms_norm(q, w["q_norm"], eps=c.norm_eps)
-            k = rms_norm(k, w["k_norm"], eps=c.norm_eps)
+            q = rms_norm(q, _norm_weight(c, w["q_norm"]), eps=c.norm_eps)
+            k = rms_norm(k, _norm_weight(c, w["k_norm"]), eps=c.norm_eps)
         elif c.qk_norm:
             q = _qk_norm(q, w["q_norm"])
             k = _qk_norm(k, w["k_norm"])
@@ -1733,7 +1953,8 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
             # the router's bias reads it and takes it out of the metrics
             metrics["moe_expert_counts"] = aux["expert_counts"]
             metrics["moe_bias_swapped"] = aux["bias_swapped"]
-    for name in ("kda_log_decay_min", "attn_gate_mean"):
+    for name in ("kda_log_decay_min", "attn_gate_mean",
+                 "moe_shared_gate_mean"):
         if name in aux:
             metrics = dict(metrics, **{name: aux[name]})
     return loss, metrics
@@ -1887,13 +2108,14 @@ def init_train_state(rng, config: TransformerConfig, optimizer):
 def refuse_decode(c: TransformerConfig) -> None:
     """The KV-cache decode runs one kind of dense layer: refuse, by name,
     a model it would run wrongly in silence."""
-    if "kda" in c.layer_mixers:
+    if c.layer_mixers:
         raise NotImplementedError(
             f"KV-cache decode does not run a model with layer_mixers "
             f"({c.layer_mixers!r}; kda_heads {c.kda_heads}, kda_head_dim "
-            f"{c.kda_head_dim}, kda_conv {c.kda_conv}): a KDA layer keeps a "
-            f"recurrent state and its convolution's last positions, not "
-            f"keys and values, and would be decoded as plain attention")
+            f"{c.kda_head_dim}, kda_conv {c.kda_conv}, linear_key_heads "
+            f"{c.linear_key_heads}): a linear (KDA or Gated DeltaNet) layer "
+            f"keeps a recurrent state and its convolution's last positions, "
+            f"not keys and values, and would be decoded as plain attention")
     for name, value in (("kv_latent", c.kv_latent),
                         ("latent_rope", not c.latent_rope),
                         ("n_dense_layers", c.n_dense_layers),
@@ -1901,6 +2123,10 @@ def refuse_decode(c: TransformerConfig) -> None:
                         ("qk_norm", c.qk_norm),
                         ("attn_gate", c.attn_gate),
                         ("post_norm", c.post_norm),
+                        ("rope_fraction", c.rope_fraction != 1.0
+                         and c.rope_fraction),
+                        ("norm_zero_centred", c.norm_zero_centred),
+                        ("shared_expert_gate", c.shared_expert_gate),
                         ("embed_scale", c.embed_scale != 1.0
                          and c.embed_scale)):
         if value:
@@ -1908,7 +2134,8 @@ def refuse_decode(c: TransformerConfig) -> None:
                 f"KV-cache decode does not run a model with {name} "
                 f"({value!r}): the cache holds kv_heads x head_dim x 2 a "
                 f"token and every layer would be decoded as a dense one "
-                f"of plain attention, its q and k not normed, its output "
+                f"of plain attention, its q and k not normed, its heads "
+                f"rotated whole, its norms not zero-centred, its output "
                 f"neither gated nor normed, its embedding not scaled")
     if c.n_experts > 0:
         raise NotImplementedError(

@@ -35,7 +35,16 @@ def rope_frequencies(head_dim: int, max_len: int, *, theta: float = 10000.0):
 
 def apply_rope(x, cos, sin, *, position_offset: int = 0, positions=None):
     """Rotate [B, T, H, D] by position. ``positions`` overrides the
-    arange (needed by sequence-parallel shards and decode steps)."""
+    arange (needed by sequence-parallel shards and decode steps). Tables
+    narrower than D / 2 rotate the FIRST ``2 x their width`` values of a
+    head (the two halves of those, paired as ever) and leave the rest of
+    the head as it is (a partial rotary factor)."""
+    rotated = 2 * cos.shape[-1]
+    if rotated < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotated], cos, sin,
+                        position_offset=position_offset, positions=positions),
+             x[..., rotated:]], axis=-1)
     t = x.shape[1]
     if positions is None:
         positions = position_offset + jnp.arange(t)

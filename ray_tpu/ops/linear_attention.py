@@ -1,7 +1,12 @@
 """Linear attention that carries a STATE along the sequence: the causal
-depthwise short convolution and the gated delta rule of Kimi Delta
-Attention (KDA; Kimi Linear, arXiv:2510.26692), the first ops here whose
-work is a recurrence over positions and not a sum over (query, key) pairs.
+depthwise short convolution and the gated delta rule, as Kimi Delta
+Attention runs it (KDA; Kimi Linear, arXiv:2510.26692: a log-decay per
+CHANNEL, as many key heads as value heads) and as Gated DeltaNet does
+(Qwen3-Next: ONE log-decay a value head and token, fewer key heads than
+value heads, the output gate ``silu(z)`` of a full-rank projection:
+``head_gates``, ``silu_gated_head_norm``, and the paragraph on the
+head-wise form below), the first ops here whose work is a recurrence over
+positions and not a sum over (query, key) pairs.
 
 Per head, with keys ``k_t`` and queries ``q_t`` in R^dk (the caller's
 l2-normed ones), values ``v_t`` in R^dv, a log-decay per CHANNEL ``g_t``
@@ -37,6 +42,19 @@ from ``exp(G_t - G_s)`` itself, position pair by position pair. The
 triangular inverse is forward substitution in the diagonal sub-blocks
 and block products between them (a Neumann series of the whole chunk
 cancels catastrophically where keys repeat).
+
+**ONE decay a head** (``g`` shaped as ``beta``, [B, T, H]: the rule's
+rank test) is the case ``g_t[c] = g_t`` for every channel, and the chunk's
+matrices lose their sum over channels: ``A[t, s] = (k_t . k_s) exp(G_t -
+G_s)``, ``B`` likewise, so a chunk is ONE product of its rows against its
+keys times ONE [C, C] decay matrix a head, every exponent <= 0 as it
+stands: no reference decay a sub-block, no scaling of rows and columns,
+nothing pair by pair (``_head_matrices`` and its gradient in the kernels;
+the scan spreads ``g`` over the lanes and stays the form they are tested
+against). **Fewer key heads than value heads** (q, k [B, T, H / r, dk]):
+value head ``j`` reads key head ``j // r``; a kernel step's block of q and
+k holds its value heads' key heads through the same index map, and the
+key head's gradient is the sum over the value heads it serves.
 
 The state is float32; the products take their operands in the inputs'
 dtype (bfloat16 in a train step) and accumulate in float32, as the
@@ -103,14 +121,16 @@ crossed between the two tilings on the way in (unnamed transposes) and on
 the way out (copies into the kernels' tiling): 87 + 11 + 10 ms of a 720-ms
 step (PERF.md section 6, PR 49).
 
-``SCOPES`` are the named scopes this file opens around the parts of a KDA
-layer's mixer that are neither projections nor the delta rule
+``SCOPES`` are the named scopes this file opens around the parts of a
+linear (KDA or Gated DeltaNet) layer's mixer that are neither projections
+nor the delta rule
 (``ray_tpu/models/transformer.py`` opens ``attn_linear`` and the rest):
 ``kda_conv`` (the three convolutions, SiLU, the l2 norms: on a TPU the
 chains' Pallas calls, which ``step_kda_kernel_ms`` and
 ``step_attn_kernel_ms`` therefore count beside the rule's) and
-``kda_gate`` (the decay's and the output gate's low-rank maps, ``beta``,
-softplus / exp, the head norm and the gate's product).
+``kda_gate`` (the decay's and the output gate's low-rank maps, or Gated
+DeltaNet's one decay a head; ``beta``, softplus / exp, the head norm and
+the gate's product, ``sigmoid`` or ``silu(z)``).
 """
 
 from __future__ import annotations
@@ -215,6 +235,23 @@ def gates(h, w):
         return g, beta
 
 
+def head_gates(h, w):
+    """Gated DeltaNet's two gates of the normed input ``h`` [B, T, D], ONE
+    number a value head and token each, float32 [B, T, H] (scope
+    ``kda_gate``): ``g = -exp(A_log) x softplus(W_a h + dt_bias)``, the
+    log-decay a HEAD (<= 0; ``gated_delta_rule`` tells it from a decay per
+    channel by its shape), and ``beta = sigmoid(W_b h)``. ``w``: ``w_a``,
+    ``w_beta`` [D, H], ``dt_bias``, ``A_log`` [H]."""
+    dt = h.dtype
+    with jax.named_scope("kda_gate"):
+        a, b = (jnp.einsum("btd,dh->bth", h, w[name].astype(dt),
+                           preferred_element_type=jnp.float32)
+                for name in ("w_a", "w_beta"))
+        g = -jnp.exp(w["A_log"].astype(jnp.float32)) * jax.nn.softplus(
+            a + w["dt_bias"].astype(jnp.float32))
+        return g, jax.nn.sigmoid(b)
+
+
 def _head_lanes(heads: int, d: int):
     """[H, H * d] float32: row ``h`` is 1 over head ``h``'s ``d`` lanes of
     a flat ``[.., H * d]`` array and 0 elsewhere. A flat array times its
@@ -238,21 +275,40 @@ def gated_head_norm(o, h, w, *, eps: float):
     back are products with ``_head_lanes``, the rest is elementwise; all
     float32, rounded once at the end."""
     dt = h.dtype
-    b, t, heads, dv = o.shape
     with jax.named_scope("kda_gate"):
         low = jnp.einsum("btd,dr->btr", h, w["g_a"].astype(dt))
         gate = jnp.einsum("btr,rc->btc", low,
                           w["g_b"].astype(dt).reshape(low.shape[-1], -1),
                           preferred_element_type=jnp.float32)
-        of = o.astype(jnp.float32).reshape(b, t, heads * dv)
-        lanes = _head_lanes(heads, dv)
-        mean = jnp.einsum("btc,hc->bth", of * of, lanes,
-                          precision=_HIGHEST) / dv
-        scale = jnp.einsum("bth,hc->btc", jax.lax.rsqrt(mean + eps), lanes,
-                           precision=_HIGHEST)
-        weight = jnp.tile(w["o_norm"].astype(jnp.float32), heads)
-        return (of * scale * weight * jax.nn.sigmoid(gate)).astype(
-            dt).reshape(o.shape)
+        return (_normed_heads(o, w["o_norm"], eps)
+                * jax.nn.sigmoid(gate)).astype(dt).reshape(o.shape)
+
+
+def _normed_heads(o, weight, eps: float):
+    """``rmsnorm_head(o)`` of ``o`` [B, T, H, dv] with the ONE weight [dv]
+    all heads share -> FLAT float32 [B, T, H * dv]: the mean over a head's
+    lanes and its spread back are products with ``_head_lanes``."""
+    b, t, heads, dv = o.shape
+    of = o.astype(jnp.float32).reshape(b, t, heads * dv)
+    lanes = _head_lanes(heads, dv)
+    mean = jnp.einsum("btc,hc->bth", of * of, lanes,
+                      precision=_HIGHEST) / dv
+    scale = jnp.einsum("bth,hc->btc", jax.lax.rsqrt(mean + eps), lanes,
+                       precision=_HIGHEST)
+    return of * scale * jnp.tile(weight.astype(jnp.float32), heads)
+
+
+def silu_gated_head_norm(o, z, weight, *, eps: float):
+    """Gated DeltaNet's output: ``rmsnorm_head(o; weight) * silu(z)``, ``o``
+    [B, T, H, dv] the delta rule's output, ``z`` [B, T, H * dv] the FLAT
+    full-rank gate projection of the block's normed input (where KDA's
+    gate is ``sigmoid`` of a low-rank map, ``gated_head_norm``), ``weight``
+    [dv] shared by the heads (scope ``kda_gate``). Worked flat and in
+    float32, rounded once at the end, as ``gated_head_norm`` is."""
+    with jax.named_scope("kda_gate"):
+        return (_normed_heads(o, weight, eps)
+                * jax.nn.silu(z.astype(jnp.float32))).astype(
+                    o.dtype).reshape(o.shape)
 
 
 def log_decay_min(g):
@@ -521,7 +577,9 @@ def _kernel_intra(operands):
     [C, dk], ``v`` [C, dv] in the operands' dtype, ``g`` [C, dk] and
     ``beta`` [C, 1] float32) -> a ``_Chunk`` each with ``_intra``'s six
     results (``w``, ``u0``, ``qt``, ``bm``, ``kbar``; ``gamma`` [1, dk])
-    and what the backward reads besides. Sub-block by sub-block as
+    and what the backward reads besides. ``g`` as a ROW, [1, C], is ONE
+    log-decay a head and token (Gated DeltaNet): ``A`` and ``B`` are then
+    ``_head_matrices``', and ``gamma`` is [1, 1]. Else sub-block by sub-block as
     ``_intra``: below the diagonal one product against a reference decay,
     on it pair by pair, a column ``s`` of the block a step; the SUB x SUB
     diagonal blocks of the inverse by elimination in float32, all of them
@@ -533,9 +591,60 @@ def _kernel_intra(operands):
     for q, k, v, g, beta in operands:
         x = _Chunk()
         x.dt, x.g, x.beta = q.dtype, g, beta
+        x.qd, x.kd = q, k
         x.qf, x.kf, x.vf = [a.astype(_F32) for a in (q, k, v)]
         x.scale = 1.0 / math.sqrt(q.shape[1])
         xs.append(x)
+    matrices = _head_matrices if _by_head(xs) else _channel_matrices
+    return _kernel_inverse(xs, *matrices(xs))
+
+
+def _by_head(xs) -> bool:
+    """Whether the chunks' ``g`` is a row: ONE decay a head and token."""
+    return xs[0].g.shape[0] == 1
+
+
+def _head_matrices(xs):
+    """``A`` and ``B`` of a chunk whose heads have ONE log-decay a token
+    (``x.g`` a row [1, C]): ``A[t, s] = (k_t . k_s) exp(G_t - G_s)``, so
+    one product of the chunk's rows against its keys and ONE [C, C] decay
+    matrix a head, every exponent <= 0 as it stands: no reference decay a
+    sub-block, no scaling of rows and columns by channel, nothing pair by
+    pair. Leaves what ``_channel_matrices`` leaves (``big`` here [C, 1],
+    which every later use spreads over the lanes)."""
+    c = xs[0].qf.shape[0]
+    n_sub = c // SUB
+    r, s = _iota((c, c), 0), _iota((c, c), 1)
+    lane, sub = _iota((SUB, c), 1), _iota((SUB, c), 0)
+    for x in xs:
+        # the running sums down the chunk, as a column and as a row: sums
+        # of float32 on the VPU, exact
+        x.big = jnp.sum(jnp.where(r >= s, x.g, 0.0), 1, keepdims=True)
+        x.big_row = jnp.sum(jnp.where(r <= s, _column(x.g), 0.0), 0,
+                            keepdims=True)
+        x.decay = jnp.exp(jnp.minimum(x.big - x.big_row, 0.0))
+    for x in xs:
+        both = _mm(jnp.concatenate([x.kd, x.qd], 0), x.kd, _NT)  # [2 C, C]
+        x.a = jnp.where(r > s, both[:c] * x.decay, 0.0)
+        x.b = jnp.where(r >= s, both[c:] * x.decay, 0.0)
+        x.m = x.beta * x.a
+    for x in xs:
+        # column j of every diagonal block of beta * A, across its lanes
+        x.columns = []
+        for j in range(SUB - 1):
+            col = jnp.zeros((SUB, c), _F32)
+            for i in range(n_sub):
+                r0 = i * SUB
+                col = jnp.where(lane // SUB == i,
+                                x.m[r0:r0 + SUB, r0 + j:r0 + j + 1], col)
+            x.columns.append(col)
+        x.packed = jnp.where(lane % SUB == sub, 1.0, 0.0)
+    return lane, sub
+
+
+def _channel_matrices(xs):
+    """``A`` and ``B`` of a chunk whose log-decay is per CHANNEL (``x.g``
+    [C, dk]), sub-block by sub-block as ``_intra`` (``_kernel_intra``)."""
     c, dk = xs[0].qf.shape
     n_sub = c // SUB
     for x in xs:
@@ -596,6 +705,14 @@ def _kernel_intra(operands):
         x.columns = [jnp.where(sub > j, col * x.beta_lanes, 0.0)
                      for j, col in enumerate(x.columns)]
         x.packed = jnp.where(lane % SUB == sub, 1.0, 0.0)
+    return lane, sub
+
+
+def _kernel_inverse(xs, lane, sub):
+    """``_kernel_intra`` from ``A``, ``B`` and the diagonal blocks' columns
+    on: the inverse and the chunk's six results."""
+    c, dk = xs[0].qf.shape
+    n_sub = c // SUB
     # the diagonal blocks' inverses: X <- X - n_j (x) X[j], j ascending,
     # n_j column j of the block (rows > j)
     for j in range(SUB - 1):
@@ -631,21 +748,29 @@ def _kernel_intra(operands):
     return xs
 
 
-def _head_slices(h: int, dk: int, dv: int):
-    return slice(h * dk, (h + 1) * dk), slice(h * dv, (h + 1) * dv)
+def _head_slices(h: int, dk: int, dv: int, rep: int = 1):
+    """(the lanes of value head ``h``'s KEY head, ``h // rep`` (``rep``
+    value heads read one key head), its own lanes of v and o) of a step's
+    blocks."""
+    return (slice(h // rep * dk, (h // rep + 1) * dk),
+            slice(h * dv, (h + 1) * dv))
 
 
-def _load_heads(heads, dk, dv, q_ref, k_ref, v_ref, g_ref, beta_ref):
+def _load_heads(heads, dk, dv, rep, q_ref, k_ref, v_ref, g_ref, beta_ref):
+    """A step's operands, a value head each. ``g_ref`` blocked like
+    ``beta_ref`` (rows) is ONE decay a head: handed on as a row."""
     out = []
     for h in range(heads):
-        keys, values = _head_slices(h, dk, dv)
+        keys, values = _head_slices(h, dk, dv, rep)
         out.append((q_ref[0, :, keys], k_ref[0, :, keys], v_ref[0, :, values],
-                    g_ref[0, :, keys], _column(beta_ref[0, 0, 0, h:h + 1])))
+                    g_ref[0, 0, 0, h:h + 1] if len(g_ref.shape) == 5
+                    else g_ref[0, :, keys],
+                    _column(beta_ref[0, 0, 0, h:h + 1])))
     return out
 
 
 def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
-                      heads: int, dk: int, dv: int):
+                      heads: int, dk: int, dv: int, rep: int):
     import jax.experimental.pallas as pl
 
     *starts_ref, state = rest       # the chunk-start states only if kept
@@ -654,8 +779,8 @@ def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
     def _zero():
         state[...] = jnp.zeros_like(state)
 
-    xs = _kernel_intra(_load_heads(heads, dk, dv, q_ref, k_ref, v_ref, g_ref,
-                                   beta_ref))
+    xs = _kernel_intra(_load_heads(heads, dk, dv, rep, q_ref, k_ref, v_ref,
+                                   g_ref, beta_ref))
     for h, x in enumerate(xs):
         x.s = state[h]
         if starts_ref:
@@ -674,19 +799,18 @@ def _delta_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, *rest,
 
 def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref,
                       do_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                      d_state, *, heads: int, dk: int, dv: int):
+                      d_state, *, heads: int, dk: int, dv: int, rep: int):
     import jax.experimental.pallas as pl
 
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         d_state[...] = jnp.zeros_like(d_state)
 
-    xs = _kernel_intra(_load_heads(heads, dk, dv, q_ref, k_ref, v_ref, g_ref,
-                                   beta_ref))
+    xs = _kernel_intra(_load_heads(heads, dk, dv, rep, q_ref, k_ref, v_ref,
+                                   g_ref, beta_ref))
     c = xs[0].qf.shape[0]
-    n_sub = c // SUB
+    by_head = _by_head(xs)
     r, col = _iota((c, c), 0), _iota((c, c), 1)
-    lane, column = _iota((SUB, c), 1), _iota((SUB, 1), 0)
     # ``_chunk_backward``, the state and its gradient transposed
     for h, x in enumerate(xs):
         x.s, x.d_after = starts_ref[0, 0, h], d_state[h]
@@ -732,6 +856,58 @@ def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref,
         x.d_big = x.kf * (written - kept) + x.qf * x.d_q
         x.d_last = (jnp.sum(kept * x.kf, 0, keepdims=True)
                     + x.d_gamma * x.gamma)
+        if by_head:         # ONE number a token: the channels' sum
+            x.d_big = jnp.sum(x.d_big, 1, keepdims=True)
+            x.d_last = jnp.sum(x.d_last, 1, keepdims=True)
+    (_head_matrices_grad if by_head else _channel_matrices_grad)(xs)
+    at_last = _iota((c, 1), 0) == c - 1
+    for h, x in enumerate(xs):
+        keys, values = _head_slices(h, dk, dv, rep)
+        d_big = x.d_big + jnp.where(at_last, x.d_last, 0.0)
+        d_q, d_k = x.d_q + x.q_rows, x.d_k + x.k_rows + x.k_cols
+        # a key head's gradient is the sum over the value heads it serves
+        if h % rep:
+            d_q, d_k = d_q + of_key[0], d_k + of_key[1]
+        of_key = d_q, d_k
+        if h % rep == rep - 1:
+            dq_ref[0, :, keys] = d_q.astype(dq_ref.dtype)
+            dk_ref[0, :, keys] = d_k.astype(dk_ref.dtype)
+        dv_ref[0, :, values] = x.d_v.astype(dv_ref.dtype)
+        if by_head:         # the running sum up the chunk, as a row
+            dg_ref[0, 0, 0, h:h + 1] = jnp.sum(
+                jnp.where(r >= col, d_big, 0.0), 0, keepdims=True)
+        else:
+            dg_ref[0, :, keys] = _running_sum(d_big, reverse=True)
+        dbeta_ref[0, 0, 0, h:h + 1] = _row(x.d_beta)
+
+
+def _head_matrices_grad(xs):
+    """The gradient of ``_head_matrices``' ``A = (K K^T) * D`` and ``B = (Q
+    K^T) * D`` from ``x.d_a`` and ``x.d_b`` (masked as ``A`` and ``B``
+    are): k as a row of ``A`` and q as a row of ``B`` (``k_rows``,
+    ``q_rows``: one product), k as a column of both (``k_cols``: one
+    more), and the decay's, ``d_a A + d_b B`` summed along a token's row
+    less the same summed down its column, added to ``x.d_big`` [C, 1]."""
+    c = xs[0].qf.shape[0]
+    for x in xs:
+        both = jnp.concatenate([x.d_a * x.decay, x.d_b * x.decay], 0).astype(
+            x.dt)                                            # [2 C, C]
+        rows = _mm(both, x.kd)
+        x.k_rows, x.q_rows = rows[:c], rows[c:]
+        x.k_cols = _mm(both, jnp.concatenate([x.kd, x.qd], 0), _TN)
+        through = x.d_a * x.a + x.d_b * x.b
+        x.d_big = (x.d_big + jnp.sum(through, 1, keepdims=True)
+                   - _column(jnp.sum(through, 0, keepdims=True)))
+
+
+def _channel_matrices_grad(xs):
+    """The gradient of ``_channel_matrices``' ``A`` and ``B``, sub-block by
+    sub-block as they were made: leaves ``k_rows``, ``q_rows``, ``k_cols``
+    [C, dk] and adds the decays' part to ``x.d_big`` [C, dk]."""
+    c, dk = xs[0].qf.shape
+    n_sub = c // SUB
+    lane, column = _iota((SUB, c), 1), _iota((SUB, 1), 0)
+    for x in xs:
         x.k_rows, x.q_rows, x.k_diag = [], [], []
         x.k_cols = jnp.zeros_like(x.kf)
     # A and B: the gradient of k as a row of either (``k_rows``), of q as a
@@ -787,24 +963,23 @@ def _delta_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, starts_ref,
                                keepdims=True))
             x.d_big = x.d_big + jnp.where(_iota((c, 1), 0) == r0 - 1, d_ref,
                                           0.0)
-    for h, x in enumerate(xs):
-        keys, values = _head_slices(h, dk, dv)
-        k_rows, q_rows = (jnp.concatenate(a, 0) for a in (x.k_rows, x.q_rows))
-        k_cols = x.k_cols + jnp.concatenate(x.k_diag, 0)
-        d_big = (x.d_big + x.kf * (k_rows - k_cols) + x.qf * q_rows
-                 + jnp.where(_iota((c, 1), 0) == c - 1, x.d_last, 0.0))
-        dq_ref[0, :, keys] = (x.d_q + q_rows).astype(dq_ref.dtype)
-        dk_ref[0, :, keys] = (x.d_k + k_rows + k_cols).astype(dk_ref.dtype)
-        dv_ref[0, :, values] = x.d_v.astype(dv_ref.dtype)
-        dg_ref[0, :, keys] = _running_sum(d_big, reverse=True)
-        dbeta_ref[0, 0, 0, h:h + 1] = _row(x.d_beta)
+    for x in xs:
+        x.k_rows, x.q_rows = (jnp.concatenate(a, 0)
+                              for a in (x.k_rows, x.q_rows))
+        x.k_cols = x.k_cols + jnp.concatenate(x.k_diag, 0)
+        x.d_big = (x.d_big + x.kf * (x.k_rows - x.k_cols)
+                   + x.qf * x.q_rows)
 
 
 def _kernel_call(kernel, operands, out_like, *, starts_out: bool = False,
-                 reverse: bool = False, interpret: bool | None = None):
+                 reverse: bool = False, interpret: bool | None = None,
+                 rep: int = 1):
     """One ``pallas_call`` over (batch, head blocks, chunks), the chunks
     sequential and walked from the last with ``reverse``. ``operands``: q,
-    k, v, g as [B, T, H * d], beta's rows, then (backward) the chunk-start
+    k [B, T, H / rep * dk] (``rep`` value heads read one key head: a
+    step's block of q and k holds its value heads' key heads, through the
+    same index map), v [B, T, H * dv], g [B, T, H * dk] or, ONE decay a
+    head, rows as beta's, beta's rows, then (backward) the chunk-start
     states and d_o; ``out_like``: arrays whose shapes and dtypes the
     outputs take, blocked as the operand of that shape is; ``starts_out``
     adds the chunk-start states, [B, T / CHUNK, H, dv, dk] float32.
@@ -816,11 +991,13 @@ def _kernel_call(kernel, operands, out_like, *, starts_out: bool = False,
         interpret = _interpret()
     return _launch(kernel, tuple(operands),
                    tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                         for a in out_like), starts_out, reverse, interpret)
+                         for a in out_like), starts_out, reverse, interpret,
+                   rep)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4, 5))
-def _launch(kernel, operands, out_shape, starts_out, reverse, interpret):
+@functools.partial(jax.jit, static_argnums=(0, 2, 3, 4, 5, 6))
+def _launch(kernel, operands, out_shape, starts_out, reverse, interpret,
+            rep=1):
     """``_kernel_call`` behind a ``jax.jit`` of its own, for the time a
     step takes to TRACE: a kernel's body is some ten thousand equations,
     seconds to trace and to lower, and a train step calls each kernel in
@@ -837,7 +1014,7 @@ def _launch(kernel, operands, out_shape, starts_out, reverse, interpret):
     b, t = q.shape[:2]
     heads, n = beta.shape[-2], t // CHUNK
     h = beta.shape[1] * heads
-    dk, dv = q.shape[-1] // h, v.shape[-1] // h
+    dk, dv = q.shape[-1] * rep // h, v.shape[-1] // h
     at = (lambda j: n - 1 - j) if reverse else (lambda j: j)
     starts_shape = (b, n, h, dv, dk)
 
@@ -855,7 +1032,7 @@ def _launch(kernel, operands, out_shape, starts_out, reverse, interpret):
     if starts_out:
         out_shape.append(jax.ShapeDtypeStruct(starts_shape, jnp.float32))
     return pl.pallas_call(
-        functools.partial(kernel, heads=heads, dk=dk, dv=dv),
+        functools.partial(kernel, heads=heads, dk=dk, dv=dv, rep=rep),
         grid=(b, h // heads, n),
         in_specs=[spec(a) for a in operands],
         out_specs=[spec(a) for a in out_shape], out_shape=out_shape,
@@ -867,23 +1044,24 @@ def _launch(kernel, operands, out_shape, starts_out, reverse, interpret):
     )(*operands)
 
 
-@jax.custom_vjp
-def _kernel_rule(q, k, v, g, beta):
-    """``q``, ``k`` [B, T, H * dk], ``v`` [B, T, H * dv], ``g`` float32,
-    ``beta`` [B, H / heads, T / CHUNK, heads, CHUNK], T whole chunks -> o
-    [B, T, H * dv]."""
-    return _kernel_call(_delta_fwd_kernel, (q, k, v, g, beta), [v])[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kernel_rule(q, k, v, g, beta, rep):
+    """``q``, ``k`` [B, T, H / rep * dk], ``v`` [B, T, H * dv], ``g``
+    float32 [B, T, H * dk] or rows as ``beta``'s, ``beta`` [B, H / heads, T
+    / CHUNK, heads, CHUNK], T whole chunks -> o [B, T, H * dv]."""
+    return _kernel_call(_delta_fwd_kernel, (q, k, v, g, beta), [v],
+                        rep=rep)[0]
 
 
-def _kernel_rule_fwd(q, k, v, g, beta):
+def _kernel_rule_fwd(q, k, v, g, beta, rep):
     o, starts = _kernel_call(_delta_fwd_kernel, (q, k, v, g, beta), [v],
-                             starts_out=True)
+                             starts_out=True, rep=rep)
     return o, (q, k, v, g, beta, starts)
 
 
-def _kernel_rule_bwd(kept, d_o):
+def _kernel_rule_bwd(rep, kept, d_o):
     return tuple(_kernel_call(_delta_bwd_kernel, (*kept, d_o), kept[:5],
-                              reverse=True))
+                              reverse=True, rep=rep))
 
 
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
@@ -1157,21 +1335,34 @@ def _by_kernels(q, k, v, g, beta):
     d], or by heads as they come (``flat`` does nothing to a flat one, so
     what ``conv_silu`` and ``gates`` make is handed on as it is), T padded
     to whole chunks, the heads in blocks of ``_KERNEL_HEADS`` where they
-    come in fours."""
+    come in fours. ``g`` shaped as ``beta`` (ONE decay a head) goes as
+    rows, as ``beta`` does. Fewer key heads than value heads: a step's
+    heads bring their key heads' lanes, where those are whole key heads;
+    where a key head serves more value heads than a step holds, q and k
+    are repeated ahead of the kernels."""
     b, t, h = beta.shape
     pad = -t % CHUNK
-    heads = next(n for n in (_KERNEL_HEADS, 2, 1) if h % n == 0)
+    rep = h // q.shape[2] if q.ndim == 4 else 1
+    blocks = [n for n in (_KERNEL_HEADS, 2, 1) if h % n == 0]
+    if not any(n % rep == 0 for n in blocks):
+        q, k, rep = jnp.repeat(q, rep, axis=2), jnp.repeat(k, rep, axis=2), 1
+    heads = next(n for n in blocks if n % rep == 0)
 
     def flat(a):
         if pad:
             a = jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
         return a.reshape(b, t + pad, -1)
 
-    rows = jnp.transpose(
-        flat(beta.astype(jnp.float32)).reshape(
-            b, (t + pad) // CHUNK, CHUNK, h // heads, heads), (0, 3, 1, 4, 2))
-    o = _kernel_rule(flat(q), flat(k), flat(v), flat(g.astype(jnp.float32)),
-                     rows)
+    def rows(a):
+        return jnp.transpose(
+            flat(a.astype(jnp.float32)).reshape(
+                b, (t + pad) // CHUNK, CHUNK, h // heads, heads),
+            (0, 3, 1, 4, 2))
+
+    by_head = g.shape == beta.shape
+    o = _kernel_rule(flat(q), flat(k), flat(v),
+                     rows(g) if by_head else flat(g.astype(jnp.float32)),
+                     rows(beta), rep)
     return o.reshape(b, t + pad, h, -1)[:, :t]
 
 
@@ -1183,13 +1374,18 @@ def gated_delta_rule(q, k, v, g, beta):
     heads or FLAT, [B, T, H * d], what ``conv_silu`` and ``gates`` make and
     the kernels read (they take it with no reshape, transpose or copy, and
     its gradient goes back flat); its RANK says which, ``beta`` how many
-    heads. A ``T`` that is no whole number of chunks is padded behind the
-    row with tokens that write nothing (``beta`` = 0) and forget nothing
-    (``g`` = 0): no real token sees them. Differentiable in all five
-    operands. The Pallas kernels where ``_on_one_tpu`` finds their case,
-    else the scan in plain XLA."""
-    dk, dv = (a.shape[-1] // (beta.shape[-1] if a.ndim == 3 else 1)
-              for a in (q, v))
+    heads. **``g`` shaped as ``beta``, [B, T, H], is ONE log-decay a head
+    and token** (Gated DeltaNet; ``head_gates``), every channel's. **``q``
+    and ``k`` by heads may have FEWER heads than ``v``**, [B, T, H / r,
+    dk]: value head ``j`` reads key head ``j // r`` (a flat q or k has
+    ``H`` heads). A ``T`` that is no whole number of chunks is padded
+    behind the row with tokens that write nothing (``beta`` = 0) and
+    forget nothing (``g`` = 0): no real token sees them. Differentiable in
+    all five operands. The Pallas kernels where ``_on_one_tpu`` finds
+    their case, else the scan in plain XLA."""
+    h = beta.shape[-1]
+    dk = q.shape[-1] // (h if q.ndim == 3 else 1)
+    dv = v.shape[-1] // (h if v.ndim == 3 else 1)
     if _on_one_tpu(q, dk, dv):
         return _by_kernels(q, k, v, g, beta)
     return _by_scan(q, k, v, g, beta)
@@ -1198,6 +1394,10 @@ def gated_delta_rule(q, k, v, g, beta):
 def _by_scan(q, k, v, g, beta):
     b, t, h = beta.shape
     pad = -t % CHUNK
+    if q.ndim == 4 and q.shape[2] != h:    # a key head to h / its heads
+        q, k = (jnp.repeat(a, h // a.shape[2], axis=2) for a in (q, k))
+    if g.shape == beta.shape:               # ONE decay a head: every channel's
+        g = jnp.repeat(g, q.shape[-1] // (h if q.ndim == 3 else 1), axis=2)
     # a flat operand by heads: the scan is chunk-major
     q, k, v, g = (a.reshape(b, t, h, -1) for a in (q, k, v, g))
 

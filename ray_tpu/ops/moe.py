@@ -117,8 +117,9 @@ import numpy as np
 # by ``moe_swiglu_dropless``: router matmul, scores, top-k and the loss
 # terms; the sort and the gather into expert order; the three grouped
 # matmuls and the activation; the gather back, the gates and the sum. And
-# by ``shared_expert``: the gated FFN every token passes through beside
-# its routed experts.
+# by ``shared_expert`` / ``gated_shared_expert``: the gated FFN every token
+# passes through beside its routed experts (and the one number a token
+# that may scale it).
 SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
           "moe_shared")
 ROUTER_SCORES = ("softmax", "sigmoid")
@@ -496,6 +497,21 @@ def shared_expert(x, w_gate, w_up, w_down):
     [F, D]."""
     with jax.named_scope("moe_shared"):
         return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+
+
+def gated_shared_expert(x, w_gate, w_up, w_down, w_share):
+    """``shared_expert`` times ``sigmoid(w_share . x)``, ONE number a
+    token (``w_share`` [D]; the sigmoid and the product float32), all of
+    it under ``moe_shared`` -> (the gated output, the gate's mean: 0.5 at
+    a seeded init, 0 where the gate has shut and the shared expert is paid
+    for by nobody; no gradient)."""
+    with jax.named_scope("moe_shared"):
+        share = jax.nn.sigmoid(jnp.einsum(
+            "...d,d->...", x, w_share.astype(x.dtype),
+            preferred_element_type=jnp.float32))[..., None]
+        out = (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+        return ((out * share).astype(out.dtype),
+                jax.lax.stop_gradient(share).mean())
 
 
 def held_range(num_experts: int, rank: int, of: int) -> tuple[int, int]:
