@@ -233,17 +233,22 @@ class TransformerConfig:
     # kernel's output and logsumexp (``layer_of``).
     remat_policy: str = "full"       # "full" | "dots"
     scan_layers: bool = True         # lax.scan over layers vs unrolled loop
-    # Chunked LM-head loss: compute logits/CE in chunks of this many
-    # tokens inside a remat'd scan, so the [B,T,vocab] float32 logits
-    # tensor is never materialized (peak-memory, not FLOPs, is what caps
-    # batch size on a single chip). 0 = off (single fused head matmul).
+    # Rows a block of the LM head + loss: ``lm_loss`` computes logits/CE
+    # in blocks of this many tokens inside a scan, so the [B,T,vocab]
+    # float32 logits tensor is never materialized (peak-memory, not
+    # FLOPs, is what caps batch size on a single chip). 0 = ONE block of
+    # all the rows: the same op with its gradients made in its forward
+    # (``ce_impl`` "fused"), on the operands as they are (under a mesh
+    # every device works on its own rows). Blocks are for memory, not for
+    # speed; set this where a step's float32 logits do not fit.
     loss_chunk: int = 0
     # Token-accuracy metric in the CE loss: an argmax sweep over the
-    # [*, vocab] float32 logits per chunk, in the forward AND its remat
-    # recompute. Throughput-bench configs turn it off (the metric dict
-    # then reports accuracy 0.0).
+    # [*, vocab] float32 logits per block (and in ``ce_impl``
+    # "checkpoint"'s remat recompute). Throughput-bench configs turn it
+    # off (the metric dict then reports accuracy 0.0).
     ce_accuracy: bool = True
-    # Chunked-CE backward strategy. "fused": custom-VJP that computes
+    # Backward strategy of a ``loss_chunk`` a caller sets (at 0 it is
+    # "fused"). "fused": custom-VJP that computes
     # dlogits = softmax - onehot analytically INSIDE the forward scan
     # and saves only dx/dhead — each chunk's logits are computed exactly
     # once per train step. "checkpoint": jax.checkpoint around the chunk
@@ -1265,8 +1270,8 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     over the layers).
     ``return_hidden`` skips
     the LM head and returns the final
-    normed hidden states [B, T, D] (the chunked-loss path applies the head
-    itself).
+    normed hidden states [B, T, D] (``lm_loss`` applies the head itself,
+    inside its loss).
     """
     c = config
     dt = c.compute_dtype
@@ -1786,10 +1791,10 @@ def chunked_ce_loss(x, head, targets, *, mask=None, z_loss: float = 0.0,
 
 
 def _ce_chunk_stats(logits, tb, mb, z_loss, accuracy):
-    """Shared per-chunk CE statistics: (nll_masked_sum, correct_masked_sum,
-    lse). logits fp32 [c,V]; tb [c] int; mb [c] fp32."""
+    """Shared per-block CE statistics: (nll_masked_sum, correct_masked_sum,
+    lse). logits fp32 [..., V]; tb [...] int; mb [...] fp32."""
     lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
+    gold = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
     nll = lse - gold
     if z_loss:
         nll = nll + z_loss * jnp.square(lse)
@@ -1798,99 +1803,119 @@ def _ce_chunk_stats(logits, tb, mb, z_loss, accuracy):
     return (nll * mb).sum(), correct, lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def fused_chunked_ce_loss(x, head, targets, mask, z_loss, chunk, accuracy):
-    """Chunked LM-head CE whose BACKWARD is computed analytically in the
-    forward scan (dlogits = softmax - onehot), so each chunk's logits
-    matmul runs exactly once per train step — vs jax.checkpoint's
-    recompute-in-backward (see TransformerConfig.ce_impl). x [N,D]
-    (flattened final hidden), head [D,V], targets [N] int, mask [N] f32.
-    Returns (loss, acc). The un-differentiated call (eval) skips the
-    gradient work entirely."""
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def fused_chunked_ce_loss(x, head, targets, mask, z_loss, chunk, accuracy,
+                          con=None):
+    """LM head + CE as one op whose BACKWARD is computed analytically in
+    the forward (dlogits = softmax - onehot), so the logits' matmul runs
+    exactly once per train step — vs jax.checkpoint's
+    recompute-in-backward (see TransformerConfig.ce_impl) — and no float32
+    logits live from the forward to the backward. x [..., D] (final
+    hidden), head [D,V], targets [...] int, mask [...] f32. With
+    ``chunk`` rows or fewer it is ONE block on the operands as they are
+    (``con``, if given, constrains the block's logits' sharding); with
+    more, a scan over blocks of ``chunk`` of the flattened rows. Returns
+    (loss, acc). The un-differentiated call (eval) skips the gradient
+    work entirely."""
     nll_sum, correct_sum, denom = _fused_ce_scan(
-        x, head, targets, mask, z_loss, chunk, accuracy, want_grads=False)
+        x, head, targets, mask, z_loss, chunk, accuracy, con,
+        want_grads=False)
     return nll_sum / denom, correct_sum / denom
 
 
-def _fused_ce_scan(x, head, targets, mask, z_loss, chunk, accuracy,
+def _fused_ce_block(xb, head, tb, mb, denom, z_loss, accuracy, con,
+                    want_grads):
+    """One block of rows: (nll_sum, correct_sum) and, with want_grads,
+    (dx [..., D] f32, dhead [D,V] f32)."""
+    logits = jnp.einsum("...d,dv->...v", xb, head,
+                        preferred_element_type=jnp.float32)
+    if con is not None:
+        logits = con(logits)
+    nll_s, corr_s, lse = _ce_chunk_stats(logits, tb, mb, z_loss, accuracy)
+    if not want_grads:
+        return (nll_s, corr_s), None
+    # dloss/dlogits for loss = sum(nll*m)/denom:
+    #   (softmax * (1 + 2*z*lse) - onehot) * m / denom
+    p = jnp.exp(logits - lse[..., None])
+    dl = p * (1.0 + 2.0 * z_loss * lse)[..., None] if z_loss else p
+    # onehot subtraction as an iota-compare (TPU scatter is slow, and
+    # jax's transposed take_along_axis scatters into a linear [N * V])
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, dl.shape, dl.ndim - 1)
+              == tb[..., None])
+    dl = (dl - onehot.astype(dl.dtype)) * (mb / denom)[..., None]
+    # bf16 matmul operands (MXU), fp32 accumulation: same precision
+    # story as the rest of the model's backward.
+    dlc = dl.astype(head.dtype)
+    dxb = jnp.einsum("...v,dv->...d", dlc, head,
+                     preferred_element_type=jnp.float32)
+    dhead = jnp.einsum("...d,...v->dv", xb.astype(head.dtype), dlc,
+                       preferred_element_type=jnp.float32)
+    return (nll_s, corr_s), (dxb, dhead)
+
+
+def _fused_ce_scan(x, head, targets, mask, z_loss, chunk, accuracy, con,
                    want_grads):
-    """Scan over token chunks. Returns (nll_sum, correct_sum, denom) and,
-    with want_grads, also (dx [N,D] f32-accurate, dhead [D,V] f32): the
-    cotangents of x/head for a unit loss cotangent, already including
-    the 1/denom and z_loss terms."""
-    N, D = x.shape
-    V = head.shape[1]
-    chunk = min(chunk, N)
+    """Returns (nll_sum, correct_sum, denom) and, with want_grads, also
+    (dx like x, f32-accurate, dhead [D,V] f32): the cotangents of x/head
+    for a unit loss cotangent, already including the 1/denom and z_loss
+    terms."""
+    D, V = head.shape
+    N = targets.size
+    denom = jnp.maximum(mask.sum(), 1.0)
+    if chunk >= N:
+        (nll_sum, correct_sum), grads = _fused_ce_block(
+            x, head, targets, mask, denom, z_loss, accuracy, con, want_grads)
+        sums = (nll_sum, correct_sum, denom)
+        return (sums, grads) if want_grads else sums
     n_chunks = (N + chunk - 1) // chunk
     pad = n_chunks * chunk - N
-    xf, tf, mf = x, targets, mask
+    xf, tf, mf = x.reshape(N, D), targets.reshape(N), mask.reshape(N)
     if pad:
         xf = jnp.concatenate([xf, jnp.zeros((pad, D), xf.dtype)])
         tf = jnp.concatenate([tf, jnp.zeros((pad,), tf.dtype)])
         mf = jnp.concatenate([mf, jnp.zeros((pad,), mf.dtype)])
-    xc = xf.reshape(n_chunks, chunk, D)
-    tc = tf.reshape(n_chunks, chunk)
-    mc = mf.reshape(n_chunks, chunk)
-    denom = jnp.maximum(mf.sum(), 1.0)
+    xs = (xf.reshape(n_chunks, chunk, D), tf.reshape(n_chunks, chunk),
+          mf.reshape(n_chunks, chunk))
 
-    def body(carry, xs):
-        xb, tb, mb = xs
-        logits = jnp.einsum("cd,dv->cv", xb, head,
-                            preferred_element_type=jnp.float32)
-        nll_s, corr_s, lse = _ce_chunk_stats(logits, tb, mb, z_loss,
-                                             accuracy)
+    def body(carry, block):
+        xb, tb, mb = block
+        (nll_s, corr_s), grads = _fused_ce_block(
+            xb, head, tb, mb, denom, z_loss, accuracy, None, want_grads)
         if not want_grads:
-            nll_sum, correct_sum = carry
-            return (nll_sum + nll_s, correct_sum + corr_s), None
-        nll_sum, correct_sum, dhead = carry
-        # dloss/dlogits for loss = sum(nll*m)/denom:
-        #   (softmax * (1 + 2*z*lse) - onehot) * m / denom
-        p = jnp.exp(logits - lse[:, None])
-        dl = p * (1.0 + 2.0 * z_loss * lse)[:, None] if z_loss else p
-        # onehot subtraction as an iota-compare (TPU scatter is slow)
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, dl.shape, 1)
-                  == tb[:, None])
-        dl = (dl - onehot.astype(dl.dtype)) * (mb / denom)[:, None]
-        # bf16 matmul operands (MXU), fp32 accumulation: same precision
-        # story as the rest of the model's backward.
-        dlc = dl.astype(head.dtype)
-        dxb = jnp.einsum("cv,dv->cd", dlc, head,
-                         preferred_element_type=jnp.float32)
-        dhead = dhead + jnp.einsum("cd,cv->dv", xb.astype(head.dtype), dlc,
-                                   preferred_element_type=jnp.float32)
-        return (nll_sum + nll_s, correct_sum + corr_s, dhead), dxb
+            return (carry[0] + nll_s, carry[1] + corr_s), None
+        dxb, dhead = grads
+        return (carry[0] + nll_s, carry[1] + corr_s, carry[2] + dhead), dxb
 
     zero = jnp.zeros((), jnp.float32)
     if not want_grads:
-        (nll_sum, correct_sum), _ = jax.lax.scan(body, (zero, zero),
-                                                 (xc, tc, mc))
+        (nll_sum, correct_sum), _ = jax.lax.scan(body, (zero, zero), xs)
         return nll_sum, correct_sum, denom
-    dhead0 = jnp.zeros((D, V), jnp.float32)
     (nll_sum, correct_sum, dhead), dxc = jax.lax.scan(
-        body, (zero, zero, dhead0), (xc, tc, mc))
-    dx = dxc.reshape(n_chunks * chunk, D)[:N]
+        body, (zero, zero, jnp.zeros((D, V), jnp.float32)), xs)
+    dx = dxc.reshape(n_chunks * chunk, D)[:N].reshape(x.shape)
     return (nll_sum, correct_sum, denom), (dx, dhead)
 
 
-def _fused_ce_fwd(x, head, targets, mask, z_loss, chunk, accuracy):
+def _fused_ce_fwd(x, head, targets, mask, z_loss, chunk, accuracy, con):
     (nll_sum, correct_sum, denom), (dx, dhead) = _fused_ce_scan(
-        x, head, targets, mask, z_loss, chunk, accuracy, want_grads=True)
+        x, head, targets, mask, z_loss, chunk, accuracy, con,
+        want_grads=True)
     return ((nll_sum / denom, correct_sum / denom),
             (dx.astype(x.dtype), dhead.astype(head.dtype)))
 
 
-def _fused_ce_bwd(z_loss, chunk, accuracy, res, g):
+def _fused_ce_bwd(z_loss, chunk, accuracy, con, res, g):
     import numpy as np
 
     dx, dhead = res
     g_loss, _g_acc = g  # accuracy is a metric; its cotangent is dropped
-    n = dx.shape[0]
+    rows = dx.shape[:-1]
     # targets are int (float0 cotangent); mask is standardized to f32 by
     # the callers (lm_loss) so its zero cotangent dtype is static here.
     return ((dx * g_loss).astype(dx.dtype),
             (dhead * g_loss).astype(dhead.dtype),
-            np.zeros((n,), jax.dtypes.float0),
-            jnp.zeros((n,), jnp.float32))
+            np.zeros(rows, jax.dtypes.float0),
+            jnp.zeros(rows, jnp.float32))
 
 
 fused_chunked_ce_loss.defvjp(_fused_ce_fwd, _fused_ce_bwd)
@@ -1899,7 +1924,16 @@ fused_chunked_ce_loss.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
             z_loss: float = 0.0):
     """Next-token LM loss. batch: {"tokens": [B,T]} (targets = shift) or
-    {"inputs","targets"[,"mask"]}."""
+    {"inputs","targets"[,"mask"]}. The head and the cross entropy are ONE
+    op on the final hidden state (``fused_chunked_ce_loss``: no float32
+    [B,T,V] lives from the forward to the backward; ``cross_entropy_loss``
+    on ``forward``'s logits is the same mathematics, and the tests'
+    oracle). At ``config.loss_chunk`` 0 it is ONE block of all the rows;
+    a caller's ``loss_chunk`` and ``ce_impl`` mean what they meant. The
+    op has a gradient rule of its own, so at every ``loss_chunk`` the
+    loss differentiates in reverse mode only (``jax.jvp`` and
+    ``jax.hessian`` of it fail), and ``config.ce_accuracy`` False zeroes
+    ``accuracy`` at 0 as it does in blocks."""
     if "inputs" in batch:
         inp, tgt = batch["inputs"], batch["targets"]
         mask = batch.get("mask")
@@ -1909,37 +1943,49 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
         mask = batch.get("mask")
         if mask is not None:
             mask = mask[:, 1:]
-    if config.loss_chunk > 0:
-        if config.ce_impl not in ("fused", "checkpoint"):
-            raise ValueError(
-                f"ce_impl must be 'fused' or 'checkpoint', got "
-                f"{config.ce_impl!r}")
-        x, aux = forward(params, inp, config, mesh=mesh, return_aux=True,
-                         return_hidden=True)
-        with jax.named_scope("head_loss"):
-            head = (params["embed"]["tokens"].T if config.tied
-                    else params["lm_head"]).astype(config.compute_dtype)
-            if config.ce_impl == "fused":
-                B, T, D = x.shape
-                mf = (mask.reshape(-1).astype(jnp.float32)
-                      if mask is not None
-                      else jnp.ones((B * T,), jnp.float32))
-                loss, acc = fused_chunked_ce_loss(
-                    x.reshape(B * T, D), head, tgt.reshape(-1), mf,
-                    float(z_loss), int(config.loss_chunk),
-                    bool(config.ce_accuracy))
-                metrics = {"loss": loss, "accuracy": acc,
-                           "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
-            else:
-                loss, metrics = chunked_ce_loss(x, head, tgt, mask=mask,
-                                                z_loss=z_loss,
-                                                chunk=config.loss_chunk,
-                                                accuracy=config.ce_accuracy)
-    else:
-        logits, aux = forward(params, inp, config, mesh=mesh, return_aux=True)
-        with jax.named_scope("head_loss"):
-            loss, metrics = cross_entropy_loss(logits, tgt, mask=mask,
-                                               z_loss=z_loss)
+    if config.loss_chunk > 0 and config.ce_impl not in ("fused",
+                                                         "checkpoint"):
+        raise ValueError(
+            f"ce_impl must be 'fused' or 'checkpoint', got "
+            f"{config.ce_impl!r}")
+    x, aux = forward(params, inp, config, mesh=mesh, return_aux=True,
+                     return_hidden=True)
+    with jax.named_scope("head_loss"):
+        head = (params["embed"]["tokens"].T if config.tied
+                else params["lm_head"]).astype(config.compute_dtype)
+        if config.loss_chunk > 0 and config.ce_impl == "checkpoint":
+            loss, metrics = chunked_ce_loss(x, head, tgt, mask=mask,
+                                            z_loss=z_loss,
+                                            chunk=config.loss_chunk,
+                                            accuracy=config.ce_accuracy)
+        else:
+            chunk, con = config.loss_chunk, None
+            if not chunk:
+                # ONE block of all the rows, which under a mesh stay on
+                # their devices: the logits are written once and read by
+                # the sum, the argmax and both backward matmuls, which
+                # make ``dlogits`` in their operand fusions. The largest
+                # block a cell of the benchmark has, SmallThinker's
+                # ``[16384, 37984]`` float32 of 2.49 GB (v5e, PR 52's
+                # traced pair): the step 474.8 -> 449.5 ms, outside any
+                # scope 36.8 -> 15.0 ms (the three passes over 2.49 GB
+                # that XLA put between the token-minor logits a batch of
+                # ONE row gets and the LINEAR float32 ``[N * V]`` that
+                # jax's transposed ``take_along_axis`` scatters into:
+                # 7.4 + 7.2 + 5.6 ms). Logits that do not fit want a
+                # ``loss_chunk``: no cell has such, so no rule picks one.
+                chunk = tgt.size
+                if mesh is not None:
+                    def con(logits):
+                        return constrain(logits, mesh, _BATCH, AXIS_SEQUENCE,
+                                         AXIS_TENSOR)
+            mf = (jnp.ones(tgt.shape, jnp.float32) if mask is None
+                  else mask.astype(jnp.float32))
+            loss, acc = fused_chunked_ce_loss(
+                x, head, tgt, mf, float(z_loss), int(chunk),
+                bool(config.ce_accuracy), con)
+            metrics = {"loss": loss, "accuracy": acc,
+                       "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
     if config.n_experts > 0:
         loss = (loss + config.router_aux_weight * aux["balance"]
                 + config.router_z_weight * aux["z"])

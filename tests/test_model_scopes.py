@@ -85,6 +85,35 @@ def test_unchunked_loss_accumulation_and_experts_have_their_scopes():
     assert not any(part == "mlp" for part, _ in moe)
 
 
+def test_default_head_loss_backward_rule_is_in_head_loss():
+    """At the default ``loss_chunk`` 0 head and loss are one op with its
+    own gradient rule, whose gradients are made in its forward: the rule's
+    backward is the scaling by the loss's cotangent alone, and jax runs a
+    rule under the scopes its op was called in, so with a cotangent that
+    is not 1 (which XLA folds away) every instruction of it reads
+    ``head_loss`` ``backward`` and the scaling adds no other name."""
+    cfg = models.tiny(remat=True)
+    assert cfg.loss_chunk == 0
+    params = jax.eval_shape(lambda k: models.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    batch = {"tokens": jax.ShapeDtypeStruct((4, 33), jnp.int32)}
+
+    def names(scaled: bool) -> set[str]:
+        def grad(p, b, s):
+            return jax.grad(lambda p: transformer.lm_loss(p, b, cfg)[0]
+                            * (s if scaled else 1.0))(p)
+        text = jax.jit(grad).lower(
+            params, batch, jax.ShapeDtypeStruct((), jnp.float32)
+        ).compile().as_text()
+        return set(re.findall(r'op_name="([^"]*)"', text))
+
+    plain, scaled = names(False), names(True)
+    rule = {n for n in scaled - plain if "head_loss" in n}
+    assert any(n.endswith("/mul") for n in rule), sorted(scaled - plain)
+    assert {scopes.classify(n) for n in rule} == {("head_loss", "backward")}
+    assert scaled - plain - rule == {"s"}      # the cotangent's parameter
+
+
 def test_dropless_experts_carry_their_sub_scopes_in_every_pass():
     """The four sub-scopes the dropless block of ``ops/moe.py`` opens
     inside ``moe`` (``moe_shared`` is a shared expert's, which this model

@@ -1,5 +1,7 @@
 """Model library tests (tiny configs on the 8-device CPU mesh)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -261,6 +263,135 @@ def test_fused_ce_matches_checkpoint_ce():
             params)
         for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
             assert np.allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def _materialised_oracle(cfg, batch, z):
+    """``cross_entropy_loss`` on ``forward``'s whole logits: what
+    ``lm_loss`` was at ``loss_chunk`` 0 before the head and the loss were
+    one op, and the mathematics they are held to."""
+    from ray_tpu.models import transformer as tfm
+
+    toks, mask = batch["tokens"], batch.get("mask")
+
+    def loss(p):
+        logits = tfm.forward(p, toks[:, :-1], cfg)
+        return tfm.cross_entropy_loss(
+            logits, toks[:, 1:], mask=None if mask is None else mask[:, 1:],
+            z_loss=z)
+    return loss
+
+
+@pytest.mark.parametrize("arch,masked,z,loss_chunk", [   # gpt2 is tied
+    ("gpt2", False, 0.0, 0), ("gpt2", True, 1e-3, 24), ("gpt2", False, 0.0, 24),
+    ("llama", True, 0.0, 0), ("llama", False, 1e-3, 24),
+    ("llama", True, 1e-3, 0)])
+def test_head_loss_is_the_materialised_cross_entropy(arch, masked, z,
+                                                     loss_chunk):
+    """The loss, every metric and the gradients of ``lm_loss`` are
+    ``cross_entropy_loss``'s on materialised logits, at the default
+    ``loss_chunk`` 0 (one block of all the rows) and in three blocks whose
+    rows (24) do not divide the batch's 64, and the un-differentiated call
+    gives the differentiated one's loss."""
+    from ray_tpu.models import transformer as tfm
+
+    assert _cfg(arch).loss_chunk == 0
+    cfg = _cfg(arch, loss_chunk=loss_chunk)
+    assert cfg.tied == (arch == "gpt2") and cfg.ce_impl == "fused"
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                                          cfg.vocab_size)}
+    if masked:
+        batch["mask"] = (jax.random.uniform(jax.random.PRNGKey(2), (2, 33))
+                         > 0.2).astype(np.float32)
+    oracle = _materialised_oracle(cfg, batch, z)
+
+    def program(p):
+        return tfm.lm_loss(p, batch, cfg, z_loss=z)
+
+    (l0, m0), g0 = jax.value_and_grad(oracle, has_aux=True)(params)
+    (l1, m1), g1 = jax.value_and_grad(program, has_aux=True)(params)
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+    assert sorted(m1) == sorted(m0)
+    for name in m0:
+        # exp(loss) carries the loss's last bit 5.5 times over
+        np.testing.assert_allclose(float(m1[name]), float(m0[name]),
+                                   rtol=1e-5, err_msg=name)
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=2e-6)
+    undifferentiated, _ = program(params)
+    np.testing.assert_allclose(float(undifferentiated), float(l1), rtol=1e-6)
+
+
+def _avals(jaxpr, found, primitive=None):
+    """Every (shape, dtype) a jaxpr's equations (those of ``primitive``)
+    produce, its sub-jaxprs' included."""
+    for eqn in jaxpr.eqns:
+        if primitive in (None, eqn.primitive.name):
+            found.update((v.aval.shape, str(v.aval.dtype))
+                         for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _avals(sub, found, primitive)
+    return found
+
+
+def test_default_head_loss_engages_and_keeps_no_whole_float32_logits():
+    """``jax.grad(lm_loss)`` at the default: the whole float32 logits
+    exist once, as the op's one block, and nothing scatters into them
+    (jax's transposed ``take_along_axis``, which the materialised loss's
+    gradient holds, is what cost the copies); with a ``loss_chunk`` no
+    float32 array of all the rows by the vocabulary exists, only a
+    block's."""
+    from ray_tpu.models import transformer as tfm
+
+    cfg = _cfg("llama", vocab_size=320)     # no other width of the model
+    V = cfg.vocab_size
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jnp.zeros((2, 41), jnp.int32)}    # 80 rows, D is 64
+
+    def produced(loss, primitive=None):
+        return _avals(jax.make_jaxpr(jax.grad(loss))(params).jaxpr, set(),
+                      primitive)
+
+    def program(loss_chunk):
+        c = dataclasses.replace(cfg, loss_chunk=loss_chunk)
+        return lambda p: tfm.lm_loss(p, batch, c)[0]
+
+    def oracle(p):
+        return _materialised_oracle(cfg, batch, 0.0)(p)[0]
+
+    whole = {((2, 40, V), "float32"), ((80, V), "float32")}
+    assert whole & produced(oracle, "scatter-add")
+    assert not whole & produced(program(0), "scatter-add")
+    assert ((2, 40, V), "float32") in produced(program(0))    # the block
+    blocks = produced(program(24))
+    assert not whole & blocks
+    assert ((24, V), "float32") in blocks
+
+
+def test_head_loss_under_a_mesh_is_one_block_on_each_devices_own_rows():
+    """Two devices share the batch's rows: the default is one block of all
+    the rows as they lie (blocks would regroup rows across devices), the
+    block's logits keep ``forward``'s sharding constraint, and loss and
+    gradients are the unsharded program's."""
+    from ray_tpu.models import transformer as tfm
+
+    cfg = _cfg("llama")
+    mesh = MeshConfig(data=1, fsdp=2).build(jax.devices()[:2])
+    params = tfm.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                                          cfg.vocab_size)}
+    grad = jax.jit(jax.value_and_grad(
+        lambda p: tfm.lm_loss(p, batch, cfg, mesh=mesh)[0]))
+    text = grad.lower(params).as_text()
+    logits = f"tensor<2x32x{cfg.vocab_size}xf32>"
+    assert any("sharding_constraint" in line and logits in line
+               for line in text.splitlines()), "the logits are unconstrained"
+    l1, g1 = grad(params)
+    l0, g0 = jax.value_and_grad(
+        lambda p: _materialised_oracle(cfg, batch, 0.0)(p)[0])(params)
+    np.testing.assert_allclose(float(l1), float(l0), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=2e-6)
 
 
 def test_fused_clip_adamw_matches_optax():
