@@ -475,17 +475,26 @@ class Worker:
         from ray_tpu._private.worker_exit import await_chips_free
         from ray_tpu.accelerators.tpu import (TPUAcceleratorManager,
                                               host_chip_nodes)
+        from ray_tpu.util import tracing
 
         host = host_chip_nodes()
         nodes = [host[int(c)] for c in chips if int(c) < len(host)]
-        waited = await_chips_free(nodes)
-        if waited:
-            print(f"[ray_tpu] worker {self.worker_id} waited {waited:.1f} s "
-                  f"for the last holder of {', '.join(nodes)} to let go",
-                  file=sys.stderr, flush=True)
-        self._chips = chips
-        TPUAcceleratorManager.set_current_process_visible_accelerator_ids(
-            chips)
+        # The one span of a lease (a repeated push returned above; the
+        # head dispatches nothing before this process has its runtime, so
+        # the span is kept): the stderr line is the operator's log,
+        # ``waited_s`` the timeline's.
+        with tracing.span("worker.hold_chips", chips=chips,
+                          nodes=len(nodes)) as attrs:
+            waited = attrs["waited_s"] = await_chips_free(nodes)
+            if waited:
+                print(f"[ray_tpu] worker {self.worker_id} waited "
+                      f"{waited:.1f} s for the last holder of "
+                      f"{', '.join(nodes)} to let go",
+                      file=sys.stderr, flush=True)
+            self._chips = chips
+            worker_context.set_held_chips(chips)
+            TPUAcceleratorManager.set_current_process_visible_accelerator_ids(
+                chips)
 
     # ------------------------------------------------------------------
     # actor concurrency plumbing

@@ -49,6 +49,20 @@ def try_runtime():
     return _runtime
 
 
+# The chips this process's one chip lease pointed it at
+# (Worker._hold_chips); None in a process that holds none.
+_held_chips: "list | None" = None
+
+
+def set_held_chips(chips: list) -> None:
+    global _held_chips
+    _held_chips = list(chips)
+
+
+def held_chips() -> "list | None":
+    return _held_chips
+
+
 def get_head():
     return _head
 

@@ -14,7 +14,8 @@ import inspect
 from typing import Any, Callable
 
 import ray_tpu
-from ray_tpu._private.worker_context import global_runtime
+from ray_tpu._private.worker_context import global_runtime, held_chips
+from ray_tpu.train.backend import JaxConfig
 from ray_tpu.train.checkpoint import Checkpoint, CheckpointManager
 from ray_tpu.train.config import CheckpointConfig, ScalingConfig
 from ray_tpu.util import tracing
@@ -112,6 +113,7 @@ class TrainWorker:
         self.rank = rank
         self.world_size = world_size
         self.group_name = group_name
+        self._jax_backend = isinstance(backend_config, JaxConfig)
         if backend_config is not None:
             backend = backend_config.backend_cls()()
             # Dispatch on arity, not exception type: a TypeError raised
@@ -149,6 +151,17 @@ class TrainWorker:
         session_mod.set_session(session)
         try:
             with tracing.span("train.loop", rank=self.rank):
+                if self._jax_backend and held_chips():
+                    # What the loop's first ``jax.devices()`` would do,
+                    # under a name: the import and libtpu opening the
+                    # leased chips. A worker without chips starts nothing.
+                    with tracing.span("train.backend_init") as attrs:
+                        import jax
+
+                        devices = jax.devices()
+                        attrs.update(platform=devices[0].platform,
+                                     device_kind=devices[0].device_kind,
+                                     devices=len(devices))
                 sig = inspect.signature(fn)
                 if len(sig.parameters) == 0:
                     fn()

@@ -83,6 +83,9 @@ def test_fit_leaves_a_timeline_and_removes_a_stale_one(cluster, tmp_path):
     init = spans["runtime.init"][-1]
     assert init["tid"] == fit["tid"] and init["args"]["head"] == "started"
     assert init["ts"] + init["dur"] <= fit["ts"]
+    # a worker whose lease has no chips starts no backend and holds none
+    assert "train.backend_init" not in spans
+    assert "worker.hold_chips" not in spans
 
 
 def test_a_failed_run_leaves_its_timeline_too(cluster, tmp_path):
@@ -115,3 +118,85 @@ def test_a_timeline_that_cannot_be_written_costs_the_run_nothing(
                                              storage_path=str(tmp_path))).fit()
     assert result.metrics["loss"] == 1.0
     assert not os.path.exists(os.path.join(result.path, TIMELINE_FILE))
+
+
+@pytest.fixture
+def emitted(monkeypatch):
+    from ray_tpu.util import tracing
+
+    events: list[dict] = []
+    monkeypatch.setattr(tracing, "_emit", events.append)
+    return events
+
+
+@pytest.mark.parametrize("chips, backend, expected", [
+    ([0], "jax", True),       # a chip lease: the backend starts under a name
+    (None, "jax", False),     # no chips: nothing recorded, nothing imported
+    ([0], "other", False),    # another framework's worker keeps its chips
+])
+def test_the_loop_opens_with_the_backends_start_only_on_a_chip_lease(
+        chips, backend, expected, emitted, monkeypatch):
+    from ray_tpu.train import backend as backend_mod
+    from ray_tpu.train import worker_group
+
+    monkeypatch.setattr(worker_group, "held_chips", lambda: chips)
+    monkeypatch.setattr(backend_mod.JaxBackend, "on_worker_setup",
+                        lambda self, *a, **k: None)
+    config = (backend_mod.JaxConfig() if backend == "jax"
+              else backend_mod.BackendConfig())
+    worker = worker_group.TrainWorker._cls(0, 1, "unit", config)
+    seen = []
+    assert worker.run(lambda: seen.append(len(emitted)), None, None,
+                      "unit", None, None) == 0
+    by_name = {e["name"]: e for e in emitted}
+    assert ("train.backend_init" in by_name) is expected
+    loop = by_name["train.loop"]
+    if expected:
+        init = by_name["train.backend_init"]
+        assert init["parent"] == "train.loop"
+        assert loop["start"] <= init["start"] <= init["end"] <= loop["end"]
+        assert init["attributes"]["platform"] == "cpu"
+        assert init["attributes"]["devices"] >= 1
+        assert init["attributes"]["device_kind"]
+        assert "seconds" not in init["attributes"]
+        # closed before the user's function ran
+        assert seen == [emitted.index(init) + 1]
+
+
+def test_a_lease_records_its_wait_once(emitted, monkeypatch):
+    """``Worker._hold_chips`` on a first lease: one ``worker.hold_chips``
+    span with what ``await_chips_free`` returned; the repeated push that
+    every later task brings records nothing."""
+    import types
+
+    from ray_tpu._private import worker, worker_context, worker_exit
+    from ray_tpu.accelerators import tpu
+
+    probed, pointed = [], []
+
+    def wait(nodes):
+        probed.append(list(nodes))
+        return 2.5
+
+    monkeypatch.setattr(worker_exit, "await_chips_free", wait)
+    monkeypatch.setattr(tpu, "host_chip_nodes",
+                        lambda: ["/dev/vfio/0", "/dev/vfio/1"])
+    monkeypatch.setattr(
+        tpu.TPUAcceleratorManager,
+        "set_current_process_visible_accelerator_ids",
+        staticmethod(pointed.append))
+    monkeypatch.setattr(worker_context, "_held_chips", None)
+    me = types.SimpleNamespace(_chips=None, worker_id="w-unit")
+
+    worker.Worker._hold_chips(me, [1])
+    (ev,) = emitted
+    assert ev["name"] == "worker.hold_chips"
+    assert ev["attributes"] == {"chips": [1], "nodes": 1, "waited_s": 2.5}
+    assert probed == [["/dev/vfio/1"]] and pointed == [[1]]
+    assert me._chips == [1] and worker_context.held_chips() == [1]
+
+    worker.Worker._hold_chips(me, [1])           # every push repeats it
+    assert len(emitted) == 1 and len(probed) == 1
+    with pytest.raises(RuntimeError, match="cannot be re-pointed"):
+        worker.Worker._hold_chips(me, [0])
+    assert len(emitted) == 1
