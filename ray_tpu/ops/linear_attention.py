@@ -96,8 +96,8 @@ into ``gated_head_norm``, its statistic's broadcast and its output gate),
 none of them arithmetic. So ``gates`` and ``gated_head_norm`` make their
 low-rank maps by ONE plain matmul against ``f_b`` / ``g_b`` viewed [r, H *
 d] (the contraction ``btr,rhk->bthk`` is, whose result XLA writes by
-heads), take per-head vectors as [H * d] views, and the head norm's mean
-over a head's lanes is a product with ``_head_lanes``; ``gated_delta_rule``
+heads), take per-head vectors as [H * d] views, and the plain head norm's
+mean over a head's lanes is a product with ``_head_lanes``; ``gated_delta_rule``
 takes ``g`` flat or by heads and tells them apart by rank. The parameter
 leaves keep their shapes: the views are of a megabyte of weights
 (PERF.md section 6, PR 43; ``tests/test_kda_layout.py`` holds the step
@@ -121,6 +121,29 @@ crossed between the two tilings on the way in (unnamed transposes) and on
 the way out (copies into the kernels' tiling): 87 + 11 + 10 ms of a 720-ms
 step (PERF.md section 6, PR 49).
 
+**The head norm behind the rule is ONE pass a direction too** (PR 54):
+``rmsnorm_head(o) * gate``, the gate ``sigmoid(low @ g_b)`` of KDA's
+low-rank map (``gated_head_norm``) or ``silu(z)`` of Gated DeltaNet's
+projection (``silu_gated_head_norm``), one ``_head_norm`` handed the gate's
+source. Two implementations, chosen as the chains' are (``_on_one_tpu``):
+two Pallas kernels over the chains' tiles (``_norm_fwd_kernel``,
+``_norm_bwd_kernel``: a head's mean of squares a float32 lane sum, ``low @
+g_b`` made on the MXU a block at a time; the backward keeps the operands,
+makes the statistic and the gate again and sums ``d_o_norm`` in its output
+block), each at the rate a plain copy of its operands reaches on a v5e;
+elsewhere ``_head_norm_plain``, which is what they are tested against.
+**Arrays that no longer exist on the kernels' path**, each [B, T, H * dv]:
+the output gate's float32 pre-activation (268 MB at [1, 16384, 4096],
+written to be read once), the float32 spread of a head's statistic over
+its lanes (a ``_head_lanes`` product at ``highest``), their gradients, and
+the float32 gradient of the pre-activation, which XLA rounded in a pass
+of its own under no scope: it leaves the backward kernel in the compute
+dtype, ``dz`` as it is, or what ``d_low`` and ``d_g_b`` (two small XLA
+matmuls) read. A plain layer made four passes forward where this makes
+one: 1.64 -> 0.49 ms forward and 6.18 -> 2.31 forward + backward for KDA,
+1.15 -> 0.64 and 3.49 -> 1.68 for Gated DeltaNet (PERF.md section 6, PR
+54).
+
 ``SCOPES`` are the named scopes this file opens around the parts of a
 linear (KDA or Gated DeltaNet) layer's mixer that are neither projections
 nor the delta rule
@@ -130,7 +153,8 @@ chains' Pallas calls, which ``step_kda_kernel_ms`` and
 ``step_attn_kernel_ms`` therefore count beside the rule's) and
 ``kda_gate`` (the decay's and the output gate's low-rank maps, or Gated
 DeltaNet's one decay a head; ``beta``, softplus / exp, the head norm and
-the gate's product, ``sigmoid`` or ``silu(z)``).
+the gate's product, ``sigmoid`` or ``silu(z)``: on a TPU the head norm's
+Pallas calls, counted by the same two readers).
 """
 
 from __future__ import annotations
@@ -270,18 +294,62 @@ def gated_head_norm(o, h, w, *, eps: float):
     normed input ``h`` (scope ``kda_gate``). ``w``: ``g_a`` [D, r],
     ``g_b`` [r, H, dv], ``o_norm`` [dv]: the leaves keep their shapes.
     Worked FLAT, [B, T, H * dv], as the delta rule's kernels write ``o``
-    (module docstring): the gate is ONE plain matmul ``[B T, r] x [r, H *
-    dv]`` (``gates``' form), the mean over a head's lanes and its spread
-    back are products with ``_head_lanes``, the rest is elementwise; all
-    float32, rounded once at the end."""
+    (module docstring), all float32, rounded once at the end. ``low = h @
+    g_a`` [B, T, r] is a plain matmul either way; the norm and the gate
+    are ``_head_norm``'s, which is handed ``(low, g_b)`` for the gate's
+    source: on one TPU chip ONE Pallas pass forward and one backward that
+    make ``low @ g_b`` on the MXU and never write the pre-activation;
+    anywhere else ONE plain matmul ``[B T, r] x [r, H * dv]`` (``gates``'
+    form), products with ``_head_lanes`` and an elementwise rest."""
     dt = h.dtype
     with jax.named_scope("kda_gate"):
         low = jnp.einsum("btd,dr->btr", h, w["g_a"].astype(dt))
-        gate = jnp.einsum("btr,rc->btc", low,
-                          w["g_b"].astype(dt).reshape(low.shape[-1], -1),
-                          preferred_element_type=jnp.float32)
-        return (_normed_heads(o, w["o_norm"], eps)
-                * jax.nn.sigmoid(gate)).astype(dt).reshape(o.shape)
+        g_b = w["g_b"].astype(dt).reshape(low.shape[-1], -1)
+        return _head_norm(o, (low, g_b), w["o_norm"], eps)
+
+
+def silu_gated_head_norm(o, z, weight, *, eps: float):
+    """Gated DeltaNet's output: ``rmsnorm_head(o; weight) * silu(z)``, ``o``
+    [B, T, H, dv] the delta rule's output, ``z`` [B, T, H * dv] the FLAT
+    full-rank gate projection of the block's normed input (where KDA's
+    gate is ``sigmoid`` of a low-rank map, ``gated_head_norm``), ``weight``
+    [dv] shared by the heads (scope ``kda_gate``). Worked flat and in
+    float32, rounded once at the end, as ``gated_head_norm`` is, by the
+    same ``_head_norm`` handed ``(z,)``: the same one Pallas pass a
+    direction on one TPU chip (``z`` read as it is, ``dz`` written
+    rounded), the same plain form anywhere else."""
+    with jax.named_scope("kda_gate"):
+        return _head_norm(o, (z,), weight, eps)
+
+
+def _head_norm(o, source, weight, eps: float):
+    """``rmsnorm_head(o; weight)`` times the output gate -> ``o``'s shape
+    [B, T, H, dv] and dtype. **The gate is what ``source`` holds**: ``(z,)``
+    [B, T, H * dv] for ``silu(z)``, ``(low, g_b)`` [B, T, r], [r, H * dv]
+    for ``sigmoid(low @ g_b)``. **Two implementations, chosen as the
+    chains' are** (``_on_one_tpu``: one rule for the whole mixer, no
+    option): the Pallas kernels further down (``_head_norm_kernels``: one
+    pass over ``o`` and the source forward, one backward; no float32 [B,
+    T, H * dv] array reaches HBM) or ``_head_norm_plain``, the same
+    arithmetic in plain XLA, which is also what the kernels are tested
+    against."""
+    fused = _on_one_tpu(o, o.shape[-1], o.shape[-1])
+    return (_head_norm_kernels if fused else _head_norm_plain)(
+        o, source, weight, eps)
+
+
+def _head_norm_plain(o, source, weight, eps: float):
+    """``_head_norm`` in plain XLA on the flat arrays: the pre-activation
+    of a low-rank gate ONE matmul ``[B T, r] x [r, H * dv]`` summed in
+    float32, the head's statistic by ``_normed_heads``, the rest
+    elementwise; float32 throughout, rounded once to ``o``'s dtype."""
+    if len(source) == 1:
+        gate = jax.nn.silu(source[0].astype(jnp.float32))
+    else:
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "btr,rc->btc", *source, preferred_element_type=jnp.float32))
+    return (_normed_heads(o, weight, eps) * gate).astype(o.dtype).reshape(
+        o.shape)
 
 
 def _normed_heads(o, weight, eps: float):
@@ -296,19 +364,6 @@ def _normed_heads(o, weight, eps: float):
     scale = jnp.einsum("bth,hc->btc", jax.lax.rsqrt(mean + eps), lanes,
                        precision=_HIGHEST)
     return of * scale * jnp.tile(weight.astype(jnp.float32), heads)
-
-
-def silu_gated_head_norm(o, z, weight, *, eps: float):
-    """Gated DeltaNet's output: ``rmsnorm_head(o; weight) * silu(z)``, ``o``
-    [B, T, H, dv] the delta rule's output, ``z`` [B, T, H * dv] the FLAT
-    full-rank gate projection of the block's normed input (where KDA's
-    gate is ``sigmoid`` of a low-rank map, ``gated_head_norm``), ``weight``
-    [dv] shared by the heads (scope ``kda_gate``). Worked flat and in
-    float32, rounded once at the end, as ``gated_head_norm`` is."""
-    with jax.named_scope("kda_gate"):
-        return (_normed_heads(o, weight, eps)
-                * jax.nn.silu(z.astype(jnp.float32))).astype(
-                    o.dtype).reshape(o.shape)
 
 
 def log_decay_min(g):
@@ -1286,15 +1341,19 @@ def _conv_tokens(t: int) -> int:
     return min(_CONV_TOKENS, -(-t // _CONV_HALO_BLOCK) * _CONV_HALO_BLOCK)
 
 
+def _conv_tile(x) -> tuple[int, int]:
+    """(tokens, lanes) a grid step of ``x`` [B, T, C]: T whole
+    ``_conv_tokens``, C whole heads of 128 lanes."""
+    return (_conv_tokens(x.shape[1]),
+            next(n for n in (_CONV_LANES, 256, 128) if x.shape[2] % n == 0))
+
+
 def _conv_call(norm: bool, d: int, x, w, dy=None):
-    """``_conv_launch`` with the tile the operands take (``x`` [B, T, C],
-    T whole ``_conv_tokens``, C whole heads of ``d`` = 128 lanes),
+    """``_conv_launch`` with the tile the operands take (``_conv_tile``),
     interpreted where the attention kernels are."""
     from ray_tpu.ops.attention import _interpret
 
-    lanes = next(n for n in (_CONV_LANES, 256, 128) if x.shape[2] % n == 0)
-    return _conv_launch(norm, d, (_conv_tokens(x.shape[1]), lanes),
-                        _interpret(), x, w, dy)
+    return _conv_launch(norm, d, _conv_tile(x), _interpret(), x, w, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -1328,6 +1387,188 @@ def _chain_kernels(x, w, norm: bool):
     y = _kernel_chain(x, w.astype(_F32).reshape(w.shape[0], -1), norm,
                       w.shape[-1])
     return y[:, :t]
+
+
+# -- the gated head norm, as Pallas kernels -----------------------------------------
+#
+# The chains' tiling and the chains' loops: a grid step is ``_CONV_TOKENS``
+# tokens of ``_CONV_LANES`` lanes (whole heads) of the flat [B, T, H * dv]
+# arrays, and inside it a head's 128 lanes and ``_CONV_ROWS`` tokens run
+# start to end at a time. A token reads nothing of another, so there is no
+# halo and no order among the tiles. A head's mean of squares is a lane sum
+# in float32. The gate's pre-activation is read (``z``) or made on the MXU
+# from the block's [rows, r] of ``low`` and one head's [r, 128] of ``g_b``,
+# float32 sums; the backward makes the statistic and the gate again and
+# keeps nothing of the forward's. ``d_weight`` [B, 8, C] float32 sums in its
+# output block along the token axis, eight rows of whole registers (the
+# chains' ``dw``); what is left of the sum (batch, the eight rows, the
+# heads that share the weight) is XLA's, over a few kilobytes. All
+# arithmetic float32, ONE rounding, at each store.
+
+
+def _gate_parts(source, rows, cols):
+    """(the output gate's pre-activation ``x``, ``sigmoid(x)``) float32
+    [rows, d] for a block's rows and one head's lanes: ``z`` as it is read
+    where ``source`` is ``(z_ref,)``, ``low @ g_b`` where it is ``(low_ref,
+    g_b_ref)``."""
+    if len(source) == 1:
+        x = source[0][0, rows, cols].astype(_F32)
+    else:
+        low_ref, g_b_ref = source
+        x = jnp.dot(low_ref[0, rows, :], g_b_ref[:, cols],
+                    preferred_element_type=_F32)
+    return x, jax.nn.sigmoid(x)
+
+
+def _norm_fwd_kernel(o_ref, *refs, eps: float, d: int):
+    *source, w_ref, y_ref = refs
+    silu = len(source) == 1
+    size, starts = _conv_blocks(o_ref)
+    w = w_ref[...]
+    for at in range(0, o_ref.shape[2], d):
+        cols = slice(at, at + d)
+        for r0 in starts:
+            rows = slice(r0, r0 + size)
+            of = o_ref[0, rows, cols].astype(_F32)
+            x, s = _gate_parts(source, rows, cols)
+            scale = jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
+            y_ref[0, rows, cols] = (of * scale * w * (x * s if silu else s)
+                                    ).astype(y_ref.dtype)
+
+
+def _norm_bwd_kernel(o_ref, *refs, eps: float, d: int):
+    import jax.experimental.pallas as pl
+
+    *source, w_ref, dy_ref, do_ref, dx_ref, dw_ref = refs
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    silu = len(source) == 1
+    size, starts = _conv_blocks(o_ref)
+    w = w_ref[...]
+    for at in range(0, o_ref.shape[2], d):
+        cols = slice(at, at + d)
+        total = jnp.zeros((8, d), _F32)
+        for r0 in starts:
+            rows = slice(r0, r0 + size)
+            of = o_ref[0, rows, cols].astype(_F32)
+            x, s = _gate_parts(source, rows, cols)
+            gate, slope = ((x * s, s * (1.0 + x * (1.0 - s))) if silu else
+                           (s, s * (1.0 - s)))
+            scale = jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
+            n = of * scale
+            dy = dy_ref[0, rows, cols].astype(_F32)
+            by_n = dy * n                   # y = n w gate
+            dx_ref[0, rows, cols] = (by_n * w * slope).astype(dx_ref.dtype)
+            # n = o / rms(o): d_o = (dn - n mean(dn n)) / rms(o)
+            dn = dy * w * gate
+            do_ref[0, rows, cols] = (scale * (
+                dn - n * jnp.mean(dn * n, -1, keepdims=True))
+            ).astype(do_ref.dtype)
+            by_gate = by_n * gate
+            total = total + sum(by_gate[r:r + 8] for r in range(0, size, 8))
+        dw_ref[0, :, cols] += total
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _norm_launch(eps, tile, interpret, o, source, weight, dy=None):
+    """The head norm's forward (``dy`` None: -> y) or backward (-> d_o, the
+    pre-activation's gradient in the source's dtype, d_weight [B, 8, C]
+    float32) over (batch, lane blocks, token tiles), behind a ``jax.jit``
+    of its own as ``_conv_launch`` is. ``o`` [B, T, C] and ``tile``
+    (tokens, lanes) as ``_norm_call`` gives them; ``source`` ``(z,)`` or
+    ``(low, g_b)``; ``weight`` [1, d] float32."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ray_tpu.ops.attention import _FLASH_VMEM_MOST
+
+    b, t, c = o.shape
+    rows, lanes = tile
+    block = pl.BlockSpec((1, rows, lanes), lambda i, l, j: (i, j, l))
+    gate = [block] if len(source) == 1 else [
+        pl.BlockSpec((1, rows, source[0].shape[2]), lambda i, l, j: (i, j, 0)),
+        pl.BlockSpec((source[1].shape[0], lanes), lambda i, l, j: (0, l))]
+    shared = pl.BlockSpec(weight.shape, lambda i, l, j: (0, 0))
+    like = jax.ShapeDtypeStruct(o.shape, o.dtype)
+    params = dict(
+        grid=(b, c // lanes, t // rows), interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_MOST))
+    kernel = dict(eps=eps, d=weight.shape[1])
+    if dy is None:
+        return pl.pallas_call(
+            functools.partial(_norm_fwd_kernel, **kernel),
+            in_specs=[block, *gate, shared], out_specs=block, out_shape=like,
+            **params)(o, *source, weight)
+    return pl.pallas_call(
+        functools.partial(_norm_bwd_kernel, **kernel),
+        in_specs=[block, *gate, shared, block],
+        out_specs=[block, block,
+                   pl.BlockSpec((1, 8, lanes), lambda i, l, j: (i, 0, l))],
+        out_shape=[like, jax.ShapeDtypeStruct(o.shape, source[0].dtype),
+                   jax.ShapeDtypeStruct((b, 8, c), _F32)],
+        **params)(o, *source, weight, dy)
+
+
+def _norm_call(eps: float, o, source, weight, dy=None):
+    """``_norm_launch`` with the tile the operands take, the chains'
+    (``_conv_tile``), interpreted where the attention kernels are."""
+    from ray_tpu.ops.attention import _interpret
+
+    return _norm_launch(eps, _conv_tile(o), _interpret(), o, source, weight,
+                        dy)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _kernel_head_norm(o, source, weight, eps):
+    """``o`` [B, T, C], T whole tiles; ``source`` ``(z,)`` or ``(low,
+    g_b)``; ``weight`` [1, d] float32 -> the gated norm [B, T, C] in
+    ``o``'s dtype. The backward keeps the operands and makes the statistic
+    and the gate again inside its one pass."""
+    return _norm_call(eps, o, source, weight)
+
+
+def _kernel_head_norm_fwd(o, source, weight, eps):
+    return _norm_call(eps, o, source, weight), (o, source, weight)
+
+
+def _kernel_head_norm_bwd(eps, kept, dy):
+    """``dx``, the pre-activation's gradient, leaves the kernel rounded
+    once: it IS ``dz``, and the low-rank map's two small matmuls, XLA's,
+    read it as they read XLA's own rounding pass of the float32 one.
+    ``d_g_b`` sums over the tokens, the MAJOR dimension of both operands:
+    asked for in float32 XLA takes them as they lie; asked for in bfloat16
+    it first writes ``dx`` transposed, [H * dv, B T], 0.40 ms of a 0.81-ms
+    backward at [1, 16384, 4096] (PERF.md section 6, PR 54)."""
+    _, source, weight = kept
+    d_o, dx, dw = _norm_call(eps, *kept, dy)
+    d_source = (dx,) if len(source) == 1 else (
+        jnp.einsum("btc,rc->btr", dx, source[1]),
+        jnp.einsum("btr,btc->rc", source[0], dx,
+                   preferred_element_type=_F32).astype(source[1].dtype))
+    return d_o, d_source, dw.reshape(-1, *weight.shape).sum(0)
+
+
+_kernel_head_norm.defvjp(_kernel_head_norm_fwd, _kernel_head_norm_bwd)
+
+
+def _head_norm_kernels(o, source, weight, eps: float):
+    """``_head_norm`` through the kernels: ``o`` viewed flat, T padded
+    behind the row to whole tiles as ``_chain_kernels`` pads it (zeros: no
+    token reads another, a zero ``o`` norms to zero, and their ``dy`` is
+    zero), the weight [1, dv] in float32."""
+    b, t, heads, dv = o.shape
+    flat, (by_token, *rest) = o.reshape(b, t, -1), source   # z, or low
+    pad = -t % _conv_tokens(t)
+    if pad:
+        flat, by_token = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
+                          for a in (flat, by_token))
+    y = _kernel_head_norm(flat, (by_token, *rest),
+                          weight.astype(_F32).reshape(1, dv), eps)
+    return y[:, :t].reshape(b, t, heads, dv)
 
 
 def _by_kernels(q, k, v, g, beta):

@@ -7,8 +7,9 @@ every lane and the key heads repeated; a ``T`` that is no whole number of
 chunks; a decay at the strong end; bfloat16 operands. And compiled for a
 described ``v5e:2x2`` device at the cell's widths: ``_on_one_tpu`` takes
 this case, the mixer's forward, recompute and backward hold the kernels
-and no ``while`` (the scan's loop), and no array of the operands' size
-changes its tiling on the way (``tests/test_kda_layout.py``'s assertion).
+(the rule's, the chains' and the head norm's) and no ``while`` (the scan's
+loop), and no array of the operands' size changes its tiling on the way
+(``tests/test_kda_layout.py``'s assertion).
 
 Nothing here is a speed. The interpreter compiles the two kernels once a
 shape; the cases share them through module-scoped fixtures.
@@ -207,6 +208,8 @@ def test_on_a_described_v5e_the_mixer_runs_the_kernels_and_no_scan(chip):  # noq
     # the rule forward, recomputed, backward; a chain a call and pass
     assert sum("attn_core" in ln for ln in calls) == 3
     assert sum("kda_conv" in ln for ln in calls) == 9
+    # ``silu_gated_head_norm``: forward, recomputed, backward
+    assert sum("kda_gate" in ln for ln in calls) == 3
     assert " while(" not in hlo             # no scan of the delta rule
     # q, k (16 heads), v, z, o (32 heads): none crosses between tilings
     sizes = {B * TC * 16 * 128, B * TC * 32 * 128}

@@ -7,10 +7,15 @@ kernels read q, k, v, ``g`` and write ``o``. On the CPU at tiny widths: the
 flat ``g``, the head norm and their gradients are the parent's
 ``btr,rhk->bthk`` forms' (kept here, ``_gates_by_heads``,
 ``_head_norm_by_heads``), and the rule and the counter give the same for
-rank-3 and rank-4 operands. Compiled for a described ``v5e:2x2`` device at
+rank-3 and rank-4 operands; at 128-wide heads the head norm's Pallas
+kernels (``_head_norm_kernels``, interpreted, both gates) are the plain form
+``_head_norm_plain``, value and every gradient, and ``_on_one_tpu`` alone
+chooses between them. Compiled for a described ``v5e:2x2`` device at
 the cell's widths: the mixer's forward, recompute and backward hold NO
-relayout of an array of that size, float32 or bfloat16, and the convolution
-chains are Pallas calls under ``kda_conv``; the same assertion fails on each
+relayout of an array of that size, float32 or bfloat16, the convolution
+chains are Pallas calls under ``kda_conv`` and the head norm three under
+``kda_gate``, where no float32 array of that size is written but ``g``'s
+(PR 54); the relayout assertion fails on each
 of the parent's forms (PR 43's ``gates`` and ``gated_head_norm`` by heads,
 PR 49's projections and ``conv_silu`` by heads: ``_mixer_by_heads``), so it
 sees the fault (268 MB float32 or 134 MB bfloat16 crossing between two
@@ -161,6 +166,75 @@ def test_the_flat_head_norm_is_the_parents(dtype, t):
         assert _near(a.astype(F32), b_.astype(F32), tol)
 
 
+def _norm_case(dtype, t: int = 136, heads: int = 2, dv: int = 128):
+    """Both gates' operands at the kernels' head width: T = 136 is two
+    64-token tiles (``small_tiles``) and 8 tokens, so the row is padded
+    and ``d_weight`` sums over three grid steps."""
+    b, d, r = 1, 24, 8
+    ks = jax.random.split(jax.random.PRNGKey(7), 7)
+    w = {"g_a": jax.random.normal(ks[0], (d, r)) * 0.3,
+         "g_b": jax.random.normal(ks[1], (r, heads, dv)) * 0.3,
+         "o_norm": 1.0 + 0.1 * jax.random.normal(ks[2], (dv,))}
+    h = jax.random.normal(ks[3], (b, t, d)).astype(dtype)
+    o = jax.random.normal(ks[4], (b, t, heads, dv)).astype(dtype)
+    z = jax.random.normal(ks[5], (b, t, heads * dv)).astype(dtype)
+    weight = jax.random.normal(ks[6], o.shape)
+    return {"sigmoid": (lambda o, h, w: la.gated_head_norm(
+                o, h, w, eps=1e-5), (o, h, w)),
+            "silu": (lambda o, z, w: la.silu_gated_head_norm(
+                o, z, w["o_norm"], eps=1e-5), (o, z, w))}, weight
+
+
+@pytest.fixture
+def small_tiles():
+    """64 tokens a grid step: the cell's 1,024 would make the interpreter
+    walk the same code over more rows."""
+    with mock.patch.object(la, "_CONV_TOKENS", 64):
+        yield
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("gate", ["sigmoid", "silu"])
+def test_the_head_norms_kernels_are_the_plain_form(small_tiles, gate, dtype):
+    """Interpreted, against ``_head_norm_plain``: the value and every
+    gradient (``o``, ``h`` / ``z``, ``g_a``, ``g_b``, ``o_norm``), each in
+    its operand's shape and dtype."""
+    cases, weight = _norm_case(dtype)
+    norm, args = cases[gate]
+    both = jax.value_and_grad(
+        lambda *a: (norm(*a).astype(F32) * weight).sum(), (0, 1, 2))
+    plain = both(*args)
+    with mock.patch.object(la, "_on_one_tpu", lambda *a: True):
+        fused = both(*args)
+    if gate == "silu":      # the leaves of the OTHER gate: no gradient
+        assert all(not float(jnp.abs(g[2][name]).max())
+                   for g in (plain[1], fused[1]) for name in ("g_a", "g_b"))
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    for a, b_ in zip(jax.tree.leaves(fused), jax.tree.leaves(plain)):
+        assert a.shape == b_.shape and a.dtype == b_.dtype
+        assert _near(a.astype(F32), b_.astype(F32), tol)
+
+
+@pytest.mark.parametrize("gate", ["sigmoid", "silu"])
+@pytest.mark.parametrize("taken", [True, False], ids=["one_tpu", "elsewhere"])
+def test_the_head_norms_choice_follows_on_one_tpu(taken, gate):
+    """One rule for the whole mixer: where ``_on_one_tpu`` takes the call,
+    the kernels; anywhere else the plain form; asked once, of ``o`` and
+    its heads' width."""
+    norm, args = _norm_case(F32, t=16)[0][gate]
+    asked, ran = [], []
+    run = lambda name: lambda o, *a: ran.append(name) or o  # noqa: E731
+    with mock.patch.object(la, "_on_one_tpu",
+                           lambda a, dk, dv: asked.append((a.shape, dk, dv))
+                           or taken), \
+            mock.patch.object(la, "_head_norm_kernels", run("kernels")), \
+            mock.patch.object(la, "_head_norm_plain", run("plain")):
+        norm(*args)
+    assert asked == [(args[0].shape, 128, 128)]
+    assert ran == ["kernels" if taken else "plain"]
+
+
 def _rule_case(t: int, heads: int, d: int):
     ks = jax.random.split(jax.random.PRNGKey(11), 6)
     ops = (la.l2_norm(jax.random.normal(ks[0], (1, t, heads, d))),
@@ -284,6 +358,27 @@ def _kernels_under(hlo: str, scope: str) -> int:
         if " custom-call(" in line)
 
 
+_RESULT = re.compile(r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) "
+                     r"(?:fusion|custom-call|convolution)\(")
+
+
+def _float32_written_under(hlo: str, scope: str) -> list[str]:
+    """The instructions OUTSIDE fused computations (a fusion, a kernel, a
+    matmul: what writes its result to memory) under ``scope`` with a
+    float32 result of ``B * T * H * dk`` elements, tuples' parts too."""
+    found, fused = [], False
+    for line in hlo.splitlines():
+        if line[:1] in "%E":                    # a computation opens
+            fused = line.startswith("%fused_computation")
+        m = None if fused else _RESULT.match(line)
+        if m and f"/{scope}/" in line.replace(f"({scope})", f"/{scope}/") \
+                and any(math.prod(map(int, dims.split(","))) == (
+                    B * T * HEADS * DK)
+                    for dims in re.findall(r"f32\[([\d,]+)\]", m.group(1))):
+            found.append(line.strip()[:400])
+    return found
+
+
 def test_no_array_of_the_operands_size_changes_its_tiling(chip):
     hlo = _mixer_hlo(chip)
     assert "kda_conv" in hlo and "/kda_gate/" in hlo       # the scopes' names
@@ -291,6 +386,15 @@ def test_no_array_of_the_operands_size_changes_its_tiling(chip):
     # a chain a Pallas call: three forward, three recomputed, three backward
     assert _kernels_under(hlo, "kda_conv") == 9
     assert _kernels_under(hlo, "attn_core") == 3
+    # the head norm a Pallas call: forward, recomputed, backward; and under
+    # ``kda_gate`` no float32 [B, T, H * dv] array is written but ``g``'s
+    # (``gates``' matmul: forward ``g``, recomputed ``g`` and softplus'
+    # argument): no pre-activation of the output gate, no spread statistic
+    assert _kernels_under(hlo, "kda_gate") == 3
+    written = _float32_written_under(hlo, "kda_gate")
+    assert written and all("btr,rc->btc/dot_general" in line
+                           for line in written), written
+    assert len(written) <= 2
 
 
 @pytest.mark.parametrize("where,name,parents", [
