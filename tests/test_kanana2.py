@@ -23,7 +23,6 @@ from dataclasses import replace
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from chipbench import spec
@@ -33,7 +32,9 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 from ray_tpu.ops import moe
 
-SCALE = 5.0
+import _small_models as sm
+from _small_models import highest_precision  # noqa: F401 (autouse)
+
 TOL = 2e-5
 T, E, K, RANKS = 64, 8, 3, 4
 
@@ -50,24 +51,8 @@ def small(**kw):
 
 def make(seed: int = 0, **kw):
     """(cfg, params, rows [2, T + 1])."""
-    cfg = small(**kw)
-    params = models.init_params(jax.random.PRNGKey(seed), cfg)
-    out = dict(params)
-    for stack in ("layers", "dense_layers"):
-        layers = jax.tree.map(lambda a: a * SCALE, params[stack])
-        for name in ("ln1", "ln2"):
-            layers[name]["w"] = params[stack][name]["w"]
-        layers["attn"]["kv_norm"] = params[stack]["attn"]["kv_norm"]
-        out[stack] = layers
-    out["layers"]["router"]["w"] = out["layers"]["router"]["w"] * 10.0
-    out["layers"]["router"]["b"] = out["layers"]["router"]["b"] * 5.0
-    rows = jax.random.randint(jax.random.PRNGKey(seed + 1000), (2, T + 1), 0,
-                              cfg.vocab_size)
-    return cfg, out, rows
-
-
-def program_loss(params, rows, cfg):
-    return models.lm_loss(params, {"tokens": rows}, cfg)[0]
+    return sm.make(small, seed, tokens=T, bias_scale=5.0,
+                   as_drawn=("ln1", "ln2", "kv_norm"), **kw)
 
 
 def reference_loss(params, rows, cfg):
@@ -135,7 +120,7 @@ def test_every_other_factory_keeps_its_weights_and_its_loss(name):
                for a in jax.tree.leaves(params)) == pytest.approx(
         total, rel=1e-9)
     rows = jax.random.randint(jax.random.PRNGKey(5), (2, 33), 0, 256)
-    assert float(models.lm_loss(params, {"tokens": rows}, cfg)[0]) == \
+    assert float(sm.lm_loss(params, rows, cfg)[0]) == \
         pytest.approx(loss, rel=1e-6)
     assert "dense_layers" not in params
 
@@ -196,13 +181,10 @@ def test_every_branch_moves_the_logits():
                                        (3, None)])
 def test_program_equals_reference_logits_loss_and_gradients(seed, held):
     cfg, params, rows = make(seed, experts_held=held)
-    with jax.default_matmul_precision("highest"):
-        z_p = models.forward(params, rows[:, :-1], cfg)
-        z_r = reference.forward(params, rows[:, :-1], cfg)
-        (l_p, metrics), g_p = jax.value_and_grad(
-            lambda p: models.lm_loss(p, {"tokens": rows}, cfg),
-            has_aux=True)(params)
-        l_r, g_r = jax.value_and_grad(reference_loss)(params, rows, cfg)
+    z_p = sm.forward(params, rows[:, :-1], cfg)
+    z_r = reference.forward(params, rows[:, :-1], cfg)
+    (l_p, metrics), g_p = sm.loss_metrics_and_grads(params, rows, cfg)
+    l_r, g_r = sm.value_and_grad(reference_loss, cfg)(params, rows)
     assert float(z_r.std()) > 0.1
     assert float(jnp.abs(z_p - z_r).max()) < TOL
     # no router term: the program's whole loss IS its cross entropy
@@ -221,12 +203,16 @@ def test_program_equals_reference_logits_loss_and_gradients(seed, held):
 
 def test_scanned_unrolled_and_rematted_layers_are_the_same_model():
     cfg, params, rows = make()
-    want = jax.value_and_grad(program_loss)(params, rows, cfg)
+
+    def loss_and_grads(cfg):
+        (loss, _), grads = sm.loss_metrics_and_grads(params, rows, cfg)
+        return loss, grads
+
+    want = loss_and_grads(cfg)
     for changes in (dict(scan_layers=False), dict(remat=False),
                     dict(scan_layers=False, remat=False),
                     dict(remat_policy="dots")):
-        got = jax.value_and_grad(program_loss)(params, rows,
-                                               replace(cfg, **changes))
+        got = loss_and_grads(replace(cfg, **changes))
         for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
             assert float(jnp.abs(a - b).max()) < TOL, changes
 
@@ -297,7 +283,9 @@ def test_a_fault_fails_the_comparison(name, monkeypatch):
         mlp["shared_w_down"] = mlp["shared_w_down"] * 0
         params = dict(params, layers=dict(params["layers"], mlp=mlp))
         fault = {}
-    z_p = models.forward(params, rows[:, :-1], replace(cfg, **(fault or {})))
+    # a patched program is in no key of ``sm``: it runs op by op
+    run = models.forward if fault is None else sm.forward
+    z_p = run(params, rows[:, :-1], replace(cfg, **(fault or {})))
     _, good, _ = make()
     z_r = reference.forward(good, rows[:, :-1], cfg)
     assert float(jnp.abs(z_p - z_r).max()) > 100 * TOL, name
@@ -305,17 +293,21 @@ def test_a_fault_fails_the_comparison(name, monkeypatch):
 
 # -- the share --------------------------------------------------------------------
 
-def _one_layer(x, lp, cfg, dense=False):
+def _block(x, lp, cfg, dense):
     rope = transformer.rope_frequencies(cfg.d_head_rope, cfg.max_seq_len,
                                         theta=cfg.rope_theta)
     return transformer._block(x, lp, cfg, rope=rope,
                               con=lambda t, *spec: t, dense=dense)[0]
 
 
+def _one_layer(x, lp, cfg, dense=False):
+    return sm.jitted(_block, cfg, dense)(x, lp)
+
+
 def _reference_layer(x, lp, cfg, dense=False, first_held=0):
-    return reference._layer(x, lp, dense, cfg.d_head_nope, cfg.kv_latent,
-                            float(cfg.rope_theta), cfg.expert_top_k,
-                            float(cfg.expert_gate_scale), first_held)
+    return jax.jit(reference._layer, static_argnums=tuple(range(2, 9)))(
+        x, lp, dense, cfg.d_head_nope, cfg.kv_latent, float(cfg.rope_theta),
+        cfg.expert_top_k, float(cfg.expert_gate_scale), first_held)
 
 
 @pytest.mark.parametrize("layer", [0, 1])
@@ -329,28 +321,13 @@ def test_the_ranks_routed_parts_and_the_shared_expert_once_sum_to_the_uncut_laye
     cfg, full, rows = make(experts_held=None)
     x = full["embed"]["tokens"][rows[:, :-1]] * 10.0
     lp = _common.layer_slice(full["layers"], layer)
-    with jax.default_matmul_precision("highest"):
-        uncut = _reference_layer(x, lp, cfg)
-        no_routed = dict(lp, mlp=dict(lp["mlp"],
-                                      w_down=lp["mlp"]["w_down"] * 0))
-        alike = _reference_layer(x, no_routed, cfg)     # h + shared expert
-        no_shared = dict(no_routed, mlp=dict(
-            no_routed["mlp"],
-            shared_w_down=lp["mlp"]["shared_w_down"] * 0))
-        h = _reference_layer(x, no_shared, cfg)
+    alike = sm.ranks_parts_sum_to_the_uncut_layer(       # h + shared expert
+        x, lp, cfg, RANKS, lambda x, lp: _reference_layer(x, lp, cfg),
+        _one_layer, TOL)
+    bare = dict(lp, mlp=dict(lp["mlp"], w_down=lp["mlp"]["w_down"] * 0,
+                             shared_w_down=lp["mlp"]["shared_w_down"] * 0))
+    h = _reference_layer(x, bare, cfg)
     assert float(jnp.abs(alike - h).max()) > 1000 * TOL     # the shared part
-    parts = []
-    for rank in range(RANKS):
-        first, end = moe.held_range(E, rank, RANKS)
-        mlp = {name: (w[first:end] if name.startswith("w_") else w)
-               for name, w in lp["mlp"].items()}
-        y_r = _one_layer(x, dict(lp, mlp=mlp),
-                         replace(cfg, experts_held=(rank, RANKS)))
-        parts.append(y_r - alike)
-    assert all(float(jnp.abs(p).max()) > 1000 * TOL for p in parts)
-    assert float(jnp.abs(alike + sum(parts) - uncut).max()) < 5 * TOL
-    # and the program that holds every expert is that layer too
-    assert float(jnp.abs(_one_layer(x, lp, cfg) - uncut).max()) < 5 * TOL
 
 
 def test_the_dense_layer_is_the_references():
@@ -419,15 +396,13 @@ def test_the_steps_rule_moves_the_bias_and_adamws_decay_does_not(accum_steps):
     leaf) and its moments never see it."""
     cfg, params, rows = make(router_bias_rate=0.01)
     rows = jnp.concatenate([rows, rows[::-1] + 1], 0) % cfg.vocab_size
-    opt = optax.adamw(3e-4, weight_decay=0.1)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    step = jax.jit(models.make_train_step(cfg, opt, accum_steps=accum_steps))
-    new, metrics = step(state, {"tokens": rows})
+    opt = sm.adamw(3e-4, weight_decay=0.1)
+    state = sm.train_state(params, opt)
+    new, metrics = sm.train_step(cfg, opt, accum_steps)(
+        state, {"tokens": rows})
     assert "moe_expert_counts" not in metrics
     assert all(np.ndim(v) == 0 for v in metrics.values())
-    counts = models.lm_loss(params, {"tokens": rows}, cfg)[1][
-        "moe_expert_counts"]
+    counts = sm.lm_loss(params, rows, cfg)[1]["moe_expert_counts"]
     assert counts.shape == (2, E) and float(counts.sum()) == 2 * 4 * T * K
     old = params["layers"]["router"]["b"]
     want = old + 0.01 * jnp.sign(counts.mean(-1, keepdims=True) - counts)
@@ -444,15 +419,15 @@ def test_the_steps_rule_moves_the_bias_and_adamws_decay_does_not(accum_steps):
             jax.tree.leaves(params)):
         assert bool(jnp.any(a != b)), jax.tree_util.keystr(path)
     # rate 0: the bias stays EXACTLY what it was (no decay either)
-    still = jax.jit(models.make_train_step(
-        replace(cfg, router_bias_rate=0.0), opt))(state, {"tokens": rows})[0]
+    still = sm.train_step(replace(cfg, router_bias_rate=0.0), opt)(
+        state, {"tokens": rows})[0]
     assert bool(jnp.array_equal(still["params"]["layers"]["router"]["b"],
                                 old))
 
 
 def test_the_train_steps_counters_count_the_whole_batch():
     cfg, params, rows = make()
-    metrics = models.lm_loss(params, {"tokens": rows}, cfg)[1]
+    metrics = sm.lm_loss(params, rows, cfg)[1]
     n = 2 * T
     share, load, swapped, over = [], [], [], []
     x = params["embed"]["tokens"][rows[:, :-1]]
@@ -555,14 +530,8 @@ def test_the_second_stack_goes_through_partition_specs_on_a_virtual_mesh():
     of the CPU's virtual devices is the unsharded step."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel import (MeshConfig, batch_sharding,
-                                  infer_param_specs, make_shardings)
-
     cfg, params, rows = make(experts_held=None)
-    specs = models.partition_specs(cfg)
-    assert jax.tree.structure(specs, is_leaf=lambda s: s is None or isinstance(
-        s, P)) == jax.tree.structure(jax.tree.map(lambda a: None, params),
-                                     is_leaf=lambda s: s is None)
+    specs, placed = sm.sharded_loss_is_the_unsharded(cfg, params, rows, TOL)
     for stack in ("layers", "dense_layers"):
         attn = specs[stack]["attn"]
         assert attn["wq"] == attn["wkv_b"] == P(None, None, "tensor", None)
@@ -573,16 +542,7 @@ def test_the_second_stack_goes_through_partition_specs_on_a_virtual_mesh():
     assert specs["layers"]["mlp"]["w_gate"] == P(None, "expert", None,
                                                  "tensor")
     assert specs["layers"]["router"]["b"] is None
-    mesh = MeshConfig(data=2, fsdp=2, tensor=2).build()
-    shardings = make_shardings(mesh, infer_param_specs(params, mesh, specs))
-    placed = jax.tree.map(jax.device_put, params, shardings)
     assert len(placed["dense_layers"]["mlp"]["w_gate"].sharding.device_set) == 8
-    rows4 = jnp.concatenate([rows, rows[::-1]], 0)
-    want = program_loss(params, rows4, cfg)
-    got = jax.jit(lambda p, r: models.lm_loss(p, {"tokens": r}, cfg,
-                                              mesh=mesh)[0])(
-        placed, jax.device_put(rows4, batch_sharding(mesh)))
-    assert float(got) == pytest.approx(float(want), abs=TOL)
 
 
 # -- scopes -----------------------------------------------------------------------
@@ -592,11 +552,10 @@ def test_the_new_scopes_are_on_the_instructions():
     shared expert under ``moe`` / ``moe_shared``; the dense layer's FFN
     under ``mlp``; the bias's rule under ``optimizer``."""
     cfg, params, rows = make()
-    opt = optax.adamw(3e-4)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    text = jax.jit(models.make_train_step(cfg, opt)).lower(
-        state, {"tokens": rows}).as_text(debug_info=True)
+    opt = sm.adamw(3e-4)
+    text = sm.train_step(cfg, opt).lower(
+        sm.train_state(params, opt), {"tokens": rows}).as_text(
+            debug_info=True)
     for path in ("attn/attn_full/mla_latent", "moe/moe_shared",
                  "moe/moe_router", "moe/moe_experts", "/mlp/",
                  "optimizer/sign"):

@@ -27,6 +27,7 @@ fixture, never at import (the on-chip-measurement guide).
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import re
@@ -245,6 +246,17 @@ def _rule_case(t: int, heads: int, d: int):
     return ops, jax.random.normal(ks[5], (1, t, heads, d))
 
 
+@functools.cache
+def _rule_and_gradients(rule: str):
+    """(the rule's output weighed and summed, its gradient by the five
+    operands), jitted once a rule: the by-heads call of both cases below
+    is ONE compile (at the kernels' width, interpreted, the longest of
+    the file)."""
+    return jax.jit(jax.value_and_grad(
+        lambda weight, *a: (getattr(la, rule)(*a) * weight).sum(),
+        argnums=range(1, 6)))
+
+
 # T is no whole number of chunks: a flat operand is padded as the others are
 @pytest.mark.parametrize("which", [(3,), (0, 1, 2, 3)], ids=["g", "qkvg"])
 @pytest.mark.parametrize("rule,t,heads,d", [
@@ -260,10 +272,8 @@ def test_the_rule_takes_g_flat_or_by_heads_and_gives_the_same(rule, t, heads,
     flat = tuple(a.reshape(1, t, heads * d) if i in which else a
                  for i, a in enumerate(ops))
     with jax.default_matmul_precision("highest"):
-        both = jax.jit(jax.value_and_grad(
-            lambda *a: (getattr(la, rule)(*a) * weight).sum(),
-            argnums=range(5)))
-        (o4, grads4), (o3, grads3) = both(*ops), both(*flat)
+        both = _rule_and_gradients(rule)
+        (o4, grads4), (o3, grads3) = both(weight, *ops), both(weight, *flat)
     assert float(o3) == float(o4)
     for a, b, given in zip(grads3, grads4, flat):
         assert a.shape == given.shape               # comes back as it went

@@ -27,7 +27,6 @@ from dataclasses import replace
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from chipbench import spec
@@ -37,7 +36,9 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 from ray_tpu.ops import linear_attention, moe
 
-SCALE = 5.0
+import _small_models as sm
+from _small_models import highest_precision  # noqa: F401 (autouse)
+
 TOL = 5e-5
 T, E, K, RANKS = 80, 8, 3, 4
 AS_DRAWN = ("A_log", "dt_bias", "conv_q", "conv_k", "conv_v", "o_norm",
@@ -55,53 +56,22 @@ def small(**kw):
     return models.kimi_linear_48b_a3b(**base)
 
 
-def scaled(params):
-    """``params`` with every matrix of both stacks at SCALE x its draw."""
-    def one(path, a):
-        names = [k.key for k in path]
-        if names[0] in ("ln1", "ln2") or names[-1] in AS_DRAWN:
-            return a
-        return a * SCALE
-
-    out = dict(params)
-    for stack in ("layers", "dense_layers"):
-        out[stack] = jax.tree_util.tree_map_with_path(one, params[stack])
-    router = dict(out["layers"]["router"])
-    router["w"] = router["w"] * 10.0
-    out["layers"] = dict(out["layers"], router=router)
-    return out
-
-
 init = jax.jit(models.init_params, static_argnums=1)
 
 
 def make(seed: int = 0, **kw):
     """(cfg, params, rows [2, T + 1])."""
-    cfg = small(**kw)
-    params = scaled(init(jax.random.PRNGKey(seed), cfg))
-    rows = jax.random.randint(jax.random.PRNGKey(seed + 1000), (2, T + 1), 0,
-                              cfg.vocab_size)
-    return cfg, params, rows
+    return sm.make(small, seed, tokens=T, init=init,
+                   as_drawn=("ln1", "ln2") + AS_DRAWN, **kw)
 
 
 # jitted: eagerly, the chunked rule's loops are thousands of dispatches
-forward = jax.jit(models.forward, static_argnums=2)
-
-
-@functools.partial(jax.jit, static_argnums=2)
-def program_loss(params, rows, cfg):
-    return models.lm_loss(params, {"tokens": rows}, cfg)[0]
+forward = sm.forward
 
 
 def reference_loss(params, rows, cfg):
     return _common.next_token_loss(
         reference.forward(params, rows[:, :-1], cfg), rows)
-
-
-@pytest.fixture(autouse=True)
-def _highest():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 # -- the preset ---------------------------------------------------------------
@@ -189,8 +159,7 @@ def test_program_equals_reference_logits_and_loss(seed, held):
     z_ref = reference.forward(params, rows[:, :-1], cfg)
     assert float(jnp.std(z_ref)) > 0.05
     assert float(jnp.abs(z - z_ref).max()) < TOL
-    loss, metrics = jax.jit(lambda p, r: models.lm_loss(
-        p, {"tokens": r}, cfg))(params, rows)
+    loss, metrics = sm.lm_loss(params, rows, cfg)
     assert float(loss) == pytest.approx(
         float(reference_loss(params, rows, cfg)), abs=TOL)
     # the most negative sum of 64 log-decays: at most 16 x 0.1 a token
@@ -199,9 +168,8 @@ def test_program_equals_reference_logits_and_loss(seed, held):
 
 def test_program_equals_reference_gradients_through_lm_loss():
     cfg, params, rows = make(0)
-    got = jax.grad(program_loss)(params, rows, cfg)
-    want = jax.jit(jax.grad(lambda p, r: reference_loss(p, r, cfg)))(
-        params, rows)
+    got = sm.loss_metrics_and_grads(params, rows, cfg)[1]
+    want = sm.grad(reference_loss, cfg)(params, rows)
     flat_got = jax.tree_util.tree_leaves_with_path(got)
     flat_want = jax.tree.leaves(want)
     assert len(flat_got) == len(flat_want) == len(jax.tree.leaves(params))
@@ -292,26 +260,16 @@ def test_the_ranks_routed_parts_sum_to_the_uncut_layer(layer):
     x = full["embed"]["tokens"][rows[:, :-1]] * 10.0
     lp = reference.stack_layer(full["layers"], mixers, layer)
     kind = cfg.layer_kind(1 + layer)
-    uncut = _reference_layer(x, lp, cfg, mixers[layer])
-    no_routed = dict(lp, mlp=dict(lp["mlp"], w_down=lp["mlp"]["w_down"] * 0))
-    alike = _reference_layer(x, no_routed, cfg, mixers[layer])
-    bare = dict(no_routed, mlp=dict(
-        no_routed["mlp"], shared_w_down=lp["mlp"]["shared_w_down"] * 0),
-        attn={"wo": lp["attn"]["wo"] * 0})
+    alike = sm.ranks_parts_sum_to_the_uncut_layer(
+        x, lp, cfg, RANKS,
+        lambda x, lp: _reference_layer(x, lp, cfg, mixers[layer]),
+        lambda x, lp, cfg: _one_layer(x, lp, cfg, kind), TOL)
+    bare = dict(lp, mlp=dict(lp["mlp"], w_down=lp["mlp"]["w_down"] * 0,
+                             shared_w_down=lp["mlp"]["shared_w_down"] * 0),
+                attn={"wo": lp["attn"]["wo"] * 0})
     assert float(jnp.abs(_reference_layer(x, bare, cfg, mixers[layer])
                          - x).max()) < TOL
     assert float(jnp.abs(alike - x).max()) > 1000 * TOL
-    parts = []
-    for rank in range(RANKS):
-        first, end = moe.held_range(E, rank, RANKS)
-        mlp = {name: (w[first:end] if name.startswith("w_") else w)
-               for name, w in lp["mlp"].items()}
-        y_r = _one_layer(x, dict(lp, mlp=mlp),
-                         replace(cfg, experts_held=(rank, RANKS)), kind)
-        parts.append(y_r - alike)
-    assert all(float(jnp.abs(p).max()) > 1000 * TOL for p in parts)
-    assert float(jnp.abs(alike + sum(parts) - uncut).max()) < 5 * TOL
-    assert float(jnp.abs(_one_layer(x, lp, cfg, kind) - uncut).max()) < 5 * TOL
 
 
 def test_the_dense_layer_has_a_kda_mixer_and_is_the_references():
@@ -327,6 +285,19 @@ def test_the_dense_layer_has_a_kda_mixer_and_is_the_references():
 
 # -- a training step ----------------------------------------------------------------
 
+def _published_kinds_step(lr: float = 3e-4):
+    """(the jitted train step, its state, its batch, the parameters) of the
+    published 27 kinds as rank 31 of a 32-way share: ONE trace and lowering
+    for the case that runs the step and the case that reads its text."""
+    cfg = small(dtype="bfloat16", n_layers=27, d_ff=16, d_ff_dense=32,
+                d_ff_shared=16, experts_held=(31, 32), n_experts=32)
+    params = init(jax.random.PRNGKey(6), cfg)
+    rows = jax.random.randint(jax.random.PRNGKey(7), (2, 65), 0, 256)
+    opt = sm.adamw(lr, weight_decay=0.1)
+    return (sm.train_step(cfg, opt), sm.train_state(params, opt),
+            {"tokens": rows}, params)
+
+
 def test_a_step_moves_every_leaf_by_adamw_and_the_bias_by_its_rule():
     """``make_train_step`` with the cell's options (defaults: remat, scan,
     bfloat16 compute, unchunked loss) on the published 27 kinds (the scan
@@ -336,16 +307,9 @@ def test_a_step_moves_every_leaf_by_adamw_and_the_bias_by_its_rule():
     the router's bias moves by about the learning rate (AdamW's first
     update), the bias by exactly its rate, and the new counter is in the
     metrics."""
-    cfg = small(dtype="bfloat16", n_layers=27, d_ff=16, d_ff_dense=32,
-                d_ff_shared=16, experts_held=(31, 32), n_experts=32)
-    params = init(jax.random.PRNGKey(6), cfg)
-    rows = jax.random.randint(jax.random.PRNGKey(7), (2, 65), 0, 256)
     lr = 3e-4
-    opt = optax.adamw(lr, weight_decay=0.1)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    new, metrics = jax.jit(models.make_train_step(cfg, opt))(
-        state, {"tokens": rows})
+    step, state, batch, params = _published_kinds_step(lr)
+    new, metrics = step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
     assert float(metrics["kda_log_decay_min"]) < 0
     assert "moe_expert_counts" not in metrics
@@ -436,14 +400,8 @@ def test_no_serving_path_runs_this_model():
 def test_the_mixers_stacks_go_through_partition_specs_on_a_virtual_mesh():
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel import (MeshConfig, batch_sharding,
-                                  infer_param_specs, make_shardings)
-
     cfg, params, rows = make(8, experts_held=None)
-    specs = models.partition_specs(cfg)
-    assert jax.tree.structure(specs, is_leaf=lambda s: s is None or isinstance(
-        s, P)) == jax.tree.structure(jax.tree.map(lambda a: None, params),
-                                     is_leaf=lambda s: s is None)
+    specs, _ = sm.sharded_loss_is_the_unsharded(cfg, params, rows, TOL)
     for stack in ("layers", "dense_layers"):
         kda = specs[stack]["kda"]
         by_head = P(None, None, "tensor", None)
@@ -453,15 +411,6 @@ def test_the_mixers_stacks_go_through_partition_specs_on_a_virtual_mesh():
         assert kda["f_a"] is None and kda["o_norm"] is None
         assert specs[stack]["attn"]["wo"] == P(None, "tensor", None, None)
     assert specs["layers"]["mla"]["wkv_b"] == P(None, None, "tensor", None)
-    mesh = MeshConfig(data=2, fsdp=2, tensor=2).build()
-    shardings = make_shardings(mesh, infer_param_specs(params, mesh, specs))
-    placed = jax.tree.map(jax.device_put, params, shardings)
-    rows4 = jnp.concatenate([rows, rows[::-1]], 0)
-    want = program_loss(params, rows4, replace(cfg))
-    got = jax.jit(lambda p, r: models.lm_loss(p, {"tokens": r}, cfg,
-                                              mesh=mesh)[0])(
-        placed, jax.device_put(rows4, batch_sharding(mesh)))
-    assert float(got) == pytest.approx(float(want), abs=TOL)
 
 
 # -- scopes -----------------------------------------------------------------------
@@ -470,13 +419,10 @@ def test_the_new_scopes_are_on_the_instructions():
     """``attn_linear`` and inside it ``attn_qkv``, ``kda_conv``,
     ``kda_gate``, ``attn_core``, ``attn_out``, in both stacks; the latent
     layer keeps ``attn_full`` / ``mla_latent`` / ``attn_core`` and opens no
-    ``attn_pos`` (nothing is rotated)."""
-    cfg, params, rows = make(9)
-    opt = optax.adamw(3e-4)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    text = jax.jit(models.make_train_step(cfg, opt)).lower(
-        state, {"tokens": rows}).as_text(debug_info=True)
+    ``attn_pos`` (nothing is rotated). Read off the step of the published
+    27 kinds, which the step's own case has traced."""
+    step, state, batch, _ = _published_kinds_step()
+    text = step.lower(state, batch).as_text(debug_info=True)
     for path in ("attn/attn_linear/attn_qkv", "attn/attn_linear/kda_conv",
                  "attn/attn_linear/kda_gate", "attn/attn_linear/attn_core",
                  "attn/attn_linear/attn_out", "attn/attn_full/mla_latent",

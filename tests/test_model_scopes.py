@@ -8,6 +8,7 @@ the backward pass apart. Settled here on CPU programs, not on the chip:
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib
 import re
 from dataclasses import replace
@@ -202,6 +203,17 @@ ATTENTION_PATHS = {
 }
 
 
+@functools.cache
+def _attention_step(path: str):
+    """(lowered, optimised text) of ``ATTENTION_PATHS[path]``'s step: the
+    two tests below read the same text, compiled once."""
+    make, seq_len, _ = ATTENTION_PATHS[path]
+    lowered = _traced_step(make(), rows=2, seq_len=seq_len).lower()
+    text = lowered.compile().as_text()
+    assert text.startswith("HloModule jit_train_step")
+    return lowered, text
+
+
 @pytest.mark.parametrize("path", ATTENTION_PATHS)
 def test_attention_names_its_parts_in_every_pass(path):
     """The seven names of ``transformer.ATTN_PART_SCOPES`` and
@@ -211,11 +223,10 @@ def test_attention_names_its_parts_in_every_pass(path):
     instructions to ``attn``, so ``step_attn_ms`` stays whole. The two
     names ``ops/attention.py`` opens inside the custom gradient's
     backward rule read ``backward`` (their path holds ``transpose(``)."""
-    make, seq_len, want = ATTENTION_PATHS[path]
-    lowered = _traced_step(make(), rows=2, seq_len=seq_len).lower()
+    _, _, want = ATTENTION_PATHS[path]
+    lowered, text = _attention_step(path)
     found: dict[str, set] = {}
-    for name in re.findall(r'op_name="([^"]*)"',
-                           lowered.compile().as_text()):
+    for name in re.findall(r'op_name="([^"]*)"', text):
         pieces = scopes._CUT.split(name)
         for part in set(pieces).intersection(ATTN_NAMES):
             model_part, ps = scopes.classify(name)
@@ -245,9 +256,7 @@ def test_the_attention_scopes_cost_nothing(path, monkeypatch):
     stripped, the same text, instruction for instruction."""
     make, seq_len, _ = ATTENTION_PATHS[path]
 
-    def stripped() -> tuple[str, bool]:
-        jax.clear_caches()
-        text = _step_text(make(), rows=2, seq_len=seq_len)
+    def stripped(text: str) -> tuple[str, bool]:
         named = any(f"/{name}/" in text for name in ATTN_NAMES)
         # the module's tables of the Python frames its metadata points at
         text = re.sub(r"\n(?:FileNames|FunctionNames|FileLocations|"
@@ -255,12 +264,13 @@ def test_the_attention_scopes_cost_nothing(path, monkeypatch):
         text = re.sub(r',? ?metadata=\{[^}]*\}', "", text)
         return re.sub(r'scopes="[^"]*"', "", text), named
 
-    with_names, named = stripped()
+    with_names, named = stripped(_attention_step(path)[1])
     assert named
     opened = jax.named_scope
     monkeypatch.setattr(jax, "named_scope", lambda name: (
         contextlib.nullcontext() if name in ATTN_NAMES else opened(name)))
-    without, named = stripped()
+    jax.clear_caches()      # nothing traced with the names open
+    without, named = stripped(_step_text(make(), rows=2, seq_len=seq_len))
     assert not named
     assert with_names == without
 

@@ -32,7 +32,8 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 from ray_tpu.ops import moe
 
-SCALE = 5.0
+import _small_models as sm
+
 TOL = 2e-5
 
 
@@ -48,26 +49,17 @@ def make(seed: int = 0, skew: float = 0.0):
     state a common direction (a constant added to the embedding) and
     points expert 0's router column along it: about ``skew`` x 55 on its
     logit, whose spread is 8, so most tokens choose expert 0."""
-    cfg = small()
-    params = models.init_params(jax.random.PRNGKey(seed), cfg)
+    cfg, params, _ = sm.make(small, seed)
     keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1000), 8))
-    layers = jax.tree.map(lambda a: a * SCALE, params["layers"])
-    layers["router"]["w"] = layers["router"]["w"] * 10.0
-    for name in ("ln1", "ln2"):
-        layers[name]["w"] = params["layers"][name]["w"]
+    layers = params["layers"]
     for name in ("q_norm", "k_norm"):
         layers["attn"][name] = 1.0 + 0.5 * jax.random.normal(
-            next(keys), params["layers"]["attn"][name].shape)
-    params = dict(params, layers=layers)
+            next(keys), layers["attn"][name].shape)
     if skew:
         layers["router"]["w"] = layers["router"]["w"].at[:, :, 0].add(skew)
         params["embed"] = {"tokens": params["embed"]["tokens"] + 0.05}
     rows = jax.random.randint(next(keys), (2, 33), 0, cfg.vocab_size)
     return cfg, params, rows
-
-
-def program_loss(params, rows, cfg):
-    return models.lm_loss(params, {"tokens": rows}, cfg)[0]
 
 
 def test_preset_is_olmoe_as_published():
@@ -90,11 +82,11 @@ def test_the_expert_branch_moves_the_logits():
     changes the logits by a tenth of their spread, so a fault in the
     expert branch cannot hide under the tolerance."""
     cfg, params, rows = make()
-    want = models.forward(params, rows[:, :-1], cfg)
+    want = sm.forward(params, rows[:, :-1], cfg)
     mlp = dict(params["layers"]["mlp"],
                w_down=params["layers"]["mlp"]["w_down"] * 0.0)
-    off = models.forward(dict(params, layers=dict(params["layers"], mlp=mlp)),
-                         rows[:, :-1], cfg)
+    off = sm.forward(dict(params, layers=dict(params["layers"], mlp=mlp)),
+                     rows[:, :-1], cfg)
     moved = float(jnp.abs(want - off).max())
     assert moved > 0.05 * float(want.std()) and moved > 1000 * TOL
 
@@ -102,20 +94,19 @@ def test_the_expert_branch_moves_the_logits():
 @pytest.mark.parametrize("seed", [0, 1])
 def test_program_equals_reference_logits_loss_and_gradients(seed):
     cfg, params, rows = make(seed)
-    got = models.forward(params, rows[:, :-1], cfg)
+    got = sm.forward(params, rows[:, :-1], cfg)
     want = reference.forward(params, rows[:, :-1], cfg)
     assert float(jnp.abs(got - want).max()) < TOL
-    loss, metrics = models.lm_loss(params, {"tokens": rows}, cfg)
-    assert float(loss) == pytest.approx(
-        float(reference.loss(params, rows, cfg)), abs=TOL)
+    (loss, metrics), g = sm.loss_metrics_and_grads(params, rows, cfg)
+    reference_loss, r = sm.value_and_grad(reference.loss, cfg)(params, rows)
+    assert float(loss) == pytest.approx(float(reference_loss), abs=TOL)
     # the whole loss is cross entropy + 0.01 x balance + 0.001 x z
     rest = 0.01 * float(metrics["router_aux"]) + 0.001 * float(
         metrics["router_z"])
     assert rest > 0.012 and float(loss) - rest == pytest.approx(
         float(metrics["loss"]) - rest)
     # gradients: the experts' and the router's weights, and a q norm
-    g = jax.grad(program_loss)(params, rows, cfg)["layers"]
-    r = jax.grad(reference.loss)(params, rows, cfg)["layers"]
+    g, r = g["layers"], r["layers"]
     for got_g, want_g in ((g["mlp"]["w_gate"][0, 2], r["mlp"]["w_gate"][0, 2]),
                           (g["mlp"]["w_down"][1, 5], r["mlp"]["w_down"][1, 5]),
                           (g["router"]["w"], r["router"]["w"]),
@@ -170,8 +161,12 @@ def test_a_broken_variant_fails_the_comparison(name, monkeypatch):
                             _balance_over_token_shares(
                                 moe.moe_swiglu_dropless))
     cfg = replace(cfg, **changes)
-    got = {"logits": models.forward(params, rows[:, :-1], cfg),
-           "loss": program_loss(params, rows, cfg)}
+    if patch:       # a patched program is in no key of ``sm``: op by op
+        got = {"logits": models.forward(params, rows[:, :-1], cfg),
+               "loss": sm.program_loss(params, rows, cfg)}
+    else:
+        got = {"logits": sm.forward(params, rows[:, :-1], cfg),
+               "loss": sm.loss(params, rows, cfg)}
     miss = float(jnp.abs(got[what] - want[what]).max())
     assert miss > 100 * TOL, (name, miss)
 
@@ -181,14 +176,14 @@ def test_dropless_computes_every_assignment_under_skew():
     the dropless path still equals the reference (which has no capacity
     at all), and ``moe_load_max`` equals a count made with numpy."""
     cfg, params, rows = make(skew=0.5)
-    got = models.forward(params, rows[:, :-1], cfg)
+    got = sm.forward(params, rows[:, :-1], cfg)
     assert float(jnp.abs(got - reference.forward(params, rows[:, :-1], cfg)
                          ).max()) < TOL
     # layer 0's router on the program's own normed hidden states
     cfg1 = replace(cfg, n_layers=1)
     first = jax.tree.map(lambda a: a[:1], params["layers"])
     p1 = dict(params, layers=first)
-    _, metrics = models.lm_loss(p1, {"tokens": rows}, cfg1)
+    _, metrics = sm.lm_loss(p1, rows, cfg1)
     h = _router_inputs(p1, rows[:, :-1], cfg1)
     logits = np.asarray(h, np.float64) @ np.asarray(first["router"]["w"][0],
                                                     np.float64)

@@ -20,14 +20,12 @@ by the depth of the sums; a fault has to miss by 100 x that.
 
 from __future__ import annotations
 
-import functools
 import json
 from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from chipbench.reference import qwen3_next as reference
@@ -35,7 +33,9 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 from ray_tpu.ops import linear_attention, moe
 
-SCALE = 5.0
+import _small_models as sm
+from _small_models import highest_precision  # noqa: F401 (autouse)
+
 TOL = 2e-5
 T, E, K, RANKS = 64, 16, 4, 4
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -53,44 +53,25 @@ def small(**kw):
 
 def make(seed: int = 0, **kw):
     """(cfg, params, rows [2, T + 1])."""
-    cfg = small(**kw)
-    params = models.init_params(jax.random.PRNGKey(seed), cfg)
+    cfg, params, rows = sm.make(small, seed, tokens=T, as_drawn=(
+        "ln1", "ln2", "q_norm", "k_norm", "o_norm", "A_log", "dt_bias"), **kw)
     spread = iter(jax.random.split(jax.random.PRNGKey(seed + 500), 64))
 
     def around(a):
         return a + 0.3 * jax.random.normal(next(spread), a.shape, a.dtype)
 
-    layers = jax.tree.map(lambda a: a * SCALE, params["layers"])
+    layers = params["layers"]
     for name in ("ln1", "ln2"):
-        layers[name]["w"] = around(params["layers"][name]["w"])
+        layers[name]["w"] = around(layers[name]["w"])
     for name in ("q_norm", "k_norm"):
-        layers["mha"][name] = around(params["layers"]["mha"][name])
+        layers["mha"][name] = around(layers["mha"][name])
     for name in ("o_norm", "A_log", "dt_bias"):     # not scaled: their own
-        layers["gdn"][name] = around(params["layers"]["gdn"][name])
-    layers["router"]["w"] = layers["router"]["w"] * 10.0
-    out = dict(params, layers=layers,
-               final_norm={"w": around(params["final_norm"]["w"])})
-    rows = jax.random.randint(jax.random.PRNGKey(seed + 1000), (2, T + 1), 0,
-                              cfg.vocab_size)
-    return cfg, out, rows
+        layers["gdn"][name] = around(layers["gdn"][name])
+    return cfg, dict(params, final_norm={
+        "w": around(params["final_norm"]["w"])}), rows
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted(cfg, loss: bool):
-    """One jitted call a configuration: the cases share its compile."""
-    if loss:
-        return jax.jit(lambda p, r: models.lm_loss(p, {"tokens": r}, cfg)[0])
-    return jax.jit(lambda p, t: models.forward(p, t, cfg))
-
-
-def forward(params, tokens, cfg):
-    with jax.default_matmul_precision("highest"):
-        return _jitted(cfg, False)(params, tokens)
-
-
-def program_loss(params, rows, cfg):
-    with jax.default_matmul_precision("highest"):
-        return _jitted(cfg, True)(params, rows)
+forward = sm.forward
 
 
 # -- the preset ---------------------------------------------------------------
@@ -186,16 +167,14 @@ def test_program_equals_reference_logits_and_loss(seed, kw):
     want = reference.forward(params, rows[:, :-1], cfg)
     assert float(want.std()) > 0.1
     assert float(jnp.abs(z - want).max()) < 5 * TOL
-    assert float(program_loss(params, rows, cfg)) == pytest.approx(
+    assert float(sm.loss(params, rows, cfg)) == pytest.approx(
         float(reference.loss(params, rows, cfg)), abs=TOL)
 
 
 def test_program_equals_reference_gradients_through_lm_loss():
     cfg, params, rows = make(4)
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(
-            lambda p: models.lm_loss(p, {"tokens": rows}, cfg)[0]))(params)
-        want = jax.grad(lambda p: reference.loss(p, rows, cfg))(params)
+    got = sm.loss_metrics_and_grads(params, rows, cfg)[1]
+    want = sm.grad(reference.loss, cfg)(params, rows)
     flat = jax.tree_util.tree_leaves_with_path(got)
     assert len(flat) == len(jax.tree.leaves(want)) > 30
     for (path, a), b in zip(flat, jax.tree.leaves(want)):
@@ -259,32 +238,33 @@ def test_the_decay_and_the_balance_term_are_in_the_loss():
     assert float(jnp.abs(forward(still, rows[:, :-1], cfg) - base).max()) \
         > 100 * TOL
     no_term = replace(cfg, router_aux_weight=0.0)
-    d = float(program_loss(params, rows, cfg)
-              - program_loss(params, rows, no_term))
+    d = float(sm.loss(params, rows, cfg)
+              - sm.loss(params, rows, no_term))
     assert 0.001 < d < 0.004           # 0.001 x a balance term of 1-4
-    _, metrics = models.lm_loss(params, {"tokens": rows}, cfg)
+    _, metrics = sm.lm_loss(params, rows, cfg)
     assert float(metrics["router_aux"]) * 0.001 == pytest.approx(d, rel=1e-3)
 
 
 # -- the share ----------------------------------------------------------------------
 
-def _one_layer(x, lp, cfg, kind):
+def _block(x, lp, cfg, kind):
     from ray_tpu.ops.layers import rope_frequencies
 
     rope = rope_frequencies(int(cfg.head_dim * cfg.rope_fraction),
                             cfg.max_seq_len, theta=cfg.rope_theta)
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(lambda x, lp: transformer._block(
-            x, lp, cfg, rope=rope, con=lambda t, *spec: t, kind=kind)[0])(
-                x, lp)
+    return transformer._block(x, lp, cfg, rope=rope, con=lambda t, *spec: t,
+                              kind=kind)[0]
+
+
+def _one_layer(x, lp, cfg, kind):
+    return sm.jitted(_block, cfg, kind)(x, lp)
 
 
 def _reference_layer(x, lp, cfg, mixer, first_held=0):
-    with jax.default_matmul_precision("highest"):
-        return reference._layer(
-            x, lp, mixer, float(cfg.rope_theta),
-            int(cfg.head_dim * cfg.rope_fraction), cfg.expert_top_k,
-            first_held)[0]
+    return reference._jit_layer(
+        x, lp, mixer, float(cfg.rope_theta),
+        int(cfg.head_dim * cfg.rope_fraction), cfg.expert_top_k,
+        first_held)[0]
 
 
 @pytest.mark.parametrize("layer", [0, 3], ids=["deltanet", "attention"])
@@ -299,21 +279,11 @@ def test_the_ranks_routed_parts_and_the_gated_shared_expert_once_sum_to_the_uncu
     x = full["embed"]["tokens"][rows[:, :-1]] * 10.0
     lp = reference.stack_layer(full["layers"], mixers, layer)
     kind = cfg.layer_kind(layer)
-    uncut = _reference_layer(x, lp, cfg, mixers[layer])
-    no_routed = dict(lp, mlp=dict(lp["mlp"], w_down=lp["mlp"]["w_down"] * 0))
-    alike = _reference_layer(x, no_routed, cfg, mixers[layer])
+    alike = sm.ranks_parts_sum_to_the_uncut_layer(
+        x, lp, cfg, RANKS,
+        lambda x, lp: _reference_layer(x, lp, cfg, mixers[layer]),
+        lambda x, lp, cfg: _one_layer(x, lp, cfg, kind), TOL)
     assert float(jnp.abs(alike - x).max()) > 1000 * TOL
-    parts = []
-    for rank in range(RANKS):
-        first, end = moe.held_range(E, rank, RANKS)
-        mlp = {name: (w[first:end] if name.startswith("w_") else w)
-               for name, w in lp["mlp"].items()}
-        y_r = _one_layer(x, dict(lp, mlp=mlp),
-                         replace(cfg, experts_held=(rank, RANKS)), kind)
-        parts.append(y_r - alike)
-    assert all(float(jnp.abs(p).max()) > 1000 * TOL for p in parts)
-    assert float(jnp.abs(alike + sum(parts) - uncut).max()) < 5 * TOL
-    assert float(jnp.abs(_one_layer(x, lp, cfg, kind) - uncut).max()) < 5 * TOL
 
 
 # -- a training step ------------------------------------------------------------------
@@ -321,11 +291,9 @@ def test_the_ranks_routed_parts_and_the_gated_shared_expert_once_sum_to_the_uncu
 def test_a_step_moves_every_leaf_by_adamw_and_reports_the_counters():
     cfg, params, rows = make(9)
     lr = 1e-3
-    opt = optax.adamw(lr, weight_decay=0.0)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    new, metrics = jax.jit(models.make_train_step(cfg, opt))(
-        state, {"tokens": rows})
+    opt = sm.adamw(lr, weight_decay=0.0)
+    state = sm.train_state(params, opt)
+    new, metrics = sm.train_step(cfg, opt)(state, {"tokens": rows})
     for (path, before), after in zip(
             jax.tree_util.tree_leaves_with_path(params),
             jax.tree.leaves(new["params"])):
@@ -342,11 +310,15 @@ def test_a_step_moves_every_leaf_by_adamw_and_reports_the_counters():
     assert 0.0 < float(metrics["moe_held_share"]) < 1.0
     assert float(metrics["router_aux"]) > 1.0
     # accumulation keeps the counters
-    _, accumulated = jax.jit(models.make_train_step(
-        cfg, opt, accum_steps=2))(state, {"tokens": rows})
+    _, accumulated = sm.train_step(cfg, opt, accum_steps=2)(
+        state, {"tokens": rows})
     for name in ("attn_gate_mean", "moe_shared_gate_mean"):
         assert float(accumulated[name]) == pytest.approx(
             float(metrics[name]), abs=0.05)
+
+
+def _forward_and_aux(params, tokens, cfg):
+    return models.forward(params, tokens, cfg, return_aux=True)
 
 
 def test_the_shared_gates_mean_is_zero_where_nobody_pays_for_it():
@@ -367,7 +339,7 @@ def test_the_shared_gates_mean_is_zero_where_nobody_pays_for_it():
     assert float(mean) < 1e-30 and float(jnp.abs(out).max()) < 1e-30
     # in the model: the mean over the layers, about a half at a seeded init
     cfg, params, rows = make(10)
-    _, aux = models.forward(params, rows[:, :-1], cfg, return_aux=True)
+    _, aux = sm.jitted(_forward_and_aux, cfg)(params, rows[:, :-1])
     assert 0.2 < float(aux["moe_shared_gate_mean"]) < 0.8
 
 
@@ -439,14 +411,8 @@ def test_no_serving_path_runs_this_model():
 def test_the_mixers_stacks_go_through_partition_specs_on_a_virtual_mesh():
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel import (MeshConfig, batch_sharding,
-                                  infer_param_specs, make_shardings)
-
     cfg, params, rows = make(11, experts_held=None)
-    specs = models.partition_specs(cfg)
-    assert jax.tree.structure(specs, is_leaf=lambda s: s is None or isinstance(
-        s, P)) == jax.tree.structure(jax.tree.map(lambda a: None, params),
-                                     is_leaf=lambda s: s is None)
+    specs, _ = sm.sharded_loss_is_the_unsharded(cfg, params, rows, TOL)
     gdn, by_head = specs["layers"]["gdn"], P(None, None, "tensor", None)
     assert gdn["wq"] == gdn["wz"] == gdn["conv_k"] == gdn["conv_v"] == by_head
     assert gdn["A_log"] == gdn["dt_bias"] == P(None, "tensor")
@@ -457,15 +423,6 @@ def test_the_mixers_stacks_go_through_partition_specs_on_a_virtual_mesh():
     assert mha["q_norm"] is None
     assert specs["layers"]["attn"]["wo"] == P(None, "tensor", None, None)
     assert specs["layers"]["mlp"]["shared_gate"] is None
-    mesh = MeshConfig(data=2, fsdp=2, tensor=2).build()
-    shardings = make_shardings(mesh, infer_param_specs(params, mesh, specs))
-    placed = jax.tree.map(jax.device_put, params, shardings)
-    rows4 = jnp.concatenate([rows, rows[::-1]], 0)
-    want = program_loss(params, rows4, cfg)
-    got = jax.jit(lambda p, r: models.lm_loss(p, {"tokens": r}, cfg,
-                                              mesh=mesh)[0])(
-        placed, jax.device_put(rows4, batch_sharding(mesh)))
-    assert float(got) == pytest.approx(float(want), abs=TOL)
 
 
 # -- scopes -------------------------------------------------------------------------------
@@ -476,11 +433,10 @@ def test_the_scopes_are_on_the_instructions():
     attention layer under ``attn_full`` with its parts and its gate; the
     shared expert's gate inside ``moe_shared``."""
     cfg, params, rows = make(12)
-    opt = optax.adamw(3e-4)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    text = jax.jit(models.make_train_step(cfg, opt)).lower(
-        state, {"tokens": rows}).as_text(debug_info=True)
+    opt = sm.adamw(1e-3, weight_decay=0.0)      # the step's above: its trace
+    text = sm.train_step(cfg, opt).lower(
+        sm.train_state(params, opt), {"tokens": rows}).as_text(
+            debug_info=True)
     for path in ("attn/attn_linear/attn_qkv", "attn/attn_linear/kda_conv",
                  "attn/attn_linear/kda_gate", "attn/attn_linear/attn_core",
                  "attn/attn_linear/attn_out", "attn/attn_full/attn_qkv",

@@ -17,6 +17,7 @@ depth of the sums; a fault has to miss by 100 x that.
 
 from __future__ import annotations
 
+import functools
 import importlib
 from dataclasses import replace
 
@@ -32,8 +33,8 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 from ray_tpu.ops import moe
 
+import _small_models as sm
 
-SCALE = 5.0
 TOL = 2e-5
 T, WINDOW, E, RANKS = 64, 16, 8, 4
 
@@ -49,15 +50,11 @@ def small(**kw):
 
 def make(seed: int = 0, **kw):
     """(cfg, params, rows [2, T + 1])."""
-    cfg = small(**kw)
-    params = models.init_params(jax.random.PRNGKey(seed), cfg)
-    layers = jax.tree.map(lambda a: a * SCALE, params["layers"])
-    layers["router"]["w"] = layers["router"]["w"] * 10.0
-    for name in ("ln1", "ln2"):
-        layers[name]["w"] = params["layers"][name]["w"]
-    rows = jax.random.randint(jax.random.PRNGKey(seed + 1000), (2, T + 1), 0,
-                              cfg.vocab_size)
-    return cfg, dict(params, layers=layers), rows
+    return sm.make(small, seed, tokens=T, **kw)
+
+
+# the reference's layer, compiled once a kind of layer as its ``_run`` does
+reference_layer = jax.jit(reference._layer, static_argnums=tuple(range(2, 8)))
 
 
 def held_slice(params, cfg, held):
@@ -66,10 +63,6 @@ def held_slice(params, cfg, held):
     first, end = moe.held_range(cfg.n_experts, *held)
     mlp = jax.tree.map(lambda a: a[:, first:end], params["layers"]["mlp"])
     return dict(params, layers=dict(params["layers"], mlp=mlp))
-
-
-def program_loss(params, rows, cfg):
-    return models.lm_loss(params, {"tokens": rows}, cfg)[0]
 
 
 # -- the preset ---------------------------------------------------------------
@@ -128,11 +121,11 @@ def test_every_branch_moves_the_logits():
     """The scale of this file's weights: zeroing the held experts' output
     moves the logits far over the tolerance, so a fault cannot hide."""
     cfg, params, rows = make()
-    want = models.forward(params, rows[:, :-1], cfg)
+    want = sm.forward(params, rows[:, :-1], cfg)
     mlp = dict(params["layers"]["mlp"],
                w_down=params["layers"]["mlp"]["w_down"] * 0.0)
-    off = models.forward(dict(params, layers=dict(params["layers"], mlp=mlp)),
-                         rows[:, :-1], cfg)
+    off = sm.forward(dict(params, layers=dict(params["layers"], mlp=mlp)),
+                     rows[:, :-1], cfg)
     assert float(jnp.abs(want - off).max()) > 1000 * TOL
 
 
@@ -140,13 +133,12 @@ def test_every_branch_moves_the_logits():
                                        (3, None)])
 def test_program_equals_reference_logits_loss_and_gradients(seed, held):
     cfg, params, rows = make(seed, experts_held=held)
-    got = jax.jit(models.forward, static_argnums=2)(params, rows[:, :-1], cfg)
+    got = sm.forward(params, rows[:, :-1], cfg)
     want = reference.forward(params, rows[:, :-1], cfg)
     assert float(jnp.abs(got - want).max()) < TOL
-    loss, metrics = jax.jit(models.lm_loss, static_argnums=2)(
-        params, {"tokens": rows}, cfg)
-    assert float(loss) == pytest.approx(
-        float(reference.loss(params, rows, cfg)), abs=TOL)
+    (loss, metrics), g = sm.loss_metrics_and_grads(params, rows, cfg)
+    reference_loss, r = sm.value_and_grad(reference.loss, cfg)(params, rows)
+    assert float(loss) == pytest.approx(float(reference_loss), abs=TOL)
     # the whole loss is cross entropy + 0.01 x balance over all 8 experts
     rest = 0.01 * float(metrics["router_aux"])
     assert rest > 0.0099 and float(metrics["router_z"]) > 0.0
@@ -154,10 +146,7 @@ def test_program_equals_reference_logits_loss_and_gradients(seed, held):
     assert float(loss) - rest == pytest.approx(float(ce), abs=TOL)
     assert ("moe_held_share" in metrics) == (held is not None)
     assert ("moe_full_buffer" in metrics) == (held is not None)
-    g = jax.jit(jax.grad(program_loss), static_argnums=2)(
-        params, rows, cfg)["layers"]
-    r = jax.jit(jax.grad(reference.loss), static_argnums=2)(
-        params, rows, cfg)["layers"]
+    g, r = g["layers"], r["layers"]
     for got_g, want_g in ((g["mlp"]["w_gate"][1, 0], r["mlp"]["w_gate"][1, 0]),
                           (g["mlp"]["w_down"][0, 1], r["mlp"]["w_down"][0, 1]),
                           (g["router"]["w"], r["router"]["w"]),
@@ -170,10 +159,10 @@ def test_program_equals_reference_logits_loss_and_gradients(seed, held):
 
 def test_unrolled_and_rematted_layers_are_the_same_model():
     cfg, params, rows = make()
-    want = models.forward(params, rows[:, :-1], cfg)
+    want = sm.forward(params, rows[:, :-1], cfg)
     for changes in (dict(scan_layers=False), dict(remat=False),
                     dict(remat_policy="dots")):
-        got = models.forward(params, rows[:, :-1], replace(cfg, **changes))
+        got = sm.forward(params, rows[:, :-1], replace(cfg, **changes))
         assert float(jnp.abs(got - want).max()) < TOL, changes
 
 
@@ -204,10 +193,9 @@ def test_a_fault_fails_the_comparison(name):
                              ref_cfg)
     run_cfg = replace(ref_cfg, **changes)
     params = held_slice(full, cfg, program_holds or (0, 4))
-    right = models.forward(held_slice(full, cfg, (0, 4)), rows[:, :-1],
-                           ref_cfg)
+    right = sm.forward(held_slice(full, cfg, (0, 4)), rows[:, :-1], ref_cfg)
     assert float(jnp.abs(right - want).max()) < TOL
-    got = models.forward(params, rows[:, :-1], run_cfg)
+    got = sm.forward(params, rows[:, :-1], run_cfg)
     assert float(jnp.abs(got - want).max()) > 100 * TOL, name
 
 
@@ -234,21 +222,9 @@ def test_the_four_ranks_shares_sum_to_the_uncut_layer(layer):
     args = (cfg.n_heads, float(cfg.rope_theta), cfg.expert_top_k,
             cfg.sliding_window if kind[0] else None, kind[1], 0)
     with jax.default_matmul_precision("highest"):
-        uncut, _ = reference._layer(x, lp, *args)
-        no_experts = dict(lp, mlp=dict(lp["mlp"], w_down=lp["mlp"]["w_down"] * 0))
-        h, _ = reference._layer(x, no_experts, *args)
-    parts = []
-    for rank in range(RANKS):
-        first, end = moe.held_range(E, rank, RANKS)
-        lp_r = dict(lp, mlp=jax.tree.map(lambda a: a[first:end], lp["mlp"]))
-        y_r = _one_layer(x, lp_r, replace(cfg, experts_held=(rank, RANKS)), kind)
-        parts.append(y_r - h)
-    assert all(float(jnp.abs(p).max()) > 1000 * TOL for p in parts)
-    total = h + sum(parts)
-    assert float(jnp.abs(total - uncut).max()) < 5 * TOL
-    # and the program that holds every expert is that layer too
-    whole = _one_layer(x, lp, cfg, kind)
-    assert float(jnp.abs(whole - uncut).max()) < 5 * TOL
+        sm.ranks_parts_sum_to_the_uncut_layer(
+            x, lp, cfg, RANKS, lambda x, lp: reference_layer(x, lp, *args)[0],
+            lambda x, lp, cfg: _one_layer(x, lp, cfg, kind), TOL)
 
 
 # -- dropless under skew, with held experts ---------------------------------------
@@ -310,6 +286,33 @@ SKEWS = {
 HELD_TOTALS = {"held total fills the buffer": 1024,
                "held total one over the buffer": 1025,
                "every assignment held": 2048, "no assignment held": 0}
+HELD = (2, 4)
+
+
+def _held_block(x, logits, *weights, top_k):
+    return moe.moe_swiglu_dropless(
+        x, None, *weights, top_k=top_k, router_logits=logits, held=HELD,
+        activation="relu")
+
+
+def _plain_block(x, logits, *weights, top_k):
+    """``_plain_held`` on the held experts' ``weights`` among zeros."""
+    whole = [jnp.zeros((E,) + w.shape[1:], w.dtype).at[HELD[0]:HELD[1]].set(w)
+             for w in weights]
+    return _plain_held(x, logits, *whole, HELD, top_k), None
+
+
+@functools.cache
+def _block_calls(block, top_k, whole_buffer=False):
+    """(``block``, the gradient of its output's squares by its five
+    inputs), compiled as a step is (op by op rounds apart) and once a
+    shape for all the skews. ``whole_buffer``: the CALLER has patched
+    ``moe._buffer_rows`` to give every row."""
+    def out(*a):
+        return block(*a, top_k=top_k)
+
+    return jax.jit(out), jax.jit(jax.grad(
+        lambda *a: (out(*a)[0] ** 2).sum(), range(5)))
 
 
 @pytest.mark.parametrize("skew", list(SKEWS))
@@ -326,21 +329,13 @@ def test_dropless_with_held_experts_under_skew(skew, monkeypatch):
     float32 rounding."""
     tokens, top_k, skewed, full_buffer = SKEWS[skew]
     x, w_gate, w_up, w_down, noise = _block_inputs(n=tokens)
-    logits, held = skewed(noise), (2, 4)
+    logits, held = skewed(noise), HELD
     weights = [w[held[0]:held[1]] for w in (w_gate, w_up, w_down)]
     assert moe._buffer_rows(tokens * top_k, 2, E) == {96: 288, 1024: 1024}[
         tokens]
 
-    def run(x, logits, *weights):
-        return moe.moe_swiglu_dropless(
-            x, None, *weights, top_k=top_k, router_logits=logits, held=held,
-            activation="relu")
-
-    def grads_of(block):        # compiled, as a step is: op by op rounds apart
-        return jax.jit(jax.grad(lambda *a: (block(*a) ** 2).sum(), range(5)))(
-            x, logits, *weights)
-
-    out, stats = jax.jit(run)(x, logits, *weights)
+    run, grads_of_run = _block_calls(_held_block, top_k)
+    out, stats = run(x, logits, *weights)
     want = _plain_held(x, logits, w_gate, w_up, w_down, held, top_k)
     assert float(jnp.abs(out - want).max()) < TOL
     chosen = np.asarray(jax.lax.top_k(logits, top_k)[1]).reshape(-1)
@@ -358,11 +353,8 @@ def test_dropless_with_held_experts_under_skew(skew, monkeypatch):
     if skew in ("all on absent experts", "no assignment held"):
         assert here.sum() == 0 and float(jnp.abs(out).max()) == 0.0
     # gradients: jax's own through the plain block
-    grads = grads_of(lambda *a: run(*a)[0])
-    plain = grads_of(lambda x, logits, *w: _plain_held(
-        x, logits, *[jnp.zeros_like(full).at[held[0]:held[1]].set(part)
-                     for full, part in zip((w_gate, w_up, w_down), w)],
-        held, top_k))
+    grads = grads_of_run(x, logits, *weights)
+    plain = _block_calls(_plain_block, top_k)[1](x, logits, *weights)
     for got, want_g in zip(grads, plain):
         assert bool(jnp.isfinite(got).all())
         np.testing.assert_allclose(np.asarray(got), np.asarray(want_g),
@@ -371,7 +363,8 @@ def test_dropless_with_held_experts_under_skew(skew, monkeypatch):
     # held: EQUAL where the held rows take one round of the buffer; where
     # they take two, a token's choices are summed round by round
     monkeypatch.setattr(moe, "_buffer_rows", lambda rows, held, of: rows)
-    full_out, full_stats = jax.jit(lambda *a: run(*a))(x, logits, *weights)
+    run, grads_of_run = _block_calls(_held_block, top_k, whole_buffer=True)
+    full_out, full_stats = run(x, logits, *weights)
     same = (np.testing.assert_array_equal if not full_buffer else
             lambda a, b: np.testing.assert_allclose(
                 a, b, rtol=1e-5, atol=1e-5 * float(np.abs(b).max())))
@@ -380,7 +373,7 @@ def test_dropless_with_held_experts_under_skew(skew, monkeypatch):
     for name, value in full_stats.items():
         np.testing.assert_array_equal(np.asarray(stats[name]),
                                       np.asarray(value), err_msg=name)
-    x_g, logits_g, *weight_gs = zip(grads, grads_of(lambda *a: run(*a)[0]))
+    x_g, logits_g, *weight_gs = zip(grads, grads_of_run(x, logits, *weights))
     for got, full in (x_g, *weight_gs):
         same(np.asarray(got), np.asarray(full))
     # the gates' gradient <h, dh_u> is a sum of ``d_ff`` products that
@@ -403,7 +396,7 @@ def _held_counts(cfg, params, rows):
         chosen = np.asarray(jax.lax.top_k(r, cfg.expert_top_k)[1]).reshape(-1)
         counts.append(np.bincount(chosen, minlength=cfg.n_experts)[first:end])
         windowed, with_rope = cfg.layer_pattern[i % len(cfg.layer_pattern)]
-        x, _ = reference._layer(
+        x, _ = reference_layer(
             x, lp, cfg.n_heads, float(cfg.rope_theta), cfg.expert_top_k,
             cfg.sliding_window if windowed else None, bool(with_rope), first)
     return np.stack(counts)
@@ -420,14 +413,10 @@ def test_the_train_steps_counters_count_the_whole_batch(seed, held):
     share of the layers whose held assignments overflow the row buffer
     (none: at 384 assignments the buffer is all of them). A model that
     holds every expert reports neither the share nor the buffer."""
-    import optax
-
     cfg, params, rows = make(seed, experts_held=held)
-    opt = optax.adamw(3e-4)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    _, metrics = jax.jit(models.make_train_step(cfg, opt))(
-        state, {"tokens": rows})
+    opt = sm.adamw(3e-4)
+    _, metrics = sm.train_step(cfg, opt)(sm.train_state(params, opt),
+                                         {"tokens": rows})
     here = _held_counts(cfg, params, rows)
     assignments = rows[:, :-1].size * cfg.expert_top_k
     shares, fullest = here.sum(1) / assignments, here.max(1) / here.mean(1)
@@ -440,12 +429,21 @@ def test_the_train_steps_counters_count_the_whole_batch(seed, held):
                             cfg.n_experts) == assignments
     assert float(metrics["moe_full_buffer"]) == 0.0
     whole = replace(cfg, experts_held=None)
-    _, metrics = jax.jit(models.make_train_step(whole, opt))(
-        {**state, "params": models.init_params(jax.random.PRNGKey(0), whole),
-         "opt_state": opt.init(models.init_params(jax.random.PRNGKey(0),
-                                                  whole))}, {"tokens": rows})
+    _, metrics = sm.train_step(whole, opt)(
+        sm.train_state(models.init_params(jax.random.PRNGKey(0), whole), opt),
+        {"tokens": rows})
     assert not {"moe_held_share", "moe_full_buffer"} & set(metrics)
     assert "moe_load_max" in metrics
+
+
+@functools.cache
+def _patched_step(cfg, room):
+    """The loss, its metrics and its gradients under ``moe._HELD_ROOM`` =
+    ``room`` (None: ``moe._buffer_rows`` gives every row), which the CALLER
+    has patched: the patch is in this key because it is in no key of
+    ``_small_models``."""
+    return jax.jit(jax.value_and_grad(lambda p, rows: models.lm_loss(
+        p, {"tokens": rows}, cfg), has_aux=True))
 
 
 @pytest.mark.parametrize("seed,room", [(0, 2), (4, 2), (4, 1)])
@@ -463,16 +461,13 @@ def test_the_short_row_buffer_is_the_same_model(seed, room, monkeypatch):
     buffer = moe._buffer_rows(512 * cfg.expert_top_k, 2, cfg.n_experts)
     assert buffer == 512 * room
 
-    def step():
-        return jax.jit(jax.value_and_grad(lambda p: models.lm_loss(
-            p, {"tokens": rows}, cfg), has_aux=True))(params)
-
-    (loss, metrics), grads = step()
+    (loss, metrics), grads = _patched_step(cfg, room)(params, rows)
     over = _held_counts(cfg, params, rows).sum(1) > buffer
     assert float(metrics["moe_full_buffer"]) == over.mean()
     assert over.any() == (room == 1) and not over.all()
     monkeypatch.setattr(moe, "_buffer_rows", lambda rows, held, of: rows)
-    (full_loss, full_metrics), full_grads = step()
+    (full_loss, full_metrics), full_grads = _patched_step(cfg, None)(
+        params, rows)
     assert float(full_metrics["moe_full_buffer"]) == 0.0
     assert float(loss) == pytest.approx(float(full_loss), abs=1e-6)
     for got, full in zip(jax.tree.leaves(grads), jax.tree.leaves(full_grads)):
@@ -535,13 +530,13 @@ def test_the_new_scopes_are_on_the_instructions():
     matmul under ``moe`` / ``moe_router`` although it runs ahead of
     attention; a model with no pattern opens neither attention scope."""
     cfg, params, rows = make()
-    text = jax.jit(lambda p, t: models.forward(p, t, cfg)).lower(
+    text = sm.jitted(models.forward, cfg).lower(
         params, rows[:, :-1]).as_text(debug_info=True)
     for path in ("attn/attn_full", "attn/attn_window", "moe/moe_router",
                  "moe/moe_experts"):
         assert path in text, path
     dense = models.tiny(arch="llama")
-    text = jax.jit(lambda p, t: models.forward(p, t, dense)).lower(
+    text = sm.jitted(models.forward, dense).lower(
         models.init_params(jax.random.PRNGKey(0), dense),
         rows[:, :-1]).as_text(debug_info=True)
     assert "attn_full" not in text and "attn_window" not in text

@@ -26,7 +26,6 @@ from dataclasses import replace
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 from chipbench import spec
@@ -36,7 +35,8 @@ from ray_tpu import models
 from ray_tpu.models import transformer
 from ray_tpu.ops import moe
 
-SCALE = 5.0
+import _small_models as sm
+
 TOL = 2e-5
 T, E, K, RANKS, WINDOW = 64, 8, 3, 4, 16
 S, F = (True, True), (False, False)
@@ -56,30 +56,21 @@ def small(**kw):
 
 def make(seed: int = 0, **kw):
     """(cfg, params, rows [2, T + 1])."""
-    cfg = small(**kw)
-    params = models.init_params(jax.random.PRNGKey(seed), cfg)
+    cfg, params, rows = sm.make(
+        small, seed, tokens=T, bias_scale=5.0,
+        as_drawn=NORMS + ("q_norm", "k_norm"), **kw)
     spread = iter(jax.random.split(jax.random.PRNGKey(seed + 500), 64))
 
     def around_one(a):
         return a + 0.3 * jax.random.normal(next(spread), a.shape, a.dtype)
 
-    out = dict(params)
     for stack in ("layers", "dense_layers"):
-        layers = jax.tree.map(lambda a: a * SCALE, params[stack])
+        layers = params[stack]
         for name in NORMS:
-            layers[name]["w"] = around_one(params[stack][name]["w"])
+            layers[name]["w"] = around_one(layers[name]["w"])
         for name in ("q_norm", "k_norm"):
-            layers["attn"][name] = around_one(params[stack]["attn"][name])
-        out[stack] = layers
-    out["layers"]["router"]["w"] = out["layers"]["router"]["w"] * 10.0
-    out["layers"]["router"]["b"] = out["layers"]["router"]["b"] * 5.0
-    rows = jax.random.randint(jax.random.PRNGKey(seed + 1000), (2, T + 1), 0,
-                              cfg.vocab_size)
-    return cfg, out, rows
-
-
-def program_loss(params, rows, cfg):
-    return models.lm_loss(params, {"tokens": rows}, cfg)[0]
+            layers["attn"][name] = around_one(layers["attn"][name])
+    return cfg, params, rows
 
 
 def reference_loss(params, rows, cfg):
@@ -156,9 +147,8 @@ def test_the_scan_over_periods_equals_the_unrolled_model(kw):
     logits are the unrolled one's (its gradients are held to the
     reference's below, leaf by leaf)."""
     cfg, params, rows = make(**kw)
-    z = models.forward(params, rows[:, :-1], cfg)
-    z_loop = models.forward(params, rows[:, :-1],
-                            replace(cfg, scan_layers=False))
+    z = sm.forward(params, rows[:, :-1], cfg)
+    z_loop = sm.forward(params, rows[:, :-1], replace(cfg, scan_layers=False))
     assert float(jnp.abs(z - z_loop).max()) < TOL
 
 
@@ -170,11 +160,11 @@ def test_the_scan_over_periods_equals_the_unrolled_model(kw):
     ids=["share", "uncut", "from0"])
 def test_logits_and_loss_are_the_references(kw):
     cfg, params, rows = make(**kw)
-    z_p = models.forward(params, rows[:, :-1], cfg)
+    z_p = sm.forward(params, rows[:, :-1], cfg)
     z_r = reference.forward(params, rows[:, :-1], cfg)
     assert float(jnp.std(z_r)) > 0.1
     assert float(jnp.abs(z_p - z_r).max()) < 5 * TOL
-    assert float(program_loss(params, rows, cfg)) == pytest.approx(
+    assert float(sm.loss(params, rows, cfg)) == pytest.approx(
         float(reference_loss(params, rows, cfg)), abs=TOL)
 
 
@@ -184,8 +174,8 @@ def test_every_leafs_gradient_is_the_references():
     (through its scale) among the leaves; the router's bias alone has no
     gradient, on either side."""
     cfg, params, rows = make()
-    g_p = jax.jit(jax.grad(program_loss), static_argnums=2)(params, rows, cfg)
-    g_r = jax.grad(reference_loss)(params, rows, cfg)
+    g_p = sm.loss_metrics_and_grads(params, rows, cfg)[1]
+    g_r = sm.grad(reference_loss, cfg)(params, rows)
     flat_p = dict(jax.tree_util.tree_flatten_with_path(g_p)[0])
     flat_r = dict(jax.tree_util.tree_flatten_with_path(g_r)[0])
     assert flat_p.keys() == flat_r.keys()
@@ -237,7 +227,7 @@ def test_the_comparison_sees(name):
             attn["q_norm"] = jnp.tile(attn["q_norm"], (1, cfg.n_heads))
             attn["k_norm"] = jnp.tile(attn["k_norm"], (1, cfg.kv_heads))
             params = dict(params, **{stack: dict(params[stack], attn=attn)})
-    z_p = models.forward(params, rows[:, :-1], replace(cfg, **fault))
+    z_p = sm.forward(params, rows[:, :-1], replace(cfg, **fault))
     _, good, _ = make()
     z_r = reference.forward(good, rows[:, :-1], cfg)
     assert float(jnp.abs(z_p - z_r).max()) > 100 * TOL, name
@@ -268,7 +258,7 @@ def test_the_sixteenths_and_the_shared_expert_once_sum_to_the_uncut_layer(
     rope = transformer.rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                         theta=cfg.rope_theta)
     with jax.default_matmul_precision("highest"):
-        uncut = reference._layer(
+        uncut = jax.jit(reference._layer, static_argnums=tuple(range(2, 8)))(
             x, lp, False, window, float(cfg.rope_theta), cfg.expert_top_k,
             float(cfg.expert_gate_scale), 0)
         after_attn = reference._mixer(x, lp, window, float(cfg.rope_theta))
@@ -345,7 +335,7 @@ def test_what_the_config_accepts():
         cfg = models.tiny(arch="llama", **kw)
         params = models.init_params(jax.random.PRNGKey(0), cfg)
         rows = jnp.zeros((1, 9), jnp.int32)
-        assert np.isfinite(float(program_loss(params, rows, cfg)))
+        assert np.isfinite(float(sm.loss(params, rows, cfg)))
     # a plain model's leaves are what they were
     plain = models.init_params(jax.random.PRNGKey(0), models.tiny(arch="llama"))
     assert set(plain["layers"]) == {"attn", "ln1", "ln2", "mlp"}
@@ -400,11 +390,9 @@ def test_partition_specs_cover_the_new_leaves():
 def test_the_step_reports_the_gates_mean_and_moves_the_bias_by_rule():
     cfg, _, rows = make()
     params = models.init_params(jax.random.PRNGKey(0), cfg)
-    opt = optax.adamw(1e-3, weight_decay=0.1)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    new, metrics = jax.jit(models.make_train_step(cfg, opt))(
-        state, {"tokens": rows})
+    opt = sm.adamw(1e-3, weight_decay=0.1)
+    new, metrics = sm.train_step(cfg, opt)(sm.train_state(params, opt),
+                                           {"tokens": rows})
     # N(0, 0.02) gate weights on a unit-size input: logits of std 0.16
     assert float(metrics["attn_gate_mean"]) == pytest.approx(0.5, abs=0.01)
     assert np.ndim(metrics["attn_gate_mean"]) == 0
@@ -426,9 +414,7 @@ def test_the_step_reports_the_gates_mean_and_moves_the_bias_by_rule():
 def test_accumulation_keeps_the_counter():
     cfg, _, rows = make()
     params = models.init_params(jax.random.PRNGKey(0), cfg)
-    opt = optax.adamw(1e-3)
-    state = {"params": params, "opt_state": opt.init(params),
-             "step": jnp.zeros((), jnp.int32)}
-    _, metrics = jax.jit(models.make_train_step(cfg, opt, accum_steps=2))(
-        state, {"tokens": rows})
+    opt = sm.adamw(1e-3)
+    _, metrics = sm.train_step(cfg, opt, accum_steps=2)(
+        sm.train_state(params, opt), {"tokens": rows})
     assert float(metrics["attn_gate_mean"]) == pytest.approx(0.5, abs=0.01)
