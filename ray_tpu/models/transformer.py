@@ -1680,28 +1680,42 @@ def _latent_qkv(h, w, c: TransformerConfig, rope, positions):
     (``ops.attention``: ``q_shared`` / ``k_shared``). RoPE pairs the
     halves of the rotary part, as ``apply_rope`` does everywhere. With
     ``rope`` None (``latent_rope`` False) nothing is rotated: the shared
-    part is a plain key part, and ``attn_pos`` stays empty."""
+    part is a plain key part, and ``attn_pos`` stays empty.
+
+    The WEIGHTS are split (``wq`` into its no-position and rotary columns,
+    ``wkv_b`` into its key and value columns), never the ``[B, T, H, 192]``
+    / ``[B, T, H, 256]`` activations: a slice between a matmul and a
+    custom call cannot fuse into either, so each was a copy of the whole
+    operand (``[2, 32, 8192, 128]``: 0.43 ms, v5e), where a projection's
+    own output is written head-major as the kernels read it. The tree
+    keeps ONE ``wq`` and ONE ``wkv_b``."""
     dt = c.compute_dtype
     nope, latent = c.d_head_nope, c.kv_latent
     with jax.named_scope("attn_qkv"):
-        q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
+        wq = w["wq"].astype(dt)
+        q_nope = jnp.einsum("btd,dhk->bthk", h, wq[..., :nope])
+        q_rope = jnp.einsum("btd,dhk->bthk", h, wq[..., nope:])
     with jax.named_scope(MLA_SCOPE):
         down = jnp.einsum("btd,dc->btc", h, w["wkv_a"].astype(dt))
-        kv = jnp.einsum("btc,chk->bthk",
-                        rms_norm(down[..., :latent], w["kv_norm"],
-                                 eps=c.norm_eps),
-                        w["wkv_b"].astype(dt))
-        if rope is None:
-            q_rope, k_rope = q[..., nope:], down[..., latent:]
-        else:
+        normed = rms_norm(down[..., :latent], w["kv_norm"], eps=c.norm_eps)
+        wkv_b = w["wkv_b"].astype(dt)
+        k_nope = jnp.einsum("btc,chk->bthk", normed, wkv_b[..., :nope])
+        v = jnp.einsum("btc,chk->bthk", normed, wkv_b[..., nope:])
+        k_rope = down[..., latent:]
+        if rope is not None:
             cos, sin = rope
             with jax.named_scope("attn_pos"):
-                q_rope = apply_rope(q[..., nope:], cos, sin,
-                                    positions=positions)
-                k_rope = apply_rope(down[:, :, None, latent:], cos, sin,
+                # RoPE reads the projection ROUNDED to ``dt``, as the
+                # kernels read q_nope: left to itself XLA hands it the
+                # matmul's float32 accumulator (excess precision), and
+                # the forward is no longer the one ``correct`` was set on.
+                bits = jnp.finfo(dt)
+                q_rope = apply_rope(
+                    jax.lax.reduce_precision(q_rope, bits.nexp, bits.nmant),
+                    cos, sin, positions=positions)
+                k_rope = apply_rope(k_rope[:, :, None], cos, sin,
                                     positions=positions)[:, :, 0]
-        return (q[..., :nope], kv[..., :nope], kv[..., nope:],
-                {"q_shared": q_rope, "k_shared": k_rope})
+        return (q_nope, k_nope, v, {"q_shared": q_rope, "k_shared": k_rope})
 
 
 def _qk_norm(x, weight):

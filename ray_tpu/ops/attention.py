@@ -491,6 +491,10 @@ def _flash_fwd_kernel(*refs, block_q, block_k, n_k, n_steps, causal, scale,
         lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
 
 
+# The two moves below are bitcasts where XLA can lay their operand
+# head-major itself, as it does a projection's OWN output: a slice, a pad
+# or a concatenate between the matmul and the kernel makes each a copy
+# of the whole operand (``models/transformer.py`` ``_latent_qkv``).
 def _heads_flat(x):
     """[B, T, H, W] -> [B * H, T, W]: one grid row a (batch, head)."""
     b, t, h, w = x.shape

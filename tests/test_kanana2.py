@@ -217,6 +217,48 @@ def test_scanned_unrolled_and_rematted_layers_are_the_same_model():
             assert float(jnp.abs(a - b).max()) < TOL, changes
 
 
+@pytest.mark.parametrize("rotated", [True, False],
+                         ids=["rope", "nope_as_kimi_linears_layer"])
+def test_no_activation_is_cut_between_a_projection_and_the_kernels(rotated):
+    """``_latent_qkv`` splits the WEIGHTS: nothing slices a ``[B, T, H, *]``
+    activation but RoPE taking the halves of a projection's own output
+    (rounded first), and each of the four by-head operands is a
+    ``dot_general``'s output (the rotary query part through RoPE). On the
+    chip a cut between a matmul and a custom call is a copy of the whole
+    operand."""
+    cfg, params, _ = make()
+    own = jax.tree.map(lambda a: a[0], params["layers"]["attn"])
+    rope = transformer.rope_frequencies(
+        cfg.d_head_rope, cfg.max_seq_len, theta=cfg.rope_theta
+    ) if rotated else None
+    jaxpr = jax.make_jaxpr(
+        lambda h, w: transformer._latent_qkv(h, w, cfg, rope, None))(
+            jnp.zeros((2, T, cfg.d_model), jnp.float32), own).jaxpr
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+
+    def by_head(v):
+        shape = getattr(v.aval, "shape", ())
+        return len(shape) == 4 and shape[:3] == (2, T, cfg.n_heads)
+
+    def in_rope(e):
+        return "attn_pos" in str(e.source_info.name_stack)
+
+    cuts = [e for e in jaxpr.eqns if
+            e.primitive.name in ("slice", "split", "dynamic_slice", "gather")
+            and by_head(e.invars[0])]
+    assert len(cuts) == rotated and all(in_rope(e) for e in cuts), cuts
+    q_nope, k_nope, v, k_shared, q_shared = jaxpr.outvars   # keys sorted
+    assert not by_head(k_shared) and by_head(q_shared)
+    source, passed = made_by[q_shared], set()
+    while in_rope(source):              # back through RoPE's arithmetic
+        passed.add(source.primitive.name)
+        source = made_by[next(v for v in source.invars if by_head(v))]
+    # RoPE reads the projection rounded: XLA may not hand it the accumulator
+    assert ("reduce_precision" in passed) == rotated == bool(passed)
+    for e in (made_by[q_nope], made_by[k_nope], made_by[v], source):
+        assert e.primitive.name == "dot_general", e
+
+
 def _scan_unrolls(cfg):
     """The ``unroll`` of every ``scan`` in the model's forward."""
     tokens = jax.ShapeDtypeStruct((1, 32), jnp.int32)
