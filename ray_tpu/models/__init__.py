@@ -28,6 +28,7 @@ from ray_tpu.models.transformer import (
     mistral_7b,
     mixtral_8x7b,
     moe_small,
+    nemotron_3_nano_30b_a3b,
     olmoe_1b_7b,
     partition_specs,
     qwen2_7b,
@@ -58,6 +59,7 @@ __all__ = [
     "init_train_state",
     "kanana_2_30b_a3b",
     "kimi_linear_48b_a3b",
+    "nemotron_3_nano_30b_a3b",
     "trinity_mini_26b_a3b",
     "qwen3_next_80b_a3b",
     "llama2_7b",

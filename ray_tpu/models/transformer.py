@@ -72,6 +72,20 @@ stack ``mha``), whose heads rotate their first quarter only
 (``rope_fraction``); **zero-centred norms** (``norm_zero_centred``: ``x /
 rms x (1 + w)``, ``w`` made 0); a **gate on the shared expert**
 (``shared_expert_gate``: ``sigmoid(w_s . h)``, one number a token).
+And a ``nemotron_h``-shaped model (Nemotron-3-Nano): a stack of
+**single-sublayer blocks**. ``layer_mixers`` may name ``"ffn"``, NO mixer:
+such a layer is its norm and its FFN (here: experts) alone, and in a model
+that has one, a layer named by a mixer is its norm and that mixer alone:
+``x <- x + f(norm(x))`` with ONE ``f`` a layer. A layer holds only its own
+sublayer's leaves (``_holds``: ``ln1``, ``attn/wo`` and the mixer's own
+stack over the mixer layers, ``ln2``, ``router`` and ``mlp`` over the FFN
+layers: no dead leaf for the optimizer to carry). Its mixers are **Mamba-2
+state-space layers** (``"ssm"``: ``ops/state_space.py``, a scalar decay a
+head, ``B`` and ``C`` shared by the heads of a group, a gated group norm)
+and plain GQA attention with **no positional encoding** (``attn_rope``
+False); its experts have **no gate projection** (``expert_gated`` False:
+``W_down relu(W_up u)^2``, ``expert_activation`` "relu2"), the shared
+expert likewise.
 With a period of P > 1 the scan runs over
 WHOLE PERIODS and unrolls a period's P layers in its body, so each
 position's kind is static: a windowed layer compiles to the kernel that
@@ -99,7 +113,7 @@ import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops import linear_attention, moe
+from ray_tpu.ops import linear_attention, moe, state_space
 from ray_tpu.ops.attention import (FLASH_LSE_NAME, FLASH_OUT_NAME, attention,
                                    dot_product_attention)
 from ray_tpu.ops.layers import (
@@ -136,23 +150,28 @@ SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
 # Inside ``attn``, for a model with a ``layer_pattern``, with latent
 # attention (whose layers are all full causal ones) or with
 # ``layer_mixers``: which kind of layer the instruction belongs to.
-# ``attn_linear`` is everything of a linear (KDA or Gated DeltaNet)
-# layer's mixer: inside it ``attn_qkv`` (the q / k / v projections; Gated
-# DeltaNet's z with them), ``ops.linear_attention.SCOPES`` (``kda_conv``,
-# ``kda_gate``), ``attn_core`` (the chunked delta rule and nothing else)
-# and ``attn_out``.
+# ``attn_linear`` is everything of a linear (KDA, Gated DeltaNet or
+# state-space) layer's mixer: inside it ``attn_qkv`` (the q / k / v
+# projections; Gated DeltaNet's z with them; a state-space layer's input
+# projection), ``ops.linear_attention.SCOPES`` (``kda_conv``, ``kda_gate``),
+# ``attn_core`` (the chunked delta rule, or the state-space scan with its
+# ``ssm_carry``, and nothing else) and ``attn_out``.
 ATTN_SCOPES = ("attn_full", "attn_window", "attn_linear")
-# The token mixers ``layer_mixers`` may name: "kda" and "gdn" are the
-# LINEAR ones (a state carried along the sequence); "attn" is the model's
-# attention, latent with ``kv_latent`` and plain without.
-MIXERS = ("attn", "kda", "gdn")
-LINEAR_MIXERS = ("kda", "gdn")
+# The token mixers ``layer_mixers`` may name: "kda", "gdn" and "ssm" are
+# the LINEAR ones (a state carried along the sequence); "attn" is the
+# model's attention, latent with ``kv_latent`` and plain without.
+# ``FFN_ONLY`` names no mixer: the layer is its FFN alone, and makes the
+# model a stack of single-sublayer blocks (``single_sublayer``).
+MIXERS = ("attn", "kda", "gdn", "ssm")
+LINEAR_MIXERS = ("kda", "gdn", "ssm")
+FFN_ONLY = "ffn"
 # A stack's subtrees that hold ONE kind of mixer's own leaves, stacked
 # over the layers of that kind (``layer_mixers`` models only): a linear
 # mixer's under its own name, attention's under ``mla`` (latent) or
 # ``mha`` (plain: q, k, v, the head norms, the gate). ``attn/wo`` is every
 # layer's, whatever its mixer.
-MIXER_STACKS = {"kda": "kda", "gdn": "gdn", "attn": ("mla", "mha")}
+MIXER_STACKS = {"kda": "kda", "gdn": "gdn", "ssm": "ssm",
+                "attn": ("mla", "mha")}
 
 
 def _own_stacks(c) -> tuple[str | None, str]:
@@ -186,7 +205,8 @@ ATTN_PART_SCOPES = ("attn_qkv", "attn_pos", "attn_gqa", "attn_core",
 # metadata out, so a step loaded from the cache would keep the scope names
 # of whatever tree compiled it. ``SCOPES_ID`` names the bytes of every
 # file that opens a scope of the step (this one, ``ops/moe.py``,
-# ``ops/attention.py`` and ``ops/linear_attention.py``) and
+# ``ops/attention.py``, ``ops/linear_attention.py`` and
+# ``ops/state_space.py``) and
 # rides on one instruction of the train step (the step counter's add) as a
 # frontend attribute, which the key does take: a tree in which one of them
 # differs compiles its own step and never loads another's, so the names in
@@ -195,7 +215,7 @@ ATTN_PART_SCOPES = ("attn_qkv", "attn_pos", "attn_gqa", "attn_core",
 # (``ray_tpu.ops.attention`` the attribute is the function, not the module.)
 SCOPE_FILES = (__file__, moe.__file__,
                sys.modules[attention.__module__].__file__,
-               linear_attention.__file__)
+               linear_attention.__file__, state_space.__file__)
 
 
 def _scopes_id(files=SCOPE_FILES) -> str:
@@ -376,6 +396,26 @@ class TransformerConfig:
     # The shared expert's output times ``sigmoid(w_s . h)``, ONE number a
     # token (``mlp/shared_gate`` [D]).
     shared_expert_gate: bool = False
+    # -- a nemotron_h-shaped model (llama arch) ----------------------------
+    # A state-space ("ssm") layer: ``kda_heads`` heads of ``kda_head_dim``
+    # channels and a convolution over ``kda_conv`` positions, as the other
+    # linear mixers'; a state ``ssm_state`` wide a channel, ``B`` and ``C``
+    # shared by the ``kda_heads / ssm_groups`` heads of a group (also the
+    # groups of the output's gated norm), in chunks of ``ssm_chunk``.
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 128
+    # The convolution adds a bias a channel. Every state-space model here
+    # has it; the field stays only because the benchmark's control
+    # ``--break ssm_conv_bias=false`` runs the program without one.
+    ssm_conv_bias: bool = True
+    # Plain attention beside ``layer_mixers`` rotates its heads (RoPE);
+    # False: no positional encoding at all.
+    attn_rope: bool = True
+    # An expert (routed or shared) is ``W_down (act(W_gate u) * W_up u)``;
+    # False: NO gate projection, ``W_down act(W_up u)`` (no ``w_gate`` /
+    # ``shared_w_gate`` leaf).
+    expert_gated: bool = True
 
     def __post_init__(self):
         # A config file's JSON gives lists: keep the config hashable.
@@ -416,22 +456,38 @@ class TransformerConfig:
 
     @property
     def linear_mixer(self) -> str | None:
-        """The model's LINEAR mixer ("kda" or "gdn": one model has at most
-        one kind), None for a model of attention layers only."""
+        """The model's LINEAR mixer ("kda", "gdn" or "ssm": one model has
+        at most one kind), None for a model of attention layers only."""
         return next((m for m in LINEAR_MIXERS if m in self.layer_mixers),
                     None)
 
+    @property
+    def single_sublayer(self) -> bool:
+        """Whether every layer is ONE sublayer (its mixer or its FFN):
+        the model names a layer with no mixer."""
+        return FFN_ONLY in self.layer_mixers
+
+    def layers_with(self, sublayer: str) -> tuple[int, ...]:
+        """The layers (of the ``n_layers``) that run ``sublayer``: the
+        mixer of that name, or "ffn" (every layer of a model of whole
+        blocks)."""
+        if sublayer == FFN_ONLY and not self.single_sublayer:
+            return tuple(range(self.n_layers))
+        names = self.layer_mixers or ("attn",) * self.n_layers
+        return tuple(i for i, m in enumerate(names) if m == sublayer)
+
     def layer_kind(self, i: int) -> tuple[bool, bool] | str | None:
-        """The kind of layer ``i`` of the ``n_layers``: "kda" or "gdn" for
-        a linear layer, else (windowed, rope) of its attention (latent
-        attention: full, rotated or not; plain attention beside linear
-        layers: full, rotated); None with no pattern: the arch's own."""
-        if self.layer_mixers and self.layer_mixers[i] in LINEAR_MIXERS:
+        """The kind of layer ``i`` of the ``n_layers``: "kda", "gdn" or
+        "ssm" for a linear layer, "ffn" for a layer with no mixer, else
+        (windowed, rope) of its attention (latent attention: full, rotated
+        or not; plain attention beside linear layers: full, rotated or
+        not, ``attn_rope``); None with no pattern: the arch's own."""
+        if self.layer_mixers and self.layer_mixers[i] != "attn":
             return self.layer_mixers[i]
         if self.kv_latent is not None:
             return (False, self.latent_rope)
         if self.layer_mixers:
-            return (False, True)
+            return (False, self.attn_rope)
         if not self.layer_pattern:
             return None
         return self.layer_pattern[((self.first_layer or 0) + i)
@@ -705,6 +761,48 @@ def qwen3_next_80b_a3b(**kw) -> TransformerConfig:
     )
 
 
+NEMOTRON_3_NANO_LAYERS = (
+    "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME")
+
+
+def nemotron_3_nano_30b_a3b(**kw) -> TransformerConfig:
+    """NVIDIA-Nemotron-3-Nano-30B-A3B (nvidia ``config.json``,
+    ``model_type`` ``nemotron_h``; arXiv:2504.03624; the public
+    implementation of its mixer is ``transformers``' ``models/bamba`` /
+    ``mamba2`` ``torch_forward``, of its router ``models/deepseek_v3``): 52
+    layers over a 2,688-wide stream, EACH ONE sublayer behind one RMSNorm,
+    by ``hybrid_override_pattern``: ``M`` a Mamba-2 mixer (64 heads of 64,
+    state 128, ``B`` / ``C`` in 8 groups, a convolution over 4 positions
+    with a bias, chunks of 128, the output gated by ``silu(z)`` AHEAD of an
+    RMS norm over 8 groups of 512 channels), ``*`` attention (32 query
+    heads on 2 key / value heads of 128, NO positional encoding), ``E``
+    experts (128 of ``W_down relu(W_up u)^2`` 1,856 wide, no gate
+    projection, 6 a token by a sigmoid router whose bias only the choice
+    sees, gates renormalised and scaled by 2.5, one shared expert of the
+    same form 3,712 wide); no router loss term. ``n_layers=n`` takes the
+    published layers 0 to n - 1. The bias's rate and rule are DeepSeek-V3's
+    (arXiv:2412.19437); ``rescale_prenorm_residual`` (an init scale) is
+    not applied."""
+    names = {"M": "ssm", "E": FFN_ONLY, "*": "attn"}
+    mixers = tuple(names[kind] for kind
+                   in NEMOTRON_3_NANO_LAYERS[:kw.get("n_layers", 52)])
+    return replace(
+        TransformerConfig(
+            vocab_size=131072, n_layers=52, d_model=2688, n_heads=32,
+            n_kv_heads=2, d_head=128, d_ff=1856, max_seq_len=262144,
+            arch="llama", norm_eps=1e-5, attn_rope=False,
+            layer_mixers=mixers, kda_heads=64, kda_head_dim=64, kda_conv=4,
+            ssm_state=128, ssm_groups=8, ssm_chunk=128, d_ff_shared=3712,
+            expert_gated=False, expert_activation="relu2", n_experts=128,
+            expert_top_k=6, expert_capacity_factor=None,
+            expert_norm_topk=True, router_aux_weight=0.0,
+            router_z_weight=0.0, router_score="sigmoid", router_bias=True,
+            router_bias_rate=1e-3, expert_gate_scale=2.5,
+        ),
+        **kw,
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -804,14 +902,18 @@ def _check_config(c: TransformerConfig) -> None:
                      or c.router_input != "mlp_norm"
                      or c.expert_activation != "silu"
                      or c.router_score != "softmax" or c.router_bias
-                     or c.expert_gate_scale != 1.0 or c.d_ff_shared)
+                     or c.expert_gate_scale != 1.0 or c.d_ff_shared
+                     or not c.expert_gated)
     if asks_dropless and (c.n_experts == 0
                           or c.expert_capacity_factor is not None):
         raise ValueError(
-            "experts_held, router_input='attn_norm', a ReGLU "
+            "experts_held, router_input='attn_norm', a ReGLU or relu2 "
             "expert_activation, router_score='sigmoid', router_bias, "
-            "expert_gate_scale and d_ff_shared are the dropless path's "
-            "(n_experts > 0, expert_capacity_factor=None)")
+            "expert_gate_scale, d_ff_shared and expert_gated=False are the "
+            "dropless path's (n_experts > 0, expert_capacity_factor=None)")
+    if not c.expert_gated and c.shared_expert_gate:
+        raise ValueError("shared_expert_gate does not run with experts "
+                         "that have no gate projection (expert_gated)")
     if c.router_bias_rate and not c.router_bias:
         raise ValueError("router_bias_rate moves the router_bias: set it")
     if c.kv_latent is not None:
@@ -838,12 +940,33 @@ def _check_config(c: TransformerConfig) -> None:
             f"linear_key_heads={c.linear_key_heads} are the query / key "
             f"heads of a model with 'gdn' layer_mixers and divide its "
             f"kda_heads={c.kda_heads}")
+    if not c.attn_rope and (not c.layer_mixers or c.kv_latent is not None):
+        raise ValueError("attn_rope=False describes plain attention beside "
+                         "layer_mixers (a layer_pattern says it a position, "
+                         "latent_rope for latent attention)")
     if c.layer_mixers:
         latent = c.kv_latent is not None
         wo = (c.n_heads, c.d_head_v if latent else c.head_dim)
+        linear_name = ("KDA" if latent else "state-space"
+                       if "ssm" in c.layer_mixers else "Gated DeltaNet")
+        # a layer whose kind has no stack to take its leaves from, by index
+        needs = {"ssm": ("ssm_state >= 1, ssm_chunk >= 1 and ssm_groups "
+                         "that divide kda_heads",
+                         min(c.ssm_state, c.ssm_chunk, c.ssm_groups) >= 1
+                         and c.kda_heads % max(c.ssm_groups, 1) == 0),
+                 FFN_ONLY: ("an FFN of experts alone (n_experts > 0, no "
+                            "n_dense_layers, no post_norm, router_input "
+                            "'mlp_norm')",
+                            c.n_experts > 0 and not c.n_dense_layers
+                            and not c.post_norm
+                            and c.router_input == "mlp_norm")}
+        for i, name in enumerate(c.layer_mixers):
+            if name in needs and not needs[name][1]:
+                raise ValueError(
+                    f"layer_mixers[{i}] = {name!r} needs {needs[name][0]}")
         for name, wrong in (
-                (f"names other than {MIXERS}",
-                 not set(c.layer_mixers) <= set(MIXERS)),
+                (f"names other than {MIXERS + (FFN_ONLY,)}",
+                 not set(c.layer_mixers) <= set(MIXERS + (FFN_ONLY,))),
                 (f"{len(c.layer_mixers)} names for n_layers={c.n_layers}",
                  len(c.layer_mixers) != c.n_layers),
                 ("a layer_pattern", bool(c.layer_pattern)),
@@ -851,14 +974,16 @@ def _check_config(c: TransformerConfig) -> None:
                  "kda" in c.layer_mixers and not latent),
                 ("'gdn' beside latent attention (kv_latent)",
                  "gdn" in c.layer_mixers and latent),
-                ("'kda' and 'gdn' in one model",
-                 set(LINEAR_MIXERS) <= set(c.layer_mixers)),
+                ("'ssm' beside latent attention (kv_latent)",
+                 "ssm" in c.layer_mixers and latent),
+                ("more than one kind of linear mixer in one model",
+                 len(set(LINEAR_MIXERS) & set(c.layer_mixers)) > 1),
                 ("kda_heads, kda_head_dim or kda_conv < 1",
                  min(c.kda_heads, c.kda_head_dim, c.kda_conv) < 1),
                 # attn/wo is ONE stack over every layer, whatever its mixer:
                 # as many rows from a linear layer's heads as from
                 # attention's (KDA: the same heads)
-                (f"{'KDA' if latent else 'Gated DeltaNet'} heads "
+                (f"{linear_name} heads "
                  f"{(c.kda_heads, c.kda_head_dim)} that are not attention's "
                  f"(n_heads, a value's width) {wo}",
                  (c.kda_heads, c.kda_head_dim) != wo if latent
@@ -868,12 +993,13 @@ def _check_config(c: TransformerConfig) -> None:
     if c.n_dense_layers:
         unanchored = bool(c.layer_pattern) and c.first_layer is None
         if (c.n_experts == 0 or unanchored or c.d_ff_dense is None
-                or not 0 < c.n_dense_layers < c.n_layers):
+                or not 0 < c.n_dense_layers < c.n_layers
+                or not c.expert_gated):
             raise ValueError(
                 "n_dense_layers are the first of an expert model's n_layers "
                 "(n_experts > 0, no layer_pattern that first_layer does not "
-                "anchor, 0 < n_dense_layers < n_layers) and need their FFN "
-                "width d_ff_dense")
+                "anchor, 0 < n_dense_layers < n_layers, expert_gated) and "
+                "need their FFN width d_ff_dense")
     if c.experts_held is not None:      # raises where they do not divide
         moe.held_range(c.n_experts, *c.experts_held)
 
@@ -897,7 +1023,16 @@ def init_params(rng, config: TransformerConfig):
     dv] (its columns regrouped by what they make: each shards by head),
     ``w_a`` and ``w_beta`` its second; plain attention's fused q-and-gate
     projection the leaves ``wq`` and ``wg``. A zero-centred norm's weight
-    is made 0.
+    is made 0. A state-space layer's leaves (``ssm``): the published ONE
+    input projection as ``w_z`` [D, heads, channels] (the gate), ``w_xbc``
+    [D, heads x channels + 2 x groups x state] (``[x | B | C]``, one
+    convolution's operand) and ``w_dt`` [D, heads]; ``conv_w`` [taps, .]
+    and ``conv_b`` (``ssm_conv_bias``) U(-1 / sqrt(taps), 1 / sqrt(taps));
+    ``dt_bias`` and ``A_log`` as above, a head; ``D`` 1; ``o_norm`` [heads
+    x channels] 1.
+    In a model of single-sublayer blocks a layer holds its own sublayer's
+    leaves alone (``_holds``); experts with no gate projection have no
+    ``w_gate`` / ``shared_w_gate`` leaf.
     """
     c = config
     _check_config(c)
@@ -993,32 +1128,62 @@ def init_params(rng, config: TransformerConfig):
             "o_norm": jnp.ones((n, d), pdt),
         }
 
+    def ssm_stack(keys, n):
+        Hs, P, taps = c.kda_heads, c.kda_head_dim, c.kda_conv
+        wide = Hs * P + 2 * c.ssm_groups * c.ssm_state
+        edge = 1.0 / math.sqrt(taps)
+        step = jnp.maximum(jnp.exp(uniform(
+            next(keys), n, Hs, low=math.log(1e-3), high=math.log(1e-1))),
+            1e-4)
+        return {
+            "w_z": norm(next(keys), n, D, Hs, P),
+            "w_xbc": norm(next(keys), n, D, wide),
+            "w_dt": norm(next(keys), n, D, Hs),
+            "conv_w": uniform(next(keys), n, taps, wide, low=-edge,
+                              high=edge).astype(pdt),
+            **({"conv_b": uniform(next(keys), n, wide, low=-edge,
+                                  high=edge).astype(pdt)}
+               if c.ssm_conv_bias else {}),
+            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
+            "A_log": jnp.log(uniform(next(keys), n, Hs, low=1.0,
+                                     high=16.0)).astype(pdt),
+            "D": jnp.ones((n, Hs), pdt),
+            "o_norm": jnp.ones((n, Hs * P), pdt),
+        }
+
     def mixer_stacks(keys, first, n):
         """The token mixers' leaves of the ``n`` layers from ``first``."""
         if not c.layer_mixers:
             return {"attn": attn_stack(keys, n)}
         linear, own = _own_stacks(c)
-        n_linear = sum(m in LINEAR_MIXERS
-                       for m in c.layer_mixers[first:first + n])
+        here = c.layer_mixers[first:first + n]
+        n_linear = sum(m in LINEAR_MIXERS for m in here)
+        n_attn = sum(m == "attn" for m in here)
         stacks = {}
-        if n - n_linear:
-            stacks[own] = attn_stack(keys, n - n_linear)
+        if n_attn:
+            stacks[own] = attn_stack(keys, n_attn)
             del stacks[own]["wo"]
         if n_linear:
-            stacks[linear] = (kda_stack if linear == "kda" else gdn_stack)(
-                third, n_linear)
+            stacks[linear] = {"kda": kda_stack, "gdn": gdn_stack,
+                              "ssm": ssm_stack}[linear](third, n_linear)
         value = c.d_head_v if c.kv_latent is not None else Dh
-        stacks["attn"] = {"wo": norm(next(third), n, H, value, D, s=res_std)}
+        stacks["attn"] = {"wo": norm(next(third), n_attn + n_linear, H,
+                                     value, D, s=res_std)}
         return stacks
 
     def block_norms(n):
         names = ("ln1", "ln2") + (("ln1_post", "ln2_post") if c.post_norm
                                   else ())
+        if c.single_sublayer:       # a layer has ONE norm: its sublayer's
+            n_ffn = len(c.layers_with("ffn"))
+            return {"ln1": {"w": unit(n - n_ffn, D)},
+                    "ln2": {"w": unit(n_ffn, D)}}
         return {name: {"w": unit(n, D)} for name in names}
 
     def ffn_stack(keys, n, width):
         return {
-            "w_gate": norm(next(keys), n, D, width),
+            **({"w_gate": norm(next(keys), n, D, width)}
+               if c.expert_gated else {}),
             "w_up": norm(next(keys), n, D, width),
             "w_down": norm(next(keys), n, width, D, s=res_std),
         }
@@ -1047,10 +1212,13 @@ def init_params(rng, config: TransformerConfig):
         params["layers"].update(block_norms(L))
         if c.n_experts > 0:
             E = c.experts_here
+            if c.single_sublayer:       # the FFN layers' leaves alone
+                L = len(c.layers_with("ffn"))
             params["layers"]["router"] = {
                 "w": norm(next(keys), L, D, c.n_experts)}
             params["layers"]["mlp"] = {
-                "w_gate": norm(next(keys), L, E, D, F),
+                **({"w_gate": norm(next(keys), L, E, D, F)}
+                   if c.expert_gated else {}),
                 "w_up": norm(next(keys), L, E, D, F),
                 "w_down": norm(next(keys), L, E, F, D, s=res_std),
             }
@@ -1126,7 +1294,12 @@ def partition_specs(config: TransformerConfig):
         "w_a": P(None, None, AXIS_TENSOR),
         "w_beta": P(None, None, AXIS_TENSOR),
     }
-    mixers = {"attn": attn, "mla": attn, "mha": attn, "kda": kda, "gdn": gdn}
+    # a state-space layer: the gate's projection by head; what the ONE
+    # convolution reads side by side ([x | B | C]) and the rest replicated
+    # (the scan does not run under a mesh's tensor axis yet: ROADMAP B3)
+    ssm = {"w_z": by_head}
+    mixers = {"attn": attn, "mla": attn, "mha": attn, "kda": kda, "gdn": gdn,
+              "ssm": ssm}
     specs = {
         "embed": {"tokens": P(AXIS_TENSOR, None)},
         "layers": {**mixers, "ln1": None, "ln2": None},
@@ -1210,20 +1383,32 @@ def _period(kinds: tuple) -> int:
     return n
 
 
+def _holds(c: TransformerConfig, name: str, kind) -> bool:
+    """Whether a layer of kind ``kind`` (``layer_kind``) has leaves in the
+    subtree ``name`` of its stack. A mixer's own subtree
+    (``MIXER_STACKS``) is stacked over the layers of that mixer alone;
+    in a model of single-sublayer blocks ``ln1`` and ``attn`` (``wo``)
+    over the layers that are a mixer, everything else (``ln2``, the
+    router, ``mlp``) over those that are an FFN; any other subtree over
+    all the layers."""
+    if not c.layer_mixers:
+        return True
+    linear, attn = _own_stacks(c)
+    mixer = kind if isinstance(kind, str) else "attn"
+    if name in (linear, attn):
+        return (mixer in LINEAR_MIXERS) if name == linear else mixer == "attn"
+    if not c.single_sublayer:
+        return True
+    return (name in ("ln1", "attn")) == (mixer != FFN_ONLY)
+
+
 def _split_stack(c: TransformerConfig, stack, kinds: tuple, period: int):
-    """A stack's leaves as (whole periods [periods, layers of the leaf's
-    kind a period, ...], the layers left after them): a mixer's own
-    subtree (``MIXER_STACKS``) is stacked over the layers of that kind,
-    every other subtree over all."""
+    """A stack's leaves as (whole periods [periods, layers that hold the
+    leaf a period, ...], the layers left after them) (``_holds``)."""
     periods = len(kinds) // period
-    own = {}
-    if c.layer_mixers:
-        linear, attn = _own_stacks(c)
-        n_linear = sum(isinstance(k, str) for k in kinds[:period])
-        own = {linear: n_linear, attn: period - n_linear}
 
     def split(name, sub):
-        n = own.get(name, period)
+        n = sum(_holds(c, name, kind) for kind in kinds[:period])
         return (jax.tree.map(lambda a: a[:periods * n].reshape(
                     periods, n, *a.shape[1:]), sub),
                 jax.tree.map(lambda a: a[periods * n:], sub))
@@ -1235,18 +1420,14 @@ def _split_stack(c: TransformerConfig, stack, kinds: tuple, period: int):
 
 def _take_layer(c: TransformerConfig, stack, kinds: tuple, i: int):
     """Layer ``i``'s parameters out of a stack (or a slice of one) whose
-    layers have the kinds ``kinds``: what every layer has at ``i``, its
-    mixer's own leaves at its place among the layers of its kind."""
+    layers have the kinds ``kinds``: of every subtree the layer holds
+    (``_holds``), its place among the layers that hold it."""
     if not c.layer_mixers:
         return jax.tree.map(lambda a: a[i], stack)
-    stacks = _own_stacks(c)
-    linear = isinstance(kinds[i], str)      # a linear layer's kind is a name
-    own = stacks[not linear]
-    place = sum(isinstance(k, str) == linear for k in kinds[:i])
     return {name: jax.tree.map(
-                lambda a, at=(place if name == own else i): a[at], sub)
-            for name, sub in stack.items()
-            if name == own or name not in stacks}
+                lambda a, at=sum(_holds(c, name, kind)
+                                 for kind in kinds[:i]): a[at], sub)
+            for name, sub in stack.items() if _holds(c, name, kinds[i])}
 
 
 def forward(params, tokens, config: TransformerConfig, *, mesh=None,
@@ -1264,7 +1445,9 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     experts], the batch's assignments to every expert, and
     ``bias_swapped``, the mean over the layers; with linear layers also
     ``kda_log_decay_min``, the most negative cumulative log-decay inside
-    any chunk of any of them; with an ``attn_gate`` also
+    any chunk of any of them, and with state-space layers ``ssm_step_mean``,
+    the mean step ``Delta`` over their tokens, heads and layers; with an
+    ``attn_gate`` also
     ``attn_gate_mean``, the gate's mean over the attention layers; with a
     ``shared_expert_gate`` also ``moe_shared_gate_mean``, that gate's mean
     over the layers).
@@ -1299,7 +1482,8 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                 pos_emb = params["embed"]["pos"][positions]
             x = x + pos_emb.astype(dt)
             rope = None
-        elif c.kv_latent is not None and not c.latent_rope:
+        elif not c.attn_rope or (c.kv_latent is not None
+                                 and not c.latent_rope):
             rope = None                 # no layer rotates anything
         else:
             cos, sin = rope_frequencies(
@@ -1375,15 +1559,24 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                                       c.n_dense_layers, dense=True)
         x, auxs = run_stack(x, params["layers"], c.n_dense_layers,
                             c.n_scan_layers)
-        aux = {"balance": auxs["balance"].mean(), "z": auxs["z"].mean(),
+        # a model of single-sublayer blocks: a layer that is no FFN reads
+        # 0 in the router's statistics, and the means are the FFN layers'
+        ffn = jnp.asarray(c.layers_with("ffn")) if c.single_sublayer else None
+
+        def mean(a):
+            return a.mean() if ffn is None else a.reshape(-1)[ffn].mean()
+
+        aux = {"balance": mean(auxs["balance"]), "z": mean(auxs["z"]),
                "load_max": auxs["load_max"].max()}
         if c.experts_held is not None:
-            aux["held_share"] = auxs["held_share"].mean()
-            aux["full_buffer"] = auxs["full_buffer"].mean()
+            aux["held_share"] = mean(auxs["held_share"])
+            aux["full_buffer"] = mean(auxs["full_buffer"])
         if c.router_bias:
             # [layers, experts], whether the scan stacked layers or periods
             aux["expert_counts"] = auxs["counts"].reshape(-1, c.n_experts)
-            aux["bias_swapped"] = auxs["bias_swapped"].mean()
+            if ffn is not None:         # the layers that have a router
+                aux["expert_counts"] = aux["expert_counts"][ffn]
+            aux["bias_swapped"] = mean(auxs["bias_swapped"])
         if c.attn_gate:
             total = auxs["gate_mean"].sum()
             if c.n_dense_layers:
@@ -1399,6 +1592,9 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                 aux["kda_log_decay_min"] = jnp.minimum(
                     aux["kda_log_decay_min"],
                     dense_auxs["log_decay_min"].min())
+        if c.linear_mixer == "ssm":
+            aux["ssm_step_mean"] = auxs["step_mean"].sum() / len(
+                c.layers_with("ssm"))
 
     with jax.named_scope("final_norm"):
         if c.arch == "gpt2":
@@ -1425,11 +1621,13 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     = (windowed, rope) is the layer's place in the ``layer_pattern``
     (static; None with no pattern: full causal attention, the arch's own
     positions), and names the sub-scope its attention runs under.
-    ``kind`` = "kda" or "gdn": the token mixer is that linear one, under
-    ``attn_linear``. ``dense``: one of an expert model's leading dense layers. With
+    ``kind`` = "kda", "gdn" or "ssm": the token mixer is that linear one,
+    under ``attn_linear``. ``dense``: one of an expert model's leading dense layers. With
     ``post_norm`` a sublayer's output is normed under ``post_norm``,
-    inside the sublayer's own scope, and joins the stream there."""
-    dt = c.compute_dtype
+    inside the sublayer's own scope, and joins the stream there. In a
+    model of single-sublayer blocks (``single_sublayer``) the block is ONE
+    of its halves: ``attn_norm`` + ``attn`` for a layer named by a mixer,
+    ``mlp_norm`` + ``moe`` for one named "ffn"."""
     experts = c.n_experts > 0 and not dense
 
     def join(x, out, norm: str):
@@ -1440,6 +1638,43 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
             return x + rms_norm(out, _norm_weight(c, lp[norm]["w"]),
                                 eps=c.norm_eps)
 
+    router = decay_min = gate_mean = step_mean = None
+    if kind != FFN_ONLY:
+        x, router, decay_min, gate_mean, step_mean = _mixer_sublayer(
+            x, lp, c, rope=rope, con=con, positions=positions, kind=kind,
+            experts=experts, join=join)
+    zero = jnp.zeros((), jnp.float32)
+    aux = {name: zero for name in ("balance", "z", "load_max")}
+    if c.single_sublayer and kind != FFN_ONLY:
+        # a mixer layer of a stack of single-sublayer blocks: every layer
+        # of a stack reports alike, this one an expert layer's statistics
+        # at 0 (``forward`` takes its means over the FFN layers)
+        if c.experts_held is not None:
+            aux.update(held_share=zero, full_buffer=zero)
+        if c.router_bias:
+            aux.update(counts=jnp.zeros((c.n_experts,), jnp.float32),
+                       bias_swapped=zero)
+    else:
+        x, aux = _ffn_sublayer(x, lp, c, router=router, con=con,
+                               experts=experts, join=join, aux=aux)
+    if c.attn_gate:                     # every layer of a stack alike
+        aux = dict(aux, gate_mean=zero if gate_mean is None else gate_mean)
+    if c.linear_mixer:                  # likewise
+        aux = dict(aux, log_decay_min=zero if decay_min is None
+                   else decay_min)
+    if c.linear_mixer == "ssm":
+        aux = dict(aux, step_mean=zero if step_mean is None else step_mean)
+    return x, aux
+
+
+def _mixer_sublayer(x, lp, c: TransformerConfig, *, rope, con, positions,
+                    kind, experts: bool, join):
+    """A block's first half: ``attn_norm`` and the token mixer under
+    ``attn`` with its residual add -> (x, the router's logits where it
+    reads this norm, the linear mixer's log-decay minimum, the output
+    gate's mean, a state-space mixer's mean step; None where the layer has
+    none)."""
+    dt = c.compute_dtype
     window = None
     linear = kind in LINEAR_MIXERS
     if kind is not None and not linear:
@@ -1455,11 +1690,13 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     if experts and c.router_input == "attn_norm":
         with jax.named_scope("moe"):
             router = moe.router_matmul(h, lp["router"]["w"])
-    decay_min = gate_mean = None
+    decay_min = gate_mean = step_mean = None
     with jax.named_scope("attn"), (
             contextlib.nullcontext() if kind is None else jax.named_scope(
                 ATTN_SCOPES[2 if linear else window is not None])):
-        if linear:
+        if kind == "ssm":
+            o, decay_min, step_mean = _ssm_mixer(h, lp[kind], c)
+        elif linear:
             mixer = _kda_mixer if kind == "kda" else _gdn_mixer
             o, decay_min = mixer(h, lp[kind], c)
         else:
@@ -1488,9 +1725,15 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                 x = x + o
         if c.post_norm:
             x = join(x, o, "ln1_post")
+    return x, router, decay_min, gate_mean, step_mean
 
-    aux = {name: jnp.zeros((), jnp.float32)
-           for name in ("balance", "z", "load_max")}
+
+def _ffn_sublayer(x, lp, c: TransformerConfig, *, router, con,
+                  experts: bool, join, aux):
+    """A block's second half: ``mlp_norm`` and the FFN under ``mlp`` /
+    ``moe`` with its residual add -> (x, the router's statistics:
+    ``aux`` as it came for a dense FFN)."""
+    dt = c.compute_dtype
     with jax.named_scope("mlp_norm"):
         if c.arch == "gpt2":
             h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"], eps=c.norm_eps)
@@ -1513,12 +1756,6 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
                        lp["mlp"]["w_up"].astype(dt),
                        lp["mlp"]["w_down"].astype(dt))
             x = join(x, m, "ln2_post")
-    zero = jnp.zeros((), jnp.float32)
-    if c.attn_gate:                     # every layer of a stack alike
-        aux = dict(aux, gate_mean=zero if gate_mean is None else gate_mean)
-    if c.linear_mixer:                  # likewise
-        aux = dict(aux, log_decay_min=zero if decay_min is None
-                   else decay_min)
     return x, aux
 
 
@@ -1534,8 +1771,9 @@ def _expert_ffn(h, lp, c: TransformerConfig, router, con):
     router's statistics), AHEAD of any output norm and of the residual
     add. ``router``: the logits, where they were made ahead of attention."""
     dt = c.compute_dtype
-    weights = (lp["router"]["w"], lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-               lp["mlp"]["w_down"])
+    # experts with no gate projection have no such leaf (``expert_gated``)
+    weights = (lp["router"]["w"], lp["mlp"].get("w_gate"),
+               lp["mlp"]["w_up"], lp["mlp"]["w_down"])
     if c.expert_capacity_factor is not None:
         return moe.moe_swiglu(
             h, *weights, top_k=c.expert_top_k,
@@ -1550,6 +1788,11 @@ def _expert_ffn(h, lp, c: TransformerConfig, router, con):
         activation=c.expert_activation, score=c.router_score,
         select_bias=lp["router"].get("b"), gate_scale=c.expert_gate_scale)
     if c.d_ff_shared:
+        if not c.expert_gated:
+            m = m + moe.shared_expert(
+                h, None, lp["mlp"]["shared_w_up"].astype(dt),
+                lp["mlp"]["shared_w_down"].astype(dt), c.expert_activation)
+            return m, aux
         shared = [lp["mlp"][f"shared_{name}"].astype(dt)
                   for name in ("w_gate", "w_up", "w_down")]
         if c.shared_expert_gate:
@@ -1622,6 +1865,43 @@ def _gdn_mixer(h, w, c: TransformerConfig):
     return (linear_attention.silu_gated_head_norm(o, z, w["o_norm"],
                                                   eps=c.norm_eps),
             linear_attention.log_decay_min(g))
+
+
+def _ssm_mixer(h, w, c: TransformerConfig):
+    """A state-space (Mamba-2) layer's mixer up to (not with) the output
+    projection, from the normed input ``h`` [B, T, D] and the layer's own
+    leaves ``w`` -> (o FLAT [B, T, heads x channels], the most negative
+    cumulative log-decay inside any chunk, the mean step ``Delta``). Its
+    parts under the names the linear mixers' readers read: ``attn_qkv``
+    the published ONE input projection as three plain matmuls (the gate's
+    ``z``, ``[x | B | C]`` side by side as the ONE convolution reads them,
+    the step's ``dt`` summed in float32), ``kda_conv`` the chain on ``[x |
+    B | C]``, ``kda_gate`` the step, the decay, the gated group norm and
+    the counters, ``attn_core`` the ONE scan call and nothing else
+    (``ops/state_space.py``)."""
+    dt = c.compute_dtype
+    heads, width, groups = c.kda_heads, c.kda_head_dim, c.ssm_groups
+    with jax.named_scope("attn_qkv"):
+        z, xbc = (jnp.einsum("btd,dc->btc", h,
+                             w[name].reshape(c.d_model, -1).astype(dt))
+                  for name in ("w_z", "w_xbc"))
+        raw = jnp.einsum("btd,dh->bth", h, w["w_dt"].astype(dt),
+                         preferred_element_type=jnp.float32)
+    xbc = linear_attention.flat_conv_silu(
+        xbc, w["conv_w"], w["conv_b"] if c.ssm_conv_bias else None)
+    step, decay = state_space.step_and_decay(raw, w)
+    with jax.named_scope("attn_core"):
+        inner, state = heads * width, groups * c.ssm_state
+        by = lambda a, n: a.reshape(*a.shape[:2], n, -1)
+        o = state_space.ssm_scan(
+            by(xbc[..., :inner], heads), step, decay,
+            by(xbc[..., inner:inner + state], groups),
+            by(xbc[..., inner + state:], groups), w["D"], chunk=c.ssm_chunk)
+    o = state_space.gated_group_norm(o, z, w["o_norm"], groups,
+                                     eps=c.norm_eps)
+    with jax.named_scope("kda_gate"):
+        step_mean = jax.lax.stop_gradient(step).mean()
+    return o, linear_attention.log_decay_min(decay, c.ssm_chunk), step_mean
 
 
 def _gate_output(o, h, wg):
@@ -2013,7 +2293,7 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
             # the router's bias reads it and takes it out of the metrics
             metrics["moe_expert_counts"] = aux["expert_counts"]
             metrics["moe_bias_swapped"] = aux["bias_swapped"]
-    for name in ("kda_log_decay_min", "attn_gate_mean",
+    for name in ("kda_log_decay_min", "ssm_step_mean", "attn_gate_mean",
                  "moe_shared_gate_mean"):
         if name in aux:
             metrics = dict(metrics, **{name: aux[name]})
@@ -2168,6 +2448,17 @@ def init_train_state(rng, config: TransformerConfig, optimizer):
 def refuse_decode(c: TransformerConfig) -> None:
     """The KV-cache decode runs one kind of dense layer: refuse, by name,
     a model it would run wrongly in silence."""
+    if c.single_sublayer or "ssm" in c.layer_mixers:
+        raise NotImplementedError(
+            f"KV-cache decode does not run a stack of single-sublayer blocks "
+            f"or a state-space layer (layer_mixers {c.layer_mixers!r}; "
+            f"kda_heads {c.kda_heads}, kda_head_dim {c.kda_head_dim}, "
+            f"ssm_state {c.ssm_state}, ssm_groups {c.ssm_groups}, kda_conv "
+            f"{c.kda_conv}): a state-space ('ssm') layer keeps a recurrent "
+            f"state [heads, channels, ssm_state] and its convolution's last "
+            f"positions, not keys and values, and a layer named 'ffn' has "
+            f"no mixer and so no cache at all, where every layer would be "
+            f"decoded as attention AND an FFN")
     if c.layer_mixers:
         raise NotImplementedError(
             f"KV-cache decode does not run a model with layer_mixers "

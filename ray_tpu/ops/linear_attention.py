@@ -220,11 +220,25 @@ def conv_silu(q, k, v, w_q, w_k, w_v):
                 chain(v, w_v, False))
 
 
-def _chain(x, w, norm: bool):
+def flat_conv_silu(x, w, bias=None):
+    """A state-space layer's ONE chain on its flat ``[x | B | C]``
+    projection ``x`` [B, T, C]: ``silu(short_conv(x) + bias)``, taps ``w``
+    [K, C], ``bias`` [C] or None, no l2 norm (scope ``kda_conv``). The
+    plain chain everywhere: the kernels take no bias yet."""
+    with jax.named_scope("kda_conv"):
+        return _chain(x, w, False, bias)
+
+
+def _chain(x, w, norm: bool, bias=None):
     """One chain in plain XLA: ``x`` [B, T, H * d], ``w`` [K, H, d] ->
     ``silu(short_conv(x))``, l2-normed over each head's ``d`` lanes with
-    ``norm``; float32 throughout, rounded once to ``x``'s dtype."""
-    y = jax.nn.silu(_conv(x, w.reshape(w.shape[0], -1)))
+    ``norm``; float32 throughout, rounded once to ``x``'s dtype. With a
+    ``bias`` [H * d], ``silu(short_conv(x) + bias)`` (``flat_conv_silu``;
+    ``w`` may be [K, C])."""
+    y = _conv(x, w.reshape(w.shape[0], -1))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    y = jax.nn.silu(y)
     if norm:
         y = l2_norm(y.reshape(*x.shape[:2], *w.shape[1:])).reshape(x.shape)
     return y.astype(x.dtype)
@@ -366,16 +380,17 @@ def _normed_heads(o, weight, eps: float):
     return of * scale * jnp.tile(weight.astype(jnp.float32), heads)
 
 
-def log_decay_min(g):
-    """The most negative cumulative log-decay inside any chunk: how near
-    the chunked form runs to float32's range (no gradient). ``g`` [B, T, H
-    * dk] or [B, T, H, dk]: the chunks' sums are over positions alone."""
+def log_decay_min(g, chunk: int = CHUNK):
+    """The most negative cumulative log-decay inside any chunk of
+    ``chunk`` positions: how near the chunked form runs to float32's
+    range (no gradient). ``g`` [B, T, H * dk], [B, T, H, dk] or [B, T, H]:
+    the chunks' sums are over positions alone."""
     with jax.named_scope("kda_gate"):
         g = jax.lax.stop_gradient(g)
-        pad = -g.shape[1] % CHUNK
+        pad = -g.shape[1] % chunk
         if pad:
             g = jnp.pad(g, ((0, 0), (0, pad)) + ((0, 0),) * (g.ndim - 2))
-        return g.reshape(g.shape[0], -1, CHUNK, *g.shape[2:]).sum(2).min()
+        return g.reshape(g.shape[0], -1, chunk, *g.shape[2:]).sum(2).min()
 
 
 # -- what a chunk needs of its own tokens -------------------------------------

@@ -36,7 +36,12 @@ two dispatches, chosen by the model's ``expert_capacity_factor``:
   statistics says whether a layer needed more than the one round.
   The caller may make the router's logits itself (``router_logits``;
   ``router_matmul``), for a model whose router does not read the
-  experts' input, and the activation is SwiGLU's or ReGLU's. A model
+  experts' input, and the activation is SwiGLU's or ReGLU's; experts
+  WITHOUT a gate projection (``w_gate`` None: ``W_down act(W_up u)``,
+  Nemotron-H's under "relu2") run two grouped matmuls where the gated
+  ones run three, on either path (``_gated``), and an inner width that
+  is no whole number of 128-lane tiles is padded with zeros for the
+  TPU's kernel (``_lane_whole``), on either path too. A model
   with a shared expert adds ``shared_expert``, which every token passes
   through whole, to the block's output beside this. What is
   NOT here is the exchange: there is no all-to-all yet (ROADMAP B2),
@@ -477,7 +482,8 @@ def _down_and_combine_bwd(res, d_out):
 _down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def router_matmul(x, router_w):
@@ -490,13 +496,16 @@ def router_matmul(x, router_w):
                           router_w.astype(jnp.float32))
 
 
-def shared_expert(x, w_gate, w_up, w_down):
+def shared_expert(x, w_gate, w_up, w_down, activation: str = "silu"):
     """The gated FFN every token passes through beside its routed experts
     (DeepSeek's shared experts, fused into one SwiGLU), ungated, under
     the scope ``moe_shared``. x [..., D]; w_gate / w_up [D, F]; w_down
-    [F, D]."""
+    [F, D]. ``w_gate`` None: an expert WITHOUT a gate projection,
+    ``activation(x W_up) W_down`` (Nemotron-H's, under "relu2")."""
     with jax.named_scope("moe_shared"):
-        return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
+        if w_gate is None:
+            return ACTIVATIONS[activation](x @ w_up) @ w_down
+        return (ACTIVATIONS[activation](x @ w_gate) * (x @ w_up)) @ w_down
 
 
 def gated_shared_expert(x, w_gate, w_up, w_down, w_share):
@@ -541,9 +550,31 @@ def _buffer_rows(assignments: int, held: int, of: int) -> int:
     return min(assignments, -(-rows // _GMM_ROWS) * _GMM_ROWS)
 
 
+def _lane_whole(w_gate, w_up, w_down):
+    """The experts' matrices with their inner width ``F`` padded by zero
+    columns (rows of ``w_down``) to whole 128-lane tiles, where a TPU's
+    grouped-matmul kernel would otherwise step aside for an ``F`` that is
+    none (Nemotron-H's 1,856 = 14.5 tiles; ``_use_megablox``). The padded
+    columns make ``activation(0)`` x 0 = 0 against zero rows of
+    ``w_down``: nothing of the result moves, the leaves and their
+    gradients keep the published width (a pad's transpose is a slice).
+    Anywhere else, and at a width in whole tiles, the matrices as they
+    are."""
+    pad = -w_up.shape[-1] % 128
+    if not pad or jax.devices()[0].platform != "tpu":
+        return w_gate, w_up, w_down
+    wider = ((0, 0), (0, 0), (0, pad))
+    return (None if w_gate is None else jnp.pad(w_gate, wider),
+            jnp.pad(w_up, wider), jnp.pad(w_down, ((0, 0), (0, pad), (0, 0))))
+
+
 def _gated(rows, w_gate, w_up, counts, activation: str):
-    """``activation(gate) * up`` of the rows, each by its own expert."""
+    """``activation(gate) * up`` of the rows, each by its own expert;
+    ``activation(up)`` for experts without a gate (``w_gate`` None: two
+    grouped matmuls an expert layer, not three)."""
     with jax.named_scope("moe_experts"):
+        if w_gate is None:
+            return ACTIVATIONS[activation](grouped_matmul(rows, w_up, counts))
         return ACTIVATIONS[activation](grouped_matmul(
             rows, w_gate, counts)) * grouped_matmul(rows, w_up, counts)
 
@@ -654,10 +685,12 @@ def _held_block_bwd(buffer, activation, inputs, d_out):
             d_gate = jax.lax.dynamic_update_slice(d_gate, d_gate_here,
                                                   (i * buffer,))
         with jax.named_scope("moe_experts"):
-            return (dx, dw_gate + dw_g, dw_up + dw_u, dw_down + dw, d_gate)
+            return (dx, None if w_gate is None else dw_gate + dw_g,
+                    dw_up + dw_u, dw_down + dw, d_gate)
 
     with jax.named_scope("moe_experts"):
-        grads = (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(w_gate),
+        grads = (jnp.zeros(x.shape, jnp.float32),
+                 None if w_gate is None else jnp.zeros_like(w_gate),
                  jnp.zeros_like(w_up), jnp.zeros_like(w_down),
                  jnp.zeros((a_rows + pad,), jnp.float32))
     dx, dw_gate, dw_up, dw_down, d_gate = jax.lax.fori_loop(
@@ -682,7 +715,9 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     to an expert that is here is computed.
 
     x [B, S, D]; router_w [D, E]; w_gate/w_up [Eh, D, F]; w_down
-    [Eh, F, D]. ``router_logits`` [B, S, E], where the caller made them
+    [Eh, F, D]; ``w_gate`` None for experts with no gate projection
+    (``activation(up)``: "relu2" is ``relu(.)^2``). ``router_logits`` [B,
+    S, E], where the caller made them
     (``router_w`` is then not read). Returns (out [B, S, D], {"balance",
     "z", "load_max"} scalars); the losses are over all the tokens of
     ``x`` and all E experts (see the module docstring).
@@ -749,17 +784,16 @@ def moe_swiglu_dropless(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         iota = jnp.arange(A, dtype=jnp.int32)
         _, order = jax.lax.sort((keys, iota), num_keys=1)
         inverse = jnp.zeros((A,), jnp.int32).at[order].set(iota)
+    with jax.named_scope("moe_experts"):
+        w_gate, w_up, w_down = _lane_whole(
+            None if w_gate is None else w_gate.astype(dt),
+            w_up.astype(dt), w_down.astype(dt))
     if held is not None and buffer < A:
-        with jax.named_scope("moe_experts"):
-            weights = w_gate.astype(dt), w_up.astype(dt), w_down.astype(dt)
-        out = _held_block(xf, *weights, gates, held_counts, order, inverse,
-                          buffer, activation)
+        out = _held_block(xf, w_gate, w_up, w_down, gates, held_counts,
+                          order, inverse, buffer, activation)
         return out.reshape(B, S, D), stats
     with jax.named_scope("moe_dispatch"):
         rows = _rows_to_experts(xf, order, inverse, top_k)
-    with jax.named_scope("moe_experts"):
-        gate = grouped_matmul(rows, w_gate.astype(dt), counts)
-        up = grouped_matmul(rows, w_up.astype(dt), counts)
-        h, w_down = ACTIVATIONS[activation](gate) * up, w_down.astype(dt)
+    h = _gated(rows, w_gate, w_up, counts, activation)
     out = _down_and_combine(h, w_down, gates, counts, order, inverse)
     return out.reshape(B, S, D), stats
