@@ -154,8 +154,8 @@ SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
 # state-space) layer's mixer: inside it ``attn_qkv`` (the q / k / v
 # projections; Gated DeltaNet's z with them; a state-space layer's input
 # projection), ``ops.linear_attention.SCOPES`` (``kda_conv``, ``kda_gate``),
-# ``attn_core`` (the chunked delta rule, or the state-space scan with its
-# ``ssm_carry``, and nothing else) and ``attn_out``.
+# ``attn_core`` (the delta rule or the state-space scan, nothing else: on one
+# TPU chip two Pallas kernels, elsewhere XLA with ``ssm_carry``), ``attn_out``.
 ATTN_SCOPES = ("attn_full", "attn_window", "attn_linear")
 # The token mixers ``layer_mixers`` may name: "kda", "gdn" and "ssm" are
 # the LINEAR ones (a state carried along the sequence); "attn" is the

@@ -22,18 +22,32 @@ chunks at once, with ``G_t`` the cumulative log-decay inside a chunk:
 **The decay enters only as differences of cumulative log-decays with the
 later position first** (``G_t - G_s`` for s <= t, ``G_last - G_s``), masked
 BEFORE the exponential: no ``exp`` of a positive sum, whatever the step.
-The chunks' own sums (``_chunk_sums``) and outputs (``_chunk_outputs``) are
-batched matmuls over every chunk; between them the CARRY walks the chunks
-in order, ``T / chunk`` steps of an elementwise update of the [H, P, N]
-state (``_carry``, scope ``ssm_carry``): what is bound by latency and not
-by the MXU.
 
-**The backward is the op's own** (``jax.custom_vjp``): the forward keeps
-the operands and the state at every chunk's START, nothing of a chunk's
-[chunk, chunk] matrices. The backward makes the two batched stages again
-under ``jax.vjp`` (each is plain arithmetic with no loop) and turns the
-carry round by hand (``_carry_back``: it is linear in the state), so what
-autodiff ever holds of the intra-chunk arithmetic is that of one stage.
+**Two forms of the same arithmetic, and ONE rule between them**
+(``_takes_kernels``, from what a call shows: platform, mesh, shapes):
+
+- **On one TPU chip, at the widths the kernels were measured at** (chunk
+  and state 128, a group's heads a whole number of 128-lane tiles): two
+  Pallas kernels, ``_ssm_fwd_kernel`` and ``_ssm_bwd_kernel``, over a
+  grid (batch, groups, chunks) whose chunk axis is sequential. A chunk's
+  [chunk, chunk] decay matrices, its scores ``C B^T`` (once a group) and
+  ``M`` never leave VMEM, and the [state, heads x channels] state rides
+  the chunk walk in a VMEM scratch: there is no separate carry, and no
+  instruction under ``ssm_carry``. The backward walks the same grid from
+  the last chunk with the state's cotangent in the scratch.
+- **Everywhere else** (the CPU, a mesh over the operand, other widths),
+  and as the tests' second opinion: plain XLA. The chunks' own sums
+  (``_chunk_sums``) and outputs (``_chunk_outputs``) are batched matmuls
+  over every chunk; between them the CARRY walks the chunks in order,
+  ``T / chunk`` steps of an elementwise update of the [H, P, N] state
+  (``_carry``, scope ``ssm_carry``). Its backward makes the two batched
+  stages again under ``jax.vjp`` (each is plain arithmetic with no loop)
+  and turns the carry round by hand (``_carry_back``: it is linear in the
+  state).
+
+**The backward is the op's own in both** (``jax.custom_vjp``): the forward
+keeps the operands and the state at every chunk's START (the same bytes in
+both forms), nothing of a chunk's [chunk, chunk] matrices.
 
 The state and every sum are float32; the products take their operands in
 ``x``'s dtype (bfloat16 in a train step), as the attention kernels do.
@@ -47,13 +61,19 @@ its flat ``[x | B | C]`` projection is ``linear_attention``'s chain with a
 bias and no l2 norm (``linear_attention.flat_conv_silu``, ``kda_conv``).
 
 This file is one of ``models.transformer.SCOPE_FILES``: it opens
-``ssm_carry`` and ``kda_gate``.
+``ssm_carry`` (the plain form alone) and ``kda_gate``.
 """
 
 from __future__ import annotations
 
+import functools
+import types
+
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops import linear_attention as la
+from ray_tpu.ops.linear_attention import _NT, _TN, _iota, _mm
 
 SCOPES = ("ssm_carry",)
 _F32 = jnp.float32
@@ -187,6 +207,296 @@ def _scan_bwd(kept, d_y):
 _scan.defvjp(_scan_fwd, _scan_bwd)
 
 
+# -- the same chunk walk, as Pallas kernels -----------------------------------------
+#
+# One grid step is one chunk of ONE GROUP's heads, grid (batch, groups,
+# chunks), the chunks sequential (``ops/linear_attention.py``'s kernels are
+# the pattern). The operands arrive FLAT, ``x`` [B, T, H * P], ``b`` and
+# ``c`` [B, T, G * S], ``dt`` and ``a`` [B, T, H] as they are: a group is a
+# run of whole 128-lane tiles of ``x`` and one tile of ``b`` and ``c``, and
+# a step reads every head's ``dt`` and ``a`` (H lanes) and picks its own.
+# A chunk's [Q, Q] decay matrices, its scores and ``mixed`` live in VMEM
+# and nowhere else; the state, float32 and TRANSPOSED ([S, heads x
+# channels]: a head's decay scales its lanes, and every product with it is
+# one the MXU takes as it stands), is a scratch that the chunk axis carries.
+#
+# **Numbers a head and token reach the lanes by 0/1 matrices on the MXU**
+# (``_picked``): the cumulative log-decay [Q, H] is a triangle of ones
+# against ``a``, and a head's column is SPREAD over its P lanes ([Q, L])
+# and laid as a ROW ([heads, Q]) by one-hot products, the float32 split
+# into three bfloat16 parts that sum to it, so the row and the column of
+# ``G_t - G_s`` are the SAME float32 and the diagonal's exponent is 0
+# exactly. Two heads of 64 channels share a 128-lane tile: ``M X`` runs a
+# tile at a time, each head's ``M`` against the whole tile and a select
+# after (the MXU is 128 columns wide either way), and nothing is sliced
+# inside a tile.
+
+_LANES = 128    # the chunk, the state and a tile of lanes: what was measured
+
+
+def _thirds(x):
+    """float32 -> three bfloat16 parts that sum to it."""
+    high = x.astype(jnp.bfloat16)
+    return (high, *la._split(x - high.astype(_F32)))
+
+
+def _picked(x, ones, dims=((1,), (0,)), ones_first: bool = False):
+    """``x`` (float32) against the 0/1 matrix ``ones`` (``ones_first``:
+    ``ones`` against ``x``), every product exact whatever precision the
+    MXU gives float32 operands; a one-hot ``ones`` moves ``x``'s numbers
+    as they are."""
+    return sum(_mm(ones, part, dims) if ones_first else _mm(part, ones, dims)
+               for part in _thirds(x))
+
+
+def _ones(mask):
+    """The 0/1 matrix of ``mask``, bfloat16."""
+    return jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _kernel_chunk(x_ref, dt_ref, a_ref, b_ref, c_ref, p: int):
+    """A step's operands and what both kernels make of them first, [Q, L]
+    arrays over the group's L = heads x ``p`` lanes unless said: ``x``,
+    ``b``, ``c`` as read, ``xf`` float32; ``g`` the cumulative log-decay,
+    ``g_rows`` the same a head a ROW ([>= heads, Q]); ``step`` (Delta);
+    ``grown`` exp(G), ``tail`` exp(G_last - G), ``gamma`` exp(G_last) [1,
+    L]; ``weight`` Delta x tail; ``stepped`` and ``written`` (x Delta, x
+    weight, rounded as ``_chunk_outputs`` and ``_chunk_sums`` round them);
+    ``scores`` C B^T [Q, Q] float32, once a group; ``decay(h)`` head h's
+    exp(G_t - G_s), masked AHEAD of the exponential."""
+    import jax.experimental.pallas as pl
+
+    k = types.SimpleNamespace(x=x_ref[0], b=b_ref[0], c=c_ref[0])
+    k.dt, k.xf = k.x.dtype, k.x.astype(_F32)
+    (q, lanes), heads = k.x.shape, dt_ref.shape[-1]
+    r = lanes // p
+    first = pl.program_id(1) * r                    # the group's first head
+    seen = _iota((q, q), 0) >= _iota((q, q), 1)
+    k.lower = _ones(seen)
+    rows = -(-r // 16) * 16
+    spread = _ones(_iota((heads, lanes), 0)
+                   == first + _iota((heads, lanes), 1) // p)
+    row, head = _iota((rows, heads), 0), _iota((rows, heads), 1)
+    total = _picked(a_ref[0], k.lower, ones_first=True)         # [Q, H]
+    k.g = _picked(total, spread)
+    k.g_rows = _picked(total, _ones((head == first + row) & (row < r)), _NT,
+                       ones_first=True)
+    k.step = _picked(dt_ref[0], spread)
+    last = k.g[q - 1:q]
+    k.grown, k.tail, k.gamma = jnp.exp(k.g), jnp.exp(last - k.g), jnp.exp(last)
+    k.weight = k.step * k.tail
+    k.stepped = (k.xf * k.step).astype(k.dt)
+    k.written = (k.xf * k.weight).astype(k.dt)
+    k.scores = _mm(k.c, k.b, _NT)
+    k.decay = lambda h: jnp.exp(jnp.where(
+        seen, k.g[:, h * p:h * p + 1] - k.g_rows[h:h + 1], -jnp.inf))
+    return k
+
+
+def _tiles(lanes: int, p: int):
+    """(a tile's lanes, [(its i-th head, that head's lanes of the tile)])
+    for every 128-lane tile of a group's ``lanes``."""
+    per = _LANES // p
+    of = _iota((_LANES, _LANES), 1) // p
+    return [(slice(t * _LANES, (t + 1) * _LANES),
+             [(t * per + i, of == i) for i in range(per)])
+            for t in range(lanes // _LANES)]
+
+
+def _by_head(parts):
+    """One [Q, 128] tile from ``parts``, [(its lanes' mask, the array whose
+    lanes under the mask are the head's)]."""
+    out = parts[0][1]
+    for mask, one in parts[1:]:
+        out = jnp.where(mask, one, out)
+    return out
+
+
+def _ssm_fwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, *rest, p: int):
+    import jax.experimental.pallas as pl
+
+    *starts_ref, state = rest       # the chunk-start states only if kept
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        state[...] = jnp.zeros_like(state)
+
+    k = _kernel_chunk(x_ref, dt_ref, a_ref, b_ref, c_ref, p)
+    s = state[...]
+    if starts_ref:
+        starts_ref[0][0, 0] = s
+    before = _mm(k.c, s.astype(k.dt))                           # C S
+    for cols, heads in _tiles(k.x.shape[1], p):
+        inside = _by_head([
+            (mask, _mm((k.scores * k.decay(h)).astype(k.dt),
+                       k.stepped[:, cols])) for h, mask in heads])
+        y_ref[0, :, cols] = (inside + k.grown[:, cols] * before[:, cols]
+                             ).astype(y_ref.dtype)
+    state[...] = k.gamma * s + _mm(k.b, k.written, _TN)
+
+
+def _ssm_bwd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, starts_ref, dy_ref,
+                    dx_ref, ddt_ref, da_ref, db_ref, dc_ref, d_state, *,
+                    p: int):
+    """The forward's step turned round, ``d_state`` the cotangent of the
+    state a chunk hands on. A cotangent enters a product rounded to the
+    operands' dtype, as a TPU's default precision rounds it in the plain
+    form's gradient. ``d dt`` and ``d a`` leave as ROWS, [heads, Q]: [.., Q,
+    heads] would be 16 times its size in HBM's tiles."""
+    import jax.experimental.pallas as pl
+
+    @pl.when(pl.program_id(2) == 0)
+    def _zero():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    k = _kernel_chunk(x_ref, dt_ref, a_ref, b_ref, c_ref, p)
+    q, lanes = k.x.shape
+    r = lanes // p
+    rows = k.g_rows.shape[0]
+    s, d_after, dy = starts_ref[0, 0], d_state[...], dy_ref[0]
+    sd, dsd = s.astype(k.dt), d_after.astype(k.dt)
+    before = _mm(k.c, sd)
+    lit = k.grown * dy.astype(_F32)                 # d (C S)
+    litd = lit.astype(k.dt)
+    d_written = _mm(k.b, dsd)
+    # what the cumulative log-decay receives over the lanes: through
+    # ``grown``, and the chunk's whole decay (through ``tail`` and
+    # ``gamma``) on the last position's
+    moved = d_written * k.xf
+    kept = moved * k.weight
+    d_last = (jnp.sum(kept, 0, keepdims=True)
+              + k.gamma * jnp.sum(d_after * s, 0, keepdims=True))
+    d_g = lit * before - kept + jnp.where(
+        _iota((q, lanes), 0) == q - 1, d_last, 0.0)
+    # the chunk's own matrices, a head at a time: d M = d_y X^T, d X = M^T
+    # d_y; the decay's cotangent along a token's row less down its column
+    d_scores = jnp.zeros((q, q), _F32)
+    d_g_rows = jnp.zeros((rows, q), _F32)
+    d_g_cols = jnp.zeros((q, _LANES), _F32)
+    at_row, at_col = _iota(d_g_rows.shape, 0), _iota(d_g_cols.shape, 1)
+    d_stepped, d_step = [], []
+    for cols, heads in _tiles(lanes, p):
+        dy_t, parts = dy[:, cols], []
+        for h, mask in heads:
+            decay = k.decay(h)
+            mixed = k.scores * decay
+            d_mixed = _mm(jnp.where(mask, dy_t, jnp.zeros_like(dy_t)),
+                          k.stepped[:, cols], _NT)
+            parts.append((mask, _mm(mixed.astype(k.dt), dy_t, _TN)))
+            d_scores = d_scores + d_mixed * decay
+            through = d_mixed * mixed
+            d_g_cols = jnp.where(
+                at_col == h, jnp.sum(through, 1, keepdims=True), d_g_cols)
+            d_g_rows = jnp.where(
+                at_row == h, -jnp.sum(through, 0, keepdims=True), d_g_rows)
+        d_stepped.append(_by_head(parts))
+        d_step.append(d_stepped[-1] * k.xf[:, cols]
+                      + moved[:, cols] * k.tail[:, cols])
+    d_stepped = jnp.concatenate(d_stepped, 1)
+    d_scores = d_scores.astype(k.dt)
+    dc_ref[0] = (_mm(d_scores, k.b) + _mm(litd, sd, _NT)).astype(dc_ref.dtype)
+    db_ref[0] = (_mm(d_scores, k.c, _TN) + _mm(k.written, dsd, _NT)
+                 ).astype(db_ref.dtype)
+    d_state[...] = k.gamma * d_after + _mm(k.c, litd, _TN)
+    dx_ref[0] = (d_stepped * k.step + d_written * k.weight
+                 ).astype(dx_ref.dtype)
+    # summed over a head's lanes and laid as rows; ``a``'s is the running
+    # sum UP the chunk of the cumulative log-decay's
+    gather = _ones(_iota((rows, lanes), 0) == _iota((rows, lanes), 1) // p)
+    d_g_rows = (d_g_rows + d_g_cols.T[:rows]
+                + _picked(d_g, gather, _NT, ones_first=True))
+    da_ref[0, 0, 0] = _picked(d_g_rows, k.lower)[:r]
+    ddt_ref[0, 0, 0] = _picked(jnp.concatenate(d_step, 1), gather, _NT,
+                               ones_first=True)[:r]
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _launch(backward: bool, keep: bool, interpret: bool, *operands):
+    """One ``pallas_call`` over (batch, groups, chunks), the chunks
+    sequential and, ``backward``, walked from the last; ``keep`` adds the
+    chunk-start states [B, T / Q, S, H * P] float32 to the forward's
+    output. Behind a ``jax.jit`` of its own so that a kernel's body is
+    traced once a process (``linear_attention._launch``)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from ray_tpu.ops.attention import _FLASH_VMEM_MOST
+
+    x, dt, _, b = operands[:4]
+    (bsz, t, flat), h, q, s = x.shape, dt.shape[-1], _LANES, _LANES
+    g, n = b.shape[-1] // s, t // q
+    lanes, r = flat // g, h // g
+    at = (lambda j: n - 1 - j) if backward else (lambda j: j)
+    by_group = lambda width: pl.BlockSpec(
+        (1, q, width), lambda i, gi, j: (i, at(j), gi))
+    by_token = pl.BlockSpec((1, q, h), lambda i, gi, j: (i, at(j), 0))
+    states = pl.BlockSpec((1, 1, s, lanes), lambda i, gi, j: (i, at(j), 0, gi))
+    rows = pl.BlockSpec((1, 1, 1, r, q), lambda i, gi, j: (i, gi, at(j), 0, 0))
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    in_specs = [by_group(lanes), by_token, by_token, by_group(s), by_group(s)]
+    if backward:
+        in_specs += [states, by_group(lanes)]
+        out_specs = [by_group(lanes), rows, rows, by_group(s), by_group(s)]
+        as_rows = jax.ShapeDtypeStruct((bsz, g, n, r, q), _F32)
+        out_shape = [like(x), as_rows, as_rows, like(b), like(b)]
+    else:
+        out_specs = [by_group(lanes)] + [states] * keep
+        out_shape = [like(x)] + [
+            jax.ShapeDtypeStruct((bsz, n, s, flat), _F32)] * keep
+    return pl.pallas_call(
+        functools.partial(_ssm_bwd_kernel if backward else _ssm_fwd_kernel,
+                          p=flat // h),
+        grid=(bsz, g, n), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((s, lanes), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_FLASH_VMEM_MOST),
+        interpret=interpret,
+    )(*operands)
+
+
+def _kernel_call(backward: bool, keep: bool, *operands):
+    from ray_tpu.ops.attention import _interpret
+
+    return _launch(backward, keep, _interpret(), *operands)
+
+
+@jax.custom_vjp
+def _kernel_scan(x, dt, a, b, c):
+    """``_scan`` through the kernels: ``x`` [B, T, H * P], ``dt`` and ``a``
+    [B, T, H] float32, ``b`` and ``c`` [B, T, G * S], T whole chunks -> y
+    [B, T, H * P]."""
+    return _kernel_call(False, False, x, dt, a, b, c)[0]
+
+
+def _kernel_scan_fwd(x, dt, a, b, c):
+    y, starts = _kernel_call(False, True, x, dt, a, b, c)
+    return y, (x, dt, a, b, c, starts)
+
+
+def _kernel_scan_bwd(kept, d_y):
+    dx, d_dt, da, db, dc = _kernel_call(True, False, *kept, d_y)
+    by_token = lambda rows: jnp.transpose(rows, (0, 2, 4, 1, 3)).reshape(
+        kept[1].shape)
+    return dx, by_token(d_dt), by_token(da), db, dc
+
+
+_kernel_scan.defvjp(_kernel_scan_fwd, _kernel_scan_bwd)
+
+
+def _takes_kernels(x, b, chunk: int) -> bool:
+    """Whether the Pallas kernels run a call, from what can be seen of it
+    (``x`` [B, T, H, P], ``b`` [B, T, G, S]): a TPU; the shapes the kernels
+    were written and measured at (a chunk and a state of 128, heads that
+    fill 128 lanes a whole number at a time, a group a whole number of such
+    tiles and its heads' rows inside one); no mesh over the operand
+    (``linear_attention._mesh_over``). Everything else is the plain form's."""
+    (h, p), (g, s) = x.shape[2:], b.shape[2:]
+    return (chunk == s == _LANES and _LANES % p == 0
+            and (h // g * p) % _LANES == 0 and h // g <= _LANES
+            and jax.devices()[0].platform == "tpu" and not la._mesh_over(x))
+
+
 def ssm_scan(x, dt, a, b, c, skip, *, chunk: int):
     """The state-space recurrence in chunks (module docstring): ``x`` [B,
     T, H, P], ``dt`` (the step ``Delta``) and ``a`` (the log-decay, <= 0)
@@ -199,14 +509,28 @@ def ssm_scan(x, dt, a, b, c, skip, *, chunk: int):
     g = b.shape[2]
     pad = -t % chunk
 
-    def by_chunks(v, *tail):
+    def padded(v):
         if pad:
             v = jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
-        return v.reshape(bsz, (t + pad) // chunk, chunk, *tail)
+        return v
 
-    y = _scan(by_chunks(x, g, h // g, p), by_chunks(dt.astype(_F32), g, h // g),
-              by_chunks(a.astype(_F32), g, h // g),
-              by_chunks(b, g, b.shape[3]), by_chunks(c, g, c.shape[3]))
-    y = y.reshape(bsz, t + pad, h, p)[:, :t]
-    return (y.astype(_F32) + skip.astype(_F32)[:, None] * x.astype(_F32)
-            ).astype(x.dtype)
+    if _takes_kernels(x, b, chunk):
+        # flat all the way: [.., H, P] and [.., H * P] are two layouts on a
+        # TPU, and the mixer hands over, and takes back, the flat one
+        flat = lambda v: v.reshape(bsz, v.shape[1], -1)
+        x = flat(x)
+        y = _kernel_scan(padded(x), padded(dt.astype(_F32)),
+                         padded(a.astype(_F32)), padded(flat(b)),
+                         padded(flat(c)))[:, :t]
+        skip = jnp.repeat(skip.astype(_F32), p)
+    else:
+        by_chunks = lambda v, *tail: padded(v).reshape(
+            bsz, (t + pad) // chunk, chunk, *tail)
+        y = _scan(by_chunks(x, g, h // g, p),
+                  by_chunks(dt.astype(_F32), g, h // g),
+                  by_chunks(a.astype(_F32), g, h // g),
+                  by_chunks(b, g, b.shape[3]), by_chunks(c, g, c.shape[3]))
+        y = y.reshape(bsz, t + pad, h, p)[:, :t]
+        skip = skip.astype(_F32)[:, None]
+    return (y.astype(_F32) + skip * x.astype(_F32)).astype(x.dtype).reshape(
+        bsz, t, h, p)

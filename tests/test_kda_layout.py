@@ -19,7 +19,9 @@ chains are Pallas calls under ``kda_conv`` and the head norm three under
 of the parent's forms (PR 43's ``gates`` and ``gated_head_norm`` by heads,
 PR 49's projections and ``conv_silu`` by heads: ``_mixer_by_heads``), so it
 sees the fault (268 MB float32 or 134 MB bfloat16 crossing between two
-tilings: PERF.md section 6, PR 40, PR 43 and PR 49).
+tilings: PERF.md section 6, PR 40, PR 43 and PR 49). The same described
+device compiles the state-space scan's two kernels
+(``ray_tpu/ops/state_space.py``) at Nemotron-3-Nano's widths (PR 59).
 
 Nothing here is a speed. The topology is described inside a module-scoped
 fixture, never at import (the on-chip-measurement guide).
@@ -424,3 +426,23 @@ def test_the_parents_forms_do_and_the_assertion_sees_it(chip, where, name,
                    for line in found) >= 6
         assert sum("f32[" in line and "kda_conv" in line
                    for line in found) >= 3
+
+
+def test_the_state_space_scans_kernels_compile_at_the_cells_widths(chip):
+    """Mosaic takes ``ops/state_space.py``'s two kernels at Nemotron-3-Nano's
+    widths (64 heads of 64 in 8 groups, state 128: a 512-lane group a step,
+    its state [128, 512] float32 in VMEM), which interpret mode cannot
+    show; forward with the chunk-start states, and backward."""
+    from ray_tpu.ops import state_space as ss
+
+    one = jax.sharding.SingleDeviceSharding(chip)
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one)
+    x, dt, b = (like((B, T, 64 * 64), jnp.bfloat16), like((B, T, 64), F32),
+                like((B, T, 8 * 128), jnp.bfloat16))
+    starts = like((B, T // 128, 128, 64 * 64), F32)
+    for backward, operands in ((False, (x, dt, dt, b, b)),
+                               (True, (x, dt, dt, b, b, starts, x))):
+        text = ss._launch.lower(backward, not backward, False,
+                                *operands).compile().as_text()
+        assert "tpu_custom_call" in text
