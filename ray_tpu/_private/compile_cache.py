@@ -2,7 +2,7 @@
 
 One directory for every process of a checkout: the head and the node
 agents put it into each worker's spawn environment, and single-process
-scripts (``bench.py``) export it before importing jax. JAX reads
+scripts export it before importing jax. JAX reads
 ``JAX_COMPILATION_CACHE_DIR`` itself, so nothing here touches
 ``jax.config``.
 

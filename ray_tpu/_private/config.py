@@ -265,8 +265,7 @@ class Config:
     # lifecycle phases onto existing control-plane messages and keep a
     # bounded head-side event table rendered by util.state.timeline().
     # Costs a few time.time() calls and floats per task; disable for
-    # overhead-sensitive floods (benchmarks/microbenchmark.py measures
-    # the delta).
+    # overhead-sensitive floods.
     task_events_enabled: bool = True
     # How often each runtime piggybacks its rpc counter snapshot (and
     # buffered chaos events) to the head — the cluster-wide half of
@@ -336,8 +335,7 @@ class Config:
     # a tiny mmap'd beacon per task; supervisors reap the real exit
     # status, classify it, and keep a bounded crash-report table on the
     # head. Arming is one-time at boot and the beacon write is an mmap
-    # slice per task — steady-state free (microbenchmark measures the
-    # on/off delta).
+    # slice per task.
     crash_forensics_enabled: bool = True
     # Bounded head-side crash report table (oldest evicted past this).
     crash_reports_max: int = 256

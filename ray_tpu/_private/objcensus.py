@@ -21,9 +21,8 @@ owner half lives beside CoreRuntime:
     merges these with its ObjectEntry directory into the cluster-wide
     `ray-tpu memory` view and feeds the leak detector's trend windows.
 
-Disable with RAY_TPU_OBJECT_CENSUS_ENABLED=0 (the microbenchmark's
-census on/off op measures the delta — a stack walk per NEW callsite,
-a dict write per object otherwise).
+Disable with RAY_TPU_OBJECT_CENSUS_ENABLED=0 (the cost: a stack walk
+per NEW callsite, a dict write per object otherwise).
 """
 
 from __future__ import annotations

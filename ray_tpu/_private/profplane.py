@@ -18,8 +18,7 @@ TaskEventBuffer made task events always-on):
     OTHER thread's stack via sys._current_frames() at
     ``RAY_TPU_PROFILE_HZ``, but only for ``RAY_TPU_PROFILE_DUTY_CYCLE``
     of each one-second cycle — steady-state cost is duty * hz stack
-    walks per second (≈4/s at the defaults), measured ≤3% on the
-    depth-32 pipelined op (benchmarks/microbenchmark.py).
+    walks per second (≈4/s at the defaults).
   * Samples fold into a BOUNDED collapsed-stack table
     (``profiling_table_max``; overflow counts into "(other stacks)" +
     a dropped counter — a stack explosion must not leak the

@@ -24,74 +24,37 @@ Two architectures behind one config:
   - ``arch="gpt2"``  — learned positions, LayerNorm, GELU MLP, tied head.
   - ``arch="llama"`` — RoPE, RMSNorm, SwiGLU, GQA, untied head.
 
-The ``llama`` arch also takes what describes a model whose layers are
-not all alike (SmallThinker): a **period** of layer kinds
-(``layer_pattern``: per position, a sliding window or none, RoPE or no
-positional encoding at all), heads whose width is not ``d_model /
-n_heads`` (``d_head``), a router that reads the block's FIRST norm
-(``router_input="attn_norm"``: its logits are made ahead of attention
-and its experts run after the second norm), ReGLU experts, and **held
-experts** (``experts_held = (rank, of)``: the parameters are one
-expert-parallel rank's share of every layer's experts, the router still
-chooses among all of them, and the block adds the held experts' part of
-the sum; ``ops/moe.py``). And a DeepSeek-V3-shaped model (Kanana-2):
-**latent attention** (``kv_latent``: keys and values are made from one
-narrow compression of the token, the rotary part of the key is ONE
-vector all heads share, and values are narrower than queries and keys),
-a count of **leading dense layers** with an FFN width of their own
-(``n_dense_layers``: a SECOND stack of parameters, ``dense_layers``,
-run ahead of the scan over ``layers``), a **shared expert** beside the
-routed ones (``d_ff_shared``), and a **sigmoid router** whose learned-by-
-rule bias only the choice of experts sees (``router_score``,
-``router_bias``; the step moves the bias itself, ``make_train_step``).
-And a model whose layers do not all mix tokens the same way (Kimi
-Linear): **a mixer a layer** (``layer_mixers``: ``"kda"``, gated
-delta-rule linear attention with a state carried along the sequence,
-``ops/linear_attention.py``, or ``"attn"``, the model's latent
-attention, here without any rotation: ``latent_rope``). Such a model
-keeps, beside the leaves every layer has (the norms, ``attn/wo``, the
-router, the FFN: stacked over a stack's layers as ever), ONE STACK A KIND
-OF MIXER for the leaves only that kind has (``kda/*``, ``mla/*``:
-stacked over the layers of that kind).
-And an ``afmoe``-shaped model (Trinity): a **gate on attention's
-output** (``attn_gate``: ``sigmoid(W_g h)``, a value a query head and
-column, multiplies what the kernels return ahead of ``wo``), **QK-norm a
-head** (``qk_norm="head"``: ONE ``head_dim``-wide weight for all the
-query heads of a layer and one for its key heads, ahead of RoPE),
-**norms on the sublayers' outputs** (``post_norm``: four norms a block),
-a **scaled embedding** (``embed_scale``), and a ``layer_pattern``
-**anchored to the published layer numbers** (``first_layer``), which may
-then stand behind leading dense layers (the dense stack's layers take
-their kinds from the same pattern) and start or stop mid-period.
-And a ``qwen3_next``-shaped model (Qwen3-Next): **Gated DeltaNet** mixers
-(``layer_mixers`` ``"gdn"``: the delta rule with ONE decay a value head,
-fewer key heads than value heads, ``linear_key_heads``, and an output
-gate ``silu(z)`` of a full-rank projection) three to one layer of PLAIN
-gated attention (``"attn"`` beside ``"gdn"`` with no ``kv_latent``: own
-stack ``mha``), whose heads rotate their first quarter only
-(``rope_fraction``); **zero-centred norms** (``norm_zero_centred``: ``x /
-rms x (1 + w)``, ``w`` made 0); a **gate on the shared expert**
-(``shared_expert_gate``: ``sigmoid(w_s . h)``, one number a token).
-And a ``nemotron_h``-shaped model (Nemotron-3-Nano): a stack of
-**single-sublayer blocks**. ``layer_mixers`` may name ``"ffn"``, NO mixer:
-such a layer is its norm and its FFN (here: experts) alone, and in a model
-that has one, a layer named by a mixer is its norm and that mixer alone:
-``x <- x + f(norm(x))`` with ONE ``f`` a layer. A layer holds only its own
-sublayer's leaves (``_holds``: ``ln1``, ``attn/wo`` and the mixer's own
-stack over the mixer layers, ``ln2``, ``router`` and ``mlp`` over the FFN
-layers: no dead leaf for the optimizer to carry). Its mixers are **Mamba-2
-state-space layers** (``"ssm"``: ``ops/state_space.py``, a scalar decay a
-head, ``B`` and ``C`` shared by the heads of a group, a gated group norm)
-and plain GQA attention with **no positional encoding** (``attn_rope``
-False); its experts have **no gate projection** (``expert_gated`` False:
-``W_down relu(W_up u)^2``, ``expert_activation`` "relu2"), the shared
-expert likewise.
-With a period of P > 1 the scan runs over
-WHOLE PERIODS and unrolls a period's P layers in its body, so each
-position's kind is static: a windowed layer compiles to the kernel that
-skips tiles, never to a ``cond`` over both kinds. Layers left over after
-the last whole period (Kimi Linear's 26 expert layers are six periods of
-K K A K and then K A) run unrolled behind the scan.
+The ``llama`` arch also takes, a field or a few each (``TransformerConfig``
+says what each means, the presets which published model sets it):
+
+  - **layers that are not all alike**: a period of (windowed, rope) kinds
+    (``layer_pattern``, anchored to the published numbering by
+    ``first_layer``), or **a mixer a layer** (``layer_mixers``: a name of
+    ``mixers.MIXERS`` for every layer, or ``"ffn"``, NO mixer, which makes
+    the model a stack of **single-sublayer blocks**: ``x <- x + f(norm(x))``
+    with ONE ``f`` a layer). Such a model keeps, beside the leaves every
+    layer has (the norms, ``attn/wo``, the router, the FFN), ONE STACK A
+    KIND OF MIXER for the leaves only that kind has, and a layer holds its
+    own sublayer's leaves alone (``_holds``). With a period of P > 1 the
+    scan runs over WHOLE PERIODS and unrolls a period's P layers in its
+    body, so each position's kind is static (a windowed layer compiles to
+    the kernel that skips tiles, never to a ``cond``); layers left over
+    after the last whole period run unrolled behind the scan.
+  - **what a token mixer is** (softmax attention plain or latent, KDA,
+    Gated DeltaNet, a Mamba-2 state-space layer) is its record's,
+    ``models/mixers.py``. **To add a mixer**: a record there, its line in
+    ``mixers.MIXERS`` and its fields here; nothing in ``_block``,
+    ``forward``, ``lm_loss``, ``init_params`` or ``partition_specs``, which
+    take a mixer's leaves, specs, scope, function and counters from it.
+  - **experts**: capacity slots over a mesh or dropless on one chip
+    (``ops/moe.py``), a router that reads the block's FIRST norm, held
+    experts (``experts_held``: one expert-parallel rank's share), a shared
+    expert with or without a gate, a sigmoid router whose bias the train
+    step moves by rule (``make_train_step``), experts with no gate
+    projection; **leading dense layers** are a SECOND stack of parameters
+    (``dense_layers``) run ahead of the scan over ``layers``.
+  - norms on the sublayers' outputs (``post_norm``), zero-centred norms, a
+    scaled embedding.
 
 Every part runs under a ``jax.named_scope`` from ``SCOPES``, so each
 device instruction of a profiler trace says which part it belongs to
@@ -102,17 +65,28 @@ the compiled program is the same with and without them.
 from __future__ import annotations
 
 import contextlib
+import functools
 import hashlib
 import math
 import sys
 from dataclasses import dataclass, replace
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.experimental.xla_metadata import set_xla_metadata
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models import mixers
+from ray_tpu.models.mixers import (  # noqa: F401  (read from here by name)
+    _BATCH,
+    ATTN_PART_SCOPES,
+    ATTN_SCOPES,
+    GATE_SCOPE,
+    MLA_SCOPE,
+    Counter,
+    _expand_gqa,
+    _norm_weight,
+)
 from ray_tpu.ops import linear_attention, moe, state_space
 from ray_tpu.ops.attention import (FLASH_LSE_NAME, FLASH_OUT_NAME, attention,
                                    dot_product_attention)
@@ -125,9 +99,7 @@ from ray_tpu.ops.layers import (
     swiglu,
 )
 from ray_tpu.parallel.mesh import (
-    AXIS_DATA,
     AXIS_EXPERT,
-    AXIS_FSDP,
     AXIS_SEQUENCE,
     AXIS_TENSOR,
 )
@@ -144,78 +116,34 @@ from ray_tpu.parallel.sharding import constrain
 # ``final_norm`` and
 # ``head_loss`` (head matmul + every cross entropy); in make_train_step
 # ``grad_accum`` (the micro-batch scan's sums) and ``optimizer`` (update +
-# apply).
+# apply). What a mixer opens inside ``attn`` is in ``models/mixers.py``
+# (``ATTN_SCOPES``, ``ATTN_PART_SCOPES``, ``MLA_SCOPE``, ``GATE_SCOPE``).
 SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
           "final_norm", "head_loss", "grad_accum", "optimizer")
-# Inside ``attn``, for a model with a ``layer_pattern``, with latent
-# attention (whose layers are all full causal ones) or with
-# ``layer_mixers``: which kind of layer the instruction belongs to.
-# ``attn_linear`` is everything of a linear (KDA, Gated DeltaNet or
-# state-space) layer's mixer: inside it ``attn_qkv`` (the q / k / v
-# projections; Gated DeltaNet's z with them; a state-space layer's input
-# projection), ``ops.linear_attention.SCOPES`` (``kda_conv``, ``kda_gate``),
-# ``attn_core`` (the delta rule or the state-space scan, nothing else: on one
-# TPU chip two Pallas kernels, elsewhere XLA with ``ssm_carry``), ``attn_out``.
-ATTN_SCOPES = ("attn_full", "attn_window", "attn_linear")
-# The token mixers ``layer_mixers`` may name: "kda", "gdn" and "ssm" are
-# the LINEAR ones (a state carried along the sequence); "attn" is the
-# model's attention, latent with ``kv_latent`` and plain without.
-# ``FFN_ONLY`` names no mixer: the layer is its FFN alone, and makes the
-# model a stack of single-sublayer blocks (``single_sublayer``).
-MIXERS = ("attn", "kda", "gdn", "ssm")
-LINEAR_MIXERS = ("kda", "gdn", "ssm")
+# The token mixers ``layer_mixers`` may name (``mixers.MIXERS`` has a
+# record each), the LINEAR ones among them (a state carried along the
+# sequence; attention is the first record), and ``FFN_ONLY``, which names
+# no mixer: the layer is its FFN alone, and makes the model a stack of
+# single-sublayer blocks (``single_sublayer``).
+MIXERS = tuple(mixers.MIXERS)
+LINEAR_MIXERS = MIXERS[1:]
 FFN_ONLY = "ffn"
-# A stack's subtrees that hold ONE kind of mixer's own leaves, stacked
-# over the layers of that kind (``layer_mixers`` models only): a linear
-# mixer's under its own name, attention's under ``mla`` (latent) or
-# ``mha`` (plain: q, k, v, the head norms, the gate). ``attn/wo`` is every
-# layer's, whatever its mixer.
-MIXER_STACKS = {"kda": "kda", "gdn": "gdn", "ssm": "ssm",
-                "attn": ("mla", "mha")}
-
-
-def _own_stacks(c) -> tuple[str | None, str]:
-    """(the name of the subtree that holds the model's linear mixer's own
-    leaves, or None with none; the name of attention's)."""
-    linear = c.linear_mixer
-    return (linear and MIXER_STACKS[linear],
-            MIXER_STACKS["attn"][c.kv_latent is None])
-# Inside ``attn`` (and its ``attn_full``), latent attention only: what the
-# latent form adds outside the kernels (down-projection, the latent's
-# norm, up-projection, RoPE on the rotary parts).
-MLA_SCOPE = "mla_latent"
-# Inside ``attn``: the output gate (its projection, the sigmoid and the
-# product, every pass). Inside ``attn`` and inside ``mlp`` / ``moe``: the
-# norm of the sublayer's output and the residual add behind it.
-GATE_SCOPE = "attn_gate"
+# Inside ``attn`` and inside ``mlp`` / ``moe``: the norm of the sublayer's
+# output and the residual add behind it.
 POST_NORM_SCOPE = "post_norm"
-# Inside ``attn``, whatever its kind: what attention does, part by part.
-# ``attn_qkv`` the projections into it (latent attention: the query's
-# alone, the latent's are ``mla_latent``), ``attn_pos`` QK-norm (of all
-# heads together or a head) and RoPE
-# (latent attention: nested inside ``mla_latent``), ``attn_gqa`` k and v
-# repeated to the query heads, ``attn_core`` the one ``attention(...)``
-# call (the kernels and what ``ops.attention.SCOPES`` names around them,
-# or the materialised scores, softmax and ``p v``), ``attn_out`` the
-# output projection and the residual add.
-ATTN_PART_SCOPES = ("attn_qkv", "attn_pos", "attn_gqa", "attn_core",
-                    "attn_out")
 
 # Which tree's scopes an executable carries. jax's compile-cache key leaves
 # metadata out, so a step loaded from the cache would keep the scope names
-# of whatever tree compiled it. ``SCOPES_ID`` names the bytes of every
-# file that opens a scope of the step (this one, ``ops/moe.py``,
-# ``ops/attention.py``, ``ops/linear_attention.py`` and
-# ``ops/state_space.py``) and
-# rides on one instruction of the train step (the step counter's add) as a
-# frontend attribute, which the key does take: a tree in which one of them
-# differs compiles its own step and never loads another's, so the names in
-# a trace are always those of the tree that ran
+# of whatever tree compiled it. ``SCOPES_ID`` names the bytes of every file
+# that opens a scope of the step and rides on one instruction of the train
+# step (the step counter's add) as a frontend attribute, which the key does
+# take: a tree in which one of them differs compiles its own step
 # (``tests/test_model_scopes.py`` holds jax to it on a real cache).
 # (``ray_tpu.ops.attention`` the attribute is the function, not the module.)
 SCOPE_FILES = (__file__, moe.__file__,
                sys.modules[attention.__module__].__file__,
-               linear_attention.__file__, state_space.__file__)
+               linear_attention.__file__, state_space.__file__,
+               mixers.__file__)
 
 
 def _scopes_id(files=SCOPE_FILES) -> str:
@@ -268,19 +196,12 @@ class TransformerConfig:
     # off (the metric dict then reports accuracy 0.0).
     ce_accuracy: bool = True
     # Backward strategy of a ``loss_chunk`` a caller sets (at 0 it is
-    # "fused"). "fused": custom-VJP that computes
-    # dlogits = softmax - onehot analytically INSIDE the forward scan
-    # and saves only dx/dhead — each chunk's logits are computed exactly
-    # once per train step. "checkpoint": jax.checkpoint around the chunk
-    # body — the backward recomputes every chunk's logits (an extra
-    # head matmul, ~10% of GPT-2 124M's step FLOPs). Both are O(T)
-    # memory; eval (no grad) never pays the fused path's extra work
-    # because custom_vjp only runs it under differentiation.
+    # "fused"). "fused": custom-VJP that makes dlogits = softmax - onehot
+    # INSIDE the forward scan and saves only dx / dhead, so each chunk's
+    # logits are computed once a step. "checkpoint": jax.checkpoint around
+    # the chunk body, whose backward makes every chunk's logits again (96.0k
+    # against 90.9k tok/s/chip at GPT-2 124M, ``benchmarks/ab_results.jsonl``).
     ce_impl: str = "fused"           # "fused" | "checkpoint"
-    # Default is "fused": confirmed on hardware (v5e A/B, round 5 —
-    # benchmarks/ab_results.jsonl): 96.0k tok/s/chip vs 90.9k for
-    # "checkpoint" on GPT-2 124M @ T=1024 (the saved head-matmul
-    # recompute is ~10% of step FLOPs).
     # Mixture of Experts (llama arch only; 0 = dense FFN). Greenfield vs
     # the reference (SURVEY.md §2.4: EP absent upstream) — see ops/moe.py.
     n_experts: int = 0
@@ -307,13 +228,11 @@ class TransformerConfig:
     # (full causal attention; RoPE in the llama arch).
     layer_pattern: tuple[tuple[bool, bool], ...] = ()
     # The PUBLISHED number of the model's first layer, which anchors the
-    # pattern to the published numbering: layer i is position
-    # ``(first_layer + i) % len``. A number: any n_layers, and leading
-    # dense layers take their kinds from the pattern too. None runs as 0
-    # and is its own state because it says less: the caller has not said
-    # where in the published numbering the stack stands, so a part-period
-    # and a dense stack ahead of the pattern are refused (a SmallThinker
-    # of 6 layers is a slip, not a cut).
+    # pattern: layer i is position ``(first_layer + i) % len``. A number:
+    # any n_layers, and leading dense layers take their kinds from the
+    # pattern too. None runs as 0 and says less (where the stack stands in
+    # the published numbering is not known), so a part-period and a dense
+    # stack ahead of the pattern are refused.
     first_layer: int | None = None
     sliding_window: int | None = None  # keys a query of a windowed layer sees
     expert_activation: str = "silu"  # "silu" (SwiGLU) | "relu" (ReGLU)
@@ -357,10 +276,10 @@ class TransformerConfig:
     router_bias_rate: float = 0.0
     expert_gate_scale: float = 1.0   # x the gates, after renormalising
     # -- a model whose layers mix tokens in more than one way (llama arch) --
-    # The token mixer of every one of the ``n_layers``, by name: "kda"
-    # (gated delta-rule linear attention, ``ops/linear_attention.py``) or
-    # "attn" (the model's latent attention). Empty: attention everywhere.
-    # The scan's period is read off the list (``_period``).
+    # The token mixer of every one of the ``n_layers``, by name: a key of
+    # ``mixers.MIXERS`` ("attn", "kda", "gdn", "ssm") or "ffn" (NO mixer:
+    # the layer is its FFN alone). Empty: attention everywhere. The scan's
+    # period is read off the list (``_period``).
     layer_mixers: tuple[str, ...] = ()
     kda_heads: int = 0               # (value) heads of a linear layer
     kda_head_dim: int = 0            # a linear head's key AND value width,
@@ -945,49 +864,32 @@ def _check_config(c: TransformerConfig) -> None:
                          "layer_mixers (a layer_pattern says it a position, "
                          "latent_rope for latent attention)")
     if c.layer_mixers:
-        latent = c.kv_latent is not None
-        wo = (c.n_heads, c.d_head_v if latent else c.head_dim)
-        linear_name = ("KDA" if latent else "state-space"
-                       if "ssm" in c.layer_mixers else "Gated DeltaNet")
+        names = tuple(mixers.MIXERS) + (FFN_ONLY,)
+        checks = {name: mixers.MIXERS[name].check(c)
+                  for name in mixers.MIXERS if name in c.layer_mixers}
         # a layer whose kind has no stack to take its leaves from, by index
-        needs = {"ssm": ("ssm_state >= 1, ssm_chunk >= 1 and ssm_groups "
-                         "that divide kda_heads",
-                         min(c.ssm_state, c.ssm_chunk, c.ssm_groups) >= 1
-                         and c.kda_heads % max(c.ssm_groups, 1) == 0),
-                 FFN_ONLY: ("an FFN of experts alone (n_experts > 0, no "
+        needs = {FFN_ONLY: ("an FFN of experts alone (n_experts > 0, no "
                             "n_dense_layers, no post_norm, router_input "
                             "'mlp_norm')",
                             c.n_experts > 0 and not c.n_dense_layers
                             and not c.post_norm
-                            and c.router_input == "mlp_norm")}
+                            and c.router_input == "mlp_norm"),
+                 **{name: need for name, (need, _) in checks.items()
+                    if need is not None}}
         for i, name in enumerate(c.layer_mixers):
             if name in needs and not needs[name][1]:
                 raise ValueError(
                     f"layer_mixers[{i}] = {name!r} needs {needs[name][0]}")
         for name, wrong in (
-                (f"names other than {MIXERS + (FFN_ONLY,)}",
-                 not set(c.layer_mixers) <= set(MIXERS + (FFN_ONLY,))),
+                (f"names other than {names}",
+                 not set(c.layer_mixers) <= set(names)),
                 (f"{len(c.layer_mixers)} names for n_layers={c.n_layers}",
                  len(c.layer_mixers) != c.n_layers),
                 ("a layer_pattern", bool(c.layer_pattern)),
-                ("'kda' beside attention that is not latent (kv_latent)",
-                 "kda" in c.layer_mixers and not latent),
-                ("'gdn' beside latent attention (kv_latent)",
-                 "gdn" in c.layer_mixers and latent),
-                ("'ssm' beside latent attention (kv_latent)",
-                 "ssm" in c.layer_mixers and latent),
+                # what each of the model's mixers does not run with
+                *(row for _, rows in checks.values() for row in rows),
                 ("more than one kind of linear mixer in one model",
-                 len(set(LINEAR_MIXERS) & set(c.layer_mixers)) > 1),
-                ("kda_heads, kda_head_dim or kda_conv < 1",
-                 min(c.kda_heads, c.kda_head_dim, c.kda_conv) < 1),
-                # attn/wo is ONE stack over every layer, whatever its mixer:
-                # as many rows from a linear layer's heads as from
-                # attention's (KDA: the same heads)
-                (f"{linear_name} heads "
-                 f"{(c.kda_heads, c.kda_head_dim)} that are not attention's "
-                 f"(n_heads, a value's width) {wo}",
-                 (c.kda_heads, c.kda_head_dim) != wo if latent
-                 else c.kda_heads * c.kda_head_dim != wo[0] * wo[1])):
+                 len(set(LINEAR_MIXERS) & set(c.layer_mixers)) > 1)):
             if wrong:
                 raise ValueError(f"layer_mixers does not run with {name}")
     if c.n_dense_layers:
@@ -1013,23 +915,10 @@ def init_params(rng, config: TransformerConfig):
     stacks: ``dense_layers`` [n_dense_layers, ...] and ``layers`` (the
     expert layers, [n_layers - n_dense_layers, ...]). With
     ``layer_mixers`` a stack's ``attn`` holds ``wo`` alone, every
-    layer's; ``kda`` / ``gdn`` and ``mla`` / ``mha`` hold the linear
-    layers' and the attention layers' own leaves, stacked over the layers
-    of that kind. A linear mixer's init is its public implementation's:
-    the convolutions U(-1 / sqrt(taps), 1 / sqrt(taps)), ``A_log`` = log
-    U(1, 16), ``dt_bias`` the inverse softplus of a log-uniform step in
-    [0.001, 0.1]. Gated DeltaNet's one published input projection is the
-    leaves ``wq``, ``wk`` [D, key heads, dk], ``wv``, ``wz`` [D, heads,
-    dv] (its columns regrouped by what they make: each shards by head),
-    ``w_a`` and ``w_beta`` its second; plain attention's fused q-and-gate
-    projection the leaves ``wq`` and ``wg``. A zero-centred norm's weight
-    is made 0. A state-space layer's leaves (``ssm``): the published ONE
-    input projection as ``w_z`` [D, heads, channels] (the gate), ``w_xbc``
-    [D, heads x channels + 2 x groups x state] (``[x | B | C]``, one
-    convolution's operand) and ``w_dt`` [D, heads]; ``conv_w`` [taps, .]
-    and ``conv_b`` (``ssm_conv_bias``) U(-1 / sqrt(taps), 1 / sqrt(taps));
-    ``dt_bias`` and ``A_log`` as above, a head; ``D`` 1; ``o_norm`` [heads
-    x channels] 1.
+    layer's; a mixer's own leaves (``kda`` / ``gdn`` / ``ssm``, attention's
+    ``mla`` / ``mha``) are stacked over the layers of that kind and drawn
+    by its record (``mixers.MIXERS``: ``init``, where each one's leaves are
+    described). A zero-centred norm's weight is made 0.
     In a model of single-sublayer blocks a layer holds its own sublayer's
     leaves alone (``_holds``); experts with no gate projection have no
     ``w_gate`` / ``shared_w_gate`` leaf.
@@ -1037,10 +926,8 @@ def init_params(rng, config: TransformerConfig):
     c = config
     _check_config(c)
     pdt = jnp.dtype(c.param_dtype)
-    L, D, H, KV, Dh, F = (
-        c.n_scan_layers, c.d_model, c.n_heads, c.kv_heads, c.head_dim,
-        c.ffn_dim,
-    )
+    L, D, H, Dh, F = (
+        c.n_scan_layers, c.d_model, c.n_heads, c.head_dim, c.ffn_dim)
     std = 0.02
     res_std = std / math.sqrt(2 * c.n_layers)
     keys = iter(jax.random.split(rng, 16))
@@ -1062,113 +949,29 @@ def init_params(rng, config: TransformerConfig):
         """A norm's weight at its start: it scales by 1."""
         return (jnp.zeros if c.norm_zero_centred else jnp.ones)(shape, pdt)
 
-    def attn_stack(keys, n):
-        if c.kv_latent is None:
-            stack = {
-                "wq": norm(next(keys), n, D, H, Dh),
-                "wk": norm(next(keys), n, D, KV, Dh),
-                "wv": norm(next(keys), n, D, KV, Dh),
-                "wo": norm(next(keys), n, H, Dh, D, s=res_std),
-            }
-            if c.qk_norm:
-                per_head = c.qk_norm == "head"
-                stack["q_norm"] = unit(n, Dh if per_head else H * Dh)
-                stack["k_norm"] = unit(n, Dh if per_head else KV * Dh)
-            if c.attn_gate:
-                stack["wg"] = norm(next(fourth), n, D, H, Dh)
-            return stack
-        # [latent ; the one rotary key] down, the latent's norm, then
-        # [k_nope ; v] of every head up.
-        return {
-            "wq": norm(next(keys), n, D, H, Dh),
-            "wkv_a": norm(next(keys), n, D, c.kv_latent + c.d_head_rope),
-            "kv_norm": jnp.ones((n, c.kv_latent), pdt),
-            "wkv_b": norm(next(keys), n, c.kv_latent, H,
-                          c.d_head_nope + c.d_head_v),
-            "wo": norm(next(keys), n, H, c.d_head_v, D, s=res_std),
-        }
-
-    def kda_stack(keys, n):
-        Hk, dk, taps = c.kda_heads, c.kda_head_dim, c.kda_conv
-        edge = 1.0 / math.sqrt(taps)
-        step = jnp.exp(uniform(next(keys), n, Hk, dk, low=math.log(1e-3),
-                               high=math.log(1e-1)))
-        return {
-            **{f"w{x}": norm(next(keys), n, D, Hk, dk) for x in "qkv"},
-            **{f"conv_{x}": uniform(next(keys), n, taps, Hk, dk, low=-edge,
-                                    high=edge).astype(pdt) for x in "qkv"},
-            "f_a": norm(next(keys), n, D, dk),
-            "f_b": norm(next(keys), n, dk, Hk, dk),
-            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
-            "A_log": jnp.log(uniform(next(keys), n, Hk, low=1.0,
-                                     high=16.0)).astype(pdt),
-            "w_beta": norm(next(keys), n, D, Hk),
-            "g_a": norm(next(keys), n, D, dk),
-            "g_b": norm(next(keys), n, dk, Hk, dk),
-            "o_norm": jnp.ones((n, dk), pdt),
-        }
-
-    def gdn_stack(keys, n):
-        Hv, Hk = c.kda_heads, c.linear_key_heads or c.kda_heads
-        d, taps = c.kda_head_dim, c.kda_conv
-        edge = 1.0 / math.sqrt(taps)
-        heads = {"q": Hk, "k": Hk, "v": Hv, "z": Hv}
-        step = jnp.exp(uniform(next(keys), n, Hv, low=math.log(1e-3),
-                               high=math.log(1e-1)))
-        return {
-            **{f"w{x}": norm(next(keys), n, D, heads[x], d) for x in "qkvz"},
-            **{f"conv_{x}": uniform(next(keys), n, taps, heads[x], d,
-                                    low=-edge, high=edge).astype(pdt)
-               for x in "qkv"},
-            "w_a": norm(next(keys), n, D, Hv),
-            "w_beta": norm(next(keys), n, D, Hv),
-            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
-            "A_log": jnp.log(uniform(next(keys), n, Hv, low=1.0,
-                                     high=16.0)).astype(pdt),
-            "o_norm": jnp.ones((n, d), pdt),
-        }
-
-    def ssm_stack(keys, n):
-        Hs, P, taps = c.kda_heads, c.kda_head_dim, c.kda_conv
-        wide = Hs * P + 2 * c.ssm_groups * c.ssm_state
-        edge = 1.0 / math.sqrt(taps)
-        step = jnp.maximum(jnp.exp(uniform(
-            next(keys), n, Hs, low=math.log(1e-3), high=math.log(1e-1))),
-            1e-4)
-        return {
-            "w_z": norm(next(keys), n, D, Hs, P),
-            "w_xbc": norm(next(keys), n, D, wide),
-            "w_dt": norm(next(keys), n, D, Hs),
-            "conv_w": uniform(next(keys), n, taps, wide, low=-edge,
-                              high=edge).astype(pdt),
-            **({"conv_b": uniform(next(keys), n, wide, low=-edge,
-                                  high=edge).astype(pdt)}
-               if c.ssm_conv_bias else {}),
-            "dt_bias": (step + jnp.log(-jnp.expm1(-step))).astype(pdt),
-            "A_log": jnp.log(uniform(next(keys), n, Hs, low=1.0,
-                                     high=16.0)).astype(pdt),
-            "D": jnp.ones((n, Hs), pdt),
-            "o_norm": jnp.ones((n, Hs * P), pdt),
-        }
+    draw = mixers.Draw(norm=norm, uniform=uniform, unit=unit,
+                       res_std=res_std, gate_keys=fourth)
 
     def mixer_stacks(keys, first, n):
-        """The token mixers' leaves of the ``n`` layers from ``first``."""
+        """The token mixers' leaves of the ``n`` layers from ``first``:
+        each kind's from its record (``mixers.MIXERS``), attention's from
+        the stack's own keys and any other's from ``third``."""
+        attn = mixers.MIXERS["attn"]
         if not c.layer_mixers:
-            return {"attn": attn_stack(keys, n)}
-        linear, own = _own_stacks(c)
+            return {"attn": attn.init(c, keys, n, draw)}
         here = c.layer_mixers[first:first + n]
-        n_linear = sum(m in LINEAR_MIXERS for m in here)
-        n_attn = sum(m == "attn" for m in here)
         stacks = {}
-        if n_attn:
-            stacks[own] = attn_stack(keys, n_attn)
-            del stacks[own]["wo"]
-        if n_linear:
-            stacks[linear] = {"kda": kda_stack, "gdn": gdn_stack,
-                              "ssm": ssm_stack}[linear](third, n_linear)
+        for kind, mixer in mixers.MIXERS.items():
+            if kind in here:
+                stacks[mixer.stack(c)] = mixer.init(
+                    c, keys if mixer is attn else third, here.count(kind),
+                    draw)
+        # ``attn/wo`` is every mixer layer's, whatever its mixer
+        stacks.get(attn.stack(c), {}).pop("wo", None)
         value = c.d_head_v if c.kv_latent is not None else Dh
-        stacks["attn"] = {"wo": norm(next(third), n_attn + n_linear, H,
-                                     value, D, s=res_std)}
+        stacks["attn"] = {"wo": norm(
+            next(third), sum(kind != FFN_ONLY for kind in here), H, value, D,
+            s=res_std)}
         return stacks
 
     def block_norms(n):
@@ -1259,51 +1062,20 @@ def partition_specs(config: TransformerConfig):
     embedding/head. FSDP is layered on top by infer_param_specs.
     """
     c = config
-    attn = {
-        "wq": P(None, None, AXIS_TENSOR, None),
-        "wk": P(None, None, AXIS_TENSOR, None),
-        "wv": P(None, None, AXIS_TENSOR, None),
-        "wo": P(None, AXIS_TENSOR, None, None),
-        # the output gate shards by head like wq; a head's q / k norm
-        # weights are every head's (no entry: replicated)
-        "wg": P(None, None, AXIS_TENSOR, None),
-        # latent attention: the down-projection and the latent's norm are
-        # every head's; the up-projection shards by head like wq
-        "wkv_b": P(None, None, AXIS_TENSOR, None),
-    }
     ffn = {
         "w_gate": P(None, None, AXIS_TENSOR),
         "w_up": P(None, None, AXIS_TENSOR),
         "w_down": P(None, AXIS_TENSOR, None),
     }
-    # KDA: whatever has a head axis shards by head; the low-rank maps'
-    # first halves and the head norm's one weight are every head's
-    by_head = P(None, None, AXIS_TENSOR, None)
-    kda = {
-        **{name: by_head for name in ("wq", "wk", "wv", "conv_q", "conv_k",
-                                      "conv_v", "f_b", "g_b")},
-        "dt_bias": P(None, AXIS_TENSOR, None),
-        "A_log": P(None, AXIS_TENSOR),
-        "w_beta": P(None, None, AXIS_TENSOR),
-    }
-    # Gated DeltaNet: the same rule (q and k by KEY head)
-    gdn = {
-        **{name: by_head for name in ("wq", "wk", "wv", "wz", "conv_q",
-                                      "conv_k", "conv_v")},
-        "dt_bias": P(None, AXIS_TENSOR), "A_log": P(None, AXIS_TENSOR),
-        "w_a": P(None, None, AXIS_TENSOR),
-        "w_beta": P(None, None, AXIS_TENSOR),
-    }
-    # a state-space layer: the gate's projection by head; what the ONE
-    # convolution reads side by side ([x | B | C]) and the rest replicated
-    # (the scan does not run under a mesh's tensor axis yet: ROADMAP B3)
-    ssm = {"w_z": by_head}
-    mixers = {"attn": attn, "mla": attn, "mha": attn, "kda": kda, "gdn": gdn,
-              "ssm": ssm}
+    # every mixer's own leaves by its record, under the name of its stack;
+    # ``attn`` (every mixer layer's ``wo``) by attention's
+    stacks = {"attn": mixers.MIXERS["attn"].specs(c),
+              **{mixer.stack(c): mixer.specs(c)
+                 for mixer in mixers.MIXERS.values()}}
     specs = {
         "embed": {"tokens": P(AXIS_TENSOR, None)},
-        "layers": {**mixers, "ln1": None, "ln2": None},
-        "dense_layers": {**mixers, "ln1": None, "ln2": None, "mlp": ffn},
+        "layers": {**stacks, "ln1": None, "ln2": None},
+        "dense_layers": {**stacks, "ln1": None, "ln2": None, "mlp": ffn},
         "final_norm": None,
     }
     if c.arch == "gpt2":
@@ -1344,18 +1116,15 @@ def _mirror(specs, shapes):
 
 # -- forward ----------------------------------------------------------------
 
-_BATCH = (AXIS_DATA, AXIS_FSDP)
 # A stack of a few LARGE layers runs unrolled, as ONE scan step
 # (``lax.scan``'s ``unroll``): around a ``while`` loop XLA keeps whole-stack
 # temporaries (the casts of the stacked weights hoisted out of the loop,
 # the stacked gradients beside the optimizer's) that layers laid out in
-# line do not need. kanana-2's cell, four expert layers of 446 MB each, is
-# 16.58 GB in the compiler's account as a loop and 12.25 GB in line (PR 35,
-# rehearsal compile for the v5e). Both bounds are what can be seen at trace
+# line do not need (kanana-2's cell: 16.58 GB as a loop, 12.25 GB in line,
+# by the compiler's account). Both bounds are what can be seen at trace
 # time: at most this many steps (longer stacks keep the loop: compile time
 # is O(1) in depth there), and at least this many bytes of parameters in
-# the stack (below it the temporaries are small change). A stack of one
-# step (a period of SmallThinker's, OLMoE's one layer) is what it was.
+# the stack (below it the temporaries are small change).
 _SCAN_UNROLL_MOST = 4
 _SCAN_UNROLL_BYTES = 2 ** 30
 
@@ -1385,18 +1154,19 @@ def _period(kinds: tuple) -> int:
 
 def _holds(c: TransformerConfig, name: str, kind) -> bool:
     """Whether a layer of kind ``kind`` (``layer_kind``) has leaves in the
-    subtree ``name`` of its stack. A mixer's own subtree
-    (``MIXER_STACKS``) is stacked over the layers of that mixer alone;
+    subtree ``name`` of its stack. A mixer's own subtree (its record's
+    ``stack``) is stacked over the layers of that mixer alone;
     in a model of single-sublayer blocks ``ln1`` and ``attn`` (``wo``)
     over the layers that are a mixer, everything else (``ln2``, the
     router, ``mlp``) over those that are an FFN; any other subtree over
     all the layers."""
     if not c.layer_mixers:
         return True
-    linear, attn = _own_stacks(c)
     mixer = kind if isinstance(kind, str) else "attn"
-    if name in (linear, attn):
-        return (mixer in LINEAR_MIXERS) if name == linear else mixer == "attn"
+    own = {mixers.MIXERS[m].stack(c): m for m in mixers.MIXERS
+           if m in c.layer_mixers}
+    if name in own:
+        return own[name] == mixer
     if not c.single_sublayer:
         return True
     return (name in ("ln1", "attn")) == (mixer != FFN_ONLY)
@@ -1437,20 +1207,16 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
 
     ``mesh`` adds with_sharding_constraint annotations on activations
     (batch over data+fsdp, heads/ffn over tensor); pass None outside pjit.
-    ``return_aux`` additionally returns the router's statistics over the
-    layers, ``{"balance", "z", "load_max"}`` (``ops/moe.py``; the two loss
-    terms as means, the fullest layer's ``load_max``; zeros for a dense
-    model; with ``experts_held`` also ``held_share`` and ``full_buffer``,
-    the means over the layers; with a ``router_bias`` also ``expert_counts`` [layers,
-    experts], the batch's assignments to every expert, and
-    ``bias_swapped``, the mean over the layers; with linear layers also
-    ``kda_log_decay_min``, the most negative cumulative log-decay inside
-    any chunk of any of them, and with state-space layers ``ssm_step_mean``,
-    the mean step ``Delta`` over their tokens, heads and layers; with an
-    ``attn_gate`` also
-    ``attn_gate_mean``, the gate's mean over the attention layers; with a
-    ``shared_expert_gate`` also ``moe_shared_gate_mean``, that gate's mean
-    over the layers).
+    ``return_aux`` additionally returns the step's statistics, every
+    counter the model's layers report (``_counters``) folded over the
+    layers under its ``metric`` name (``_fold``: the router's loss terms as
+    means over the expert layers, the fullest layer's ``moe_load_max``,
+    ``moe_expert_counts`` [expert layers, experts], ``kda_log_decay_min``
+    the most negative cumulative log-decay inside any chunk of any linear
+    layer, ...; none for a dense model of plain attention), and under
+    ``"layers"`` every layer's own values as the main stack's scan
+    stacked them ({a counter's ``key``: [layers or periods, ...]}; a layer
+    that has no such sublayer reads zeros).
     ``return_hidden`` skips
     the LM head and returns the final
     normed hidden states [B, T, D] (``lm_loss`` applies the head itself,
@@ -1559,50 +1325,13 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                                       c.n_dense_layers, dense=True)
         x, auxs = run_stack(x, params["layers"], c.n_dense_layers,
                             c.n_scan_layers)
-        # a model of single-sublayer blocks: a layer that is no FFN reads
-        # 0 in the router's statistics, and the means are the FFN layers'
-        ffn = jnp.asarray(c.layers_with("ffn")) if c.single_sublayer else None
-
-        def mean(a):
-            return a.mean() if ffn is None else a.reshape(-1)[ffn].mean()
-
-        aux = {"balance": mean(auxs["balance"]), "z": mean(auxs["z"]),
-               "load_max": auxs["load_max"].max()}
-        if c.experts_held is not None:
-            aux["held_share"] = mean(auxs["held_share"])
-            aux["full_buffer"] = mean(auxs["full_buffer"])
-        if c.router_bias:
-            # [layers, experts], whether the scan stacked layers or periods
-            aux["expert_counts"] = auxs["counts"].reshape(-1, c.n_experts)
-            if ffn is not None:         # the layers that have a router
-                aux["expert_counts"] = aux["expert_counts"][ffn]
-            aux["bias_swapped"] = mean(auxs["bias_swapped"])
-        if c.attn_gate:
-            total = auxs["gate_mean"].sum()
-            if c.n_dense_layers:
-                total = total + dense_auxs["gate_mean"].sum()
-            aux["attn_gate_mean"] = total / sum(
-                m not in LINEAR_MIXERS
-                for m in c.layer_mixers or ("attn",) * c.n_layers)
-        if c.shared_expert_gate:
-            aux["moe_shared_gate_mean"] = auxs["shared_gate_mean"].mean()
-        if c.linear_mixer:
-            aux["kda_log_decay_min"] = auxs["log_decay_min"].min()
-            if c.n_dense_layers:
-                aux["kda_log_decay_min"] = jnp.minimum(
-                    aux["kda_log_decay_min"],
-                    dense_auxs["log_decay_min"].min())
-        if c.linear_mixer == "ssm":
-            aux["ssm_step_mean"] = auxs["step_mean"].sum() / len(
-                c.layers_with("ssm"))
+        stacks = [(c.n_dense_layers, c.n_scan_layers, auxs)]
+        if c.n_dense_layers:
+            stacks.append((0, c.n_dense_layers, dense_auxs))
+        aux = dict(_fold(c, _counters(c), stacks), layers=auxs)
 
     with jax.named_scope("final_norm"):
-        if c.arch == "gpt2":
-            x = layer_norm(x, params["final_norm"]["w"],
-                           params["final_norm"]["b"], eps=c.norm_eps)
-        else:
-            x = rms_norm(x, _norm_weight(c, params["final_norm"]["w"]),
-                         eps=c.norm_eps)
+        x = _norm(c, x, params["final_norm"])
     if return_hidden:
         return (x, aux) if return_aux else x
     with jax.named_scope("head_loss"):
@@ -1613,6 +1342,13 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
     return (logits, aux) if return_aux else logits
 
 
+def _norm(c: TransformerConfig, x, p):
+    """The arch's norm of the stream ``x`` by the leaves ``p``."""
+    if c.arch == "gpt2":
+        return layer_norm(x, p["w"], p["b"], eps=c.norm_eps)
+    return rms_norm(x, _norm_weight(c, p["w"]), eps=c.norm_eps)
+
+
 def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
            kind=None, dense: bool = False):
     """One transformer block (pre-norm residual). Its parts carry the
@@ -1621,13 +1357,17 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     = (windowed, rope) is the layer's place in the ``layer_pattern``
     (static; None with no pattern: full causal attention, the arch's own
     positions), and names the sub-scope its attention runs under.
-    ``kind`` = "kda", "gdn" or "ssm": the token mixer is that linear one,
-    under ``attn_linear``. ``dense``: one of an expert model's leading dense layers. With
+    ``kind`` = a name of ``mixers.MIXERS``: the token mixer is that one,
+    under its record's scope. ``dense``: one of an expert model's leading
+    dense layers. With
     ``post_norm`` a sublayer's output is normed under ``post_norm``,
     inside the sublayer's own scope, and joins the stream there. In a
     model of single-sublayer blocks (``single_sublayer``) the block is ONE
     of its halves: ``attn_norm`` + ``attn`` for a layer named by a mixer,
-    ``mlp_norm`` + ``moe`` for one named "ffn"."""
+    ``mlp_norm`` + ``moe`` for one named "ffn". Returns (x, every counter
+    of the model, ``_counters``, by its ``key``: what this layer's
+    sublayers report, zeros for what they do not, so that every layer of
+    a stack reports alike)."""
     experts = c.n_experts > 0 and not dense
 
     def join(x, out, norm: str):
@@ -1638,82 +1378,46 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
             return x + rms_norm(out, _norm_weight(c, lp[norm]["w"]),
                                 eps=c.norm_eps)
 
-    router = decay_min = gate_mean = step_mean = None
+    router, found = None, {}
     if kind != FFN_ONLY:
-        x, router, decay_min, gate_mean, step_mean = _mixer_sublayer(
+        x, router, found = _mixer_sublayer(
             x, lp, c, rope=rope, con=con, positions=positions, kind=kind,
             experts=experts, join=join)
-    zero = jnp.zeros((), jnp.float32)
-    aux = {name: zero for name in ("balance", "z", "load_max")}
-    if c.single_sublayer and kind != FFN_ONLY:
-        # a mixer layer of a stack of single-sublayer blocks: every layer
-        # of a stack reports alike, this one an expert layer's statistics
-        # at 0 (``forward`` takes its means over the FFN layers)
-        if c.experts_held is not None:
-            aux.update(held_share=zero, full_buffer=zero)
-        if c.router_bias:
-            aux.update(counts=jnp.zeros((c.n_experts,), jnp.float32),
-                       bias_swapped=zero)
-    else:
-        x, aux = _ffn_sublayer(x, lp, c, router=router, con=con,
-                               experts=experts, join=join, aux=aux)
-    if c.attn_gate:                     # every layer of a stack alike
-        aux = dict(aux, gate_mean=zero if gate_mean is None else gate_mean)
-    if c.linear_mixer:                  # likewise
-        aux = dict(aux, log_decay_min=zero if decay_min is None
-                   else decay_min)
-    if c.linear_mixer == "ssm":
-        aux = dict(aux, step_mean=zero if step_mean is None else step_mean)
-    return x, aux
+    if kind == FFN_ONLY or not c.single_sublayer:
+        x, more = _ffn_sublayer(x, lp, c, router=router, con=con,
+                                experts=experts, join=join)
+        found = {**found, **more}
+    return x, {k.key: found[k.key] if k.key in found
+               else jnp.zeros(k.shape(c), jnp.float32) for k in _counters(c)}
 
 
 def _mixer_sublayer(x, lp, c: TransformerConfig, *, rope, con, positions,
                     kind, experts: bool, join):
     """A block's first half: ``attn_norm`` and the token mixer under
     ``attn`` with its residual add -> (x, the router's logits where it
-    reads this norm, the linear mixer's log-decay minimum, the output
-    gate's mean, a state-space mixer's mean step; None where the layer has
-    none)."""
+    reads this norm, else None, the mixer's counters by their keys). The
+    mixer is its record's ``apply`` (``mixers.MIXERS``) on its own leaves;
+    what it returns goes through ``attn/wo``, every mixer layer's."""
     dt = c.compute_dtype
+    mixer = mixers.MIXERS[kind if isinstance(kind, str) else "attn"]
     window = None
-    linear = kind in LINEAR_MIXERS
-    if kind is not None and not linear:
+    if isinstance(kind, tuple):         # attention's place in the pattern
         windowed, with_rope = kind
         window = c.sliding_window if windowed else None
         rope = rope if with_rope else None
     with jax.named_scope("attn_norm"):
-        if c.arch == "gpt2":
-            h = layer_norm(x, lp["ln1"]["w"], lp["ln1"]["b"], eps=c.norm_eps)
-        else:
-            h = rms_norm(x, _norm_weight(c, lp["ln1"]["w"]), eps=c.norm_eps)
+        h = _norm(c, x, lp["ln1"])
     router = None
     if experts and c.router_input == "attn_norm":
         with jax.named_scope("moe"):
             router = moe.router_matmul(h, lp["router"]["w"])
-    decay_min = gate_mean = step_mean = None
     with jax.named_scope("attn"), (
-            contextlib.nullcontext() if kind is None else jax.named_scope(
-                ATTN_SCOPES[2 if linear else window is not None])):
-        if kind == "ssm":
-            o, decay_min, step_mean = _ssm_mixer(h, lp[kind], c)
-        elif linear:
-            mixer = _kda_mixer if kind == "kda" else _gdn_mixer
-            o, decay_min = mixer(h, lp[kind], c)
-        else:
-            # attention's own leaves: a stack of their own beside linear
-            # layers' (``MIXER_STACKS``)
-            own = lp[_own_stacks(c)[1] if c.layer_mixers else "attn"]
-            if c.kv_latent is not None:
-                q, k, v, shared = _latent_qkv(h, own, c, rope, positions)
-            else:
-                q, k, v = _plain_qkv(h, own, c, rope, positions)
-                shared = {}
-            q = con(q, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
-            with jax.named_scope("attn_core"):
-                o = attention(q, k, v, causal=True, impl=c.attn_impl,
-                              window=window, **shared)
-            if c.attn_gate:
-                o, gate_mean = _gate_output(o, h, own["wg"])
+            contextlib.nullcontext() if kind is None
+            else jax.named_scope(mixer.scope(window))):
+        o, counters = mixer.apply(
+            h, lp[mixer.stack(c)], c,
+            mixers.Ctx(rope=rope, positions=positions, window=window,
+                       con=con))
         with jax.named_scope("attn_out"):
             wo = lp["attn"]["wo"].astype(dt)
             # one stack for every layer: a linear layer's heads are its
@@ -1725,20 +1429,18 @@ def _mixer_sublayer(x, lp, c: TransformerConfig, *, rope, con, positions,
                 x = x + o
         if c.post_norm:
             x = join(x, o, "ln1_post")
-    return x, router, decay_min, gate_mean, step_mean
+    return x, router, counters
 
 
 def _ffn_sublayer(x, lp, c: TransformerConfig, *, router, con,
-                  experts: bool, join, aux):
+                  experts: bool, join):
     """A block's second half: ``mlp_norm`` and the FFN under ``mlp`` /
-    ``moe`` with its residual add -> (x, the router's statistics:
-    ``aux`` as it came for a dense FFN)."""
+    ``moe`` with its residual add -> (x, the router's and the shared
+    expert's counters by their keys: none for a dense FFN)."""
     dt = c.compute_dtype
+    counters = {}
     with jax.named_scope("mlp_norm"):
-        if c.arch == "gpt2":
-            h = layer_norm(x, lp["ln2"]["w"], lp["ln2"]["b"], eps=c.norm_eps)
-        else:
-            h = rms_norm(x, _norm_weight(c, lp["ln2"]["w"]), eps=c.norm_eps)
+        h = _norm(c, x, lp["ln2"])
     if c.arch == "gpt2":
         with jax.named_scope("mlp"):
             m = gelu_mlp(h, lp["mlp"]["w_in"].astype(dt),
@@ -1748,7 +1450,7 @@ def _ffn_sublayer(x, lp, c: TransformerConfig, *, router, con,
             x = x + m
     elif experts:
         with jax.named_scope("moe"):
-            m, aux = _expert_ffn(h, lp, c, router, con)
+            m, counters = _expert_ffn(h, lp, c, router, con)
             x = join(x, m, "ln2_post")
     else:
         with jax.named_scope("mlp"):
@@ -1756,20 +1458,91 @@ def _ffn_sublayer(x, lp, c: TransformerConfig, *, router, con,
                        lp["mlp"]["w_up"].astype(dt),
                        lp["mlp"]["w_down"].astype(dt))
             x = join(x, m, "ln2_post")
-    return x, aux
+    return x, counters
 
 
-def _norm_weight(c: TransformerConfig, w):
-    """What a norm scales by, from its leaf: ``1 + w`` where the model's
-    norms are zero-centred (``norm_zero_centred``), in float32."""
-    return 1.0 + w.astype(jnp.float32) if c.norm_zero_centred else w
+# What an expert layer reports, each statistic named ONCE (``Counter``):
+# the router's seven (``ops/moe.py`` hands them out under these keys; the
+# counts are [expert layers, experts], NOT a scalar: the train step's rule
+# for the router's bias reads them and takes them out of the metrics) and
+# the mean of the shared expert's gate. The mixers' are their records'.
+_moe = lambda c: c.n_experts > 0                            # noqa: E731
+_held = lambda c: c.experts_held is not None                # noqa: E731
+_biased = lambda c: c.router_bias                           # noqa: E731
+_EXPERT_COUNTS = Counter("counts", "moe_expert_counts", "stack", _biased,
+                         lambda c: (c.n_experts,))
+_SHARED_GATE_MEAN = Counter("shared_gate_mean", "moe_shared_gate_mean",
+                            "mean", lambda c: c.shared_expert_gate)
+EXPERT_COUNTERS = (
+    Counter("balance", "router_aux", "mean", _moe,
+            weight="router_aux_weight"),
+    Counter("z", "router_z", "mean", _moe, weight="router_z_weight"),
+    Counter("load_max", "moe_load_max", "max", _moe),
+    Counter("held_share", "moe_held_share", "mean", _held),
+    Counter("full_buffer", "moe_full_buffer", "mean", _held),
+    _EXPERT_COUNTS,
+    Counter("bias_swapped", "moe_bias_swapped", "mean", _biased),
+    _SHARED_GATE_MEAN,
+)
+
+
+def _counters(c: TransformerConfig) -> dict:
+    """{a counter the model's layers report: the layers (of ``n_layers``)
+    that report it}, in the order ``forward`` folds them: the expert
+    layers', then the mixers' (``mixers.counters_of``)."""
+    experts = tuple(i for i in c.layers_with(FFN_ONLY)
+                    if i >= c.n_dense_layers)
+    return {**{k: experts for k in EXPERT_COUNTERS if k.has(c)},
+            **mixers.counters_of(c)}
+
+
+# ``Counter.fold`` -> (a stack's rows to one number, two stacks' numbers to
+# one). Every layer of a stack reports, zeros where it has no such
+# sublayer, which none of these minds: a maximum >= 0, a minimum <= 0, a sum
+_REDUCE = {"max": (jnp.max, jnp.maximum), "min": (jnp.min, jnp.minimum),
+           "kind mean": (jnp.sum, jnp.add)}
+
+
+def _fold(c: TransformerConfig, counters: dict, stacks: list) -> dict:
+    """The step's statistics from every layer's: {a counter's ``metric``:
+    its layers' values folded by its ``fold``}. ``stacks``: (the first
+    layer, the number of layers, {a counter's ``key``: the layers' values
+    as the scan stacked them}) of the main stack, then of the leading
+    dense one; a stack none of whose layers reports a counter is left out
+    of its fold. "mean" and "stack" are the expert layers' (one stack) and
+    read the layers that report alone (a model of single-sublayer blocks:
+    its FFN layers)."""
+    index = functools.cache(jnp.asarray)    # one array a choice of rows
+    out = {}
+    for counter, layers in counters.items():
+        parts = []      # (a stack's values, its rows that report or None)
+        for first, n, aux in stacks:
+            rows = tuple(i - first for i in layers if first <= i < first + n)
+            if rows:
+                parts.append((aux[counter.key], rows if len(rows) < n else ()))
+        if counter.fold in _REDUCE:
+            reduce, combine = _REDUCE[counter.fold]
+            value = functools.reduce(combine, [reduce(a) for a, _ in parts])
+            if counter.fold == "kind mean":
+                value = value / len(layers)
+        else:
+            (value, rows), = parts
+            if rows or counter.fold == "stack":
+                value = value.reshape(-1, *counter.shape(c))
+            if rows:
+                value = value[index(rows)]
+            if counter.fold == "mean":
+                value = value.mean()
+        out[counter.metric] = value
+    return out
 
 
 def _expert_ffn(h, lp, c: TransformerConfig, router, con):
     """An expert layer's FFN on its normed input ``h`` [B, T, D] -> (the
     held experts' part of the routed sum plus the shared expert, the
-    router's statistics), AHEAD of any output norm and of the residual
-    add. ``router``: the logits, where they were made ahead of attention."""
+    layer's counters by their keys), AHEAD of any output norm and of the
+    residual add. ``router``: the logits, where they were made ahead of
+    attention."""
     dt = c.compute_dtype
     # experts with no gate projection have no such leaf (``expert_gated``)
     weights = (lp["router"]["w"], lp["mlp"].get("w_gate"),
@@ -1798,218 +1571,10 @@ def _expert_ffn(h, lp, c: TransformerConfig, router, con):
         if c.shared_expert_gate:
             out, gate_mean = moe.gated_shared_expert(
                 h, *shared, lp["mlp"]["shared_gate"])
-            m, aux = m + out, dict(aux, shared_gate_mean=gate_mean)
+            m, aux = m + out, {**aux, _SHARED_GATE_MEAN.key: gate_mean}
         else:
             m = m + moe.shared_expert(h, *shared)
     return m, aux
-
-
-def _kda_mixer(h, w, c: TransformerConfig):
-    """A KDA layer's mixer up to (not with) the output projection, from
-    the normed input ``h`` [B, T, D] and the layer's own leaves ``w`` ->
-    (o [B, T, H, dv], the most negative cumulative log-decay inside any
-    chunk). ``attn_qkv`` the three projections, ``attn_core`` the chunked
-    delta rule and nothing else; convolutions, gates and the gated head
-    norm open their scopes in ``ops/linear_attention.py``. **From the
-    projections to the head norm every array is FLAT**, [B, T, H * d], a
-    head a 128-lane slice with 8 tokens in a tile's sublanes, as the
-    rule's kernels read q, k, v, ``g`` and write ``o``: the projections
-    are plain matmuls against ``wq`` / ``wk`` / ``wv`` viewed [D, H * d]
-    (the leaves and their sharding by head keep their shapes; viewed FIRST
-    and cast after, which is the order in which XLA takes the weights'
-    gradient from the flat ``dq`` with no transposed copy of it), the
-    convolution chains take and return flat arrays and round once, at
-    their end, and ``g`` [B, T, H * dk] float32 goes from the gates to the
-    rule as it is. An array of that size that changes its tiling costs a
-    pass over HBM each way, 44 of them a step before PR 43 and 48 more
-    before PR 49 (``ops/linear_attention.py``'s docstring;
-    ``tests/test_kda_layout.py`` holds the compiled mixer to none)."""
-    dt = c.compute_dtype
-    with jax.named_scope("attn_qkv"):
-        q, k, v = (jnp.einsum("btd,dc->btc", h,
-                              w[name].reshape(c.d_model, -1).astype(dt))
-                   for name in ("wq", "wk", "wv"))
-    q, k, v = linear_attention.conv_silu(q, k, v, w["conv_q"], w["conv_k"],
-                                         w["conv_v"])
-    g, beta = linear_attention.gates(h, w)
-    with jax.named_scope("attn_core"):
-        o = linear_attention.gated_delta_rule(q, k, v, g, beta)
-    return (linear_attention.gated_head_norm(o, h, w, eps=c.norm_eps),
-            linear_attention.log_decay_min(g))
-
-
-def _gdn_mixer(h, w, c: TransformerConfig):
-    """A Gated DeltaNet layer's mixer up to (not with) the output
-    projection, from the normed input ``h`` [B, T, D] and the layer's own
-    leaves ``w`` -> (o [B, T, H, dv], the most negative cumulative
-    log-decay inside any chunk). As ``_kda_mixer``, FLAT from the
-    projections to the head norm; what differs is the mechanism: the
-    published ONE input projection (q, k by KEY head, v and the gate's z
-    by value head: four plain matmuls under ``attn_qkv``), ONE log-decay a
-    value head and ``beta`` from a second, 2 x heads wide (``head_gates``),
-    a key head read by ``kda_heads / linear_key_heads`` value heads (q and
-    k go to the rule as VIEWS by heads, which is how it learns their
-    count: nothing is computed on the view), and the output gate
-    ``silu(z)`` (``silu_gated_head_norm``)."""
-    dt = c.compute_dtype
-    with jax.named_scope("attn_qkv"):
-        q, k, v, z = (jnp.einsum("btd,dc->btc", h,
-                                 w[name].reshape(c.d_model, -1).astype(dt))
-                      for name in ("wq", "wk", "wv", "wz"))
-    q, k, v = linear_attention.conv_silu(q, k, v, w["conv_q"], w["conv_k"],
-                                         w["conv_v"])
-    g, beta = linear_attention.head_gates(h, w)
-    with jax.named_scope("attn_core"):
-        q, k = (a.reshape(*a.shape[:2], -1, c.kda_head_dim) for a in (q, k))
-        o = linear_attention.gated_delta_rule(q, k, v, g, beta)
-    return (linear_attention.silu_gated_head_norm(o, z, w["o_norm"],
-                                                  eps=c.norm_eps),
-            linear_attention.log_decay_min(g))
-
-
-def _ssm_mixer(h, w, c: TransformerConfig):
-    """A state-space (Mamba-2) layer's mixer up to (not with) the output
-    projection, from the normed input ``h`` [B, T, D] and the layer's own
-    leaves ``w`` -> (o FLAT [B, T, heads x channels], the most negative
-    cumulative log-decay inside any chunk, the mean step ``Delta``). Its
-    parts under the names the linear mixers' readers read: ``attn_qkv``
-    the published ONE input projection as three plain matmuls (the gate's
-    ``z``, ``[x | B | C]`` side by side as the ONE convolution reads them,
-    the step's ``dt`` summed in float32), ``kda_conv`` the chain on ``[x |
-    B | C]``, ``kda_gate`` the step, the decay, the gated group norm and
-    the counters, ``attn_core`` the ONE scan call and nothing else
-    (``ops/state_space.py``)."""
-    dt = c.compute_dtype
-    heads, width, groups = c.kda_heads, c.kda_head_dim, c.ssm_groups
-    with jax.named_scope("attn_qkv"):
-        z, xbc = (jnp.einsum("btd,dc->btc", h,
-                             w[name].reshape(c.d_model, -1).astype(dt))
-                  for name in ("w_z", "w_xbc"))
-        raw = jnp.einsum("btd,dh->bth", h, w["w_dt"].astype(dt),
-                         preferred_element_type=jnp.float32)
-    xbc = linear_attention.flat_conv_silu(
-        xbc, w["conv_w"], w["conv_b"] if c.ssm_conv_bias else None)
-    step, decay = state_space.step_and_decay(raw, w)
-    with jax.named_scope("attn_core"):
-        inner, state = heads * width, groups * c.ssm_state
-        by = lambda a, n: a.reshape(*a.shape[:2], n, -1)
-        o = state_space.ssm_scan(
-            by(xbc[..., :inner], heads), step, decay,
-            by(xbc[..., inner:inner + state], groups),
-            by(xbc[..., inner + state:], groups), w["D"], chunk=c.ssm_chunk)
-    o = state_space.gated_group_norm(o, z, w["o_norm"], groups,
-                                     eps=c.norm_eps)
-    with jax.named_scope("kda_gate"):
-        step_mean = jax.lax.stop_gradient(step).mean()
-    return o, linear_attention.log_decay_min(decay, c.ssm_chunk), step_mean
-
-
-def _gate_output(o, h, wg):
-    """Attention's output ``o`` [B, T, H, Dh] times ``sigmoid(W_g h)`` of
-    the block's normed input ``h`` [B, T, D] -> (gated o, the gate's mean:
-    0.5 at a seeded init, 0 where the gate has shut and attention is paid
-    for by nobody). The sigmoid and the product are float32."""
-    with jax.named_scope(GATE_SCOPE):
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "btd,dhk->bthk", h, wg.astype(h.dtype),
-            preferred_element_type=jnp.float32))
-        return (o * gate).astype(o.dtype), jax.lax.stop_gradient(gate).mean()
-
-
-def _plain_qkv(h, w, c: TransformerConfig, rope, positions):
-    """q, k, v [B, T, H, Dh] of plain attention from the normed input
-    ``h`` [B, T, D]: projections, QK-norm, RoPE, k and v repeated to the
-    query heads."""
-    dt = c.compute_dtype
-    with jax.named_scope("attn_qkv"):
-        if c.kv_heads == c.n_heads:
-            # Fused QKV: one (d → 3·h·k) matmul keeps the MXU busier than
-            # three skinny d→d projections (the weight concat is a few MB,
-            # amortized by XLA across the fused step).
-            wqkv = jnp.concatenate(
-                [w["wq"].astype(dt), w["wk"].astype(dt), w["wv"].astype(dt)],
-                axis=-1,
-            )  # [d, h, 3k]
-            qkv = jnp.einsum("btd,dhm->bthm", h, wqkv)
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-        else:
-            q = jnp.einsum("btd,dhk->bthk", h, w["wq"].astype(dt))
-            k = jnp.einsum("btd,dhk->bthk", h, w["wk"].astype(dt))
-            v = jnp.einsum("btd,dhk->bthk", h, w["wv"].astype(dt))
-    with jax.named_scope("attn_pos"):
-        if c.qk_norm == "head":         # a head at a time, one weight
-            q = rms_norm(q, _norm_weight(c, w["q_norm"]), eps=c.norm_eps)
-            k = rms_norm(k, _norm_weight(c, w["k_norm"]), eps=c.norm_eps)
-        elif c.qk_norm:
-            q = _qk_norm(q, w["q_norm"])
-            k = _qk_norm(k, w["k_norm"])
-        if rope is not None:
-            cos, sin = rope
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
-    return (q, *_expand_gqa(k, v, c))
-
-
-def _latent_qkv(h, w, c: TransformerConfig, rope, positions):
-    """Latent attention's operands from the normed input ``h`` [B, T, D]:
-    (q_nope [B, T, H, nope], k_nope [B, T, H, nope], v [B, T, H, v],
-    {"q_shared": q_rope [B, T, H, rope], "k_shared": k_rope [B, T,
-    rope]}). Keys and values come up from ONE ``kv_latent``-wide normed
-    compression of the token; the rotary part of the key is one vector a
-    token, which every head scores its own rotary query part against
-    (``ops.attention``: ``q_shared`` / ``k_shared``). RoPE pairs the
-    halves of the rotary part, as ``apply_rope`` does everywhere. With
-    ``rope`` None (``latent_rope`` False) nothing is rotated: the shared
-    part is a plain key part, and ``attn_pos`` stays empty.
-
-    The WEIGHTS are split (``wq`` into its no-position and rotary columns,
-    ``wkv_b`` into its key and value columns), never the ``[B, T, H, 192]``
-    / ``[B, T, H, 256]`` activations: a slice between a matmul and a
-    custom call cannot fuse into either, so each was a copy of the whole
-    operand (``[2, 32, 8192, 128]``: 0.43 ms, v5e), where a projection's
-    own output is written head-major as the kernels read it. The tree
-    keeps ONE ``wq`` and ONE ``wkv_b``."""
-    dt = c.compute_dtype
-    nope, latent = c.d_head_nope, c.kv_latent
-    with jax.named_scope("attn_qkv"):
-        wq = w["wq"].astype(dt)
-        q_nope = jnp.einsum("btd,dhk->bthk", h, wq[..., :nope])
-        q_rope = jnp.einsum("btd,dhk->bthk", h, wq[..., nope:])
-    with jax.named_scope(MLA_SCOPE):
-        down = jnp.einsum("btd,dc->btc", h, w["wkv_a"].astype(dt))
-        normed = rms_norm(down[..., :latent], w["kv_norm"], eps=c.norm_eps)
-        wkv_b = w["wkv_b"].astype(dt)
-        k_nope = jnp.einsum("btc,chk->bthk", normed, wkv_b[..., :nope])
-        v = jnp.einsum("btc,chk->bthk", normed, wkv_b[..., nope:])
-        k_rope = down[..., latent:]
-        if rope is not None:
-            cos, sin = rope
-            with jax.named_scope("attn_pos"):
-                # RoPE reads the projection ROUNDED to ``dt``, as the
-                # kernels read q_nope: left to itself XLA hands it the
-                # matmul's float32 accumulator (excess precision), and
-                # the forward is no longer the one ``correct`` was set on.
-                bits = jnp.finfo(dt)
-                q_rope = apply_rope(
-                    jax.lax.reduce_precision(q_rope, bits.nexp, bits.nmant),
-                    cos, sin, positions=positions)
-                k_rope = apply_rope(k_rope[:, :, None], cos, sin,
-                                    positions=positions)[:, :, 0]
-        return (q_nope, k_nope, v, {"q_shared": q_rope, "k_shared": k_rope})
-
-
-def _qk_norm(x, weight):
-    """RMSNorm of a q or k projection [B, T, H, Dh] over ALL its heads
-    together (H * Dh values a token), as OLMoE norms them."""
-    return rms_norm(x.reshape(*x.shape[:2], -1), weight).reshape(x.shape)
-
-
-def _expand_gqa(k, v, c: TransformerConfig):
-    if c.kv_heads == c.n_heads:
-        return k, v
-    rep = c.n_heads // c.kv_heads
-    with jax.named_scope("attn_gqa"):
-        return (jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2))
 
 
 # -- loss / train step ------------------------------------------------------
@@ -2097,7 +1662,7 @@ def _ce_chunk_stats(logits, tb, mb, z_loss, accuracy):
     return (nll * mb).sum(), correct, lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def fused_chunked_ce_loss(x, head, targets, mask, z_loss, chunk, accuracy,
                           con=None):
     """LM head + CE as one op whose BACKWARD is computed analytically in
@@ -2258,16 +1823,9 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
                 # ONE block of all the rows, which under a mesh stay on
                 # their devices: the logits are written once and read by
                 # the sum, the argmax and both backward matmuls, which
-                # make ``dlogits`` in their operand fusions. The largest
-                # block a cell of the benchmark has, SmallThinker's
-                # ``[16384, 37984]`` float32 of 2.49 GB (v5e, PR 52's
-                # traced pair): the step 474.8 -> 449.5 ms, outside any
-                # scope 36.8 -> 15.0 ms (the three passes over 2.49 GB
-                # that XLA put between the token-minor logits a batch of
-                # ONE row gets and the LINEAR float32 ``[N * V]`` that
-                # jax's transposed ``take_along_axis`` scatters into:
-                # 7.4 + 7.2 + 5.6 ms). Logits that do not fit want a
-                # ``loss_chunk``: no cell has such, so no rule picks one.
+                # make ``dlogits`` in their operand fusions (PERF.md, PR
+                # 52). Logits that do not fit want a ``loss_chunk``: no
+                # cell has such (2.49 GB at most), so no rule picks one.
                 chunk = tgt.size
                 if mesh is not None:
                     def con(logits):
@@ -2280,23 +1838,15 @@ def lm_loss(params, batch, config: TransformerConfig, *, mesh=None,
                 bool(config.ce_accuracy), con)
             metrics = {"loss": loss, "accuracy": acc,
                        "perplexity": jnp.exp(jnp.minimum(loss, 20.0))}
-    if config.n_experts > 0:
-        loss = (loss + config.router_aux_weight * aux["balance"]
-                + config.router_z_weight * aux["z"])
-        metrics = dict(metrics, router_aux=aux["balance"], router_z=aux["z"],
-                       moe_load_max=aux["load_max"], loss=loss)
-        if "held_share" in aux:
-            metrics["moe_held_share"] = aux["held_share"]
-            metrics["moe_full_buffer"] = aux["full_buffer"]
-        if "expert_counts" in aux:
-            # [layers, experts], NOT a scalar: the train step's rule for
-            # the router's bias reads it and takes it out of the metrics
-            metrics["moe_expert_counts"] = aux["expert_counts"]
-            metrics["moe_bias_swapped"] = aux["bias_swapped"]
-    for name in ("kda_log_decay_min", "ssm_step_mean", "attn_gate_mean",
-                 "moe_shared_gate_mean"):
-        if name in aux:
-            metrics = dict(metrics, **{name: aux[name]})
+    # every counter the model's layers report, under its name in the
+    # table that names it (``_counters``); a loss term times its weight
+    counters = _counters(config)
+    for counter in counters:
+        if counter.weight is not None:
+            loss = loss + (getattr(config, counter.weight)
+                           * aux[counter.metric])
+    metrics = dict(metrics, loss=loss,
+                   **{k.metric: aux[k.metric] for k in counters})
     return loss, metrics
 
 
@@ -2417,7 +1967,7 @@ def make_train_step(config: TransformerConfig, optimizer, *, mesh=None,
                 ))
         metrics = dict(metrics, grad_norm=gnorm)
         if config.router_bias:
-            counts = metrics.pop("moe_expert_counts")
+            counts = metrics.pop(_EXPERT_COUNTS.metric)
             with jax.named_scope("optimizer"):
                 b = state["params"]["layers"]["router"]["b"]
                 b = b + config.router_bias_rate * jnp.sign(
@@ -2448,25 +1998,16 @@ def init_train_state(rng, config: TransformerConfig, optimizer):
 def refuse_decode(c: TransformerConfig) -> None:
     """The KV-cache decode runs one kind of dense layer: refuse, by name,
     a model it would run wrongly in silence."""
-    if c.single_sublayer or "ssm" in c.layer_mixers:
-        raise NotImplementedError(
-            f"KV-cache decode does not run a stack of single-sublayer blocks "
-            f"or a state-space layer (layer_mixers {c.layer_mixers!r}; "
-            f"kda_heads {c.kda_heads}, kda_head_dim {c.kda_head_dim}, "
-            f"ssm_state {c.ssm_state}, ssm_groups {c.ssm_groups}, kda_conv "
-            f"{c.kda_conv}): a state-space ('ssm') layer keeps a recurrent "
-            f"state [heads, channels, ssm_state] and its convolution's last "
-            f"positions, not keys and values, and a layer named 'ffn' has "
-            f"no mixer and so no cache at all, where every layer would be "
-            f"decoded as attention AND an FFN")
+    # a model's mixers say why themselves (their records' ``decodes``); a
+    # layer with NO mixer in the words of the mixer that stands beside such
+    # layers, attention under ``layer_mixers`` (its own leaves in a stack of
+    # their own) in the linear mixers'
     if c.layer_mixers:
-        raise NotImplementedError(
-            f"KV-cache decode does not run a model with layer_mixers "
-            f"({c.layer_mixers!r}; kda_heads {c.kda_heads}, kda_head_dim "
-            f"{c.kda_head_dim}, kda_conv {c.kda_conv}, linear_key_heads "
-            f"{c.linear_key_heads}): a linear (KDA or Gated DeltaNet) layer "
-            f"keeps a recurrent state and its convolution's last positions, "
-            f"not keys and values, and would be decoded as plain attention")
+        why = mixers.refuses_single_sublayers if c.single_sublayer else next(
+            (mixer.decodes for kind, mixer in mixers.MIXERS.items()
+             if kind in c.layer_mixers and mixer.decodes),
+            mixers.refuses_linear)
+        raise NotImplementedError(why(c))
     for name, value in (("kv_latent", c.kv_latent),
                         ("latent_rope", not c.latent_rope),
                         ("n_dense_layers", c.n_dense_layers),

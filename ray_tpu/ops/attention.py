@@ -494,7 +494,7 @@ def _flash_fwd_kernel(*refs, block_q, block_k, n_k, n_steps, causal, scale,
 # The two moves below are bitcasts where XLA can lay their operand
 # head-major itself, as it does a projection's OWN output: a slice, a pad
 # or a concatenate between the matmul and the kernel makes each a copy
-# of the whole operand (``models/transformer.py`` ``_latent_qkv``).
+# of the whole operand (``models/mixers.py`` ``_latent_qkv``).
 def _heads_flat(x):
     """[B, T, H, W] -> [B * H, T, W]: one grid row a (batch, head)."""
     b, t, h, w = x.shape

@@ -147,7 +147,8 @@ one: 1.64 -> 0.49 ms forward and 6.18 -> 2.31 forward + backward for KDA,
 ``SCOPES`` are the named scopes this file opens around the parts of a
 linear (KDA or Gated DeltaNet) layer's mixer that are neither projections
 nor the delta rule
-(``ray_tpu/models/transformer.py`` opens ``attn_linear`` and the rest):
+(``ray_tpu/models/transformer.py`` opens ``attn_linear``, the mixers of
+``ray_tpu/models/mixers.py`` the rest):
 ``kda_conv`` (the three convolutions, SiLU, the l2 norms: on a TPU the
 chains' Pallas calls, which ``step_kda_kernel_ms`` and
 ``step_attn_kernel_ms`` therefore count beside the rule's) and

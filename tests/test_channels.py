@@ -371,7 +371,7 @@ def test_compiled_dag_throughput_vs_actor_calls(cluster):
     """The channel pipeline beats by-ref actor calls on 1 MiB payloads.
     CI floor is 2x: this test also runs on single-core boxes where every
     hop is a context switch; on multi-core hosts the spin-path puts the
-    gap at an order of magnitude (see benchmarks/channel_bench.py)."""
+    gap at an order of magnitude."""
 
     @ray_tpu.remote
     class Fwd:

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import transformer
+from ray_tpu.models import mixers, transformer
 from ray_tpu.ops import linear_attention as la
 
 F32 = jnp.float32
@@ -186,8 +186,8 @@ def _mixer_hlo(device):
 
     @jax.checkpoint
     def layer(h, w):
-        o, decay_min = transformer._gdn_mixer(h, w, c)
-        return jnp.square(o.astype(F32)).sum() + decay_min
+        o, counters = mixers._gdn_mixer(h, w, c)
+        return jnp.square(o.astype(F32)).sum() + counters["log_decay_min"]
 
     with mock.patch.object(jax, "devices", lambda *a, **k: [device]):
         assert la._on_one_tpu(h, c.kda_head_dim, c.kda_head_dim)

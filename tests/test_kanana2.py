@@ -29,7 +29,7 @@ from chipbench import spec
 from chipbench.reference import _common
 from chipbench.reference import kanana2 as reference
 from ray_tpu import models
-from ray_tpu.models import transformer
+from ray_tpu.models import mixers, transformer
 from ray_tpu.ops import moe
 
 import _small_models as sm
@@ -232,7 +232,7 @@ def test_no_activation_is_cut_between_a_projection_and_the_kernels(rotated):
         cfg.d_head_rope, cfg.max_seq_len, theta=cfg.rope_theta
     ) if rotated else None
     jaxpr = jax.make_jaxpr(
-        lambda h, w: transformer._latent_qkv(h, w, cfg, rope, None))(
+        lambda h, w: mixers._latent_qkv(h, w, cfg, rope, None))(
             jnp.zeros((2, T, cfg.d_model), jnp.float32), own).jaxpr
     made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
 

@@ -1,7 +1,7 @@
 """The layout in which a KDA layer hands arrays from its projections to the
 delta rule and on to its head norm (``ray_tpu/ops/linear_attention.py``
 ``conv_silu``, ``gates``, ``gated_delta_rule``, ``gated_head_norm``,
-``log_decay_min``; ``ray_tpu/models/transformer.py`` ``_kda_mixer``): FLAT,
+``log_decay_min``; ``ray_tpu/models/mixers.py`` ``_kda_mixer``): FLAT,
 [B, T, H * d], a head a 128-lane slice, which is how the rule's Pallas
 kernels read q, k, v, ``g`` and write ``o``. On the CPU at tiny widths: the
 flat ``g``, the head norm and their gradients are the parent's
@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from ray_tpu.models import transformer
+from ray_tpu.models import mixers, transformer
 from ray_tpu.ops import linear_attention as la
 
 F32 = jnp.float32
@@ -99,7 +99,8 @@ def _mixer_by_heads(h, w, c):
     g, beta = la.gates(h, w)
     with jax.named_scope("attn_core"):
         o = la.gated_delta_rule(q, k, v, g, beta)
-    return la.gated_head_norm(o, h, w, eps=c.norm_eps), la.log_decay_min(g)
+    return (la.gated_head_norm(o, h, w, eps=c.norm_eps),
+            {"log_decay_min": la.log_decay_min(g)})
 
 
 def _near(a, b, tol: float = 1e-6) -> bool:
@@ -330,8 +331,8 @@ def _mixer_hlo(device) -> str:
 
     @jax.checkpoint
     def layer(h, w):
-        o, decay_min = transformer._kda_mixer(h, w, c)
-        return jnp.square(o.astype(F32)).sum() + decay_min
+        o, counters = mixers._kda_mixer(h, w, c)
+        return jnp.square(o.astype(F32)).sum() + counters["log_decay_min"]
 
     # ``gated_delta_rule`` asks ``jax.devices()``, which here is the CPU's:
     # steer it, in the test, to the described chip, as the rehearsal does
@@ -412,14 +413,14 @@ def test_no_array_of_the_operands_size_changes_its_tiling(chip):
 @pytest.mark.parametrize("where,name,parents", [
     (la, "gates", _gates_by_heads),
     (la, "gated_head_norm", _head_norm_by_heads),
-    (transformer, "_kda_mixer", _mixer_by_heads)],
+    (mixers, "_kda_mixer", _mixer_by_heads)],
     ids=["gates", "gated_head_norm", "_kda_mixer"])
 def test_the_parents_forms_do_and_the_assertion_sees_it(chip, where, name,
                                                         parents):
     with mock.patch.object(where, name, parents):
         found = _relayouts(_mixer_hlo(chip))
     assert found, f"{name} by heads should cross between two tilings"
-    if where is transformer:
+    if where is mixers:
         # q, k, v into the kernels' tiling (bfloat16, forward and recompute)
         # and the chains' backward with the positions in the lanes (float32)
         assert sum("bf16[" in line and "attn_core" in line

@@ -153,14 +153,16 @@ def test_dropless_experts_carry_their_sub_scopes_in_every_pass():
 
 def test_scopes_id_covers_every_file_that_opens_a_scope(tmp_path):
     """``SCOPES_ID`` is over the bytes of ``models/transformer.py``,
-    ``ops/moe.py``, ``ops/attention.py``, ``ops/linear_attention.py`` AND
-    ``ops/state_space.py``: an edit of any gives another id, so a step
+    ``ops/moe.py``, ``ops/attention.py``, ``ops/linear_attention.py``,
+    ``ops/state_space.py`` AND ``models/mixers.py``: an edit of any gives
+    another id, so a step
     cached by a tree with other sub-scope names is never loaded."""
+    from ray_tpu.models import mixers
     from ray_tpu.ops import linear_attention, moe, state_space
 
     assert transformer.SCOPE_FILES == (
         transformer.__file__, moe.__file__, attention_ops.__file__,
-        linear_attention.__file__, state_space.__file__)
+        linear_attention.__file__, state_space.__file__, mixers.__file__)
     assert transformer.SCOPES_ID == transformer._scopes_id()
     for i, path in enumerate(transformer.SCOPE_FILES):
         edited = tmp_path / f"edited{i}.py"

@@ -397,7 +397,7 @@ def test_head_loss_under_a_mesh_is_one_block_on_each_devices_own_rows():
 def test_fused_clip_adamw_matches_optax():
     """ops.optim.FusedClipAdamW must reproduce
     optax.chain(clip_by_global_norm, adamw) exactly — it is an HBM-pass
-    fusion, not a new optimizer (bench.py's train step depends on it)."""
+    fusion, not a new optimizer."""
     from ray_tpu.ops.optim import FusedClipAdamW
 
     cfg = models.tiny()

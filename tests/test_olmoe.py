@@ -29,7 +29,7 @@ import pytest
 
 from chipbench.reference import olmoe as reference
 from ray_tpu import models
-from ray_tpu.models import transformer
+from ray_tpu.models import mixers
 from ray_tpu.ops import moe
 
 import _small_models as sm
@@ -155,7 +155,7 @@ def test_a_broken_variant_fails_the_comparison(name, monkeypatch):
     want = {"logits": reference.forward(params, rows[:, :-1], cfg),
             "loss": reference.loss(params, rows, cfg)}
     if patch == "qk_norm":
-        monkeypatch.setattr(transformer, "_qk_norm", _per_head_qk_norm)
+        monkeypatch.setattr(mixers, "_qk_norm", _per_head_qk_norm)
     if patch == "balance":
         monkeypatch.setattr(moe, "moe_swiglu_dropless",
                             _balance_over_token_shares(

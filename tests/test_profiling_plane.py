@@ -4,8 +4,7 @@ Modeled on the reference's dashboard profiling (py-spy-driven
 profile_manager) made ALWAYS-ON: unit tests for the duty-cycled
 sampler (bounded tables, kill switch, borrow unification with the
 on-demand probe, GIL-starvation exemplars, crash-sidecar join), the
-folded-profile algebra the head/CLI share, and the perf-regression
-sentinel's gate logic (injected measurements — no runtime); plus
+folded-profile algebra the head/CLI share; plus
 end-to-end tests asserting a live cluster yields a merged flamegraph
 spanning the head and multiple workers purely from piggybacked report
 casts, that `ray-tpu profile` renders/exports/diffs it, and that the
@@ -261,103 +260,6 @@ def test_sidecar_written_and_crash_report_join(tmp_path, busy_thread):
         assert report["profile"]["samples"] == rec["samples"]
     finally:
         s.stop()
-
-
-# ========================================== perf-regression sentinel
-
-
-def _fake_measure(rates):
-    def measure(op_names, runs):
-        return {name: [r * (1 + 0.01 * i) for i in range(runs)]
-                for name, r in rates.items()
-                if not op_names or name in op_names}
-    return measure
-
-
-@pytest.fixture
-def sentinel_env(tmp_path):
-    from benchmarks import perf_sentinel
-    base = str(tmp_path / "baseline.json")
-    traj = str(tmp_path / "trajectory.jsonl")
-    rates = {"tasks_async": 1000.0, "actor_pipeline_32": 4000.0}
-    rc = perf_sentinel.run_sentinel(
-        ["--write-baseline", "--runs", "3", "--baseline", base,
-         "--trajectory", traj], measure=_fake_measure(rates))
-    assert rc == 0
-    return perf_sentinel, base, traj, rates
-
-
-def test_sentinel_baseline_written_and_clean_pass(sentinel_env, capsys):
-    perf_sentinel, base, traj, rates = sentinel_env
-    with open(base) as f:
-        baseline = json.load(f)
-    assert set(baseline["ops"]) == set(rates)
-    assert baseline["ops"]["tasks_async"]["median"] == \
-        pytest.approx(1010.0)
-    # Unchanged tree: the gate passes and says so.
-    rc = perf_sentinel.run_sentinel(
-        ["--baseline", base, "--trajectory", traj],
-        measure=_fake_measure(rates))
-    assert rc == 0
-    assert "ok (within noise bands)" in capsys.readouterr().out
-    with open(traj) as f:
-        lines = [json.loads(ln) for ln in f]
-    assert len(lines) == 2 and lines[1]["regressions"] == []
-
-
-def test_sentinel_flags_seeded_regression(sentinel_env, capsys):
-    perf_sentinel, base, traj, rates = sentinel_env
-    rc = perf_sentinel.run_sentinel(
-        ["--baseline", base, "--trajectory", traj,
-         "--inject-slowdown", "tasks_async=2.0"],
-        measure=_fake_measure(rates))
-    assert rc == 1
-    out = capsys.readouterr()
-    assert "REGRESSION in tasks_async" in out.err
-    # Only the seeded op gated; the healthy op stayed ok.
-    assert "actor_pipeline_32" not in out.err
-    last = json.loads(open(traj).read().splitlines()[-1])
-    assert last["regressions"] == ["tasks_async"]
-    assert last["ratios"]["tasks_async"] == pytest.approx(0.5, abs=0.02)
-
-
-def test_sentinel_noise_band_absorbs_jitter(sentinel_env):
-    perf_sentinel, base, traj, rates = sentinel_env
-    # 15% slower is inside the 25% noise floor: no flapping gate.
-    rc = perf_sentinel.run_sentinel(
-        ["--baseline", base, "--trajectory", traj,
-         "--inject-slowdown", "tasks_async=1.15"],
-        measure=_fake_measure(rates))
-    assert rc == 0
-    # A brand-new op (absent from the baseline) reports but never gates.
-    rc = perf_sentinel.run_sentinel(
-        ["--baseline", base, "--trajectory", traj],
-        measure=_fake_measure(dict(rates, new_op=1.0)))
-    assert rc == 0
-
-
-def test_sentinel_requires_baseline(tmp_path):
-    from benchmarks import perf_sentinel
-    rc = perf_sentinel.run_sentinel(
-        ["--baseline", str(tmp_path / "missing.json"),
-         "--trajectory", str(tmp_path / "t.jsonl")],
-        measure=_fake_measure({"tasks_async": 1.0}))
-    assert rc == 2
-
-
-def test_committed_baseline_and_trajectory_exist():
-    # The repo ships a real baseline + its trajectory head — the gate
-    # is armed from the first clone, not after a bootstrap run.
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmarks", "perf_baseline.json")) as f:
-        baseline = json.load(f)
-    assert {"tasks_async", "actor_pipeline_32", "put_small",
-            "get_small"} <= set(baseline["ops"])
-    for op in baseline["ops"].values():
-        assert op["median"] > 0 and len(op["samples"]) >= 3
-    with open(os.path.join(root, "benchmarks",
-                           "perf_trajectory.jsonl")) as f:
-        assert len(f.read().splitlines()) >= 1
 
 
 # ========================================== end-to-end (live cluster)

@@ -32,7 +32,7 @@ from chipbench import spec
 from chipbench.reference import _common
 from chipbench.reference import afmoe as reference
 from ray_tpu import models
-from ray_tpu.models import transformer
+from ray_tpu.models import mixers, transformer
 from ray_tpu.ops import moe
 
 import _small_models as sm
@@ -405,7 +405,7 @@ def test_the_step_reports_the_gates_mean_and_moves_the_bias_by_rule():
             assert float(jnp.abs(new["params"][stack]["attn"][leaf]
                                  - params[stack]["attn"][leaf]).max()) > 0
     # a gate that has shut reads 0
-    o, mean = transformer._gate_output(
+    o, mean = mixers._gate_output(
         jnp.ones((1, 8, 4, 16)), jnp.ones((1, 8, 64)),
         jnp.full((64, 4, 16), -10.0))
     assert float(mean) < 1e-6 and float(jnp.abs(o).max()) < 1e-6
