@@ -581,7 +581,9 @@ def _ssm_mixer(h, w, c, ctx: Ctx | None = None):
     the step's ``dt`` summed in float32), ``kda_conv`` the chain on ``[x |
     B | C]``, ``kda_gate`` the step, the decay, the gated group norm and
     the counters, ``attn_core`` the ONE scan call and nothing else
-    (``ops/state_space.py``)."""
+    (``ops/state_space.py``). On one TPU chip chain, scan and norm are
+    Pallas passes over the same flat tiling (each op's own rule, all three
+    asking ``linear_attention._one_tpu``); elsewhere their plain forms."""
     dt = c.compute_dtype
     heads, width, groups = c.kda_heads, c.kda_head_dim, c.ssm_groups
     with jax.named_scope("attn_qkv"):

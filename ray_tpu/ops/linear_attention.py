@@ -224,10 +224,15 @@ def conv_silu(q, k, v, w_q, w_k, w_v):
 def flat_conv_silu(x, w, bias=None):
     """A state-space layer's ONE chain on its flat ``[x | B | C]``
     projection ``x`` [B, T, C]: ``silu(short_conv(x) + bias)``, taps ``w``
-    [K, C], ``bias`` [C] or None, no l2 norm (scope ``kda_conv``). The
-    plain chain everywhere: the kernels take no bias yet."""
+    [K, C], ``bias`` [C] or None, no l2 norm (scope ``kda_conv``). On one
+    TPU chip (``_one_tpu``) at whole 128-lane tiles and taps the halo
+    holds, ONE Pallas pass forward and one backward (``_chain_kernels``,
+    the bias a row beside the taps); anywhere else ``_chain``, which is
+    also what the kernels are tested against."""
     with jax.named_scope("kda_conv"):
-        return _chain(x, w, False, bias)
+        fused = (x.shape[2] % _KERNEL_WIDTH == 0
+                 and w.shape[0] - 1 <= _CONV_HALO and _one_tpu(x))
+        return (_chain_kernels if fused else _chain)(x, w, False, bias)
 
 
 def _chain(x, w, norm: bool, bias=None):
@@ -1150,12 +1155,19 @@ def _takes_kernels(q, v) -> bool:
 def _on_one_tpu(a, dk: int, dv: int) -> bool:
     """Whether the Pallas kernels (the delta rule's and the convolution
     chains': one rule for the whole mixer) run a call, from what can be
-    seen of it: a TPU; heads of ``_KERNEL_WIDTH``, the one width the
-    kernels were measured at and their four-head step's live set fits the
-    32-MiB ceiling at (a wider head would fail in Mosaic where the scan
-    runs); no mesh over the operand ``a`` (``_mesh_over``)."""
-    return (dk == dv == _KERNEL_WIDTH
-            and jax.devices()[0].platform == "tpu" and not _mesh_over(a))
+    seen of it: heads of ``_KERNEL_WIDTH``, the one width the kernels were
+    measured at and their four-head step's live set fits the 32-MiB
+    ceiling at (a wider head would fail in Mosaic where the scan runs);
+    one TPU chip under the operand ``a`` (``_one_tpu``)."""
+    return dk == dv == _KERNEL_WIDTH and _one_tpu(a)
+
+
+def _one_tpu(a) -> bool:
+    """A TPU, and no mesh over the operand ``a`` (``_mesh_over``): where
+    any Pallas kernel of a linear mixer may run. The state-space mixer's
+    three ops ask this and their own shapes (``flat_conv_silu``,
+    ``state_space._takes_kernels``, ``state_space._norm_takes_kernels``)."""
+    return jax.devices()[0].platform == "tpu" and not _mesh_over(a)
 
 
 def _mesh_over(a) -> bool:
@@ -1208,7 +1220,11 @@ def _log_once(message: str) -> None:
 # gradient of the K - 1 tokens behind it, which the next block is handed
 # and a scratch carries from tile to tile (``dz`` is made once a token); ``dw`` [B, K, C] float32 sums in
 # its output block, which stays in VMEM along the token axis. All
-# arithmetic float32, ONE rounding, at the store.
+# arithmetic float32, ONE rounding, at the store. A chain WITH A BIAS (a
+# state-space layer's, ``flat_conv_silu``) is handed one more row beside the
+# taps, [1, C] float32, added ahead of the SiLU in both kernels; its
+# gradient is one more row of ``dw``'s block. A chain that has none hands
+# no operand in and traces no arithmetic for one.
 
 _CONV_TOKENS = 1024
 _CONV_LANES = 512
@@ -1238,19 +1254,23 @@ def _unit_scale(s):
     return jax.lax.rsqrt(jnp.sum(s * s, -1, keepdims=True) + L2_EPS)
 
 
-def _conv_blocks(x_ref):
+def _conv_blocks(x_ref, d: int = _KERNEL_WIDTH):
     """(tokens a block of the loop inside a tile: ``_CONV_ROWS``, or a
     ``_CONV_HALO_BLOCK`` where a short row's tile is no whole number of
-    those; every block's first row) of a tile."""
+    those; every block's first row) of a tile. Over ``d`` lanes at a time
+    and not 128, as many fewer tokens, so that a block stays the same
+    vector registers."""
     tokens = x_ref.shape[1]
     rows = _CONV_ROWS if tokens % _CONV_ROWS == 0 else _CONV_HALO_BLOCK
+    rows = max(rows * _KERNEL_WIDTH // d, _CONV_HALO_BLOCK)
     return rows, range(0, tokens, rows)
 
 
-def _conv_fwd_kernel(x_ref, ahead_ref, w_ref, y_ref, *, norm: bool, d: int):
+def _conv_fwd_kernel(x_ref, ahead_ref, w_ref, *rest, norm: bool, d: int):
     import jax.experimental.pallas as pl
 
-    rows, starts = _conv_blocks(x_ref)
+    *bias_ref, y_ref = rest         # the bias row [1, lanes] only if given
+    rows, starts = _conv_blocks(x_ref, d)
     for at in range(0, x_ref.shape[2], d):
         cols = slice(at, at + d)
         w = w_ref[:, cols]
@@ -1259,6 +1279,8 @@ def _conv_fwd_kernel(x_ref, ahead_ref, w_ref, y_ref, *, norm: bool, d: int):
         for r0 in starts:
             here = x_ref[0, r0:r0 + rows, cols].astype(_F32)
             z, _ = _taps(jnp.concatenate([ahead, here], 0), w, rows)
+            if bias_ref:
+                z = z + bias_ref[0][:, cols]
             y = jax.nn.silu(z)
             if norm:
                 y = y * _unit_scale(y)
@@ -1266,28 +1288,33 @@ def _conv_fwd_kernel(x_ref, ahead_ref, w_ref, y_ref, *, norm: bool, d: int):
             ahead = here[rows - _CONV_HALO:]
 
 
-def _conv_bwd_kernel(x_ref, ahead_ref, w_ref, dy_ref, dx_ref, dw_ref, behind,
-                     *, norm: bool, d: int):
+def _conv_bwd_kernel(x_ref, ahead_ref, w_ref, *rest, norm: bool, d: int):
+    """``dw_ref`` [1, K, lanes], with a bias [1, K + 1, lanes]: the bias'
+    gradient, ``dz`` summed as the taps' are, is its last row."""
     import jax.experimental.pallas as pl
+
+    *bias_ref, dy_ref, dx_ref, dw_ref, behind = rest
 
     @pl.when(pl.program_id(2) == 0)
     def _zero():
         behind[...] = jnp.zeros_like(behind)
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
-    rows, starts = _conv_blocks(x_ref)
+    rows, starts = _conv_blocks(x_ref, d)
     taps = w_ref.shape[0]
     first = pl.program_id(2) == pl.num_programs(2) - 1      # of its row
     for at in range(0, x_ref.shape[2], d):
         cols = slice(at, at + d)
         w = w_ref[:, cols]
         after = behind[:, cols]
-        sums = [jnp.zeros((8, d), _F32)] * taps
+        sums = [jnp.zeros((8, d), _F32)] * dw_ref.shape[1]
         for r0 in reversed(starts):
             here = x_ref[0, r0:r0 + rows, cols].astype(_F32)
             ahead = (_halo(x_ref, r0 - _CONV_HALO_BLOCK, cols) if r0 else
                      jnp.where(first, 0.0, _halo(ahead_ref, 0, cols)))
             z, read = _taps(jnp.concatenate([ahead, here], 0), w, rows)
+            if bias_ref:
+                z = z + bias_ref[0][:, cols]
             gate = jax.nn.sigmoid(z)
             ds = dy_ref[0, r0:r0 + rows, cols].astype(_F32)
             if norm:
@@ -1302,8 +1329,9 @@ def _conv_bwd_kernel(x_ref, ahead_ref, w_ref, dy_ref, dx_ref, dw_ref, behind,
                 under[taps - 1 - j:][:rows] * w[j:j + 1]
                 for j in range(taps)).astype(dx_ref.dtype)
             # eight rows' sums a tap: whole registers added, no shuffles
+            # (the bias' row reads ones)
             sums = [total + sum((dz * x)[r:r + 8] for r in range(0, rows, 8))
-                    for total, x in zip(sums, read)]
+                    for total, x in zip(sums, read + [1.0] * len(bias_ref))]
             after = dz[:_CONV_HALO]
         behind[:, cols] = after
         dw_ref[0, :, cols] += jnp.concatenate(
@@ -1311,12 +1339,13 @@ def _conv_bwd_kernel(x_ref, ahead_ref, w_ref, dy_ref, dx_ref, dw_ref, behind,
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
-def _conv_launch(norm, d, tile, interpret, x, w, dy=None):
+def _conv_launch(norm, d, tile, interpret, x, w, bias=None, dy=None):
     """One chain's forward (``dy`` None: -> y) or backward (-> dx, dw [B,
-    K, C] float32) over (batch, lane blocks, token tiles), behind a
-    ``jax.jit`` of its own as ``_launch`` is: a step traces each variant
-    once. ``x`` [B, T, C] and ``tile`` (tokens, lanes) as ``_conv_call``
-    gives them; ``w`` [K, C] float32."""
+    K, C] float32, with a bias [B, K + 1, C]: its gradient the last row)
+    over (batch, lane blocks, token tiles), behind a ``jax.jit`` of its own
+    as ``_launch`` is: a step traces each variant once. ``x`` [B, T, C] and
+    ``tile`` (tokens, lanes) as ``_conv_call`` gives them; ``w`` [K, C],
+    ``bias`` [1, C] or None, float32."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from ray_tpu.ops.attention import _FLASH_VMEM_MOST
@@ -1330,6 +1359,8 @@ def _conv_launch(norm, d, tile, interpret, x, w, dy=None):
         (1, _CONV_HALO_BLOCK, lanes),
         lambda i, l, j: (i, jnp.maximum(at(j) * per - 1, 0), l))
     taps = pl.BlockSpec((w.shape[0], lanes), lambda i, l, j: (0, l))
+    given = [] if bias is None else [bias]
+    row = [pl.BlockSpec((1, lanes), lambda i, l, j: (0, l))] * len(given)
     like = jax.ShapeDtypeStruct(x.shape, x.dtype)
     params = dict(
         grid=(b, c // lanes, n), interpret=interpret,
@@ -1339,16 +1370,17 @@ def _conv_launch(norm, d, tile, interpret, x, w, dy=None):
     if dy is None:
         return pl.pallas_call(
             functools.partial(_conv_fwd_kernel, norm=norm, d=d),
-            in_specs=[block, ahead, taps], out_specs=block, out_shape=like,
-            **params)(x, x, w)
+            in_specs=[block, ahead, taps, *row], out_specs=block,
+            out_shape=like, **params)(x, x, w, *given)
+    sums = w.shape[0] + len(given)
     return pl.pallas_call(
         functools.partial(_conv_bwd_kernel, norm=norm, d=d),
-        in_specs=[block, ahead, taps, block],
-        out_specs=[block, pl.BlockSpec((1, w.shape[0], lanes),
+        in_specs=[block, ahead, taps, *row, block],
+        out_specs=[block, pl.BlockSpec((1, sums, lanes),
                                        lambda i, l, j: (i, 0, l))],
-        out_shape=[like, jax.ShapeDtypeStruct((b, *w.shape), _F32)],
+        out_shape=[like, jax.ShapeDtypeStruct((b, sums, c), _F32)],
         scratch_shapes=[pltpu.VMEM((_CONV_HALO, lanes), _F32)],
-        **params)(x, x, w, dy)
+        **params)(x, x, w, *given, dy)
 
 
 def _conv_tokens(t: int) -> int:
@@ -1364,44 +1396,54 @@ def _conv_tile(x) -> tuple[int, int]:
             next(n for n in (_CONV_LANES, 256, 128) if x.shape[2] % n == 0))
 
 
-def _conv_call(norm: bool, d: int, x, w, dy=None):
+def _conv_call(norm: bool, d: int, x, w, bias=None, dy=None):
     """``_conv_launch`` with the tile the operands take (``_conv_tile``),
     interpreted where the attention kernels are."""
     from ray_tpu.ops.attention import _interpret
 
-    return _conv_launch(norm, d, _conv_tile(x), _interpret(), x, w, dy)
+    return _conv_launch(norm, d, _conv_tile(x), _interpret(), x, w, bias, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _kernel_chain(x, w, norm, d):
-    """``x`` [B, T, C], T whole tiles; ``w`` [K, C] float32 -> the chain's
-    ``y`` [B, T, C] in ``x``'s dtype. The backward keeps ``x`` and ``w``
-    and makes the pre-activation again inside its one pass."""
-    return _conv_call(norm, d, x, w)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _kernel_chain(x, w, bias, norm, d):
+    """``x`` [B, T, C], T whole tiles; ``w`` [K, C], ``bias`` [1, C] or
+    None, float32 -> the chain's ``y`` [B, T, C] in ``x``'s dtype. The
+    backward keeps the operands and makes the pre-activation again inside
+    its one pass."""
+    return _conv_call(norm, d, x, w, bias)
 
 
-def _kernel_chain_fwd(x, w, norm, d):
-    return _conv_call(norm, d, x, w), (x, w)
+def _kernel_chain_fwd(x, w, bias, norm, d):
+    return _conv_call(norm, d, x, w, bias), (x, w, bias)
 
 
 def _kernel_chain_bwd(norm, d, kept, dy):
     dx, dw = _conv_call(norm, d, *kept, dy)
-    return dx, dw.sum(0)
+    dw = dw.sum(0)
+    if kept[2] is None:
+        return dx, dw, None
+    return dx, dw[:-1], dw[-1:]
 
 
 _kernel_chain.defvjp(_kernel_chain_fwd, _kernel_chain_bwd)
 
 
-def _chain_kernels(x, w, norm: bool):
+def _chain_kernels(x, w, norm: bool, bias=None):
     """``_chain`` through the kernels: T padded behind the row to whole
     tiles (zeros: a causal chain's real tokens never read them, and their
-    ``dy`` is zero), the taps viewed [K, H * d] in float32."""
+    ``dy`` is zero), the taps viewed [K, H * d] in float32 and the bias
+    [1, C]. A chain with no heads (taps [K, C]: no l2 norm, so no statistic
+    to keep inside a head) runs a grid step's lanes whole and as many fewer
+    tokens at a time (``_conv_blocks``): a quarter of the eight-row slices
+    to trace for the same arithmetic."""
     t = x.shape[1]
     pad = -t % _conv_tokens(t)
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-    y = _kernel_chain(x, w.astype(_F32).reshape(w.shape[0], -1), norm,
-                      w.shape[-1])
+    if bias is not None:
+        bias = bias.astype(_F32).reshape(1, -1)
+    y = _kernel_chain(x, w.astype(_F32).reshape(w.shape[0], -1), bias, norm,
+                      w.shape[-1] if w.ndim == 3 else _conv_tile(x)[1])
     return y[:, :t]
 
 
@@ -1420,6 +1462,17 @@ def _chain_kernels(x, w, norm: bool):
 # chains' ``dw``); what is left of the sum (batch, the eight rows, the
 # heads that share the weight) is XLA's, over a few kilobytes. All
 # arithmetic float32, ONE rounding, at each store.
+#
+# **The same two kernels are a state-space layer's gated GROUP norm**
+# (``state_space.gated_group_norm``: ``rmsnorm_group(y silu(z)) weight``)
+# where the launch is handed a ``group``: the statistic spans the group's
+# lanes (512 in Nemotron-3-Nano: a grid step's lanes hold whole groups),
+# the gate is AHEAD of the norm and the weight is [1, C], a channel each.
+# The loop then runs a group's lanes and as many fewer tokens at a time
+# (``_conv_blocks``: 32 x 512 where a head is 128 x 128, the same vector
+# registers), so the statistic stays ONE lane sum and nothing is walked
+# twice. Which of the two a call is, is a static argument of the launch:
+# a head norm's kernels hold nothing of the group norm's.
 
 
 def _gate_parts(source, rows, cols):
@@ -1436,10 +1489,10 @@ def _gate_parts(source, rows, cols):
     return x, jax.nn.sigmoid(x)
 
 
-def _norm_fwd_kernel(o_ref, *refs, eps: float, d: int):
+def _norm_fwd_kernel(o_ref, *refs, eps: float, d: int, ahead: bool):
     *source, w_ref, y_ref = refs
     silu = len(source) == 1
-    size, starts = _conv_blocks(o_ref)
+    size, starts = _conv_blocks(o_ref, d)
     w = w_ref[...]
     for at in range(0, o_ref.shape[2], d):
         cols = slice(at, at + d)
@@ -1447,12 +1500,15 @@ def _norm_fwd_kernel(o_ref, *refs, eps: float, d: int):
             rows = slice(r0, r0 + size)
             of = o_ref[0, rows, cols].astype(_F32)
             x, s = _gate_parts(source, rows, cols)
+            if ahead:
+                of = of * (x * s)
             scale = jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
-            y_ref[0, rows, cols] = (of * scale * w * (x * s if silu else s)
-                                    ).astype(y_ref.dtype)
+            y_ref[0, rows, cols] = (
+                of * scale * w[:, cols] if ahead else
+                of * scale * w * (x * s if silu else s)).astype(y_ref.dtype)
 
 
-def _norm_bwd_kernel(o_ref, *refs, eps: float, d: int):
+def _norm_bwd_kernel(o_ref, *refs, eps: float, d: int, ahead: bool):
     import jax.experimental.pallas as pl
 
     *source, w_ref, dy_ref, do_ref, dx_ref, dw_ref = refs
@@ -1462,7 +1518,7 @@ def _norm_bwd_kernel(o_ref, *refs, eps: float, d: int):
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
     silu = len(source) == 1
-    size, starts = _conv_blocks(o_ref)
+    size, starts = _conv_blocks(o_ref, d)
     w = w_ref[...]
     for at in range(0, o_ref.shape[2], d):
         cols = slice(at, at + d)
@@ -1473,29 +1529,39 @@ def _norm_bwd_kernel(o_ref, *refs, eps: float, d: int):
             x, s = _gate_parts(source, rows, cols)
             gate, slope = ((x * s, s * (1.0 + x * (1.0 - s))) if silu else
                            (s, s * (1.0 - s)))
-            scale = jax.lax.rsqrt(jnp.mean(of * of, -1, keepdims=True) + eps)
-            n = of * scale
+            normed = of * gate if ahead else of
+            scale = jax.lax.rsqrt(
+                jnp.mean(normed * normed, -1, keepdims=True) + eps)
+            n = normed * scale
             dy = dy_ref[0, rows, cols].astype(_F32)
-            by_n = dy * n                   # y = n w gate
-            dx_ref[0, rows, cols] = (by_n * w * slope).astype(dx_ref.dtype)
-            # n = o / rms(o): d_o = (dn - n mean(dn n)) / rms(o)
-            dn = dy * w * gate
-            do_ref[0, rows, cols] = (scale * (
-                dn - n * jnp.mean(dn * n, -1, keepdims=True))
-            ).astype(do_ref.dtype)
-            by_gate = by_n * gate
+            by_n = dy * n                   # y = n w gate, or y = n w
+            if not ahead:
+                dx_ref[0, rows, cols] = (by_n * w * slope).astype(dx_ref.dtype)
+            # n = v / rms(v), v what is normed: d_v = (dn - n mean(dn n)) /
+            # rms(v)
+            dn = dy * w[:, cols] if ahead else dy * w * gate
+            d_normed = scale * (dn - n * jnp.mean(dn * n, -1, keepdims=True))
+            if ahead:                       # v = o gate
+                dx_ref[0, rows, cols] = (d_normed * of * slope
+                                         ).astype(dx_ref.dtype)
+                d_normed = d_normed * gate
+            do_ref[0, rows, cols] = d_normed.astype(do_ref.dtype)
+            by_gate = by_n if ahead else by_n * gate
             total = total + sum(by_gate[r:r + 8] for r in range(0, size, 8))
         dw_ref[0, :, cols] += total
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1, 2))
-def _norm_launch(eps, tile, interpret, o, source, weight, dy=None):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _norm_launch(eps, group, tile, interpret, o, source, weight, dy=None):
     """The head norm's forward (``dy`` None: -> y) or backward (-> d_o, the
     pre-activation's gradient in the source's dtype, d_weight [B, 8, C]
     float32) over (batch, lane blocks, token tiles), behind a ``jax.jit``
     of its own as ``_conv_launch`` is. ``o`` [B, T, C] and ``tile``
     (tokens, lanes) as ``_norm_call`` gives them; ``source`` ``(z,)`` or
-    ``(low, g_b)``; ``weight`` [1, d] float32."""
+    ``(low, g_b)``; ``weight`` [1, d] float32, the heads'. **A ``group``
+    (lanes; 0: none) makes it the state-space layer's gated GROUP norm**
+    (``state_space.gated_group_norm``): the statistic over ``group`` lanes,
+    the gate AHEAD of the norm, ``weight`` [1, C], a channel each."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from ray_tpu.ops.attention import _FLASH_VMEM_MOST
@@ -1506,14 +1572,15 @@ def _norm_launch(eps, tile, interpret, o, source, weight, dy=None):
     gate = [block] if len(source) == 1 else [
         pl.BlockSpec((1, rows, source[0].shape[2]), lambda i, l, j: (i, j, 0)),
         pl.BlockSpec((source[1].shape[0], lanes), lambda i, l, j: (0, l))]
-    shared = pl.BlockSpec(weight.shape, lambda i, l, j: (0, 0))
+    shared = (pl.BlockSpec((1, lanes), lambda i, l, j: (0, l)) if group else
+              pl.BlockSpec(weight.shape, lambda i, l, j: (0, 0)))
     like = jax.ShapeDtypeStruct(o.shape, o.dtype)
     params = dict(
         grid=(b, c // lanes, t // rows), interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_FLASH_VMEM_MOST))
-    kernel = dict(eps=eps, d=weight.shape[1])
+    kernel = dict(eps=eps, d=group or weight.shape[1], ahead=bool(group))
     if dy is None:
         return pl.pallas_call(
             functools.partial(_norm_fwd_kernel, **kernel),
@@ -1529,29 +1596,30 @@ def _norm_launch(eps, tile, interpret, o, source, weight, dy=None):
         **params)(o, *source, weight, dy)
 
 
-def _norm_call(eps: float, o, source, weight, dy=None):
+def _norm_call(eps: float, group: int, o, source, weight, dy=None):
     """``_norm_launch`` with the tile the operands take, the chains'
     (``_conv_tile``), interpreted where the attention kernels are."""
     from ray_tpu.ops.attention import _interpret
 
-    return _norm_launch(eps, _conv_tile(o), _interpret(), o, source, weight,
-                        dy)
+    return _norm_launch(eps, group, _conv_tile(o), _interpret(), o, source,
+                        weight, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _kernel_head_norm(o, source, weight, eps):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _kernel_head_norm(o, source, weight, eps, group):
     """``o`` [B, T, C], T whole tiles; ``source`` ``(z,)`` or ``(low,
-    g_b)``; ``weight`` [1, d] float32 -> the gated norm [B, T, C] in
-    ``o``'s dtype. The backward keeps the operands and makes the statistic
-    and the gate again inside its one pass."""
-    return _norm_call(eps, o, source, weight)
+    g_b)``; ``weight`` [1, d] float32, or with a ``group`` [1, C]
+    (``_norm_launch``) -> the gated norm [B, T, C] in ``o``'s dtype. The
+    backward keeps the operands and makes the statistic and the gate again
+    inside its one pass."""
+    return _norm_call(eps, group, o, source, weight)
 
 
-def _kernel_head_norm_fwd(o, source, weight, eps):
-    return _norm_call(eps, o, source, weight), (o, source, weight)
+def _kernel_head_norm_fwd(o, source, weight, eps, group):
+    return _norm_call(eps, group, o, source, weight), (o, source, weight)
 
 
-def _kernel_head_norm_bwd(eps, kept, dy):
+def _kernel_head_norm_bwd(eps, group, kept, dy):
     """``dx``, the pre-activation's gradient, leaves the kernel rounded
     once: it IS ``dz``, and the low-rank map's two small matmuls, XLA's,
     read it as they read XLA's own rounding pass of the float32 one.
@@ -1560,7 +1628,7 @@ def _kernel_head_norm_bwd(eps, kept, dy):
     it first writes ``dx`` transposed, [H * dv, B T], 0.40 ms of a 0.81-ms
     backward at [1, 16384, 4096] (PERF.md section 6, PR 54)."""
     _, source, weight = kept
-    d_o, dx, dw = _norm_call(eps, *kept, dy)
+    d_o, dx, dw = _norm_call(eps, group, *kept, dy)
     d_source = (dx,) if len(source) == 1 else (
         jnp.einsum("btc,rc->btr", dx, source[1]),
         jnp.einsum("btr,btc->rc", source[0], dx,
@@ -1571,20 +1639,22 @@ def _kernel_head_norm_bwd(eps, kept, dy):
 _kernel_head_norm.defvjp(_kernel_head_norm_fwd, _kernel_head_norm_bwd)
 
 
-def _head_norm_kernels(o, source, weight, eps: float):
+def _head_norm_kernels(o, source, weight, eps: float, group: int = 0):
     """``_head_norm`` through the kernels: ``o`` viewed flat, T padded
     behind the row to whole tiles as ``_chain_kernels`` pads it (zeros: no
     token reads another, a zero ``o`` norms to zero, and their ``dy`` is
-    zero), the weight [1, dv] in float32."""
-    b, t, heads, dv = o.shape
+    zero), the weight [1, dv] in float32. With a ``group`` the state-space
+    layer's gated group norm of a flat ``o`` (``_norm_launch``), the weight
+    [1, C]."""
+    b, t = o.shape[:2]
     flat, (by_token, *rest) = o.reshape(b, t, -1), source   # z, or low
     pad = -t % _conv_tokens(t)
     if pad:
         flat, by_token = (jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
                           for a in (flat, by_token))
     y = _kernel_head_norm(flat, (by_token, *rest),
-                          weight.astype(_F32).reshape(1, dv), eps)
-    return y[:, :t].reshape(b, t, heads, dv)
+                          weight.astype(_F32).reshape(1, -1), eps, group)
+    return y[:, :t].reshape(o.shape)
 
 
 def _by_kernels(q, k, v, g, beta):

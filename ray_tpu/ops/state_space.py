@@ -52,13 +52,23 @@ both forms), nothing of a chunk's [chunk, chunk] matrices.
 The state and every sum are float32; the products take their operands in
 ``x``'s dtype (bfloat16 in a train step), as the attention kernels do.
 
-Around the scan, a state-space layer's small parts, in plain XLA under the
-scopes the linear mixers' readers read (``linear_attention.SCOPES``):
-``step_and_decay`` and ``gated_group_norm`` (``kda_gate``: ``Delta`` and
-the decay; the output's RMS norm over GROUPS of channels, wider than a
-head, the gate ahead of the norm). The layer's ONE convolution chain on
-its flat ``[x | B | C]`` projection is ``linear_attention``'s chain with a
-bias and no l2 norm (``linear_attention.flat_conv_silu``, ``kda_conv``).
+Around the scan, a state-space layer's other parts, under the scopes the
+linear mixers' readers read (``linear_attention.SCOPES``):
+``step_and_decay`` (``kda_gate``: ``Delta`` and the decay, [B, T, H]
+numbers in plain XLA) and the two passes over the layer's wide arrays,
+which on one TPU chip are the Pallas passes the KDA / Gated DeltaNet
+mixers have, on the same flat tiling the scan's kernels read and write,
+so that between the input projections and ``wo`` no [B, T, heads x
+channels] array is written in float32 or changes its tiling:
+``gated_group_norm`` (``kda_gate``: the output's RMS norm over GROUPS of
+channels, wider than a head, the gate ahead of the norm, a weight a
+channel: ``linear_attention._norm_fwd_kernel`` / ``_norm_bwd_kernel`` with
+a ``group``, where ``_norm_takes_kernels`` finds their case) and the
+layer's ONE convolution chain on its flat ``[x | B | C]`` projection,
+``linear_attention``'s chain with a bias row and no l2 norm
+(``linear_attention.flat_conv_silu``, ``kda_conv``). Each of the three ops
+asks ``linear_attention._one_tpu`` (a TPU, no mesh over the operand) and
+its own shapes; everywhere else its plain form runs.
 
 This file is one of ``models.transformer.SCOPE_FILES``: it opens
 ``ssm_carry`` (the plain form alone) and ``kda_gate``.
@@ -94,14 +104,36 @@ def gated_group_norm(y, z, weight, groups: int, *, eps: float):
     C] AHEAD of the norm, the mean square taken over each of ``groups``
     runs of ``C / groups`` channels (wider than a head), ``weight`` [C] a
     channel -> FLAT [B, T, C] in ``z``'s dtype; float32 throughout, rounded
-    once (scope ``kda_gate``)."""
+    once (scope ``kda_gate``). Where ``_norm_takes_kernels`` finds their
+    case ONE Pallas pass forward and one backward over the flat arrays
+    (the linear mixers' head norm's kernels, handed the group's lanes),
+    else ``_group_norm_plain``, which is what they are tested against."""
     with jax.named_scope("kda_gate"):
-        b, t, c = z.shape
-        gated = y.reshape(b, t, c).astype(_F32) * jax.nn.silu(z.astype(_F32))
-        by_group = gated.reshape(b, t, groups, c // groups)
-        normed = by_group * jax.lax.rsqrt(
-            jnp.mean(by_group * by_group, -1, keepdims=True) + eps)
-        return (normed.reshape(b, t, c) * weight.astype(_F32)).astype(z.dtype)
+        y, group = y.reshape(z.shape), z.shape[2] // groups
+        if _norm_takes_kernels(y, group):
+            return la._head_norm_kernels(y, (z,), weight, eps, group)
+        return _group_norm_plain(y, z, weight, groups, eps)
+
+
+def _group_norm_plain(y, z, weight, groups: int, eps: float):
+    """``gated_group_norm`` in plain XLA: the flat arrays viewed by groups
+    for the mean."""
+    b, t, c = z.shape
+    gated = y.astype(_F32) * jax.nn.silu(z.astype(_F32))
+    by_group = gated.reshape(b, t, groups, c // groups)
+    normed = by_group * jax.lax.rsqrt(
+        jnp.mean(by_group * by_group, -1, keepdims=True) + eps)
+    return (normed.reshape(b, t, c) * weight.astype(_F32)).astype(z.dtype)
+
+
+def _norm_takes_kernels(y, group: int) -> bool:
+    """Whether the norm's Pallas kernels run a call, from what can be seen
+    of it (``y`` [B, T, C] flat, ``group`` lanes a statistic): one TPU chip
+    under ``y`` (``linear_attention._one_tpu``); a group a whole number of
+    128-lane tiles that divides a grid step's lanes, so a step holds its
+    groups whole."""
+    return (group % _LANES == 0 and la._conv_tile(y)[1] % group == 0
+            and la._one_tpu(y))
 
 
 # -- the chunked scan ------------------------------------------------------------
@@ -490,11 +522,11 @@ def _takes_kernels(x, b, chunk: int) -> bool:
     were written and measured at (a chunk and a state of 128, heads that
     fill 128 lanes a whole number at a time, a group a whole number of such
     tiles and its heads' rows inside one); no mesh over the operand
-    (``linear_attention._mesh_over``). Everything else is the plain form's."""
+    (``linear_attention._one_tpu``). Everything else is the plain form's."""
     (h, p), (g, s) = x.shape[2:], b.shape[2:]
     return (chunk == s == _LANES and _LANES % p == 0
             and (h // g * p) % _LANES == 0 and h // g <= _LANES
-            and jax.devices()[0].platform == "tpu" and not la._mesh_over(x))
+            and la._one_tpu(x))
 
 
 def ssm_scan(x, dt, a, b, c, skip, *, chunk: int):

@@ -8,7 +8,9 @@ bfloat16). Backward: ``dx`` and ``dw`` against ``jax.grad`` of ``_chain``.
 The token tiles' edges: a row that is no whole number of tiles, a row shorter
 than one, the halo (what a tile reads of the tile ahead of it, forward, and
 of the tile behind it, backward), and batch rows that must not leak into each
-other. Nothing here is a speed.
+other. The chain WITH A BIAS row (a state-space layer's, ``flat_conv_silu``)
+against ``_chain(x, w, False, bias)`` at one lane-whole shape: value, ``dx``,
+``dw``, ``d_bias``, the halo both ways, and the rule. Nothing here is a speed.
 """
 
 from __future__ import annotations
@@ -222,3 +224,111 @@ def test_the_cells_own_tiles_give_the_same(norm):
     for got, want in ((dx, ref_dx), (dw, ref_dw)):
         assert float(jnp.abs(got - want).max()) <= 2e-6 * float(
             jnp.abs(want).max())
+
+
+# -- the chain with a bias row: a state-space layer's (``flat_conv_silu``) ----
+
+def _bias_case(dtype, t: int = 2 * TILE + 13, c: int = 3 * D, seed: int = 11):
+    """``[K, C]`` taps (no heads) and a bias at ONE lane-whole shape: two
+    tiles and 13 tokens of three 128-lane tiles."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.normal(ks[0], (2, t, c)).astype(dtype)
+    w = jax.random.uniform(ks[1], (4, c), minval=-0.5, maxval=0.5)
+    bias = jax.random.uniform(ks[2], (c,), minval=-0.5, maxval=0.5)
+    weight = jax.random.normal(ks[3], (2, t, c)).astype(jnp.bfloat16)
+    return x, w, bias, weight.astype(F32)
+
+
+@pytest.mark.parametrize("dtype", [F32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_chain_with_a_bias_is_the_plain_chain_and_its_gradients(dtype):
+    """Value, ``dx``, ``dw`` and ``d_bias`` against ``_chain(x, w, False,
+    bias)``: the forward bit-equal from float32 and within an ulp from
+    bfloat16, the gradients as ``test_dx_and_dw_are_the_chains_gradients``
+    holds the chains without one; ``d_bias`` float32 in the bias' shape."""
+    x, w, bias, weight = _bias_case(dtype)
+    fused, chain = (jax.jit(lambda *a, f=f: f(a[0], a[1], False, a[2]))(
+        x, w, bias) for f in (la._chain_kernels, la._chain))
+    assert (fused.shape, fused.dtype) == (x.shape, x.dtype)
+    if dtype == F32:
+        assert float(jnp.abs(fused - chain).max()) == 0.0
+    assert bool((jnp.abs(fused.astype(F32) - chain.astype(F32))
+                 <= _ulp(chain)).all())
+    # the bias is felt: the chain without it is another
+    assert float(jnp.abs(chain.astype(F32) - plain_chain(x, w, False).astype(
+        F32)).max()) > 0.05
+    loss = lambda f: lambda x, w, b: (  # noqa: E731
+        f(x, w, False, b).astype(F32) * weight).sum()
+    dx, dw, db = jax.jit(jax.grad(loss(la._chain_kernels), (0, 1, 2)))(
+        x, w, bias)
+    ref_dx, ref_dw, ref_db = jax.jit(jax.grad(loss(la._chain), (0, 1, 2)))(
+        x.astype(F32), w, bias)
+    assert (dx.shape, dx.dtype) == (x.shape, x.dtype)
+    assert (dw.shape, dw.dtype) == (w.shape, F32)
+    assert (db.shape, db.dtype) == (bias.shape, F32)
+    near = 2e-6 if dtype == F32 else 2e-2       # y's rounding is in the sums
+    for got, want in ((dw, ref_dw), (db, ref_db)):
+        assert float(jnp.abs(got - want).max()) <= near * float(
+            jnp.abs(want).max())
+    if dtype == F32:
+        assert float(jnp.abs(dx - ref_dx).max()) <= 2e-6 * float(
+            jnp.abs(ref_dx).max())
+    else:
+        assert bool((jnp.abs(dx.astype(F32) - ref_dx)
+                     <= _ulp(ref_dx.astype(dtype)) + 1e-6).all())
+
+
+def test_the_halo_with_a_bias_forward_and_backward():
+    """An impulse at a tile's last row reaches the next tile's first K - 1
+    rows THROUGH the bias (every row reads ``silu(bias)`` at least, so it is
+    the difference from the chain of zeros that is confined); a gradient at
+    a tile's first row reaches the K - 1 rows ahead of it, and ``d_bias``
+    is that row's ``dz`` alone."""
+    taps, c = 4, 2 * D
+    _, w, bias, _ = _bias_case(F32, c=c)
+    fused = jax.jit(lambda x: la._chain_kernels(x, w, False, bias))
+    plain = jax.jit(lambda x: la._chain(x, w, False, bias))
+    at = TILE - 1
+    zeros = jnp.zeros((2, 3 * TILE, c), F32)
+    x = zeros.at[0, at].set(1.0)
+    moved = np.abs(np.asarray(fused(x) - fused(zeros))).max(-1) > 0
+    assert moved[0].nonzero()[0].tolist() == list(range(at, at + taps))
+    assert not moved[1].any()
+    assert float(jnp.abs(fused(x) - plain(x)).max()) == 0.0
+    # every row of the chain of zeros is silu(bias): the bias reached them
+    assert float(jnp.abs(fused(zeros) - jax.nn.silu(bias)).max()) == 0.0
+    x = jax.random.normal(jax.random.PRNGKey(5), zeros.shape)
+    at = 2 * TILE
+    dy = zeros.at[1, at].set(1.0)
+    back = lambda chain: jax.vjp(  # noqa: E731
+        lambda x, b: chain(x, w, False, b), x, bias)[1](dy)
+    (dx, db), (ref_dx, ref_db) = back(la._chain_kernels), back(la._chain)
+    touched = np.abs(np.asarray(dx)).max(-1) > 0
+    assert touched[1].nonzero()[0].tolist() == list(
+        range(at - taps + 1, at + 1))
+    assert not touched[0].any()
+    for got, want in ((dx, ref_dx), (db, ref_db)):
+        assert float(jnp.abs(got - want).max()) <= 2e-6 * float(
+            jnp.abs(want).max())
+    assert float(jnp.abs(db).max()) > 0
+
+
+@pytest.mark.parametrize("taken", [True, False], ids=["one_tpu", "elsewhere"])
+def test_flat_conv_silu_asks_one_tpu_and_its_own_shapes(taken):
+    """``flat_conv_silu`` takes the kernels on one TPU chip at whole 128-lane
+    tiles and taps the halo holds; a CPU, 96 lanes over a tile or nine taps
+    take the plain chain. Either way the same flat array."""
+    x, w, bias, _ = _bias_case(F32, t=40)
+    ran = []
+    run = lambda name, f: lambda *a: ran.append(name) or f(*a)  # noqa: E731
+    with mock.patch.object(la, "_one_tpu", lambda a: taken), \
+            mock.patch.object(la, "_chain_kernels",
+                              run("kernels", la._chain_kernels)), \
+            mock.patch.object(la, "_chain", run("plain", la._chain)):
+        got = jax.jit(lambda *a: la.flat_conv_silu(*a))(x, w, bias)  # anew
+        assert ran == ["kernels" if taken else "plain"]
+        la.flat_conv_silu(x[..., :D + 96], w[:, :D + 96], bias[:D + 96])
+        la.flat_conv_silu(x, jnp.tile(w, (3, 1))[:la._CONV_HALO + 2], bias)
+        la.flat_conv_silu(x, w)                     # no bias: the same rule
+    assert ran[1:] == ["plain", "plain", "kernels" if taken else "plain"]
+    assert float(jnp.abs(got - plain_chain(x, w, False, bias)).max()) == 0.0

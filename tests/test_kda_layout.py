@@ -21,7 +21,11 @@ PR 49's projections and ``conv_silu`` by heads: ``_mixer_by_heads``), so it
 sees the fault (268 MB float32 or 134 MB bfloat16 crossing between two
 tilings: PERF.md section 6, PR 40, PR 43 and PR 49). The same described
 device compiles the state-space scan's two kernels
-(``ray_tpu/ops/state_space.py``) at Nemotron-3-Nano's widths (PR 59).
+(``ray_tpu/ops/state_space.py``) at Nemotron-3-Nano's widths (PR 59), and
+``_ssm_mixer`` whole (PR 61): the chain with a bias row and the gated group
+norm over 512-lane groups are Pallas calls, three each beside the scan's
+three, and no array of ``y``'s or ``[x | B | C]``'s size is relaid or written
+in float32 under their scopes, which the parent's plain group norm fails.
 
 Nothing here is a speed. The topology is described inside a module-scoped
 fixture, never at import (the on-chip-measurement guide).
@@ -347,10 +351,11 @@ _INSTRUCTION = re.compile(
     r"= (?:f32|bf16)\[([\d,]+)\]\S* (copy|transpose|reshape)\(")
 
 
-def _relayouts(hlo: str) -> list[str]:
+def _relayouts(hlo: str, sizes=(B * T * HEADS * DK,)) -> list[str]:
     """Every ``copy``, ``transpose`` and ``reshape`` (one that is no
     bitcast stays a ``reshape`` in optimised HLO) whose result is float32
-    or bfloat16 with ``B * T * H * dk`` elements, inside fusions too,
+    or bfloat16 with ``B * T * H * dk`` elements (``sizes``), inside fusions
+    too,
     under ANY scope or none: the projections' (``attn_qkv``), the
     convolutions' (``kda_conv``), the rule's (``attn_core``), the gates'
     and the head norm's (``kda_gate``: PR 43's contract, which the
@@ -358,8 +363,7 @@ def _relayouts(hlo: str) -> list[str]:
     found = []
     for line in hlo.splitlines():
         m = _INSTRUCTION.search(line)
-        if m and math.prod(map(int, m.group(1).split(","))) == (
-                B * T * HEADS * DK):
+        if m and math.prod(map(int, m.group(1).split(","))) in sizes:
             found.append(line.strip()[:400])
     return found
 
@@ -375,19 +379,21 @@ _RESULT = re.compile(r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) "
                      r"(?:fusion|custom-call|convolution)\(")
 
 
-def _float32_written_under(hlo: str, scope: str) -> list[str]:
+def _float32_written_under(hlo: str, scope: str,
+                           sizes=(B * T * HEADS * DK,)) -> list[str]:
     """The instructions OUTSIDE fused computations (a fusion, a kernel, a
     matmul: what writes its result to memory) under ``scope`` with a
-    float32 result of ``B * T * H * dk`` elements, tuples' parts too."""
+    float32 result of ``B * T * H * dk`` elements (``sizes``), tuples'
+    parts too."""
     found, fused = [], False
     for line in hlo.splitlines():
         if line[:1] in "%E":                    # a computation opens
             fused = line.startswith("%fused_computation")
         m = None if fused else _RESULT.match(line)
         if m and f"/{scope}/" in line.replace(f"({scope})", f"/{scope}/") \
-                and any(math.prod(map(int, dims.split(","))) == (
-                    B * T * HEADS * DK)
-                    for dims in re.findall(r"f32\[([\d,]+)\]", m.group(1))):
+                and any(math.prod(map(int, dims.split(","))) in sizes
+                        for dims in re.findall(r"f32\[([\d,]+)\]",
+                                               m.group(1))):
             found.append(line.strip()[:400])
     return found
 
@@ -447,3 +453,62 @@ def test_the_state_space_scans_kernels_compile_at_the_cells_widths(chip):
         text = ss._launch.lower(backward, not backward, False,
                                 *operands).compile().as_text()
         assert "tpu_custom_call" in text
+
+
+# -- (c) the state-space mixer, compiled for the same described device ----------
+
+SSM_SIZES = (B * T * 4096, B * T * 6144)    # y / z; the chain's [x | B | C]
+
+
+def _ssm_hlo(device) -> str:
+    """``_mixer_hlo`` for ``_ssm_mixer`` at Nemotron-3-Nano's widths (64
+    heads of 64 in 8 groups of 512 lanes, state 128, four taps and a bias
+    over 6,144 lanes): forward, recompute and backward of a layer."""
+    c = transformer.nemotron_3_nano_30b_a3b(n_layers=1)
+    assert c.layer_mixers == ("ssm",) and c.ssm_conv_bias
+    assert (c.kda_heads * c.kda_head_dim, c.ssm_groups) == (4096, 8)
+    one = jax.sharding.SingleDeviceSharding(device)
+    w = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype, sharding=one),
+        c.shapes()["layers"]["ssm"])
+    assert w["conv_w"].shape == (4, 6144) and w["o_norm"].shape == (4096,)
+    h = jax.ShapeDtypeStruct((B, T, c.d_model), c.compute_dtype, sharding=one)
+
+    @jax.checkpoint
+    def layer(h, w):
+        o, counters = mixers._ssm_mixer(h, w, c)
+        return jnp.square(o.astype(F32)).sum() + sum(counters.values())
+
+    with mock.patch.object(jax, "devices", lambda *a, **k: [device]):
+        lowered = jax.jit(jax.value_and_grad(layer, argnums=(0, 1))).lower(
+            h, w)
+    return lowered.compile().as_text()
+
+
+def test_the_state_space_mixer_stays_flat_between_its_kernels(chip):
+    """Mosaic takes the chain with a bias row and the norm with a 512-lane
+    group; a layer is three Pallas calls each for the chain, the scan and
+    the norm (forward, recomputed, backward); under ``kda_conv`` and
+    ``kda_gate`` no array of ``y``'s or ``[x | B | C]``'s size changes its
+    tiling, and under ``kda_gate`` none is written in float32."""
+    hlo = _ssm_hlo(chip)
+    assert [_kernels_under(hlo, scope) for scope
+            in ("kda_conv", "attn_core", "kda_gate")] == [3, 3, 3]
+    assert [line for line in _relayouts(hlo, SSM_SIZES)
+            if "kda_gate" in line or "kda_conv" in line] == []
+    assert _float32_written_under(hlo, "kda_gate", SSM_SIZES) == []
+
+
+def test_the_parents_group_norm_does_relayout_and_the_assertion_sees_it(chip):
+    """``gated_group_norm``'s plain body where the kernels were (PR 59's
+    tree): the norm's ``[T, 8, 512]`` view of the scan's flat ``y`` is
+    another tiling, float32 arrays of ``y``'s size cross under
+    ``kda_gate``, and no kernel runs there."""
+    from ray_tpu.ops import state_space as ss
+
+    with mock.patch.object(ss, "_norm_takes_kernels", lambda *a: False):
+        hlo = _ssm_hlo(chip)
+    assert _kernels_under(hlo, "kda_gate") == 0
+    assert _kernels_under(hlo, "kda_conv") == 3
+    assert [line for line in _relayouts(hlo, SSM_SIZES)
+            if "kda_gate" in line and "f32[" in line]

@@ -3,9 +3,11 @@
 lane-whole shape (one group of two 64-wide heads, state 128, chunk 128, a
 row of 300 that is padded): against the token-by-token recurrence forward
 and in all six gradients, under mild decays and under ones whose ``exp(-G)``
-overflows float32; what the forward keeps; the rule that chooses between
-the kernels and the plain form; and the scopes the ``pallas_call``s are
-traced under in a model's gradient."""
+overflows float32; what the forward keeps; the rules that choose between
+the kernels and the plain forms, the scan's, the gated group norm's and the
+chain's (one question, ``linear_attention._one_tpu``, and each op's own
+shapes); and the scopes the layer's nine ``pallas_call``s are traced under
+in a model's gradient."""
 
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ray_tpu import models
+from ray_tpu.ops import linear_attention as la
 from ray_tpu.ops import state_space as ss
 from test_state_space import _step_by_step
 
@@ -101,6 +104,32 @@ def test_one_rule_sends_a_cpu_a_mesh_and_an_odd_width_to_the_plain_form(
     # the cell's own: 64 heads of 64 in 8 groups
     assert ss._takes_kernels(jnp.zeros((1, 8, 64, 64)),
                              jnp.zeros((1, 8, 8, 128)), CHUNK)
+    # the norm's own shapes, under the same question (``la._one_tpu``): a
+    # group whole 128-lane tiles that divides a grid step's 512 lanes
+    flat = jnp.zeros((1, 8, 4096))
+    assert ss._norm_takes_kernels(flat, 512)                # the cell's own
+    assert ss._norm_takes_kernels(flat, 128)
+    assert not ss._norm_takes_kernels(flat, 1024)           # over a step
+    assert not ss._norm_takes_kernels(flat[..., :768], 96)  # a group of 96
+    assert not ss._norm_takes_kernels(flat[..., :768], 384)  # a 256 step
+    assert not ss._norm_takes_kernels(jax.device_put(flat, spread.sharding),
+                                      512)                  # a mesh
+    # and the chain's: whole 128-lane tiles, taps the halo holds
+    ran = []
+    monkeypatch.setattr(la, "_chain_kernels",
+                        lambda *a: ran.append(a[1].shape))
+    monkeypatch.setattr(la, "_chain", lambda *a: None)
+    wide, taps = jnp.zeros((1, 8, 6144)), jnp.zeros((4, 6144))
+    la.flat_conv_silu(wide, taps, taps[0])                  # the cell's own
+    la.flat_conv_silu(wide[..., :96], taps[:, :96])         # 96 lanes
+    # nine taps are the most the halo's eight rows hold: ten
+    la.flat_conv_silu(wide, jnp.zeros((la._CONV_HALO + 2, 6144)))
+    la.flat_conv_silu(jax.device_put(wide, spread.sharding), taps)  # a mesh
+    assert ran == [(4, 6144)]
+    monkeypatch.undo()
+    assert not ss._norm_takes_kernels(flat, 512)            # a CPU
+    monkeypatch.setattr(la, "_chain_kernels", None)         # never reached
+    assert la.flat_conv_silu(wide, taps, taps[0]).shape == wide.shape
 
 
 def _pallas_calls(jaxpr, under=""):
@@ -124,8 +153,10 @@ def test_the_kernels_keep_the_scopes_the_readers_read(monkeypatch):
     """``step_kda_core_ms``, ``kda_core_peak_share`` and
     ``step_attn_kernel_ms`` read what runs under ``attn`` / ``attn_linear``
     / ``attn_core``: the forward's kernel, the recomputed forward's and
-    the backward's."""
-    monkeypatch.setattr(ss, "_takes_kernels", lambda *a: True)
+    the backward's. The chain's three run under ``kda_conv``
+    (``step_kda_conv_ms``) and the gated group norm's three under
+    ``kda_gate`` (``step_kda_gate_ms``), all nine under ``attn_linear``."""
+    monkeypatch.setattr(la, "_one_tpu", lambda a: True)    # the ONE question
     cfg = models.nemotron_3_nano_30b_a3b(
         n_layers=1, d_model=128, n_heads=H, n_kv_heads=H, d_head=P,
         kda_heads=H, kda_head_dim=P, ssm_state=S, ssm_groups=G,
@@ -138,9 +169,20 @@ def test_the_kernels_keep_the_scopes_the_readers_read(monkeypatch):
     calls = _pallas_calls(jax.make_jaxpr(jax.grad(
         lambda p, r: models.lm_loss(p, {"tokens": r}, cfg)[0]))(
             params, rows).jaxpr)
-    assert len(calls) == 3
-    assert all("attn/attn_linear/attn_core" in under for under, _ in calls)
+    assert len(calls) == 9
+    assert all("attn/attn_linear/" in under for under, _ in calls)
     assert "ssm_carry" not in "".join(under for under, _ in calls)
+    by_scope = {scope: [c for c in calls if f"attn_linear/{scope}" in c[0]]
+                for scope in ("kda_conv", "attn_core", "kda_gate")}
+    assert [len(found) for found in by_scope.values()] == [3, 3, 3]
+    flat, wide = (1, CHUNK, H * P), (1, CHUNK, H * P + 2 * G * S)
+    # the chain: y, y again, then dx with the taps' and the bias' sums
+    assert [shapes for _, shapes in by_scope["kda_conv"]] == [
+        [wide], [wide], [wide, (1, 4 + 1, wide[2])]]
+    # the norm: y, y again, then d_y, d_z and the weight's eight-row sums
+    assert [shapes for _, shapes in by_scope["kda_gate"]] == [
+        [flat], [flat], [flat, flat, (1, 8, H * P)]]
+    calls = by_scope["attn_core"]
     states, rows_out = (1, 1, S, H * P), (1, G, 1, H // G, CHUNK)
     assert [shapes[1] for _, shapes in calls] == [states, states, rows_out]
     assert sum("transpose" in under for under, _ in calls) == 2

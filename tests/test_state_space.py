@@ -2,9 +2,11 @@
 against the token-by-token recurrence, forward and backward, at a length
 that is no whole number of chunks and with decays under which a naive
 ``exp(-G)`` overflows float32; the convolution chain with its bias and the
-gated group norm against their plain forms; and what ``ops/moe.py`` gained
-for experts WITHOUT a gate projection (``relu2``, ``_held_block`` and the
-dropless path on two grouped matmuls, the lane-whole padding)."""
+gated group norm against their plain forms, and the norm's Pallas kernels
+(interpreted, two groups of 256 lanes) against the plain norm; and what
+``ops/moe.py`` gained for experts WITHOUT a gate projection (``relu2``,
+``_held_block`` and the dropless path on two grouped matmuls, the
+lane-whole padding)."""
 
 from __future__ import annotations
 
@@ -129,6 +131,48 @@ def test_gated_group_norm_is_its_plain_form_and_not_a_heads_norm():
     one_group = ss.gated_group_norm(y, z, w, 1, eps=1e-5)
     assert float(jnp.abs(got - by_head).max()) > 0.05
     assert float(jnp.abs(got - one_group).max()) > 0.05
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_group_norms_kernels_are_the_plain_form(dtype, monkeypatch):
+    """The linear mixers' norm kernels handed a GROUP (interpreted, ONE
+    lane-whole shape: two groups of 256 lanes, a row of 77 that is padded to
+    three 32-token tiles, so ``d_weight`` sums over grid steps) against
+    ``_group_norm_plain``, today's body: value, ``d_y``, ``d_z``,
+    ``d_weight``, each in its operand's shape and dtype; and what they
+    compute is neither a heads' norm nor a one-group norm."""
+    monkeypatch.setattr(la, "_CONV_TOKENS", 32)
+    k = jax.random.split(jax.random.PRNGKey(2), 4)
+    y = jax.random.normal(k[0], (2, 77, 8, 64)).astype(dtype)   # 8 heads of 64
+    z = jax.random.normal(k[1], (2, 77, 512)).astype(dtype)
+    w = 1 + 0.1 * jax.random.normal(k[2], (512,))
+    weight = jax.random.normal(k[3], z.shape)
+
+    def both(groups):
+        return jax.jit(jax.value_and_grad(
+            lambda y, z, w: (ss.gated_group_norm(
+                y, z, w, groups, eps=1e-5).astype(jnp.float32) * weight).sum(),
+            (0, 1, 2)))(y, z, w)
+
+    plain = both(2)
+    monkeypatch.setattr(la, "_one_tpu", lambda a: True)
+    monkeypatch.setattr(ss, "_group_norm_plain", None)          # never reached
+    assert ss._norm_takes_kernels(z, 256)
+    fused = both(2)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    for a, b in zip(jax.tree.leaves(fused), jax.tree.leaves(plain)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert float(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)
+                             ).max()) <= tol * (1 + float(jnp.abs(b).max()))
+    assert fused[1][0].shape == y.shape and fused[1][2].shape == w.shape
+    out = ss.gated_group_norm(y, z, w, 2, eps=1e-5)
+    assert out.shape == z.shape and out.dtype == dtype
+    # four groups of 128 lanes and one of 512 are other norms (both kernels')
+    for groups in (4, 1):
+        other = ss.gated_group_norm(y, z, w, groups, eps=1e-5)
+        assert float(jnp.abs(out.astype(jnp.float32)
+                             - other.astype(jnp.float32)).max()) > 0.05
 
 
 def test_step_and_decay_and_the_counter_by_another_chunk():
