@@ -31,6 +31,7 @@ from ray_tpu.models.transformer import (
     nemotron_3_nano_30b_a3b,
     olmoe_1b_7b,
     partition_specs,
+    phi4_mini_flash_reasoning,
     qwen2_7b,
     qwen3_next_80b_a3b,
     smallthinker_21b_a3b,
@@ -62,6 +63,7 @@ __all__ = [
     "nemotron_3_nano_30b_a3b",
     "trinity_mini_26b_a3b",
     "qwen3_next_80b_a3b",
+    "phi4_mini_flash_reasoning",
     "llama2_7b",
     "llama3_8b",
     "lm_loss",

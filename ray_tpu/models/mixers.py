@@ -3,23 +3,40 @@
 ``transformer._block`` knows a mixer as a ``Mixer`` and nothing else: where
 its own leaves live, how they are drawn and sharded, what a configuration
 has to give it, the scope it runs under, the function from a block's
-normed input to what ``attn/wo`` projects, the statistics it reports
-(``Counter``), why the KV-cache decode refuses it. ``layer_mixers`` names a
-layer's mixer by its key; a model without it runs ``"attn"`` everywhere.
+normed input to what ``attn/wo`` projects (or, ``own_out``, to what joins
+the stream: an inner width of its own, its output projection among its own
+leaves), the memory it hands later layers or reads from an earlier one
+(``writes`` / ``reads``), the statistics it reports (``Counter``), why the
+KV-cache decode refuses it. ``layer_mixers`` names a layer's mixer by its
+key; a model without it runs ``"attn"`` everywhere.
 
   - ``"attn"``: softmax attention, plain (``_plain_qkv``: fused or GQA
     projections, QK-norm of all heads together or a head, RoPE on a head's
     whole width or its first ``rope_fraction``, an output gate
-    ``sigmoid(W_g h)``) or, with ``kv_latent``, latent (``_latent_qkv``).
+    ``sigmoid(W_g h)``) or, with ``kv_latent``, latent (``_latent_qkv``),
+    or, with ``diff_attn``, DIFFERENTIAL (``_diff_core``: two softmax
+    maps a pair of heads, ``a_1 - lambda a_2``, a norm a pair).
   - ``"kda"``, ``"gdn"``, ``"ssm"``, the LINEAR ones (a state carried along
     the sequence, under ``attn_linear``): Kimi Delta Attention
     (``_kda_mixer``), Gated DeltaNet (``_gdn_mixer``), a Mamba-2
-    state-space layer (``_ssm_mixer``).
+    state-space layer (``_ssm_mixer``), a Mamba-1 selective-scan layer
+    (``"ssm1"``, ``_ssm1_mixer``: 2 x the stream wide, its own ``wo``).
+  - ``"gmu"`` and ``"cross"``, the READERS of a memory (no state of their
+    own): a gated memory unit ``W_2 (m * silu(W_1 h))`` on the scan output
+    ``m`` the last ``"ssm1"`` layer ahead of it handed out, and differential
+    cross-attention whose keys and values are the last ``"attn"`` layer's.
 
 **To add a mixer**: its record here, its line in ``MIXERS``, its fields in
 ``TransformerConfig``; nothing in ``transformer.py``'s block, loss, init or
-specs (``tests/test_mixers.py`` registers a fifth from outside and trains
-it). This file opens scopes of the step: one of ``transformer.SCOPE_FILES``.
+specs (``tests/test_mixers.py`` registers one more from outside and trains
+it). A record's ``apply`` returns ``(o, counters)``: ``o`` is what
+``attn/wo``, ONE stack over every layer whose mixer has attention's inner
+width, projects; a record with ``own_out`` keeps its output projection
+among its own leaves and returns what joins the stream. A record that
+``writes`` returns a third value, {its memory's name: the arrays}: the
+stack keeps it from the last such layer ahead of the first layer whose
+record ``reads`` that name, and hands it to every reader in ``ctx.memory``.
+This file opens scopes of the step: one of ``transformer.SCOPE_FILES``.
 """
 
 from __future__ import annotations
@@ -51,6 +68,13 @@ ATTN_SCOPES = ("attn_full", "attn_window", "attn_linear")
 # parts). The output gate: its projection, the sigmoid, the product.
 MLA_SCOPE = "mla_latent"
 GATE_SCOPE = "attn_gate"
+# Differential attention outside the kernels (the pairs laid out, the
+# ``lambda``s, ``a_1 - lambda a_2``, the pair norm, the scale); everything
+# of a cross layer's mixer but ``attn_out`` (inside ``attn_full``); a gated
+# memory unit's two projections and product (inside ``attn_linear``).
+DIFF_SCOPE = "attn_diff"
+CROSS_SCOPE = "attn_cross"
+GMU_SCOPE = "gmu"
 # What attention does, part by part: ``attn_qkv`` the projections (latent
 # attention: the query's alone, the latent's are ``mla_latent``),
 # ``attn_pos`` QK-norm and RoPE, ``attn_gqa`` k and v repeated to the query
@@ -85,16 +109,24 @@ class Counter(NamedTuple):
 GATE_MEAN = Counter("gate_mean", "attn_gate_mean", "kind mean")
 LOG_DECAY_MIN = Counter("log_decay_min", "kda_log_decay_min", "min")
 STEP_MEAN = Counter("step_mean", "ssm_step_mean", "kind mean")
+# differential attention's ``lambda``, a mean over the attention and cross
+# layers; a memory unit's gate ``silu(W_1 h)``, a mean over its layers
+DIFF_LAMBDA = Counter("diff_lambda", "attn_diff_lambda", "kind mean")
+GMU_GATE_MEAN = Counter("gmu_gate_mean", "gmu_gate_mean", "kind mean")
 
 
 class Ctx(NamedTuple):
     """What of a layer only attention reads: (cos, sin) or None where
     nothing is rotated, the tokens' positions, the sliding window's width
-    or None, the mesh's sharding constraint."""
+    or None, the mesh's sharding constraint; ``memory``: {name: what an
+    earlier layer handed out} for a record that ``reads``; ``layer``: the
+    layer's published number (a traced scalar) where a record asks it."""
     rope: Any
     positions: Any
     window: int | None
     con: Callable
+    memory: Any = None
+    layer: Any = None
 
 
 class Draw(NamedTuple):
@@ -119,10 +151,15 @@ class Mixer(NamedTuple):
     # (words, wrong) of what ``layer_mixers`` does not run with)
     check: Callable
     # (h [B, T, D] normed, its own leaves, c, ctx) -> (what ``attn/wo``
-    # projects, {a counter's key: its value})
+    # projects, {a counter's key: its value}[, {``writes``: the memory}])
     apply: Callable
     counters: Callable  # c -> the counters a layer of this kind reports
     decodes: Callable | None    # c -> why decode refuses it; None: it runs
+    # ``apply`` returns what JOINS THE STREAM [B, T, D]: the record's inner
+    # width is its own and so is its output projection (no ``attn/wo`` row)
+    own_out: bool = False
+    reads: str | None = None    # the memory ``apply`` finds in ``ctx.memory``
+    writes: str | None = None   # the memory ``apply`` hands out (third value)
 
 
 def counters_of(c) -> dict:
@@ -148,17 +185,31 @@ def _attn_init(c, keys, n, draw):
     """Attention's leaves ``wq``, ``wk``, ``wv``, ``wo`` (with ``qk_norm``
     ``q_norm`` / ``k_norm``, a head's or all heads'; with ``attn_gate``
     ``wg``, plain attention's fused q-and-gate projection as two leaves);
+    with ``attn_bias`` ``bq``, ``bk``, ``bv``, ``bo``, N(0, 0.02) like a
+    matrix: a program that dropped a zero bias would compute what a sound
+    one does; with ``diff_attn`` ``_diff_leaves``);
     latent attention's ``wq``, ``wkv_a`` ([latent ; the one rotary key]
     down), ``kv_norm`` and ``wkv_b`` ([k_nope ; v] of every head up)."""
     norm, unit = draw.norm, draw.unit
     D, H, KV, Dh = c.d_model, c.n_heads, c.kv_heads, c.head_dim
     if c.kv_latent is None:
+        first = next(keys)
         stack = {
-            "wq": norm(next(keys), n, D, H, Dh),
+            "wq": norm(first, n, D, H, Dh),
             "wk": norm(next(keys), n, D, KV, Dh),
             "wv": norm(next(keys), n, D, KV, Dh),
             "wo": norm(next(keys), n, H, Dh, D, s=draw.res_std),
         }
+        # what ``attn_bias`` and ``diff_attn`` add draws from keys of its
+        # own, so every other model's weights stay what the seed gave
+        more = iter(jax.random.split(jax.random.fold_in(first, 1), 8))
+        if c.attn_bias:
+            stack.update(bq=norm(next(more), n, H, Dh),
+                         bk=norm(next(more), n, KV, Dh),
+                         bv=norm(next(more), n, KV, Dh),
+                         bo=norm(next(more), n, D))
+        if c.diff_attn:
+            stack.update(_diff_leaves(c, more, n, draw))
         if c.qk_norm:
             per_head = c.qk_norm == "head"
             stack["q_norm"] = unit(n, Dh if per_head else H * Dh)
@@ -197,6 +248,15 @@ def _attention(h, w, c, ctx: Ctx):
     """Softmax attention up to (not with) the output projection: the
     operands (``_plain_qkv`` / ``_latent_qkv``), the ONE ``attention(...)``
     call under ``attn_core``, the output gate."""
+    if c.diff_attn:
+        q1, q2 = _diff_queries(h, w, c)
+        with jax.named_scope("attn_qkv"):
+            k1, k2 = (_project(h, wk, bk, c) for wk, bk in zip(
+                _halves(w["wk"]), _halves(w.get("bk"))))
+            v = _project(h, _side_by_side(w["wv"]),
+                         _side_by_side(w.get("bv")), c)
+        o, counters = _diff_core(q1, q2, k1, k2, v, w, c, ctx)
+        return o, counters, {"kv": (k1, k2, v)}
     if c.kv_latent is not None:
         q, k, v, shared = _latent_qkv(h, w, c, ctx.rope, ctx.positions)
     else:
@@ -222,6 +282,88 @@ def _gate_output(o, h, wg):
             "btd,dhk->bthk", h, wg.astype(h.dtype),
             preferred_element_type=jnp.float32))
         return (o * gate).astype(o.dtype), jax.lax.stop_gradient(gate).mean()
+
+
+# Differential attention (arXiv:2410.05258, as Phi-4-mini-flash's
+# ``FlashDiffCustomAttention`` has it). The heads PAIR UP, pair ``n`` heads
+# ``2 n`` and ``2 n + 1`` (interleaved: the implementation's ``reshape(..,
+# heads // 2, 2, head_dim)``): H / 2 query pairs ``(q1, q2)``, KV / 2 key
+# pairs ``(k1, k2)``, KV / 2 values 2 x head_dim wide (a pair's two value
+# heads side by side); query pair ``n`` reads key / value pair ``n // (H /
+# KV)``. ``a_i = softmax(q_i k_i^T / sqrt(head_dim), causal[, window]) v``,
+# ``lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init(l)``,
+# ``lambda_init(l) = 0.8 - 0.6 exp(-0.3 l)`` by the layer's PUBLISHED
+# number, ``o = (1 - lambda_init(l)) rmsnorm(a_1 - lambda a_2) w_sub`` over a
+# pair's 2 x head_dim channels. The WEIGHTS are split by the pair, never
+# the activations (``_latent_qkv`` says why): each projection's own output
+# is what a kernel reads. Two ``attention(...)`` calls a layer, head_dim-
+# wide scores against ONE 2 x head_dim-wide value: no kernel of its own.
+
+def _diff_leaves(c, keys, n, draw):
+    """The four ``lambda`` vectors N(0, 0.1) as ONE leaf ``lambdas`` [4,
+    head_dim] (``lq1``, ``lk1``, ``lq2``, ``lk2``: a vector's elements all
+    move by one scalar's gradient, so four leaves of 64 would be the
+    tree's smallest and say little of a step) and the pair norm's weight
+    1."""
+    return {"lambdas": draw.norm(next(keys), n, 4, c.head_dim, s=0.1),
+            "sub_norm": jnp.ones((n, 2 * c.head_dim),
+                                 jnp.dtype(c.param_dtype))}
+
+
+def _halves(a):
+    """A leaf over heads that pair up, [.., 2 n, Dh] -> (the pairs' first
+    heads, their second) [.., n, Dh] each; None (no bias) stays None."""
+    if a is None:
+        return None, None
+    pairs = a.reshape(*a.shape[:-2], a.shape[-2] // 2, 2, a.shape[-1])
+    return pairs[..., 0, :], pairs[..., 1, :]
+
+
+def _side_by_side(a):
+    """[.., 2 n, Dh] -> [.., n, 2 Dh]: a pair's two heads side by side."""
+    if a is None:
+        return None
+    return a.reshape(*a.shape[:-2], a.shape[-2] // 2, 2 * a.shape[-1])
+
+
+def _project(h, weight, bias, c):
+    """``h W + b`` by heads: ``weight`` [D, heads, width], ``bias`` [heads,
+    width] or None -> [B, T, heads, width]."""
+    dt = c.compute_dtype
+    out = jnp.einsum("btd,dhk->bthk", h, weight.astype(dt))
+    return out if bias is None else out + bias.astype(dt)
+
+
+def _diff_queries(h, w, c):
+    """(q1, q2) [B, T, H / 2, Dh] of the normed input ``h``."""
+    with jax.named_scope("attn_qkv"):
+        return tuple(_project(h, wq, bq, c) for wq, bq in zip(
+            _halves(w["wq"]), _halves(w.get("bq"))))
+
+
+def _diff_core(q1, q2, k1, k2, v, w, c, ctx: Ctx):
+    """Differential attention from its operands (the comment above) ->
+    (o [B, T, H / 2, 2 Dh], {``lambda``}). ``attn_core`` holds the TWO
+    ``attention(...)`` calls, ``attn_diff`` the ``lambda``s, the combine,
+    the pair norm and the scale, in float32."""
+    rep = q1.shape[2] // k1.shape[2]
+    if rep > 1:
+        with jax.named_scope("attn_gqa"):
+            k1, k2, v = (jnp.repeat(a, rep, axis=2) for a in (k1, k2, v))
+    q1 = ctx.con(q1, _BATCH, AXIS_SEQUENCE, AXIS_TENSOR, None)
+    with jax.named_scope("attn_core"):
+        a1, a2 = (attention(q, k, v, causal=True, impl=c.attn_impl,
+                            window=ctx.window)
+                  for q, k in ((q1, k1), (q2, k2)))
+    with jax.named_scope(DIFF_SCOPE):
+        f32 = lambda name: w[name].astype(jnp.float32)
+        init = 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(ctx.layer, jnp.float32))
+        lq1, lk1, lq2, lk2 = f32("lambdas")
+        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + init
+        d = a1.astype(jnp.float32) - lam * a2.astype(jnp.float32)
+        o = d * jax.lax.rsqrt(jnp.mean(d * d, -1, keepdims=True) + c.norm_eps)
+        o = (o * ((1.0 - init) * f32("sub_norm"))).astype(a1.dtype)
+        return o, {DIFF_LAMBDA.key: jax.lax.stop_gradient(lam)}
 
 
 def _plain_qkv(h, w, c, rope, positions):
@@ -611,14 +753,175 @@ def _ssm_mixer(h, w, c, ctx: Ctx | None = None):
         STEP_MEAN.key: step_mean}
 
 
+# -- Mamba-1, and the readers of a memory ------------------------------------------
+
+def refuses_shared_memory(c) -> str:
+    return (
+        f"KV-cache decode does not run a model with layer_mixers "
+        f"({c.layer_mixers!r}; ssm_expand {c.ssm_expand}, ssm_state "
+        f"{c.ssm_state}, ssm_dt_rank {c.ssm_dt_rank}, kda_conv {c.kda_conv}, "
+        f"diff_attn {c.diff_attn}): a selective-scan ('ssm1') layer keeps "
+        f"a recurrent state [channels, ssm_state] and its convolution's "
+        f"last positions, not keys and values; differential attention "
+        f"reads TWO softmax maps a pair of heads and norms their "
+        f"difference; a gated memory unit ('gmu') reads the scan output of "
+        f"an EARLIER layer, which no cache holds; and the 'cross' layers "
+        f"read ONE earlier layer's keys and values, where the cache would "
+        f"give each layer its own")
+
+
+def _rounded(a):
+    """``a`` in float32, holding the values ``a``'s own dtype rounded it to:
+    left to itself XLA may hand a consumer the float32 a matmul or a sum
+    made BEFORE the rounding (excess precision), and a forward alone and
+    the forward of a train step then differ (``_latent_qkv`` has the case
+    that showed it)."""
+    bits = jnp.finfo(a.dtype)
+    return jax.lax.reduce_precision(a.astype(jnp.float32), bits.nexp,
+                                    bits.nmant)
+
+
+def _ssm1_init(c, keys, n, draw):
+    """A Mamba-1 layer's leaves, the implementation's init (``mamba_ssm``
+    ``Mamba``): the published ONE input projection as ``w_x`` and ``w_z``
+    [D, inner] (its columns by what they make), ``conv_w`` [taps, inner]
+    and ``conv_b`` (``ssm_conv_bias``) U(-1 / sqrt(taps), 1 / sqrt(taps)),
+    ``w_low`` [inner, rank + 2 x state] (``[dt_low | B | C]``), ``w_dt``
+    [rank, inner] U(-rank^-0.5, rank^-0.5), ``dt_bias`` the inverse softplus
+    of a step log-uniform in [0.001, 0.1] floored at 1e-4, ``A_log`` =
+    log(1..state) a channel, ``D`` 1, ``wo`` [inner, D]."""
+    norm, uniform, pdt = draw.norm, draw.uniform, jnp.dtype(c.param_dtype)
+    D, inner, state, rank = c.d_model, c.ssm_inner, c.ssm_state, c.ssm_dt_rank
+    edge, dt_edge = 1.0 / math.sqrt(c.kda_conv), rank ** -0.5
+    step = jnp.maximum(jnp.exp(uniform(
+        next(keys), n, inner, low=math.log(1e-3), high=math.log(1e-1))),
+        1e-4)
+    return {
+        "w_x": norm(next(keys), n, D, inner),
+        "w_z": norm(next(keys), n, D, inner),
+        "conv_w": uniform(next(keys), n, c.kda_conv, inner, low=-edge,
+                          high=edge).astype(pdt),
+        **({"conv_b": uniform(next(keys), n, inner, low=-edge,
+                              high=edge).astype(pdt)}
+           if c.ssm_conv_bias else {}),
+        "w_low": norm(next(keys), n, inner, rank + 2 * state),
+        "w_dt": uniform(next(keys), n, rank, inner, low=-dt_edge,
+                        high=dt_edge).astype(pdt),
+        "dt_bias": _step_bias(step, pdt),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, state + 1, dtype=jnp.float32)),
+            (n, inner, state)).astype(pdt),
+        "D": jnp.ones((n, inner), pdt),
+        "wo": norm(next(keys), n, inner, D, s=draw.res_std),
+    }
+
+
+def _ssm1_check(c):
+    return (("ssm_expand, ssm_state, ssm_dt_rank, ssm_chunk and kda_conv "
+             ">= 1", min(c.ssm_expand, c.ssm_state, c.ssm_dt_rank,
+                         c.ssm_chunk, c.kda_conv) >= 1),
+            (("'ssm1' beside latent attention (kv_latent)",
+              c.kv_latent is not None),))
+
+
+def _ssm1_mixer(h, w, c, ctx: Ctx | None = None):
+    """A Mamba-1 (selective-scan, arXiv:2312.00752) layer's mixer WITH its
+    output projection, from the normed input ``h`` [B, T, D] and the
+    layer's own leaves ``w`` -> (out [B, T, D], its counters, {"m": the
+    scan's output ``y`` [B, T, inner] with the ``D`` term, AHEAD of the
+    gate}). ``[x | z] = h W_in``; ``x' = silu(conv(x) + b)``; ``[dt_low |
+    B_t | C_t] = x' W_low``; ``Delta_t = softplus(dt_low W_dt + b_dt)``, ONE
+    step a channel; ``A = -exp(A_log)`` [inner, state]; ``y`` the recurrence
+    (``state_space.selective_scan``: the decay differs by channel AND state
+    index, ``B_t`` and ``C_t`` are every channel's); ``out = W_o (y *
+    silu(z))``. No norm inside. Its parts under the names the linear
+    mixers' readers read: ``attn_qkv`` the three projections, ``kda_conv``
+    the chain (``flat_conv_silu``), ``kda_gate`` the step, the gate and the
+    counters, ``attn_core`` the ONE scan call, ``attn_out`` ``W_o``."""
+    dt = c.compute_dtype
+    rank, state = c.ssm_dt_rank, c.ssm_state
+    with jax.named_scope("attn_qkv"):
+        x, z = (jnp.einsum("btd,dc->btc", h, w[name].astype(dt))
+                for name in ("w_x", "w_z"))
+    x = linear_attention.flat_conv_silu(
+        x, w["conv_w"], w["conv_b"] if c.ssm_conv_bias else None)
+    with jax.named_scope("attn_qkv"):
+        low = jnp.einsum("btc,cr->btr", x, w["w_low"].astype(dt))
+        raw = jnp.einsum("btr,rc->btc", low[..., :rank], w["w_dt"].astype(dt),
+                         preferred_element_type=jnp.float32)
+    step, a = state_space.step_and_decay(raw, w)
+    with jax.named_scope("attn_core"):
+        y = state_space.selective_scan(
+            x, step, a, low[..., rank:rank + state], low[..., rank + state:],
+            w["D"], chunk=c.ssm_chunk)
+    with jax.named_scope("kda_gate"):
+        gated = (_rounded(y) * jax.nn.silu(_rounded(z))).astype(dt)
+        step = jax.lax.stop_gradient(step)
+        # the most negative ``Delta A`` summed inside a chunk: a channel's
+        # summed step times its most negative ``A``
+        counters = {
+            LOG_DECAY_MIN.key: linear_attention.log_decay_min(
+                step * jnp.min(a, axis=-1), c.ssm_chunk),
+            STEP_MEAN.key: step.mean()}
+    with jax.named_scope("attn_out"):
+        out = jnp.einsum("btc,cd->btd", gated, w["wo"].astype(dt))
+    return out, counters, {"m": y}
+
+
+def _gmu_init(c, keys, n, draw):
+    """A gated memory unit's two projections, ``w1`` [D, inner] and ``w2``
+    [inner, D] (a residual-out projection), no bias."""
+    return {"w1": draw.norm(next(keys), n, c.d_model, c.ssm_inner),
+            "w2": draw.norm(next(keys), n, c.ssm_inner, c.d_model,
+                            s=draw.res_std)}
+
+
+def _gmu_mixer(h, w, c, ctx: Ctx):
+    """A gated memory unit (SambaY, arXiv:2507.06607) WITH its output
+    projection: ``W_2 (m * silu(h W_1))``, ``m`` the scan output an earlier
+    ``"ssm1"`` layer handed out (``ctx.memory``). No scan, no convolution,
+    no state of its own; all of it under ``gmu``."""
+    dt = c.compute_dtype
+    with jax.named_scope(GMU_SCOPE):
+        gate = jax.nn.silu(jnp.einsum(
+            "btd,dc->btc", h, w["w1"].astype(dt),
+            preferred_element_type=jnp.float32))
+        gated = (_rounded(ctx.memory["m"]) * gate).astype(dt)
+        out = jnp.einsum("btc,cd->btd", gated, w["w2"].astype(dt))
+        return out, {GMU_GATE_MEAN.key: jax.lax.stop_gradient(gate).mean()}
+
+
+def _cross_init(c, keys, n, draw):
+    """A cross layer's own leaves: the query projection ``wq`` (``bq``) and
+    the differential form's (``_diff_leaves``); ``attn/wo`` (``bo``) is the
+    stack's, as attention's."""
+    first = next(keys)
+    more = iter(jax.random.split(jax.random.fold_in(first, 1), 8))
+    return {"wq": draw.norm(first, n, c.d_model, c.n_heads, c.head_dim),
+            **({"bq": draw.norm(next(more), n, c.n_heads, c.head_dim)}
+               if c.attn_bias else {}),
+            **_diff_leaves(c, more, n, draw)}
+
+
+def _cross_attention(h, w, c, ctx: Ctx):
+    """Differential CROSS-attention up to (not with) the output projection:
+    the layer's own queries against the keys and values an earlier
+    attention layer handed out (``ctx.memory``), full causal, its own
+    ``lambda``s and pair norm; all of it under ``attn_cross``."""
+    with jax.named_scope(CROSS_SCOPE):
+        return _diff_core(*_diff_queries(h, w, c), *ctx.memory["kv"], w, c,
+                          ctx)
+
+
 # -- the records --------------------------------------------------------------------
 
-def _linear(name: str, init, specs, check, apply, counters, decodes) -> Mixer:
+def _linear(name: str, init, specs, check, apply, counters, decodes,
+            **more) -> Mixer:
     """A linear mixer's record: its own leaves under its own name, its
     work under ``attn_linear``."""
     return Mixer(stack=lambda c: name, scope=lambda window: ATTN_SCOPES[2],
                  init=init, specs=specs, check=check, apply=apply,
-                 counters=lambda c: counters, decodes=decodes)
+                 counters=lambda c: counters, decodes=decodes, **more)
 
 
 MIXERS = {
@@ -630,12 +933,29 @@ MIXERS = {
         scope=lambda window: ATTN_SCOPES[window is not None],
         init=_attn_init, specs=_attn_specs, check=lambda c: (None, ()),
         apply=_attention,
-        counters=lambda c: (GATE_MEAN,) if c.attn_gate else (),
-        decodes=None),
+        counters=lambda c: ((GATE_MEAN,) if c.attn_gate else ())
+        + ((DIFF_LAMBDA,) if c.diff_attn else ()),
+        decodes=None, writes="kv"),
     "kda": _linear("kda", _kda_init, _kda_specs, _kda_check, _kda_mixer,
                    (LOG_DECAY_MIN,), refuses_linear),
     "gdn": _linear("gdn", _gdn_init, _gdn_specs, _gdn_check, _gdn_mixer,
                    (LOG_DECAY_MIN,), refuses_linear),
     "ssm": _linear("ssm", _ssm_init, _ssm_specs, _ssm_check, _ssm_mixer,
                    (LOG_DECAY_MIN, STEP_MEAN), refuses_single_sublayers),
+    # replicated under a mesh, as "ssm"'s (ROADMAP B3)
+    "ssm1": _linear("ssm1", _ssm1_init, lambda c: {}, _ssm1_check,
+                    _ssm1_mixer, (LOG_DECAY_MIN, STEP_MEAN),
+                    refuses_shared_memory, own_out=True, writes="m"),
+    "gmu": _linear("gmu", _gmu_init, lambda c: {},
+                   lambda c: (("ssm_expand >= 1 (the memory's width)",
+                               c.ssm_expand >= 1), ()),
+                   _gmu_mixer, (GMU_GATE_MEAN,), refuses_shared_memory,
+                   own_out=True, reads="m"),
+    "cross": Mixer(
+        stack=lambda c: "cross", scope=lambda window: ATTN_SCOPES[0],
+        init=_cross_init, specs=lambda c: {"wq": _BY_HEAD},
+        check=lambda c: (("diff_attn (cross-attention is written in its "
+                          "differential form alone)", c.diff_attn), ()),
+        apply=_cross_attention, counters=lambda c: (DIFF_LAMBDA,),
+        decodes=refuses_shared_memory, reads="kv"),
 }

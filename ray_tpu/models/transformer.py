@@ -40,12 +40,18 @@ says what each means, the presets which published model sets it):
     body, so each position's kind is static (a windowed layer compiles to
     the kernel that skips tiles, never to a ``cond``); layers left over
     after the last whole period run unrolled behind the scan.
-  - **what a token mixer is** (softmax attention plain or latent, KDA,
-    Gated DeltaNet, a Mamba-2 state-space layer) is its record's,
-    ``models/mixers.py``. **To add a mixer**: a record there, its line in
-    ``mixers.MIXERS`` and its fields here; nothing in ``_block``,
+  - **what a token mixer is** (softmax attention plain, latent or
+    differential, KDA, Gated DeltaNet, a Mamba-2 state-space layer, a
+    Mamba-1 selective scan, a gated memory unit, cross-attention) is its
+    record's, ``models/mixers.py``. **To add a mixer**: a record there, its
+    line in ``mixers.MIXERS`` and its fields here; nothing in ``_block``,
     ``forward``, ``lm_loss``, ``init_params`` or ``partition_specs``, which
     take a mixer's leaves, specs, scope, function and counters from it.
+  - **a memory the stack carries beside x**: a layer whose record
+    ``writes`` hands out named arrays (a Mamba-1 layer its scan output, an
+    attention layer its keys and values) and every later layer whose
+    record ``reads`` that name finds them in its ``Ctx`` (``_writes``,
+    ``run_stack``); a model with no reader carries x alone.
   - **experts**: capacity slots over a mesh or dropless on one chip
     (``ops/moe.py``), a router that reads the block's FIRST norm, held
     experts (``experts_held``: one expert-parallel rank's share), a shared
@@ -122,11 +128,12 @@ SCOPES = ("embed", "layers", "attn_norm", "attn", "mlp_norm", "mlp", "moe",
           "final_norm", "head_loss", "grad_accum", "optimizer")
 # The token mixers ``layer_mixers`` may name (``mixers.MIXERS`` has a
 # record each), the LINEAR ones among them (a state carried along the
-# sequence; attention is the first record), and ``FFN_ONLY``, which names
+# sequence; "gmu" and "cross" carry none: they read an earlier layer's
+# memory), and ``FFN_ONLY``, which names
 # no mixer: the layer is its FFN alone, and makes the model a stack of
 # single-sublayer blocks (``single_sublayer``).
 MIXERS = tuple(mixers.MIXERS)
-LINEAR_MIXERS = MIXERS[1:]
+LINEAR_MIXERS = ("kda", "gdn", "ssm", "ssm1")
 FFN_ONLY = "ffn"
 # Inside ``attn`` and inside ``mlp`` / ``moe``: the norm of the sublayer's
 # output and the residual add behind it.
@@ -335,6 +342,22 @@ class TransformerConfig:
     # False: NO gate projection, ``W_down act(W_up u)`` (no ``w_gate`` /
     # ``shared_w_gate`` leaf).
     expert_gated: bool = True
+    # -- a phi4flash-shaped model (SambaY; llama arch) ----------------------
+    # A Mamba-1 ("ssm1") layer and a gated memory unit ("gmu") are
+    # ``ssm_expand x d_model`` wide inside (``ssm_inner``); the scan's state
+    # is ``ssm_state`` a channel, its step comes up from ``ssm_dt_rank``
+    # numbers a token, its convolution is ``kda_conv`` / ``ssm_conv_bias``,
+    # its chunks ``ssm_chunk``.
+    ssm_expand: int = 0
+    ssm_dt_rank: int = 0
+    # Plain attention is DIFFERENTIAL (``mixers._diff_core``): heads
+    # in pairs, two softmax maps, ``a_1 - lambda a_2`` normed a pair;
+    # ``lambda_init`` goes by the layer's published number (``first_layer``).
+    diff_attn: bool = False
+    attn_bias: bool = False         # biases on attention's four projections
+    # The block norms and the final norm are LayerNorm with a weight AND a
+    # bias (what the gpt2 arch always has), not RMSNorm.
+    layer_norm: bool = False
 
     def __post_init__(self):
         # A config file's JSON gives lists: keep the config hashable.
@@ -356,6 +379,11 @@ class TransformerConfig:
         return self.d_head or self.d_model // self.n_heads
 
     @property
+    def ssm_inner(self) -> int:
+        """The inner width of a Mamba-1 layer and of a memory unit."""
+        return self.ssm_expand * self.d_model
+
+    @property
     def n_scan_layers(self) -> int:
         """Layers of the main stack ``params["layers"]``: all but the
         leading dense ones."""
@@ -375,8 +403,8 @@ class TransformerConfig:
 
     @property
     def linear_mixer(self) -> str | None:
-        """The model's LINEAR mixer ("kda", "gdn" or "ssm": one model has
-        at most one kind), None for a model of attention layers only."""
+        """The model's LINEAR mixer ("kda", "gdn", "ssm" or "ssm1": one
+        model has at most one kind), None for a model with none."""
         return next((m for m in LINEAR_MIXERS if m in self.layer_mixers),
                     None)
 
@@ -399,13 +427,14 @@ class TransformerConfig:
         """The kind of layer ``i`` of the ``n_layers``: "kda", "gdn" or
         "ssm" for a linear layer, "ffn" for a layer with no mixer, else
         (windowed, rope) of its attention (latent attention: full, rotated
-        or not; plain attention beside linear layers: full, rotated or
-        not, ``attn_rope``); None with no pattern: the arch's own."""
+        or not; plain attention beside other mixers: its place in the
+        ``layer_pattern`` where there is one, else full, rotated or not,
+        ``attn_rope``); None with no pattern: the arch's own."""
         if self.layer_mixers and self.layer_mixers[i] != "attn":
             return self.layer_mixers[i]
         if self.kv_latent is not None:
             return (False, self.latent_rope)
-        if self.layer_mixers:
+        if self.layer_mixers and not self.layer_pattern:
             return (False, self.attn_rope)
         if not self.layer_pattern:
             return None
@@ -722,6 +751,59 @@ def nemotron_3_nano_30b_a3b(**kw) -> TransformerConfig:
     )
 
 
+def phi4_mini_flash_reasoning(**kw) -> TransformerConfig:
+    """Phi-4-mini-flash-reasoning (microsoft ``config.json``, ``model_type``
+    ``phi4flash``; the architecture is SambaY, arXiv:2507.06607; the public
+    implementation is the repository's ``modeling_phi4flash.py``): 32 layers
+    over a 2,560-wide stream, each ``x <- x + mixer(LN(x))``, ``x <- x +
+    MLP(LN(x))``, LayerNorm with weight and bias (eps 1e-5), the MLP a SwiGLU
+    of 10,240 (the published ``[gate | up]`` matrix as two leaves), tied
+    embedding and head, NO positional encoding. The mixer by published layer
+    ``l`` (from 0): ``l`` even and <= 16 a Mamba-1 selective scan (arXiv:
+    2312.00752: inner width 5,120, state 16, a convolution over 4 positions
+    with a bias, ``dt_rank`` 160; layer 16 also hands out its scan output
+    ``y``, the memory); ``l`` odd and <= 15 differential attention
+    (arXiv:2410.05258) over a window of 512 keys, ``l`` = 17 the same, full
+    causal, which also hands out its keys and values (40 query and 20 key /
+    value heads of 64, in interleaved pairs, biases on all four
+    projections); ``l`` even and >= 18 a gated memory unit ``W_2 (m *
+    silu(W_1 h))`` on layer 16's ``y``; ``l`` odd and >= 19 differential
+    cross-attention, its own queries against layer 17's keys and values.
+    ``first_layer=f, n_layers=n`` takes the published layers f to f + n - 1
+    (``lambda_init`` goes by the published number); default all 32, 3.85 B
+    parameters. NOT in ``config.json`` and taken from the implementation:
+    the four Mamba sizes (its defaults: state 16, convolution 4, expand 2,
+    ``dt_rank`` ceil(2,560 / 16)), the biases on ``Wqkv`` / ``out_proj``
+    (``nn.Linear(..., bias=True)``) and their absence on the Mamba and
+    memory-unit projections, the differential form with ``lambda_init(l) =
+    0.8 - 0.6 exp(-0.3 l)`` and its RMS norm over a pair's 128 channels,
+    heads paired interleaved (pair n is heads 2 n and 2 n + 1), the window
+    counted with the query's own position, that nothing is rotated."""
+    first = kw.get("first_layer", 0)
+    n = kw.get("n_layers", 32 - first)
+
+    def mixer(l: int) -> str:
+        if l % 2 == 0:
+            return "ssm1" if l <= 16 else "gmu"
+        return "attn" if l <= 17 else "cross"
+
+    return replace(
+        TransformerConfig(
+            vocab_size=200064, d_model=2560, n_heads=40, n_kv_heads=20,
+            d_head=64, d_ff=10240, max_seq_len=262144, arch="llama",
+            norm_eps=1e-5, tie_embeddings=True, layer_norm=True,
+            attn_rope=False, diff_attn=True, attn_bias=True,
+            sliding_window=512, first_layer=0,
+            layer_pattern=tuple((l % 2 == 1 and l <= 15, False)
+                                for l in range(32)),
+            ssm_expand=2, ssm_state=16, ssm_dt_rank=160, kda_conv=4,
+            ssm_chunk=128,
+        ),
+        **{**kw, "n_layers": n,
+           "layer_mixers": tuple(mixer(l) for l in range(first, first + n))},
+    )
+
+
 def moe_small(**kw) -> TransformerConfig:
     """Mixtral-style MoE on the small-llama geometry: 8 experts, top-2.
     Per-token FLOPs ≈ dense small; total params ≈ 8× the FFN stack."""
@@ -782,9 +864,31 @@ def _check_config(c: TransformerConfig) -> None:
             f"{len(c.layer_pattern)} layers (layer_pattern; first_layer "
             f"anchors a pattern that starts or stops mid-period)")
     for name, wrong in (("attn_gate", c.attn_gate),
-                        ("post_norm", c.post_norm)):
+                        ("post_norm", c.post_norm),
+                        ("diff_attn", c.diff_attn),
+                        ("attn_bias", c.attn_bias),
+                        ("layer_norm", c.layer_norm)):
         if wrong and c.arch != "llama":
             raise ValueError(f"{name} requires arch='llama'")
+    if c.diff_attn:
+        for name, wrong in (
+                ("kv_latent", c.kv_latent is not None),
+                ("qk_norm", c.qk_norm), ("attn_gate", c.attn_gate),
+                ("heads it rotates (give layer_mixers with attn_rope=False "
+                 "or a layer_pattern of unrotated kinds)",
+                 any((c.layer_kind(i) or (False, True))[1]
+                     for i in c.layers_with("attn"))),
+                (f"an odd number of heads (n_heads {c.n_heads}, kv_heads "
+                 f"{c.kv_heads}): they pair up",
+                 c.n_heads % 2 or c.kv_heads % 2)):
+            if wrong:
+                raise ValueError(
+                    f"differential attention (diff_attn) does not run with "
+                    f"{name}")
+    if c.attn_bias and not c.diff_attn:
+        raise ValueError("attn_bias is read by differential attention "
+                         "(diff_attn) alone: plain and latent attention "
+                         "project without a bias")
     if c.attn_gate and c.kv_latent is not None:
         raise ValueError("attn_gate gates plain attention's output: it does "
                          "not run with kv_latent")
@@ -885,13 +989,29 @@ def _check_config(c: TransformerConfig) -> None:
                  not set(c.layer_mixers) <= set(names)),
                 (f"{len(c.layer_mixers)} names for n_layers={c.n_layers}",
                  len(c.layer_mixers) != c.n_layers),
-                ("a layer_pattern", bool(c.layer_pattern)),
+                ("a layer_pattern that no first_layer anchors (it gives the "
+                 "attention layers' kinds by their published numbers)",
+                 bool(c.layer_pattern) and c.first_layer is None),
                 # what each of the model's mixers does not run with
                 *(row for _, rows in checks.values() for row in rows),
                 ("more than one kind of linear mixer in one model",
                  len(set(LINEAR_MIXERS) & set(c.layer_mixers)) > 1)):
             if wrong:
                 raise ValueError(f"layer_mixers does not run with {name}")
+        # a reader of a memory no layer ahead of it writes, by index
+        written = set()
+        for i, name in enumerate(c.layer_mixers):
+            mixer = mixers.MIXERS.get(name)
+            if mixer is None:               # "ffn": no mixer
+                continue
+            if mixer.reads not in (None, *written):
+                writers = [k for k, m in mixers.MIXERS.items()
+                           if m.writes == mixer.reads]
+                raise ValueError(
+                    f"layer_mixers[{i}] = {name!r} reads the memory "
+                    f"{mixer.reads!r}, which no layer ahead of it hands out "
+                    f"(a layer named one of {writers})")
+            written.add(mixer.writes)
     if c.n_dense_layers:
         unanchored = bool(c.layer_pattern) and c.first_layer is None
         if (c.n_experts == 0 or unanchored or c.d_ff_dense is None
@@ -914,11 +1034,14 @@ def init_params(rng, config: TransformerConfig):
     1/sqrt(2*n_layers). A model with leading dense layers has two
     stacks: ``dense_layers`` [n_dense_layers, ...] and ``layers`` (the
     expert layers, [n_layers - n_dense_layers, ...]). With
-    ``layer_mixers`` a stack's ``attn`` holds ``wo`` alone, every
-    layer's; a mixer's own leaves (``kda`` / ``gdn`` / ``ssm``, attention's
-    ``mla`` / ``mha``) are stacked over the layers of that kind and drawn
-    by its record (``mixers.MIXERS``: ``init``, where each one's leaves are
-    described). A zero-centred norm's weight is made 0.
+    ``layer_mixers`` a stack's ``attn`` holds ``wo`` (``bo``) alone, of
+    every layer whose mixer has attention's inner width (a record with
+    ``own_out`` keeps its own output projection); a mixer's own leaves
+    (``kda`` / ``gdn`` / ``ssm`` / ``ssm1`` / ``gmu`` / ``cross``,
+    attention's ``mla`` / ``mha``) are stacked over the layers of that kind
+    and drawn by its record (``mixers.MIXERS``: ``init``, where each one's
+    leaves are described). A zero-centred norm's weight is made 0; with
+    ``layer_norm`` the stream's norms have a bias ``b``, made 0.
     In a model of single-sublayer blocks a layer holds its own sublayer's
     leaves alone (``_holds``); experts with no gate projection have no
     ``w_gate`` / ``shared_w_gate`` leaf.
@@ -966,22 +1089,36 @@ def init_params(rng, config: TransformerConfig):
                 stacks[mixer.stack(c)] = mixer.init(
                     c, keys if mixer is attn else third, here.count(kind),
                     draw)
-        # ``attn/wo`` is every mixer layer's, whatever its mixer
-        stacks.get(attn.stack(c), {}).pop("wo", None)
+        # ``attn/wo`` (``bo``) is ONE stack over every layer whose mixer
+        # has attention's inner width, whatever the mixer; a record with
+        # ``own_out`` has an inner width, and an output projection, of its
+        # own
+        for name in ("wo", "bo"):
+            stacks.get(attn.stack(c), {}).pop(name, None)
         value = c.d_head_v if c.kv_latent is not None else Dh
-        stacks["attn"] = {"wo": norm(
-            next(third), sum(kind != FFN_ONLY for kind in here), H, value, D,
-            s=res_std)}
+        rows = sum(kind != FFN_ONLY and not mixers.MIXERS[kind].own_out
+                   for kind in here)
+        if rows:
+            stacks["attn"] = {"wo": norm(next(third), rows, H, value, D,
+                                         s=res_std)}
+            if c.attn_bias:
+                stacks["attn"]["bo"] = norm(next(third), rows, D)
         return stacks
+
+    def stream_norm(*shape):
+        """A norm of the stream: a weight, and with ``layer_norm`` a bias."""
+        return {"w": unit(*shape),
+                **({"b": jnp.zeros(shape, pdt)} if c.layer_norm else {})}
 
     def block_norms(n):
         names = ("ln1", "ln2") + (("ln1_post", "ln2_post") if c.post_norm
                                   else ())
         if c.single_sublayer:       # a layer has ONE norm: its sublayer's
             n_ffn = len(c.layers_with("ffn"))
-            return {"ln1": {"w": unit(n - n_ffn, D)},
-                    "ln2": {"w": unit(n_ffn, D)}}
-        return {name: {"w": unit(n, D)} for name in names}
+            return {"ln1": stream_norm(n - n_ffn, D),
+                    "ln2": stream_norm(n_ffn, D)}
+        return {name: stream_norm(n, D) if name in ("ln1", "ln2")
+                else {"w": unit(n, D)} for name in names}
 
     def ffn_stack(keys, n, width):
         return {
@@ -994,7 +1131,7 @@ def init_params(rng, config: TransformerConfig):
     params = {
         "embed": {"tokens": norm(next(keys), c.vocab_size, D)},
         "layers": mixer_stacks(keys, c.n_dense_layers, L),
-        "final_norm": {"w": unit(D)},
+        "final_norm": stream_norm(D),
     }
     if c.arch == "gpt2":
         params["embed"]["pos"] = norm(next(keys), c.max_seq_len, D)
@@ -1156,10 +1293,11 @@ def _holds(c: TransformerConfig, name: str, kind) -> bool:
     """Whether a layer of kind ``kind`` (``layer_kind``) has leaves in the
     subtree ``name`` of its stack. A mixer's own subtree (its record's
     ``stack``) is stacked over the layers of that mixer alone;
-    in a model of single-sublayer blocks ``ln1`` and ``attn`` (``wo``)
-    over the layers that are a mixer, everything else (``ln2``, the
-    router, ``mlp``) over those that are an FFN; any other subtree over
-    all the layers."""
+    ``attn`` (``wo``) over the layers whose mixer has no output projection
+    of its own (``own_out``); in a model of single-sublayer blocks ``ln1``
+    and ``attn`` over the layers that are a mixer, everything else
+    (``ln2``, the router, ``mlp``) over those that are an FFN; any other
+    subtree over all the layers."""
     if not c.layer_mixers:
         return True
     mixer = kind if isinstance(kind, str) else "attn"
@@ -1167,6 +1305,8 @@ def _holds(c: TransformerConfig, name: str, kind) -> bool:
            if m in c.layer_mixers}
     if name in own:
         return own[name] == mixer
+    if name == "attn" and mixer != FFN_ONLY and mixers.MIXERS[mixer].own_out:
+        return False
     if not c.single_sublayer:
         return True
     return (name in ("ln1", "attn")) == (mixer != FFN_ONLY)
@@ -1198,6 +1338,23 @@ def _take_layer(c: TransformerConfig, stack, kinds: tuple, i: int):
                 lambda a, at=sum(_holds(c, name, kind)
                                  for kind in kinds[:i]): a[at], sub)
             for name, sub in stack.items() if _holds(c, name, kinds[i])}
+
+
+def _writes(c: TransformerConfig) -> tuple:
+    """For every layer (of ``n_layers``) the names of the memories it hands
+    out: of each memory some layer's record ``reads``, the LAST layer whose
+    record ``writes`` it ahead of the FIRST reader (``_check_config`` has
+    seen that there is one). Empty tuples for a model with no reader."""
+    names = c.layer_mixers or ("attn",) * c.n_layers
+    records = [mixers.MIXERS.get(name) for name in names]
+    out = [()] * c.n_layers
+    for memory in sorted({r.reads for r in records if r and r.reads}):
+        reader = next(i for i, r in enumerate(records)
+                      if r and r.reads == memory)
+        writer = max(i for i, r in enumerate(records[:reader])
+                     if r and r.writes == memory)
+        out[writer] += (memory,)
+    return tuple(out)
 
 
 def forward(params, tokens, config: TransformerConfig, *, mesh=None,
@@ -1258,11 +1415,22 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
             rope = (cos, sin)
         x = con(x, _BATCH, AXIS_SEQUENCE, None)
 
-    def layer_of(kind, dense: bool = False):
-        """The block of one kind of layer (static), under remat."""
-        def layer(x, lp):
+    writes = _writes(c)
+    # A model that hands out memory runs its layers IN LINE (``run_stack``),
+    # and there the stream is cut between a block's halves (``_block``).
+    cut = any(writes)
+
+    def layer_of(kind, dense: bool = False, writes: tuple = ()):
+        """The block of one kind of layer (static), under remat: (x, its
+        leaves, {a memory's name: what an earlier layer handed out}, its
+        published number or None) -> (x, its counters, the memories named
+        in ``writes``, which it hands out). A memory is an INPUT of every
+        layer that reads it and an output of the one that writes it: kept
+        once, never made again for a reader."""
+        def layer(x, lp, memory, number):
             return _block(x, lp, c, rope=rope, con=con, positions=positions,
-                          kind=kind, dense=dense)
+                          kind=kind, dense=dense, memory=memory,
+                          writes=writes, number=number, cut=cut)
 
         if not c.remat:
             return layer
@@ -1279,52 +1447,81 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
                 policies.dots_with_no_batch_dims_saveable, policy)
         return jax.checkpoint(layer, policy=policy)
 
-    def run_stack(x, stack, first: int, n: int, dense: bool = False):
+    def run_stack(x, stack, first: int, n: int, memory: dict,
+                  dense: bool = False):
         """Layers ``first`` to ``first + n`` of the model, whose
-        parameters are ``stack`` -> (x, every layer's aux, stacked)."""
+        parameters are ``stack`` -> (x, every layer's aux, stacked, the
+        memories handed out so far). A layer that hands out a memory is a
+        kind of its own (no period repeats it) and runs IN LINE; a scan's
+        steps read the memories written ahead of it and write none."""
         kinds = tuple(c.layer_kind(first + i) for i in range(n))
-        period = _period(kinds)
-        layers = [layer_of(kind, dense) for kind in kinds]
+        roles = writes[first:first + n]
+        period = _period(tuple(zip(kinds, roles)) if any(roles) else kinds)
+        layers = [layer_of(kind, dense, role)
+                  for kind, role in zip(kinds, roles)]
+        # the layers' PUBLISHED numbers, for a model that reads them
+        start = (c.first_layer or 0) + first if c.diff_attn else None
 
-        def in_line(h, part, kinds, layers):
+        def numbers(steps: int, stride: int):
+            return None if start is None else (
+                start + stride * jnp.arange(steps, dtype=jnp.int32))
+
+        def in_line(h, part, kinds, layers, memory, number):
             per_layer = []
             for i, layer in enumerate(layers):
-                h, aux_i = layer(h, _take_layer(c, part, kinds, i))
+                h, aux_i, wrote = layer(
+                    h, _take_layer(c, part, kinds, i), memory,
+                    None if number is None else number + i)
+                memory = {**memory, **wrote}
                 per_layer.append(aux_i)
-            return h, jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+            return (h, jax.tree.map(lambda *a: jnp.stack(a), *per_layer),
+                    memory)
 
-        if not c.scan_layers:
+        if not c.scan_layers or (any(writes) and n == period):
             # Unrolled: larger compile, but lets XLA schedule across layer
             # boundaries (and sidesteps scan-differentiation limits on some
-            # backends when remat is off).
-            return in_line(x, stack, kinds, layers)
+            # backends when remat is off). A model that hands memory from
+            # layer to layer and has no period runs so too.
+            return in_line(x, stack, kinds, layers, memory, start)
+        if any(roles[:n // period * period]):
+            raise NotImplementedError(
+                f"layers {first} to {first + n}: a layer that hands out a "
+                f"memory inside a repeated period of {period} layers")
         if period == 1:
-            return _scan_layers(lambda h, lp: layers[0](h, lp), x, stack, n)
+            x, auxs = _scan_layers(
+                lambda h, ops: layers[0](h, ops[0], memory, ops[1])[:2], x,
+                (stack, numbers(n, 1)), n)
+            return x, auxs, memory
         # One scan step is one whole period, its layers unrolled: the
         # stacked [L, ...] weights are read as [L / P, P, ...] (a mixer's
         # own leaves as [L / P, layers of that kind a period, ...]); the
         # layers behind the last whole period run in line.
         whole, left = _split_stack(c, stack, kinds, period)
         x, auxs = _scan_layers(
-            lambda h, pp: in_line(h, pp, kinds[:period], layers[:period]),
-            x, whole, n // period)
+            lambda h, ops: in_line(h, ops[0], kinds[:period], layers[:period],
+                                   memory, ops[1])[:2],
+            x, (whole, numbers(n // period, period)), n // period)
         if n % period:
             at = n // period * period
-            x, more = in_line(x, left, kinds[at:], layers[at:])
+            x, more, memory = in_line(
+                x, left, kinds[at:], layers[at:], memory,
+                None if start is None else start + at)
             auxs = jax.tree.map(lambda a, b: jnp.concatenate(
                 [a.reshape(-1, *a.shape[2:]), b]), auxs, more)
-        return x, auxs
+        return x, auxs, memory
 
     # ``layers`` holds what belongs to no one part of a block: the scan's
     # reads of the stacked weights and writes of their stacked gradients.
     with jax.named_scope("layers"):
+        memory = {}
         if c.n_dense_layers:
             # the leading dense layers: a stack of their own, a scan (or
             # loop) of its own ahead of the expert layers'
-            x, dense_auxs = run_stack(x, params["dense_layers"], 0,
-                                      c.n_dense_layers, dense=True)
-        x, auxs = run_stack(x, params["layers"], c.n_dense_layers,
-                            c.n_scan_layers)
+            x, dense_auxs, memory = run_stack(
+                x, params["dense_layers"], 0, c.n_dense_layers, memory,
+                dense=True)
+        x, auxs, _ = run_stack(x, params["layers"], c.n_dense_layers,
+                               c.n_scan_layers, memory)
         stacks = [(c.n_dense_layers, c.n_scan_layers, auxs)]
         if c.n_dense_layers:
             stacks.append((0, c.n_dense_layers, dense_auxs))
@@ -1344,13 +1541,14 @@ def forward(params, tokens, config: TransformerConfig, *, mesh=None,
 
 def _norm(c: TransformerConfig, x, p):
     """The arch's norm of the stream ``x`` by the leaves ``p``."""
-    if c.arch == "gpt2":
+    if c.arch == "gpt2" or c.layer_norm:
         return layer_norm(x, p["w"], p["b"], eps=c.norm_eps)
     return rms_norm(x, _norm_weight(c, p["w"]), eps=c.norm_eps)
 
 
 def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
-           kind=None, dense: bool = False):
+           kind=None, dense: bool = False, memory=None, writes: tuple = (),
+           number=None, cut: bool = False):
     """One transformer block (pre-norm residual). Its parts carry the
     scopes ``attn_norm``, ``attn``, ``mlp_norm`` and ``mlp`` / ``moe``
     (see SCOPES); each part's residual add is inside its scope. ``kind``
@@ -1364,10 +1562,14 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
     inside the sublayer's own scope, and joins the stream there. In a
     model of single-sublayer blocks (``single_sublayer``) the block is ONE
     of its halves: ``attn_norm`` + ``attn`` for a layer named by a mixer,
-    ``mlp_norm`` + ``moe`` for one named "ffn". Returns (x, every counter
-    of the model, ``_counters``, by its ``key``: what this layer's
-    sublayers report, zeros for what they do not, so that every layer of
-    a stack reports alike)."""
+    ``mlp_norm`` + ``moe`` for one named "ffn". ``memory``: what earlier
+    layers handed out, by name, for a mixer that reads one; ``writes``:
+    the memories THIS layer hands out; ``number``: its published number;
+    ``cut``: the stream between the two halves is a value of its own.
+    Returns (x, every counter of the model, ``_counters``, by its ``key``:
+    what this layer's sublayers report, zeros for what they do not, so
+    that every layer of a stack reports alike, {a name in ``writes``: the
+    memory})."""
     experts = c.n_experts > 0 and not dense
 
     def join(x, out, norm: str):
@@ -1378,26 +1580,39 @@ def _block(x, lp, c: TransformerConfig, *, rope, con, positions=None,
             return x + rms_norm(out, _norm_weight(c, lp[norm]["w"]),
                                 eps=c.norm_eps)
 
-    router, found = None, {}
+    router, found, wrote = None, {}, {}
     if kind != FFN_ONLY:
-        x, router, found = _mixer_sublayer(
+        x, router, found, wrote = _mixer_sublayer(
             x, lp, c, rope=rope, con=con, positions=positions, kind=kind,
-            experts=experts, join=join)
+            experts=experts, join=join, memory=memory, writes=writes,
+            number=number)
+        if cut:
+            # Found on the chip by bisection (PERF.md section 6, PR 62): an
+            # attention layer IN LINE behind another layer gave a forward
+            # alone one loss and a train step's forward another (up to 3e-4
+            # at 16,384 tokens): XLA's two programs round what lies between
+            # ``attn/wo``'s operand and the next norm at different cuts.
+            # With a barrier here both read the SAME rounded stream.
+            x = jax.lax.optimization_barrier(x)
     if kind == FFN_ONLY or not c.single_sublayer:
         x, more = _ffn_sublayer(x, lp, c, router=router, con=con,
                                 experts=experts, join=join)
         found = {**found, **more}
     return x, {k.key: found[k.key] if k.key in found
-               else jnp.zeros(k.shape(c), jnp.float32) for k in _counters(c)}
+               else jnp.zeros(k.shape(c), jnp.float32)
+               for k in _counters(c)}, wrote
 
 
 def _mixer_sublayer(x, lp, c: TransformerConfig, *, rope, con, positions,
-                    kind, experts: bool, join):
+                    kind, experts: bool, join, memory=None,
+                    writes: tuple = (), number=None):
     """A block's first half: ``attn_norm`` and the token mixer under
     ``attn`` with its residual add -> (x, the router's logits where it
-    reads this norm, else None, the mixer's counters by their keys). The
-    mixer is its record's ``apply`` (``mixers.MIXERS``) on its own leaves;
-    what it returns goes through ``attn/wo``, every mixer layer's."""
+    reads this norm, else None, the mixer's counters by their keys, the
+    memories named in ``writes``). The mixer is its record's ``apply``
+    (``mixers.MIXERS``) on its own leaves; what it returns goes through
+    ``attn/wo`` (and ``bo``), or, from a record with ``own_out``, joins the
+    stream as it is."""
     dt = c.compute_dtype
     mixer = mixers.MIXERS[kind if isinstance(kind, str) else "attn"]
     window = None
@@ -1414,22 +1629,26 @@ def _mixer_sublayer(x, lp, c: TransformerConfig, *, rope, con, positions,
     with jax.named_scope("attn"), (
             contextlib.nullcontext() if kind is None
             else jax.named_scope(mixer.scope(window))):
-        o, counters = mixer.apply(
+        o, counters, *wrote = mixer.apply(
             h, lp[mixer.stack(c)], c,
             mixers.Ctx(rope=rope, positions=positions, window=window,
-                       con=con))
+                       con=con, memory=memory, layer=number))
         with jax.named_scope("attn_out"):
-            wo = lp["attn"]["wo"].astype(dt)
-            # one stack for every layer: a linear layer's heads are its
-            # rows regrouped (32 x 128 of Gated DeltaNet's for 16 x 256)
-            if o.shape[2:] != wo.shape[:2]:
-                o = o.reshape(*o.shape[:2], *wo.shape[:2])
-            o = jnp.einsum("bthk,hkd->btd", o, wo)
+            if not mixer.own_out:
+                wo = lp["attn"]["wo"].astype(dt)
+                # one stack for every layer whose mixer is as wide inside
+                # as attention: a linear layer's heads are its rows
+                # regrouped (32 x 128 of Gated DeltaNet's for 16 x 256)
+                if o.shape[2:] != wo.shape[:2]:
+                    o = o.reshape(*o.shape[:2], *wo.shape[:2])
+                o = jnp.einsum("bthk,hkd->btd", o, wo)
+                if c.attn_bias:
+                    o = o + lp["attn"]["bo"].astype(dt)
             if not c.post_norm:
                 x = x + o
         if c.post_norm:
             x = join(x, o, "ln1_post")
-    return x, router, counters
+    return x, router, counters, {name: wrote[0][name] for name in writes}
 
 
 def _ffn_sublayer(x, lp, c: TransformerConfig, *, router, con,
@@ -2020,15 +2239,19 @@ def refuse_decode(c: TransformerConfig) -> None:
                         ("norm_zero_centred", c.norm_zero_centred),
                         ("shared_expert_gate", c.shared_expert_gate),
                         ("embed_scale", c.embed_scale != 1.0
-                         and c.embed_scale)):
+                         and c.embed_scale),
+                        ("diff_attn", c.diff_attn),
+                        ("attn_bias", c.attn_bias),
+                        ("layer_norm", c.layer_norm)):
         if value:
             raise NotImplementedError(
                 f"KV-cache decode does not run a model with {name} "
                 f"({value!r}): the cache holds kv_heads x head_dim x 2 a "
                 f"token and every layer would be decoded as a dense one "
                 f"of plain attention, its q and k not normed, its heads "
-                f"rotated whole, its norms not zero-centred, its output "
-                f"neither gated nor normed, its embedding not scaled")
+                f"rotated whole, its norms RMS and not zero-centred, its "
+                f"output neither gated nor normed, its projections without "
+                f"a bias, its embedding not scaled")
     if c.n_experts > 0:
         raise NotImplementedError(
             "KV-cache decode for MoE models is not implemented yet"

@@ -92,10 +92,14 @@ _F32 = jnp.float32
 def step_and_decay(raw, w):
     """(``Delta`` = softplus(raw + dt_bias), the log-decay ``a`` = -exp(A_log)
     x Delta) [B, T, H] float32 of the step's projection ``raw`` [B, T, H]
-    float32 (scope ``kda_gate``). ``w``: ``dt_bias``, ``A_log`` [H]."""
+    float32 (scope ``kda_gate``). ``w``: ``dt_bias``, ``A_log`` [H]. With
+    ``A_log`` [channels, state] (Mamba-1: a decay a channel AND state index,
+    which ``selective_scan`` multiplies out itself) the second is ``A`` =
+    -exp(A_log) as it is."""
     with jax.named_scope("kda_gate"):
         delta = jax.nn.softplus(raw + w["dt_bias"].astype(_F32))
-        return delta, -jnp.exp(w["A_log"].astype(_F32)) * delta
+        a = -jnp.exp(w["A_log"].astype(_F32))
+        return delta, (a * delta if a.ndim == 1 else a)
 
 
 def gated_group_norm(y, z, weight, groups: int, *, eps: float):
@@ -566,3 +570,198 @@ def ssm_scan(x, dt, a, b, c, skip, *, chunk: int):
         skip = skip.astype(_F32)[:, None]
     return (y.astype(_F32) + skip * x.astype(_F32)).astype(x.dtype).reshape(
         bsz, t, h, p)
+
+
+# -- the selective scan (Mamba-1): a decay a channel AND a state index --------------
+#
+# ``H_t[c, n] = exp(Delta_t[c] A[c, n]) H_{t-1}[c, n] + Delta_t[c] x_t[c] B_t[n]``,
+# ``y_t[c] = sum_n H_t[c, n] C_t[n] + D[c] x_t[c]``: the decay is an array
+# [channels, state] a token, ``B_t`` and ``C_t`` are every channel's, and
+# there is no matrix product in it (``ssm_scan``'s chunks are matmuls
+# because a head has ONE decay). In plain XLA, in chunks of ``chunk``
+# positions, the state [.., state, channels] float32 with the CHANNELS in
+# the lanes:
+#
+#   starts   every chunk's START state: what a chunk's own tokens leave at
+#            its end is ONE fused sum over its positions (``exp(A (G_last -
+#            G_t))``, exponent <= 0), and a short scan over the chunks
+#            carries it (``_selective_starts``);
+#   the walk ALL chunks step through their positions in lockstep, one
+#            ``lax.scan`` step a position on [B, T / chunk, state,
+#            channels], each from its own start state (``_walk``); its
+#            output stays float32 until ``D x`` is added: rounded ONCE.
+#
+# [T, channels, state] float32 (5.4 GB a layer at 16,384 tokens and 5,120
+# channels) never exists: the residuals are the operands and the start
+# states (42 MB). The backward is the op's own: the chunks' start states'
+# cotangents the same way round (a fused sum, a short scan from the last
+# chunk), then the walk again forward (the states made anew: ``d C`` and
+# what ``H_t`` gives the decay's gradient) and once backward (the states'
+# cotangents: ``d x``, ``d B``, the rest of the decay's). The decay's
+# gradient needs ``lambda_t * H_{t-1}`` where the two walks run opposite
+# ways; it is taken as the running sum ``W_t = sum_{s >= t} (g_s H_s -
+# lambda_s u_s)`` inside a chunk plus ``d S_end * S_end`` at its end (``W_t
+# = lambda_t alpha_t H_{t-1}``, ``lambda_t H_t = g_t H_t + W_{t+1}``), so
+# nothing is ever divided by a decay. Every exponent is ``Delta A`` or a
+# difference of cumulative sums with the later position first, <= 0.
+
+SELECTIVE_CHUNK = 128
+# Positions a ``while`` step of the walk holds. On the v5e at [1, 16384, 5120]
+# 1, 2, 4, 8 read 53.9, 50.7, 49.3, 55.6 ms forward + backward (PERF.md, PR
+# 62): 4 is 9% of the scan and 0.8 GB of the step's memory, which the
+# Phi-4-mini-flash cell does not have.
+_WALK_UNROLL = 1
+
+
+def _in_chunks(v, q: int):
+    """[B, T, ...] -> [B, T / Q, Q, ...] float32."""
+    return v.reshape(v.shape[0], -1, q, *v.shape[2:]).astype(_F32)
+
+
+def _chunked(v, q: int):
+    """[B, T, ...] -> [Q, B, T / Q, ...]: a chunk's positions first."""
+    return jnp.moveaxis(v.reshape(v.shape[0], -1, q, *v.shape[2:]), 2, 0)
+
+
+def _advance(h, x_t, d_t, b_t, a):
+    """One position of the recurrence on every chunk's state ``h`` [B, N,
+    S, C]: ``exp(Delta_t A) h + (Delta_t x_t) (x) B_t``."""
+    return (jnp.exp(d_t[:, :, None] * a) * h
+            + (d_t * x_t)[:, :, None] * b_t[..., None])
+
+
+def _unchunked(v):
+    """[Q, B, N, ...] -> [B, N * Q, ...]."""
+    v = jnp.moveaxis(v, 0, 2)
+    return v.reshape(v.shape[0], -1, *v.shape[3:])
+
+
+def _chunk_decays(delta, q: int):
+    """(the step summed from a chunk's first position to each, [B, N, Q,
+    C], the whole chunk's [B, N, C]) of ``delta`` [B, T, C]."""
+    total = jnp.cumsum(_in_chunks(delta, q), axis=2)
+    return total, total[:, :, -1]
+
+
+def _carry_chunks(added, whole, reverse: bool = False):
+    """``S' = whole * S + added`` along the chunks (axis 1) from zero ->
+    the state each chunk is HANDED ([B, N, S, C]: its start state, or with
+    ``reverse`` the cotangent of its end state)."""
+    def chunk(state, ops):
+        add, keep = ops
+        return keep * state + add, state
+
+    with jax.named_scope("ssm_carry"):
+        _, handed = jax.lax.scan(
+            chunk, jnp.zeros_like(added[:, 0]),
+            (jnp.moveaxis(added, 1, 0), jnp.moveaxis(whole, 1, 0)),
+            reverse=reverse)
+        return jnp.moveaxis(handed, 0, 1)
+
+
+def _selective_starts(x, delta, a, b, q: int):
+    """Every chunk's start state [B, N, S, C] float32."""
+    total, last = _chunk_decays(delta, q)
+    written = _in_chunks(x, q) * _in_chunks(delta, q)
+    left = jnp.sum(
+        jnp.exp((last[:, :, None] - total)[:, :, :, None] * a)
+        * written[:, :, :, None] * _in_chunks(b, q)[..., None], axis=2)
+    return _carry_chunks(left, jnp.exp(last[:, :, None] * a))
+
+
+def _walk(x, delta, a, b, c, starts, q: int):
+    """y [B, T, C] float32 (without ``D x``): every chunk from its start
+    state, position by position, all chunks at once."""
+    def position(h, ops):
+        x_t, d_t, b_t, c_t = (v.astype(_F32) for v in ops)
+        h = _advance(h, x_t, d_t, b_t, a)
+        return h, jnp.sum(h * c_t[..., None], axis=2)
+
+    _, y = jax.lax.scan(position, starts,
+                        tuple(_chunked(v, q) for v in (x, delta, b, c)),
+                        unroll=_WALK_UNROLL)
+    return _unchunked(y)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _selective(x, delta, a, b, c, q):
+    return _selective_fwd(x, delta, a, b, c, q)[0]
+
+
+def _selective_fwd(x, delta, a, b, c, q):
+    starts = _selective_starts(x, delta, a, b, q)
+    return _walk(x, delta, a, b, c, starts, q), (x, delta, a, b, c, starts)
+
+
+def _selective_bwd(q, kept, d_y):
+    x, delta, a, b, c, starts = kept
+    total, last = _chunk_decays(delta, q)
+    # the cotangent of every chunk's END state: what its later chunks read
+    read = jnp.sum(jnp.exp(total[:, :, :, None] * a)
+                   * _in_chunks(d_y, q)[:, :, :, None]
+                   * _in_chunks(c, q)[..., None], axis=2)
+    d_ends = _carry_chunks(read, jnp.exp(last[:, :, None] * a), reverse=True)
+    ends = jnp.concatenate([starts[:, 1:], jnp.zeros_like(starts[:, :1])], 1)
+    at_end = d_ends * ends                                  # W past a chunk
+    ops = tuple(_chunked(v, q) for v in (x, delta, b, c, d_y))
+    ops += (jnp.moveaxis(total, 2, 0),)
+    zero = jnp.zeros(a.shape, _F32)
+
+    def forth(carry, ops):
+        """The states again: ``d C`` and ``g_t H_t``'s two sums."""
+        h, d_a = carry
+        x_t, d_t, b_t, c_t, g_t, sum_t = (v.astype(_F32) for v in ops)
+        h = _advance(h, x_t, d_t, b_t, a)
+        lit = h * g_t[:, :, None]                           # H_t dy_t
+        through = lit * c_t[..., None]                      # g_t H_t
+        d_a = d_a + jnp.sum(through * sum_t[:, :, None], (0, 1))
+        return (h, d_a), (jnp.sum(lit, 3), jnp.sum(through * a, 2))
+
+    def back(carry, ops):
+        """The states' cotangents: ``d x``, ``d B``, ``lambda_t u_t``'s
+        two sums; ``ahead`` is what a state's successor hands it."""
+        ahead, d_a = carry
+        x_t, d_t, b_t, c_t, g_t, sum_t = (v.astype(_F32) for v in ops)
+        lam = c_t[..., None] * g_t[:, :, None] + ahead
+        wrote = d_t * x_t
+        aimed = lam * b_t[..., None]                        # lambda_t B_t
+        d_wrote = jnp.sum(aimed, 2)
+        through = aimed * wrote[:, :, None]                 # lambda_t u_t
+        d_a = d_a + jnp.sum(through * sum_t[:, :, None], (0, 1))
+        out = (d_wrote * d_t, d_wrote * x_t,
+               jnp.sum(lam * wrote[:, :, None], 3), jnp.sum(through * a, 2))
+        return (jnp.exp(d_t[:, :, None] * a) * lam, d_a), out
+
+    (_, d_a_forth), (d_c, gained) = jax.lax.scan(
+        forth, (starts, zero), ops, unroll=_WALK_UNROLL)
+    (_, d_a_back), (d_x, d_delta, d_b, lost) = jax.lax.scan(
+        back, (d_ends, zero), ops, reverse=True, unroll=_WALK_UNROLL)
+    # W_t summed over the state index against A: the running sum from a
+    # chunk's last position, and what lies past the chunk
+    inside = jnp.flip(jnp.cumsum(jnp.flip(gained - lost, 0), 0), 0)
+    d_delta = _unchunked(d_delta + inside) + jnp.repeat(
+        jnp.sum(at_end * a, 2), q, axis=1)
+    d_a = d_a_forth - d_a_back + jnp.sum(at_end * last[:, :, None], (0, 1))
+    return (_unchunked(d_x).astype(x.dtype), d_delta.astype(delta.dtype),
+            d_a.astype(a.dtype), _unchunked(d_b).astype(b.dtype),
+            _unchunked(d_c).astype(c.dtype))
+
+
+_selective.defvjp(_selective_fwd, _selective_bwd)
+
+
+def selective_scan(x, delta, a, b, c, skip, *, chunk: int = SELECTIVE_CHUNK):
+    """The Mamba-1 recurrence (the comment above): ``x`` [B, T, C], the
+    step ``delta`` [B, T, C] float32 (> 0), ``a`` [C, N] (``A``, < 0),
+    ``b`` and ``c`` [B, T, N] (every channel's), ``skip`` [C] (``D``) ->
+    ``y`` [B, T, C] in ``x``'s dtype, ``H_0 = 0``. A ``T`` that is no whole
+    number of chunks is padded behind the row with tokens that write
+    nothing and forget nothing (``delta`` = 0). Differentiable in all six
+    operands; the state, the decays and every sum float32."""
+    t = x.shape[1]
+    q = min(chunk, t)
+    pad = -t % q
+    padded = lambda v: jnp.pad(v, ((0, 0), (0, pad), (0, 0))) if pad else v
+    y = _selective(padded(x), padded(delta.astype(_F32)),
+                   a.astype(_F32).T, padded(b), padded(c), q)[:, :t]
+    return (y + skip.astype(_F32) * x.astype(_F32)).astype(x.dtype)

@@ -4,10 +4,11 @@ kind, and a counter named once.
 (a) each of the four records against the preset that uses it: the leaves
 its ``init`` draws are the subtree the model keeps and the subtree
 ``partition_specs`` names, and its ``check`` refuses its own missing field
-by today's words; (b) a FIFTH mixer that only this file knows (a causal
+by today's words; (b) ONE MORE mixer that only this file knows (a causal
 cumulative mean with one learned scale a layer, one counter folded by
-``max``) trains beside attention through ``make_train_step`` with nothing
-in ``transformer.py`` edited; (c) ``init_params`` at seed 0 gives, for the
+``max``; it writes no memory and its ``apply`` returns the two values it
+always did) trains beside attention through ``make_train_step`` with
+nothing in ``transformer.py`` edited; (c) ``init_params`` at seed 0 gives, for the
 seven architectures' small models and three presets, the bytes the tree
 before the seam gave (PR 59's, recorded from its checkout); (d) every
 counter of the tables reaches ``lm_loss``'s metrics under its name, for the
@@ -127,8 +128,8 @@ def test_a_records_check_refuses_its_own_field_by_name(kind, name, changes,
 
 def test_the_names_other_modules_import_are_still_transformers():
     assert transformer.MIXERS == tuple(mixers.MIXERS) == (
-        "attn", "kda", "gdn", "ssm")
-    assert transformer.LINEAR_MIXERS == ("kda", "gdn", "ssm")
+        "attn", "kda", "gdn", "ssm", "ssm1", "gmu", "cross")
+    assert transformer.LINEAR_MIXERS == ("kda", "gdn", "ssm", "ssm1")
     assert transformer.FFN_ONLY == "ffn"
     assert transformer._expand_gqa is mixers._expand_gqa
     assert transformer.SCOPE_FILES[-1] == mixers.__file__
@@ -138,7 +139,7 @@ def test_the_names_other_modules_import_are_still_transformers():
         assert mixers.MIXERS[kind].stack(None) == kind
 
 
-# -- (b) a fifth mixer, registered from outside ----------------------------------
+# -- (b) one more mixer, registered from outside ----------------------------------
 
 PEAK = mixers.Counter("cummean_peak", "cummean_out_absmax", "max")
 
@@ -209,7 +210,7 @@ def test_a_fifth_mixer_trains_beside_attention_with_no_edit(monkeypatch):
 def test_a_name_no_record_has_is_still_refused():
     with pytest.raises(ValueError, match="names other than "
                                          r"\('attn', 'kda', 'gdn', 'ssm', "
-                                         r"'ffn'\)"):
+                                         r"'ssm1', 'gmu', 'cross', 'ffn'\)"):
         transformer._check_config(models.tiny(
             arch="llama", layer_mixers=("attn", "cummean")))
 
